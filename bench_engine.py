@@ -30,7 +30,7 @@ from typing import List, Optional
 REPO = Path(__file__).resolve().parent
 sys.path.insert(0, str(REPO))
 
-from bench import baseline_ratio, ensure_backend  # noqa: E402
+from bench import baseline_ratio, require_tpu  # noqa: E402
 
 
 def _make_engine(model: str, B: int, isl: int, osl: int, K: int, page: int = 64,
@@ -512,18 +512,9 @@ def main(argv: Optional[List[str]] = None):
         import os
 
         os.environ["JAX_PLATFORMS"] = "cpu"
-        if "jax" in sys.modules:
-            import jax
-
-            jax.config.update("jax_platforms", "cpu")
-            assert jax.devices()[0].platform == "cpu"
+    require_tpu(args.smoke)
 
     model = args.model or ("tiny" if args.smoke else "llama3-3b")
-    if not args.smoke:
-        unavailable = ensure_backend(f"engine_decode_{model}")
-        if unavailable is not None:
-            print(json.dumps(unavailable))
-            return 0
     vocab = 512 if model in ("tiny", "tiny-moe") else 128000
     B, isl, osl = args.batch, args.isl, args.osl
     if args.smoke:

@@ -26,7 +26,7 @@ Arms:
 Usage:
   python bench_kv_cache.py                 # full CPU report (3 arms)
   python bench_kv_cache.py --smoke         # CI gate (2 arms, floors)
-  python bench_kv_cache.py --quantize int8 # hardware phase (bench_watchdog)
+  python bench_kv_cache.py --quantize int8 # the on-chip arm
 """
 
 from __future__ import annotations

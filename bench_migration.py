@@ -25,8 +25,7 @@ have produced (count-contiguity is a corollary).
 
 --smoke gates (CI):  median ckpt TTFT <= --max-ratio x median recompute
 TTFT, resume_source_checkpoint > 0 on B, and byte-identical
-continuations on every round. The real-hardware claim rides the
-`engine_migration` bench_watchdog phase.
+continuations on every round. On the chip: not measured.
 """
 
 from __future__ import annotations

@@ -248,6 +248,16 @@ COMPILE_SURFACES = {
         "help": "KV page scatter for migration/onboard import; donates "
                 "the pool (aliased in-place update)",
     },
+    "zero_pool": {
+        "module": "dynamo_tpu/ops/kv_quant.py",
+        "kind": "jit",
+        "donate": (),
+        "static": (),
+        "axes": {"pool": "one program per engine: the sharded pool's shape"},
+        "warmup": False,
+        "help": "allocates a mesh-sharded KV pool shard by shard at engine "
+                "construction (the whole pool does not fit one device)",
+    },
     # ----------------------------------------------------------------- #
     # ops/ — attention kernels (jit wrappers staging pallas_call bodies)
     # ----------------------------------------------------------------- #

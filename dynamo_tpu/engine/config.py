@@ -21,26 +21,25 @@ class EngineConfig:
     # fused decode: K steps per dispatch (one host read per K*B tokens);
     # speculated tokens past a stop condition are discarded (bounded waste)
     decode_block_steps: int = 8
-    # KV-write strategy inside the fused block (measured on v5e, llama3-3b
-    # B=32 K=16):
+    # KV-write strategy inside the fused block:
     #   "scatter": per-step XLA scatter into the pool carried through the
-    #     scan. Fastest at small pools (303 ms/block @ 392 pages) but the
-    #     scatter materializes pool-sized copies — 941 ms @ 1024 pages.
+    #     scan. The chip-proven path (rounds r1-r3; chip_smoke.py, PR 21).
+    #     Compiled for a v5e at llama3-3b widths: 0.38 GiB of temporaries
+    #     beside a 1,008-page pool.
     #   "local": pool stays READ-ONLY inside the scan; new KV accumulates
     #     in a [K]-entry buffer merged by the fused pallas kernel
     #     (ops/pallas_paged_attention._decode_local_kernel) and is written
-    #     once per block. Needs decode_block_unroll > 1: under a rolled
-    #     lax.scan XLA re-copies closed-over HBM arrays every iteration
-    #     (~4 ms/GB/step). Near pool-size-invariant; compile time grows
-    #     with the unroll factor.
-    # DEFAULT = None = auto by platform (engine init): "local" on TPU —
-    # production pools are auto-sized (num_pages=0 → thousands of pages on
-    # a 16G v5e), where scatter's pool copies dominate (941 ms/block
-    # @ 1024 pages vs ~300 projected local, r3 measurement; the r4
-    # sweep's local arms finished ~25% faster by wall-clock before its
-    # metric read crashed) — and "scatter" on CPU, where the pathology
-    # doesn't exist and the unrolled local scan just multiplies compile
-    # time. bench_sweep.py re-decides empirically per chip.
+    #     once per block. Needs decode_block_unroll > 1. On the chip XLA
+    #     hoists the kernel's relayout of the loop-invariant pool out of
+    #     the scan — a second pool's worth of HBM (3.71 GiB of temporaries
+    #     at 504 pages, compiled for a v5e): with the pool auto-sized to
+    #     fill the chip its first decode block failed in the compiler
+    #     (RESOURCE_EXHAUSTED, PR 21), so the auto-sizer now halves a
+    #     "local" pool instead.
+    # DEFAULT = None = "scatter" on every platform. Which is faster at a
+    # deployment-sized pool has not been measured (ROADMAP A4): the chip
+    # cost both modes share is the decode kernel's per-layer relayout of
+    # the pool, per step under scatter and per block under local.
     decode_pool_mode: Optional[str] = None
     decode_block_unroll: int = 0  # 0 = auto: 4 under local, 1 under scatter
     # batched prefill: token budget per dispatch; lanes = budget // bucket
@@ -52,10 +51,12 @@ class EngineConfig:
     quantize: Optional[str] = None
     # quantized KV cache ("none" | "int8" | "int4"; None = resolve from
     # DYN_KV_QUANT, default none): pages quantize on write with
-    # per-page-per-head scales and dequantize inside the attention
-    # kernels' VMEM window (ops/kv_quant.py, docs/kvbm.md). int8 halves /
-    # int4 quarters KV bytes per page, so the auto-sized pool holds ~2x/4x
-    # the pages — roughly 2x resident sessions at fixed HBM — and every
+    # per-page-per-head scales and dequantize in the XLA gather path (the
+    # in-kernel dequant does not compile for a TPU yet: the dispatch gate
+    # routes quantized pools to XLA; ops/kv_quant.py, docs/kvbm.md). int8
+    # halves / int4 quarters KV bytes per page, so the auto-sized pool
+    # holds ~2x/4x the pages — roughly 2x resident sessions at fixed HBM —
+    # and every
     # KVBM tier/peer-fabric/disagg transfer shrinks the same way. "none"
     # is the seed's exact fp path (byte-identical streams). Requires
     # tp_size == pp_size == sp_size == 1 (scale sharding is the

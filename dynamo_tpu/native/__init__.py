@@ -1,10 +1,11 @@
 """ctypes binding for the native core (csrc/dynamo_core.cpp).
 
-Loads csrc/libdynamo_core.so, building it on first use if the toolchain is
-available. Every entry point has a pure-Python twin (llm/tokens.py,
-llm/kv_router/indexer.py); callers use `native_available()` / the
-`NativeRadixTree` class and fall back transparently. Disable with
-DYN_NATIVE=0.
+Builds csrc/libdynamo_core.so with `make` on first use (tracked sources
+only; the .so is gitignored) and loads it. Every entry point has a
+pure-Python twin (llm/tokens.py, llm/kv_router/indexer.py); callers use
+`native_available()` / the `NativeRadixTree` class and fall back when the
+build or load fails — which one is active is logged at WARNING, once per
+process. Disable with DYN_NATIVE=0.
 
 Reference parity: lib/llm/src/tokens.rs compute_hash_v2 :36 and
 kv_router/indexer.rs RadixTree :224 (Rust there; C++ + ctypes here).
@@ -37,9 +38,12 @@ def _load() -> Optional[ctypes.CDLL]:
     from ..runtime.config import env_bool
 
     if not env_bool("DYN_NATIVE", True):
+        logger.warning("native core disabled (DYN_NATIVE=0): pure-Python twin in use")
         return None
     # always invoke make: a no-op when the .so is fresh, a rebuild when
-    # csrc/ changed (a stale gitignored .so must not silently win)
+    # csrc/ changed. The .so is gitignored and built only from the tracked
+    # csrc/Makefile + csrc/dynamo_core.cpp; one that make could not
+    # produce from them is never loaded (a stale binary must not win).
     try:
         subprocess.run(
             ["make", "-C", _CSRC],
@@ -47,15 +51,13 @@ def _load() -> Optional[ctypes.CDLL]:
             capture_output=True,
             timeout=120,
         )
-    except Exception as e:  # noqa: BLE001 — fall back to pure Python
-        logger.info("native core build failed (%s); using pure Python", e)
-        if not os.path.exists(_SO):
-            return None
-    try:
         lib = ctypes.CDLL(_SO)
-    except OSError as e:
-        logger.info("native core load failed (%s); using pure Python", e)
+    except (OSError, subprocess.SubprocessError) as e:
+        logger.warning(
+            "native core unavailable (%s): pure-Python twin in use", e
+        )
         return None
+    logger.warning("native core loaded: %s", _SO)
     u64, i64, p = ctypes.c_uint64, ctypes.c_int64, ctypes.c_void_p
     u64p = ctypes.POINTER(ctypes.c_uint64)
     i64p = ctypes.POINTER(ctypes.c_int64)

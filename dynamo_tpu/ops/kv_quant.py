@@ -148,12 +148,17 @@ def alloc_kv_store(num_layers: int, num_pages: int, page_size: int,
     before first use)."""
     bits = kv_quant_bits(mode)
     if bits == 0:
-        arr = jnp.zeros(
-            (num_layers, num_pages, page_size, num_kv_heads, head_dim), dtype
+        shape = (num_layers, num_pages, page_size, num_kv_heads, head_dim)
+        if sharding is None:
+            return jnp.zeros(shape, dtype)
+        # born sharded: every device fills its own shard. Building the
+        # whole pool on one device and moving it (the first four-chip run:
+        # 21.78 G asked of a 16 G chip) cannot hold a pool sized to fill
+        # the mesh.
+        zero_pool = jax.jit(
+            lambda: jnp.zeros(shape, dtype), out_shardings=sharding
         )
-        if sharding is not None:
-            arr = jax.device_put(arr, sharding)
-        return arr
+        return zero_pool()
     if sharding is not None:
         raise ValueError(
             "kv_quant with a sharded KV pool is unsupported (per-head scale "

@@ -12,7 +12,9 @@ Layouts:
 
 from __future__ import annotations
 
-from functools import partial
+import contextlib
+import contextvars
+import os
 from typing import Optional
 
 import jax
@@ -52,13 +54,9 @@ def prefill_attention(
     (the engine bounds the table length to the context bucket, so the
     gather is context-sized, not max-context-sized).
     """
-    if (
-        total_len is not None and _pallas_eligible(q.shape[-1])
-        and not is_quant_kv(kv_k_layer)
+    if total_len is not None and _pallas_eligible(
+        q.shape[-1], is_quant_kv(kv_k_layer)
     ):
-        # quantized pages ride the XLA reference here: prefill is
-        # compute-bound, and the in-kernel dequant investment went to the
-        # ragged + decode kernels (the HBM-bound paths)
         from .pallas_prefill_attention import paged_prefill_attention_pallas
 
         return paged_prefill_attention_pallas(
@@ -101,7 +99,7 @@ def prefill_attention_batched(
     context pages; elsewhere the XLA path gathers each (engine-bounded)
     page table.
     """
-    if _pallas_eligible(q.shape[-1]) and not is_quant_kv(kv_k_layer):
+    if _pallas_eligible(q.shape[-1], is_quant_kv(kv_k_layer)):
         from .pallas_prefill_attention import paged_prefill_attention_pallas_batched
 
         return paged_prefill_attention_pallas_batched(
@@ -127,33 +125,83 @@ def prefill_attention_batched(
     return out.reshape(B, T, H, D)
 
 
-def _use_pallas_decode() -> bool:
-    import os
+# Set by an engine around its model calls (engine._ScopedModel): False
+# when the engine runs over a multi-device mesh. None = no engine scope
+# (bare op calls, profiler): kernels wherever the backend is a TPU.
+_MESH_ALLOWS_KERNELS: contextvars.ContextVar = contextvars.ContextVar(
+    "attention_mesh_allows_kernels", default=None
+)
 
+
+def mesh_allows_kernels(mesh) -> bool:
+    """The mesh half of the gate. No mesh or a one-device mesh: the
+    operands live whole on one chip and the Pallas kernels apply. A
+    multi-device mesh (tp/ep/pp/sp > 1) shards the KV cache over heads or
+    pages and a bare pallas_call has no partitioning rule, so those
+    engines take the XLA path — by this rule, not by device count: a
+    one-chip engine in a process that sees four devices keeps its kernels.
+    (Kernels under shard_map are ROADMAP A5.)"""
+    return mesh is None or mesh.devices.size == 1
+
+
+@contextlib.contextmanager
+def attention_scope(allows_kernels: bool):
+    """Trace-time scope: every attention op called inside resolves its
+    implementation for an engine whose mesh `allows_kernels`."""
+    tok = _MESH_ALLOWS_KERNELS.set(bool(allows_kernels))
+    try:
+        yield
+    finally:
+        _MESH_ALLOWS_KERNELS.reset(tok)
+
+
+def _use_pallas_decode() -> bool:
     mode = os.environ.get("DYNAMO_TPU_PAGED_ATTN", "auto")
     if mode == "pallas":
         return True
     if mode == "xla":
         return False
-    try:
-        # auto: single-chip TPU only. Under a tp>1 GSPMD mesh the KV cache is
-        # sharded over heads and a bare pallas_call has no partitioning rule —
-        # the XLA path partitions cleanly there. (shard_map-wrapped kernel is
-        # the multi-chip follow-up.)
-        return jax.default_backend() == "tpu" and jax.device_count() == 1
-    except Exception:
+    if mode != "auto":
+        raise ValueError(
+            f"DYNAMO_TPU_PAGED_ATTN={mode!r}: expected auto, pallas or xla"
+        )
+    if _MESH_ALLOWS_KERNELS.get() is False:
         return False
+    # a backend that cannot be asked is an error, never "use the reference"
+    return jax.default_backend() == "tpu"
 
 
-def _pallas_eligible(lane_dim: int) -> bool:
+def _pallas_eligible(lane_dim: int, quantized: bool = False) -> bool:
     """THE Pallas dispatch gate, shared by every attention op in this
-    module: the DYNAMO_TPU_PAGED_ATTN env/platform knob (auto = single-chip
-    TPU) plus the Mosaic 128-lane DMA alignment on the kernel's lane
-    dimension. `lane_dim` is whatever the kernel's page DMA slices —
-    head_dim for the per-head-column prefill/ragged kernels, KH*D for the
-    whole-page decode kernels; smaller (tiny/test) models fall back to the
-    bounded XLA reference paths."""
-    return lane_dim % 128 == 0 and _use_pallas_decode()
+    module. Kernels run when all of these hold:
+      * DYNAMO_TPU_PAGED_ATTN allows it (auto = the backend is a TPU and
+        the calling engine's mesh is one device, see mesh_allows_kernels);
+      * the kernel's lane dimension is 128-aligned (Mosaic DMA). `lane_dim`
+        is whatever the kernel's page DMA slices — head_dim for the
+        per-head-column prefill/ragged kernels, KH*D for the whole-page
+        decode kernels; smaller (tiny/test) models take the bounded XLA
+        reference paths;
+      * the pool is not quantized. The in-kernel int8/int4 dequant has
+        never compiled for a TPU (per-page scales overflow scalar-prefetch
+        SMEM at real pool sizes; the int4 unpack shifts i8 vectors, which
+        Mosaic cannot legalize — tests/test_tpu_compile.py pins both), so
+        quantized pools take the XLA gather+dequant path on every op until
+        it does (ROADMAP A2/A9)."""
+    return lane_dim % 128 == 0 and not quantized and _use_pallas_decode()
+
+
+def resolved_attention(head_dim: int, kv_heads: int, quantized: bool) -> dict:
+    """Which implementation each attention op resolves to for a model of
+    these widths under the current scope: what the engine logs at start
+    and publishes in stats()."""
+    def name(lane_dim):
+        return "pallas" if _pallas_eligible(lane_dim, quantized) else "xla"
+
+    return {
+        "decode": name(kv_heads * head_dim),
+        "prefill": name(head_dim),
+        "ragged": name(head_dim),
+    }
 
 
 def paged_attention_decode_mixed(
@@ -184,14 +232,13 @@ def paged_attention_decode_mixed(
     G = H // KH
     K = loc_k.shape[1]
     scale = 1.0 / jnp.sqrt(jnp.asarray(D, jnp.float32))
-    if _pallas_eligible(KH * D_):
+    if _pallas_eligible(KH * D_, is_quant_kv(kv_k_layer)):
         # pool chunks AND the local buffer flash-merge inside ONE kernel
         # launch — an XLA-level lse combine costs ~8 extra op launches per
         # layer-step, which dominates a 28-layer x 16-step fused block.
-        # Quantized pools dequantize inside the VMEM window (the scales
-        # ride scalar prefetch beside the page tables); the block-local
-        # buffer stays full precision — quantization happens on POOL
-        # writes only (the once-per-block carry patch).
+        # The block-local buffer stays full precision under a quantized
+        # pool — quantization happens on POOL writes only (the
+        # once-per-block carry patch).
         from .pallas_paged_attention import paged_attention_decode_pallas_local
 
         return paged_attention_decode_pallas_local(
@@ -238,7 +285,7 @@ def paged_attention_decode(
     # the decode kernel's page window has lane dim KH*D (whole-page
     # copies), so that is what must be 128-aligned here (int4 packs along
     # the page_size/sublane axis, so the lane dim is unchanged)
-    if _pallas_eligible(KH_ * D_):
+    if _pallas_eligible(KH_ * D_, is_quant_kv(kv_k_layer)):
         from .pallas_paged_attention import paged_attention_decode_pallas
 
         return paged_attention_decode_pallas(
@@ -335,7 +382,7 @@ def ragged_attention(
     aligned to `ragged_tile_q(q.dtype)` — the engine's mixed packer aligns
     exactly when this gate says the kernel will run
     (engine/engine.py:_dispatch_mixed)."""
-    if _pallas_eligible(q.shape[-1]):
+    if _pallas_eligible(q.shape[-1], is_quant_kv(kv_k_layer)):
         from .pallas_ragged_attention import ragged_paged_attention_pallas
 
         return ragged_paged_attention_pallas(

@@ -358,16 +358,12 @@ ENV_REGISTRY: tuple = (
            "for (the gpu_memory_utilization role).",
            "engine/engine.py"),
     EnvVar("DYN_HBM_BYTES", "int", None,
-           "Device memory override in bytes for platforms without "
-           "memory_stats (CPU, tunneled runtimes).",
+           "Device memory size in bytes for a device that reports no "
+           "memory_stats; an accelerator without either is an error.",
            "engine/engine.py"),
     EnvVar("DYN_HBM_RESERVE_MB", "float", "512",
            "Memory held back for compile/activation workspace the "
            "post-weights snapshot cannot see.",
-           "engine/engine.py"),
-    EnvVar("DYN_WORKERS_PER_DEVICE", "int", "1",
-           "Split the free KV pool between co-located workers sharing "
-           "one chip (single-chip disagg).",
            "engine/engine.py"),
     # -- workers / models / native ------------------------------------- #
     EnvVar("DYN_WORKER_INDEX", "int", None,
@@ -382,13 +378,12 @@ ENV_REGISTRY: tuple = (
            "Set to 0 to disable the optional native (C) extension and "
            "force the pure-Python paths.",
            "native/__init__.py"),
-    EnvVar("DYNAMO_TPU_COMPILE_CACHE", "path", "~/.cache/dynamo_tpu_xla",
-           "Persistent XLA compilation-cache directory; 'off' disables.",
-           "engine/engine.py"),
     EnvVar("DYNAMO_TPU_PAGED_ATTN", "enum", "auto",
            "Paged-attention kernel selection: auto / pallas / xla "
            "reference. One gate (`_pallas_eligible`) covers the prefill, "
-           "decode, and ragged mixed-step kernels.",
+           "decode, and ragged mixed-step kernels; auto = kernels on a TPU "
+           "for an engine on one device, XLA for a multi-device mesh; "
+           "quantized KV pools always take XLA.",
            "ops/paged_attention.py"),
     EnvVar("DYN_MIXED_DISPATCH", "bool", "1",
            "Ragged unified mixed dispatch: fuse the step's prefill chunks "

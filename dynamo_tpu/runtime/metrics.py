@@ -81,6 +81,17 @@ METRICS = {
     "request_total_slots": {"kind": "gauge", "layer": "engine", "unit": "slots", "help": "Configured max concurrent sequences.", "wire": True, "export": True},
     "kv_quant": {"kind": "info", "layer": "engine", "help": "KV cache quantization format (bf16/int8/int4)."},
     "kv_pool_bytes": {"kind": "gauge", "layer": "engine", "unit": "bytes", "help": "Resident KV pool bytes including scales.", "export": True},
+    # bring-up surface: what the engine runs on and which paths it resolved
+    # (chip_smoke.py reads these off the worker metrics topic)
+    "device": {"kind": "info", "layer": "engine", "help": "Device as JAX reports it (platform, device_kind, device_count) plus jax/jaxlib/libtpu versions."},
+    "attention_impl": {"kind": "info", "layer": "engine", "help": "Implementation (pallas/xla) the decode, prefill and ragged attention ops resolved to."},
+    "decode_pool_mode": {"kind": "info", "layer": "engine", "help": "Resolved KV-write strategy of the fused decode block (local/scatter)."},
+    "native_core": {"kind": "info", "layer": "engine", "help": "True when the C++ core (csrc/) is loaded, False on the pure-Python twin."},
+    "device_memory": {"kind": "info", "layer": "engine", "help": "Per local device: bytes_limit, bytes_in_use, peak_bytes_in_use from memory_stats()."},
+    "weight_bytes_per_device": {"kind": "info", "layer": "engine", "help": "Model weight bytes resident on each local device."},
+    "kv_bytes_per_device": {"kind": "info", "layer": "engine", "help": "KV pool bytes resident on each local device."},
+    "warmup_s": {"kind": "gauge", "layer": "engine", "unit": "seconds", "help": "Wall-clock of the boot warmup (compiles included)."},
+    "warmup_compiles": {"kind": "gauge", "layer": "engine", "unit": "programs", "help": "XLA executables across staged surfaces when warmup finished."},
     "kv_format_mismatches": {"kind": "counter", "layer": "engine", "help": "Typed mixed-precision KV transfer rejections.", "export": True},
     KV_ACTIVE_BLOCKS: {"kind": "gauge", "layer": "engine", "unit": "blocks", "help": "KV blocks referenced by live sequences.", "wire": True, "export": True},
     KV_TOTAL_BLOCKS: {"kind": "gauge", "layer": "engine", "unit": "blocks", "help": "Total KV blocks in the device pool.", "wire": True, "export": True},

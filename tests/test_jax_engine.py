@@ -651,7 +651,7 @@ def test_moe_family_greedy_parity():
             max_prefill_chunk=16,
         )
         eng = JaxEngine(cfg, model_config=mcfg, params=mparams)
-        assert eng._model is moe
+        assert eng._model._module is moe
         req = PreprocessedRequest(
             token_ids=prompt,
             stop_conditions={"max_tokens": n_steps},

@@ -1,0 +1,271 @@
+"""Ask the TPU compiler, before any chip run: do the main-path kernels
+compile for a v5e at llama3-3b widths?
+
+Interpret-mode tests (test_pallas_attention.py, test_ragged_attention.py)
+check the kernels' arithmetic; they cannot see what Mosaic refuses (SMEM
+budgets, unaligned slices, ops it cannot legalize) or what does not fit the
+device. Here the installed TPU compiler compiles for a chip that is
+DESCRIBED, not attached: nothing runs, so nothing here is a chip result.
+
+The topology is described inside a module-scoped fixture — never at import,
+in a skipif or in conftest.py — and every compile happens in this test
+process: only one process may hold the TPU library, so under xdist exactly
+the worker that is handed this file loads it.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from dynamo_tpu.models import llama
+from dynamo_tpu.ops import paged_attention as ops
+from dynamo_tpu.ops.kv_quant import QuantKV, alloc_kv_store
+
+# llama3-3b attention widths at the worker's default serving shape
+H, KH, D = 24, 8, 128
+PAGE, B, TABLE, POOL = 64, 64, 128, 1024
+HBM_BYTES = 16 * 2**30  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this install
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip; keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def tpu_gate(monkeypatch):
+    """Steer the dispatch gate the way a one-chip TPU engine would see it:
+    the process here is on the CPU backend, the program is compiled for the
+    described chip."""
+    monkeypatch.delenv("DYNAMO_TPU_PAGED_ATTN", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _shapes(one_chip):
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    return sds
+
+
+def _pool(sds, mode="none"):
+    """Per-layer K (or V) pool operand: fp array or QuantKV of shapes."""
+    if mode == "none":
+        return sds((POOL, PAGE, KH, D), jnp.bfloat16)
+    bits = {"int8": 8, "int4": 4}[mode]
+    rows = PAGE // 2 if bits == 4 else PAGE
+    return QuantKV(
+        sds((POOL, rows, KH, D), jnp.int8), sds((POOL, KH), jnp.float32),
+        bits, PAGE,
+    )
+
+
+def _op_cases(sds, mode):
+    """(name, fn, args) for the four serving attention ops through the
+    dispatch gate, with a `mode` KV pool."""
+    k, v = _pool(sds, mode), _pool(sds, mode)
+    i32 = jnp.int32
+    q1 = sds((B, H, D), jnp.bfloat16)
+    tables = sds((B, TABLE), i32)
+    lens = sds((B,), i32)
+    K = 8
+    loc = sds((B, K, KH, D), jnp.bfloat16)
+    T = 128
+    Bp = 8
+    N = 512
+    R = 128
+    return {
+        "decode": (ops.paged_attention_decode, (q1, k, v, tables, lens)),
+        "decode_local": (
+            ops.paged_attention_decode_mixed,
+            (q1, k, v, tables, lens, loc, loc, sds((), i32)),
+        ),
+        "prefill_batched": (
+            ops.prefill_attention_batched,
+            (sds((Bp, T, H, D), jnp.bfloat16), k, v, sds((Bp, T), i32),
+             sds((Bp, TABLE), i32), sds((Bp,), i32), sds((Bp,), i32)),
+        ),
+        "ragged": (
+            ops.ragged_attention,
+            (sds((N, H, D), jnp.bfloat16), k, v, sds((R, TABLE), i32),
+             sds((R,), i32), sds((R,), i32), sds((R,), i32)),
+        ),
+    }
+
+
+OPS = ("decode", "decode_local", "prefill_batched", "ragged")
+
+
+@pytest.mark.parametrize("op", OPS)
+def test_fp_kernels_compile_for_v5e(op, one_chip, no_persistent_cache, tpu_gate):
+    fn, args = _op_cases(_shapes(one_chip), "none")[op]
+    compiled = _compile(fn, *args)
+    assert "tpu_custom_call" in compiled.as_text(), (
+        f"{op}: the gate did not put the Pallas kernel into the program"
+    )
+
+
+@pytest.mark.parametrize("mode", ("int8", "int4"))
+@pytest.mark.parametrize("op", OPS)
+def test_quantized_kv_routes_to_xla_and_compiles(
+    op, mode, one_chip, no_persistent_cache, tpu_gate
+):
+    """The decision for --kv-quant on a TPU: the one gate routes quantized
+    pools to the XLA gather+dequant path on every op, and that path
+    compiles at a real pool size (the in-kernel dequant does not: next
+    test)."""
+    fn, args = _op_cases(_shapes(one_chip), mode)[op]
+    assert not ops._pallas_eligible(128, quantized=True)
+    compiled = _compile(fn, *args)
+    assert "tpu_custom_call" not in compiled.as_text()
+
+
+@pytest.mark.parametrize("mode", ("int8", "int4"))
+def test_in_kernel_dequant_is_still_refused(mode, one_chip, no_persistent_cache):
+    """Why the gate rule exists. When the TPU compiler starts accepting the
+    quantized kernels this fails: then drop the `quantized` clause of
+    ops/paged_attention._pallas_eligible (ROADMAP A2/A9)."""
+    from dynamo_tpu.ops.pallas_ragged_attention import (
+        ragged_paged_attention_pallas,
+    )
+
+    sds = _shapes(one_chip)
+    i32 = jnp.int32
+    args = (
+        sds((512, H, D), jnp.bfloat16), _pool(sds, mode), _pool(sds, mode),
+        sds((128, TABLE), i32), sds((128,), i32), sds((128,), i32),
+        sds((128,), i32),
+    )
+    with pytest.raises(Exception, match="smem|legalize|Mosaic"):
+        _compile(ragged_paged_attention_pallas, *args)
+
+
+def test_full_depth_decode_step_fits_one_chip(
+    one_chip, no_persistent_cache, tpu_gate
+):
+    """The whole llama3-3b decode step — 28 layers, bf16 weights, a
+    1,024-page pool — with the decode kernel inside, within 16 GiB."""
+    cfg = llama.LlamaConfig.llama3_2_3b()
+    sds = _shapes(one_chip)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
+
+    params = on_chip(jax.eval_shape(
+        functools.partial(llama.init_params, cfg), jax.random.PRNGKey(0)
+    ))
+    kv = on_chip(jax.eval_shape(lambda: alloc_kv_store(
+        cfg.num_layers, POOL + 1, PAGE, cfg.num_kv_heads, cfg.head_dim,
+        cfg.dtype, "none",
+    )))
+    i32 = jnp.int32
+
+    def step(params, tokens, positions, kv_k, kv_v, tables, seq_lens):
+        return llama.decode_forward(
+            params, cfg, tokens, positions, kv_k, kv_v, tables, seq_lens
+        )
+
+    compiled = jax.jit(step, donate_argnums=(3, 4)).lower(
+        params, sds((B,), i32), sds((B,), i32), kv, kv,
+        sds((B, TABLE), i32), sds((B,), i32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    need = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert need < HBM_BYTES, f"decode step needs {need / 2**30:.2f} GiB"
+
+
+def test_tp4_decode_step_shards_over_a_four_chip_mesh(
+    topo, no_persistent_cache, tpu_gate
+):
+    """The --tp-size 4 path, compiled over a Mesh of the four described
+    chips with the worker's own shardings (depth cut to four layers: the
+    sharding rules are per layer). The gate's mesh rule takes XLA
+    attention; each chip gets a quarter of the weights and of the pool, and
+    the compiler put the tp all-reduces in."""
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from dynamo_tpu.parallel.mesh import (
+        LlamaShardings,
+        ParallelConfig,
+        build_mesh,
+    )
+
+    import dataclasses
+
+    cfg = dataclasses.replace(llama.LlamaConfig.llama3_2_3b(), num_layers=4)
+    mesh = build_mesh(ParallelConfig(tp_size=4), devices=list(topo.devices))
+    sh = LlamaShardings(mesh)
+    repl = NamedSharding(mesh, PartitionSpec())
+    assert not ops.mesh_allows_kernels(mesh)
+
+    params = jax.eval_shape(
+        functools.partial(llama.init_params, cfg), jax.random.PRNGKey(0)
+    )
+    specs = sh.param_shardings()
+    specs["lm_head"] = None  # tied embeddings: no separate head leaf
+    params = jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        params, specs,
+    )
+    kv = jax.ShapeDtypeStruct(
+        (cfg.num_layers, POOL + 1, PAGE, cfg.num_kv_heads, cfg.head_dim),
+        cfg.dtype, sharding=sh.kv_sharding(),
+    )
+    i32 = jnp.int32
+
+    def r(shape):
+        return jax.ShapeDtypeStruct(shape, i32, sharding=repl)
+
+    def step(params, tokens, positions, kv_k, kv_v, tables, seq_lens):
+        with ops.attention_scope(ops.mesh_allows_kernels(mesh)):
+            return llama.decode_forward(
+                params, cfg, tokens, positions, kv_k, kv_v, tables, seq_lens
+            )
+
+    compiled = jax.jit(step, donate_argnums=(3, 4)).lower(
+        params, r((B,)), r((B,)), kv, kv, r((B, TABLE)), r((B,)),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert "all-reduce" in text
+    total = sum(
+        int(np.prod(x.shape)) * x.dtype.itemsize
+        for x in jax.tree.leaves((params, kv, kv))
+    )
+    per_device = compiled.memory_analysis().argument_size_in_bytes
+    assert 0.24 < per_device / total < 0.27, per_device / total
