@@ -1,0 +1,301 @@
+"""The load generator and the other clients of the served path: streaming
+`/v1/completions` over HTTP, the worker's stats off the metrics topic, and
+greedy re-sends with log-probabilities over the request plane.
+
+One process, one asyncio loop, no JAX. SSE handling, `read_stats` and the
+request-plane client are copied from `chip_smoke.py` (proven on the chip,
+PR 21); times are taken from when a request was DUE, not from when it was
+sent, so a stalled generator cannot hide queueing.
+"""
+
+from __future__ import annotations
+
+import asyncio
+import json
+import time
+from typing import Callable, List, Optional
+
+from traffic import Request
+
+PAGE_SIZE = 64  # the worker's default --page-size
+
+
+def completion_body(req: Request, model: str, temperature: float) -> dict:
+    return {
+        "model": model,
+        "prompt": req.prompt,
+        "max_tokens": req.max_tokens,
+        "temperature": temperature,
+        "stream": True,
+        "stream_options": {"include_usage": True},
+        # random weights may emit EOS at once: exact lengths are the point
+        "nvext": {"ignore_eos": True},
+    }
+
+
+async def send(session, base: str, model: str, temperature: float,
+               req: Request) -> Request:
+    """One streamed completion. Fills the request's times (time.monotonic),
+    frame and token counts; never raises for a fault of the server: a failed
+    request is a result."""
+    req.t_send = time.monotonic()
+    try:
+        async with session.post(
+            base + "/v1/completions",
+            json=completion_body(req, model, temperature),
+        ) as resp:
+            if resp.status != 200:
+                req.error = f"HTTP {resp.status}: {(await resp.text())[:200]}"
+                return req
+            done = False
+            async for raw in resp.content:
+                line = raw.decode("utf-8").rstrip("\r\n")
+                if not line.startswith("data: "):
+                    continue  # blank separators and ": event" comments
+                payload = line[len("data: "):]
+                if payload == "[DONE]":
+                    done = True
+                    continue
+                chunk = json.loads(payload)
+                if chunk.get("error"):
+                    req.error = str(chunk["error"])[:200]
+                    continue
+                if chunk.get("usage"):
+                    req.tokens = chunk["usage"].get("completion_tokens", 0)
+                for ch in chunk.get("choices") or []:
+                    if ch.get("finish_reason"):
+                        req.extra["finish_reason"] = ch["finish_reason"]
+                        continue
+                    now = time.monotonic()
+                    if req.t_first is None:
+                        req.t_first = now
+                    req.t_last = now
+                    req.frames += 1
+                    # when each frame came and how much it carried: the byte
+                    # tokenizer decodes a token to one character (all but
+                    # the 259 lowest ids of the vocabulary), and `usage`
+                    # gives the exact total at the end (metrics.py)
+                    req.frame_at.append(now)
+                    req.frame_chars.append(len(ch.get("text") or ""))
+            req.ok = done and req.error is None and req.t_first is not None
+            if req.error is None and not req.ok:
+                req.error = ("no frame carried text" if done
+                             else "stream ended without [DONE]")
+    except asyncio.CancelledError:
+        req.error = "unfinished at the drain deadline"
+        raise
+    except Exception as e:  # noqa: BLE001 — any fault of a request is a result
+        req.error = f"{type(e).__name__}: {e}"[:200]
+    finally:
+        req.t_end = time.monotonic()
+    return req
+
+
+class Load:
+    """Offers a cell's traffic and keeps every request it sent."""
+
+    def __init__(self, session, base: str, model: str, temperature: float):
+        self.session, self.base, self.model = session, base, model
+        self.temperature = temperature
+        self.sent: List[Request] = []
+        self.tasks: List[asyncio.Task] = []
+        self._clients: List[asyncio.Task] = []
+        self._stop = False
+        self._gate: Optional[dict] = None
+        self.phase = "warm"
+
+    def _launch(self, req: Request, t_due: float) -> None:
+        req.t_due = t_due
+        self.sent.append(req)
+        self.tasks.append(asyncio.create_task(self._run(req)))
+
+    async def _run(self, req: Request) -> None:
+        await send(self.session, self.base, self.model, self.temperature, req)
+        nxt = req.next_turn
+        if nxt is not None and not self._stop:
+            # a session's next turn: due `think_s` after this one ended
+            t_due = req.t_end + req.think_s
+            await asyncio.sleep(max(t_due - time.monotonic(), 0))
+            if not self._stop:
+                nxt.phase = self.phase
+                self._launch(nxt, t_due)
+
+    async def open_block(self, schedule: List[Request], t0: float) -> None:
+        """Send each request when it is due (t0 + due), whatever the server
+        is doing; returns when the last of the block is sent."""
+        for req in schedule:
+            t_due = t0 + req.due
+            delay = t_due - time.monotonic()
+            if delay > 0:
+                await asyncio.sleep(delay)
+            self._launch(req, t_due)
+
+    def start_clients(self, streams: List[List[Request]]) -> None:
+        """Closed loop: each client sends its next request when the last
+        ends; a request is due the moment the one before it ended."""
+
+        async def client(stream: List[Request]) -> None:
+            i = 0
+            while not self._stop:
+                gate = self._gate
+                if gate is not None and gate["waiting"] < gate["need"]:
+                    gate["waiting"] += 1
+                    if gate["waiting"] == gate["need"]:
+                        gate["open"].set()
+                    await gate["open"].wait()
+                src = stream[i % len(stream)]
+                req = Request(rid=f"{src.rid}r{i // len(stream)}",
+                              prompt=src.prompt, max_tokens=src.max_tokens,
+                              phase=self.phase)
+                req.t_due = time.monotonic()
+                self.sent.append(req)
+                await send(self.session, self.base, self.model,
+                           self.temperature, req)
+                i += 1
+
+        self._clients = [asyncio.create_task(client(s)) for s in streams]
+
+    async def bursts(self, sizes: List[int]) -> None:
+        """Closed loop, warm phase only: for each k, hold the next k clients
+        that finish and release them together, so that k prompts arrive in
+        one step. A closed loop's arrivals bunch like this by chance, rarely
+        for large k, and each bunch size is a token bucket of the engine's
+        mixed step: a program that would otherwise compile inside a window."""
+        for k in sizes:
+            self._gate = {"need": k, "waiting": 0, "open": asyncio.Event()}
+            await self._gate["open"].wait()
+            await asyncio.sleep(0.5)
+        self._gate = None
+
+    def stop_offering(self) -> None:
+        self._stop = True
+
+    async def drain(self, deadline_s: float) -> int:
+        """Wait for every request in flight; what is unfinished at the
+        deadline is cancelled and counts as failed. Returns how many."""
+        pending = [t for t in self.tasks + self._clients if not t.done()]
+        if not pending:
+            return 0
+        _, late = await asyncio.wait(pending, timeout=deadline_s)
+        for t in late:
+            t.cancel()
+        if late:
+            await asyncio.wait(late, timeout=10)
+        return len(late)
+
+    def in_flight(self) -> int:
+        return sum(1 for r in self.sent if r.t_end is None)
+
+
+# ---------------------------------------------------------------------- #
+# the worker's stats, off the metrics topic it already publishes
+# ---------------------------------------------------------------------- #
+
+
+class StatsWatch:
+    """Keeps the newest `JaxEngine.stats()` the worker published (every
+    0.25 s, for the router and planner)."""
+
+    def __init__(self, discovery_addr: str, component: str = "backend"):
+        self.addr, self.component = discovery_addr, component
+        self.latest: Optional[dict] = None
+        self.seen = 0
+        self._task: Optional[asyncio.Task] = None
+
+    async def start(self) -> None:
+        from dynamo_tpu.runtime.discovery import DiscoveryClient
+
+        host, port = self.addr.rsplit(":", 1)
+        self._cli = await DiscoveryClient.connect(host, int(port))
+        self._sub = await self._cli.subscribe(f"kv_metrics/dynamo/{self.component}")
+        self._task = asyncio.create_task(self._loop())
+
+    async def _loop(self) -> None:
+        from dynamo_tpu.runtime import codec
+
+        async for payload in self._sub:
+            self.latest = codec.unpack(payload).get("stats", {})
+            self.seen += 1
+
+    async def fresh(self, timeout: float = 15.0) -> dict:
+        """Stats published after this call."""
+        n, t_end = self.seen, time.monotonic() + timeout
+        while self.seen == n:
+            if time.monotonic() > t_end:
+                raise TimeoutError("no stats on the worker's metrics topic")
+            await asyncio.sleep(0.05)
+        return self.latest
+
+    async def close(self) -> None:
+        if self._task:
+            self._task.cancel()
+            await asyncio.gather(self._task, return_exceptions=True)
+            await self._sub.cancel()
+            await self._cli.close()
+
+
+# ---------------------------------------------------------------------- #
+# greedy re-sends over the request plane: token ids and log-probabilities
+# ---------------------------------------------------------------------- #
+
+
+async def resend_greedy(discovery_addr: str, model: str, vocab_size: int,
+                        context_length: int, picks: List[dict],
+                        fail: Callable[[str], Exception]) -> dict:
+    """The picked requests again, at temperature 0, on the worker's generate
+    endpoint, all at once. The preprocessor is the frontend's own, so the
+    wire request is what the frontend sends. Token ids and not text: the
+    byte tokenizer's decode does not round-trip."""
+    from dynamo_tpu.llm.model_card import ModelDeploymentCard
+    from dynamo_tpu.llm.preprocessor import OpenAIPreprocessor
+    from dynamo_tpu.llm.protocols import CompletionRequest
+    from dynamo_tpu.llm.tokenizers import load_tokenizer
+    from dynamo_tpu.runtime import DistributedRuntime, RuntimeConfig
+
+    tok = load_tokenizer(f"byte:{vocab_size}")
+    pre = OpenAIPreprocessor(
+        ModelDeploymentCard(name=model, tokenizer="byte",
+                            kv_cache_block_size=PAGE_SIZE,
+                            context_length=context_length),
+        tok,
+    )
+    cfg = RuntimeConfig()
+    cfg.discovery_endpoint = discovery_addr
+    drt = await DistributedRuntime.create(cfg)
+    try:
+        ep = drt.namespace("dynamo").component("backend").endpoint("generate")
+        client = await ep.client()
+        (instance,) = await client.wait_for_instances(timeout=30)
+
+        async def one(pick: dict) -> dict:
+            body = {
+                "model": model, "prompt": pick["prompt"],
+                "max_tokens": pick["max_tokens"], "temperature": 0,
+                "stream": False, "nvext": {"ignore_eos": True},
+                # the served log-probability of each served token rides the
+                # existing logprobs option
+                "logprobs": 0,
+            }
+            req = pre.preprocess_completion(CompletionRequest(**body))
+            out, lps = [], []
+            stream = await client.direct(req.to_dict(), instance)
+            async for item in stream:
+                if item.get("event") == "error":
+                    raise fail(f"{pick['why']}: {item.get('comment')}")
+                data = item.get("data") or {}
+                out.extend(data.get("token_ids") or [])
+                lps.extend(data.get("log_probs") or [])
+            if not len(out) == len(lps) == pick["max_tokens"]:
+                raise fail(
+                    f"{pick['why']}: {len(out)} token ids and {len(lps)} "
+                    f"logprobs over the request plane, {pick['max_tokens']} asked"
+                )
+            return {"prompt_ids": list(req.token_ids), "served_ids": out,
+                    "served_logprobs": lps, "why": pick["why"]}
+
+        served = await asyncio.wait_for(
+            asyncio.gather(*[one(p) for p in picks]), timeout=180
+        )
+    finally:
+        await drt.close()
+    return {f"{i}.{p['why']}": s for i, (p, s) in enumerate(zip(picks, served))}
