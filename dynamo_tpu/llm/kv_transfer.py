@@ -259,6 +259,7 @@ class KvDataPlaneServer:
         self.max_transfer_time = max_transfer_time
         self.chunk_timeout = chunk_timeout
         self._server: Optional[asyncio.AbstractServer] = None
+        self._connections: set = set()
         self._staged: Dict[str, _Staged] = {}
         self._reaper: Optional[asyncio.Task] = None
         # observability: exact evidence that THIS host's data plane moved
@@ -293,6 +294,10 @@ class KvDataPlaneServer:
             self._unstage(t, ok=False)
         if self._server is not None:
             self._server.close()
+            # close live connections, else wait_closed() sits out every
+            # peer's pooled keep-alive until its chunk_timeout
+            for writer in list(self._connections):
+                writer.close()
             await self._server.wait_closed()
 
     async def register(self, drt):
@@ -413,6 +418,7 @@ class KvDataPlaneServer:
 
     async def _serve(self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter):
         _set_nodelay(writer)
+        self._connections.add(writer)
         try:
             # ranged/kvbm requests are request-response and KEEP the
             # connection: a peer onboarding at admission rate would
@@ -450,6 +456,7 @@ class KvDataPlaneServer:
         except Exception:  # noqa: BLE001 — one bad peer must not kill the server
             logger.exception("kv data plane connection failed")
         finally:
+            self._connections.discard(writer)
             writer.close()
 
     async def _serve_transfer(self, body: bytes, writer: asyncio.StreamWriter):

@@ -163,13 +163,15 @@ async def read_frame(reader: asyncio.StreamReader) -> Optional[Tuple[dict, bytes
 async def write_frame(
     writer: asyncio.StreamWriter, control: dict, payload: bytes = b""
 ):
-    # corked write: hand the transport the three segments in one call
-    # instead of concatenating header+payload into a fresh buffer — on the
-    # token hot path the payload is the large part and must not be copied
+    # corked write: hand the transport the segments in one call instead of
+    # concatenating header+payload into a fresh buffer — on the token hot
+    # path the payload is the large part and must not be copied. An empty
+    # payload (every control frame) is left out: CPython 3.12's selector
+    # transport never pops a zero-length segment off its write buffer, so
+    # the loop spins on sendmsg([b""]) and close() never completes
     header = msgpack.packb(control, use_bin_type=True)
-    writer.writelines(
-        (_HDR.pack(MAGIC, len(header), len(payload)), header, payload)
-    )
+    head = _HDR.pack(MAGIC, len(header), len(payload))
+    writer.writelines((head, header, payload) if payload else (head, header))
     await writer.drain()
 
 

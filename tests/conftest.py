@@ -21,6 +21,8 @@ os.environ.setdefault("JAX_ENABLE_X64", "0")
 os.environ.setdefault("DYN_LEASE_TTL_S", "45")
 
 import asyncio  # noqa: E402
+import signal  # noqa: E402
+import traceback  # noqa: E402
 
 import pytest  # noqa: E402
 
@@ -35,3 +37,62 @@ def pytest_configure(config):
 @pytest.fixture
 def event_loop_policy():
     return asyncio.DefaultEventLoopPolicy()
+
+
+# One clock for every test. A test phase (setup, call, teardown) that is
+# still running after TEST_LIMIT_S fails with the main thread's stack, so a
+# hang costs one test and not an xdist worker until the driver's cut.
+# SIGALRM lands in the main thread, which is where pytest and xdist run
+# tests; the exception is a KeyboardInterrupt so that asyncio re-raises it
+# out of a callback or task instead of logging it, and subprocess.run kills
+# its child. It fires again every tenth of the limit, because what the
+# first one unwinds into (asyncio.run's cleanup, a finally that joins a
+# thread) can block as well.
+TEST_LIMIT_S = 300
+
+
+class _TestClockExpired(KeyboardInterrupt):
+    pass
+
+
+def _clocked(item, phase):
+    stacks = []
+
+    def on_alarm(signum, frame):
+        stacks.append("".join(traceback.format_list(
+            f for f in traceback.extract_stack(frame)
+            if "/_pytest/" not in f.filename and "/pluggy/" not in f.filename
+        )))
+        raise _TestClockExpired()
+
+    previous = signal.signal(signal.SIGALRM, on_alarm)
+    signal.setitimer(signal.ITIMER_REAL, TEST_LIMIT_S, TEST_LIMIT_S / 10)
+    try:
+        result = yield
+    except _TestClockExpired:
+        pass
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, previous)
+    if stacks:  # also when the test swallowed the exception and returned
+        pytest.fail(
+            f"{item.nodeid} {phase} still running after {TEST_LIMIT_S} s; "
+            f"main thread was at:\n{stacks[0]}",
+            pytrace=False,
+        )
+    return result
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_setup(item):
+    return (yield from _clocked(item, "setup"))
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_call(item):
+    return (yield from _clocked(item, "call"))
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_teardown(item):
+    return (yield from _clocked(item, "teardown"))

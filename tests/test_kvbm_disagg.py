@@ -85,12 +85,17 @@ def test_g4_onboard_replaces_remote_prefill(tmp_path):
     decode2 = None
     try:
         _wait_model(base)
-        prompt = "the distributed block mesh reuses offloaded prefixes! " * 3
         # first serve: long fresh prompt -> remote prefill; the prefill
-        # worker commits + write-through-offloads the blocks and announces
+        # worker commits + write-through-offloads the blocks and announces.
+        # The model is up as soon as the decode worker is, which can be
+        # before the prefill worker: a prompt served locally then sits in
+        # the decode worker's prefix cache and never goes remote again, so
+        # every try is a fresh prompt (its first block differs)
         deadline = time.time() + 60
-        text1, remote1 = None, False
+        text1, remote1, tries = None, False, 0
         while time.time() < deadline and not remote1:
+            tries += 1
+            prompt = f"try {tries}: " + "the distributed block mesh reuses offloaded prefixes! " * 3
             text1, remote1 = _generate(base, prompt)
         assert remote1 is True, "remote prefill never engaged"
         # prefill worker's host tier must now hold the prompt's blocks

@@ -138,6 +138,9 @@ class TestHealthCheck:
             server = DiscoveryServer(port=0)
             _, port = await server.start()
             cfg = _drt_config(port)
+            # the wedged canary stream is still in flight at close(): do not
+            # sit out the default 30 s drain for a handler wedged on purpose
+            cfg.graceful_shutdown_timeout = 0.5
 
             healthy_mode = {"on": True}
 

@@ -5,6 +5,7 @@ servers, echo engines, lease-expiry and cancellation behaviors.
 """
 
 import asyncio
+import time
 
 import pytest
 
@@ -30,6 +31,44 @@ def test_codec_roundtrip():
     c2, p2 = decode_frame(frame)
     assert c2 == control
     assert codec.unpack(p2)["text"] == "héllo"
+
+
+@pytest.mark.parametrize("check", ["close_completes", "loop_idles"])
+def test_write_frame_bodyless_frame_leaves_transport_empty(check):
+    """Every control frame has no payload. write_frame must not hand the
+    transport a zero-length segment: CPython 3.12 never pops it off the
+    write buffer, so the loop spins on sendmsg and close() never ends."""
+
+    async def main():
+        got = asyncio.Queue()
+
+        async def handler(reader, writer):
+            await got.put(await codec.read_frame(reader))
+            await reader.read()  # until the peer goes away
+            writer.close()
+
+        server = await asyncio.start_server(handler, "127.0.0.1", 0)
+        port = server.sockets[0].getsockname()[1]
+        _, writer = await asyncio.open_connection("127.0.0.1", port)
+        try:
+            control = {"t": codec.T_DONE, "stream": 1}
+            await codec.write_frame(writer, control)
+            assert await asyncio.wait_for(got.get(), 2) == (control, b"")
+            if check == "close_completes":
+                writer.close()
+                await asyncio.wait_for(writer.wait_closed(), 2)
+            else:
+                # the loop runs in this thread: its CPU time is the spin's
+                # (the process's would count other tests' leftover threads)
+                cpu = time.thread_time()
+                await asyncio.sleep(1.0)
+                assert time.thread_time() - cpu < 0.2
+        finally:
+            writer.transport.abort()  # drops whatever a failure left buffered
+            server.close()
+            await server.wait_closed()
+
+    asyncio.run(main())
 
 
 def test_traceparent():

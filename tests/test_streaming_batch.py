@@ -621,13 +621,17 @@ def test_unknown_payload_encoding_is_typed_error():
 
     async def main():
         async def serve(reader, writer):
-            frame = await _codec.read_frame(reader)
-            control, _ = frame
-            sid = control["stream"]
-            await _codec.write_frame(
-                writer, {"t": "data", "stream": sid, "n": 1, "enc": "zzz"},
-                b"\x00" * 8,
-            )
+            try:
+                frame = await _codec.read_frame(reader)
+                control, _ = frame
+                sid = control["stream"]
+                await _codec.write_frame(
+                    writer, {"t": "data", "stream": sid, "n": 1, "enc": "zzz"},
+                    b"\x00" * 8,
+                )
+                await reader.read()  # until the client goes away
+            finally:
+                writer.close()  # 3.12 wait_closed() waits for open transports
 
         server = await asyncio.start_server(serve, "127.0.0.1", 0)
         port = server.sockets[0].getsockname()[1]
