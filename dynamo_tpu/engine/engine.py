@@ -160,13 +160,11 @@ def _auto_num_pages(params, model_cfg, config: EngineConfig,
     multihost mode the leader's result is broadcast so every process
     allocates identical KV shapes (dispatch replay requires it).
 
-    The "local" decode KV-write strategy keeps the pool read-only inside
-    the fused block, and XLA hoists the decode kernel's per-layer
-    [pages, rows, KH, D] -> [pages, rows, KH*D] relayout of that invariant
-    pool out of the loop: a second pool's worth of temporaries, which the
-    sizing leaves room for. "scatter" relayouts one layer at a time
-    (compiled for a v5e: 0.38 GiB of temporaries beside a 1,008-page
-    pool), so it gets the whole budget.
+    The "local" decode KV-write strategy still sizes for half the
+    budget: that left room for the hoisted relayout of the read-only
+    pool, which went with the lane-dense layout (PR 26: the kernels read
+    the pool where it lies); whether "local" now deserves the whole
+    budget, or goes, is ROADMAP C4's to measure on the chip.
     """
     dev = jax.local_devices()[0]
     util = float(os.environ.get("DYN_HBM_UTILIZATION", "0.85"))
@@ -215,7 +213,7 @@ def _auto_num_pages(params, model_cfg, config: EngineConfig,
         page_bytes_dev = page_bytes // _kv_shard_div(kv_sharding)
         alloc_bytes_dev = page_bytes_dev
         if config.decode_pool_mode == "local":
-            alloc_bytes_dev *= 2  # hoisted flat copy of the read-only pool
+            alloc_bytes_dev *= 2  # see the docstring: C4 decides
         free = int(limit * util) - int(in_use) - reserve
         n = free // alloc_bytes_dev
         logger.info(
@@ -895,8 +893,8 @@ class JaxEngine:
                 from ..ops.kv_quant import kv_write_all_layers
 
                 # the decode carry patch: ONE pool write per block —
-                # quantize-on-write under DYN_KV_QUANT, the seed's fused
-                # scatter otherwise (byte-identical jaxpr)
+                # quantize-on-write under DYN_KV_QUANT, one fused scatter
+                # of lane-dense rows otherwise
                 kv_k = kv_write_all_layers(kv_k, phys, offs, jnp.stack(loc_k))
                 kv_v = kv_write_all_layers(kv_v, phys, offs, jnp.stack(loc_v))
                 return toks, tokens, positions, seq_lens, kv_k, kv_v, rng, pen
@@ -1327,23 +1325,29 @@ class JaxEngine:
         self._patch_lanes = patch_lanes
 
         # disagg KV movement (host-staged; llm/disagg.py wire format).
-        # tree_map covers both store shapes: a plain fp array, or a
-        # QuantKV whose q pages AND per-page scales gather/scatter on the
-        # same `[:, page_ids]` slice — scales travel with their pages
-        # through every tier/wire hop.
+        # ops/kv_quant's accessors cover both store shapes: a plain fp
+        # pool, or a QuantKV whose q pages AND per-page scales
+        # gather/scatter on the same `[:, page_ids]` slice — scales travel
+        # with their pages through every tier/wire hop. Pages cross this
+        # boundary as [L, n, rows, KH, D] (a reshape of the gathered
+        # pages: the pool itself stays lane-dense), which is what KVBM,
+        # the KV data plane and disagg have always carried.
+        from ..ops import kv_quant
+
         @jax.jit
         def extract_pages(kv_k, kv_v, page_ids):
-            ex = lambda a: a[:, page_ids]  # noqa: E731
-            return jax.tree.map(ex, kv_k), jax.tree.map(ex, kv_v)
+            return (
+                kv_quant.extract_pages(kv_k, page_ids, c.num_kv_heads),
+                kv_quant.extract_pages(kv_v, page_ids, c.num_kv_heads),
+            )
 
         self._extract_pages = extract_pages
 
         @partial(jax.jit, donate_argnums=(0, 1))
         def inject_pages(kv_k, kv_v, page_ids, data_k, data_v):
-            inj = lambda a, d: a.at[:, page_ids].set(d)  # noqa: E731
             return (
-                jax.tree.map(inj, kv_k, data_k),
-                jax.tree.map(inj, kv_v, data_v),
+                kv_quant.inject_pages(kv_k, page_ids, data_k),
+                kv_quant.inject_pages(kv_v, page_ids, data_v),
             )
 
         self._inject_pages = inject_pages
@@ -3142,7 +3146,8 @@ class JaxEngine:
 
     def _kv_headwise_shards_ok(self) -> bool:
         """True iff every local KV-pool shard spans the FULL extent on all
-        axes except the kv-head axis (3) — the only layout that
+        axes except the lane axis (3: kv heads x head_dim, sharded in
+        whole-head blocks) — the only layout that
         _local_shard_views/_extract_local_shard (axis-3 concat) and
         _dev_inject_shard (global_shape widened on axis 3 only) can
         reassemble. A pool sharded on layers (pp multihost) or pages
@@ -3151,7 +3156,7 @@ class JaxEngine:
         allgather transfer instead (advisor r3 finding)."""
         shape = self.kv_k.shape
         for s in self.kv_k.addressable_shards:
-            for ax in (0, 1, 2, 4):
+            for ax in (0, 1, 2):
                 sl = s.index[ax]
                 if (sl.start or 0) != 0 or not (
                     sl.stop is None or sl.stop >= shape[ax]
@@ -3161,8 +3166,8 @@ class JaxEngine:
 
     def _local_shard_views(self):
         """This host's KV shard pieces, deduped across replicas and sorted
-        by the sharded (kv-head) axis slice. Single-device arrays — safe to
-        index at host-divergent times (no collectives)."""
+        by the sharded (lane: whole kv heads) axis slice. Single-device
+        arrays — safe to index at host-divergent times (no collectives)."""
         def pick(arr):
             seen = {}
             for s in arr.addressable_shards:
@@ -3183,8 +3188,8 @@ class JaxEngine:
         ks, _ = self._local_shard_views()
         L = ks[0].data.shape[0]
         page = ks[0].data.shape[2]
-        kh_local = sum(s.data.shape[3] for s in ks)
-        d = ks[0].data.shape[4]
+        d = self.model_config.head_dim
+        kh_local = sum(s.data.shape[3] for s in ks) // d
         return [L, page, kh_local, d]
 
     def _extract_local_shard(self, page_ids):
@@ -3197,7 +3202,11 @@ class JaxEngine:
         v_parts = [np.asarray(s.data[:, ids]) for s in vs]
         k = k_parts[0] if len(k_parts) == 1 else np.concatenate(k_parts, axis=3)
         v = v_parts[0] if len(v_parts) == 1 else np.concatenate(v_parts, axis=3)
-        return k, v
+        # the wire carries heads apart, as ever: a view of the gathered rows
+        d = self.model_config.head_dim
+        return (
+            k.reshape(*k.shape[:3], -1, d), v.reshape(*v.shape[:3], -1, d)
+        )
 
     def _dev_inject_shard(self, page_ids, k_local, v_local):
         """SPMD inject where each host supplies ITS OWN shard bytes: build a
@@ -3209,10 +3218,11 @@ class JaxEngine:
             sharding = self._kv_sharding
         else:
             sharding = NamedSharding(self._mesh, PartitionSpec())
-        L, n, page, d = (
-            k_local.shape[0], k_local.shape[1], k_local.shape[2], k_local.shape[4]
-        )
-        global_shape = (L, n, page, self.model_config.num_kv_heads, d)
+        c = self.model_config
+        # wire [L, n, page, KH_local, D] -> this host's lanes of the pool
+        k_local = k_local.reshape(*k_local.shape[:3], -1)
+        v_local = v_local.reshape(*v_local.shape[:3], -1)
+        global_shape = (*k_local.shape[:3], c.num_kv_heads * c.head_dim)
         k_g = jax.make_array_from_process_local_data(sharding, k_local, global_shape)
         v_g = jax.make_array_from_process_local_data(sharding, v_local, global_shape)
         self.kv_k, self.kv_v = self._inject_pages(

@@ -1,8 +1,9 @@
 """Paged KV cache: device arrays + host-side page allocator with prefix reuse.
 
 The TPU analogue of vLLM's paged KV + the reference mocker's KvManager:
-  * device side: kv_k/kv_v [layers, num_pages, page_size, kv_heads, head_dim]
-    (sharded over the tp axis on the kv_heads dim)
+  * device side: kv_k/kv_v [layers, num_pages, page_size, kv_heads*head_dim],
+    lane-dense from allocation on (sharded over the tp axis in whole-head
+    blocks of the last dim); the attention kernels read it where it lies
   * host side: free-list page allocator; pages keyed by chained block hash
     for prefix reuse (same hashes the router indexes, llm/tokens.py), with
     LRU eviction of unreferenced cached pages and KV stored/removed events.
@@ -35,9 +36,10 @@ def alloc_kv_arrays(
     sharding=None,
     kv_quant: str = "none",
 ) -> Tuple[jax.Array, jax.Array]:
-    """Allocate the K and V stores: plain fp arrays for kv_quant="none"
-    (the seed behavior, byte-identical), ops/kv_quant.QuantKV pytrees
-    (packed int8/int4 pages + per-page-per-head f32 scales) otherwise."""
+    """Allocate the K and V stores, each [L, pages, rows, KH*D]: plain fp
+    arrays for kv_quant="none", ops/kv_quant.QuantKV pytrees (packed
+    int8/int4 pages in the same lane-dense shape + per-page-per-head f32
+    scales [L, pages, KH]) otherwise."""
     from ..ops.kv_quant import alloc_kv_store
 
     kv_k = alloc_kv_store(

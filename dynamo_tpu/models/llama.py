@@ -9,8 +9,9 @@ Design notes (TPU-first):
   * all matmuls bf16 on the MXU; accumulation f32 via preferred_element_type
   * static shapes everywhere: prefill takes a fixed [chunk] token block,
     decode takes the full [max_seqs] slot batch with masking
-  * KV cache is paged: [layers, pages, page_size, kv_heads, head_dim]; the
-    engine passes page tables; attention gathers pages (ops/paged_attention)
+  * KV cache is paged and lane-dense: [layers, pages, page_size,
+    kv_heads*head_dim]; the engine passes page tables; attention reads the
+    pool where it lies (ops/paged_attention, ops/kv_quant.KVLayer)
   * tensor parallel: heads and MLP hidden sharded over the "tp" mesh axis
     via NamedSharding on params + cache (parallel/sharding.py); XLA inserts
     the all-reduces (scaling-book recipe), no manual collectives needed
@@ -186,7 +187,7 @@ def prefill_forward(
     config: LlamaConfig,
     tokens: jax.Array,  # [chunk]
     positions: jax.Array,  # [chunk] absolute positions
-    kv_k: jax.Array,  # [L, pages, page_size, kv_heads, head_dim]
+    kv_k: jax.Array,  # [L, pages, page_size, kv_heads*head_dim] (lane-dense)
     kv_v: jax.Array,
     page_table: jax.Array,  # [max_pages] pages of THIS sequence
     context_len: jax.Array,  # scalar: positions[<context_len] are valid history
@@ -250,7 +251,7 @@ def prefill_forward_batched(
     config: LlamaConfig,
     tokens: jax.Array,  # [B, T] one chunk per sequence (padded to bucket)
     positions: jax.Array,  # [B, T] absolute positions (pads -> scratch tail)
-    kv_k: jax.Array,  # [L, pages, page_size, kv_heads, head_dim]
+    kv_k: jax.Array,  # [L, pages, page_size, kv_heads*head_dim] (lane-dense)
     kv_v: jax.Array,
     page_tables: jax.Array,  # [B, max_pages] per-seq tables (ctx-bounded)
     context_lens: jax.Array,  # [B] history length per seq
@@ -328,7 +329,7 @@ def ragged_forward(
     tokens: jax.Array,  # [N] flat packed: prefill chunks + decode singletons
     positions: jax.Array,  # [N] absolute positions (pads -> scratch tail)
     row_ids: jax.Array,  # [N] owning row per flat token
-    kv_k: jax.Array,  # [L, pages, page_size, kv_heads, head_dim]
+    kv_k: jax.Array,  # [L, pages, page_size, kv_heads*head_dim] (lane-dense)
     kv_v: jax.Array,
     page_tables: jax.Array,  # [R, max_pages] per-row tables (ctx-bounded)
     row_starts: jax.Array,  # [R] flat index of each row's token 0
@@ -409,7 +410,7 @@ def prefill_forward_ring(
     params: Dict[str, Any],
     config: LlamaConfig,
     tokens: jax.Array,  # [T] whole prompt (padded to a multiple of sp)
-    kv_k: jax.Array,  # [L, pages, page_size, kv_heads, head_dim]
+    kv_k: jax.Array,  # [L, pages, page_size, kv_heads*head_dim] (lane-dense)
     kv_v: jax.Array,
     page_table: jax.Array,  # [max_pages] this sequence's table
     real_len: jax.Array,  # scalar i32: tokens beyond this are padding
@@ -453,8 +454,8 @@ def prefill_forward_ring(
         v = v.reshape(T, c.num_kv_heads, c.head_dim)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
-        kv_k = kv_k.at[li, phys, offs].set(k)
-        kv_v = kv_v.at[li, phys, offs].set(v)
+        kv_k = kv_write(kv_k, li, phys, offs, k)
+        kv_v = kv_write(kv_v, li, phys, offs, v)
         attn = ring_attention(q, k, v, mesh, axis_name=axis_name, causal=True)
         attn = attn.reshape(T, c.num_heads * c.head_dim)
         x = x + qdot(attn, layer["wo"]).astype(c.dtype)
@@ -476,7 +477,7 @@ def _stage_layers_decode(local_params, local_kv, x, aux, valid, c, mlp_fn):
 
     kv_k_loc, kv_v_loc = local_kv
     positions, tables, seq_lens = aux["positions"], aux["tables"], aux["seq_lens"]
-    page_size = kv_k_loc.shape[2]
+    page_size = kv_page_size(kv_k_loc)
     cos, sin = rope_cos_sin(positions, c.head_dim, c.rope_theta)
     max_positions = tables.shape[1] * page_size
     logical = jnp.minimum(positions // page_size, tables.shape[1] - 1)
@@ -495,9 +496,11 @@ def _stage_layers_decode(local_params, local_kv, x, aux, valid, c, mlp_fn):
         v = v.reshape(-1, c.num_kv_heads, c.head_dim)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
-        kv_k_loc = kv_k_loc.at[li, phys, offs].set(k)
-        kv_v_loc = kv_v_loc.at[li, phys, offs].set(v)
-        attn = paged_attention_decode(q, kv_k_loc[li], kv_v_loc[li], tables, seq_lens)
+        kv_k_loc = kv_write(kv_k_loc, li, phys, offs, k)
+        kv_v_loc = kv_write(kv_v_loc, li, phys, offs, v)
+        attn = paged_attention_decode(
+            q, kv_layer(kv_k_loc, li), kv_layer(kv_v_loc, li), tables, seq_lens
+        )
         attn = attn.reshape(-1, c.num_heads * c.head_dim)
         x = x + qdot(attn, layer["wo"]).astype(c.dtype)
         x = mlp_fn(layer, x, c)
@@ -509,7 +512,7 @@ def decode_forward_pp(
     config: LlamaConfig,
     tokens: jax.Array,  # [B]
     positions: jax.Array,  # [B]
-    kv_k: jax.Array,  # [L, pages, page_size, KH, D] (pp-sharded on L)
+    kv_k: jax.Array,  # [L, pages, page_size, KH*D] (pp-sharded on L)
     kv_v: jax.Array,
     page_tables: jax.Array,  # [B, max_pages]
     seq_lens: jax.Array,  # [B]
@@ -576,7 +579,7 @@ def _stage_layers_prefill(local_params, local_kv, x, aux, valid, c, mlp_fn):
     context_len = aux["context_len"]  # scalar: history before this span
     total_len = aux["total_len"]  # scalar: history + real tokens in span
     real_mask = aux["real_mask"]  # [t] bool: padding -> scratch writes
-    page_size = kv_k_loc.shape[2]
+    page_size = kv_page_size(kv_k_loc)
     cos, sin = rope_cos_sin(positions, c.head_dim, c.rope_theta)
     logical = jnp.minimum(positions // page_size, table.shape[0] - 1)
     phys = jnp.where(valid & real_mask, table[logical], 0)
@@ -593,10 +596,11 @@ def _stage_layers_prefill(local_params, local_kv, x, aux, valid, c, mlp_fn):
         v = v.reshape(-1, c.num_kv_heads, c.head_dim)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
-        kv_k_loc = kv_k_loc.at[li, phys, offs].set(k)
-        kv_v_loc = kv_v_loc.at[li, phys, offs].set(v)
+        kv_k_loc = kv_write(kv_k_loc, li, phys, offs, k)
+        kv_v_loc = kv_write(kv_v_loc, li, phys, offs, v)
         attn = prefill_attention(
-            q, k, v, kv_k_loc[li], kv_v_loc[li], positions, table,
+            q, k, v, kv_layer(kv_k_loc, li), kv_layer(kv_v_loc, li),
+            positions, table,
             context_len, total_len,
         )
         attn = attn.reshape(-1, c.num_heads * c.head_dim)

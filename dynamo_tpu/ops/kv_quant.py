@@ -10,10 +10,19 @@ production shape is RTP-LLM's (PAPERS.md): pages quantized ON WRITE,
 dequantized INSIDE the attention kernel's VMEM window, scales riding the
 scalar-prefetch operands beside the page tables.
 
-Representation — `QuantKV`, a registered pytree replacing the raw
-[L, pages, page_size, KH, D] kv_k/kv_v arrays:
+Pool layout — ONE for every store: lane-dense `[L, pages, rows, KH*D]`,
+heads flattened into the minor (lane) axis from allocation on. Where a
+page lies in HBM is where the attention kernels read it: the Pallas
+wrappers take the WHOLE pool (`memory_space=pl.ANY`) plus the layer index
+as a scalar-prefetch operand and DMA `pool[li, page]`; nothing the size
+of the pool is ever sliced or reshaped. Readers that want heads apart get
+the `[.., KH, D]` view of the PAGES they gathered (`gather_dequant`,
+`extract_pages`), never of the pool.
 
-    q: int8  [L, pages, ps_eff, KH, D]   quantized values; int4 packs two
+Representation — `QuantKV`, a registered pytree replacing the raw
+[L, pages, page_size, KH*D] kv_k/kv_v arrays:
+
+    q: int8  [L, pages, ps_eff, KH*D]    quantized values; int4 packs two
                                          tokens per byte ALONG THE
                                          page_size axis (ps_eff = ps//2),
                                          pairing token o with o + ps/2 so
@@ -25,6 +34,8 @@ Representation — `QuantKV`, a registered pytree replacing the raw
 (bits, page_size) are STATIC pytree aux data: jit specializes per format,
 donation/tree_map/jax.device transfers all work leaf-wise, and
 extract/inject gathers ride the same `[:, page_ids]` slice on both leaves.
+A plain array does not know its head count; callers that need it pass
+`head_dim` (the ops read it off q) or `num_kv_heads` (the engine's config).
 
 Scale discipline (quantize-on-write, `kv_write`):
   * a page's scale is the running max over the amax of every write into
@@ -35,8 +46,8 @@ Scale discipline (quantize-on-write, `kv_write`):
     slot a position can occupy, so any prior content belongs to a dead
     sequence): the stale scale is dropped first, which also zero-scrubs
     the stale ints — page reuse cannot inflate quantization error.
-  * fp mode ("none") is the exact original scatter — jaxprs are identical,
-    so quant off == seed behavior byte-for-byte.
+  * fp mode ("none") is one plain in-place scatter of lane-dense rows:
+    quant off adds nothing to the seed's write path.
 
 Host/wire boundary (`host_pack_pages`/`host_unpack_pages`): a page
 serializes as q-bytes ‖ scale-bytes in one uint8 row `[L, n, PAGE_BYTES]`
@@ -51,7 +62,7 @@ kvbm pull handshake; a mixed-precision fleet fails TYPED
 from __future__ import annotations
 
 import os
-from typing import Any, Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -148,7 +159,7 @@ def alloc_kv_store(num_layers: int, num_pages: int, page_size: int,
     before first use)."""
     bits = kv_quant_bits(mode)
     if bits == 0:
-        shape = (num_layers, num_pages, page_size, num_kv_heads, head_dim)
+        shape = (num_layers, num_pages, page_size, num_kv_heads * head_dim)
         if sharding is None:
             return jnp.zeros(shape, dtype)
         # born sharded: every device fills its own shard. Building the
@@ -166,8 +177,8 @@ def alloc_kv_store(num_layers: int, num_pages: int, page_size: int,
             "DYN_KV_QUANT=none"
         )
     q = jnp.zeros(
-        (num_layers, num_pages, _ps_eff(page_size, bits), num_kv_heads,
-         head_dim),
+        (num_layers, num_pages, _ps_eff(page_size, bits),
+         num_kv_heads * head_dim),
         jnp.int8,
     )
     s = jnp.zeros((num_layers, num_pages, num_kv_heads), jnp.float32)
@@ -182,39 +193,57 @@ def kv_page_size(store) -> int:
     return store.shape[2]
 
 
-def kv_layer(store, li: int):
-    """Per-layer view for the attention ops: kv[li] for fp arrays, a
-    per-layer QuantKV (q [pages, ps_eff, KH, D], s [pages, KH]) else."""
-    if not isinstance(store, QuantKV):
-        return store[li]
-    return QuantKV(store.q[li], store.s[li], store.bits, store.page_size)
+class KVLayer(NamedTuple):
+    """One layer of a KV store as the attention ops are handed it: the
+    WHOLE pool and the layer's index, not a slice. A slice of a multi-GB
+    pool is a copy on the TPU (70% of device time before PR 26); the
+    kernels index `pool[li, page]` in their DMAs and the XLA reference
+    path folds `li` into its page gather."""
+
+    pool: Any  # [L, pages, rows, KH*D] array, or the whole QuantKV
+    li: Any  # python int or i32 scalar
 
 
-def kernel_operands(kv_k_layer, kv_v_layer):
+def kv_layer(store, li) -> KVLayer:
+    """Per-layer operand for the attention ops (see KVLayer)."""
+    return KVLayer(store, li)
+
+
+def layer_dims(layer: KVLayer, head_dim: int) -> Tuple[int, int]:
+    """(page_size, KH) of a per-layer KV operand. The pool is lane-dense,
+    so the head count comes from `head_dim` (q's minor dim at every call
+    site)."""
+    pool = layer.pool
+    lanes = (pool.q if isinstance(pool, QuantKV) else pool).shape[3]
+    assert lanes % head_dim == 0, (lanes, head_dim)
+    return kv_page_size(pool), lanes // head_dim
+
+
+def kernel_operands(kv_k_layer: KVLayer, kv_v_layer: KVLayer, head_dim: int):
     """Destructure per-layer KV operands for the Pallas wrappers — the ONE
-    spelling of the packed-layout contract (pallas_ragged_attention +
-    both decode kernels): returns (k_raw, v_raw, rows, page_size,
-    kv_bits, scale_prefetch) where k_raw/v_raw are the arrays to flatten
-    and DMA ([pages, rows, KH, D]; rows = page_size, or page_size//2
-    int4-packed along the sublane axis), kv_bits selects the in-kernel
-    dequant path (0 = fp), and scale_prefetch is the list of f32 scale
+    spelling of what the kernels are handed (pallas_ragged_attention, the
+    prefill kernel and both decode kernels): returns (k_pool, v_pool, li,
+    KH, rows, page_size, kv_bits, scale_prefetch). k_pool/v_pool are the
+    WHOLE lane-dense pools [L, pages, rows, KH*D] to pass in `pl.ANY` as
+    they lie (rows = page_size, or page_size//2 int4-packed along the
+    sublane axis); li is the [1] i32 layer index for the scalar-prefetch
+    operands (the page DMA is `pool[li, page]`); KH is the lane axis over
+    `head_dim`; kv_bits selects the in-kernel dequant path (0 = fp);
+    scale_prefetch is the list of this layer's f32 [pages, KH] scale
     operands to append to the scalar-prefetch refs (empty for fp)."""
-    if isinstance(kv_k_layer, QuantKV):
+    li = jnp.reshape(jnp.asarray(kv_k_layer.li, jnp.int32), (1,))
+    page_size, KH = layer_dims(kv_k_layer, head_dim)
+    k_pool, v_pool = kv_k_layer.pool, kv_v_layer.pool
+    if isinstance(k_pool, QuantKV):
         return (
-            kv_k_layer.q,
-            kv_v_layer.q,
-            kv_k_layer.q.shape[1],
-            kv_k_layer.page_size,
-            kv_k_layer.bits,
+            k_pool.q, v_pool.q, li, KH, k_pool.q.shape[2], page_size,
+            k_pool.bits,
             [
-                kv_k_layer.s.astype(jnp.float32),
-                kv_v_layer.s.astype(jnp.float32),
+                k_pool.s[kv_k_layer.li].astype(jnp.float32),
+                v_pool.s[kv_v_layer.li].astype(jnp.float32),
             ],
         )
-    return (
-        kv_k_layer, kv_v_layer, kv_k_layer.shape[1], kv_k_layer.shape[1],
-        0, [],
-    )
+    return k_pool, v_pool, li, KH, page_size, page_size, 0, []
 
 
 # ---------------------------------------------------------------------- #
@@ -224,7 +253,8 @@ def kernel_operands(kv_k_layer, kv_v_layer):
 
 def _write_one_layer(q, s, phys, offs, vals, bits: int, page_size: int):
     """Core scatter-write of `vals` [T, KH, D] (f-dtype) at (phys[t],
-    offs[t]) into one layer's (q [P, ps_eff, KH, D], s [P, KH]).
+    offs[t]) into one layer's (q [P, ps_eff, KH*D], s [P, KH]). Heads
+    come apart on the GATHERED pages only ([T, ps_eff, KH, D]).
 
     Duplicate pages within one write are handled exactly: scale combines
     via scatter-max, the requantize pass writes identical whole-page
@@ -246,7 +276,8 @@ def _write_one_layer(q, s, phys, offs, vals, bits: int, page_size: int):
     eff_s = s[phys]  # [T, KH] final per-page scales (duplicates agree)
     # requantize the touched pages for grown scales (ratio 0 scrubs
     # freshly-started pages' stale ints to 0)
-    pages_q = q[phys]  # [T, ps_eff, KH, D] (pre-write content, dup-consistent)
+    # [T, ps_eff, KH, D] (pre-write content, dup-consistent)
+    pages_q = q[phys].reshape(T, q.shape[1], *vals.shape[1:])
     nib = unpack_int4(pages_q, axis=1) if bits == 4 else pages_q  # [T, ps, KH, D]
     ratio = jnp.where(eff_s > 0, old_s / jnp.maximum(eff_s, 1e-30), 0.0)
     nib = jnp.clip(
@@ -254,7 +285,11 @@ def _write_one_layer(q, s, phys, offs, vals, bits: int, page_size: int):
         -qmax, qmax,
     ).astype(jnp.int8)
     repacked = pack_int4(nib, axis=1) if bits == 4 else nib
-    q = q.at[phys].set(repacked)  # duplicates write identical content
+
+    def lanes(x):  # [T, ps_eff, KH, D] -> the pool's [T, ps_eff, KH*D]
+        return x.reshape(T, q.shape[1], -1)
+
+    q = q.at[phys].set(lanes(repacked))  # duplicates write identical content
     # quantize the new values at the final page scale and write each
     # copy's own row; the delta-add merges duplicate pages exactly
     qv = jnp.clip(
@@ -265,16 +300,19 @@ def _write_one_layer(q, s, phys, offs, vals, bits: int, page_size: int):
     wpacked = pack_int4(written, axis=1) if bits == 4 else written
     # int8 subtraction/addition wrap (two's complement); the FINAL value
     # per byte is the in-range written one, so wraparound cancels exactly
-    q = q.at[phys].add(wpacked - repacked)
+    q = q.at[phys].add(lanes(wpacked - repacked))
     return q, s
 
 
 def kv_write(store, li, phys, offs, vals):
     """Write `vals` [..., KH, D] at (li, phys[...], offs[...]) — the ONE
     KV page-write spelling for every model forward (prefill chunk store,
-    ragged mixed store, decode). fp mode is the exact original scatter."""
+    ragged mixed store, decode, ring, pp). fp mode is one in-place scatter
+    of lane-dense rows [..., KH*D] into the donated pool."""
     if not isinstance(store, QuantKV):
-        return store.at[li, phys, offs].set(vals)
+        return store.at[li, phys, offs].set(
+            vals.reshape(*vals.shape[:-2], -1)
+        )
     lead = phys.shape
     T = int(np.prod(lead)) if lead else 1
     phys_f = phys.reshape(T)
@@ -293,9 +331,9 @@ def kv_write(store, li, phys, offs, vals):
 def kv_write_all_layers(store, phys, offs, vals):
     """All-layer write (the fused decode block's once-per-block carry
     patch): vals [L, ...lead, KH, D] at (phys[...lead], offs[...lead]).
-    fp mode keeps the seed's single fused scatter."""
+    fp mode is a single fused scatter over every layer."""
     if not isinstance(store, QuantKV):
-        return store.at[:, phys, offs].set(vals)
+        return store.at[:, phys, offs].set(vals.reshape(*vals.shape[:-2], -1))
     lead = phys.shape
     T = int(np.prod(lead)) if lead else 1
     phys_f = phys.reshape(T)
@@ -316,19 +354,56 @@ def kv_write_all_layers(store, phys, offs, vals):
 # ---------------------------------------------------------------------- #
 
 
-def gather_dequant(layer, tables, dtype=jnp.float32):
-    """Gather pages for a per-layer KV operand and return FULL-PRECISION
-    context [..., n_pages, page_size, KH, D] in `dtype`. `layer` is a
-    plain [pages, ps, KH, D] array (plain gather, any dtype) or a
-    per-layer QuantKV (unpack + dequantize). `tables` may have any
-    leading shape ([max_pages] or [B, max_pages])."""
-    if not isinstance(layer, QuantKV):
-        return layer[tables]
-    q = layer.q[tables]  # [..., P, ps_eff, KH, D]
-    if layer.bits == 4:
+def gather_dequant(layer: KVLayer, tables, head_dim: int, dtype=jnp.float32):
+    """Gather pages of a per-layer KV operand and return FULL-PRECISION
+    context [..., n_pages, page_size, KH, D] in `dtype`: the accessor of
+    the XLA reference paths. The layer index rides the gather
+    (`pool[li, tables]`), and heads come apart on the gathered pages,
+    never on the pool. Plain pools gather as they are (any dtype);
+    QuantKV pools unpack + dequantize. `tables` may have any leading
+    shape ([max_pages] or [B, max_pages])."""
+    pool, li = layer
+    if not isinstance(pool, QuantKV):
+        pages = pool[li, tables]  # [..., P, ps, KH*D]
+        return pages.reshape(*pages.shape[:-1], -1, head_dim)
+    q = pool.q[li, tables]  # [..., P, ps_eff, KH*D]
+    q = q.reshape(*q.shape[:-1], -1, head_dim)
+    if pool.bits == 4:
         q = unpack_int4(q, axis=-3)  # page_size axis
-    s = layer.s[tables]  # [..., P, KH]
+    s = pool.s[li, tables]  # [..., P, KH]
     return (q.astype(jnp.float32) * s[..., None, :, None]).astype(dtype)
+
+
+def extract_pages(store, page_ids, num_kv_heads: int):
+    """Gather whole pages of EVERY layer for the host/wire boundary (KVBM
+    offload, KV transfer, disagg): `[L, n, rows, KH, D]` (a QuantKV of q
+    in that shape + s `[L, n, KH]`) — the page layout every tier and
+    payload has always carried; the bytes of a page are the same bytes in
+    the same order as in the lane-dense pool."""
+    def heads(a):
+        return a.reshape(*a.shape[:3], num_kv_heads, -1)
+
+    if not isinstance(store, QuantKV):
+        return heads(store[:, page_ids])
+    return QuantKV(
+        heads(store.q[:, page_ids]), store.s[:, page_ids],
+        store.bits, store.page_size,
+    )
+
+
+def inject_pages(store, page_ids, data):
+    """Inverse of extract_pages: scatter `[L, n, rows, KH, D]` pages (or
+    rows already lane-dense) into the pool in place."""
+    def lanes(d):
+        return d.reshape(*d.shape[:3], -1)
+
+    if not isinstance(store, QuantKV):
+        return store.at[:, page_ids].set(lanes(data))
+    return QuantKV(
+        store.q.at[:, page_ids].set(lanes(data.q)),
+        store.s.at[:, page_ids].set(data.s),
+        store.bits, store.page_size,
+    )
 
 
 # ---------------------------------------------------------------------- #

@@ -6,7 +6,9 @@ with a Pallas decode kernel (ops/pallas_paged_attention.py) swapped in on
 TPU for the HBM-bound gather.
 
 Layouts:
-  kv_k / kv_v (per layer): [num_pages, page_size, kv_heads, head_dim]
+  kv_k_layer / kv_v_layer: ops/kv_quant.KVLayer — the WHOLE lane-dense
+      pool [L, num_pages, page_size, kv_heads*head_dim] (or QuantKV) plus
+      the layer index; never a slice of the pool
   page_table: logical page index -> physical page id
 """
 
@@ -20,26 +22,17 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from .kv_quant import QuantKV, gather_dequant, is_quant_kv
+from .kv_quant import KVLayer, gather_dequant, is_quant_kv, layer_dims
 
 NEG_INF = -1e30
-
-
-def _layer_dims(layer) -> tuple:
-    """(num_pages, page_size, KH, D) for a per-layer KV operand — plain
-    array or QuantKV (whose q axis 1 is packed for int4)."""
-    if is_quant_kv(layer):
-        P, _, KH, D = layer.q.shape
-        return P, layer.page_size, KH, D
-    return layer.shape
 
 
 def prefill_attention(
     q: jax.Array,  # [T, H, D] (current chunk, rope applied)
     k_chunk: jax.Array,  # [T, KH, D] (unused: already written to pages)
     v_chunk: jax.Array,
-    kv_k_layer: jax.Array,  # [pages, page_size, KH, D]
-    kv_v_layer: jax.Array,
+    kv_k_layer: KVLayer,  # whole pool + layer index (kv_quant.kv_layer)
+    kv_v_layer: KVLayer,
     positions: jax.Array,  # [T] absolute positions of the chunk
     page_table: jax.Array,  # [max_pages]
     context_len: jax.Array,  # scalar (history before this chunk)
@@ -55,19 +48,19 @@ def prefill_attention(
     gather is context-sized, not max-context-sized).
     """
     if total_len is not None and _pallas_eligible(
-        q.shape[-1], is_quant_kv(kv_k_layer)
+        q.shape[-1], is_quant_kv(kv_k_layer.pool)
     ):
         from .pallas_prefill_attention import paged_prefill_attention_pallas
 
         return paged_prefill_attention_pallas(
             q, kv_k_layer, kv_v_layer, page_table, context_len, total_len
         )
-    _, page_size, KH_l, D_l = _layer_dims(kv_k_layer)
-    S = page_table.shape[0] * page_size
-    ctx_k = gather_dequant(kv_k_layer, page_table, q.dtype).reshape(S, KH_l, D_l)
-    ctx_v = gather_dequant(kv_v_layer, page_table, q.dtype).reshape(S, KH_l, D_l)
-
     T, H, D = q.shape
+    page_size, KH_l = layer_dims(kv_k_layer, D)
+    S = page_table.shape[0] * page_size
+    ctx_k = gather_dequant(kv_k_layer, page_table, D, q.dtype).reshape(S, KH_l, D)
+    ctx_v = gather_dequant(kv_v_layer, page_table, D, q.dtype).reshape(S, KH_l, D)
+
     KH = ctx_k.shape[1]
     G = H // KH
     qg = q.reshape(T, KH, G, D)
@@ -85,8 +78,8 @@ def prefill_attention(
 
 def prefill_attention_batched(
     q: jax.Array,  # [B, T, H, D] (chunks, rope applied)
-    kv_k_layer: jax.Array,  # [pages, page_size, KH, D]
-    kv_v_layer: jax.Array,
+    kv_k_layer: KVLayer,  # whole pool + layer index (kv_quant.kv_layer)
+    kv_v_layer: KVLayer,
     positions: jax.Array,  # [B, T] absolute positions
     page_tables: jax.Array,  # [B, max_pages]
     total_lens: jax.Array,  # [B] valid context per seq (history + real chunk)
@@ -99,17 +92,17 @@ def prefill_attention_batched(
     context pages; elsewhere the XLA path gathers each (engine-bounded)
     page table.
     """
-    if _pallas_eligible(q.shape[-1], is_quant_kv(kv_k_layer)):
+    if _pallas_eligible(q.shape[-1], is_quant_kv(kv_k_layer.pool)):
         from .pallas_prefill_attention import paged_prefill_attention_pallas_batched
 
         return paged_prefill_attention_pallas_batched(
             q, kv_k_layer, kv_v_layer, page_tables, starts, total_lens
         )
     B, T, H, D = q.shape
-    _, page_size, KH, _ = _layer_dims(kv_k_layer)
+    page_size, KH = layer_dims(kv_k_layer, D)
     S = page_tables.shape[1] * page_size
-    ctx_k = gather_dequant(kv_k_layer, page_tables, q.dtype).reshape(B, S, KH, D)
-    ctx_v = gather_dequant(kv_v_layer, page_tables, q.dtype).reshape(B, S, KH, D)
+    ctx_k = gather_dequant(kv_k_layer, page_tables, D, q.dtype).reshape(B, S, KH, D)
+    ctx_v = gather_dequant(kv_v_layer, page_tables, D, q.dtype).reshape(B, S, KH, D)
     G = H // KH
     qg = q.reshape(B, T, KH, G, D)
     scores = jnp.einsum(
@@ -206,8 +199,8 @@ def resolved_attention(head_dim: int, kv_heads: int, quantized: bool) -> dict:
 
 def paged_attention_decode_mixed(
     q: jax.Array,  # [B, H, D]
-    kv_k_layer: jax.Array,  # [pages, page_size, KH, D] — READ-ONLY pool
-    kv_v_layer: jax.Array,
+    kv_k_layer: KVLayer,  # whole pool + layer index — READ-ONLY pool
+    kv_v_layer: KVLayer,
     page_tables: jax.Array,  # [B, max_pages]
     pool_lens: jax.Array,  # [B] positions valid IN THE POOL (block-start len)
     loc_k: jax.Array,  # [B, K, KH, D] block-local new keys (this layer)
@@ -228,11 +221,11 @@ def paged_attention_decode_mixed(
     softmax on the XLA path.
     """
     B, H, D = q.shape
-    _, page_size, KH, D_ = _layer_dims(kv_k_layer)
+    page_size, KH = layer_dims(kv_k_layer, D)
     G = H // KH
     K = loc_k.shape[1]
     scale = 1.0 / jnp.sqrt(jnp.asarray(D, jnp.float32))
-    if _pallas_eligible(KH * D_, is_quant_kv(kv_k_layer)):
+    if _pallas_eligible(KH * D, is_quant_kv(kv_k_layer.pool)):
         # pool chunks AND the local buffer flash-merge inside ONE kernel
         # launch — an XLA-level lse combine costs ~8 extra op launches per
         # layer-step, which dominates a 28-layer x 16-step fused block.
@@ -249,8 +242,8 @@ def paged_attention_decode_mixed(
     # XLA reference path: gather pool pages, concatenate the local buffer,
     # one softmax over both
     S = page_tables.shape[1] * page_size
-    ctx_k = gather_dequant(kv_k_layer, page_tables, q.dtype).reshape(B, S, KH, D)
-    ctx_v = gather_dequant(kv_v_layer, page_tables, q.dtype).reshape(B, S, KH, D)
+    ctx_k = gather_dequant(kv_k_layer, page_tables, D, q.dtype).reshape(B, S, KH, D)
+    ctx_v = gather_dequant(kv_v_layer, page_tables, D, q.dtype).reshape(B, S, KH, D)
     cat_k = jnp.concatenate([ctx_k, loc_k.astype(ctx_k.dtype)], axis=1)
     cat_v = jnp.concatenate([ctx_v, loc_v.astype(ctx_v.dtype)], axis=1)
     qg = q.reshape(B, KH, G, D)
@@ -270,8 +263,8 @@ def paged_attention_decode_mixed(
 
 def paged_attention_decode(
     q: jax.Array,  # [B, H, D]
-    kv_k_layer: jax.Array,  # [pages, page_size, KH, D]
-    kv_v_layer: jax.Array,
+    kv_k_layer: KVLayer,  # whole pool + layer index (kv_quant.kv_layer)
+    kv_v_layer: KVLayer,
     page_tables: jax.Array,  # [B, max_pages]
     seq_lens: jax.Array,  # [B] (including current token)
 ) -> jax.Array:
@@ -281,21 +274,20 @@ def paged_attention_decode(
     kernel (ops/pallas_paged_attention.py) streams pages HBM→VMEM without
     materializing the gather; elsewhere the XLA reference path below runs.
     """
-    _, page_size, KH_, D_ = _layer_dims(kv_k_layer)
+    B, H, D = q.shape
+    page_size, KH = layer_dims(kv_k_layer, D)
     # the decode kernel's page window has lane dim KH*D (whole-page
     # copies), so that is what must be 128-aligned here (int4 packs along
     # the page_size/sublane axis, so the lane dim is unchanged)
-    if _pallas_eligible(KH_ * D_, is_quant_kv(kv_k_layer)):
+    if _pallas_eligible(KH * D, is_quant_kv(kv_k_layer.pool)):
         from .pallas_paged_attention import paged_attention_decode_pallas
 
         return paged_attention_decode_pallas(
             q, kv_k_layer, kv_v_layer, page_tables, seq_lens
         )
-    B, H, D = q.shape
-    KH = KH_
     S = page_tables.shape[1] * page_size
-    ctx_k = gather_dequant(kv_k_layer, page_tables, q.dtype).reshape(B, S, KH, D)
-    ctx_v = gather_dequant(kv_v_layer, page_tables, q.dtype).reshape(B, S, KH, D)
+    ctx_k = gather_dequant(kv_k_layer, page_tables, D, q.dtype).reshape(B, S, KH, D)
+    ctx_v = gather_dequant(kv_v_layer, page_tables, D, q.dtype).reshape(B, S, KH, D)
 
     G = H // KH
     qg = q.reshape(B, KH, G, D)
@@ -312,8 +304,8 @@ def paged_attention_decode(
 
 def ragged_attention_reference(
     q: jax.Array,  # [N, H, D] flat packed tokens (rope applied)
-    kv_k_layer: jax.Array,  # [pages, page_size, KH, D]
-    kv_v_layer: jax.Array,
+    kv_k_layer: KVLayer,  # whole pool + layer index (kv_quant.kv_layer)
+    kv_v_layer: KVLayer,
     page_tables: jax.Array,  # [R, max_pages]
     row_starts: jax.Array,  # [R] flat index of row r's token 0 (ascending;
     # padding rows sit at N)
@@ -329,7 +321,7 @@ def ragged_attention_reference(
     real rows."""
     N, H, D = q.shape
     R, P = page_tables.shape
-    _, page_size, KH, _ = _layer_dims(kv_k_layer)
+    page_size, KH = layer_dims(kv_k_layer, D)
     S = P * page_size
     idx = jnp.arange(N)
     # owning row per token: the last row whose start <= idx (padding
@@ -341,10 +333,10 @@ def ragged_attention_reference(
     positions = ctx_lens[row_ids] + local
     totals = ctx_lens[row_ids] + row_lens[row_ids]
     ctx_k = gather_dequant(
-        kv_k_layer, page_tables, q.dtype
+        kv_k_layer, page_tables, D, q.dtype
     ).reshape(R, S, KH, D)[row_ids]  # [N, S, KH, D]
     ctx_v = gather_dequant(
-        kv_v_layer, page_tables, q.dtype
+        kv_v_layer, page_tables, D, q.dtype
     ).reshape(R, S, KH, D)[row_ids]
     G = H // KH
     qg = q.reshape(N, KH, G, D)
@@ -365,8 +357,8 @@ def ragged_attention_reference(
 
 def ragged_attention(
     q: jax.Array,  # [N, H, D]
-    kv_k_layer: jax.Array,  # [pages, page_size, KH, D]
-    kv_v_layer: jax.Array,
+    kv_k_layer: KVLayer,  # whole pool + layer index (kv_quant.kv_layer)
+    kv_v_layer: KVLayer,
     page_tables: jax.Array,  # [R, max_pages]
     row_starts: jax.Array,  # [R]
     row_lens: jax.Array,  # [R]
@@ -382,7 +374,7 @@ def ragged_attention(
     aligned to `ragged_tile_q(q.dtype)` — the engine's mixed packer aligns
     exactly when this gate says the kernel will run
     (engine/engine.py:_dispatch_mixed)."""
-    if _pallas_eligible(q.shape[-1], is_quant_kv(kv_k_layer)):
+    if _pallas_eligible(q.shape[-1], is_quant_kv(kv_k_layer.pool)):
         from .pallas_ragged_attention import ragged_paged_attention_pallas
 
         return ragged_paged_attention_pallas(

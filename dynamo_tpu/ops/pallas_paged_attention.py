@@ -10,13 +10,16 @@ context).
 
 Layouts (match ops/paged_attention.py and engine/kv_cache.py):
     q:           [B, H, D]
-    kv_{k,v}:    [num_pages, page_size, KH, D]   (one layer)
+    kv_{k,v}:    [L, num_pages, page_size, KH*D]  (the WHOLE lane-dense pool,
+                 as it lies in HBM, + the layer index as scalar prefetch)
     page_tables: [B, max_pages] int32  (logical -> physical page)
     seq_lens:    [B] int32             (valid positions incl. current token)
 
 Design notes:
-  * grid = (B,); page_tables/seq_lens ride scalar-prefetch (SMEM) so DMA
-    source indices are known ahead of the body.
+  * grid = (B,); the layer index and page_tables/seq_lens ride
+    scalar-prefetch (SMEM) so DMA source indices (`pool[li, page]`) are
+    known ahead of the body. No per-layer slice or reshape of the pool
+    exists outside the kernel: on the TPU either is a pool-sized copy.
   * pages are streamed in chunks of CHUNK = max(128, page_size) positions so
     the score lane dimension is a full 128-lane register tile.
   * physical page ids are clamped to the valid range: tail chunks may DMA a
@@ -70,12 +73,13 @@ def _window_dequant(b, ci, slot, k_buf, v_buf, pt_ref, ks_ref, vs_ref,
 
 
 def _decode_kernel(
-    # positional refs: page_tables [B, max_pages] + seq_lens [B] int32
-    # scalar prefetch (+ per-page-per-head K/V scales [num_pages, KH] f32
-    # when kv_bits > 0), then q [1, H, D] VMEM, kv_k/kv_v
-    # [num_pages, rows, KH*D] ANY/HBM (rows = page_size, or page_size//2
-    # int4-packed along the sublane axis), the out block, and the
-    # double-buffered VMEM window + DMA semaphores.
+    # positional refs: layer index [1] + page_tables [B, max_pages] +
+    # seq_lens [B] int32 scalar prefetch (+ this layer's per-page-per-head
+    # K/V scales [num_pages, KH] f32 when kv_bits > 0), then q [1, H, D]
+    # VMEM, kv_k/kv_v [L, num_pages, rows, KH*D] ANY/HBM (the whole pool;
+    # rows = page_size, or page_size//2 int4-packed along the sublane
+    # axis), the out block, and the double-buffered VMEM window + DMA
+    # semaphores.
     *refs,
     page_size: int,
     chunk_pages: int,
@@ -86,16 +90,17 @@ def _decode_kernel(
     kv_bits: int = 0,
 ):
     if kv_bits:
-        (pt_ref, sl_ref, ks_ref, vs_ref, q_ref, kv_k_hbm, kv_v_hbm,
+        (li_ref, pt_ref, sl_ref, ks_ref, vs_ref, q_ref, kv_k_hbm, kv_v_hbm,
          out_ref, k_buf, v_buf, k_sem, v_sem) = refs
     else:
-        (pt_ref, sl_ref, q_ref, kv_k_hbm, kv_v_hbm,
+        (li_ref, pt_ref, sl_ref, q_ref, kv_k_hbm, kv_v_hbm,
          out_ref, k_buf, v_buf, k_sem, v_sem) = refs
         ks_ref = vs_ref = None
     b = pl.program_id(0)
+    li = li_ref[0]
     chunk = chunk_pages * page_size
-    num_phys = kv_k_hbm.shape[0]
-    page_rows = kv_k_hbm.shape[1]
+    num_phys = kv_k_hbm.shape[1]
+    page_rows = kv_k_hbm.shape[2]
     kh, g, d = num_kv_heads, num_heads // num_kv_heads, head_dim
 
     seq_len = jnp.maximum(sl_ref[b], 1)  # empty slots behave as len-1
@@ -109,12 +114,12 @@ def _decode_kernel(
             lp_safe = jnp.minimum(lp, max_pages - 1)
             phys = jnp.minimum(pt_ref[b, lp_safe], num_phys - 1)
             pltpu.make_async_copy(
-                kv_k_hbm.at[phys],
+                kv_k_hbm.at[li, phys],
                 k_buf.at[slot, pl.ds(p * page_rows, page_rows)],
                 k_sem.at[slot, p],
             ).start()
             pltpu.make_async_copy(
-                kv_v_hbm.at[phys],
+                kv_v_hbm.at[li, phys],
                 v_buf.at[slot, pl.ds(p * page_rows, page_rows)],
                 v_sem.at[slot, p],
             ).start()
@@ -124,12 +129,12 @@ def _decode_kernel(
             lp_safe = jnp.minimum(ci * chunk_pages + p, max_pages - 1)
             phys = jnp.minimum(pt_ref[b, lp_safe], num_phys - 1)
             pltpu.make_async_copy(
-                kv_k_hbm.at[phys],
+                kv_k_hbm.at[li, phys],
                 k_buf.at[slot, pl.ds(p * page_rows, page_rows)],
                 k_sem.at[slot, p],
             ).wait()
             pltpu.make_async_copy(
-                kv_v_hbm.at[phys],
+                kv_v_hbm.at[li, phys],
                 v_buf.at[slot, pl.ds(p * page_rows, page_rows)],
                 v_sem.at[slot, p],
             ).wait()
@@ -203,12 +208,13 @@ def _decode_kernel(
 
 
 def _decode_local_kernel(
-    # positional refs: page_tables [B, max_pages], POOL lens [B], step [1]
-    # int32 scalar prefetch (+ per-page-per-head K/V scales
-    # [num_pages, KH] f32 when kv_bits > 0), then q [1, HG, KH*D] VMEM
-    # (block-diagonal packed), the block-local loc_k/loc_v [1, K, KH*D]
-    # (ALWAYS full precision — quantization happens on pool writes only),
-    # kv_k/kv_v [num_pages, rows, KH*D] ANY/HBM, out, window scratch.
+    # positional refs: layer index [1], page_tables [B, max_pages], POOL
+    # lens [B], step [1] int32 scalar prefetch (+ this layer's
+    # per-page-per-head K/V scales [num_pages, KH] f32 when kv_bits > 0),
+    # then q [1, HG, KH*D] VMEM (block-diagonal packed), the block-local
+    # loc_k/loc_v [1, K, KH*D] (ALWAYS full precision — quantization
+    # happens on pool writes only), kv_k/kv_v [L, num_pages, rows, KH*D]
+    # ANY/HBM (the whole pool), out, window scratch.
     *refs,
     page_size: int,
     chunk_pages: int,
@@ -224,17 +230,18 @@ def _decode_local_kernel(
     decode_block): per-step XLA-level combines cost ~8 extra op launches
     per layer-step, which dominated the block at 28 layers x 16 steps."""
     if kv_bits:
-        (pt_ref, sl_ref, step_ref, ks_ref, vs_ref, q_ref, loc_k_ref,
+        (li_ref, pt_ref, sl_ref, step_ref, ks_ref, vs_ref, q_ref, loc_k_ref,
          loc_v_ref, kv_k_hbm, kv_v_hbm, out_ref, k_buf, v_buf, k_sem,
          v_sem) = refs
     else:
-        (pt_ref, sl_ref, step_ref, q_ref, loc_k_ref, loc_v_ref,
+        (li_ref, pt_ref, sl_ref, step_ref, q_ref, loc_k_ref, loc_v_ref,
          kv_k_hbm, kv_v_hbm, out_ref, k_buf, v_buf, k_sem, v_sem) = refs
         ks_ref = vs_ref = None
     b = pl.program_id(0)
+    li = li_ref[0]
     chunk = chunk_pages * page_size
-    num_phys = kv_k_hbm.shape[0]
-    page_rows = kv_k_hbm.shape[1]
+    num_phys = kv_k_hbm.shape[1]
+    page_rows = kv_k_hbm.shape[2]
     kh, g, d = num_kv_heads, num_heads // num_kv_heads, head_dim
 
     seq_len = jnp.maximum(sl_ref[b], 1)
@@ -246,12 +253,12 @@ def _decode_local_kernel(
             lp_safe = jnp.minimum(lp, max_pages - 1)
             phys = jnp.minimum(pt_ref[b, lp_safe], num_phys - 1)
             pltpu.make_async_copy(
-                kv_k_hbm.at[phys],
+                kv_k_hbm.at[li, phys],
                 k_buf.at[slot, pl.ds(p * page_rows, page_rows)],
                 k_sem.at[slot, p],
             ).start()
             pltpu.make_async_copy(
-                kv_v_hbm.at[phys],
+                kv_v_hbm.at[li, phys],
                 v_buf.at[slot, pl.ds(p * page_rows, page_rows)],
                 v_sem.at[slot, p],
             ).start()
@@ -261,12 +268,12 @@ def _decode_local_kernel(
             lp_safe = jnp.minimum(ci * chunk_pages + p, max_pages - 1)
             phys = jnp.minimum(pt_ref[b, lp_safe], num_phys - 1)
             pltpu.make_async_copy(
-                kv_k_hbm.at[phys],
+                kv_k_hbm.at[li, phys],
                 k_buf.at[slot, pl.ds(p * page_rows, page_rows)],
                 k_sem.at[slot, p],
             ).wait()
             pltpu.make_async_copy(
-                kv_v_hbm.at[phys],
+                kv_v_hbm.at[li, phys],
                 v_buf.at[slot, pl.ds(p * page_rows, page_rows)],
                 v_sem.at[slot, p],
             ).wait()
@@ -339,11 +346,24 @@ def _decode_local_kernel(
     out_ref[0] = out.astype(out_ref.dtype)
 
 
+def _block_diagonal_q(q: jax.Array, num_kv_heads: int) -> jax.Array:
+    """[B, H, D] -> [B, H, KH*D], scaled by 1/sqrt(D): head h's query in
+    the D-wide lane block of its kv head (q_bd[b, k*G+g, k*D:(k+1)*D] = q),
+    zeros elsewhere. Built lane-dense, a concatenation along the lanes and
+    a mask: a reshape that merges (KH, D) is a relayout on the TPU."""
+    _, H, D = q.shape
+    lanes = (H, num_kv_heads * D)
+    lane_head = jax.lax.broadcasted_iota(jnp.int32, lanes, 1) // D
+    row_head = jax.lax.broadcasted_iota(jnp.int32, lanes, 0) // (H // num_kv_heads)
+    tiled = jnp.concatenate([q * (1.0 / (D**0.5))] * num_kv_heads, axis=-1)
+    return jnp.where(lane_head == row_head, tiled, jnp.zeros((), q.dtype))
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention_decode_pallas_local(
     q: jax.Array,  # [B, H, D]
-    kv_k_layer: jax.Array,  # [num_pages, page_size, KH, D] (READ-ONLY pool)
-    kv_v_layer: jax.Array,
+    kv_k_layer,  # kv_quant.KVLayer: whole READ-ONLY pool + layer index
+    kv_v_layer,
     page_tables: jax.Array,  # [B, max_pages] int32
     pool_lens: jax.Array,  # [B] int32 — positions valid in the pool
     loc_k: jax.Array,  # [B, K, KH, D] block-local new keys
@@ -353,16 +373,15 @@ def paged_attention_decode_pallas_local(
     interpret: bool = False,
 ) -> jax.Array:
     """Fused pool+local decode attention; returns [B, H, D] (q.dtype).
-    The pool may be a per-layer QuantKV (ops/kv_quant.py): packed pages
-    dequantize inside the VMEM window off scalar-prefetched scales; the
-    block-local buffer is always full precision."""
+    The pool may be a QuantKV (ops/kv_quant.py): packed pages dequantize
+    inside the VMEM window off scalar-prefetched scales; the block-local
+    buffer is always full precision."""
     from .kv_quant import kernel_operands
 
     B, H, D = q.shape
-    kv_k_raw, kv_v_raw, rows, page_size, kv_bits, scale_prefetch = (
-        kernel_operands(kv_k_layer, kv_v_layer)
+    kv_k_pool, kv_v_pool, li, KH, rows, page_size, kv_bits, scale_prefetch = (
+        kernel_operands(kv_k_layer, kv_v_layer, D)
     )
-    num_pages, _, KH, _ = kv_k_raw.shape
     max_pages = page_tables.shape[1]
     K_loc = loc_k.shape[1]
     target = 512 if KH * D * page_size <= 131072 else 256
@@ -370,16 +389,12 @@ def paged_attention_decode_pallas_local(
     chunk_pages = min(chunk_pages, max_pages)
 
     KHG = KH * (H // KH)
-    scale = 1.0 / (D**0.5)
-    q_r = (q * scale).reshape(B, KH, H // KH, D)
-    eye = jnp.eye(KH, dtype=q.dtype)
-    q_bd = jnp.einsum("bkgd,kj->bkgjd", q_r, eye).reshape(B, KHG, KH * D)
+    q_bd = _block_diagonal_q(q, KH)
 
-    kv_k_flat = kv_k_raw.reshape(num_pages, rows, KH * D)
-    kv_v_flat = kv_v_raw.reshape(num_pages, rows, KH * D)
     loc_k_flat = loc_k.reshape(B, K_loc, KH * D)
     loc_v_flat = loc_v.reshape(B, K_loc, KH * D)
     prefetch = [
+        li,
         page_tables.astype(jnp.int32),
         pool_lens.astype(jnp.int32),
         jnp.reshape(step_idx, (1,)).astype(jnp.int32),
@@ -398,8 +413,8 @@ def paged_attention_decode_pallas_local(
         ],
         out_specs=pl.BlockSpec((1, H, D), lambda b, *_: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, chunk_pages * rows, KH * D), kv_k_flat.dtype),
-            pltpu.VMEM((2, chunk_pages * rows, KH * D), kv_v_flat.dtype),
+            pltpu.VMEM((2, chunk_pages * rows, KH * D), kv_k_pool.dtype),
+            pltpu.VMEM((2, chunk_pages * rows, KH * D), kv_v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, chunk_pages)),
             pltpu.SemaphoreType.DMA((2, chunk_pages)),
         ],
@@ -430,16 +445,16 @@ def paged_attention_decode_pallas_local(
         q_bd,
         loc_k_flat,
         loc_v_flat,
-        kv_k_flat,
-        kv_v_flat,
+        kv_k_pool,
+        kv_v_pool,
     )
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention_decode_pallas(
     q: jax.Array,  # [B, H, D]
-    kv_k_layer: jax.Array,  # [num_pages, page_size, KH, D]
-    kv_v_layer: jax.Array,
+    kv_k_layer,  # kv_quant.KVLayer: whole pool + layer index
+    kv_v_layer,
     page_tables: jax.Array,  # [B, max_pages] int32
     seq_lens: jax.Array,  # [B] int32
     *,
@@ -448,14 +463,13 @@ def paged_attention_decode_pallas(
     """Flash decode attention over paged KV; returns [B, H, D] (q.dtype).
     (Block-local merging lives in _decode_local_kernel — the fused variant —
     so this hot path writes exactly one output.) The pool may be a
-    per-layer QuantKV: packed pages dequantize in the VMEM window."""
+    QuantKV: packed pages dequantize in the VMEM window."""
     from .kv_quant import kernel_operands
 
     B, H, D = q.shape
-    kv_k_raw, kv_v_raw, rows, page_size, kv_bits, scale_prefetch = (
-        kernel_operands(kv_k_layer, kv_v_layer)
+    kv_k_pool, kv_v_pool, li, KH, rows, page_size, kv_bits, scale_prefetch = (
+        kernel_operands(kv_k_layer, kv_v_layer, D)
     )
-    num_pages, _, KH, _ = kv_k_raw.shape
     max_pages = page_tables.shape[1]
     # chunk target: big enough to amortize per-iteration overhead, small
     # enough that 2 double-buffered K+V chunks fit comfortably in VMEM
@@ -464,17 +478,10 @@ def paged_attention_decode_pallas(
     chunk_pages = min(chunk_pages, max_pages)
 
     KHG = KH * (H // KH)
-    # pre-pack block-diagonal queries in XLA: q_bd[b, h*G+g, h*D:(h+1)*D] = q
-    scale = 1.0 / (D**0.5)
-    q_r = (q * scale).reshape(B, KH, H // KH, D)
-    eye = jnp.eye(KH, dtype=q.dtype)
-    q_bd = jnp.einsum("bkgd,kj->bkgjd", q_r, eye).reshape(B, KHG, KH * D)
+    q_bd = _block_diagonal_q(q, KH)
 
-    # flatten [pages, rows, KH, D] -> [pages, rows, KH*D] in XLA
-    # (contiguous bitcast) — Mosaic cannot merge minor dims in-register
-    kv_k_flat = kv_k_raw.reshape(num_pages, rows, KH * D)
-    kv_v_flat = kv_v_raw.reshape(num_pages, rows, KH * D)
     prefetch = [
+        li,
         page_tables.astype(jnp.int32),
         seq_lens.astype(jnp.int32),
         *scale_prefetch,
@@ -490,8 +497,8 @@ def paged_attention_decode_pallas(
         ],
         out_specs=pl.BlockSpec((1, H, D), lambda b, *_: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, chunk_pages * rows, KH * D), kv_k_flat.dtype),
-            pltpu.VMEM((2, chunk_pages * rows, KH * D), kv_v_flat.dtype),
+            pltpu.VMEM((2, chunk_pages * rows, KH * D), kv_k_pool.dtype),
+            pltpu.VMEM((2, chunk_pages * rows, KH * D), kv_v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, chunk_pages)),
             pltpu.SemaphoreType.DMA((2, chunk_pages)),
         ],
@@ -517,4 +524,4 @@ def paged_attention_decode_pallas(
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
         cost_estimate=cost,
         interpret=interpret,
-    )(*prefetch, q_bd, kv_k_flat, kv_v_flat)
+    )(*prefetch, q_bd, kv_k_pool, kv_v_pool)

@@ -17,7 +17,8 @@ of serializing one chunk per engine-loop iteration.
 
 Layouts (match ops/paged_attention.py and engine/kv_cache.py):
     q:           [B, T, H, D]     (chunks, rope applied; T = bucket)
-    kv_{k,v}:    [num_pages, page_size, KH, D]   (one layer)
+    kv_{k,v}:    [L, num_pages, page_size, KH*D]  (the WHOLE lane-dense pool,
+                 as it lies in HBM, + the layer index as scalar prefetch)
     page_tables: [B, max_pages] int32 (per-seq logical -> physical)
     starts:      [B] int32 — absolute position of each seq's q row 0
     total_lens:  [B] int32 — valid context = start + real chunk len
@@ -58,12 +59,13 @@ NEG = -1e30
 
 def _prefill_kernel(
     # scalar prefetch
+    li_ref,  # [1] int32 (SMEM): the layer whose pages this call reads
     pt_ref,  # [B, max_pages] int32 (SMEM)
     start_ref,  # [B] int32 (SMEM)
     total_ref,  # [B] int32 (SMEM)
     # inputs
     q_ref,  # [1, 1, TQ, G*D] VMEM block (one seq, one kv-head's query group)
-    kv_k_hbm,  # [num_pages, page_size, KH*D] (ANY/HBM; flattened by wrapper)
+    kv_k_hbm,  # [L, num_pages, page_size, KH*D] (ANY/HBM; the whole pool)
     kv_v_hbm,
     # outputs
     out_ref,  # [1, 1, TQ, G*D] VMEM block
@@ -85,7 +87,8 @@ def _prefill_kernel(
     t = pl.program_id(2)
     g, d, tq = group, head_dim, tile_q
     chunk = chunk_pages * page_size
-    num_phys = kv_k_hbm.shape[0]
+    li = li_ref[0]
+    num_phys = kv_k_hbm.shape[1]
 
     start = start_ref[b]
     total_len = total_ref[b]
@@ -98,12 +101,12 @@ def _prefill_kernel(
             lp = jnp.minimum(ci * chunk_pages + p, max_pages - 1)
             phys = jnp.minimum(pt_ref[b, lp], num_phys - 1)
             pltpu.make_async_copy(
-                kv_k_hbm.at[phys, :, pl.ds(k0 * d, d)],
+                kv_k_hbm.at[li, phys, :, pl.ds(k0 * d, d)],
                 k_buf.at[slot, pl.ds(p * page_size, page_size)],
                 k_sem.at[slot, p],
             ).start()
             pltpu.make_async_copy(
-                kv_v_hbm.at[phys, :, pl.ds(k0 * d, d)],
+                kv_v_hbm.at[li, phys, :, pl.ds(k0 * d, d)],
                 v_buf.at[slot, pl.ds(p * page_size, page_size)],
                 v_sem.at[slot, p],
             ).start()
@@ -113,12 +116,12 @@ def _prefill_kernel(
             lp = jnp.minimum(ci * chunk_pages + p, max_pages - 1)
             phys = jnp.minimum(pt_ref[b, lp], num_phys - 1)
             pltpu.make_async_copy(
-                kv_k_hbm.at[phys, :, pl.ds(k0 * d, d)],
+                kv_k_hbm.at[li, phys, :, pl.ds(k0 * d, d)],
                 k_buf.at[slot, pl.ds(p * page_size, page_size)],
                 k_sem.at[slot, p],
             ).wait()
             pltpu.make_async_copy(
-                kv_v_hbm.at[phys, :, pl.ds(k0 * d, d)],
+                kv_v_hbm.at[li, phys, :, pl.ds(k0 * d, d)],
                 v_buf.at[slot, pl.ds(p * page_size, page_size)],
                 v_sem.at[slot, p],
             ).wait()
@@ -181,17 +184,23 @@ def _prefill_kernel(
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_prefill_attention_pallas_batched(
     q: jax.Array,  # [B, T, H, D] (rope applied)
-    kv_k_layer: jax.Array,  # [num_pages, page_size, KH, D]
-    kv_v_layer: jax.Array,
+    kv_k_layer,  # kv_quant.KVLayer: whole pool + layer index
+    kv_v_layer,
     page_tables: jax.Array,  # [B, max_pages] int32
     starts: jax.Array,  # [B] int32
     total_lens: jax.Array,  # [B] int32
     *,
     interpret: bool = False,
 ) -> jax.Array:
-    """Batched flash chunked-prefill over paged KV; returns [B, T, H, D]."""
+    """Batched flash chunked-prefill over paged KV; returns [B, T, H, D].
+    fp pools only: the dispatch gate sends quantized pools to XLA."""
+    from .kv_quant import kernel_operands
+
     B, T, H, D = q.shape
-    num_pages, page_size, KH, _ = kv_k_layer.shape
+    kv_k_pool, kv_v_pool, li, KH, _, page_size, kv_bits, _ = kernel_operands(
+        kv_k_layer, kv_v_layer, D
+    )
+    assert kv_bits == 0, "the prefill kernel has no in-kernel dequant"
     G = H // KH
     max_pages = page_tables.shape[1]
     tile_q = min(256, T)
@@ -210,13 +219,9 @@ def paged_prefill_attention_pallas_batched(
         .transpose(0, 2, 1, 3, 4)
         .reshape(B, KH, T, G * D)
     )
-    # flatten pages' minor dims in XLA (contiguous bitcast) — Mosaic cannot
-    # merge minor dims in-register
-    kv_k_flat = kv_k_layer.reshape(num_pages, page_size, KH * D)
-    kv_v_flat = kv_v_layer.reshape(num_pages, page_size, KH * D)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B, KH, num_tiles),
         in_specs=[
             pl.BlockSpec((1, 1, tile_q, G * D), lambda b, k0, t, *_: (b, k0, t, 0)),
@@ -227,8 +232,8 @@ def paged_prefill_attention_pallas_batched(
             (1, 1, tile_q, G * D), lambda b, k0, t, *_: (b, k0, t, 0)
         ),
         scratch_shapes=[
-            pltpu.VMEM((2, chunk_pages * page_size, D), kv_k_layer.dtype),
-            pltpu.VMEM((2, chunk_pages * page_size, D), kv_v_layer.dtype),
+            pltpu.VMEM((2, chunk_pages * page_size, D), kv_k_pool.dtype),
+            pltpu.VMEM((2, chunk_pages * page_size, D), kv_v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, chunk_pages)),
             pltpu.SemaphoreType.DMA((2, chunk_pages)),
         ],
@@ -254,12 +259,13 @@ def paged_prefill_attention_pallas_batched(
         cost_estimate=cost,
         interpret=interpret,
     )(
+        li,
         page_tables.astype(jnp.int32),
         starts.astype(jnp.int32),
         total_lens.astype(jnp.int32),
         q_g,
-        kv_k_flat,
-        kv_v_flat,
+        kv_k_pool,
+        kv_v_pool,
     )
     # [B, KH, T, G*D] -> [B, T, H, D]
     return out.reshape(B, KH, T, G, D).transpose(0, 2, 1, 3, 4).reshape(B, T, H, D)
@@ -267,8 +273,8 @@ def paged_prefill_attention_pallas_batched(
 
 def paged_prefill_attention_pallas(
     q: jax.Array,  # [T, H, D]
-    kv_k_layer: jax.Array,
-    kv_v_layer: jax.Array,
+    kv_k_layer,  # kv_quant.KVLayer
+    kv_v_layer,
     page_table: jax.Array,  # [max_pages]
     start: jax.Array,  # scalar
     total_len: jax.Array,  # scalar

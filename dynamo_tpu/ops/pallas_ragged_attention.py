@@ -10,7 +10,8 @@ ops/pallas_prefill_attention.py and ops/pallas_paged_attention.py.
 Layouts (match ops/paged_attention.py and engine/kv_cache.py):
     q:           [N, H, D]  flat packed tokens (rope applied, chunk KV
                             already written into pages by the model)
-    kv_{k,v}:    [num_pages, page_size, KH, D]   (one layer)
+    kv_{k,v}:    [L, num_pages, page_size, KH*D]  (the WHOLE lane-dense pool,
+                 as it lies in HBM, + the layer index as scalar prefetch)
     page_tables: [R, max_pages] int32 (per-row logical -> physical)
     row_starts:  [R] int32 — flat index of row r's first token, ascending,
                  ALIGNED to the q tile (ragged_tile_q); padding rows sit
@@ -63,15 +64,15 @@ def ragged_tile_q(dtype) -> int:
 
 
 def _ragged_kernel(
-    # positional refs — scalar prefetch first: tile_rows [num_tiles],
-    # row_starts [R], row_lens [R], ctx_lens [R], page_tables
-    # [R, max_pages] (all int32 SMEM) and, under kv_bits > 0, the
-    # per-page-per-head K and V scales [num_pages, KH] f32 riding the
-    # SAME scalar-prefetch channel beside the page tables; then
-    # q [1, 1, TQ, G*D] VMEM, kv_k/kv_v [num_pages, rows, KH*D] ANY/HBM
-    # (rows = page_size, or page_size//2 int4-packed along the sublane
-    # axis), the output block, and the double-buffered VMEM window +
-    # DMA semaphores.
+    # positional refs — scalar prefetch first: layer index [1],
+    # tile_rows [num_tiles], row_starts [R], row_lens [R], ctx_lens [R],
+    # page_tables [R, max_pages] (all int32 SMEM) and, under kv_bits > 0,
+    # this layer's per-page-per-head K and V scales [num_pages, KH] f32
+    # riding the SAME scalar-prefetch channel beside the page tables; then
+    # q [1, 1, TQ, G*D] VMEM, kv_k/kv_v [L, num_pages, rows, KH*D] ANY/HBM
+    # (the whole pool; rows = page_size, or page_size//2 int4-packed along
+    # the sublane axis), the output block, and the double-buffered VMEM
+    # window + DMA semaphores.
     *refs,
     page_size: int,
     chunk_pages: int,
@@ -82,11 +83,11 @@ def _ragged_kernel(
     kv_bits: int = 0,
 ):
     if kv_bits:
-        (tr_ref, rs_ref, rl_ref, ctx_ref, pt_ref, ks_ref, vs_ref,
+        (li_ref, tr_ref, rs_ref, rl_ref, ctx_ref, pt_ref, ks_ref, vs_ref,
          q_ref, kv_k_hbm, kv_v_hbm, out_ref, k_buf, v_buf, k_sem,
          v_sem) = refs
     else:
-        (tr_ref, rs_ref, rl_ref, ctx_ref, pt_ref,
+        (li_ref, tr_ref, rs_ref, rl_ref, ctx_ref, pt_ref,
          q_ref, kv_k_hbm, kv_v_hbm, out_ref, k_buf, v_buf, k_sem,
          v_sem) = refs
         ks_ref = vs_ref = None
@@ -94,11 +95,12 @@ def _ragged_kernel(
     k0 = pl.program_id(1)
     g, d, tq = group, head_dim, tile_q
     chunk = chunk_pages * page_size
-    num_phys = kv_k_hbm.shape[0]
+    li = li_ref[0]
+    num_phys = kv_k_hbm.shape[1]
     # rows each page occupies in HBM/VMEM (int4 packs 2 tokens per byte
     # along this axis; positions unpack back in order, so the causal
     # key_pos math below is untouched)
-    page_rows = kv_k_hbm.shape[1]
+    page_rows = kv_k_hbm.shape[2]
 
     r = tr_ref[t]
     ctx = ctx_ref[r]
@@ -114,12 +116,12 @@ def _ragged_kernel(
             lp = jnp.minimum(ci * chunk_pages + p, max_pages - 1)
             phys = jnp.minimum(pt_ref[r, lp], num_phys - 1)
             pltpu.make_async_copy(
-                kv_k_hbm.at[phys, :, pl.ds(k0 * d, d)],
+                kv_k_hbm.at[li, phys, :, pl.ds(k0 * d, d)],
                 k_buf.at[slot, pl.ds(p * page_rows, page_rows)],
                 k_sem.at[slot, p],
             ).start()
             pltpu.make_async_copy(
-                kv_v_hbm.at[phys, :, pl.ds(k0 * d, d)],
+                kv_v_hbm.at[li, phys, :, pl.ds(k0 * d, d)],
                 v_buf.at[slot, pl.ds(p * page_rows, page_rows)],
                 v_sem.at[slot, p],
             ).start()
@@ -129,12 +131,12 @@ def _ragged_kernel(
             lp = jnp.minimum(ci * chunk_pages + p, max_pages - 1)
             phys = jnp.minimum(pt_ref[r, lp], num_phys - 1)
             pltpu.make_async_copy(
-                kv_k_hbm.at[phys, :, pl.ds(k0 * d, d)],
+                kv_k_hbm.at[li, phys, :, pl.ds(k0 * d, d)],
                 k_buf.at[slot, pl.ds(p * page_rows, page_rows)],
                 k_sem.at[slot, p],
             ).wait()
             pltpu.make_async_copy(
-                kv_v_hbm.at[phys, :, pl.ds(k0 * d, d)],
+                kv_v_hbm.at[li, phys, :, pl.ds(k0 * d, d)],
                 v_buf.at[slot, pl.ds(p * page_rows, page_rows)],
                 v_sem.at[slot, p],
             ).wait()
@@ -227,8 +229,8 @@ def _ragged_kernel(
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def ragged_paged_attention_pallas(
     q: jax.Array,  # [N, H, D] flat packed tokens (rope applied)
-    kv_k_layer: jax.Array,  # [num_pages, page_size, KH, D]
-    kv_v_layer: jax.Array,
+    kv_k_layer,  # kv_quant.KVLayer: whole pool + layer index
+    kv_v_layer,
     page_tables: jax.Array,  # [R, max_pages] int32
     row_starts: jax.Array,  # [R] int32, ascending, TQ-aligned
     row_lens: jax.Array,  # [R] int32
@@ -238,18 +240,17 @@ def ragged_paged_attention_pallas(
 ) -> jax.Array:
     """Ragged flash attention over paged KV; returns [N, H, D] (q.dtype).
     Rows outside every [row_start, row_start+row_len) span return finite
-    garbage — the caller only reads real rows. `kv_k_layer`/`kv_v_layer`
-    may be per-layer QuantKV stores (ops/kv_quant.py): the int8/int4 pages
+    garbage — the caller only reads real rows. The pools may be QuantKV
+    stores (ops/kv_quant.py): the int8/int4 pages
     DMA at their packed width and dequantize inside the VMEM window, with
     the per-page-per-head scales scalar-prefetched beside the page
     tables."""
     from .kv_quant import kernel_operands
 
     N, H, D = q.shape
-    kv_k_raw, kv_v_raw, rows, page_size, kv_bits, scale_prefetch = (
-        kernel_operands(kv_k_layer, kv_v_layer)
+    kv_k_pool, kv_v_pool, li, KH, rows, page_size, kv_bits, scale_prefetch = (
+        kernel_operands(kv_k_layer, kv_v_layer, D)
     )
-    num_pages, _, KH, _ = kv_k_raw.shape
     G = H // KH
     max_pages = page_tables.shape[1]
     tile_q = ragged_tile_q(q.dtype)
@@ -284,14 +285,14 @@ def ragged_paged_attention_pallas(
         .transpose(0, 2, 1, 3, 4)
         .reshape(num_tiles, KH, tile_q, G * D)
     )
-    # flatten pages' minor dims in XLA (contiguous bitcast) — Mosaic cannot
-    # merge minor dims in-register. Quantized stores DMA their PACKED q
-    # bytes (int4: half the sublane rows); the f32 scales join the scalar
-    # prefetch operands right after the page tables (kernel_operands is
-    # the one spelling of this contract across all three kernels).
-    kv_k_flat = kv_k_raw.reshape(num_pages, rows, KH * D)
-    kv_v_flat = kv_v_raw.reshape(num_pages, rows, KH * D)
+    # the pool goes in as it lies (lane-dense: Mosaic cannot merge minor
+    # dims in-register, and an XLA reshape of it is a pool-sized copy).
+    # Quantized stores DMA their PACKED q bytes (int4: half the sublane
+    # rows); the f32 scales join the scalar prefetch operands right after
+    # the page tables (kernel_operands is the one spelling of this
+    # contract across all four kernels).
     prefetch = [
+        li,
         tile_rows,
         row_starts.astype(jnp.int32),
         row_lens.astype(jnp.int32),
@@ -312,8 +313,8 @@ def ragged_paged_attention_pallas(
             (1, 1, tile_q, G * D), lambda t, k0, *_: (t, k0, 0, 0)
         ),
         scratch_shapes=[
-            pltpu.VMEM((2, chunk_pages * rows, D), kv_k_flat.dtype),
-            pltpu.VMEM((2, chunk_pages * rows, D), kv_v_flat.dtype),
+            pltpu.VMEM((2, chunk_pages * rows, D), kv_k_pool.dtype),
+            pltpu.VMEM((2, chunk_pages * rows, D), kv_v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, chunk_pages)),
             pltpu.SemaphoreType.DMA((2, chunk_pages)),
         ],
@@ -342,8 +343,8 @@ def ragged_paged_attention_pallas(
     )(
         *prefetch,
         q_g,
-        kv_k_flat,
-        kv_v_flat,
+        kv_k_pool,
+        kv_v_pool,
     )
     # [num_tiles, KH, TQ, G*D] -> [N, H, D]
     return (

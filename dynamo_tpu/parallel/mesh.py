@@ -128,9 +128,11 @@ class LlamaShardings:
         )
 
     def kv_sharding(self) -> NamedSharding:
-        # [layers, pages, page_size, kv_heads, head_dim]: kv heads over tp;
-        # layers over pp when pipelining (each stage owns its layers' pool)
-        return NamedSharding(self.mesh, P(self._pp, None, None, TP_AXIS, None))
+        # [layers, pages, page_size, kv_heads*head_dim]: the lane axis over
+        # tp in contiguous blocks of (kv_heads/tp)*head_dim, so a shard
+        # holds whole heads (kv_heads % tp == 0); layers over pp when
+        # pipelining (each stage owns its layers' pool)
+        return NamedSharding(self.mesh, P(self._pp, None, None, TP_AXIS))
 
     def replicated(self) -> NamedSharding:
         return NamedSharding(self.mesh, P())
@@ -172,7 +174,7 @@ class DpAttentionShardings(MoeShardings):
     dispatch keeps its all-to-all over the same axis."""
 
     def kv_sharding(self) -> NamedSharding:
-        return NamedSharding(self.mesh, P(self._pp, EP_AXIS, None, TP_AXIS, None))
+        return NamedSharding(self.mesh, P(self._pp, EP_AXIS, None, TP_AXIS))
 
 
 def shard_params(params: dict, shardings) -> dict:

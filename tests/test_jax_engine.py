@@ -739,7 +739,7 @@ def test_gptoss_shaped_registry_resolves_and_steps():
         num_experts_per_tok=2, dtype=jnp.float32,
     )
     p = moe.init_params(small, jax.random.PRNGKey(0))
-    kv_k = jnp.zeros((1, 8, 8, 2, 16), jnp.float32)
+    kv_k = jnp.zeros((1, 8, 8, 2 * 16), jnp.float32)
     kv_v = jnp.zeros_like(kv_k)
     logits, _, _ = moe.decode_forward(
         p, small, jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.int32),
@@ -750,9 +750,9 @@ def test_gptoss_shaped_registry_resolves_and_steps():
 
 def test_kv_headwise_shard_guard():
     """The per-shard multi-host KV transfer can only reassemble pools
-    host-sharded on the kv-head axis; any other host-sharded axis must be
-    detected so the engine falls back to the inline allgather transfer
-    instead of silently corrupting KV (advisor r3 finding)."""
+    host-sharded on the lane axis (whole kv heads); any other host-sharded
+    axis must be detected so the engine falls back to the inline allgather
+    transfer instead of silently corrupting KV (advisor r3 finding)."""
     from types import SimpleNamespace
 
     import jax
@@ -764,14 +764,14 @@ def test_kv_headwise_shard_guard():
 
     devs = np.array(jax.devices()[:4]).reshape(2, 2)
     mesh = Mesh(devs, ("dp", "tp"))
-    pool = jnp.zeros((2, 8, 4, 4, 8), jnp.float32)  # [L, pages, page, KH, D]
+    pool = jnp.zeros((2, 8, 4, 4 * 8), jnp.float32)  # [L, pages, page, KH*D]
 
     def check(spec):
         arr = jax.device_put(pool, NamedSharding(mesh, spec))
         return JaxEngine._kv_headwise_shards_ok(SimpleNamespace(kv_k=arr))
 
-    assert check(P(None, None, None, "tp", None))  # kv-head sharded: ok
-    assert check(P(None, None, None, ("dp", "tp"), None))  # both axes on KH: ok
+    assert check(P(None, None, None, "tp"))  # kv-head blocks sharded: ok
+    assert check(P(None, None, None, ("dp", "tp")))  # both axes on KH*D: ok
     assert check(P())  # fully replicated: ok
-    assert not check(P(None, "dp", None, "tp", None))  # pages sharded: reject
-    assert not check(P("tp", None, None, None, None))  # layers sharded: reject
+    assert not check(P(None, "dp", None, "tp"))  # pages sharded: reject
+    assert not check(P("tp", None, None, None))  # layers sharded: reject

@@ -117,7 +117,7 @@ def _quant_block(seed, mode="int8"):
     """One packed quantized block's (k, v) rows [L, PAGE_BYTES] uint8,
     produced by the SAME host layout the engine's offload gather uses."""
     from dynamo_tpu.ops.kv_quant import (
-        alloc_kv_store, host_pack_pages, kv_write,
+        alloc_kv_store, extract_pages, host_pack_pages, kv_write,
     )
 
     L, ps, KH, D = BLOCK_SHAPE
@@ -132,9 +132,93 @@ def _quant_block(seed, mode="int8"):
         st_v = kv_write(st_v, li, phys, offs,
                         jnp.asarray(r.randn(ps, KH, D).astype(np.float32)))
     ids = jnp.asarray([1])
-    ex_k = jax.tree.map(lambda a: a[:, ids], st_k)
-    ex_v = jax.tree.map(lambda a: a[:, ids], st_v)
+    ex_k = extract_pages(st_k, ids, KH)
+    ex_v = extract_pages(st_v, ids, KH)
     return host_pack_pages(ex_k)[:, 0], host_pack_pages(ex_v)[:, 0]
+
+
+# --------------------------------------------------------------------- #
+# The lane-dense pool (PR 26): [L, pages, rows, KH*D] in HBM, while every
+# tier and wire hop still carries pages as [L, n, rows, KH, D] — the same
+# bytes in the same order. A page written by the model's scatter must come
+# back identical by every way out of the pool and back in.
+# --------------------------------------------------------------------- #
+
+
+def _via_accessor(k_pages, v_pages, tmp_path):
+    return k_pages, v_pages
+
+
+def _via_kvbm_offload_onboard(k_pages, v_pages, tmp_path):
+    """G2 store -> eviction to the G3 disk tier -> load (onboard)."""
+    mgr = KvBlockManager(
+        KvbmConfig(host_blocks=1, disk_blocks=4, disk_path=str(tmp_path / "g3")),
+        k_pages.shape[:1] + k_pages.shape[2:], np.float32,
+    )
+    for i in range(k_pages.shape[1]):
+        mgr.store(100 + i, k_pages[:, i], v_pages[:, i])
+    assert len(mgr.disk) == k_pages.shape[1] - 1
+    k_np, v_np = mgr.load_blocks([100 + i for i in range(k_pages.shape[1])])
+    return np.stack(k_np, axis=1), np.stack(v_np, axis=1)
+
+
+def _via_kv_transfer_payload(k_pages, v_pages, tmp_path):
+    """The disagg wire: header + raw bytes, through msgpack."""
+    import msgpack
+
+    from dynamo_tpu.llm.disagg import pack_kv_payload, unpack_kv_payload
+
+    payload = pack_kv_payload(k_pages, v_pages, 3 * 4, 4)
+    assert payload["shape"] == [2, 3, 4, 2, 4]  # [L, n, page, KH, D] as ever
+    wire = msgpack.unpackb(msgpack.packb(payload, use_bin_type=True), raw=False)
+    k, v, _ = unpack_kv_payload(wire)
+    return k, v
+
+
+@pytest.mark.parametrize(
+    "hop", [_via_accessor, _via_kvbm_offload_onboard, _via_kv_transfer_payload],
+    ids=["accessor", "kvbm_offload_onboard", "kv_transfer_payload"],
+)
+def test_page_written_by_the_scatter_reads_back_identically(hop, tmp_path):
+    from dynamo_tpu.ops.kv_quant import (
+        alloc_kv_store, extract_pages, gather_dequant, inject_pages,
+        kv_layer, kv_write,
+    )
+
+    L, ps, KH, D = BLOCK_SHAPE
+    r = np.random.RandomState(7)
+    vals = r.randn(2, L, 3 * ps, KH, D).astype(np.float32)  # K/V, 3 pages
+    pools = [alloc_kv_store(L, 8, ps, KH, D, jnp.float32, "none") for _ in "kv"]
+    assert pools[0].shape == (L, 8, ps, KH * D)
+    pages = np.array([5, 2, 6], np.int32)
+    phys = jnp.asarray(np.repeat(pages, ps))
+    offs = jnp.asarray(np.tile(np.arange(ps, dtype=np.int32), 3))
+    for li in range(L):  # the model's scatter, layer by layer
+        pools = [
+            kv_write(pool, li, phys, offs, jnp.asarray(vals[i, li]))
+            for i, pool in enumerate(pools)
+        ]
+    want = vals.reshape(2, L, 3, ps, KH, D)
+    # the XLA reference path's accessor, per layer
+    for li in range(L):
+        got = gather_dequant(kv_layer(pools[0], li), jnp.asarray(pages), D)
+        np.testing.assert_array_equal(np.asarray(got), want[0, li])
+    # out of the pool: pages as the tiers and the wire carry them
+    k_pages = np.asarray(extract_pages(pools[0], jnp.asarray(pages), KH))
+    v_pages = np.asarray(extract_pages(pools[1], jnp.asarray(pages), KH))
+    assert k_pages.shape == (L, 3, ps, KH, D)
+    assert k_pages.tobytes() == want[0].tobytes()
+    k_back, v_back = hop(k_pages, v_pages, tmp_path)
+    # and back into another pool, at other pages
+    dest = jnp.asarray([1, 7, 3])
+    fresh = [alloc_kv_store(L, 8, ps, KH, D, jnp.float32, "none") for _ in "kv"]
+    k_new = inject_pages(fresh[0], dest, jnp.asarray(k_back))
+    v_new = inject_pages(fresh[1], dest, jnp.asarray(v_back))
+    np.testing.assert_array_equal(np.asarray(k_new[:, dest]), np.asarray(pools[0][:, pages]))
+    np.testing.assert_array_equal(np.asarray(v_new[:, dest]), np.asarray(pools[1][:, pages]))
+    np.testing.assert_array_equal(
+        np.asarray(extract_pages(v_new, dest, KH)), want[1]
+    )
 
 
 @pytest.mark.parametrize("mode", ["int8", "int4"])

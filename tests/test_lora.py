@@ -91,11 +91,11 @@ def _mk_ragged_pack(rows, page_size=PAGE, seed=9):
     ) + 1
     pages = 1 + R * max_pages  # page 0 = scratch
     kv_k = jnp.asarray(
-        rng.randn(c.num_layers, pages, page_size, c.num_kv_heads,
-                  c.head_dim).astype(np.float32))
+        rng.randn(c.num_layers, pages, page_size,
+                  c.num_kv_heads * c.head_dim).astype(np.float32))
     kv_v = jnp.asarray(
-        rng.randn(c.num_layers, pages, page_size, c.num_kv_heads,
-                  c.head_dim).astype(np.float32))
+        rng.randn(c.num_layers, pages, page_size,
+                  c.num_kv_heads * c.head_dim).astype(np.float32))
     pt = np.arange(1, pages, dtype=np.int32).reshape(R, max_pages)
     BIG = pt.shape[1] * page_size  # pad positions -> scratch page route
     tokens = np.zeros(N, np.int32)

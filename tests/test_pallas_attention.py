@@ -12,6 +12,8 @@ import pytest
 from dynamo_tpu.ops import paged_attention as ref_ops
 from dynamo_tpu.ops.pallas_paged_attention import paged_attention_decode_pallas
 
+from .utils import kv_layer_case as L
+
 
 def _mk_case(B=4, H=8, KH=4, D=32, pages=16, page_size=8, max_pages=6, seed=0):
     rng = np.random.RandomState(seed)
@@ -34,10 +36,10 @@ def test_pallas_matches_xla(seed):
 
     os.environ["DYNAMO_TPU_PAGED_ATTN"] = "xla"
     try:
-        want = ref_ops.paged_attention_decode(q, kv_k, kv_v, pt, seq_lens)
+        want = ref_ops.paged_attention_decode(q, L(kv_k), L(kv_v), pt, seq_lens)
     finally:
         os.environ.pop("DYNAMO_TPU_PAGED_ATTN", None)
-    got = paged_attention_decode_pallas(q, kv_k, kv_v, pt, seq_lens, interpret=True)
+    got = paged_attention_decode_pallas(q, L(kv_k), L(kv_v), pt, seq_lens, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-3, atol=2e-3)
 
 
@@ -48,10 +50,10 @@ def test_pallas_partial_page_and_len1():
 
     os.environ["DYNAMO_TPU_PAGED_ATTN"] = "xla"
     try:
-        want = ref_ops.paged_attention_decode(q, kv_k, kv_v, pt, seq_lens)
+        want = ref_ops.paged_attention_decode(q, L(kv_k), L(kv_v), pt, seq_lens)
     finally:
         os.environ.pop("DYNAMO_TPU_PAGED_ATTN", None)
-    got = paged_attention_decode_pallas(q, kv_k, kv_v, pt, seq_lens, interpret=True)
+    got = paged_attention_decode_pallas(q, L(kv_k), L(kv_v), pt, seq_lens, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-3, atol=2e-3)
 
 
@@ -79,10 +81,10 @@ def test_pallas_prefill_matches_xla(T, start, real):
     q, kv_k, kv_v, pt, s, total = _mk_prefill_case(T=T, start=start, real=real, seed=T + start)
     positions = jnp.asarray(np.arange(s, s + T), jnp.int32)
     want = ref_ops.prefill_attention(
-        q, None, None, kv_k, kv_v, positions, pt, jnp.asarray(s, jnp.int32)
+        q, None, None, L(kv_k), L(kv_v), positions, pt, jnp.asarray(s, jnp.int32)
     )
     got = paged_prefill_attention_pallas(
-        q, kv_k, kv_v, pt, jnp.asarray(s, jnp.int32), jnp.asarray(total, jnp.int32),
+        q, L(kv_k), L(kv_v), pt, jnp.asarray(s, jnp.int32), jnp.asarray(total, jnp.int32),
         interpret=True,
     )
     # only the real (unpadded) rows must match; padded rows are discarded.
@@ -106,10 +108,10 @@ def test_pallas_prefill_bf16_gqa():
     start = 32
     positions = jnp.asarray(np.arange(start, start + T), jnp.int32)
     want = ref_ops.prefill_attention(
-        q, None, None, kv_k, kv_v, positions, pt, jnp.asarray(start, jnp.int32)
+        q, None, None, L(kv_k), L(kv_v), positions, pt, jnp.asarray(start, jnp.int32)
     )
     got = paged_prefill_attention_pallas(
-        q, kv_k, kv_v, pt, jnp.asarray(start, jnp.int32),
+        q, L(kv_k), L(kv_v), pt, jnp.asarray(start, jnp.int32),
         jnp.asarray(start + T, jnp.int32), interpret=True,
     )
     np.testing.assert_allclose(
@@ -129,10 +131,10 @@ def test_pallas_bf16_gqa():
 
     os.environ["DYNAMO_TPU_PAGED_ATTN"] = "xla"
     try:
-        want = ref_ops.paged_attention_decode(q, kv_k, kv_v, pt, seq_lens)
+        want = ref_ops.paged_attention_decode(q, L(kv_k), L(kv_v), pt, seq_lens)
     finally:
         os.environ.pop("DYNAMO_TPU_PAGED_ATTN", None)
-    got = paged_attention_decode_pallas(q, kv_k, kv_v, pt, seq_lens, interpret=True)
+    got = paged_attention_decode_pallas(q, L(kv_k), L(kv_v), pt, seq_lens, interpret=True)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32), rtol=5e-2, atol=5e-2
     )
@@ -174,7 +176,7 @@ def test_mixed_xla_equals_written_pool_oracle():
     loc_k = jnp.asarray(rng.randn(B, K, KH, D), jnp.float32)
     loc_v = jnp.asarray(rng.randn(B, K, KH, D), jnp.float32)
 
-    got = _mixed_reference(q, kv_k, kv_v, pt, pool_lens, loc_k, loc_v, jnp.int32(step))
+    got = _mixed_reference(q, L(kv_k), L(kv_v), pt, pool_lens, loc_k, loc_v, jnp.int32(step))
 
     # oracle: scatter local entries 0..step at positions pool_lens+j, then
     # classic decode attention with seq_lens = pool_lens + step + 1
@@ -190,7 +192,7 @@ def test_mixed_xla_equals_written_pool_oracle():
     os.environ["DYNAMO_TPU_PAGED_ATTN"] = "xla"
     try:
         want = ref_ops.paged_attention_decode(
-            q, jnp.asarray(kv_k_w), jnp.asarray(kv_v_w), pt,
+            q, L(jnp.asarray(kv_k_w)), L(jnp.asarray(kv_v_w)), pt,
             pool_lens + step + 1,
         )
     finally:
@@ -210,11 +212,98 @@ def test_fused_local_kernel_matches_xla(step):
     loc_k = jnp.asarray(rng.randn(B, K, KH, D), jnp.float32)
     loc_v = jnp.asarray(rng.randn(B, K, KH, D), jnp.float32)
     pool_lens = jnp.asarray([1, 9, 17, 40], jnp.int32)
-    want = _mixed_reference(q, kv_k, kv_v, pt, pool_lens, loc_k, loc_v, jnp.int32(step))
+    want = _mixed_reference(q, L(kv_k), L(kv_v), pt, pool_lens, loc_k, loc_v, jnp.int32(step))
 
     from dynamo_tpu.ops.pallas_paged_attention import paged_attention_decode_pallas_local
 
     got = paged_attention_decode_pallas_local(
-        q, kv_k, kv_v, pt, pool_lens, loc_k, loc_v, jnp.int32(step), interpret=True
+        q, L(kv_k), L(kv_v), pt, pool_lens, loc_k, loc_v, jnp.int32(step), interpret=True
     )
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-3, atol=2e-3)
+
+
+# --------------------------------------------------------------------- #
+# whole pool + layer index (PR 26): the kernels are handed the pool as it
+# lies in HBM, [L, pages, rows, KH*D], and DMA pool[li, page]
+# --------------------------------------------------------------------- #
+
+
+def _by_layer_decode(li, num_layers):
+    q, kv_k, kv_v, pt, seq_lens = _mk_case(seed=21)
+    want = _xla(ref_ops.paged_attention_decode, q, L(kv_k), L(kv_v), pt, seq_lens)
+    got = paged_attention_decode_pallas(
+        q, L(kv_k, li, num_layers), L(kv_v, li, num_layers, seed=1), pt,
+        seq_lens, interpret=True,
+    )
+    return got, want, slice(None)
+
+
+def _by_layer_decode_local(li, num_layers):
+    from dynamo_tpu.ops.pallas_paged_attention import paged_attention_decode_pallas_local
+
+    q, kv_k, kv_v, pt, _ = _mk_case(B=4, seed=22)
+    rng = np.random.RandomState(23)
+    loc_k = jnp.asarray(rng.randn(4, 8, *kv_k.shape[2:]), jnp.float32)
+    loc_v = jnp.asarray(rng.randn(4, 8, *kv_k.shape[2:]), jnp.float32)
+    pool_lens = jnp.asarray([1, 9, 17, 40], jnp.int32)
+    want = _mixed_reference(q, L(kv_k), L(kv_v), pt, pool_lens, loc_k, loc_v, jnp.int32(3))
+    got = paged_attention_decode_pallas_local(
+        q, L(kv_k, li, num_layers), L(kv_v, li, num_layers, seed=1), pt,
+        pool_lens, loc_k, loc_v, jnp.int32(3), interpret=True,
+    )
+    return got, want, slice(None)
+
+
+def _by_layer_prefill(li, num_layers):
+    from dynamo_tpu.ops.pallas_prefill_attention import paged_prefill_attention_pallas
+
+    q, kv_k, kv_v, pt, s, total = _mk_prefill_case(T=128, start=64, real=100, seed=24)
+    positions = jnp.asarray(np.arange(s, s + 128), jnp.int32)
+    want = ref_ops.prefill_attention(
+        q, None, None, L(kv_k), L(kv_v), positions, pt, jnp.asarray(s, jnp.int32)
+    )
+    got = paged_prefill_attention_pallas(
+        q, L(kv_k, li, num_layers), L(kv_v, li, num_layers, seed=1), pt,
+        jnp.asarray(s, jnp.int32), jnp.asarray(total, jnp.int32), interpret=True,
+    )
+    return got, want, slice(0, 100)
+
+
+def _by_layer_ragged(li, num_layers):
+    from dynamo_tpu.ops.pallas_ragged_attention import ragged_paged_attention_pallas
+
+    from .test_ragged_attention import MIX, _mk_ragged_case
+
+    (q, kv_k, kv_v, pt, rs, rl, cl, starts, lens, _N) = _mk_ragged_case(MIX, seed=25)
+    want = ref_ops.ragged_attention_reference(q, L(kv_k), L(kv_v), pt, rs, rl, cl)
+    got = ragged_paged_attention_pallas(
+        q, L(kv_k, li, num_layers), L(kv_v, li, num_layers, seed=1), pt,
+        rs, rl, cl, interpret=True,
+    )
+    real = np.concatenate([np.arange(s, s + n) for s, n in zip(starts, lens)])
+    return got, want, real
+
+
+def _xla(fn, *args):
+    import os
+
+    os.environ["DYNAMO_TPU_PAGED_ATTN"] = "xla"
+    try:
+        return fn(*args)
+    finally:
+        os.environ.pop("DYNAMO_TPU_PAGED_ATTN", None)
+
+
+@pytest.mark.parametrize("li", [0, 2, 4], ids=["first", "middle", "last"])
+@pytest.mark.parametrize(
+    "case",
+    [_by_layer_decode, _by_layer_decode_local, _by_layer_prefill, _by_layer_ragged],
+    ids=["decode", "decode_local", "prefill", "ragged"],
+)
+def test_kernels_read_the_whole_pool_by_layer(case, li):
+    """Every kernel, handed a five-layer pool whose other layers hold
+    noise, agrees with the XLA reference of that layer alone."""
+    got, want, rows = case(li, 5)
+    np.testing.assert_allclose(
+        np.asarray(got)[rows], np.asarray(want)[rows], rtol=2e-3, atol=2e-3
+    )

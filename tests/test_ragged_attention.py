@@ -19,6 +19,8 @@ from dynamo_tpu.ops.pallas_ragged_attention import (
     ragged_tile_q,
 )
 
+from .utils import kv_layer_case as L
+
 
 def _pack_rows(rows, tile_q, R_pad=None):
     """rows = [(row_len, ctx_len)] -> (row_starts, row_lens, ctx_lens, N)
@@ -94,9 +96,9 @@ def test_ragged_kernel_matches_reference(rows, name):
     (q, kv_k, kv_v, pt, rs, rl, cl, starts, lens, _N) = _mk_ragged_case(
         rows, seed=len(rows)
     )
-    want = ref_ops.ragged_attention_reference(q, kv_k, kv_v, pt, rs, rl, cl)
+    want = ref_ops.ragged_attention_reference(q, L(kv_k), L(kv_v), pt, rs, rl, cl)
     got = ragged_paged_attention_pallas(
-        q, kv_k, kv_v, pt, rs, rl, cl, interpret=True
+        q, L(kv_k), L(kv_v), pt, rs, rl, cl, interpret=True
     )
     _assert_real_rows_close(got, want, starts, lens, rtol=2e-3, atol=2e-3)
 
@@ -107,9 +109,9 @@ def test_ragged_kernel_gqa_group_sizes(gqa):
     (q, kv_k, kv_v, pt, rs, rl, cl, starts, lens, _N) = _mk_ragged_case(
         MIX, H=H, KH=KH, seed=H * 7 + KH
     )
-    want = ref_ops.ragged_attention_reference(q, kv_k, kv_v, pt, rs, rl, cl)
+    want = ref_ops.ragged_attention_reference(q, L(kv_k), L(kv_v), pt, rs, rl, cl)
     got = ragged_paged_attention_pallas(
-        q, kv_k, kv_v, pt, rs, rl, cl, interpret=True
+        q, L(kv_k), L(kv_v), pt, rs, rl, cl, interpret=True
     )
     _assert_real_rows_close(got, want, starts, lens, rtol=2e-3, atol=2e-3)
 
@@ -120,9 +122,9 @@ def test_ragged_kernel_bf16_and_padding_rows():
     (q, kv_k, kv_v, pt, rs, rl, cl, starts, lens, _N) = _mk_ragged_case(
         [(20, 5), (1, 33), (3, 0)], seed=9, R_pad=8, dtype=jnp.bfloat16
     )
-    want = ref_ops.ragged_attention_reference(q, kv_k, kv_v, pt, rs, rl, cl)
+    want = ref_ops.ragged_attention_reference(q, L(kv_k), L(kv_v), pt, rs, rl, cl)
     got = ragged_paged_attention_pallas(
-        q, kv_k, kv_v, pt, rs, rl, cl, interpret=True
+        q, L(kv_k), L(kv_v), pt, rs, rl, cl, interpret=True
     )
     _assert_real_rows_close(got, want, starts, lens, rtol=5e-2, atol=5e-2)
 
@@ -148,9 +150,9 @@ def test_ragged_fuzz_parity(seed):
     (q, kv_k, kv_v, pt, rs, rl, cl, starts, lens, _N) = _mk_ragged_case(
         rows, H=H, KH=KH, page_size=page_size, seed=seed, R_pad=n_rows + 2
     )
-    want = ref_ops.ragged_attention_reference(q, kv_k, kv_v, pt, rs, rl, cl)
+    want = ref_ops.ragged_attention_reference(q, L(kv_k), L(kv_v), pt, rs, rl, cl)
     got = ragged_paged_attention_pallas(
-        q, kv_k, kv_v, pt, rs, rl, cl, interpret=True
+        q, L(kv_k), L(kv_v), pt, rs, rl, cl, interpret=True
     )
     _assert_real_rows_close(got, want, starts, lens, rtol=2e-3, atol=2e-3)
 
@@ -166,12 +168,12 @@ def test_reference_prefill_row_equals_batched_prefill_op():
     (q, kv_k, kv_v, pt, rs, rl, cl, starts, lens, _N) = _mk_ragged_case(
         rows, seed=3
     )
-    ref = ref_ops.ragged_attention_reference(q, kv_k, kv_v, pt, rs, rl, cl)
+    ref = ref_ops.ragged_attention_reference(q, L(kv_k), L(kv_v), pt, rs, rl, cl)
     T, ctx = rows[0]
     qb = q[starts[0] : starts[0] + T][None]
     positions = jnp.asarray(np.arange(ctx, ctx + T))[None]
     want = ref_ops.prefill_attention_batched(
-        qb, kv_k, kv_v, positions, pt[0:1],
+        qb, L(kv_k), L(kv_v), positions, pt[0:1],
         jnp.asarray([ctx + T]), jnp.asarray([ctx]),
     )
     np.testing.assert_allclose(
@@ -185,12 +187,12 @@ def test_reference_decode_row_equals_decode_op():
     (q, kv_k, kv_v, pt, rs, rl, cl, starts, lens, _N) = _mk_ragged_case(
         rows, seed=3
     )
-    ref = ref_ops.ragged_attention_reference(q, kv_k, kv_v, pt, rs, rl, cl)
+    ref = ref_ops.ragged_attention_reference(q, L(kv_k), L(kv_v), pt, rs, rl, cl)
     # decode row: ctx=13, len=1 == classic decode with seq_len 14 over a
     # pool already holding the current token's KV
     qd = q[starts[1] : starts[1] + 1]
     want = ref_ops.paged_attention_decode(
-        qd, kv_k, kv_v, pt[1:2], jnp.asarray([14])
+        qd, L(kv_k), L(kv_v), pt[1:2], jnp.asarray([14])
     )
     np.testing.assert_allclose(
         np.asarray(ref)[starts[1] : starts[1] + 1], np.asarray(want),
@@ -241,7 +243,7 @@ def test_ragged_kernel_quantized_matches_oracles(mode, rows, name):
     )
     qk = _quantize_case(kv_k, kv_k.shape[1], mode)
     qv = _quantize_case(kv_v, kv_v.shape[1], mode)
-    fp_oracle = ref_ops.ragged_attention_reference(q, kv_k, kv_v, pt, rs, rl, cl)
+    fp_oracle = ref_ops.ragged_attention_reference(q, L(kv_k), L(kv_v), pt, rs, rl, cl)
     want = ref_ops.ragged_attention_reference(q, qk, qv, pt, rs, rl, cl)
     got = ragged_paged_attention_pallas(
         q, qk, qv, pt, rs, rl, cl, interpret=True
@@ -290,7 +292,7 @@ def test_ragged_quantized_fuzz_parity(mode, seed):
     )
     qk = _quantize_case(kv_k, page_size, mode)
     qv = _quantize_case(kv_v, page_size, mode)
-    fp_oracle = ref_ops.ragged_attention_reference(q, kv_k, kv_v, pt, rs, rl, cl)
+    fp_oracle = ref_ops.ragged_attention_reference(q, L(kv_k), L(kv_v), pt, rs, rl, cl)
     want = ref_ops.ragged_attention_reference(q, qk, qv, pt, rs, rl, cl)
     got = ragged_paged_attention_pallas(
         q, qk, qv, pt, rs, rl, cl, interpret=True
@@ -339,9 +341,9 @@ def test_ragged_kernel_spec_staircase_shared_tables(d):
         rows, seed=41 + d
     )
     pt = _share_sibling_tables(pt, groups)
-    want = ref_ops.ragged_attention_reference(q, kv_k, kv_v, pt, rs, rl, cl)
+    want = ref_ops.ragged_attention_reference(q, L(kv_k), L(kv_v), pt, rs, rl, cl)
     got = ragged_paged_attention_pallas(
-        q, kv_k, kv_v, pt, rs, rl, cl, interpret=True
+        q, L(kv_k), L(kv_v), pt, rs, rl, cl, interpret=True
     )
     _assert_real_rows_close(got, want, starts, lens, rtol=2e-3, atol=2e-3)
     # the staircase is real: each later sibling sees strictly more ctx,
@@ -364,9 +366,9 @@ def test_ragged_kernel_spec_rows_blend_with_prefill_and_decode():
         rows, seed=77, R_pad=len(rows) + 2
     )
     pt = _share_sibling_tables(pt, groups)
-    want = ref_ops.ragged_attention_reference(q, kv_k, kv_v, pt, rs, rl, cl)
+    want = ref_ops.ragged_attention_reference(q, L(kv_k), L(kv_v), pt, rs, rl, cl)
     got = ragged_paged_attention_pallas(
-        q, kv_k, kv_v, pt, rs, rl, cl, interpret=True
+        q, L(kv_k), L(kv_v), pt, rs, rl, cl, interpret=True
     )
     _assert_real_rows_close(got, want, starts, lens, rtol=2e-3, atol=2e-3)
 
@@ -380,7 +382,7 @@ def test_ragged_kernel_spec_staircase_quantized(mode):
     pt = _share_sibling_tables(pt, groups)
     qk = _quantize_case(kv_k, kv_k.shape[1], mode)
     qv = _quantize_case(kv_v, kv_v.shape[1], mode)
-    fp_oracle = ref_ops.ragged_attention_reference(q, kv_k, kv_v, pt, rs, rl, cl)
+    fp_oracle = ref_ops.ragged_attention_reference(q, L(kv_k), L(kv_v), pt, rs, rl, cl)
     want = ref_ops.ragged_attention_reference(q, qk, qv, pt, rs, rl, cl)
     got = ragged_paged_attention_pallas(
         q, qk, qv, pt, rs, rl, cl, interpret=True
@@ -416,7 +418,7 @@ def test_decode_kernels_quantized_match_oracles(mode):
     os.environ["DYNAMO_TPU_PAGED_ATTN"] = "xla"
     try:
         ref_q = ref_ops.paged_attention_decode(q, qk, qv, tables, seq_lens)
-        ref_fp = ref_ops.paged_attention_decode(q, kv_k, kv_v, tables, seq_lens)
+        ref_fp = ref_ops.paged_attention_decode(q, L(kv_k), L(kv_v), tables, seq_lens)
     finally:
         os.environ.pop("DYNAMO_TPU_PAGED_ATTN", None)
     got = paged_attention_decode_pallas(
@@ -465,7 +467,7 @@ def test_quantized_page_write_tracks_scale_growth():
         ref[t] = vals[0]
         st = kv_write(st, 0, jnp.asarray([1]), jnp.asarray([t]),
                       jnp.asarray(vals))
-    deq = np.asarray(gather_dequant(kv_layer(st, 0), jnp.asarray([1])))[0]
+    deq = np.asarray(gather_dequant(kv_layer(st, 0), jnp.asarray([1]), D))[0]
     page_amax = np.abs(ref[:4]).max(axis=(0, 2))  # [KH]
     # a couple of half-steps of the FINAL scale (requantize accumulation)
     tol = page_amax / 127 * 2.6 + 1e-6
@@ -476,7 +478,7 @@ def test_quantized_page_write_tracks_scale_growth():
     st = kv_write(st, 0, jnp.asarray(np.full(ps, 1, np.int32)),
                   jnp.asarray(np.arange(ps, dtype=np.int32)),
                   jnp.asarray(tiny))
-    deq = np.asarray(gather_dequant(kv_layer(st, 0), jnp.asarray([1])))[0]
+    deq = np.asarray(gather_dequant(kv_layer(st, 0), jnp.asarray([1]), D))[0]
     tiny_amax = np.abs(tiny).max(axis=(0, 2))
     assert np.all(
         np.abs(deq - tiny) <= (tiny_amax / 127 * 0.51 + 1e-8)[None, :, None]

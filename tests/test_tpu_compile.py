@@ -22,12 +22,14 @@ from jax.sharding import SingleDeviceSharding
 
 from dynamo_tpu.models import llama
 from dynamo_tpu.ops import paged_attention as ops
-from dynamo_tpu.ops.kv_quant import QuantKV, alloc_kv_store
+from dynamo_tpu.ops.kv_quant import QuantKV, alloc_kv_store, kv_layer
 
 # llama3-3b attention widths at the worker's default serving shape
 H, KH, D = 24, 8, 128
-PAGE, B, TABLE, POOL = 64, 64, 128, 1024
+PAGE, B, TABLE, POOL, LAYERS = 64, 64, 128, 1024, 28
 HBM_BYTES = 16 * 2**30  # one v5e chip
+# the benchmark's cell: Mixtral-8x7B widths, 2 layers, --num-pages 8192
+MIXTRAL = {"H": 32, "KH": 8, "layers": 2, "pool": 8193}
 
 
 @pytest.fixture(scope="module")
@@ -81,22 +83,28 @@ def _shapes(one_chip):
     return sds
 
 
-def _pool(sds, mode="none"):
-    """Per-layer K (or V) pool operand: fp array or QuantKV of shapes."""
+def _pool(sds, mode="none", layers=LAYERS, pool=POOL, kh=KH):
+    """K (or V) operand as the ops take it: the WHOLE lane-dense pool
+    [L, pages, rows, KH*D] (fp array or QuantKV of shapes) and a middle
+    layer's index."""
     if mode == "none":
-        return sds((POOL, PAGE, KH, D), jnp.bfloat16)
+        return kv_layer(sds((layers, pool, PAGE, kh * D), jnp.bfloat16), layers // 2)
     bits = {"int8": 8, "int4": 4}[mode]
     rows = PAGE // 2 if bits == 4 else PAGE
-    return QuantKV(
-        sds((POOL, rows, KH, D), jnp.int8), sds((POOL, KH), jnp.float32),
-        bits, PAGE,
+    return kv_layer(
+        QuantKV(
+            sds((layers, pool, rows, kh * D), jnp.int8),
+            sds((layers, pool, kh), jnp.float32), bits, PAGE,
+        ),
+        layers // 2,
     )
 
 
-def _op_cases(sds, mode):
+def _op_cases(sds, mode, H=H, KH=KH, layers=LAYERS, pool=POOL):
     """(name, fn, args) for the four serving attention ops through the
     dispatch gate, with a `mode` KV pool."""
-    k, v = _pool(sds, mode), _pool(sds, mode)
+    k = _pool(sds, mode, layers, pool, KH)
+    v = _pool(sds, mode, layers, pool, KH)
     i32 = jnp.int32
     q1 = sds((B, H, D), jnp.bfloat16)
     tables = sds((B, TABLE), i32)
@@ -136,6 +144,82 @@ def test_fp_kernels_compile_for_v5e(op, one_chip, no_persistent_cache, tpu_gate)
     assert "tpu_custom_call" in compiled.as_text(), (
         f"{op}: the gate did not put the Pallas kernel into the program"
     )
+
+
+@pytest.mark.parametrize("op", OPS)
+def test_whole_pool_kernels_compile_at_the_cell_size(
+    op, one_chip, no_persistent_cache, tpu_gate
+):
+    """Mixtral widths, 2 layers, 8,192 pages (4.3 GB of K and V): each
+    kernel takes the whole pool operand, and the program holds no
+    temporary the size of even one layer of one pool (1.07 GB): before
+    PR 26 the wrappers' slice + reshape made three such copies."""
+    fn, args = _op_cases(
+        _shapes(one_chip), "none", H=MIXTRAL["H"], KH=MIXTRAL["KH"],
+        layers=MIXTRAL["layers"], pool=MIXTRAL["pool"],
+    )[op]
+    compiled = _compile(fn, *args)
+    assert "tpu_custom_call" in compiled.as_text()
+    layer_bytes = MIXTRAL["pool"] * PAGE * MIXTRAL["KH"] * D * 2
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < layer_bytes // 8, f"{op}: {temp / 2**20:.0f} MiB of temporaries"
+
+
+def _pool_sized_ops(lowered, layer_numel):
+    """Operation names of a lowered program whose result holds at least
+    one layer of the pool, counted (stablehlo, before any backend)."""
+    import collections
+
+    import numpy as np
+    from jaxlib.mlir import ir
+
+    found = collections.Counter()
+
+    def visit(op):
+        for r in op.results:
+            if isinstance(r.type, ir.RankedTensorType) and (
+                int(np.prod(r.type.shape)) >= layer_numel
+            ):
+                found[op.name] += 1
+        return ir.WalkResult.ADVANCE
+
+    lowered.compiler_ir().operation.walk(visit)
+    return dict(found)
+
+
+@pytest.mark.parametrize("program", ("decode", "ragged"))
+def test_lowered_programs_touch_the_pool_only_to_update_it(program):
+    """Counted in the lowered text, on the CPU: the decode and the mixed
+    (ragged) step produce nothing the size of a layer of the pool but the
+    in-place KV scatters, one for K and one for V in each layer. A
+    per-layer slice or reshape of the pool would show here."""
+    cfg = llama.LlamaConfig.tiny(dtype=jnp.float32)
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    pages, page = 257, 8
+    kv = alloc_kv_store(
+        cfg.num_layers, pages, page, cfg.num_kv_heads, cfg.head_dim,
+        cfg.dtype, "none",
+    )
+    assert kv.shape == (cfg.num_layers, pages, page, cfg.num_kv_heads * cfg.head_dim)
+    i32 = jnp.int32
+    n, r = 32, 4
+    if program == "decode":
+        def step(params, kv_k, kv_v):
+            return llama.decode_forward(
+                params, cfg, jnp.zeros((r,), i32), jnp.zeros((r,), i32),
+                kv_k, kv_v, jnp.ones((r, 5), i32), jnp.ones((r,), i32),
+            )
+    else:
+        def step(params, kv_k, kv_v):
+            return llama.ragged_forward(
+                params, cfg, jnp.zeros((n,), i32), jnp.zeros((n,), i32),
+                jnp.zeros((n,), i32), kv_k, kv_v, jnp.ones((r, 5), i32),
+                jnp.arange(r, dtype=i32) * 8, jnp.ones((r,), i32),
+                jnp.zeros((r,), i32), jnp.arange(r, dtype=i32) * 8,
+            )
+    lowered = jax.jit(step, donate_argnums=(1, 2)).lower(params, kv, kv)
+    found = _pool_sized_ops(lowered, kv[0].size)
+    assert found == {"stablehlo.scatter": 2 * cfg.num_layers}, found
 
 
 @pytest.mark.parametrize("mode", ("int8", "int4"))
@@ -243,7 +327,7 @@ def test_tp4_decode_step_shards_over_a_four_chip_mesh(
         params, specs,
     )
     kv = jax.ShapeDtypeStruct(
-        (cfg.num_layers, POOL + 1, PAGE, cfg.num_kv_heads, cfg.head_dim),
+        (cfg.num_layers, POOL + 1, PAGE, cfg.num_kv_heads * cfg.head_dim),
         cfg.dtype, sharding=sh.kv_sharding(),
     )
     i32 = jnp.int32

@@ -150,3 +150,20 @@ def scrape_worker_stats(disc, predicate=None, *, namespace="dynamo",
             await drt.close()
 
     return asyncio.run(run())
+
+
+def kv_layer_case(kv, li: int = 0, num_layers: int = 1, seed: int = 0):
+    """A per-layer test case `[pages, page_size, KH, D]` as the attention
+    ops take it since PR 26: layer `li` of a lane-dense
+    `[num_layers, pages, page_size, KH*D]` pool (ops/kv_quant.KVLayer)
+    whose other layers hold noise, so a kernel that reads the wrong
+    layer cannot agree with the reference."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dynamo_tpu.ops.kv_quant import kv_layer
+
+    pages, ps, KH, D = kv.shape
+    pool = np.random.RandomState(seed).randn(num_layers, pages, ps, KH * D)
+    pool[li] = np.asarray(kv, np.float32).reshape(pages, ps, KH * D)
+    return kv_layer(jnp.asarray(pool, kv.dtype), li)
