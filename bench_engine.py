@@ -34,7 +34,7 @@ from bench import baseline_ratio, require_tpu  # noqa: E402
 
 
 def _make_engine(model: str, B: int, isl: int, osl: int, K: int, page: int = 64,
-                 pool_mode=None, unroll: int = 0, quantize=None,
+                 quantize=None,
                  num_pages: Optional[int] = None, spec=None,
                  mixed: Optional[bool] = None):
     from dynamo_tpu.engine import EngineConfig, JaxEngine
@@ -52,8 +52,6 @@ def _make_engine(model: str, B: int, isl: int, osl: int, K: int, page: int = 64,
         max_num_seqs=B,
         max_model_len=max_len,
         decode_block_steps=K,
-        decode_pool_mode=pool_mode,
-        decode_block_unroll=unroll,
         quantize=quantize,
         spec_mode=spec,
         enable_prefix_caching=True,
@@ -483,14 +481,10 @@ def main(argv: Optional[List[str]] = None):
     ap.add_argument("--isl", type=int, default=128)
     ap.add_argument("--osl", type=int, default=128)
     ap.add_argument("--block", type=int, default=16)
-    ap.add_argument("--pool-mode", choices=["scatter", "local"], default=None,
-                    help="default: auto (local on TPU, scatter on CPU)")
-    ap.add_argument("--unroll", type=int, default=0,
-                    help="0 = auto (4 under local, 1 under scatter)")
     ap.add_argument("--quantize", choices=["int8"], default=None)
     ap.add_argument("--num-pages", type=int, default=None,
                     help="KV pool size override (floored at the batch's "
-                    "working-set need) — the KV-write-strategy sweep axis")
+                    "working-set need)")
     ap.add_argument("--spec", choices=["ngram"], default=None,
                     help="speculative decoding; the steady trace becomes "
                     "repetition-heavy so acceptance is measurable")
@@ -531,7 +525,7 @@ def main(argv: Optional[List[str]] = None):
         return run_mixed_bench(args, model, vocab, B, isl, osl)
     engine = _make_engine(
         model, B, isl, osl, args.block,
-        pool_mode=args.pool_mode, unroll=args.unroll, quantize=args.quantize,
+        quantize=args.quantize,
         num_pages=args.num_pages, spec=args.spec,
     )
     rep = bool(args.spec)

@@ -42,8 +42,8 @@ OUT_DIR = os.path.join(HERE, "chiprun_out", "chip_smoke")
 # Shape space of the smoke's worker, bounded with the worker's own arguments
 # so that a cold-cache warmup (one full-depth compile per token bucket x
 # page-table rung x program kind, 12-40 s each on the chip) fits the run's
-# time limit. Everything else is the worker's default: auto pool, default
-# pool mode, mixed dispatch, full warmup. 1088 = the largest prefill chunk
+# time limit. Everything else is the worker's default: auto pool, mixed
+# dispatch, full warmup. 1088 = the largest prefill chunk
 # (1024) plus one page: the smallest context in which a prompt takes two
 # prefill chunks.
 MAX_MODEL_LEN = 1088

@@ -52,9 +52,8 @@ COMPILE_SURFACES = {
             "K": "config.decode_block_steps (fused steps)",
         },
         "warmup": True,
-        "help": "K fused decode steps over all lanes; one variant total "
-                "(two bodies: pool-local vs per-step scatter, picked by "
-                "decode_pool_mode at compile time)",
+        "help": "K fused decode steps over all lanes, each scattering its "
+                "K/V rows into the donated pool; one variant total",
     },
     "spec_block": {
         "module": "dynamo_tpu/engine/engine.py",
@@ -261,19 +260,6 @@ COMPILE_SURFACES = {
     # ----------------------------------------------------------------- #
     # ops/ — attention kernels (jit wrappers staging pallas_call bodies)
     # ----------------------------------------------------------------- #
-    "paged_attention_decode_pallas_local": {
-        "module": "dynamo_tpu/ops/pallas_paged_attention.py",
-        "kind": "jit",
-        "donate": (),
-        "static": ("interpret",),
-        "axes": {
-            "B": "caller lane count (engine: config.max_num_seqs)",
-            "pages": "caller page-table bucket",
-        },
-        "warmup": True,
-        "help": "fused decode attention merging block-local K/V with the "
-                "paged pool (decode_pool_mode=local)",
-    },
     "paged_attention_decode_pallas": {
         "module": "dynamo_tpu/ops/pallas_paged_attention.py",
         "kind": "jit",

@@ -21,27 +21,6 @@ class EngineConfig:
     # fused decode: K steps per dispatch (one host read per K*B tokens);
     # speculated tokens past a stop condition are discarded (bounded waste)
     decode_block_steps: int = 8
-    # KV-write strategy inside the fused block:
-    #   "scatter": per-step XLA scatter into the pool carried through the
-    #     scan. The chip-proven path (rounds r1-r3; chip_smoke.py, PR 21).
-    #     Compiled for a v5e at llama3-3b widths: 0.38 GiB of temporaries
-    #     beside a 1,008-page pool.
-    #   "local": pool stays READ-ONLY inside the scan; new KV accumulates
-    #     in a [K]-entry buffer merged by the fused pallas kernel
-    #     (ops/pallas_paged_attention._decode_local_kernel) and is written
-    #     once per block. Needs decode_block_unroll > 1. On the chip XLA
-    #     hoists the kernel's relayout of the loop-invariant pool out of
-    #     the scan — a second pool's worth of HBM (3.71 GiB of temporaries
-    #     at 504 pages, compiled for a v5e): with the pool auto-sized to
-    #     fill the chip its first decode block failed in the compiler
-    #     (RESOURCE_EXHAUSTED, PR 21), so the auto-sizer now halves a
-    #     "local" pool instead.
-    # DEFAULT = None = "scatter" on every platform. Which is faster at a
-    # deployment-sized pool has not been measured (ROADMAP A4): the chip
-    # cost both modes share is the decode kernel's per-layer relayout of
-    # the pool, per step under scatter and per block under local.
-    decode_pool_mode: Optional[str] = None
-    decode_block_unroll: int = 0  # 0 = auto: 4 under local, 1 under scatter
     # batched prefill: token budget per dispatch; lanes = budget // bucket
     prefill_batch_tokens: int = 1024
     max_prefill_batch: int = 8

@@ -159,12 +159,6 @@ def _auto_num_pages(params, model_cfg, config: EngineConfig,
     compile/activation workspace the post-weights snapshot can't see. In
     multihost mode the leader's result is broadcast so every process
     allocates identical KV shapes (dispatch replay requires it).
-
-    The "local" decode KV-write strategy still sizes for half the
-    budget: that left room for the hoisted relayout of the read-only
-    pool, which went with the lane-dense layout (PR 26: the kernels read
-    the pool where it lies); whether "local" now deserves the whole
-    budget, or goes, is ROADMAP C4's to measure on the chip.
     """
     dev = jax.local_devices()[0]
     util = float(os.environ.get("DYN_HBM_UTILIZATION", "0.85"))
@@ -211,16 +205,12 @@ def _auto_num_pages(params, model_cfg, config: EngineConfig,
             )
         )
         page_bytes_dev = page_bytes // _kv_shard_div(kv_sharding)
-        alloc_bytes_dev = page_bytes_dev
-        if config.decode_pool_mode == "local":
-            alloc_bytes_dev *= 2  # see the docstring: C4 decides
         free = int(limit * util) - int(in_use) - reserve
-        n = free // alloc_bytes_dev
+        n = free // page_bytes_dev
         logger.info(
             "auto-sized KV pool: %d pages (%.2f GiB resident of %.2f GiB free"
-            " per device, mode=%s)",
+            " per device)",
             n, n * page_bytes_dev / 2**30, free / 2**30,
-            config.decode_pool_mode,
         )
     if multihost:
         # every process must allocate identical KV shapes for dispatch
@@ -412,27 +402,14 @@ class JaxEngine:
         from ..ops.kv_quant import resolve_kv_quant
 
         kvq = resolve_kv_quant(config.kv_quant)
-        if (
-            config.decode_pool_mode is None or not config.decode_block_unroll
-            or config.kv_quant != kvq
-        ):
-            # "scatter" unless asked otherwise (EngineConfig docstring: on
-            # the chip "local" needs a second pool's worth of HBM and the
-            # auto-sized pool did not leave it). Resolve into a COPY — the
-            # caller's config keeps its auto sentinels. The KV quant mode
-            # (DYN_KV_QUANT) resolves here too so every later consumer
-            # (pool sizing, KVBM block layout, wire descriptors) reads one
-            # explicit spelling.
+        if config.kv_quant != kvq:
+            # The KV quant mode (DYN_KV_QUANT) resolves here, into a COPY
+            # (the caller's config keeps its auto sentinel), so every later
+            # consumer (pool sizing, KVBM block layout, wire descriptors)
+            # reads one explicit spelling.
             import dataclasses as _dc
 
-            mode = config.decode_pool_mode or "scatter"
-            config = _dc.replace(
-                config,
-                decode_pool_mode=mode,
-                decode_block_unroll=config.decode_block_unroll
-                or (4 if mode == "local" else 1),
-                kv_quant=kvq,
-            )
+            config = _dc.replace(config, kv_quant=kvq)
         if kvq != "none":
             if config.pp_size > 1 or config.sp_size > 1 or config.tp_size > 1:
                 raise ValueError(
@@ -475,11 +452,9 @@ class JaxEngine:
             )
         self.device = device_info()
         logger.info(
-            "engine device %s; attention %s (mesh %s, kv_quant %s); "
-            "decode_pool_mode %s",
+            "engine device %s; attention %s (mesh %s, kv_quant %s)",
             self.device, self.attention_impl,
             "none" if mesh is None else dict(mesh.shape), kvq,
-            config.decode_pool_mode,
         )
         key = jax.random.PRNGKey(config.seed)
         if params is None:
@@ -825,120 +800,43 @@ class JaxEngine:
         # (split inside jit, advanced key returned): an eager
         # jax.random.split per dispatch costs a host round-trip per step
         # (the round-1 ITL killer)
-        if cfg.decode_pool_mode == "local":
+        @partial(jax.jit, donate_argnums=(1, 2, 8, 9), out_shardings=decode_out_sh)
+        def decode_block(params, kv_k, kv_v, tokens, positions, seq_lens, page_tables, samp, rng, pen):
+            """K fused decode steps: sampled tokens feed the next step on
+            device — one host read per K*B tokens instead of per token.
+            Each step scatters its new K/V rows into the (donated) pool."""
+            rng, sub = jax.random.split(rng)
+            keys = jax.random.split(sub, K)
+            W = pen.shape[1]
+            B = tokens.shape[0]
 
-            @partial(jax.jit, donate_argnums=(1, 2, 8, 9), out_shardings=decode_out_sh)
-            def decode_block(params, kv_k, kv_v, tokens, positions, seq_lens, page_tables, samp, rng, pen):
-                """K fused decode steps, pool READ-ONLY inside the scan.
-
-                A per-step scatter into the pool makes XLA materialize
-                pool-sized copies (941 ms/block at 1024 pages vs 215 at 161
-                on v5e). Here new K/V accumulate in per-layer [B, K, KH, D]
-                local buffers — the fused pallas kernel merges them into the
-                flash softmax — and the pool is written ONCE per block.
-                Requires decode_block_unroll > 1 to dodge lax.scan's
-                per-iteration re-copy of closed-over HBM arrays."""
-                rng, sub = jax.random.split(rng)
-                keys = jax.random.split(sub, K)
-                B = tokens.shape[0]
-                pool_lens = jnp.maximum(seq_lens - 1, 0)
-                start_pos = positions
-                # local accumulators stay FULL precision even under a
-                # quantized pool (c.dtype == pool dtype in fp mode):
-                # quantization happens once, at the per-block pool commit
-                loc_shape = (B, K, c.num_kv_heads, c.head_dim)
-                loc_k0 = tuple(
-                    jnp.zeros(loc_shape, c.dtype) for _ in range(c.num_layers)
+            def step(carry, k):
+                tokens, positions, seq_lens, kv_k, kv_v, pen = carry
+                if cfg.pp_size > 1:
+                    # layers pipelined over pp: each step is a full
+                    # microbatch schedule (parallel/pipeline.py)
+                    logits, kv_k, kv_v = self._model.decode_forward_pp(
+                        params, c, tokens, positions, kv_k, kv_v,
+                        page_tables, seq_lens, self._mesh,
+                    )
+                else:
+                    logits, kv_k, kv_v = self._model.decode_forward(
+                        params, c, tokens, positions, kv_k, kv_v, page_tables, seq_lens
+                    )
+                plogits = penalized(logits, samp, pen)
+                nxt, lp, tid, tlp = sample_lp(
+                    plogits, samp, k, positions=positions, raw=logits
                 )
-                loc_v0 = tuple(
-                    jnp.zeros(loc_shape, c.dtype) for _ in range(c.num_layers)
+                pen = pen.at[jnp.arange(B), (positions + 1) % W].set(nxt)
+                return (
+                    (nxt, positions + 1, seq_lens + 1, kv_k, kv_v, pen),
+                    (nxt, lp, tid, tlp),
                 )
 
-                W = pen.shape[1]
-
-                def step(carry, inp):
-                    key_j, j = inp
-                    tokens, positions, seq_lens, loc_k, loc_v, pen = carry
-                    logits, loc_k, loc_v = self._model.decode_forward_local(
-                        params, c, tokens, positions, loc_k, loc_v, j,
-                        kv_k, kv_v, page_tables, pool_lens,
-                    )
-                    plogits = penalized(logits, samp, pen)
-                    nxt, lp, tid, tlp = sample_lp(
-                        plogits, samp, key_j, positions=positions, raw=logits
-                    )
-                    pen = pen.at[jnp.arange(B), (positions + 1) % W].set(nxt)
-                    return (
-                        (nxt, positions + 1, seq_lens + 1, loc_k, loc_v, pen),
-                        (nxt, lp, tid, tlp),
-                    )
-
-                (tokens, positions, seq_lens, loc_k, loc_v, pen), toks = jax.lax.scan(
-                    step,
-                    (tokens, positions, seq_lens, loc_k0, loc_v0, pen),
-                    (keys, jnp.arange(K)),
-                    unroll=min(max(cfg.decode_block_unroll, 1), K),
-                )
-                # one pool scatter for the whole block. Inactive lanes write
-                # via their SCRATCH table rows (the host keeps non-active
-                # lanes' device table rows at scratch), positions past the
-                # table route to physical page 0.
-                page_size = cfg.page_size
-                P = page_tables.shape[1]
-                pos = start_pos[:, None] + jnp.arange(K)[None, :]  # [B, K]
-                logical = jnp.minimum(pos // page_size, P - 1)
-                phys = jnp.take_along_axis(page_tables, logical, axis=1)
-                phys = jnp.where(pos < P * page_size, phys, 0)
-                offs = pos % page_size
-                from ..ops.kv_quant import kv_write_all_layers
-
-                # the decode carry patch: ONE pool write per block —
-                # quantize-on-write under DYN_KV_QUANT, one fused scatter
-                # of lane-dense rows otherwise
-                kv_k = kv_write_all_layers(kv_k, phys, offs, jnp.stack(loc_k))
-                kv_v = kv_write_all_layers(kv_v, phys, offs, jnp.stack(loc_v))
-                return toks, tokens, positions, seq_lens, kv_k, kv_v, rng, pen
-
-        else:
-
-            @partial(jax.jit, donate_argnums=(1, 2, 8, 9), out_shardings=decode_out_sh)
-            def decode_block(params, kv_k, kv_v, tokens, positions, seq_lens, page_tables, samp, rng, pen):
-                """K fused decode steps: sampled tokens feed the next step on
-                device — one host read per K*B tokens instead of per token.
-                Per-step pool scatter (best at small/medium pools; see
-                EngineConfig.decode_pool_mode for the trade-off)."""
-                rng, sub = jax.random.split(rng)
-                keys = jax.random.split(sub, K)
-                W = pen.shape[1]
-                B = tokens.shape[0]
-
-                def step(carry, k):
-                    tokens, positions, seq_lens, kv_k, kv_v, pen = carry
-                    if cfg.pp_size > 1:
-                        # layers pipelined over pp: each step is a full
-                        # microbatch schedule (parallel/pipeline.py)
-                        logits, kv_k, kv_v = self._model.decode_forward_pp(
-                            params, c, tokens, positions, kv_k, kv_v,
-                            page_tables, seq_lens, self._mesh,
-                        )
-                    else:
-                        logits, kv_k, kv_v = self._model.decode_forward(
-                            params, c, tokens, positions, kv_k, kv_v, page_tables, seq_lens
-                        )
-                    plogits = penalized(logits, samp, pen)
-                    nxt, lp, tid, tlp = sample_lp(
-                        plogits, samp, k, positions=positions, raw=logits
-                    )
-                    pen = pen.at[jnp.arange(B), (positions + 1) % W].set(nxt)
-                    return (
-                        (nxt, positions + 1, seq_lens + 1, kv_k, kv_v, pen),
-                        (nxt, lp, tid, tlp),
-                    )
-
-                (tokens, positions, seq_lens, kv_k, kv_v, pen), toks = jax.lax.scan(
-                    step, (tokens, positions, seq_lens, kv_k, kv_v, pen), keys
-                )
-                return toks, tokens, positions, seq_lens, kv_k, kv_v, rng, pen
+            (tokens, positions, seq_lens, kv_k, kv_v, pen), toks = jax.lax.scan(
+                step, (tokens, positions, seq_lens, kv_k, kv_v, pen), keys
+            )
+            return toks, tokens, positions, seq_lens, kv_k, kv_v, rng, pen
 
         self._decode_block = decode_block
 
@@ -1422,9 +1320,9 @@ class JaxEngine:
     async def _warmup_request(self, req: dict, on_item=None):
         """One warmup request to its end. An error item means a program
         did not compile or a step failed: that is a failed warmup, and the
-        worker must not go on to register and serve (on the chip the first
-        local-mode decode block ran out of HBM in the compiler while
-        warmup carried on and retried it for a quarter of an hour)."""
+        worker must not go on to register and serve (on the chip a decode
+        block once ran out of HBM in the compiler while warmup carried on
+        and retried it for a quarter of an hour)."""
         async for item in self.generate(req, Context()):
             if item.get("event") == "error":
                 raise RuntimeError(
@@ -1909,8 +1807,6 @@ class JaxEngine:
             return "LoRA is incompatible with speculative decoding (spec_mode)"
         if cfg.pp_size > 1 or cfg.sp_size > 1:
             return "LoRA is not supported on pp/sp layouts yet"
-        # (decode_pool_mode == "local" needs no rejection: the lora block
-        # variant uses per-step pool scatter regardless of pool mode)
         if req.guided:
             return "guided decoding with a LoRA adapter is not supported yet"
         if req.multimodal:
@@ -2323,11 +2219,12 @@ class JaxEngine:
             "kv_pool_bytes": kv_nbytes,
             # bring-up surface (chip_smoke.py reads these off the metrics
             # topic): the device as JAX reports it, the implementation each
-            # attention op resolved to, the resolved pool mode, and where
-            # the bytes are
+            # attention op resolved to, and where the bytes are
             "device": self.device,
             "attention_impl": self.attention_impl,
-            "decode_pool_mode": self.config.decode_pool_mode,
+            # a constant since the "local" decode block went: kept only
+            # because benchmark/run.py:445 and chip_smoke.py:470 index it
+            "decode_pool_mode": "scatter",
             "native_core": native_available(),
             "device_memory": [
                 {

@@ -40,12 +40,6 @@ def parse_args(argv=None):
                     "(DYN_HBM_UTILIZATION; the CPU gets a fixed 2048)")
     ap.add_argument("--max-num-seqs", type=int, default=64)
     ap.add_argument("--max-model-len", type=int, default=8192)
-    ap.add_argument("--decode-pool-mode", choices=["scatter", "local"],
-                    default=None,
-                    help="KV-write strategy in the fused decode block "
-                    "(default: scatter; see EngineConfig.decode_pool_mode)")
-    ap.add_argument("--decode-block-unroll", type=int, default=0,
-                    help="0 = auto (4 under local, 1 under scatter)")
     ap.add_argument("--lora", action="append", default=[],
                     metavar="NAME=PATH",
                     help="serve a LoRA adapter (HF PEFT export dir); "
@@ -168,8 +162,6 @@ async def main():
         num_pages=args.num_pages,
         max_num_seqs=args.max_num_seqs,
         max_model_len=args.max_model_len,
-        decode_pool_mode=args.decode_pool_mode,
-        decode_block_unroll=args.decode_block_unroll,
         quantize=args.quantize,
         kv_quant=args.kv_quant,
         spec_mode=args.spec,

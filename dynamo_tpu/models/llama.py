@@ -740,62 +740,5 @@ def decode_forward(
     return logits, kv_k, kv_v
 
 
-def decode_forward_local(
-    params: Dict[str, Any],
-    config: LlamaConfig,
-    tokens: jax.Array,  # [B] one new token per slot
-    positions: jax.Array,  # [B]
-    loc_k: tuple,  # L-tuple of [B, K, KH, D] block-local KV accumulators
-    loc_v: tuple,
-    step_idx: jax.Array,  # scalar i32: this step's slot in the local buffer
-    kv_k: jax.Array,  # READ-ONLY pool (written once per block by the engine)
-    kv_v: jax.Array,
-    page_tables: jax.Array,  # [B, max_pages]
-    pool_lens: jax.Array,  # [B] positions valid in the pool (block-start len)
-    mlp_fn=None,
-) -> Tuple[jax.Array, tuple, tuple]:
-    """One decode step that does NOT write the KV pool: new K/V go into the
-    block-local accumulators (per-layer tuple of small arrays so each
-    update is an in-place dynamic-update-slice on its own carry leaf — one
-    fused [L, ...] array would be re-materialized per layer), attention
-    reads pool+local via paged_attention_decode_mixed. Keeping the multi-GB
-    pool out of the scan carry is what makes the fused decode block's cost
-    independent of pool size (see the op's docstring).
-    Returns (logits, loc_k, loc_v)."""
-    from ..ops.paged_attention import paged_attention_decode_mixed
-
-    c = config
-    mlp_fn = mlp_fn or _mlp
-    x = embed_rows(params["embed"], tokens, c.dtype)
-    cos, sin = rope_cos_sin(positions, c.head_dim, c.rope_theta)
-    loc_k, loc_v = list(loc_k), list(loc_v)
-
-    for li in range(c.num_layers):
-        layer = jax.tree.map(lambda p: p[li], params["layers"])
-        h = rms_norm(x, layer["attn_norm"], c.rms_norm_eps)
-        q = qdot(h, layer["wq"]).astype(c.dtype)
-        k = qdot(h, layer["wk"]).astype(c.dtype)
-        v = qdot(h, layer["wv"]).astype(c.dtype)
-        q = q.reshape(-1, c.num_heads, c.head_dim)
-        k = k.reshape(-1, c.num_kv_heads, c.head_dim)
-        v = v.reshape(-1, c.num_kv_heads, c.head_dim)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        loc_k[li] = loc_k[li].at[:, step_idx].set(k)
-        loc_v[li] = loc_v[li].at[:, step_idx].set(v)
-        attn = paged_attention_decode_mixed(
-            q, kv_layer(kv_k, li), kv_layer(kv_v, li), page_tables, pool_lens,
-            loc_k[li], loc_v[li], step_idx,
-        )
-        attn = attn.reshape(-1, c.num_heads * c.head_dim)
-        x = x + qdot(attn, layer["wo"]).astype(c.dtype)
-        x = mlp_fn(layer, x, c)
-
-    x = rms_norm(x, params["final_norm"], c.rms_norm_eps)
-    head = head_leaf(params)
-    logits = qdot(x, head)
-    return logits, tuple(loc_k), tuple(loc_v)
-
-
 def param_count(params) -> int:
     return sum(x.size for x in jax.tree.leaves(params) if x is not None)

@@ -203,12 +203,13 @@ def decode_forward(
     kv_v: jax.Array,
     page_tables: jax.Array,  # [B, max_pages]
     seq_lens: jax.Array,  # [B]
+    lora=None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One decode step for the slot batch; llama attention path with the
     sparse-MoE MLP swapped in. Returns (logits [B, vocab], kv)."""
     return llama.decode_forward(
         params, config, tokens, positions, kv_k, kv_v, page_tables, seq_lens,
-        mlp_fn=moe_mlp,
+        mlp_fn=moe_mlp, lora=lora,
     )
 
 
@@ -236,26 +237,6 @@ def prefill_forward_ring(params, config, tokens, kv_k, kv_v, page_table,
     return llama.prefill_forward_ring(
         params, config, tokens, kv_k, kv_v, page_table, real_len, mesh,
         axis_name=axis_name, mlp_fn=moe_mlp,
-    )
-
-
-def decode_forward_local(
-    params: Dict[str, Any],
-    config: MoeConfig,
-    tokens: jax.Array,
-    positions: jax.Array,
-    loc_k: jax.Array,
-    loc_v: jax.Array,
-    step_idx: jax.Array,
-    kv_k: jax.Array,
-    kv_v: jax.Array,
-    page_tables: jax.Array,
-    pool_lens: jax.Array,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Pool-read-only decode step (block-local KV accumulation), MoE MLP."""
-    return llama.decode_forward_local(
-        params, config, tokens, positions, loc_k, loc_v, step_idx,
-        kv_k, kv_v, page_tables, pool_lens, mlp_fn=moe_mlp,
     )
 
 
@@ -290,6 +271,7 @@ def ragged_forward(
     row_lens: jax.Array,  # [R]
     ctx_lens: jax.Array,  # [R]
     last_flat: jax.Array,  # [R]
+    lora=None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Unified mixed-step forward (engine `_dispatch_mixed`), MoE MLP —
     the flat buffer is already [tokens, H], exactly the shape expert
@@ -297,7 +279,7 @@ def ragged_forward(
     return llama.ragged_forward(
         params, config, tokens, positions, row_ids, kv_k, kv_v,
         page_tables, row_starts, row_lens, ctx_lens, last_flat,
-        mlp_fn=moe_mlp,
+        mlp_fn=moe_mlp, lora=lora,
     )
 
 
@@ -323,10 +305,12 @@ def prefill_forward_batched(
     emb_override: Optional[jax.Array] = None,
     emb_mask: Optional[jax.Array] = None,
     all_logits: bool = False,
+    lora=None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Batched chunked prefill (multiple sequences per dispatch), MoE MLP."""
     return llama.prefill_forward_batched(
         params, config, tokens, positions, kv_k, kv_v, page_tables,
         context_lens, last_idx, mlp_fn=_moe_mlp_nd,
         emb_override=emb_override, emb_mask=emb_mask, all_logits=all_logits,
+        lora=lora,
     )

@@ -2,8 +2,8 @@
 scales (DYN_KV_QUANT / EngineConfig.kv_quant; docs/kvbm.md "Quantized KV
 format", docs/ragged_attention.md "Quantized pages").
 
-The KV cache is the HBM bound on BOTH raw speed and resident-session count
-(ROADMAP item 5): halving (int8) or quartering (int4) the bytes per page
+The KV cache is the HBM bound on BOTH raw speed and resident-session
+count: halving (int8) or quartering (int4) the bytes per page
 roughly doubles/quadruples the sessions a chip holds AND shrinks every
 byte the KVBM tiers, the peer fabric, and the disagg handoff move. The
 production shape is RTP-LLM's (PAPERS.md): pages quantized ON WRITE,
@@ -326,27 +326,6 @@ def kv_write(store, li, phys, offs, vals):
         store.q.at[li].set(q), store.s.at[li].set(s),
         store.bits, store.page_size,
     )
-
-
-def kv_write_all_layers(store, phys, offs, vals):
-    """All-layer write (the fused decode block's once-per-block carry
-    patch): vals [L, ...lead, KH, D] at (phys[...lead], offs[...lead]).
-    fp mode is a single fused scatter over every layer."""
-    if not isinstance(store, QuantKV):
-        return store.at[:, phys, offs].set(vals.reshape(*vals.shape[:-2], -1))
-    lead = phys.shape
-    T = int(np.prod(lead)) if lead else 1
-    phys_f = phys.reshape(T)
-    offs_f = offs.reshape(T)
-    L = vals.shape[0]
-    vals_f = vals.reshape(L, T, *vals.shape[1 + len(lead):])
-    write = jax.vmap(
-        lambda ql, sl, vl: _write_one_layer(
-            ql, sl, phys_f, offs_f, vl, store.bits, store.page_size
-        )
-    )
-    q, s = write(store.q, store.s, vals_f)
-    return QuantKV(q, s, store.bits, store.page_size)
 
 
 # ---------------------------------------------------------------------- #

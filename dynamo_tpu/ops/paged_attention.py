@@ -133,7 +133,7 @@ def mesh_allows_kernels(mesh) -> bool:
     pages and a bare pallas_call has no partitioning rule, so those
     engines take the XLA path — by this rule, not by device count: a
     one-chip engine in a process that sees four devices keeps its kernels.
-    (Kernels under shard_map are ROADMAP A5.)"""
+    (The kernels are not yet wrapped in a shard_map over KV heads.)"""
     return mesh is None or mesh.devices.size == 1
 
 
@@ -179,7 +179,7 @@ def _pallas_eligible(lane_dim: int, quantized: bool = False) -> bool:
         SMEM at real pool sizes; the int4 unpack shifts i8 vectors, which
         Mosaic cannot legalize — tests/test_tpu_compile.py pins both), so
         quantized pools take the XLA gather+dequant path on every op until
-        it does (ROADMAP A2/A9)."""
+        it does."""
     return lane_dim % 128 == 0 and not quantized and _use_pallas_decode()
 
 
@@ -195,70 +195,6 @@ def resolved_attention(head_dim: int, kv_heads: int, quantized: bool) -> dict:
         "prefill": name(head_dim),
         "ragged": name(head_dim),
     }
-
-
-def paged_attention_decode_mixed(
-    q: jax.Array,  # [B, H, D]
-    kv_k_layer: KVLayer,  # whole pool + layer index — READ-ONLY pool
-    kv_v_layer: KVLayer,
-    page_tables: jax.Array,  # [B, max_pages]
-    pool_lens: jax.Array,  # [B] positions valid IN THE POOL (block-start len)
-    loc_k: jax.Array,  # [B, K, KH, D] block-local new keys (this layer)
-    loc_v: jax.Array,
-    step_idx: jax.Array,  # scalar i32: local entries 0..step_idx are valid
-) -> jax.Array:
-    """Decode attention over paged pool + block-local buffer.
-
-    The fused-decode-block design (engine/engine.py) keeps the KV pool
-    READ-ONLY inside the K-step lax.scan — per-step scatters into a
-    multi-GB pool force XLA to materialize carry copies that scale with
-    pool size, not with bytes written (the reference never meets this: CUDA
-    writes KV in place, lib/llm/src/kernels/block_copy.cu). New tokens
-    accumulate in a [K]-entry local buffer carried through the scan and are
-    scattered into the pool ONCE per block. Attention therefore reads pool
-    pages (frozen at block start) plus the valid local prefix, merged with
-    a log-sum-exp combine on the Pallas path or a single concatenated
-    softmax on the XLA path.
-    """
-    B, H, D = q.shape
-    page_size, KH = layer_dims(kv_k_layer, D)
-    G = H // KH
-    K = loc_k.shape[1]
-    scale = 1.0 / jnp.sqrt(jnp.asarray(D, jnp.float32))
-    if _pallas_eligible(KH * D, is_quant_kv(kv_k_layer.pool)):
-        # pool chunks AND the local buffer flash-merge inside ONE kernel
-        # launch — an XLA-level lse combine costs ~8 extra op launches per
-        # layer-step, which dominates a 28-layer x 16-step fused block.
-        # The block-local buffer stays full precision under a quantized
-        # pool — quantization happens on POOL writes only (the
-        # once-per-block carry patch).
-        from .pallas_paged_attention import paged_attention_decode_pallas_local
-
-        return paged_attention_decode_pallas_local(
-            q, kv_k_layer, kv_v_layer, page_tables, pool_lens,
-            loc_k, loc_v, step_idx,
-        )
-
-    # XLA reference path: gather pool pages, concatenate the local buffer,
-    # one softmax over both
-    S = page_tables.shape[1] * page_size
-    ctx_k = gather_dequant(kv_k_layer, page_tables, D, q.dtype).reshape(B, S, KH, D)
-    ctx_v = gather_dequant(kv_v_layer, page_tables, D, q.dtype).reshape(B, S, KH, D)
-    cat_k = jnp.concatenate([ctx_k, loc_k.astype(ctx_k.dtype)], axis=1)
-    cat_v = jnp.concatenate([ctx_v, loc_v.astype(ctx_v.dtype)], axis=1)
-    qg = q.reshape(B, KH, G, D)
-    scores = jnp.einsum(
-        "bkgd,bskd->bkgs", qg, cat_k, preferred_element_type=jnp.float32
-    ) * scale
-    pool_valid = jnp.arange(S)[None, :] < pool_lens[:, None]  # [B, S]
-    loc_valid = jnp.broadcast_to(
-        jnp.arange(K)[None, :] <= step_idx, (B, K)
-    )
-    mask = jnp.concatenate([pool_valid, loc_valid], axis=1)  # [B, S+K]
-    scores = jnp.where(mask[:, None, None, :], scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bkgs,bskd->bkgd", probs.astype(cat_v.dtype), cat_v)
-    return out.reshape(B, H, D)
 
 
 def paged_attention_decode(

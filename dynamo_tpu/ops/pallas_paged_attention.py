@@ -207,145 +207,6 @@ def _decode_kernel(
     out_ref[0] = out.astype(out_ref.dtype)
 
 
-def _decode_local_kernel(
-    # positional refs: layer index [1], page_tables [B, max_pages], POOL
-    # lens [B], step [1] int32 scalar prefetch (+ this layer's
-    # per-page-per-head K/V scales [num_pages, KH] f32 when kv_bits > 0),
-    # then q [1, HG, KH*D] VMEM (block-diagonal packed), the block-local
-    # loc_k/loc_v [1, K, KH*D] (ALWAYS full precision — quantization
-    # happens on pool writes only), kv_k/kv_v [L, num_pages, rows, KH*D]
-    # ANY/HBM (the whole pool), out, window scratch.
-    *refs,
-    page_size: int,
-    chunk_pages: int,
-    max_pages: int,
-    num_heads: int,
-    num_kv_heads: int,
-    head_dim: int,
-    kv_bits: int = 0,
-):
-    """Decode flash attention over pool pages PLUS a block-local KV buffer,
-    all in one kernel launch. The local part is what lets the engine keep
-    the KV pool read-only inside its fused K-step scan (engine/engine.py
-    decode_block): per-step XLA-level combines cost ~8 extra op launches
-    per layer-step, which dominated the block at 28 layers x 16 steps."""
-    if kv_bits:
-        (li_ref, pt_ref, sl_ref, step_ref, ks_ref, vs_ref, q_ref, loc_k_ref,
-         loc_v_ref, kv_k_hbm, kv_v_hbm, out_ref, k_buf, v_buf, k_sem,
-         v_sem) = refs
-    else:
-        (li_ref, pt_ref, sl_ref, step_ref, q_ref, loc_k_ref, loc_v_ref,
-         kv_k_hbm, kv_v_hbm, out_ref, k_buf, v_buf, k_sem, v_sem) = refs
-        ks_ref = vs_ref = None
-    b = pl.program_id(0)
-    li = li_ref[0]
-    chunk = chunk_pages * page_size
-    num_phys = kv_k_hbm.shape[1]
-    page_rows = kv_k_hbm.shape[2]
-    kh, g, d = num_kv_heads, num_heads // num_kv_heads, head_dim
-
-    seq_len = jnp.maximum(sl_ref[b], 1)
-    n_chunks = pl.cdiv(seq_len, chunk)
-
-    def start_chunk(ci, slot):
-        for p in range(chunk_pages):
-            lp = ci * chunk_pages + p
-            lp_safe = jnp.minimum(lp, max_pages - 1)
-            phys = jnp.minimum(pt_ref[b, lp_safe], num_phys - 1)
-            pltpu.make_async_copy(
-                kv_k_hbm.at[li, phys],
-                k_buf.at[slot, pl.ds(p * page_rows, page_rows)],
-                k_sem.at[slot, p],
-            ).start()
-            pltpu.make_async_copy(
-                kv_v_hbm.at[li, phys],
-                v_buf.at[slot, pl.ds(p * page_rows, page_rows)],
-                v_sem.at[slot, p],
-            ).start()
-
-    def wait_chunk(ci, slot):
-        for p in range(chunk_pages):
-            lp_safe = jnp.minimum(ci * chunk_pages + p, max_pages - 1)
-            phys = jnp.minimum(pt_ref[b, lp_safe], num_phys - 1)
-            pltpu.make_async_copy(
-                kv_k_hbm.at[li, phys],
-                k_buf.at[slot, pl.ds(p * page_rows, page_rows)],
-                k_sem.at[slot, p],
-            ).wait()
-            pltpu.make_async_copy(
-                kv_v_hbm.at[li, phys],
-                v_buf.at[slot, pl.ds(p * page_rows, page_rows)],
-                v_sem.at[slot, p],
-            ).wait()
-
-    start_chunk(0, 0)
-    hg = kh * g
-    q_bd = q_ref[0]
-
-    m0 = jnp.full((hg, 1), NEG, jnp.float32)
-    l0 = jnp.zeros((hg, 1), jnp.float32)
-    acc0 = jnp.zeros((hg, kh * d), jnp.float32)
-
-    def flash_update(s, valid, v, carry):
-        m, l, acc = carry
-        s = jnp.where(valid, s, NEG)
-        m_n = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m - m_n)
-        p = jnp.exp(s - m_n)
-        l_n = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return m_n, l_n, acc * alpha + pv
-
-    def body(ci, carry):
-        slot = jax.lax.rem(ci, 2)
-
-        @pl.when(ci + 1 < n_chunks)
-        def _():
-            start_chunk(ci + 1, jax.lax.rem(ci + 1, 2))
-
-        wait_chunk(ci, slot)
-        if kv_bits:
-            k, v = _window_dequant(
-                b, ci, slot, k_buf, v_buf, pt_ref, ks_ref, vs_ref,
-                q_ref.dtype, chunk_pages=chunk_pages, page_rows=page_rows,
-                max_pages=max_pages, num_phys=num_phys,
-                num_kv_heads=kh, head_dim=d, kv_bits=kv_bits,
-            )
-        else:
-            k = k_buf[slot]
-            v = v_buf[slot]
-        pos = ci * chunk + jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
-        s = jax.lax.dot_general(
-            q_bd.astype(k.dtype), k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return flash_update(s, pos < seq_len, v, carry)
-
-    m, l, acc = jax.lax.fori_loop(0, n_chunks, body, (m0, l0, acc0))
-
-    # local buffer: one more flash iteration over the K in-block entries
-    k_loc = loc_k_ref[0]  # [K, KH*D]
-    v_loc = loc_v_ref[0]
-    K_loc = k_loc.shape[0]
-    j = jax.lax.broadcasted_iota(jnp.int32, (1, K_loc), 1)
-    s_loc = jax.lax.dot_general(
-        q_bd.astype(k_loc.dtype), k_loc, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    m, l, acc = flash_update(s_loc, j <= step_ref[0], v_loc, (m, l, acc))
-
-    row_head = jax.lax.broadcasted_iota(jnp.int32, (hg, 1), 0) // g
-    out = jnp.zeros((hg, d), jnp.float32)
-    for k0 in range(kh):
-        blk = jax.lax.slice(acc, (0, k0 * d), (hg, (k0 + 1) * d))
-        out = out + jnp.where(row_head == k0, blk, 0.0)
-    out = out / jnp.maximum(l, 1e-30)
-    out_ref[0] = out.astype(out_ref.dtype)
-
-
 def _block_diagonal_q(q: jax.Array, num_kv_heads: int) -> jax.Array:
     """[B, H, D] -> [B, H, KH*D], scaled by 1/sqrt(D): head h's query in
     the D-wide lane block of its kv head (q_bd[b, k*G+g, k*D:(k+1)*D] = q),
@@ -360,97 +221,6 @@ def _block_diagonal_q(q: jax.Array, num_kv_heads: int) -> jax.Array:
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def paged_attention_decode_pallas_local(
-    q: jax.Array,  # [B, H, D]
-    kv_k_layer,  # kv_quant.KVLayer: whole READ-ONLY pool + layer index
-    kv_v_layer,
-    page_tables: jax.Array,  # [B, max_pages] int32
-    pool_lens: jax.Array,  # [B] int32 — positions valid in the pool
-    loc_k: jax.Array,  # [B, K, KH, D] block-local new keys
-    loc_v: jax.Array,
-    step_idx: jax.Array,  # scalar i32 — local entries 0..step_idx valid
-    *,
-    interpret: bool = False,
-) -> jax.Array:
-    """Fused pool+local decode attention; returns [B, H, D] (q.dtype).
-    The pool may be a QuantKV (ops/kv_quant.py): packed pages dequantize
-    inside the VMEM window off scalar-prefetched scales; the block-local
-    buffer is always full precision."""
-    from .kv_quant import kernel_operands
-
-    B, H, D = q.shape
-    kv_k_pool, kv_v_pool, li, KH, rows, page_size, kv_bits, scale_prefetch = (
-        kernel_operands(kv_k_layer, kv_v_layer, D)
-    )
-    max_pages = page_tables.shape[1]
-    K_loc = loc_k.shape[1]
-    target = 512 if KH * D * page_size <= 131072 else 256
-    chunk_pages = max(1, target // page_size)
-    chunk_pages = min(chunk_pages, max_pages)
-
-    KHG = KH * (H // KH)
-    q_bd = _block_diagonal_q(q, KH)
-
-    loc_k_flat = loc_k.reshape(B, K_loc, KH * D)
-    loc_v_flat = loc_v.reshape(B, K_loc, KH * D)
-    prefetch = [
-        li,
-        page_tables.astype(jnp.int32),
-        pool_lens.astype(jnp.int32),
-        jnp.reshape(step_idx, (1,)).astype(jnp.int32),
-        *scale_prefetch,
-    ]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch),
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, KHG, KH * D), lambda b, *_: (b, 0, 0)),
-            pl.BlockSpec((1, K_loc, KH * D), lambda b, *_: (b, 0, 0)),
-            pl.BlockSpec((1, K_loc, KH * D), lambda b, *_: (b, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, H, D), lambda b, *_: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((2, chunk_pages * rows, KH * D), kv_k_pool.dtype),
-            pltpu.VMEM((2, chunk_pages * rows, KH * D), kv_v_pool.dtype),
-            pltpu.SemaphoreType.DMA((2, chunk_pages)),
-            pltpu.SemaphoreType.DMA((2, chunk_pages)),
-        ],
-    )
-    kernel = functools.partial(
-        _decode_local_kernel,
-        page_size=page_size,
-        chunk_pages=chunk_pages,
-        max_pages=max_pages,
-        num_heads=H,
-        num_kv_heads=KH,
-        head_dim=D,
-        kv_bits=kv_bits,
-    )
-    cost = pl.CostEstimate(
-        flops=4 * B * H * D * (max_pages * page_size + K_loc),
-        bytes_accessed=2 * B * max_pages * page_size * KH * D * 2,
-        transcendentals=B * H * (max_pages * page_size + K_loc),
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
-        cost_estimate=cost,
-        interpret=interpret,
-    )(
-        *prefetch,
-        q_bd,
-        loc_k_flat,
-        loc_v_flat,
-        kv_k_pool,
-        kv_v_pool,
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention_decode_pallas(
     q: jax.Array,  # [B, H, D]
     kv_k_layer,  # kv_quant.KVLayer: whole pool + layer index
@@ -461,9 +231,7 @@ def paged_attention_decode_pallas(
     interpret: bool = False,
 ) -> jax.Array:
     """Flash decode attention over paged KV; returns [B, H, D] (q.dtype).
-    (Block-local merging lives in _decode_local_kernel — the fused variant —
-    so this hot path writes exactly one output.) The pool may be a
-    QuantKV: packed pages dequantize in the VMEM window."""
+    The pool may be a QuantKV: packed pages dequantize in the VMEM window."""
     from .kv_quant import kernel_operands
 
     B, H, D = q.shape

@@ -124,7 +124,7 @@ def test_accelerator_without_memory_stats_is_an_error(monkeypatch):
     )
     monkeypatch.setattr(jax, "local_devices", lambda: [dev])
     monkeypatch.delenv("DYN_HBM_BYTES", raising=False)
-    cfg = EngineConfig(model="tiny", num_pages=0, decode_pool_mode="local")
+    cfg = EngineConfig(model="tiny", num_pages=0)
     with pytest.raises(RuntimeError, match="DYN_HBM_BYTES"):
         engine_mod._auto_num_pages({}, llama.LlamaConfig.tiny(), cfg)
     # with the size given from outside, the same device is sized from it

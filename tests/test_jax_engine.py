@@ -677,49 +677,6 @@ def test_moe_resolve_registry():
     assert isinstance(_resolve_model("mixtral-8x7b"), moe.MoeConfig)
 
 
-def test_local_pool_mode_greedy_parity(params):
-    """decode_pool_mode='local' (read-only pool + block-local KV + one
-    post-scan scatter) must produce exactly the same greedy tokens as the
-    per-step-scatter mode and the naive recompute."""
-    prompt = [5, 9, 17, 33, 101, 7, 250, 3, 42, 77]
-    n_steps = 10
-
-    naive_tokens = list(prompt)
-    for _ in range(n_steps):
-        naive_tokens.append(naive_next_token(params, naive_tokens))
-    expected = naive_tokens[len(prompt):]
-
-    async def engine_run():
-        cfg = EngineConfig(
-            model="tiny",
-            max_num_seqs=4,
-            page_size=PAGE,
-            num_pages=64,
-            max_model_len=128,
-            prefill_buckets=(16, 32),
-            max_prefill_chunk=32,
-            decode_block_steps=4,
-            decode_pool_mode="local",
-            decode_block_unroll=4,
-        )
-        eng = JaxEngine(cfg, model_config=CFG, params=params)
-        req = PreprocessedRequest(
-            token_ids=prompt,
-            stop_conditions={"max_tokens": n_steps},
-            request_id="local-parity",
-        ).to_dict()
-        toks = []
-        async for item in eng.generate(req, Context()):
-            data = item.get("data")
-            if data:
-                toks.extend(data["token_ids"])
-        await eng.close()
-        return toks
-
-    got = asyncio.run(engine_run())
-    assert got == expected, f"local-mode {got} != naive {expected}"
-
-
 def test_gptoss_shaped_registry_resolves_and_steps():
     """The gpt-oss-120b-shaped wide-MoE config (BASELINE config 5) resolves
     from the registry and one decode step runs at reduced layer count."""

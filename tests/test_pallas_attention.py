@@ -141,88 +141,6 @@ def test_pallas_bf16_gqa():
 
 
 # --------------------------------------------------------------------- #
-# mixed pool+local decode attention (write-KV-once-per-block design)
-# --------------------------------------------------------------------- #
-
-
-def _mixed_reference(q, kv_k, kv_v, pt, pool_lens, loc_k, loc_v, step_idx):
-    """Oracle: pool pages with loc entries appended, single dense softmax."""
-    import os
-
-    os.environ["DYNAMO_TPU_PAGED_ATTN"] = "xla"
-    try:
-        return ref_ops.paged_attention_decode_mixed(
-            q, kv_k, kv_v, pt, pool_lens, loc_k, loc_v, step_idx
-        )
-    finally:
-        os.environ.pop("DYNAMO_TPU_PAGED_ATTN", None)
-
-
-def test_mixed_xla_equals_written_pool_oracle():
-    """Writing the local entries into the pool and attending the classic way
-    must give the same answer as pool+local mixed attention."""
-    B, H, KH, D, page_size, max_pages = 3, 8, 4, 32, 8, 6
-    pages = 32
-    rng = np.random.RandomState(11)
-    q = jnp.asarray(rng.randn(B, H, D), jnp.float32)
-    kv_k = jnp.asarray(rng.randn(pages, page_size, KH, D), jnp.float32)
-    kv_v = jnp.asarray(rng.randn(pages, page_size, KH, D), jnp.float32)
-    pt = jnp.asarray(
-        rng.choice(pages - 1, size=(B, max_pages), replace=False).astype(np.int32) + 1
-    )
-    K = 4
-    step = 2
-    pool_lens = jnp.asarray([5, 16, 30], jnp.int32)
-    loc_k = jnp.asarray(rng.randn(B, K, KH, D), jnp.float32)
-    loc_v = jnp.asarray(rng.randn(B, K, KH, D), jnp.float32)
-
-    got = _mixed_reference(q, L(kv_k), L(kv_v), pt, pool_lens, loc_k, loc_v, jnp.int32(step))
-
-    # oracle: scatter local entries 0..step at positions pool_lens+j, then
-    # classic decode attention with seq_lens = pool_lens + step + 1
-    kv_k_w, kv_v_w = np.asarray(kv_k).copy(), np.asarray(kv_v).copy()
-    for b in range(B):
-        for j in range(step + 1):
-            pos = int(pool_lens[b]) + j
-            phys = int(pt[b, pos // page_size])
-            kv_k_w[phys, pos % page_size] = np.asarray(loc_k)[b, j]
-            kv_v_w[phys, pos % page_size] = np.asarray(loc_v)[b, j]
-    import os
-
-    os.environ["DYNAMO_TPU_PAGED_ATTN"] = "xla"
-    try:
-        want = ref_ops.paged_attention_decode(
-            q, L(jnp.asarray(kv_k_w)), L(jnp.asarray(kv_v_w)), pt,
-            pool_lens + step + 1,
-        )
-    finally:
-        os.environ.pop("DYNAMO_TPU_PAGED_ATTN", None)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-3, atol=2e-3)
-
-
-@pytest.mark.parametrize("step", [0, 5])
-def test_fused_local_kernel_matches_xla(step):
-    """The single-launch pool+local kernel must agree with the XLA
-    concat-softmax reference."""
-    q, kv_k, kv_v, pt, _ = _mk_case(B=4, seed=5)
-    rng = np.random.RandomState(13)
-    K = 8
-    KH, D = kv_k.shape[2], kv_k.shape[3]
-    B = q.shape[0]
-    loc_k = jnp.asarray(rng.randn(B, K, KH, D), jnp.float32)
-    loc_v = jnp.asarray(rng.randn(B, K, KH, D), jnp.float32)
-    pool_lens = jnp.asarray([1, 9, 17, 40], jnp.int32)
-    want = _mixed_reference(q, L(kv_k), L(kv_v), pt, pool_lens, loc_k, loc_v, jnp.int32(step))
-
-    from dynamo_tpu.ops.pallas_paged_attention import paged_attention_decode_pallas_local
-
-    got = paged_attention_decode_pallas_local(
-        q, L(kv_k), L(kv_v), pt, pool_lens, loc_k, loc_v, jnp.int32(step), interpret=True
-    )
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-3, atol=2e-3)
-
-
-# --------------------------------------------------------------------- #
 # whole pool + layer index (PR 26): the kernels are handed the pool as it
 # lies in HBM, [L, pages, rows, KH*D], and DMA pool[li, page]
 # --------------------------------------------------------------------- #
@@ -234,22 +152,6 @@ def _by_layer_decode(li, num_layers):
     got = paged_attention_decode_pallas(
         q, L(kv_k, li, num_layers), L(kv_v, li, num_layers, seed=1), pt,
         seq_lens, interpret=True,
-    )
-    return got, want, slice(None)
-
-
-def _by_layer_decode_local(li, num_layers):
-    from dynamo_tpu.ops.pallas_paged_attention import paged_attention_decode_pallas_local
-
-    q, kv_k, kv_v, pt, _ = _mk_case(B=4, seed=22)
-    rng = np.random.RandomState(23)
-    loc_k = jnp.asarray(rng.randn(4, 8, *kv_k.shape[2:]), jnp.float32)
-    loc_v = jnp.asarray(rng.randn(4, 8, *kv_k.shape[2:]), jnp.float32)
-    pool_lens = jnp.asarray([1, 9, 17, 40], jnp.int32)
-    want = _mixed_reference(q, L(kv_k), L(kv_v), pt, pool_lens, loc_k, loc_v, jnp.int32(3))
-    got = paged_attention_decode_pallas_local(
-        q, L(kv_k, li, num_layers), L(kv_v, li, num_layers, seed=1), pt,
-        pool_lens, loc_k, loc_v, jnp.int32(3), interpret=True,
     )
     return got, want, slice(None)
 
@@ -297,8 +199,8 @@ def _xla(fn, *args):
 @pytest.mark.parametrize("li", [0, 2, 4], ids=["first", "middle", "last"])
 @pytest.mark.parametrize(
     "case",
-    [_by_layer_decode, _by_layer_decode_local, _by_layer_prefill, _by_layer_ragged],
-    ids=["decode", "decode_local", "prefill", "ragged"],
+    [_by_layer_decode, _by_layer_prefill, _by_layer_ragged],
+    ids=["decode", "prefill", "ragged"],
 )
 def test_kernels_read_the_whole_pool_by_layer(case, li):
     """Every kernel, handed a five-layer pool whose other layers hold

@@ -395,14 +395,11 @@ def test_ragged_kernel_spec_staircase_quantized(mode):
 
 @pytest.mark.parametrize("mode", ["int8", "int4"])
 def test_decode_kernels_quantized_match_oracles(mode):
-    """The decode + fused pool-local kernels under quantized pools: exact
-    vs the quantized XLA reference, quant-tolerance vs the FP oracle."""
+    """The decode kernel under quantized pools: exact vs the quantized
+    XLA reference, quant-tolerance vs the FP oracle."""
     import os
 
-    from dynamo_tpu.ops.pallas_paged_attention import (
-        paged_attention_decode_pallas,
-        paged_attention_decode_pallas_local,
-    )
+    from dynamo_tpu.ops.pallas_paged_attention import paged_attention_decode_pallas
 
     rng = np.random.RandomState(41)
     pages, ps, KH, D, H, B = 12, 8, 2, 32, 4, 3
@@ -428,24 +425,6 @@ def test_decode_kernels_quantized_match_oracles(mode):
                                rtol=2e-3, atol=2e-3)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref_fp),
                                rtol=0.0, atol=_QUANT_FP_ATOL[mode])
-    # fused pool+local: quantized pool, FULL-precision local buffer
-    K_loc = 4
-    loc_k = jnp.asarray(rng.randn(B, K_loc, KH, D), jnp.float32)
-    loc_v = jnp.asarray(rng.randn(B, K_loc, KH, D), jnp.float32)
-    pool_lens = jnp.maximum(seq_lens - 1, 0)
-    os.environ["DYNAMO_TPU_PAGED_ATTN"] = "xla"
-    try:
-        ref_l = ref_ops.paged_attention_decode_mixed(
-            q, qk, qv, tables, pool_lens, loc_k, loc_v, jnp.asarray(2)
-        )
-    finally:
-        os.environ.pop("DYNAMO_TPU_PAGED_ATTN", None)
-    got_l = paged_attention_decode_pallas_local(
-        q, qk, qv, tables, pool_lens, loc_k, loc_v, jnp.asarray(2),
-        interpret=True,
-    )
-    np.testing.assert_allclose(np.asarray(got_l), np.asarray(ref_l),
-                               rtol=2e-3, atol=2e-3)
 
 
 def test_quantized_page_write_tracks_scale_growth():

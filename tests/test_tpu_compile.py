@@ -101,7 +101,7 @@ def _pool(sds, mode="none", layers=LAYERS, pool=POOL, kh=KH):
 
 
 def _op_cases(sds, mode, H=H, KH=KH, layers=LAYERS, pool=POOL):
-    """(name, fn, args) for the four serving attention ops through the
+    """(name, fn, args) for the three serving attention ops through the
     dispatch gate, with a `mode` KV pool."""
     k = _pool(sds, mode, layers, pool, KH)
     v = _pool(sds, mode, layers, pool, KH)
@@ -109,18 +109,12 @@ def _op_cases(sds, mode, H=H, KH=KH, layers=LAYERS, pool=POOL):
     q1 = sds((B, H, D), jnp.bfloat16)
     tables = sds((B, TABLE), i32)
     lens = sds((B,), i32)
-    K = 8
-    loc = sds((B, K, KH, D), jnp.bfloat16)
     T = 128
     Bp = 8
     N = 512
     R = 128
     return {
         "decode": (ops.paged_attention_decode, (q1, k, v, tables, lens)),
-        "decode_local": (
-            ops.paged_attention_decode_mixed,
-            (q1, k, v, tables, lens, loc, loc, sds((), i32)),
-        ),
         "prefill_batched": (
             ops.prefill_attention_batched,
             (sds((Bp, T, H, D), jnp.bfloat16), k, v, sds((Bp, T), i32),
@@ -134,7 +128,7 @@ def _op_cases(sds, mode, H=H, KH=KH, layers=LAYERS, pool=POOL):
     }
 
 
-OPS = ("decode", "decode_local", "prefill_batched", "ragged")
+OPS = ("decode", "prefill_batched", "ragged")
 
 
 @pytest.mark.parametrize("op", OPS)
@@ -241,7 +235,7 @@ def test_quantized_kv_routes_to_xla_and_compiles(
 def test_in_kernel_dequant_is_still_refused(mode, one_chip, no_persistent_cache):
     """Why the gate rule exists. When the TPU compiler starts accepting the
     quantized kernels this fails: then drop the `quantized` clause of
-    ops/paged_attention._pallas_eligible (ROADMAP A2/A9)."""
+    ops/paged_attention._pallas_eligible."""
     from dynamo_tpu.ops.pallas_ragged_attention import (
         ragged_paged_attention_pallas,
     )
