@@ -11,7 +11,9 @@ frozen, discovery leases lapsing.
 The rule taints host-side shape constructors (`np/jnp` `zeros`/`full`/
 `ones`/`empty`, `np.pad` widths, `.reshape` args) inside DISPATCH
 functions — functions that hand work to a serving surface (call
-`_run_on_device` or a warmup-obligated surface from COMPILE_SURFACES) —
+`_run_on_device` or a warmup-obligated surface from COMPILE_SURFACES), and
+functions of the same file that one of those calls by name (one level:
+`_blank_mixed_pack` mints the operands `_dispatch_mixed` sends) —
 and requires every dimension to resolve to a bounded source:
 
   * int literals and config attributes (any dotted path through a
@@ -293,10 +295,21 @@ class CompShapeBucketingRule(Rule):
             bounds = _Bounds(src, helper_names)
             dispatch_cache: Dict[int, bool] = {}
 
+            # functions a dispatch function calls mint its operands: one
+            # level, by bare name, within the file
+            called = set()
+            for node in ast.walk(src.tree):
+                if isinstance(
+                    node, (ast.FunctionDef, ast.AsyncFunctionDef)
+                ) and _call_tails(node) & triggers:
+                    called |= _call_tails(node)
+
             def is_dispatch(func: ast.AST) -> bool:
                 hit = dispatch_cache.get(id(func))
                 if hit is None:
-                    hit = bool(_call_tails(func) & triggers)
+                    hit = bool(_call_tails(func) & triggers) or (
+                        getattr(func, "name", None) in called
+                    )
                     dispatch_cache[id(func)] = hit
                 return hit
 

@@ -51,6 +51,51 @@ def bucket_for(n: int, buckets: Sequence[int]) -> int:
     return buckets[-1]
 
 
+# The mixed step's token axis has at most this many buckets, whatever the
+# configuration: its family of programs is compiled together at the first
+# mixed step (engine._prime_mixed_family), lean and variant, so each bucket
+# is two full-depth compiles of start-up (8 to 25 s apiece cold on a v5e).
+# A fourth would serve only packs under an eighth of mixed_max_tokens, at
+# most a few ms a step on a dense model and nothing on a MoE one.
+MIXED_TOKEN_BUCKETS_MAX = 3
+
+
+def mixed_token_buckets(config, align: int) -> tuple:
+    """The flat-token buckets of the mixed step, from the configuration
+    alone: powers of two from a floor up to `mixed_max_tokens` (floored to
+    the packer's alignment, as plan_mixed's budget is). The floor is the
+    smallest power of two that holds a full decode batch (every lane's
+    rows, each padded to `align`) beside as much again of prompt, raised
+    where needed so that at most MIXED_TOKEN_BUCKETS_MAX buckets are left.
+    A step's cost hardly grows with its bucket where attention is ragged
+    and the experts multiply routed rows only, so small buckets would buy
+    nothing but programs to compile."""
+    cap = config.mixed_max_tokens - config.mixed_max_tokens % align
+    rows = config.max_num_seqs * (
+        1 + (config.spec_draft_len if config.spec_mode else 0)
+    )
+    floor = max(
+        2 * next_pow2(rows * align),
+        next_pow2(cap) >> (MIXED_TOKEN_BUCKETS_MAX - 1),
+    )
+    buckets = []
+    while floor < cap:
+        buckets.append(floor)
+        floor *= 2
+    return tuple(buckets) + (cap,)
+
+
+def table_rungs(max_pages: int) -> tuple:
+    """Page-table widths (context pages, without the scratch column) on
+    the pow2 ladder clamped to `max_pages`: 1, 2, 4, ..., max_pages."""
+    rungs = []
+    p = 1
+    while p < max_pages:
+        rungs.append(p)
+        p *= 2
+    return tuple(rungs) + (max_pages,)
+
+
 #: Bounded shape sources the comp-shape-bucketing rule resolves against.
 #: Keyed by bare helper name (callsites match with leading underscores
 #: stripped, so `self._bucket_for(...)` and `planner.plan_prefill(...)`
@@ -76,8 +121,19 @@ BUCKETING_HELPERS = {
     },
     "plan_mixed": {
         "module": "dynamo_tpu/engine/scheduler/policy.py",
-        "bound": "min(next_pow2(total), mixed_max_tokens budget)",
+        "bound": "bucket_for(total, mixed_token_buckets(config, align))",
         "returns": "MixedPlan with .bucket token dim",
+    },
+    "mixed_token_buckets": {
+        "module": "dynamo_tpu/engine/bucketing.py",
+        "bound": "at most MIXED_TOKEN_BUCKETS_MAX powers of two up to "
+                 "config.mixed_max_tokens",
+        "returns": "the mixed step's token buckets",
+    },
+    "table_rungs": {
+        "module": "dynamo_tpu/engine/bucketing.py",
+        "bound": "pow2 ladder clamped to config.max_pages_per_seq",
+        "returns": "page-table widths of the mixed step",
     },
     "ragged_tile_q": {
         "module": "dynamo_tpu/ops/pallas_ragged_attention.py",

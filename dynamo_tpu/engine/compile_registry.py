@@ -89,17 +89,22 @@ COMPILE_SURFACES = {
         "donate": (1, 2, 12),
         "static": (),
         "axes": {
-            "N": "plan_mixed / min(next_pow2(tokens), aligned "
-                 "config.mixed_max_tokens)",
+            "N": "bucket_for(tokens, mixed_token_buckets(config, align)): "
+                 "at most 3 powers of two from 2 * next_pow2(decode rows * "
+                 "align) up to aligned config.mixed_max_tokens",
             "R": "next_pow2(config.max_num_seqs * (1 + spec_draft_len if "
                  "spec_mode else 1) + config.max_prefill_batch) — spec "
                  "verify rows share the lane row budget",
-            "P": "min(next_pow2(pages), config.max_pages_per_seq) + 1",
+            "P": "config.max_pages_per_seq + 1, the ONE width, where the "
+                 "ragged kernel is Pallas and R * P * 4 B <= "
+                 "MIXED_TABLE_SMEM_BYTES; else table_rungs: "
+                 "min(next_pow2(pages), config.max_pages_per_seq) + 1",
         },
         "warmup": True,
         "help": "ragged prefill+decode fusion over the token dimension "
                 "(plain and pure-spec packs; spec lanes pack 1+d verify "
-                "rows)",
+                "rows); the N x P family of a table width is compiled "
+                "together at its first use (engine._prime_mixed_family)",
     },
     "mixed_step_variant": {
         "module": "dynamo_tpu/engine/engine.py",
@@ -107,11 +112,13 @@ COMPILE_SURFACES = {
         "donate": (1, 2, 12),
         "static": (),
         "axes": {
-            "N": "plan_mixed / min(next_pow2(tokens), aligned "
-                 "config.mixed_max_tokens)",
+            "N": "bucket_for(tokens, mixed_token_buckets(config, align)): "
+                 "at most 3 powers of two from 2 * next_pow2(decode rows * "
+                 "align) up to aligned config.mixed_max_tokens",
             "R": "next_pow2(config.max_num_seqs * (1 + spec_draft_len if "
                  "spec_mode else 1) + config.max_prefill_batch)",
-            "P": "min(next_pow2(pages), config.max_pages_per_seq) + 1",
+            "P": "as mixed_step: one width under the Pallas ragged "
+                 "kernel, else table_rungs",
             "V8": "(vocab_size + 7) // 8 (packed per-row grammar mask; "
                   "all-ones rows are exact no-ops)",
             "rank": "pool r_max (fixed device adapter stack; operand "

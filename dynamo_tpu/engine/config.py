@@ -97,10 +97,11 @@ class EngineConfig:
     # residents). None = resolve from DYN_LORA_POOL_SLOTS (default 8).
     lora_pool_slots: Optional[int] = None
     # flat-token budget of one mixed dispatch: decode rows + granted
-    # prefill chunks, pow2-bucketed up to this cap. Bounds the mixed
-    # compile-variant space exactly like prefill_buckets bounds prefill's
-    # (one lazily-compiled variant per (token bucket, table bucket); the
-    # row axis is a single fixed bucket, see engine._mixed_row_bucket).
+    # prefill chunks, in at most three pow2 buckets up to this cap
+    # (bucketing.mixed_token_buckets). Bounds the mixed compile-variant
+    # space exactly like prefill_buckets bounds prefill's (token bucket x
+    # table width, compiled together at first use; the row axis is a
+    # single fixed bucket, see engine._mixed_row_bucket).
     mixed_max_tokens: int = 2048
     # KVBM tiers (kvbm/manager.py); 0 disables a tier
     kvbm_host_blocks: int = 0

@@ -45,7 +45,8 @@ import numpy as np
 from ..llm.mocker.kv_manager import KvEvent
 from ..llm.protocols import Annotated, LLMEngineOutput, PreprocessedRequest
 from ..llm.tokens import TokenBlockSequence, compute_seq_hashes, salt_hash
-from ..models import llama
+from ..models import llama, moe
+from ..models.quant import is_quant
 from ..native import native_available
 from ..runtime import faults
 from ..runtime.engine import Context
@@ -58,7 +59,12 @@ from ..runtime.metrics import (
     SCHED_EST_REQ_MS,
     SCHED_EST_TTFT_MS,
 )
-from .bucketing import next_pow2 as _next_pow2
+from .bucketing import (
+    bucket_for as _bucket_for,
+    mixed_token_buckets,
+    next_pow2 as _next_pow2,
+    table_rungs,
+)
 from .config import EngineConfig
 from .kv_cache import PageAllocator, alloc_kv_arrays
 from .sampling import SamplingParams, penalized, sample, sample_lp, unpack_mask
@@ -67,6 +73,13 @@ from .scheduler import SlaConfig, StepPlanner
 logger = logging.getLogger(__name__)
 
 SCRATCH_PAGE = 0  # physical page 0 is the dump target for masked lanes
+# What the mixed step's ONE table width (max_pages_per_seq + 1 columns for
+# every row of the row bucket, int32) may take of a v5e's scalar memory:
+# the ragged kernel prefetches the table into SMEM, 1 MiB on a v5e
+# (compiled for a described v5e: a 256 x 1025 table is refused), and Mosaic
+# pads its rows to 128 columns, so 256 KiB here is at most half of it.
+# The benchmark's cell takes 64 x 65 x 4 = 16,640 B. Above it: pow2 rungs.
+MIXED_TABLE_SMEM_BYTES = 256 * 1024
 
 
 # The one in-checkout home of JAX's persistent compilation cache. The path
@@ -687,6 +700,26 @@ class JaxEngine:
         self._mixed_row_bucket = _next_pow2(
             config.max_num_seqs * rows_per_lane + config.max_prefill_batch
         )
+        # the lean mixed_step family: token buckets x table widths, closed
+        # and small enough to compile together at the first mixed step
+        # (_prime_mixed_family). The Pallas ragged kernel's work follows
+        # each row's ctx_lens, not the table's width (the table is a
+        # scalar-prefetch operand), so there ONE width serves every
+        # context — while R_pad x P x 4 B stays within the budget below; the
+        # XLA reference gathers P pages a row, so it keeps the pow2 rungs.
+        self._mixed_token_buckets = mixed_token_buckets(
+            config, self._mixed_align
+        )
+        one_width = (
+            self.attention_impl["ragged"] == "pallas"
+            and self._mixed_row_bucket * (config.max_pages_per_seq + 1) * 4
+            <= MIXED_TABLE_SMEM_BYTES
+        )
+        self._mixed_table_rungs = (
+            (config.max_pages_per_seq,) if one_width
+            else table_rungs(config.max_pages_per_seq)
+        )
+        self._mixed_primed = set()  # of (variant, ctx_pages)
         # fused-vs-split visibility (stats() + jax_worker gauges): is the
         # fused path actually taken in production, and what padding does
         # each path pay per step
@@ -704,6 +737,11 @@ class JaxEngine:
         self.mixed_rows_guided = 0
         self.mixed_rows_spec = 0
         self.mixed_rows_lora = 0
+        # expert rows per dispatch, host arithmetic (moe.expert_rows): what
+        # was routed (real tokens x experts per token) against what the
+        # expert matmuls multiply; both stay 0 on a dense model
+        self.expert_rows_routed = 0
+        self.expert_rows_computed = 0
         self._last_prefill_shape = None  # (padded, real) of the latest dispatch
         self._last_decode_shape = None
         # set by _dispatch_mixed when only the in-flight decode pipeline
@@ -2316,6 +2354,15 @@ class JaxEngine:
         # prometheus gauges)
         out["mixed_steps"] = self.mixed_steps
         out["split_steps"] = self.split_steps
+        # the lean mixed_step family (token buckets x table widths) and
+        # how many of its programs the jit cache holds: equal once the
+        # first mixed step has returned (_prime_mixed_family)
+        out["mixed_family_size"] = (
+            len(self._mixed_token_buckets) * len(self._mixed_table_rungs)
+        )
+        out["mixed_family_compiled"] = int(self._mixed_step._cache_size())
+        out["expert_rows_routed"] = self.expert_rows_routed
+        out["expert_rows_computed"] = self.expert_rows_computed
         out["mixed_padding_frac"] = round(
             1.0 - self.mixed_real_tokens / self.mixed_padded_tokens, 4
         ) if self.mixed_padded_tokens else 0.0
@@ -2703,14 +2750,19 @@ class JaxEngine:
         )
         return first
 
-    def _dev_mixed(self, toks, positions, row_ids, tables, row_starts,
-                   row_lens, ctx_lens, last_flat, temps, top_ks, top_ps,
-                   seeds, pens, pen_rows, mask_packed=None, lora_idx=None):
+    def _dev_mixed(self, p: dict):
+        """One mixed step from its operands as the "mixed" broadcast carries
+        them (_blank_mixed_pack's keys). A pack that carries "prime" is a
+        priming call (_prime_mixed_family): it runs on a copy of the
+        sampling key and leaves `_rng` as it was, so that seeded streams do
+        not depend on when the family was compiled."""
+        prime = "prime" in p
+        pens = p["pens"]
         samp = SamplingParams(
-            temperature=jnp.asarray(temps),
-            top_k=jnp.asarray(top_ks),
-            top_p=jnp.asarray(top_ps),
-            seed=jnp.asarray(seeds),
+            temperature=jnp.asarray(p["temps"]),
+            top_k=jnp.asarray(p["top_ks"]),
+            top_p=jnp.asarray(p["top_ps"]),
+            seed=jnp.asarray(p["seeds"]),
             presence=jnp.asarray(pens[:, 0]),
             frequency=jnp.asarray(pens[:, 1]),
             repetition=jnp.asarray(pens[:, 2]),
@@ -2719,34 +2771,36 @@ class JaxEngine:
             self.params,
             self.kv_k,
             self.kv_v,
-            jnp.asarray(toks),
-            jnp.asarray(positions),
-            jnp.asarray(row_ids),
-            jnp.asarray(tables),
-            jnp.asarray(row_starts),
-            jnp.asarray(row_lens),
-            jnp.asarray(ctx_lens),
-            jnp.asarray(last_flat),
+            jnp.asarray(p["toks"]),
+            jnp.asarray(p["positions"]),
+            jnp.asarray(p["row_ids"]),
+            jnp.asarray(p["tables"]),
+            jnp.asarray(p["row_starts"]),
+            jnp.asarray(p["row_lens"]),
+            jnp.asarray(p["ctx_lens"]),
+            jnp.asarray(p["last_flat"]),
             samp,
-            self._rng,
-            jnp.asarray(pen_rows),
+            jnp.copy(self._rng) if prime else self._rng,  # donated
+            jnp.asarray(p["pen_rows"]),
         )
-        if mask_packed is None and lora_idx is None:
+        if "mask" not in p:
             # plain pack: the lean program, byte-identical operands to the
             # pre-variant fused path
-            first, self.kv_k, self.kv_v, self._rng = self._mixed_step(*args)
+            first, self.kv_k, self.kv_v, rng = self._mixed_step(*args)
         else:
             # variant pack: the mask operand is always present (all-ones
             # for maskless packs — an exact no-op), the LoRA operand rides
             # iff adapters are registered (idx 0 rows are the base no-op),
             # so exactly ONE variant program exists per deployment
             lora = (
-                self._lora_operand(lora_idx)
-                if self._lora is not None and lora_idx is not None else None
+                self._lora_operand(p["lora_idx"])
+                if self._lora is not None and "lora_idx" in p else None
             )
-            first, self.kv_k, self.kv_v, self._rng = self._mixed_step_variant(
-                *args, jnp.asarray(mask_packed), lora
+            first, self.kv_k, self.kv_v, rng = self._mixed_step_variant(
+                *args, jnp.asarray(p["mask"]), lora
             )
+        if not prime:
+            self._rng = rng
         return first
 
     def _dev_prefill_mm(self, toks, positions, tables, ctx_lens, last_idx,
@@ -3201,16 +3255,7 @@ class JaxEngine:
                     )
                 )
             elif tag == "mixed":
-                await self._run_on_device(
-                    partial(
-                        self._dev_mixed,
-                        p["toks"], p["positions"], p["row_ids"], p["tables"],
-                        p["row_starts"], p["row_lens"], p["ctx_lens"],
-                        p["last_flat"], p["temps"], p["top_ks"], p["top_ps"],
-                        p["seeds"], p["pens"], p["pen_rows"],
-                        p.get("mask"), p.get("lora_idx"),
-                    )
-                )
+                await self._run_on_device(partial(self._dev_mixed, p))
             elif tag == "block":
                 await self._run_on_device(self._dev_block)
             elif tag == "block_guided":
@@ -3773,6 +3818,7 @@ class JaxEngine:
         self._last_prefill_shape = (
             B_pf * bucket, sum(ch for _, ch, _ in meta)
         )
+        self._count_expert_rows(*self._last_prefill_shape)
 
         if any(s.mm for s in chosen):
             # multimodal splice operands: encoder rows land at their
@@ -3929,6 +3975,7 @@ class JaxEngine:
             tag="prefill", shape=(T_pad, 1),
         )
         self._last_prefill_shape = (T_pad, chunk)
+        self._count_expert_rows(T_pad, chunk)
         slot.prefill_pos += chunk
         self._pending_prefill.append({"first": first_dev, "done": [(slot, 0)]})
 
@@ -4471,12 +4518,14 @@ class JaxEngine:
         mixed step needs host-authoritative lanes), or the planner
         declines.
 
-        Shapes stay bounded: flat tokens pow2-bucketed to
-        config.mixed_max_tokens, ONE fixed row bucket
+        Shapes are a closed family, compiled together at its first use
+        (_prime_mixed_family): at most three flat-token buckets
+        (bucketing.mixed_token_buckets), ONE fixed row bucket
         (self._mixed_row_bucket — the row axis only sizes scalar
-        operands), tables pow2-bucketed like the prefill dispatch. Row
-        starts are aligned to the Pallas ragged kernel's q tile exactly
-        when ops._pallas_eligible says the kernel will run; on the XLA
+        operands), and one table width under the Pallas ragged kernel
+        (pow2 rungs on the XLA reference path). Row starts are aligned to
+        the Pallas ragged kernel's q tile exactly when
+        ops._pallas_eligible says the kernel will run; on the XLA
         reference path the packer is dense."""
         cfg = self.config
         self._mixed_wait_drain = False
@@ -4545,6 +4594,33 @@ class JaxEngine:
             for s in cands:
                 s.sched_skips += 1
             return False
+        def pack_shape(chunks, lanes):
+            """(variant, ctx_pages) of a pack of these prefill chunks and
+            decode lanes: the lean program or the variant with the mask /
+            adapter operands (a guided or lora row), and the table rung
+            that holds the longest context."""
+            slots = [s for s, _ in chunks] + [self.slots[i] for i in lanes]
+            pages = 1
+            for s, ch in chunks:
+                pages = max(pages, -(-(s.prefill_pos + ch) // cfg.page_size))
+            for i in lanes:
+                extra = d if self.slots[i].guided_fsm is None else 0
+                pages = max(
+                    pages,
+                    (int(self.seq_lens[i]) - 1 + extra) // cfg.page_size + 1,
+                )
+            return (
+                any(s.guided_fsm is not None or s.lora_idx for s in slots),
+                _bucket_for(pages, self._mixed_table_rungs),
+            )
+
+        shape = pack_shape(list(zip(plan.chosen, plan.chunks)), active)
+        if shape not in self._mixed_primed:
+            # first use: compile the family, then plan again — the await
+            # let arrivals and cancellations in, and nothing of this plan
+            # is committed yet
+            await self._prime_mixed_family(*shape)
+            return await self._dispatch_mixed()
         # one decode step of page headroom (1 + d under spec: draft rows
         # write KV at speculative positions); growth can preempt —
         # re-filter both the decode set and the chosen prefill slots
@@ -4572,11 +4648,6 @@ class JaxEngine:
         def aligned(n: int) -> int:
             return -(-n // align) * align
 
-        # the bucket cap floored to the alignment, mirroring plan_mixed's
-        # budget: total <= cap by construction, and a non-aligned
-        # mixed_max_tokens can never produce an N_pad the Pallas kernel's
-        # N % tile_q assert would reject
-        cap = cfg.mixed_max_tokens - cfg.mixed_max_tokens % align
         # recompute the decode row count against the SURVIVING active set
         # (page growth can preempt lanes out from under the plan)
         spec_lanes = {
@@ -4586,56 +4657,27 @@ class JaxEngine:
         n_rows_decode = len(active) + d * len(spec_lanes)
         total = sum(aligned(ch) for _, ch in chosen) \
             + aligned(1) * n_rows_decode
-        N_pad = min(_next_pow2(max(total, align)), cap)
-        R_pad = self._mixed_row_bucket
-        max_pages_needed = 1
-        for s, ch in chosen:
-            pages = (s.prefill_pos + ch + cfg.page_size - 1) // cfg.page_size
-            max_pages_needed = max(max_pages_needed, pages)
-        for i in active:
-            extra = d if i in spec_lanes else 0
-            pages = (int(self.seq_lens[i]) - 1 + extra) // cfg.page_size + 1
-            max_pages_needed = max(max_pages_needed, pages)
-        ctx_pages = min(_next_pow2(max_pages_needed), cfg.max_pages_per_seq)
-        P = ctx_pages + 1
-        pad_pos = P * cfg.page_size - 1  # pads write to the scratch tail
-
-        W = cfg.penalty_window
-        toks = np.zeros((N_pad,), np.int32)
-        positions = np.full((N_pad,), pad_pos, np.int32)
-        row_ids = np.full((N_pad,), R_pad - 1, np.int32)
-        row_starts = np.full((R_pad,), N_pad, np.int32)
-        row_lens = np.zeros((R_pad,), np.int32)
-        ctx_lens = np.zeros((R_pad,), np.int32)
-        tables = np.full((R_pad, P), SCRATCH_PAGE, np.int32)
-        last_flat = np.zeros((R_pad,), np.int32)
-        temps = np.zeros((R_pad,), np.float32)
-        top_ks = np.zeros((R_pad,), np.int32)
-        top_ps = np.ones((R_pad,), np.float32)
-        seeds = np.zeros((R_pad,), np.uint32)
-        pens = np.zeros((R_pad, 3), np.float32)
-        pens[:, 2] = 1.0  # repetition off
-        pen_rows = np.full((R_pad, W), -1, np.int32)
-
-        # variant operands: a bitpacked per-row FSM mask whenever any
-        # guided/lora row packs (all-ones rows are exact no-ops), plus
-        # per-row adapter indices when adapters are registered (index 0 =
-        # the all-zero base adapter). Pure-plain and pure-spec packs keep
-        # the LEAN program — byte-identical operands to the split path.
-        dec_slots = [self.slots[i] for i in active]
-        any_guided = any(
-            s.guided_fsm is not None for s, _ in chosen
-        ) or any(s.guided_fsm is not None for s in dec_slots)
-        any_lora = any(s.lora_idx for s, _ in chosen) or any(
-            s.lora_idx for s in dec_slots
-        )
-        mask_packed = None
-        lora_rows = None
-        if any_guided or any_lora:
-            V = self.model_config.vocab_size
-            mask_packed = np.full((R_pad, (V + 7) // 8), 0xFF, np.uint8)
-            if self._lora is not None:
-                lora_rows = np.zeros((R_pad,), np.int32)
+        # pure-plain and pure-spec packs keep the LEAN program —
+        # byte-identical operands to the split path; any guided or lora
+        # row takes the variant (all-ones mask rows and adapter index 0
+        # are exact no-ops for the rows beside it)
+        variant, ctx_pages = pack_shape(chosen, active)
+        # total <= the largest bucket by construction: it is plan_mixed's
+        # budget, mixed_max_tokens floored to the alignment, so that the
+        # Pallas kernel's N % tile_q assert holds for every bucket
+        payload = self._blank_mixed_pack(total, ctx_pages, variant)
+        N_pad = len(payload["toks"])
+        toks, positions, row_ids = (
+            payload["toks"], payload["positions"], payload["row_ids"])
+        tables, row_starts, row_lens = (
+            payload["tables"], payload["row_starts"], payload["row_lens"])
+        ctx_lens, last_flat = payload["ctx_lens"], payload["last_flat"]
+        temps, top_ks, top_ps, seeds = (
+            payload["temps"], payload["top_ks"], payload["top_ps"],
+            payload["seeds"])
+        pens, pen_rows = payload["pens"], payload["pen_rows"]
+        mask_packed = payload.get("mask")
+        lora_rows = payload.get("lora_idx")
 
         off = 0
         row = 0
@@ -4734,25 +4776,9 @@ class JaxEngine:
                 else:
                     self.mixed_rows_plain += 1
 
-        payload = {
-            "toks": toks, "positions": positions, "row_ids": row_ids,
-            "tables": tables, "row_starts": row_starts,
-            "row_lens": row_lens, "ctx_lens": ctx_lens,
-            "last_flat": last_flat, "temps": temps, "top_ks": top_ks,
-            "top_ps": top_ps, "seeds": seeds, "pens": pens,
-            "pen_rows": pen_rows,
-        }
-        if mask_packed is not None:
-            payload["mask"] = mask_packed
-        if lora_rows is not None:
-            payload["lora_idx"] = lora_rows
         self._bcast("mixed", payload)
         first_dev = await self._run_on_device(
-            partial(
-                self._dev_mixed, toks, positions, row_ids, tables,
-                row_starts, row_lens, ctx_lens, last_flat, temps, top_ks,
-                top_ps, seeds, pens, pen_rows, mask_packed, lora_rows,
-            ),
+            partial(self._dev_mixed, payload),
             tag="mixed", shape=(N_pad, row),
         )
         completions = []
@@ -4775,11 +4801,95 @@ class JaxEngine:
             "progressed": progressed, "decode": decode_rows,
             "spec": spec_rows,
         })
+        real = sum(ch for _, ch, _ in meta) + n_rows_decode
         self.mixed_steps += 1
         self.mixed_padded_tokens += N_pad
-        self.mixed_real_tokens += sum(ch for _, ch, _ in meta) + n_rows_decode
+        self.mixed_real_tokens += real
+        self._count_expert_rows(N_pad, real)
         self._step_counter += 1
         return True
+
+    def _blank_mixed_pack(self, tokens: int, pages: int,
+                          variant: bool) -> dict:
+        """The operands of one mixed step that holds `tokens` flat slots
+        and contexts of `pages` pages, with no row packed yet, keyed as
+        the "mixed" broadcast carries them: every slot padding (position
+        at the scratch tail, owned by the last row), every row empty
+        (starting past the buffer), tables of SCRATCH_PAGE, sampling and
+        penalties off. `variant` adds the all-ones FSM mask and, where
+        adapters are registered, the per-row adapter indices (0 = base).
+        The one place that mints a mixed step's shapes: a member of the
+        family, whatever it is asked for."""
+        cfg = self.config
+        N_pad = _bucket_for(tokens, self._mixed_token_buckets)
+        R_pad = self._mixed_row_bucket
+        P = _bucket_for(pages, self._mixed_table_rungs) + 1
+        pens = np.zeros((R_pad, 3), np.float32)
+        pens[:, 2] = 1.0  # repetition off
+        pack = {
+            "toks": np.zeros((N_pad,), np.int32),
+            # pads write to the scratch tail
+            "positions": np.full((N_pad,), P * cfg.page_size - 1, np.int32),
+            "row_ids": np.full((N_pad,), R_pad - 1, np.int32),
+            "tables": np.full((R_pad, P), SCRATCH_PAGE, np.int32),
+            "row_starts": np.full((R_pad,), N_pad, np.int32),
+            "row_lens": np.zeros((R_pad,), np.int32),
+            "ctx_lens": np.zeros((R_pad,), np.int32),
+            "last_flat": np.zeros((R_pad,), np.int32),
+            "temps": np.zeros((R_pad,), np.float32),
+            "top_ks": np.zeros((R_pad,), np.int32),
+            "top_ps": np.ones((R_pad,), np.float32),
+            "seeds": np.zeros((R_pad,), np.uint32),
+            "pens": pens,
+            "pen_rows": np.full((R_pad, cfg.penalty_window), -1, np.int32),
+        }
+        if variant:
+            V = self.model_config.vocab_size
+            pack["mask"] = np.full((R_pad, (V + 7) // 8), 0xFF, np.uint8)
+            if self._lora is not None:
+                pack["lora_idx"] = np.zeros((R_pad,), np.int32)
+        return pack
+
+    async def _prime_mixed_family(self, variant: bool, ctx_pages: int):
+        """Compile the lean (or variant) mixed_step programs of one table
+        width together, every token bucket, the first time one is needed:
+        a program first met under traffic is seconds of stall inside a
+        request's time to first token, and which ones a run meets depends
+        on its traffic. Under the Pallas ragged kernel there is one width,
+        so this is the whole family and no later pack meets a shape the
+        jit cache lacks; the XLA reference keeps its rungs and is primed a
+        rung at a time (its programs cost with their width, and all of
+        them at once would hold a tp=4 worker for minutes). Each member
+        runs once on a pack whose one row is one token at position 0 of
+        the scratch page (an all-padding pack would hand the grouped
+        expert matmul an empty grid): the donated pool is written only in
+        its scratch page. Sent through _bcast like any pack, so followers
+        replay it; untimed, so the cost model learns no compile time; on a
+        copy of `_rng` (_dev_mixed)."""
+        self._mixed_primed.add((variant, ctx_pages))
+        for N_pad in list(self._mixed_token_buckets):
+            pack = self._blank_mixed_pack(N_pad, ctx_pages, variant)
+            pack["positions"][0] = 0
+            pack["row_ids"][0] = 0
+            pack["row_starts"][0] = 0
+            pack["row_lens"][0] = 1
+            pack["prime"] = np.ones((1,), np.int32)
+            self._bcast("mixed", pack)
+            await self._run_on_device(
+                partial(self._dev_mixed, pack), tag="mixed_prime"
+            )
+
+    def _count_expert_rows(self, T: int, real: int, steps: int = 1):
+        """Account one dispatch of `steps` forward passes over T token
+        slots, `real` of them real, to the expert-row counters (MoE only)."""
+        if not isinstance(self.model_config, moe.MoeConfig):
+            return
+        routed, computed = self._model.expert_rows(
+            self.model_config, T, real,
+            is_quant(self.params["layers"]["w_gate"]),
+        )
+        self.expert_rows_routed += routed * steps
+        self.expert_rows_computed += computed * steps
 
     async def _dispatch_decode(self) -> bool:
         cfg = self.config
@@ -4976,6 +5086,12 @@ class JaxEngine:
             # guided/lora blocks above drain through _process_block
             kind = "spec" if cfg.spec_mode else "block"
         self._last_decode_shape = (B * adv, len(active) * adv)
+        if kind == "spec":
+            # a round verifies 1 + d tokens a lane in one batched pass
+            per = 1 + cfg.spec_draft_len
+            self._count_expert_rows(B * per, len(active) * per, cfg.spec_rounds)
+        else:
+            self._count_expert_rows(B, len(active), adv)
         entry = {
             "lanes": [(i, self.slots[i]) for i in active],
             "toks": toks_dev, "kind": kind,

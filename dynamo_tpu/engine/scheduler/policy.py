@@ -39,7 +39,7 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
 
-from ..bucketing import bucket_for, next_pow2 as _next_pow2
+from ..bucketing import bucket_for, mixed_token_buckets
 from .cost_model import CostModel
 from .sla import SlaConfig
 
@@ -63,7 +63,7 @@ class MixedPlan:
     the flat ragged buffer beside the active decode lanes, and how big
     the buffer is (engine `_dispatch_mixed`)."""
 
-    bucket: int  # flat token bucket (pow2, <= config.mixed_max_tokens)
+    bucket: int  # flat token bucket (one of bucketing.mixed_token_buckets)
     chosen: List  # prefill slots riding this dispatch, in row order
     chunks: List[int]  # granted chunk per chosen slot (1:1 with chosen)
     n_decode: int  # decode rows packed beside the chunks
@@ -354,9 +354,11 @@ class StepPlanner:
         Under sla with an ITL target, the mixed step IS the decode step
         (it advances every decode lane one token), so its predicted wall
         time is budgeted directly against `itl_target_ms`: chunks are
-        halved until the CostModel("mixed", bucket, rows) estimate fits,
-        floored at one aligned unit per chunk (a mixed step never defers
-        outright — serving the decode lanes is the point).
+        halved until the CostModel("mixed", bucket, rows) estimate fits or
+        the pack is down to the smallest token bucket (a step is priced by
+        its bucket, so smaller chunks buy nothing there), floored at one
+        aligned unit per chunk (a mixed step never defers outright —
+        serving the decode lanes is the point).
 
         Pure: no counters or decision records — the engine may still
         abandon the plan (pipeline in flight, page-growth preemption);
@@ -392,7 +394,8 @@ class StepPlanner:
             return None
 
         total = budget - space
-        bucket = min(_next_pow2(max(total, align)), budget)
+        buckets = mixed_token_buckets(cfg, align)
+        bucket = bucket_for(total, buckets)
         rows = len(chosen) + n_decode + n_spec_rows
         reason = "mixed"
         t = self.cost.predict("mixed", bucket, rows)
@@ -402,12 +405,15 @@ class StepPlanner:
             and t is not None
         ):
             itl_budget = self.sla.itl_target_ms / 1000.0
-            while t is not None and t > itl_budget and max(chunks) > align:
+            while (
+                t is not None and t > itl_budget and max(chunks) > align
+                and bucket > buckets[0]  # below it no program is cheaper
+            ):
                 # halve the biggest chunk (floored at one aligned unit)
                 i = max(range(len(chunks)), key=lambda j: chunks[j])
                 chunks[i] = max(align, chunks[i] // 2)
                 total = dec_tokens + sum(aligned(ch) for ch in chunks)
-                bucket = min(_next_pow2(max(total, align)), budget)
+                bucket = bucket_for(total, buckets)
                 t = self.cost.predict("mixed", bucket, rows)
                 reason = "mixed-shrunk"
         return MixedPlan(

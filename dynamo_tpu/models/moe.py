@@ -15,6 +15,7 @@ FFN batched over the expert dim on the MXU.
 
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
@@ -23,10 +24,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..ops.paged_attention import scope_allows_kernels
 from ..parallel.mesh import EP_AXIS, SP_AXIS
 from . import llama
 from .llama import LlamaConfig, rms_norm
-from .quant import qeinsum
+from .quant import is_quant, qeinsum
 
 
 @dataclass(frozen=True)
@@ -153,18 +155,183 @@ def expert_capacity(num_tokens: int, config: MoeConfig) -> int:
     return max(4, (c + 3) // 4 * 4)
 
 
-def moe_mlp(layer: Dict[str, Any], x: jax.Array, c: MoeConfig) -> jax.Array:
-    """Sparse MoE block for x [T, H]: top-k routing -> capacity-bounded
-    one-hot dispatch -> batched expert SwiGLU -> weighted combine."""
+class ExpertStack:
+    """A stacked expert weight [L, E, k, n] that stays whole under the layer
+    loop's `p[li]`: indexing keeps the stack and notes the layer. A slice
+    handed to a Pallas call is a copy on the TPU (0.94 GB a matrix at
+    Mixtral's widths, three a layer), so the grouped matmul takes the
+    stack as [L*E, k, n] and reaches layer li's experts as groups li*E ..
+    li*E + E - 1 (as the attention ops take the KV pool, kv_quant.KVLayer);
+    `array` is the slice, for the capacity einsum, which XLA fuses."""
+
+    def __init__(self, stack: jax.Array, li=None):
+        self.stack, self.li = stack, li
+
+    def __getitem__(self, li) -> "ExpertStack":
+        return ExpertStack(self.stack, li)
+
+    @property
+    def array(self) -> jax.Array:
+        return self.stack[self.li]
+
+
+def _whole_expert_stacks(params: Dict[str, Any]) -> Dict[str, Any]:
+    """`params` with the expert stacks wrapped (see ExpertStack), for the
+    forwards whose token count can reach the grouped path. Quantized
+    stacks are left as they are: they keep the capacity path."""
+    layers = params["layers"]
+    if is_quant(layers["w_gate"]):
+        return params
+    wrapped = {k: ExpertStack(layers[k]) for k in ("w_gate", "w_up", "w_down")}
+    return {**params, "layers": {**layers, **wrapped}}
+
+
+# Above this many tokens the capacity einsum is bound by arithmetic, not
+# by the expert weights it streams: it multiplies E*C rows whatever was
+# routed, C = T at the dropless capacity_factor E/K, and a v5e's ridge is
+# 197 TFLOP/s / 819 GB/s = 240 rows an expert. A decode block (T =
+# max_num_seqs) sits under it and keeps the einsum; a mixed step or a
+# prefill chunk sits over it and multiplies the routed rows alone.
+GROUPED_MIN_TOKENS = 256
+# megablox row tile. Every expert with rows in a tile streams its weights
+# for it, so a tile's arithmetic should hide behind one expert's stream:
+# at Mixtral's widths 256 rows multiply in 0.15 ms beside a stream of 0.14
+# ms; 512 do not (0.30 ms). On the chip, one layer's block, ms at 128 / 256
+# / 512 rows: 5.66 / 5.76 / 8.42 with 190 real tokens of 1,024, 6.69 / 6.35
+# / 9.42 with 290, 13.45 / 10.33 / 13.38 with 1,100 of 2,048 (PERF.md, PR 32).
+_GMM_ROWS = 256
+
+
+def _tile(n: int) -> int:
+    """Largest k/n tile of the grouped matmul that divides n: a 1024 x
+    1024 bf16 block of expert weights is 2 MB, double-buffered in VMEM
+    (2048 does not fit beside it; 512 is a quarter slower on the chip)."""
+    return next(t for t in (1024, 512, 256, 128) if n % t == 0)
+
+
+def _grouped_matmul(lhs: jax.Array, w: ExpertStack, group_sizes: jax.Array):
+    """lhs [M, k] (rows sorted by group) x rhs [G, k, n] -> [M, n] f32,
+    rhs the whole stack of `w` as [L*E, k, n]: the first group_sizes[0]
+    rows against rhs[0], the next against rhs[1], and so on; an empty
+    group costs nothing. Rows past sum(group_sizes) are left unwritten.
+    The Pallas grouped matmul where its tiles fit (a TPU, 128-aligned
+    widths), else XLA's ragged_dot."""
+    rhs = w.stack.reshape(-1, *w.stack.shape[2:])
+    m, k = lhs.shape
+    n = rhs.shape[-1]
+    if jax.default_backend() == "tpu" and k % 128 == 0 and n % 128 == 0:
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+        # whole row tiles: the rows added lie past every group
+        lhs = jnp.pad(lhs, ((0, -m % _GMM_ROWS), (0, 0)))
+        return gmm(
+            lhs, rhs, group_sizes, preferred_element_type=jnp.float32,
+            tiling=(_GMM_ROWS, _tile(k), _tile(n)),
+        )[:m]
+    return jax.lax.ragged_dot(
+        lhs, rhs, group_sizes, preferred_element_type=jnp.float32
+    )
+
+
+def _experts_grouped(layer, h, topi, probs, valid, c: MoeConfig) -> jax.Array:
+    """Dropless expert block over the routed rows: sort the (token,
+    expert) assignments by expert, run gate, up and down as grouped
+    matmuls over the sorted rows, weigh and sum each token's K rows.
+    Tokens that `valid` marks as padding are sent to no expert. -> [T, H]
+    f32."""
+    T, H = h.shape
+    E, K = c.num_experts, c.num_experts_per_tok
+    # a plain [E, k, n] weight is a stack of one layer
+    w_gate, w_up, w_down = (
+        w if isinstance(w, ExpertStack) else ExpertStack(w[None], 0)
+        for w in (layer["w_gate"], layer["w_up"], layer["w_down"])
+    )
+    # groups: every layer's experts, all but this layer's empty
+    G = w_gate.stack.shape[0] * E
+    group = w_gate.li * E + topi.reshape(T * K)  # assignment a = t * K + k
+    if valid is not None:
+        # group G does not exist: padding sorts behind every real row and
+        # is counted in no group
+        group = jnp.where(jnp.repeat(valid, K), group, G)
+        probs = jnp.where(valid[:, None], probs, 0.0)
+    order = jnp.argsort(group, stable=True)
+    group_sizes = jnp.zeros((G,), jnp.int32).at[group].add(1, mode="drop")
+    rows = h[order // K]  # [T*K, H]
+    gate = _grouped_matmul(rows, w_gate, group_sizes)
+    up = _grouped_matmul(rows, w_up, group_sizes)
+    act = (jax.nn.silu(gate) * up).astype(c.dtype)
+    down = _grouped_matmul(act, w_down, group_sizes)
+    # rows no group owns were never written: whatever lies there, drop it
+    live = jnp.arange(T * K) < group_sizes.sum()
+    down = jnp.where(live[:, None], down, 0.0)
+    back = jnp.zeros((T * K,), jnp.int32).at[order].set(jnp.arange(T * K))
+    return jnp.einsum("tkh,tk->th", down[back].reshape(T, K, H), probs)
+
+
+def _on_one_device() -> bool:
+    """No mesh in context, and the calling engine's mesh (the attention
+    gate's scope) is a single device. Under an `ep` axis the capacity
+    path's [E, C, H] buffers are what `_constrain_ep` lowers to an
+    all-to-all, and under any mesh a Pallas call has no partitioning rule."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return (mesh is None or mesh.empty) and scope_allows_kernels()
+
+
+def _takes_grouped(T: int, quantized: bool) -> bool:
+    """moe_mlp's choice between its two expert blocks (see there)."""
+    return T >= GROUPED_MIN_TOKENS and _on_one_device() and not quantized
+
+
+def expert_rows(c: MoeConfig, T: int, real: int, quantized: bool):
+    """(routed, computed) expert rows of one layer's moe_mlp over T token
+    slots of which `real` are real: host arithmetic for the engine's
+    counters, the routing itself stays on the device. Routed: real tokens
+    x K. Computed: E x C on the capacity path; on the grouped path an
+    upper bound, whole row tiles and one more for every expert whose
+    block starts inside another's tile."""
+    routed = real * c.num_experts_per_tok
+    if not _takes_grouped(T, quantized):
+        return routed, c.num_experts * expert_capacity(T, c)
+    tiles = -(-routed // _GMM_ROWS) + min(c.num_experts, routed) - 1
+    return routed, max(tiles, 0) * _GMM_ROWS
+
+
+def moe_mlp(
+    layer: Dict[str, Any], x: jax.Array, c: MoeConfig,
+    valid: Optional[jax.Array] = None,
+) -> jax.Array:
+    """Sparse MoE block for x [T, H]: top-k routing, a softmax over the
+    chosen logits, the chosen experts' SwiGLU, the weighted sum. Two ways
+    through the experts, chosen at trace time from T and the mesh:
+
+    * T < GROUPED_MIN_TOKENS (the decode block), any multi-device mesh, or
+      quantized expert stacks: capacity-bounded one-hot dispatch into
+      [E, C, H] buffers and matmuls batched over the expert axis. A token
+      routed to an expert whose C = `expert_capacity(T)` slots are taken is
+      DROPPED for that expert: at the default `capacity_factor` 1.25 a
+      decode step can drop tokens, at E / K it cannot.
+    * otherwise (mixed steps, prefill chunks): grouped matmuls over the
+      rows that were routed (`_experts_grouped`). Dropless by construction;
+      `capacity_factor` is not read. `valid` [T] marks the real slots of a
+      padded flat buffer (`ragged_forward`); padding reaches no expert.
+    """
     T, H = x.shape
     E, K = c.num_experts, c.num_experts_per_tok
-    C = expert_capacity(T, c)
 
     h = rms_norm(x, layer["mlp_norm"], c.rms_norm_eps)
     logits = jnp.dot(h.astype(jnp.float32), layer["router"])  # [T, E]
     topv, topi = jax.lax.top_k(logits, K)  # [T, K]
     probs = jax.nn.softmax(topv, axis=-1)  # renormalized over chosen experts
 
+    if _takes_grouped(T, is_quant(layer["w_gate"])):
+        out = _experts_grouped(layer, h, topi, probs, valid, c)
+        return x + out.astype(c.dtype)
+
+    w_gate, w_up, w_down = (
+        w.array if isinstance(w, ExpertStack) else w
+        for w in (layer["w_gate"], layer["w_up"], layer["w_down"])
+    )
+    C = expert_capacity(T, c)
     # combine weight per (token, expert); 0 where not routed
     combine = jnp.zeros((T, E), jnp.float32)
     combine = combine.at[jnp.arange(T)[:, None], topi].add(probs)
@@ -181,12 +348,10 @@ def moe_mlp(layer: Dict[str, Any], x: jax.Array, c: MoeConfig) -> jax.Array:
     expert_in = _constrain_ep(jnp.einsum("tec,th->ech", dispatch, h))
     # qeinsum: expert stacks may be int8 (models/quant.py) — scale
     # [E, 1, out] applies to the f32 accumulator after the einsum
-    gate = qeinsum("ech,ehi->eci", expert_in, layer["w_gate"])
-    up = qeinsum("ech,ehi->eci", expert_in, layer["w_up"])
+    gate = qeinsum("ech,ehi->eci", expert_in, w_gate)
+    up = qeinsum("ech,ehi->eci", expert_in, w_up)
     act = (jax.nn.silu(gate) * up).astype(c.dtype)
-    expert_out = _constrain_ep(
-        qeinsum("eci,eih->ech", act, layer["w_down"])
-    )
+    expert_out = _constrain_ep(qeinsum("eci,eih->ech", act, w_down))
 
     out = jnp.einsum(
         "ech,tec->th", expert_out, dispatch.astype(jnp.float32) * combine[..., None]
@@ -253,7 +418,8 @@ def prefill_forward(
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One prompt chunk of a single sequence (chunked prefill), MoE MLP."""
     return llama.prefill_forward(
-        params, config, tokens, positions, kv_k, kv_v, page_table, context_len,
+        _whole_expert_stacks(params), config, tokens, positions, kv_k, kv_v,
+        page_table, context_len,
         last_idx=last_idx, mlp_fn=moe_mlp,
     )
 
@@ -275,11 +441,16 @@ def ragged_forward(
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Unified mixed-step forward (engine `_dispatch_mixed`), MoE MLP —
     the flat buffer is already [tokens, H], exactly the shape expert
-    dispatch wants."""
+    dispatch wants. A slot is real when it lies inside its row's
+    [start, start + len): the packer's padding (row tails up to the q
+    tile, the buffer's tail up to its bucket) is routed to no expert."""
+    slot = jnp.arange(tokens.shape[0], dtype=jnp.int32)
+    start = row_starts[row_ids]
+    valid = (slot >= start) & (slot < start + row_lens[row_ids])
     return llama.ragged_forward(
-        params, config, tokens, positions, row_ids, kv_k, kv_v,
-        page_tables, row_starts, row_lens, ctx_lens, last_flat,
-        mlp_fn=moe_mlp, lora=lora,
+        _whole_expert_stacks(params), config, tokens, positions, row_ids,
+        kv_k, kv_v, page_tables, row_starts, row_lens, ctx_lens, last_flat,
+        mlp_fn=functools.partial(moe_mlp, valid=valid), lora=lora,
     )
 
 
@@ -309,8 +480,8 @@ def prefill_forward_batched(
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Batched chunked prefill (multiple sequences per dispatch), MoE MLP."""
     return llama.prefill_forward_batched(
-        params, config, tokens, positions, kv_k, kv_v, page_tables,
-        context_lens, last_idx, mlp_fn=_moe_mlp_nd,
+        _whole_expert_stacks(params), config, tokens, positions, kv_k, kv_v,
+        page_tables, context_lens, last_idx, mlp_fn=_moe_mlp_nd,
         emb_override=emb_override, emb_mask=emb_mask, all_logits=all_logits,
         lora=lora,
     )

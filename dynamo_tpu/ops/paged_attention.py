@@ -148,6 +148,12 @@ def attention_scope(allows_kernels: bool):
         _MESH_ALLOWS_KERNELS.reset(tok)
 
 
+def scope_allows_kernels() -> bool:
+    """False inside the scope of an engine whose mesh spans devices; True
+    in a one-device engine's scope and outside any (bare op calls)."""
+    return _MESH_ALLOWS_KERNELS.get() is not False
+
+
 def _use_pallas_decode() -> bool:
     mode = os.environ.get("DYNAMO_TPU_PAGED_ATTN", "auto")
     if mode == "pallas":
@@ -158,7 +164,7 @@ def _use_pallas_decode() -> bool:
         raise ValueError(
             f"DYNAMO_TPU_PAGED_ATTN={mode!r}: expected auto, pallas or xla"
         )
-    if _MESH_ALLOWS_KERNELS.get() is False:
+    if not scope_allows_kernels():
         return False
     # a backend that cannot be asked is an error, never "use the reference"
     return jax.default_backend() == "tpu"

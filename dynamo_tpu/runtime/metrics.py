@@ -128,6 +128,10 @@ METRICS = {
     "emit_tokens": {"kind": "counter", "layer": "engine", "unit": "tokens", "help": "Tokens emitted to streams.", "export": True},
     "mixed_steps": {"kind": "counter", "layer": "engine", "help": "Fused mixed prefill+decode dispatch steps.", "export": True},
     "split_steps": {"kind": "counter", "layer": "engine", "help": "Split prefill/decode dispatch steps.", "export": True},
+    "mixed_family_size": {"kind": "gauge", "layer": "engine", "unit": "programs", "help": "Programs in the lean mixed_step family (token buckets x table widths).", "export": True},
+    "mixed_family_compiled": {"kind": "gauge", "layer": "engine", "unit": "programs", "help": "Lean mixed_step programs the jit cache holds (the whole family after the first mixed step).", "export": True},
+    "expert_rows_routed": {"kind": "counter", "layer": "engine", "unit": "rows", "help": "Expert rows routed per layer: real tokens x experts per token, summed over dispatches (MoE).", "export": True},
+    "expert_rows_computed": {"kind": "counter", "layer": "engine", "unit": "rows", "help": "Expert rows the expert matmuls multiply per layer: E x capacity, or the grouped matmul's tile-padded rows (MoE).", "export": True},
     # compile telemetry (engine/compile_registry.py, docs/compilation.md):
     # XLA cache growth per staged surface. post_warmup_compiles is THE
     # steady-state contract number — the compile smoke gates on 0

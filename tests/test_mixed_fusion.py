@@ -354,3 +354,168 @@ def test_lora_pool_pinned_full_refuses_and_releases(params, adapters):
     assert len(after) == 4  # pin released at finish -> evict + onboard
     assert st["lora_pool_refusals"] >= 1
     assert st["lora_pool_evictions"] >= 1
+
+
+# --------------------------------------------------------------------- #
+# the mixed step as a closed family of programs (PR 32): at most three
+# token buckets, one table width under the Pallas ragged kernel, and every
+# member compiled at the first mixed step
+# --------------------------------------------------------------------- #
+
+
+@pytest.mark.parametrize("spec", [False, True], ids=["plain", "spec"])
+@pytest.mark.parametrize("align", [1, 8, 16])
+@pytest.mark.parametrize("max_tokens", [48, 256, 2048, 2050, 8192])
+@pytest.mark.parametrize("seqs", [1, 8, 32, 256])
+def test_token_buckets_are_few_and_hold_what_the_planner_packs(
+    seqs, max_tokens, align, spec
+):
+    """Over a grid of EngineConfigs: at most three buckets, ascending,
+    every one a multiple of the packer's alignment, the last one
+    plan_mixed's budget (mixed_max_tokens floored to the alignment), the
+    first one holding a full decode batch twice over unless the budget is
+    smaller."""
+    from dynamo_tpu.engine.bucketing import (
+        MIXED_TOKEN_BUCKETS_MAX,
+        mixed_token_buckets,
+        next_pow2,
+    )
+
+    kw = dict(spec_mode="ngram", spec_draft_len=3) if spec else {}
+    cfg = EngineConfig(max_num_seqs=seqs, mixed_max_tokens=max_tokens, **kw)
+    buckets = mixed_token_buckets(cfg, align)
+    budget = max_tokens - max_tokens % align
+    rows = seqs * (4 if spec else 1)
+    assert 1 <= len(buckets) <= MIXED_TOKEN_BUCKETS_MAX <= 4
+    assert list(buckets) == sorted(set(buckets))
+    assert all(b % align == 0 for b in buckets)
+    assert buckets[-1] == budget
+    assert buckets[0] >= min(budget, 2 * next_pow2(rows * align))
+
+
+def test_the_cells_family_is_two_token_buckets():
+    """The benchmark's cell: 32 lanes, bf16 (rows aligned to 16), the
+    default mixed_max_tokens."""
+    from dynamo_tpu.engine.bucketing import mixed_token_buckets
+
+    cfg = EngineConfig(max_num_seqs=32, max_model_len=4096)
+    assert mixed_token_buckets(cfg, 16) == (1024, 2048)
+
+
+FAMILY_KW = dict(max_num_seqs=4, max_model_len=128, num_pages=96,
+                 max_prefill_batch=2, mixed_max_tokens=256)
+
+
+def _one_width(eng):
+    """Steer the engine onto the ONE table width it takes under the Pallas
+    ragged kernel, which the CPU cannot run: the XLA reference serves the
+    same packs at any width."""
+    assert eng._mixed_primed == set()
+    eng._mixed_table_rungs = (eng.config.max_pages_per_seq,)
+    return eng
+
+
+async def _arrivals_beside(eng, rng, lanes, arrivals, isl):
+    """`lanes` requests decoding, then `arrivals` prompts of `isl` tokens
+    at once: their chunks share mixed steps with the decode lanes."""
+    started = [asyncio.Event() for _ in range(lanes)]
+
+    async def anchor(i):
+        req = PreprocessedRequest(
+            token_ids=rng.randint(5, 200, size=6).tolist(),
+            stop_conditions={"max_tokens": 60, "ignore_eos": True},
+            sampling_options={"temperature": 0.0}, request_id=f"a{i}-{isl}",
+        ).to_dict()
+        async for _ in eng.generate(req, Context()):
+            started[i].set()
+
+    anchors = [asyncio.create_task(anchor(i)) for i in range(lanes)]
+    await asyncio.gather(*(e.wait() for e in started))
+    await asyncio.gather(*(
+        _one(eng, rng.randint(5, 200, size=isl).tolist(), f"r{j}-{lanes}-{isl}", n=4)
+        for j in range(arrivals)
+    ))
+    await asyncio.gather(*anchors)
+
+
+@pytest.mark.parametrize("family", ["dense", "moe"])
+def test_first_mixed_step_compiles_the_whole_family(family, params):
+    """After the first mixed step no pack the scheduler can build meets a
+    mixed_step shape the jit cache lacks: 1 to max_prefill_batch arrivals,
+    contexts from one page to max_model_len, 1 to max_num_seqs - 1 lanes
+    decoding beside them. The priming calls leave no observation in the
+    cost model, and `_rng` and the pool (scratch page apart) as they were."""
+    if family == "moe":
+        from dynamo_tpu.models import moe
+
+        mcfg = moe.MoeConfig.tiny_moe(dtype=jnp.float32, capacity_factor=2.0)
+        eng = JaxEngine(
+            EngineConfig(model="tiny-moe", page_size=PAGE, prefill_buckets=(16, 32),
+                         max_prefill_chunk=32, mixed_dispatch=True, **FAMILY_KW),
+            model_config=mcfg, params=moe.init_params(mcfg, jax.random.PRNGKey(3)),
+        )
+    else:
+        eng = _engine(params, **FAMILY_KW)
+    _one_width(eng)
+
+    async def main():
+        rng = np.random.RandomState(5)
+        st0 = eng.stats()
+        assert st0["mixed_family_size"] == len(eng._mixed_token_buckets) >= 2
+        assert st0["mixed_family_compiled"] == 0
+        rng_before = np.asarray(eng._rng)
+        pool_before = np.asarray(eng.kv_k)[:, 1:].copy()
+        await eng._prime_mixed_family(False, eng.config.max_pages_per_seq)
+        np.testing.assert_array_equal(np.asarray(eng._rng), rng_before)
+        np.testing.assert_array_equal(np.asarray(eng.kv_k)[:, 1:], pool_before)
+        eng._mixed_primed.clear()  # the first pack meets the family itself
+        await _arrivals_beside(eng, rng, lanes=1, arrivals=1, isl=6)
+        first = eng.stats()
+        for lanes, arrivals, isl in [(1, 2, 40), (3, 1, 100), (2, 2, 70),
+                                     (3, 1, 7), (1, 1, 118)]:
+            await _arrivals_beside(eng, rng, lanes, arrivals, isl)
+        last = eng.stats()
+        await eng.close()
+        return first, last
+
+    first, last = asyncio.run(main())
+    assert first["mixed_steps"] >= 1
+    assert first["mixed_family_compiled"] == first["mixed_family_size"]
+    assert last["mixed_steps"] > first["mixed_steps"] + 5
+    assert last["compile_surfaces"]["mixed_step"] == first["mixed_family_size"]
+    assert last["mixed_family_compiled"] == last["mixed_family_size"]
+    # one observation a real step, none from priming
+    assert last["dispatch_mixed_count"] == last["mixed_steps"]
+    assert last["dispatch_mixed_prime_count"] >= first["mixed_family_size"]
+    observed = sum(
+        n for (kind, _, _), (_, n) in eng.scheduler.cost._ewma.items()
+        if kind == "mixed"
+    )
+    assert observed == last["mixed_steps"]
+    if family == "moe":
+        assert 0 < last["expert_rows_routed"] < last["expert_rows_computed"]
+    else:
+        assert last["expert_rows_computed"] == 0
+
+
+def test_xla_reference_keeps_its_rungs_and_primes_a_rung_at_a_time(params):
+    """Where the table's width costs (the XLA reference: this CPU, tp > 1,
+    head sizes that are not multiples of 128) the pow2 rungs stay, and a
+    rung's token buckets are compiled together when it is first reached."""
+    eng = _engine(params, **FAMILY_KW)
+    assert eng.attention_impl["ragged"] == "xla"
+    assert eng._mixed_table_rungs == (1, 2, 4, 8, 16)
+    n_buckets = len(eng._mixed_token_buckets)
+
+    async def main():
+        rng = np.random.RandomState(9)
+        await _arrivals_beside(eng, rng, lanes=1, arrivals=1, isl=6)
+        st = eng.stats()
+        await eng.close()
+        return st
+
+    st = asyncio.run(main())
+    assert st["mixed_family_size"] == 5 * n_buckets
+    assert st["mixed_steps"] >= 1
+    assert st["compile_surfaces"]["mixed_step"] % n_buckets == 0
+    assert n_buckets <= st["mixed_family_compiled"] < st["mixed_family_size"]

@@ -149,6 +149,191 @@ def test_engine_warmup_reaches_its_end_on_tiny_moe(mparams):
 
 
 # --------------------------------------------------------------------- #
+# the two expert blocks of moe_mlp (PR 32): grouped matmuls over the routed
+# rows at and above GROUPED_MIN_TOKENS on one device, the capacity einsum
+# below it (the decode block), under a mesh and for quantized stacks
+# --------------------------------------------------------------------- #
+
+
+def _parent_moe_mlp(layer, x, c):
+    """moe_mlp as it stood before the grouped path (commit 8338d8b), kept
+    verbatim as the reference the decode block's program is held to."""
+    from dynamo_tpu.models.llama import rms_norm
+    from dynamo_tpu.models.quant import qeinsum
+
+    T, H = x.shape
+    E, K = c.num_experts, c.num_experts_per_tok
+    C = moe.expert_capacity(T, c)
+
+    h = rms_norm(x, layer["mlp_norm"], c.rms_norm_eps)
+    logits = jnp.dot(h.astype(jnp.float32), layer["router"])  # [T, E]
+    topv, topi = jax.lax.top_k(logits, K)  # [T, K]
+    probs = jax.nn.softmax(topv, axis=-1)
+
+    combine = jnp.zeros((T, E), jnp.float32)
+    combine = combine.at[jnp.arange(T)[:, None], topi].add(probs)
+    routed = combine > 0.0  # [T, E]
+
+    pos = jnp.cumsum(routed.astype(jnp.int32), axis=0) - 1  # [T, E]
+    keep = routed & (pos < C)
+    dispatch = (
+        jax.nn.one_hot(jnp.where(keep, pos, C), C, dtype=h.dtype)
+        * keep[..., None]
+    )  # [T, E, C]
+
+    expert_in = moe._constrain_ep(jnp.einsum("tec,th->ech", dispatch, h))
+    gate = qeinsum("ech,ehi->eci", expert_in, layer["w_gate"])
+    up = qeinsum("ech,ehi->eci", expert_in, layer["w_up"])
+    act = (jax.nn.silu(gate) * up).astype(c.dtype)
+    expert_out = moe._constrain_ep(
+        qeinsum("eci,eih->ech", act, layer["w_down"])
+    )
+
+    out = jnp.einsum(
+        "ech,tec->th", expert_out, dispatch.astype(jnp.float32) * combine[..., None]
+    )
+    return x + out.astype(c.dtype)
+
+
+def _reference_block():
+    """benchmark/references/moe.py's float32 block (dropless, no capacity,
+    nothing from dynamo_tpu): `moe_mlp(x, w, cfg) -> (x + out, margin)`."""
+    import os
+    import sys
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(__file__)), "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from references import moe as ref
+
+    return ref.moe_mlp
+
+
+def _layer_and_x(dtype, T, seed=0):
+    cfg = moe.MoeConfig.tiny_moe(dtype=dtype, capacity_factor=2.0)  # E / K
+    params = moe.init_params(cfg, jax.random.PRNGKey(7))
+    layer = jax.tree.map(lambda p: p[1], params["layers"])
+    x = jax.random.normal(jax.random.PRNGKey(seed + T), (T, cfg.hidden_size))
+    return cfg, params, layer, x.astype(dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("real_share", [0.1, 0.5, 1.0])
+@pytest.mark.parametrize("T", [64, 1024])
+def test_grouped_experts_match_the_einsum_and_the_reference(
+    T, real_share, dtype, monkeypatch
+):
+    """The grouped path over a flat buffer whose padding is poisoned with
+    NaN, against the capacity einsum over the real rows alone at
+    capacity_factor E / K (which drops nothing) and, in float32, against
+    the benchmark's plain float32 block: same routing, outputs within the
+    dtype's tolerance, and padding reaches no expert (a NaN row in a
+    group would spread through nothing here, but a padding row counted
+    into a group would come back finite)."""
+    cfg, _, layer, x = _layer_and_x(dtype, T)
+    real = max(1, int(T * real_share))
+    valid = jnp.arange(T) < real
+    poisoned = jnp.where(valid[:, None], x, jnp.nan)
+
+    monkeypatch.setattr(moe, "GROUPED_MIN_TOKENS", 1)
+    grouped = moe.moe_mlp(layer, poisoned, cfg, valid=valid)
+    monkeypatch.setattr(moe, "GROUPED_MIN_TOKENS", 10**9)
+    einsum = moe.moe_mlp(layer, x[:real], cfg)
+
+    assert bool(jnp.isfinite(grouped[:real]).all())
+    assert bool(jnp.isnan(grouped[real:]).all())
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    scale = float(jnp.abs(einsum.astype(jnp.float32)).max())
+    np.testing.assert_allclose(
+        np.asarray(grouped[:real], np.float32), np.asarray(einsum, np.float32),
+        atol=tol * scale, rtol=0,
+    )
+    if dtype == jnp.float32:
+        ref, _ = _reference_block()(x[:real], layer, cfg)
+        np.testing.assert_allclose(
+            np.asarray(grouped[:real]), np.asarray(ref), atol=tol * scale, rtol=0
+        )
+
+
+GROUPED_OP = "ragged_dot"  # _grouped_matmul's primitive off the TPU
+
+
+@pytest.mark.parametrize("case", ["one_device", "below_threshold", "mesh", "quantized"])
+def test_moe_mlp_picks_its_expert_block_from_what_it_sees(case):
+    """T, the mesh and the weights' format decide, at trace time: no
+    option. Only a large T on one device with plain weights is grouped."""
+    from dynamo_tpu.models.quant import quantize_tree
+    from dynamo_tpu.ops.paged_attention import attention_scope
+
+    T = 32 if case == "below_threshold" else moe.GROUPED_MIN_TOKENS
+    cfg, params, layer, x = _layer_and_x(jnp.float32, T)
+    if case == "quantized":
+        layer = jax.tree.map(lambda p: p[1], quantize_tree(params)["layers"])
+    with attention_scope(case != "mesh"):
+        text = str(jax.make_jaxpr(lambda l, x: moe.moe_mlp(l, x, cfg))(layer, x))
+    assert (GROUPED_OP in text) == (case == "one_device")
+
+
+@pytest.mark.parametrize("T", [4, 32, 255])
+def test_decode_block_program_is_the_parents(T):
+    """Below the threshold moe_mlp lowers to the text the parent's moe_mlp
+    lowers to, and so does the decode forward around it: the decode block
+    is bound by the weight stream and keeps the einsum, byte for byte."""
+    cfg, params, layer, x = _layer_and_x(jnp.float32, T)
+
+    def lowered(mlp):
+        return jax.jit(lambda l, x: mlp(l, x, cfg)).lower(layer, x).as_text()
+
+    assert lowered(moe.moe_mlp) == lowered(_parent_moe_mlp)
+
+    from dynamo_tpu.ops.kv_quant import alloc_kv_store
+
+    kv = alloc_kv_store(cfg.num_layers, 9, 8, cfg.num_kv_heads, cfg.head_dim,
+                        cfg.dtype, "none")
+    i32 = jnp.int32
+    args = (jnp.zeros((T,), i32), jnp.zeros((T,), i32), kv, kv,
+            jnp.ones((T, 3), i32), jnp.ones((T,), i32))
+
+    def lowered_decode(fn, **kw):
+        return jax.jit(
+            lambda p, *a: fn(p, cfg, *a, **kw), donate_argnums=(3, 4)
+        ).lower(params, *args).as_text()
+
+    assert lowered_decode(moe.decode_forward) == lowered_decode(
+        llama.decode_forward, mlp_fn=_parent_moe_mlp)
+
+
+def test_whole_expert_stack_gives_what_the_slice_gives():
+    """ragged_forward hands moe_mlp the stacked weights whole (ExpertStack:
+    a slice is a copy under a Pallas call); layer li's experts are groups
+    li*E .. li*E+E-1 of the stack, the other layers' groups empty."""
+    cfg, params, layer, x = _layer_and_x(jnp.float32, moe.GROUPED_MIN_TOKENS)
+    valid = jnp.arange(x.shape[0]) < 100
+    whole = jax.tree.map(
+        lambda p: p[1], moe._whole_expert_stacks(params)["layers"])
+    assert isinstance(whole["w_gate"], moe.ExpertStack) and whole["w_gate"].li == 1
+    a = moe.moe_mlp(whole, x, cfg, valid=valid)
+    b = moe.moe_mlp(layer, x, cfg, valid=valid)
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6, rtol=0)
+
+
+@pytest.mark.parametrize("T,real,grouped", [(32, 20, False), (1024, 190, True),
+                                            (1024, 0, True), (2048, 2048, True)])
+def test_expert_rows_is_host_arithmetic(T, real, grouped):
+    """(routed, computed) as the engine counts them per dispatch: routed =
+    real x K; computed = E x C on the einsum path, whole 256-row tiles
+    plus one for every expert that can straddle on the grouped path."""
+    cfg = moe.MoeConfig.mixtral_8x7b(capacity_factor=4.0)
+    routed, computed = moe.expert_rows(cfg, T, real, quantized=False)
+    assert routed == 2 * real
+    if not grouped:
+        assert computed == 8 * moe.expert_capacity(T, cfg) == 8 * T
+    else:
+        assert computed % 256 == 0 and routed <= computed <= routed + 8 * 256
+        assert computed < 8 * T
+
+
+# --------------------------------------------------------------------- #
 # what went with the second ("local") decode block
 # --------------------------------------------------------------------- #
 

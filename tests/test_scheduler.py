@@ -42,6 +42,8 @@ class _FakeCfg:
     decode_block_steps: int = 4
     max_num_seqs: int = 32
     mixed_max_tokens: int = 512
+    spec_mode: Optional[str] = None
+    spec_draft_len: int = 4
 
 
 @dataclass
@@ -351,16 +353,21 @@ def test_unknown_cost_means_no_constraint():
 
 def test_plan_mixed_packs_chunks_beside_decode_rows():
     """plan_mixed grants aligned prefill chunks into the flat-token budget
-    left beside the decode rows; the bucket is the pow2 cover of the
+    left beside the decode rows; the bucket is the smallest of the mixed
+    step's token buckets (bucketing.mixed_token_buckets) that holds the
     packed total."""
-    p = _planner(policy="fifo")
+    p = _planner(policy="fifo", cfg=_FakeCfg(max_num_seqs=4))
     cands = _slots(2, prompt_len=100)
     plan = p.plan_mixed(cands, n_decode=4, align=8)
     assert plan is not None and plan.reason == "mixed"
     assert plan.chosen == cands and plan.chunks == [100, 100]
     assert plan.n_decode == 4
     # 2x ceil(100/8)*8 = 208 chunk span + 4x8 decode span = 240 -> 256
+    # of (128, 256, 512)
     assert plan.bucket == 256
+    # 32 lanes: the floor holds a full decode batch twice over, 512 = the cap
+    assert _planner(policy="fifo").plan_mixed(
+        cands, n_decode=4, align=8).bucket == 512
     # plan_mixed is pure — grants count only on engine commit
     assert p.granted_tokens == 0 and p.granted_chunks == 0
     p.commit_mixed(plan, list(zip(plan.chosen, plan.chunks)))
@@ -397,7 +404,7 @@ def test_plan_mixed_itl_budget_shrinks_chunks():
     """Under sla with an ITL target, a too-slow predicted mixed step
     halves chunks until the estimate fits (never defers outright — the
     decode lanes ride the same dispatch)."""
-    p = _planner(policy="sla", itl_ms=10.0)
+    p = _planner(policy="sla", itl_ms=10.0, cfg=_FakeCfg(max_num_seqs=4))
     # teach the model: big mixed dispatches are slow, small ones fast
     for _ in range(12):
         p.cost.observe("mixed", 512, 10, 0.050)

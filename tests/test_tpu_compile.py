@@ -347,3 +347,58 @@ def test_tp4_decode_step_shards_over_a_four_chip_mesh(
     )
     per_device = compiled.memory_analysis().argument_size_in_bytes
     assert 0.24 < per_device / total < 0.27, per_device / total
+
+
+@pytest.mark.parametrize("tokens", (1024, 2048))
+def test_the_cells_mixed_step_family_compiles_with_the_grouped_matmul(
+    tokens, one_chip, no_persistent_cache, tpu_gate
+):
+    """Both members of the benchmark cell's lean mixed_step family: Mixtral
+    widths (2 layers), the 8,193-page pool, 64 rows, the ONE table width of
+    65, the grouped expert matmul and the ragged kernel inside, within one
+    v5e. No temporary the size of an expert matrix (0.94 GB): the expert
+    stacks reach the kernel whole, a slice of one would be a copy of it."""
+    import dataclasses
+
+    from dynamo_tpu.models import moe
+
+    cfg = dataclasses.replace(
+        moe.MoeConfig.mixtral_8x7b(), num_layers=MIXTRAL["layers"],
+        capacity_factor=4.0,
+    )
+    sds = _shapes(one_chip)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
+
+    params = on_chip(jax.eval_shape(
+        functools.partial(moe.init_params, cfg), jax.random.PRNGKey(0)
+    ))
+    kv = on_chip(jax.eval_shape(lambda: alloc_kv_store(
+        cfg.num_layers, MIXTRAL["pool"], PAGE, cfg.num_kv_heads, cfg.head_dim,
+        cfg.dtype, "none",
+    )))
+    i32 = jnp.int32
+    rows, width = 64, 4096 // PAGE + 1
+
+    def step(params, kv_k, kv_v, tokens, positions, row_ids, tables,
+             row_starts, row_lens, ctx_lens, last_flat):
+        return moe.ragged_forward(
+            params, cfg, tokens, positions, row_ids, kv_k, kv_v, tables,
+            row_starts, row_lens, ctx_lens, last_flat,
+        )
+
+    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+        params, kv, kv, sds((tokens,), i32), sds((tokens,), i32),
+        sds((tokens,), i32), sds((rows, width), i32), sds((rows,), i32),
+        sds((rows,), i32), sds((rows,), i32), sds((rows,), i32),
+    ).compile()
+    # per layer: the ragged attention kernel and three grouped matmuls
+    assert compiled.as_text().count("tpu_custom_call") >= 4 * cfg.num_layers
+    mem = compiled.memory_analysis()
+    need = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert need < HBM_BYTES, f"mixed step needs {need / 2**30:.2f} GiB"
+    expert_matrix = cfg.num_experts * cfg.hidden_size * cfg.intermediate_size * 2
+    assert mem.temp_size_in_bytes < expert_matrix, (
+        f"{mem.temp_size_in_bytes / 2**20:.0f} MiB of temporaries"
+    )
