@@ -46,6 +46,7 @@ ROOT = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
 sys.path.insert(1, ROOT)
 
+import files_check  # noqa: E402
 import layer_metrics  # noqa: E402
 import metrics as e2e  # noqa: E402
 from client import Load, StatsWatch, resend_greedy  # noqa: E402
@@ -58,6 +59,10 @@ DEADLINE_READY_S = 1100
 TRACE_SECONDS = 4.0
 WARM_BUDGET_S = 1000  # a first start compiles every program its traffic reaches
 REFERENCE_POSITIONS = 2048
+# every configuration here states bf16, so the nearest precision below is
+# int8: reference.py computes the control (the program's own `--quantize
+# int8` cannot start at these sizes: PERF.md, Open questions)
+CONTROLS = ("int8",)
 
 
 def emit(obj: dict) -> None:
@@ -206,6 +211,26 @@ def pick_checked(window: list, seed: int) -> list:
             for why, r in picks]
 
 
+def compared(ref: dict, counts: dict) -> dict:
+    """name -> [value, limit] of each number `correct` rests on: the
+    reference's own (reference.py names them and their limits) and the
+    client's count of requests that returned another number of tokens than
+    asked."""
+    return dict(ref["compared"], wrong_length_requests=[len(counts["wrong_length"]), 0])
+
+
+async def own_pulse(t_end: float, period: float = 0.1) -> tuple:
+    """The longest overshoot of a loop that only sleeps `period`, and when."""
+    worst, at, last = 0.0, time.monotonic(), time.monotonic()
+    while last < t_end:
+        await asyncio.sleep(period)
+        now = time.monotonic()
+        if now - last - period > worst:
+            worst, at = now - last - period, last
+        last = now
+    return worst, at
+
+
 async def measure(args, cell: dict, mix: dict, cfg: dict, discovery_addr: str,
                   http_port: int, worker_pid: int) -> dict:
     """Warm, window, drain, re-sends. Everything the client sees."""
@@ -232,15 +257,21 @@ async def measure(args, cell: dict, mix: dict, cfg: dict, discovery_addr: str,
         t0 = t_warm = time.monotonic()
         warm_compiles = []
         while True:
-            c0 = (await watch.fresh())["compiled_variants"]
+            s0 = await watch.fresh()
             if not closed:
                 await load.open_block(gen.block(warm_s, "warm"), t0)
             elif len(warm_compiles) == 1:  # the slots are full: see Load.bursts
                 await load.bursts(mix.get("warm_bursts") or [])
             await asyncio.sleep(max(t0 + warm_s - time.monotonic(), 0))
-            grew = watch.latest["compiled_variants"] - c0
+            # stats published after the block's end: a program counts as
+            # compiled only when its compile has ended, and a cold compile of
+            # a deep model outlasts a block (mistral-7b-d16's first start, PR
+            # 34: the window opened under it and held no request). So a block
+            # passes only if tokens also flowed in it
+            s1 = await watch.fresh(timeout=WARM_BUDGET_S)
+            grew = s1["compiled_variants"] - s0["compiled_variants"]
             warm_compiles.append(grew)
-            if not grew:
+            if not grew and s1["emit_tokens"] > s0["emit_tokens"]:
                 t0 += warm_s  # the window follows without a pause
                 break
             if time.monotonic() - t_warm > WARM_BUDGET_S:
@@ -252,6 +283,9 @@ async def measure(args, cell: dict, mix: dict, cfg: dict, discovery_addr: str,
         # -- the window
         t_w0, t_w1 = t0, t0 + args.seconds
         load.phase = "window"
+        # this process's own pulse: a stall that also stops a loop that only
+        # sleeps is the machine's (a paused guest), not the program's
+        pulse = asyncio.create_task(own_pulse(t_w1))
         stats0 = watch.latest
         tracer = None
         if args.trace:
@@ -264,6 +298,7 @@ async def measure(args, cell: dict, mix: dict, cfg: dict, discovery_addr: str,
             await load.open_block(gen.block(args.seconds, "window"), t_w0)
         await asyncio.sleep(max(t_w1 - time.monotonic(), 0))
         stats1 = watch.latest
+        harness_pause_s, harness_pause_at = await pulse
         in_flight_at_end = load.in_flight()
         load.stop_offering()
         unfinished = await load.drain(float(mix.get("drain_seconds", 60)))
@@ -273,7 +308,15 @@ async def measure(args, cell: dict, mix: dict, cfg: dict, discovery_addr: str,
     window = [r for r in load.sent if t_w0 <= r.t_due < t_w1]
     penalty_ms = (args.seconds + float(mix.get("drain_seconds", 60))) * 1e3
     result = e2e.end_to_end(load.sent, t_w0, t_w1, penalty_ms)
+    frames = sorted(t for r in load.sent for t in r.frame_at if t_w0 - 5 <= t < t_w1)
+    span, span_at = max(((b - a, a) for a, b in zip(frames, frames[1:])),
+                        default=(args.seconds, t_w0))
     result["counts"].update({
+        # over 0.5 s is a stall (PERF.md); it may begin up to 5 s before the
+        # window. Beside it the longest pause of this process's own pulse
+        "no_frame_s_max": span, "no_frame_at_s": span_at - t_w0,
+        "harness_pause_s_max": harness_pause_s,
+        "harness_pause_at_s": harness_pause_at - t_w0,
         "requests_sent_all_phases": len(load.sent),
         "warm_blocks_compiles": warm_compiles,
         "compiles_in_window": stats1["compiled_variants"] - stats0["compiled_variants"],
@@ -357,8 +400,20 @@ def main() -> int:
     ap.add_argument("--trace", type=int, choices=[0, 1], default=0)
     ap.add_argument("--rehearsal", action="store_true")
     ap.add_argument("--sweep", action="store_true")
+    ap.add_argument("--control", choices=CONTROLS, default=None,
+                    help="also judge the reference in the nearest precision below the "
+                         "configuration's, in the program's place: it has to come out "
+                         "NOT correct (exit code 0 then, if the program is correct)")
+    ap.add_argument("--break", dest="broken", choices=["token"], default=None,
+                    help="a fault planted under the timed path (worker_entry.py): "
+                         "the run has to come out NOT correct")
     args = ap.parse_args()
     cell = load_cell(args.workload)
+    try:  # every run guards the files it is driven by
+        files_check.check(ROOT)
+    except files_check.BenchmarkFilesError as e:
+        print(f"BENCHMARK.json or a file it names is at fault: {e}", file=sys.stderr)
+        return 2
     if args.seconds is None:
         args.seconds = cell["run_seconds"]
     cfg = load_config(cell["config_file"], args.rehearsal)
@@ -366,14 +421,16 @@ def main() -> int:
     out_dir = os.path.join(
         ROOT, "chiprun_out", "benchmark",
         f"{cell['name']}.seed{args.seed}.trace{args.trace}"
-        + (".sweep" if args.sweep else "") + (".rehearsal" if args.rehearsal else ""))
+        + (".sweep" if args.sweep else "") + (".rehearsal" if args.rehearsal else "")
+        + (f".control-{args.control}" if args.control else "")
+        + (f".break-{args.broken}" if args.broken else ""))
     shutil.rmtree(out_dir, ignore_errors=True)
     os.makedirs(out_dir)
     trace_dir = os.path.join(out_dir, "trace")
     children = Children(out_dir)
     env = child_env(args.rehearsal)
     device = {"platform": None, "kind": None, "count": 0}
-    line = None
+    line, checked = None, {}
     try:
         disc_port, http_port = free_port(), free_port()
         discovery_addr = f"127.0.0.1:{disc_port}"
@@ -390,6 +447,8 @@ def main() -> int:
         if args.trace:
             worker_argv += ["--bench-trace-dir", trace_dir,
                             "--bench-trace-seconds", str(TRACE_SECONDS)]
+        if args.broken:
+            worker_argv += ["--bench-break", args.broken]
         worker = children.start("worker", worker_argv + list(cfg["worker_args"]), env)
         # -- the device, from the worker, before any weight is built
         m = children.wait_for_log("worker", worker, r"worker device (\{.*\})",
@@ -459,7 +518,7 @@ def main() -> int:
         cases = os.path.join(out_dir, "reference_cases.json")
         with open(cases, "w") as f:
             json.dump({"config_file": cell["config_file"], "rehearsal": args.rehearsal,
-                       "cases": res["served"]}, f)
+                       "control": args.control, "cases": res["served"]}, f)
         ref = run_child([os.path.join(HERE, "reference.py"), cases], env,
                         os.path.join(out_dir, "reference.log"), 600)
         emit({"phase": "reference", **ref})
@@ -513,6 +572,14 @@ def main() -> int:
         }
         if why_not:
             line["why_not_correct"] = why_not
+        # every number compared, beside its limit: the result's last key
+        # (moved to the end below) and the last lines of standard error
+        checked = compared(ref, counts)
+        if args.control:
+            line["control"] = {"precision": args.control,
+                               "correct": ref["control"]["agrees"],
+                               "why_not_correct": ref["control"]["why_not"],
+                               "checked": compared(ref["control"], counts)}
         if args.rehearsal:
             # counts and CPU times, under a name no device metric has
             line["cpu_rehearsal_values"] = line.pop("metrics")
@@ -539,9 +606,14 @@ def main() -> int:
         children.stop_all()
     if line is None:
         return 1
+    line["checked"] = checked
+    for name, (value, limit) in checked.items():
+        print(f"checked {name}: {value} (limit {limit})", file=sys.stderr)
     print(json.dumps(line), flush=True)
     if args.rehearsal:
         return REHEARSAL_PASSED if line["reference_agrees"] else 1
+    if args.control and line["control"]["correct"]:
+        return 1  # the limits let a lower precision through
     return 0 if line["correct"] else 1
 
 

@@ -206,6 +206,78 @@ def test_references_against_the_program():
               f"of references/{ref.__name__.split('.')[-1]}.py over {T - 31} positions")
 
 
+def test_judge_holds_each_request():
+    """reference.judge on made-up logits: a sound run agrees; ONE request of
+    four served 0.2 deviations off at every position, which the per-position
+    tolerance (0.25) and a routed family's pooled means let through, does not:
+    the worst request's median (routed) or mean (dense) catches it."""
+    import numpy as np
+
+    import reference
+
+    rng = np.random.default_rng(0)
+    lens = {"0.shortest": 70, "1.median": 140, "2.crosses_page": 700, "3.random": 390}
+    cases, rows_of, served_of, margins = {}, {}, {}, {}
+    for name, n in lens.items():
+        rows = rng.normal(size=(n, 512)).astype(np.float32)
+        served = rows.argmax(-1)
+        lp = rows.max(-1) - reference.log_normalizer(rows)
+        lp = lp + rng.normal(scale=0.012, size=n) * rows.std(-1)  # bf16's own rounding
+        cases[name] = {"prompt_ids": [1] * 100}
+        rows_of[name], served_of[name] = rows, (served, lp)
+        margins[name] = rng.uniform(0.0, 0.35, size=n)  # some 29% under the epsilon
+    for routed, number in ((True, "logprob_gap_request_median_sigmas"),
+                           (False, "logprob_gap_mean_sigmas")):
+        margins_of = margins if routed else dict.fromkeys(lens)
+        sound = reference.judge(cases, rows_of, margins_of, served_of)
+        assert sound["agrees"], sound["why_not"]
+        ids, lp = served_of["1.median"]
+        off = dict(served_of)
+        off["1.median"] = (ids, lp - 0.2 * rows_of["1.median"].std(-1))
+        broken = reference.judge(cases, rows_of, margins_of, off)
+        over = [k for k, (v, lim) in broken["compared"].items() if v > lim]
+        assert not broken["agrees"] and over == [number], (routed, broken["compared"])
+        assert broken["compared"][number][0] > 0.19
+
+
+def test_benchmark_files():
+    """files_check.py's checks (which every run of run.py makes too), and what
+    needs the program: every configuration loads, plain and with its
+    rehearsal block laid over, into the dataclass it names."""
+    import dataclasses
+    import json
+
+    import files_check
+    from worker_entry import build_model_config, load_config, lookup
+
+    root = os.path.dirname(HERE)
+    files_check.check(root)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    for c in bench["configs"]:
+        for rehearsal in (False, True):
+            cfg = load_config(os.path.join(root, c["file"]), rehearsal)
+            built = build_model_config(cfg)
+            assert dataclasses.is_dataclass(built)
+            assert type(built).__name__ == cfg["dataclass"].partition(":")[2]
+            for field, key in cfg["dataclass_fields"].items():
+                assert getattr(built, field) == lookup(cfg, key), (c["name"], field)
+    for w in bench["workloads"]:
+        load_mix(w["traffic"], False), load_mix(w["traffic"], True)
+    # the checks can fail: a width changed, a bound out of range
+    for spoil, what in ((lambda b, c: next(iter(c.values())).update(hidden_size=2048), "width"),
+                        (lambda b, c: b["end_to_end"][0].update(bound=0.2), "bound")):
+        bench2 = json.loads(json.dumps(bench))
+        cfgs = {c["name"]: json.load(open(os.path.join(root, c["file"])))
+                for c in bench2["configs"]}
+        spoil(bench2, cfgs)
+        try:
+            files_check.check_loaded(bench2, cfgs, root)
+        except files_check.BenchmarkFilesError:
+            continue
+        raise AssertionError(f"files_check passed a spoiled {what}")
+
+
 def main() -> int:
     for name, fn in sorted(globals().items()):
         if name.startswith("test_") and callable(fn):

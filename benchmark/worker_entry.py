@@ -9,7 +9,8 @@ configuration file, makes that name resolve (both the worker and JaxEngine
 look a model up through `dynamo_tpu.engine.engine._resolve_model`), and calls
 the worker's own `main()` with the worker's own arguments. In a traced run it
 starts `jax.profiler` when the parent sends SIGUSR1, stops it after the given
-seconds and leaves a marker file. It computes nothing and changes no option.
+seconds and leaves a marker file. It computes nothing and changes no option
+(`--bench-break` plants a fault on purpose, for broken_path_test.py only).
 The worker taking a configuration file and a profiler window itself is listed
 in PERF.md for the `tracing` issue; this file goes when it does.
 """
@@ -77,6 +78,7 @@ def main() -> None:
     ap.add_argument("--bench-rehearsal", action="store_true")
     ap.add_argument("--bench-trace-dir", default=None)
     ap.add_argument("--bench-trace-seconds", type=float, default=4.0)
+    ap.add_argument("--bench-break", choices=["token"], default=None)
     own, worker_argv = ap.parse_known_args()
 
     cfg = load_config(own.bench_config, own.bench_rehearsal)
@@ -90,6 +92,29 @@ def main() -> None:
         return model_config if name == own.bench_name else resolve(name)
 
     engine_mod._resolve_model = resolve_with_file
+
+    if own.bench_break == "token":
+        # a fault on purpose, for the test that `correct` comes out false: the
+        # second token of every emitted block is altered where it is produced.
+        # The method is the program's own and private: if it is renamed or
+        # takes other arguments the fault cannot be planted, and that has to
+        # stop the run, not leave a sound worker behind a test that then
+        # fails for another reason
+        import inspect
+
+        emit = getattr(engine_mod.JaxEngine, "_emit_tokens", None)
+        took = list(inspect.signature(emit).parameters) if emit else None
+        if took != ["self", "slot", "tokens", "lps", "tops"]:
+            raise SystemExit(f"--bench-break token: JaxEngine._emit_tokens takes {took}, not "
+                             "(self, slot, tokens, lps, tops): plant the fault anew")
+
+        def emit_altered(self, slot, tokens, lps, tops):
+            tokens = list(tokens)
+            if len(tokens) > 1:
+                tokens[1] = (tokens[1] + 1) % model_config.vocab_size
+            return emit(self, slot, tokens, lps, tops)
+
+        engine_mod.JaxEngine._emit_tokens = emit_altered
 
     if own.bench_trace_dir:
         started = threading.Event()
