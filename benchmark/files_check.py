@@ -1,22 +1,31 @@
 #!/usr/bin/env python3
 """BENCHMARK.json against the files it names. Every run of run.py makes
-these checks before it starts a process (they read JSON only, some
-milliseconds), so the driver's own runs guard the files: a configuration
-whose width no longer is the published one, a cut not stated, a cell whose
-files are missing or a bound outside the contract's range ends the run with
-exit code 2 and no result. selftest.py makes them too, with what needs the
-program (the dataclass each configuration loads into).
+these checks before it starts a process (they read JSON only, and for a
+routed family draw some normals: milliseconds), so the driver's own runs
+guard the files: a configuration whose width no longer is the published one,
+a cut not stated, a cell whose files are missing, a bound outside the
+contract's range or a routed family's limits that its geometry or its
+readings do not bear ends the run with exit code 2 and no result.
+selftest.py makes them too, with what needs the program (the dataclass each
+configuration loads into).
 
-    python3 benchmark/files_check.py
+    python3 benchmark/files_check.py [configuration file ...]
+
+A file named there (one that no cell uses yet, or a fixture) is held to the
+rule for a routed family's own limits, `check_judge`.
 """
 
 from __future__ import annotations
 
 import json
+import math
 import os
 import sys
 
 HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, HERE)
+
+from reference import ROUTED_LIMITS, order_statistics_share  # noqa: E402  (no JAX until it runs)
 
 # Each configuration's published sizes, written here from its source and
 # not read from its file: a width that differs is a fault of the file.
@@ -41,14 +50,154 @@ class BenchmarkFilesError(Exception):
     pass
 
 
+def need(ok, *what):
+    if not ok:
+        raise BenchmarkFilesError(" ".join(str(w) for w in what))
+
+
+# of reference.py:ROUTED_LIMITS two say which positions are judged at all; the
+# others are the numbers that a precision moves
+WHICH_POSITIONS = ("router_margin_epsilon", "router_left_out_share")
+JUDGED_NUMBERS = tuple(k for k in ROUTED_LIMITS if k not in WHICH_POSITIONS)
+SOUND_KINDS = ("served", "bf16_control")
+RUNS_MIN = 6  # of each side, and the control's no fewer than the sound side's
+UPPER_READING_TIMES = 3.0
+# a limit is at most this share of the int8 control's lowest reading: fresh
+# seeds read the control lower than a dozen did (PERF.md section 6, PR 36:
+# 0.0345 over twelve seeds, 0.0205 over twenty-four)
+CONTROL_SIDE_SHARE = 0.8
+SHARE_TOLERANCE = 0.015  # of a stated order-statistics share (20,000 tokens)
+# a routed family's geometry, under the keys most public configurations use
+# (the catalog beside the `model-configs` guide); a file whose source names
+# them otherwise states them under these too
+EXPERTS_KEYS = ("num_local_experts", "num_experts", "n_routed_experts")
+PER_TOKEN_KEYS = ("num_experts_per_tok",)
+DENSE_LAYERS_KEYS = ("num_dense_layers", "first_k_dense_replace")
+
+
+def number(x) -> bool:
+    return isinstance(x, (int, float)) and not isinstance(x, bool) and math.isfinite(x)
+
+
+def routed_geometry(name: str, cfg: dict):
+    """(experts, experts a token, routed layers) from the configuration's own
+    keys, or None where it has no experts."""
+    def first(keys, default=None):
+        return next((cfg[k] for k in keys if k in cfg), default)
+
+    experts = first(EXPERTS_KEYS)
+    if experts is None:
+        return None
+    per_token = first(PER_TOKEN_KEYS)
+    layers = cfg.get("num_hidden_layers", 0) - first(DENSE_LAYERS_KEYS, 0)
+    need(all(isinstance(x, int) and x > 0 for x in (experts, per_token, layers))
+         and per_token < experts, name, "experts, experts a token, routed layers:",
+         (experts, per_token, layers), "from", EXPERTS_KEYS, PER_TOKEN_KEYS,
+         "num_hidden_layers less", DENSE_LAYERS_KEYS)
+    return experts, per_token, layers
+
+
+def check_judge(name: str, cfg: dict) -> None:
+    """A routed family's limits, the defaults (reference.py:ROUTED_LIMITS) or
+    its own (`judge`), against its geometry and against what they were set from
+    (`judge_readings`). Each limit in force stands where PERF.md section 4 says
+    a limit stands:
+
+    - the share of positions that order statistics leave out at the epsilon
+      in force, for the experts, experts a token and routed layers of the
+      configuration's OWN keys (`reference.order_statistics_share`), is no
+      higher than the share's limit, or a sound program fails by arithmetic;
+      this holds a file without `judge` too. An override of the epsilon or of
+      the share gives a reason and states that share;
+    - a file with `judge` has, for every number that is compared, its `sound`
+      readings (lowest, highest, runs, and their kind: served runs, or the
+      bf16 control's where no program can serve the geometry yet), the
+      `control_int8` readings on the same prompts and tokens, of no fewer runs,
+      and one line of `reason`;
+    - the limit is a number (none is left out), above the highest sound
+      reading and at most CONTROL_SIDE_SHARE of the lowest control reading:
+      room on both sides, and the more of it above the sound one, so at or
+      past their geometric mean;
+    - for at least one number the control's lowest reading is three times
+      the highest sound one or more: an upper reading.
+
+    Every fault of the last two kinds is told, not the first alone.
+    """
+    geometry, limits = routed_geometry(name, cfg), cfg.get("judge")
+    if geometry is None:
+        need(limits is None, name, "has `judge` and none of", EXPERTS_KEYS)
+        return
+    need(limits is None or (isinstance(limits, dict) and limits
+                            and set(limits) <= set(ROUTED_LIMITS)
+                            and all(number(v) for v in limits.values())),
+         name, "judge may set", sorted(ROUTED_LIMITS), "each to a number, and sets", limits)
+    in_force = {**ROUTED_LIMITS, **(limits or {})}
+    epsilon, share_max = in_force["router_margin_epsilon"], in_force["router_left_out_share"]
+    need(epsilon >= 0 and 0 <= share_max <= 1,
+         name, "router_margin_epsilon", epsilon, "router_left_out_share", share_max)
+    # (no margin lies under 0: nothing to draw)
+    share = order_statistics_share(*geometry, epsilon) if epsilon else 0.0
+    need(share <= share_max, name, "router_left_out_share is held to", share_max,
+         "where order statistics leave out", share, "of a sound program's positions, at",
+         geometry, "experts, experts a token, routed layers and an epsilon of", epsilon)
+    if limits is None:
+        return
+    told = cfg.get("judge_readings")
+    need(isinstance(told, dict), name, "has `judge` and no `judge_readings`")
+
+    def reason(entry, key):
+        need(isinstance(entry, dict), name, "judge_readings has no entry for", key)
+        why = entry.get("reason")
+        need(isinstance(why, str) and why.strip() and "\n" not in why,
+             name, "judge_readings", key, "needs one line of `reason`")
+
+    for key in WHICH_POSITIONS:
+        if key in limits:
+            reason(told.get(key), key)
+            stated = told[key].get("order_statistics_share")
+            need(number(stated) and abs(stated - share) <= SHARE_TOLERANCE,
+                 name, "judge_readings", key, "order_statistics_share is", stated,
+                 "where", geometry, "at an epsilon of", epsilon, "give", share)
+    faults, upper_readings = [], 0
+    for key in JUDGED_NUMBERS:
+        limit, entry = in_force[key], told.get(key)
+        reason(entry, key)
+        sound, low = entry.get("sound"), entry.get("control_int8")
+        for side in (sound, low):
+            need(isinstance(side, dict) and number(side.get("lowest"))
+                 and number(side.get("highest")) and 0 <= side["lowest"] <= side["highest"]
+                 and isinstance(side.get("runs"), int) and side["runs"] >= RUNS_MIN,
+                 name, "judge_readings", key, "needs `sound` and `control_int8`, each with "
+                 "lowest <= highest and at least", RUNS_MIN, "runs; has", side)
+        need(sound.get("kind") in SOUND_KINDS, name, key, "sound.kind is one of", SOUND_KINDS)
+        need(low["runs"] >= sound["runs"], name, key, "has", low["runs"],
+             "runs of the int8 control for", sound["runs"], "sound ones")
+        count = key == "positions_outside"  # whole numbers: a run passes at its limit
+        middle = math.sqrt(sound["highest"] * low["lowest"])
+        for ok, what in (
+                (sound["highest"] <= limit if count else sound["highest"] < limit,
+                 f"is not above the highest sound reading {sound['highest']}"),
+                (limit >= (math.floor(middle) if count else middle),
+                 "leaves less room above the sound reading than below the control's: the "
+                 f"geometric mean of {sound['highest']} and {low['lowest']} is {middle}"),
+                (limit <= CONTROL_SIDE_SHARE * low["lowest"],
+                 f"is over {CONTROL_SIDE_SHARE} of the int8 control's lowest reading "
+                 f"{low['lowest']}")):
+            if not ok:
+                faults.append(f"{key}: limit {limit} {what}")
+        if low["lowest"] >= UPPER_READING_TIMES * max(sound["highest"], 1 if count else 0):
+            upper_readings += 1
+    if not upper_readings:
+        faults.append(f"in no number does the int8 control read {UPPER_READING_TIMES} times "
+                      "the highest sound reading")
+    need(not faults, name, "judge_readings: " + "; ".join(faults))
+
+
 def check_loaded(bench: dict, cfgs: dict, root: str) -> None:
     """`cfgs`: configuration name -> its file's content."""
-    def need(ok, *what):
-        if not ok:
-            raise BenchmarkFilesError(" ".join(str(w) for w in what))
-
     for c in bench["configs"]:
         cfg = cfgs[c["name"]]
+        check_judge(c["name"], cfg)
         need(cfg["source"] == c["source"] and cfg["reduced"] == c["reduced"],
              c["name"], "source or reduced differ between BENCHMARK.json and", c["file"])
         need(os.path.exists(os.path.join(HERE, "references", cfg["family"] + ".py")),
@@ -89,7 +238,18 @@ def check(root: str) -> None:
     check_loaded(bench, cfgs, root)
 
 
-if __name__ == "__main__":
-    check(os.path.dirname(HERE))
+def main(config_files: list) -> int:
+    try:
+        check(os.path.dirname(HERE))
+        for path in config_files:
+            with open(path) as f:
+                check_judge(path, json.load(f))
+    except BenchmarkFilesError as e:
+        print(f"BENCHMARK.json or a file it names is at fault: {e}", file=sys.stderr)
+        return 2
     print("ok")
-    sys.exit(0)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))

@@ -4,7 +4,9 @@ has exited, so the chip is free for it.
 
     python benchmark/reference.py <cases.json>
 
-Teacher-forced, as `chip_smoke.py` does it (PR 21): the plain reference of
+(`run.py` writes the file: a run's served prompts and tokens, or, under
+`--controls-only`, seeded prompts and continuations with no program behind
+them.) Teacher-forced, as `chip_smoke.py` does it (PR 21): the plain reference of
 the configuration's family (references/<family>.py) reads the SERVED tokens
 and is asked, at each generated position, how good the served next token
 was and what its log-probability should have been. Both in deviations of
@@ -98,6 +100,32 @@ ROUTED_POOLED_MEAN_ALL_SIGMAS = 0.07
 # (selftest.py plants it).
 ROUTED_REQUEST_MEDIAN_SIGMAS = 0.03
 ROUTED_REQUEST_MEDIAN_MIN_TOKENS = 128
+# All of the above are ONE geometry's readings: 8 experts, 2 a token, 2 routed
+# layers. The share of positions with a margin under an epsilon is order
+# statistics, not a property of a program (`order_statistics_share`, below:
+# 0.32 at 8 / 2 / 2 and an epsilon of 0.1, 0.95 at 64 / 4 / 4, 0.998 at 64 / 4
+# / 8), so they are the DEFAULTS, under the names `compared` prints, and a
+# configuration whose geometry they cannot hold brings its own in its file's
+# `judge` key, set from its own readings by the rule files_check.py holds it
+# to. With `router_margin_epsilon` 0 every position is kept: the two pooled
+# means coincide, the median is over all of a request's positions and
+# `router_left_out_share` reads 0; a flip is then judged, not left out, which
+# is the only way where the 4th and 5th of 64 router logits lie 0.12
+# deviations apart and every position has a near-tie in some layer. Every
+# number stays compared, under every geometry: a limit is a number.
+ROUTED_LIMITS = {
+    "router_margin_epsilon": ROUTER_MARGIN_EPSILON,
+    "router_left_out_share": ROUTER_SKIPPED_SHARE_MAX,
+    "positions_outside": ROUTED_OUTLIERS_MAX,
+    "logprob_gap_pooled_mean_sigmas": ROUTED_POOLED_MEAN_SIGMAS,
+    "logprob_gap_pooled_mean_all_sigmas": ROUTED_POOLED_MEAN_ALL_SIGMAS,
+    "logprob_gap_request_median_sigmas": ROUTED_REQUEST_MEDIAN_SIGMAS,
+}
+# what `--control` takes, in the order they are computed: the reference
+# itself in the program's place at the program's own precision (bf16: has to
+# come out agreeing) and one step down (int8 matrices: NOT agreeing). See
+# `control_rows`.
+CONTROLS = ("bf16", "int8")
 
 
 def log_normalizer(rows):
@@ -108,15 +136,48 @@ def log_normalizer(rows):
     return np.log(np.exp(rows - top[:, None]).sum(-1)) + top
 
 
-def judge(cases: dict, rows_of: dict, margins_of: dict, served_of: dict) -> dict:
+def router_margins(router_logits, per_token: int):
+    """[layers, tokens, experts] -> per token the smallest margin over the
+    layers between the last chosen and the first rejected logit, in deviations
+    of the token's router logits: references/moe.py's arithmetic in numpy."""
+    import numpy as np
+
+    top = -np.sort(-router_logits, axis=-1)
+    margin = (top[..., per_token - 1] - top[..., per_token]) / router_logits.std(axis=-1)
+    return margin.min(axis=0)
+
+
+def order_statistics_share(experts: int, per_token: int, layers: int, epsilon: float,
+                           tokens: int = 20000, seed: int = 0) -> float:
+    """The share of positions with a margin under `epsilon` in some layer
+    that independent normal router logits give: what a sound program reads as
+    `router_left_out_share`, whatever it computes (the Mixtral cell's 0.283 to
+    0.328 on the chip against 0.32 here)."""
+    import numpy as np
+
+    logits = np.random.default_rng(seed).normal(size=(layers, tokens, experts))
+    return float((router_margins(logits, per_token) < epsilon).mean())
+
+
+def judge(cases: dict, rows_of: dict, margins_of: dict, served_of: dict,
+          overrides: dict | None = None) -> dict:
     """The numbers `correct` rests on, each against its limit. `rows_of[name]`
     are the reference's logits at the positions that predict the served
     tokens, `served_of[name]` the (token ids, log-probabilities) judged: the
-    program's, or the control's (see `run`)."""
+    program's, or a control's (see `run`). `overrides` is the configuration's
+    `judge` key: a routed family's own limits (ROUTED_LIMITS)."""
     import numpy as np
 
     out, skipped, positions, outliers = {}, 0, 0, 0
     routed = any(m is not None for m in margins_of.values())
+    if overrides and not (routed and set(overrides) <= set(ROUTED_LIMITS) and all(
+            isinstance(v, (int, float)) and not isinstance(v, bool)
+            for v in overrides.values())):
+        raise ValueError(f"`judge` sets a routed family's limits {sorted(ROUTED_LIMITS)}, "
+                         f"each to a number; it sets {overrides}, and the family is "
+                         f"{'routed' if routed else 'dense'}")
+    limits = {**ROUTED_LIMITS, **(overrides or {})}
+    epsilon = limits["router_margin_epsilon"]
     dlp_sum_kept = dlp_sum_all = 0.0  # over all the run's positions
     for name, c in cases.items():
         prompt, rows = c["prompt_ids"], rows_of[name]
@@ -124,7 +185,7 @@ def judge(cases: dict, rows_of: dict, margins_of: dict, served_of: dict) -> dict
         served = np.asarray(served)
         keep = np.ones(len(served), bool)
         if margins_of[name] is not None:
-            keep = margins_of[name] >= ROUTER_MARGIN_EPSILON
+            keep = margins_of[name] >= epsilon
         std = rows.std(axis=-1)
         top = rows.max(axis=-1)
         got = rows[np.arange(len(served)), served]
@@ -160,21 +221,20 @@ def judge(cases: dict, rows_of: dict, margins_of: dict, served_of: dict) -> dict
         }
     # name -> [value, limit]: every number `correct` rests on (run.py prints
     # them and adds the client's own count of wrong lengths)
-    compared = {"positions_outside": [outliers, ROUTED_OUTLIERS_MAX if routed else 0]}
     if routed:  # a flip is counted, not sized: no worst token, no worst position
         long_enough = [r["logprob_diff_sigmas_median"] for r in out.values()
                        if r["tokens"] >= ROUTED_REQUEST_MEDIAN_MIN_TOKENS]
-        compared.update({
-            "logprob_gap_pooled_mean_sigmas": [
-                dlp_sum_kept / max(positions - skipped, 1), ROUTED_POOLED_MEAN_SIGMAS],
-            "logprob_gap_pooled_mean_all_sigmas": [
-                dlp_sum_all / max(positions, 1), ROUTED_POOLED_MEAN_ALL_SIGMAS],
-            "logprob_gap_request_median_sigmas": [
-                max(long_enough, default=0.0), ROUTED_REQUEST_MEDIAN_SIGMAS],
-            "router_left_out_share": [skipped / max(positions, 1), ROUTER_SKIPPED_SHARE_MAX],
-        })
+        values = {
+            "positions_outside": outliers,
+            "logprob_gap_pooled_mean_sigmas": dlp_sum_kept / max(positions - skipped, 1),
+            "logprob_gap_pooled_mean_all_sigmas": dlp_sum_all / max(positions, 1),
+            "logprob_gap_request_median_sigmas": max(long_enough, default=0.0),
+            "router_left_out_share": skipped / max(positions, 1),
+        }
+        compared = {name: [value, limits[name]] for name, value in values.items()}
     else:  # the worst position, and the worst request's mean
-        compared.update({
+        compared = {
+            "positions_outside": [outliers, 0],
             "token_gap_sigmas": [max(r["worst_gap_sigmas"] for r in out.values()),
                                  LOGIT_TOLERANCE_SIGMAS],
             "logprob_gap_sigmas": [max(r["logprob_diff_sigmas_max"] for r in out.values()),
@@ -182,7 +242,7 @@ def judge(cases: dict, rows_of: dict, margins_of: dict, served_of: dict) -> dict
             "logprob_gap_mean_sigmas": [
                 max(r["logprob_diff_sigmas_mean"] for r in out.values()),
                 LOGPROB_TOLERANCE_MEAN_SIGMAS],
-        })
+        }
     why = [f"{name} reads {value:.4g}, over its limit {limit}"
            for name, (value, limit) in compared.items() if not value <= limit]
     if not all(r["finite"] for r in out.values()):
@@ -190,7 +250,7 @@ def judge(cases: dict, rows_of: dict, margins_of: dict, served_of: dict) -> dict
     return {"agrees": not why, "why_not": why, "compared": compared, "cases": out,
             "routed": routed, "positions": positions,
             "router_near_ties_left_out": skipped,
-            "router_margin_epsilon": ROUTER_MARGIN_EPSILON}
+            "router_margin_epsilon": epsilon}
 
 
 def int8_weights(params: dict):
@@ -219,7 +279,8 @@ def int8_weights(params: dict):
 
 
 def load_model(config_file: str, rehearsal: bool) -> tuple:
-    """(configuration, weights, the family's plain reference)."""
+    """(configuration, weights, the family's plain reference, the file's
+    `judge` key or None)."""
     import jax
 
     from worker_entry import build_model_config, load_config
@@ -233,7 +294,7 @@ def load_model(config_file: str, rehearsal: bool) -> tuple:
     # the same seeded weights the worker built (JaxEngine: init_params from
     # PRNGKey(EngineConfig.seed))
     params = model_mod.init_params(cfg, jax.random.PRNGKey(cfg_file["weight_seed"]))
-    return cfg, params, reference
+    return cfg, params, reference, cfg_file.get("judge")
 
 
 def forward(reference, cfg, params, cases: dict) -> tuple:
@@ -253,40 +314,98 @@ def forward(reference, cfg, params, cases: dict) -> tuple:
         toks = np.zeros((T,), np.int32)
         toks[: len(seq)] = seq
         logits, margins = fwd(params, tokens=jnp.asarray(toks))
-        rows_of[name] = np.asarray(logits)[len(prompt) - 1: len(seq)]
+        # a copy of the rows judged, so that the padded [T, vocab] can go
+        rows_of[name] = np.array(np.asarray(logits)[len(prompt) - 1: len(seq)])
         margins_of[name] = (None if margins is None else
                             np.asarray(margins)[len(prompt) - 1: len(seq)])
     return rows_of, margins_of
 
 
+def control_rows(precision: str, reference, cfg, params, cases: dict):
+    """A control: the reference itself in the program's place, at each
+    position of the same prompts and tokens. It does not decode: it is judged
+    by the token it puts first and the log-probability it gives that token
+    (`control_choice`). Call it OUTSIDE the `highest` block.
+
+    bf16: the TPU's default matmul precision, one bf16 pass of the operands
+    with float32 accumulation: what the configuration states and a program
+    computes in. It has to come out AGREEING: a limit that it breaks is not a
+    limit a bf16 program can keep. On a CPU the default is float32 already:
+    None, and nothing is judged.
+    int8: every matrix rounded to int8 (`int8_weights`: IN PLACE, so it comes
+    last), the nearest precision below bf16, the step that would tempt a
+    later PR. It has to come out NOT agreeing, or the limits let it through."""
+    import jax
+
+    if precision == "bf16":
+        if jax.devices()[0].platform == "cpu":
+            return None
+        return forward(reference, cfg, params, cases)[0]
+    with jax.default_matmul_precision("highest"):
+        return forward(reference, cfg, int8_weights(params), cases)[0]
+
+
 def control_choice(low_rows: dict) -> dict:
-    """What the control is judged by: at each position the token it puts
+    """What a control is judged by: at each position the token it puts
     first and the log-probability it gives that token."""
     return {name: (low.argmax(-1), low.max(-1) - log_normalizer(low))
             for name, low in low_rows.items()}
 
 
+NOT_ON_A_CPU = ("the reference's device is a CPU, whose default matmul precision is "
+                "float32 already: nothing to judge")
+
+
 def run(case_file: str) -> dict:
+    """`cases`: name -> prompt and served tokens of ONE run, judged together;
+    under `controls_only` seed -> such a set, each judged on its own, with no
+    program's tokens to judge."""
     import jax
+    import numpy as np
 
     with open(case_file) as f:
         spec = json.load(f)
-    cfg, params, reference = load_model(spec["config_file"], spec["rehearsal"])
-    cases = spec["cases"]
+    cfg, params, reference, overrides = load_model(spec["config_file"], spec["rehearsal"])
+    only = spec.get("controls_only", False)
+    sets = spec["cases"] if only else {"run": spec["cases"]}
+    flat = {f"{s}/{name}": c for s, cases in sets.items() for name, c in cases.items()}
     with jax.default_matmul_precision("highest"):
-        rows_of, margins_of = forward(reference, cfg, params, cases)
-        result = judge(cases, rows_of, margins_of, {
-            name: (c["served_ids"], c["served_logprobs"]) for name, c in cases.items()})
-        if spec.get("control") == "int8":
-            # The control: the reference itself in the program's place, in the
-            # nearest precision below the bf16 the configuration states. It
-            # does not decode: at each position of the same prompts and
-            # tokens it is judged by the token it puts first and the
-            # log-probability it gives that token. It has to come out NOT
-            # agreeing, or the limits let a lower precision through.
-            low_rows, _ = forward(reference, cfg, int8_weights(params), cases)
-            result["control"] = judge(cases, rows_of, margins_of,
-                                      control_choice(low_rows))
+        rows_of, margins_of = forward(reference, cfg, params, flat)
+
+    def judged(served_of: dict) -> dict:
+        """seed -> the verdict on that seed's cases."""
+        out = {}
+        for s, cases in sets.items():
+            def own(d):
+                return {name: d[f"{s}/{name}"] for name in cases}
+            out[s] = judge(cases, own(rows_of), own(margins_of), own(served_of), overrides)
+        return out
+
+    def margin_share(s: str):
+        """Whatever epsilon the configuration sets: what its geometry gives."""
+        margins = [margins_of[f"{s}/{name}"] for name in sets[s]]
+        if any(m is None for m in margins):
+            return None
+        return float((np.concatenate(margins) < ROUTER_MARGIN_EPSILON).mean())
+
+    if only:
+        result = {"controls_only": True, "sets": {s: {
+            "positions": sum(len(c["served_ids"]) for c in cases.values()),
+            "margin_under_default_epsilon_share": margin_share(s),
+        } for s, cases in sets.items()}}
+    else:
+        result = judged({name: (c["served_ids"], c["served_logprobs"])
+                         for name, c in flat.items()})["run"]
+    asked = spec.get("controls") or []
+    for precision in (c for c in CONTROLS if c in asked):  # int8 last: in place
+        low_rows = control_rows(precision, reference, cfg, params, flat)
+        verdicts = ({s: {"skipped": NOT_ON_A_CPU} for s in sets} if low_rows is None
+                    else judged(control_choice(low_rows)))
+        if only:
+            for s in sets:
+                result["sets"][s][precision] = verdicts[s]
+        else:
+            result.setdefault("controls", {})[precision] = verdicts["run"]
     dev = jax.devices()[0]
     result["reference_device"] = {"platform": dev.platform, "kind": dev.device_kind}
     return result

@@ -20,6 +20,13 @@ Earlier lines are JSON objects too, one per phase.
     --sweep       one process, a ladder of rates of `--seconds` each (the
                   mix's `sweep_rates_rps`): the table from which a mix's
                   fixed rate is set, once. Prints no result line.
+    --controls-only --config-file <file> --traffic <mix> --seed n [--seed m ...]
+                  no discovery, worker or frontend: for each seed four cases
+                  drawn from the mix's own generator, and the reference's two
+                  controls (reference.py) judged on them by the file's limits.
+                  The table from which a routed family's limits are set, for
+                  any configuration file whose `dataclass` the program can
+                  build. Prints no result line.
 
 One process per chip: this parent never imports JAX. Process supervision is
 copied from chip_smoke.py (PR 21). A run that finds no TPU fails.
@@ -50,6 +57,7 @@ import files_check  # noqa: E402
 import layer_metrics  # noqa: E402
 import metrics as e2e  # noqa: E402
 from client import Load, StatsWatch, resend_greedy  # noqa: E402
+from reference import CONTROLS  # noqa: E402  (imports no JAX until it runs)
 from traffic import Generator, load_mix  # noqa: E402
 from worker_entry import load_config  # noqa: E402
 
@@ -59,10 +67,15 @@ DEADLINE_READY_S = 1100
 TRACE_SECONDS = 4.0
 WARM_BUDGET_S = 1000  # a first start compiles every program its traffic reaches
 REFERENCE_POSITIONS = 2048
-# every configuration here states bf16, so the nearest precision below is
-# int8: reference.py computes the control (the program's own `--quantize
-# int8` cannot start at these sizes: PERF.md, Open questions)
-CONTROLS = ("int8",)
+# (CONTROLS, from reference.py, which computes both: every configuration here
+# states bf16, so int8, the nearest precision below, has to come out not
+# correct and bf16 correct; the program's own `--quantize int8` cannot start
+# at these sizes: PERF.md, Open questions.)
+
+# how many requests of the mix a --controls-only run picks its cases from: a
+# closed loop's first requests of each client, about what a window finishes
+CONTROLS_ONLY_PER_CLIENT = 16
+BYTE_TOKENIZER_OFFSET = 3  # dynamo_tpu's byte tokenizer: id = byte + 3 specials
 
 
 def emit(obj: dict) -> None:
@@ -191,12 +204,13 @@ def load_cell(workload: str) -> dict:
     }
 
 
-def pick_checked(window: list, seed: int) -> list:
-    """Four of the window's own requests for the reference: the shortest, a
-    median one, the longest that decodes across a page boundary, and one at
-    random from the seed; each of at most REFERENCE_POSITIONS positions."""
-    fit = [r for r in window if r.ok
-           and len(r.prompt) + r.max_tokens <= REFERENCE_POSITIONS]
+def pick_checked(finished: list, seed: int) -> list:
+    """Four of the window's own finished requests for the reference: the
+    shortest, a median one, the longest that decodes across a page boundary,
+    and one at random from the seed; each of at most REFERENCE_POSITIONS
+    positions."""
+    fit = [r for r in finished
+           if len(r.prompt) + r.max_tokens <= REFERENCE_POSITIONS]
     if not fit:
         raise BenchFailure("no finished request of the window fits the reference")
     by_len = sorted(fit, key=lambda r: (len(r.prompt) + r.max_tokens, r.rid))
@@ -335,7 +349,7 @@ async def measure(args, cell: dict, mix: dict, cfg: dict, discovery_addr: str,
         for r in load.sent
         if r.t_due < t_w1 and (r.t_end is None or r.t_end >= t_w0)]
     result["stats"] = (stats0, stats1, stats2)
-    picks = pick_checked(window, args.seed)
+    picks = pick_checked([r for r in window if r.ok], args.seed)
     result["served"] = await resend_greedy(
         discovery_addr, cell["config"], cfg["vocab_size"],
         int(cfg["worker_args"][cfg["worker_args"].index("--max-model-len") + 1]),
@@ -389,31 +403,127 @@ async def sweep(args, cell: dict, mix: dict, http_port: int) -> None:
                       "generator_late_ms_max")}})
 
 
+def controls_only(args, seeds: list) -> int:
+    """Both controls with no program behind them (reference.py:control_rows),
+    for any configuration file: per seed four cases picked as a run picks
+    them, from the mix's own generator; the continuation is seeded random
+    ids, since a control is judged by the token IT puts first at each
+    position. One child builds the weights once and judges every seed."""
+    cfg = load_config(args.config_file, args.rehearsal)
+    mix = load_mix(args.traffic, args.rehearsal)
+    if args.seconds is None:
+        with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+            args.seconds = float(json.load(f)["run_seconds"])
+    sets = {}
+    for seed in seeds:
+        gen = Generator(mix, seed)
+        pool = ([r for stream in gen.client_streams()
+                 for r in stream[:CONTROLS_ONLY_PER_CLIENT]]
+                if mix["loop"] == "closed" else gen.block(args.seconds, "window"))
+        rnd = random.Random(f"{seed}:continuation")
+        sets[str(seed)] = {f"{i}.{p['why']}": {
+            "prompt_ids": [b + BYTE_TOKENIZER_OFFSET for b in p["prompt"].encode()],
+            "served_ids": [rnd.randrange(BYTE_TOKENIZER_OFFSET, cfg["vocab_size"])
+                           for _ in range(p["max_tokens"])],
+        } for i, p in enumerate(pick_checked(pool, seed))}
+    name = os.path.splitext(os.path.basename(args.config_file))[0]
+    out_dir = os.path.join(ROOT, "chiprun_out", "benchmark",
+                           f"controls.{name}.{args.traffic}"
+                           + (".rehearsal" if args.rehearsal else ""))
+    shutil.rmtree(out_dir, ignore_errors=True)
+    os.makedirs(out_dir)
+    cases = os.path.join(out_dir, "reference_cases.json")
+    with open(cases, "w") as f:
+        json.dump({"config_file": os.path.abspath(args.config_file),
+                   "rehearsal": args.rehearsal, "controls_only": True,
+                   "controls": list(CONTROLS), "cases": sets}, f)
+    ref = run_child([os.path.join(HERE, "reference.py"), cases],
+                    child_env(args.rehearsal), os.path.join(out_dir, "reference.log"), 3000)
+    with open(os.path.join(out_dir, "reference_result.json"), "w") as f:
+        json.dump(ref, f)  # every request's own numbers too
+    readings = {c: {} for c in CONTROLS}  # control -> number -> every seed's reading
+    judged = {c: [] for c in CONTROLS}  # control -> every judged seed's verdict
+    shares = []
+    for seed, one in ref["sets"].items():
+        shares.append(one["margin_under_default_epsilon_share"])
+        line = {"phase": "controls", "seed": int(seed), "positions": one["positions"],
+                "margin_under_default_epsilon_share": shares[-1]}
+        for c in CONTROLS:
+            low = one[c]
+            if "skipped" in low:
+                line[c] = low
+                continue
+            judged[c].append(low["agrees"])
+            line[c] = {"agrees": low["agrees"], "why_not": low["why_not"],
+                       "checked": low["compared"]}
+            for number, (value, limit) in low["compared"].items():
+                readings[c].setdefault(number, []).append(value)
+                print(f"checked {seed} {c} {number}: {value} (limit {limit})",
+                      file=sys.stderr)
+        emit(line)
+    # lowest and highest over the seeds: what `judge_readings` is written from
+    emit({"phase": "controls_summary", "config_file": args.config_file,
+          "traffic": args.traffic, "seeds": seeds, "device": ref["reference_device"],
+          "judge": cfg.get("judge"),
+          "margin_under_default_epsilon_share":
+              None if None in shares else [min(shares), max(shares)],
+          **{c: {"agreed": f"{sum(judged[c])} of {len(judged[c])}",
+                 **{n: [min(v), max(v)] for n, v in readings[c].items()}}
+             for c in CONTROLS}})
+    # the readings come before the rule can hold: a file whose `judge` has no
+    # sound `judge_readings` yet is judged by it all the same, and told so
+    files_check.check_judge(args.config_file, cfg)
+    if args.rehearsal:
+        return REHEARSAL_PASSED
+    skipped = [c for c in CONTROLS if len(judged[c]) < len(seeds)]
+    if skipped:
+        print(f"not judged: {skipped} ({ref['reference_device']})", file=sys.stderr)
+        return 1
+    # bf16 has to agree on every seed, int8 on none
+    return 0 if all(judged["bf16"]) and not any(judged["int8"]) else 1
+
+
 # ---------------------------------------------------------------------- #
 
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--workload", required=True)
-    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--workload")
+    ap.add_argument("--seed", type=int, action="append",
+                    help="once; --controls-only takes it several times")
     ap.add_argument("--seconds", type=float, default=None)
     ap.add_argument("--trace", type=int, choices=[0, 1], default=0)
     ap.add_argument("--rehearsal", action="store_true")
     ap.add_argument("--sweep", action="store_true")
-    ap.add_argument("--control", choices=CONTROLS, default=None,
-                    help="also judge the reference in the nearest precision below the "
-                         "configuration's, in the program's place: it has to come out "
-                         "NOT correct (exit code 0 then, if the program is correct)")
+    ap.add_argument("--control", choices=CONTROLS, action="append", default=[],
+                    help="also judge the reference itself in the program's place, on "
+                         "the same prompts and tokens: int8, the nearest precision below "
+                         "the configuration's, has to come out NOT correct; bf16, the "
+                         "configuration's own, correct (exit code 0 then, if the program "
+                         "is correct). May be given twice")
+    ap.add_argument("--controls-only", action="store_true")
+    ap.add_argument("--config-file", help="--controls-only: any configuration file")
+    ap.add_argument("--traffic", help="--controls-only: a mix under traffic/")
     ap.add_argument("--break", dest="broken", choices=["token"], default=None,
                     help="a fault planted under the timed path (worker_entry.py): "
                          "the run has to come out NOT correct")
     args = ap.parse_args()
-    cell = load_cell(args.workload)
+    seeds = args.seed or [0]
+    args.seed = seeds[-1]
+    if args.controls_only != bool(args.config_file and args.traffic) or (
+            args.controls_only == bool(args.workload)):
+        ap.error("either --workload, or --controls-only with --config-file and --traffic")
     try:  # every run guards the files it is driven by
         files_check.check(ROOT)
+        if args.controls_only:
+            return controls_only(args, seeds)
     except files_check.BenchmarkFilesError as e:
         print(f"BENCHMARK.json or a file it names is at fault: {e}", file=sys.stderr)
         return 2
+    except BenchFailure as e:
+        emit({"phase": "failed", "error": str(e)[-3000:]})
+        return 1
+    cell = load_cell(args.workload)
     if args.seconds is None:
         args.seconds = cell["run_seconds"]
     cfg = load_config(cell["config_file"], args.rehearsal)
@@ -422,7 +532,7 @@ def main() -> int:
         ROOT, "chiprun_out", "benchmark",
         f"{cell['name']}.seed{args.seed}.trace{args.trace}"
         + (".sweep" if args.sweep else "") + (".rehearsal" if args.rehearsal else "")
-        + (f".control-{args.control}" if args.control else "")
+        + "".join(f".control-{c}" for c in sorted(args.control))
         + (f".break-{args.broken}" if args.broken else ""))
     shutil.rmtree(out_dir, ignore_errors=True)
     os.makedirs(out_dir)
@@ -518,7 +628,7 @@ def main() -> int:
         cases = os.path.join(out_dir, "reference_cases.json")
         with open(cases, "w") as f:
             json.dump({"config_file": cell["config_file"], "rehearsal": args.rehearsal,
-                       "control": args.control, "cases": res["served"]}, f)
+                       "controls": args.control, "cases": res["served"]}, f)
         ref = run_child([os.path.join(HERE, "reference.py"), cases], env,
                         os.path.join(out_dir, "reference.log"), 600)
         emit({"phase": "reference", **ref})
@@ -575,11 +685,10 @@ def main() -> int:
         # every number compared, beside its limit: the result's last key
         # (moved to the end below) and the last lines of standard error
         checked = compared(ref, counts)
-        if args.control:
-            line["control"] = {"precision": args.control,
-                               "correct": ref["control"]["agrees"],
-                               "why_not_correct": ref["control"]["why_not"],
-                               "checked": compared(ref["control"], counts)}
+        for precision, low in ref.get("controls", {}).items():
+            line.setdefault("controls", {})[precision] = low if "skipped" in low else {
+                "correct": low["agrees"], "why_not_correct": low["why_not"],
+                "checked": compared(low, counts)}
         if args.rehearsal:
             # counts and CPU times, under a name no device metric has
             line["cpu_rehearsal_values"] = line.pop("metrics")
@@ -612,8 +721,10 @@ def main() -> int:
     print(json.dumps(line), flush=True)
     if args.rehearsal:
         return REHEARSAL_PASSED if line["reference_agrees"] else 1
-    if args.control and line["control"]["correct"]:
-        return 1  # the limits let a lower precision through
+    for precision, low in line.get("controls", {}).items():
+        # the limits let a lower precision through, or refuse the program's own
+        if low.get("correct") == (precision == "int8"):
+            return 1
     return 0 if line["correct"] else 1
 
 

@@ -9,13 +9,21 @@ per-layer expressions; the trace reduction on fixtures/synthetic_trace
 (busy union, idle share, self time, kernel share, gap attribution); and the
 dense and mixture-of-experts references against the program's own forwards
 (models/llama.py, models/moe.py at capacity_factor 4.0) at the tiny presets
-in float32 on the CPU. (PR 21 found that a wrong page stays under the
+in float32 on the CPU; the judge's limits on made-up logits, the defaults
+name for name and a configuration's own (`judge`); the rule that holds such
+limits to their readings, by planted files; and that a configuration with
+limits of its own is a new file alone (a whole `run.py --rehearsal` in a
+tree of links, some 90 s). (PR 21 found that a wrong page stays under the
 tolerance at tiny widths on the CPU: that sabotage is a chip check.)
 """
 
 from __future__ import annotations
 
+import copy
+import json
 import os
+import shutil
+import subprocess
 import sys
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
@@ -26,7 +34,7 @@ sys.path.insert(1, os.path.dirname(HERE))
 import layer_metrics  # noqa: E402
 import metrics  # noqa: E402
 import reduce_trace  # noqa: E402
-from traffic import Generator, Request, load_mix, warp  # noqa: E402
+from traffic import Generator, Request, load_mix, quantile, warp  # noqa: E402
 
 
 def close(a, b, tol=1e-9):
@@ -76,6 +84,7 @@ def test_due_time_arithmetic():
     assert m["ttft_p95_ms"] > 80e3
     # per-request TPOT: a (104 - 102) / 20 = 100 ms; d 2.7 s / 27 = 100 ms
     assert close(metrics.percentile([100.0, 100.0], 95), 100.0)
+    assert m["tpot_p95_ms"] > 80e3 and close(m["tpot_p50_ms"], 100.0, 1e-6)
     assert n["samples_tpot"] == 3 and close(n["generator_late_ms_max"], 500.0, 1e-6)
 
 
@@ -101,8 +110,28 @@ def test_same_work_every_seed():
     burst = {"factor": 8, "on_s": 2, "period_s": 10}
     assert close(warp(24.0, 1.0, burst), 10.0) and close(warp(8.0, 1.0, burst), 1.0)
     assert close(warp(20.0, 1.0, burst), 6.0)
-    closed = Generator(load_mix("decode-closed"), 3).client_streams()
+    # a closed loop: the same set for every seed, dealt in rounds, so that a
+    # window's part of it is the same work too (traffic.py:dealt_rounds)
+    cmix = load_mix("decode-closed")
+    closed = Generator(cmix, 3).client_streams()
+    other = Generator(cmix, 2**31 + 11).client_streams()
     assert len(closed) == 32 and all(len(s) == 64 for s in closed)
+    k = cmix["closed_round"]
+    for what, dist in ((lambda r: r.max_tokens, cmix["output_tokens"]),
+                       (lambda r: len(r.prompt), cmix["prompt_tokens"])):
+        every = sorted(what(r) for s in closed for r in s)
+        assert every == sorted(what(r) for s in other for r in s)
+        assert every == sorted(quantile(dist, (j + 0.5) / 2048) for j in range(2048))
+        assert [what(r) for r in closed[0]] != [what(r) for r in other[0]]
+        for streams in (closed, other):
+            for lo in range(0, 64, k):  # a round: one length of each stratum a client
+                rnd_ = sorted(what(r) for s in streams for r in s[lo:lo + k])
+                for s in streams:
+                    ranks = sorted(rnd_.index(what(r)) // 32 for r in s[lo:lo + k])
+                    assert all(abs(a - b) <= 1 for a, b in zip(ranks, range(k))), ranks
+    assert [r.prompt for r in Generator(cmix, 3).client_streams()[7]] == \
+        [r.prompt for r in closed[7]]
+    assert len({r.rid for s in closed for r in s}) == 32 * 64
 
 
 def test_layer_expressions():
@@ -115,7 +144,7 @@ def test_layer_expressions():
     s2 = dict(s1, device_memory=[{"peak_bytes_in_use": 15, "bytes_in_use": 8,
                                   "bytes_limit": 16}])
     ctx = {"stats0": s0, "stats1": s1, "stats2": s2, "trace": None, "seconds": 40.0,
-           "end_to_end": {"ttft_p50_ms": 387.5},
+           "end_to_end": {"ttft_p50_ms": 387.5, "tpot_p50_ms": 17.5},
            "client": {"tokens": 80, "frames": 10,
                       "output_tokens_completed_in_window": 4000}}
     got = layer_metrics.read_all(
@@ -127,6 +156,7 @@ def test_layer_expressions():
     assert close(got["kv.hbm_resident_share"], 50.0)
     assert close(got["client.completed_tok_s"], 100.0)
     assert got["client.ttft_p50_ms.closed"] == 387.5
+    assert got["client.tpot_p50_ms"] == 17.5
     assert close(got["client.tokens_per_frame"], 8.0)
     # no trace: the readers return nothing, and the metric is left out
     assert got["device.idle_share"] is None and got["kernel.pallas_busy_share"] is None
@@ -206,11 +236,10 @@ def test_references_against_the_program():
               f"of references/{ref.__name__.split('.')[-1]}.py over {T - 31} positions")
 
 
-def test_judge_holds_each_request():
-    """reference.judge on made-up logits: a sound run agrees; ONE request of
-    four served 0.2 deviations off at every position, which the per-position
-    tolerance (0.25) and a routed family's pooled means let through, does not:
-    the worst request's median (routed) or mean (dense) catches it."""
+def made_up_run(margins_of_length):
+    """Four requests' made-up reference logits, the tokens they put first as
+    the served ones, log-probabilities off by bf16's own rounding, and
+    routing margins from `margins_of_length(n)`."""
     import numpy as np
 
     import reference
@@ -225,7 +254,21 @@ def test_judge_holds_each_request():
         lp = lp + rng.normal(scale=0.012, size=n) * rows.std(-1)  # bf16's own rounding
         cases[name] = {"prompt_ids": [1] * 100}
         rows_of[name], served_of[name] = rows, (served, lp)
-        margins[name] = rng.uniform(0.0, 0.35, size=n)  # some 29% under the epsilon
+        margins[name] = margins_of_length(rng, n)
+    return cases, rows_of, served_of, margins
+
+
+def test_judge_holds_each_request():
+    """reference.judge on made-up logits: a sound run agrees; ONE request of
+    four served 0.2 deviations off at every position, which the per-position
+    tolerance (0.25) and a routed family's pooled means let through, does not:
+    the worst request's median (routed) or mean (dense) catches it."""
+    import reference
+
+    # some 29% of the margins under the epsilon
+    cases, rows_of, served_of, margins = made_up_run(
+        lambda rng, n: rng.uniform(0.0, 0.35, size=n))
+    lens = {name: len(rows) for name, rows in rows_of.items()}
     for routed, number in ((True, "logprob_gap_request_median_sigmas"),
                            (False, "logprob_gap_mean_sigmas")):
         margins_of = margins if routed else dict.fromkeys(lens)
@@ -240,12 +283,261 @@ def test_judge_holds_each_request():
         assert broken["compared"][number][0] > 0.19
 
 
+ROUTED_TODAY = {  # name -> limit, in the order `checked` prints them (PR 34)
+    "positions_outside": 3, "logprob_gap_pooled_mean_sigmas": 0.035,
+    "logprob_gap_pooled_mean_all_sigmas": 0.07,
+    "logprob_gap_request_median_sigmas": 0.03, "router_left_out_share": 0.45}
+DENSE_TODAY = {"positions_outside": 0, "token_gap_sigmas": 0.3,
+               "logprob_gap_sigmas": 0.25, "logprob_gap_mean_sigmas": 0.06}
+
+
+def test_judge_limits_are_data():
+    """Without a `judge` key a family is judged as before PR 36, name for name
+    and limit for limit; with one, each number is held to the override, and a
+    limit that is no number is refused; the dense family takes none."""
+    import reference
+
+    cases, rows_of, served_of, margins = made_up_run(
+        lambda rng, n: rng.uniform(0.0, 0.35, size=n))
+    plain = reference.judge(cases, rows_of, margins, served_of)
+    assert [(k, lim) for k, (_, lim) in plain["compared"].items()] == list(ROUTED_TODAY.items())
+    assert plain["router_margin_epsilon"] == 0.1
+    dense = reference.judge(cases, rows_of, dict.fromkeys(cases), served_of)
+    assert [(k, lim) for k, (_, lim) in dense["compared"].items()] == list(DENSE_TODAY.items())
+    for name, (value, _) in plain["compared"].items():
+        if not value:
+            continue  # a count of 0 passes any limit
+        held = reference.judge(cases, rows_of, margins, served_of, {name: value / 2})
+        over = [k for k, (v, lim) in held["compared"].items() if v > lim]
+        assert not held["agrees"] and over == [name] and held["compared"][name][1] == value / 2
+        assert reference.judge(cases, rows_of, margins, served_of, {name: value})["agrees"]
+    for bad, margins_of in (({"token_gap_sigmas": 1.0}, margins),
+                            ({"logprob_gap_pooled_mean_all_sigmas": None}, margins),
+                            ({"positions_outside": 9}, dict.fromkeys(cases))):
+        try:
+            reference.judge(cases, rows_of, margins_of, served_of, bad)
+        except ValueError:
+            continue
+        raise AssertionError(f"judge took {bad}")
+
+
+def test_many_experts_are_judged_with_their_flips_in():
+    """Made-up normal router logits at 64 experts, 4 a token, 8 routed layers:
+    at the default epsilon all but nothing is kept, so no program can pass; at
+    an epsilon of 0 every position is judged. And the shares that order
+    statistics give (ISSUE 36's table, which the README repeats)."""
+    import numpy as np
+
+    import reference
+
+    cases, rows_of, served_of, margins = made_up_run(
+        lambda rng, n: reference.router_margins(rng.normal(size=(8, n, 64)), 4))
+    default = reference.judge(cases, rows_of, margins, served_of)
+    assert default["compared"]["router_left_out_share"][0] > 0.99 and not default["agrees"]
+    zero = reference.judge(cases, rows_of, margins, served_of,
+                           {"router_margin_epsilon": 0, "router_left_out_share": 0})
+    got = {k: v for k, (v, _) in zero["compared"].items()}
+    assert zero["agrees"] and got["router_left_out_share"] == 0
+    assert got["logprob_gap_pooled_mean_sigmas"] == got["logprob_gap_pooled_mean_all_sigmas"]
+    dlp = {name: np.abs(lp - (rows_of[name].max(-1) - reference.log_normalizer(rows_of[name])))
+           / rows_of[name].std(-1) for name, (_, lp) in served_of.items()}
+    assert close(got["logprob_gap_request_median_sigmas"],
+                 max(np.median(d) for d in dlp.values() if len(d) >= 128), 1e-6)
+    for geometry, share in (((8, 2, 2, 0.1), 0.321), ((64, 4, 4, 0.1), 0.953),
+                            ((64, 4, 8, 0.1), 0.998), ((64, 4, 8, 0.01), 0.458),
+                            ((64, 4, 8, 0.0), 0.0)):
+        assert abs(reference.order_statistics_share(*geometry) - share) < 0.015, geometry
+
+
+def planted_judge() -> dict:
+    """A `judge` and `judge_readings` that keep the rule, made up."""
+    def told(sound, control):
+        return {"sound": {"lowest": sound[0], "highest": sound[1], "runs": 12,
+                          "kind": "served"},
+                "control_int8": {"lowest": control[0], "highest": control[1], "runs": 12},
+                "reason": "made up by selftest.py"}
+    share = {"reason": "made up by selftest.py", "order_statistics_share": 0.0}
+    return {
+        "judge": {"router_margin_epsilon": 0, "router_left_out_share": 0,
+                  "positions_outside": 12, "logprob_gap_pooled_mean_sigmas": 0.05,
+                  "logprob_gap_pooled_mean_all_sigmas": 0.05,
+                  "logprob_gap_request_median_sigmas": 0.012},
+        "judge_readings": {
+            "router_margin_epsilon": share, "router_left_out_share": dict(share),
+            "positions_outside": told((2, 6), (25, 60)),
+            "logprob_gap_pooled_mean_sigmas": told((0.02, 0.03), (0.07, 0.09)),
+            "logprob_gap_pooled_mean_all_sigmas": told((0.02, 0.03), (0.07, 0.09)),
+            "logprob_gap_request_median_sigmas": told((0.004, 0.006), (0.02, 0.03))}}
+
+
+def test_files_check_holds_limits_to_their_readings():
+    """files_check.py ends with exit code 2 on each planted file, and says
+    why: an override without readings; a limit under the sound runs' highest,
+    short of the geometric mean of the two readings, above the control's
+    lowest, just under it; fewer runs of the control than sound ones; a set in
+    which no control reads three times the sound run; a limit that is no
+    number; a share stated, or held, under what order statistics leave out at
+    the geometry of the file's OWN keys, with or without a `judge`. The sound
+    file passes. The fixture, whose readings are the chip's at 64 experts and
+    4 a token (PR 36), is refused: no limit stands between the controls'
+    counts nor between their medians, and nothing reads three times."""
+    out = os.path.join(os.path.dirname(HERE), "chiprun_out", "selftest_planted")
+    shutil.rmtree(out, ignore_errors=True)
+    os.makedirs(out)
+
+    def check(path: str):
+        p = subprocess.run([sys.executable, os.path.join(HERE, "files_check.py"), path],
+                           capture_output=True)
+        return p.returncode, p.stderr.decode()
+
+    def plant(name: str, spoil, *says) -> None:
+        """Refused, saying each of `says`; or, with none, passed."""
+        cfg = {"num_local_experts": 64, "num_experts_per_tok": 4, "num_hidden_layers": 8,
+               **planted_judge()}
+        spoil(cfg)
+        path = os.path.join(out, name + ".json")
+        with open(path, "w") as f:
+            json.dump(cfg, f)
+        rc, err = check(path)
+        assert rc == (2 if says else 0) and all(x in err for x in says), (name, rc, err)
+
+    def limit(key, value):
+        return lambda cfg: cfg["judge"].update({key: value})
+
+    def each_number(sound, control):
+        def spoil(cfg):
+            for key, told in cfg["judge_readings"].items():
+                if "sound" in told:
+                    told["sound"].update(lowest=sound[0], highest=sound[1])
+                    told["control_int8"].update(lowest=control[0], highest=control[1])
+                    cfg["judge"][key] = 0.2
+        return spoil
+
+    def default_epsilon(stated):
+        def spoil(cfg):
+            cfg["judge"].update(router_margin_epsilon=0.1, router_left_out_share=0.45)
+            cfg["judge_readings"]["router_margin_epsilon"]["order_statistics_share"] = stated
+            cfg["judge_readings"]["router_left_out_share"]["order_statistics_share"] = stated
+        return spoil
+
+    mean = "logprob_gap_pooled_mean_sigmas"
+    plant("sound", lambda cfg: None)
+    plant("no_readings", lambda cfg: cfg["judge_readings"].pop(mean), "no entry for")
+    plant("no_reason", lambda cfg: cfg["judge_readings"][mean].pop("reason"), "`reason`")
+    plant("under_the_sound_runs", limit(mean, 0.025), "is not above the highest sound")
+    plant("short_of_the_geometric_mean",  # sqrt(0.03 x 0.07) = 0.0458
+          limit(mean, 0.04), "the geometric mean of 0.03 and 0.07")
+    plant("above_the_control", limit(mean, 0.08), "is over 0.8 of the int8 control's")
+    plant("just_under_the_control", limit(mean, 0.0699), "is over 0.8 of the int8 control's")
+    plant("near_the_control", limit(mean, 0.056))  # 0.8 x 0.07: the most that passes
+    plant("fewer_control_runs",
+          lambda cfg: cfg["judge_readings"][mean]["control_int8"].update(runs=6),
+          "6 runs of the int8 control for 12 sound ones")
+    plant("not_three_times", each_number((0.1, 0.11), (0.3, 0.4)),
+          "judge_readings: in no number does the int8 control read 3.0 times")
+    plant("three_times", each_number((0.1, 0.11), (0.34, 0.4)))
+    plant("not_compared", limit(mean, None), "each to a number")
+    plant("a_dense_number", limit("token_gap_sigmas", 1.0), "judge may set")
+    # 64 experts, 4 a token, 8 routed layers at the default epsilon: 0.998
+    plant("share_by_arithmetic", default_epsilon(0.998), "order statistics leave out 0.99")
+    plant("share_understated", default_epsilon(0.3), "order statistics leave out 0.99")
+    plant("share_misstated", lambda cfg: cfg["judge_readings"]["router_left_out_share"]
+          .update(order_statistics_share=0.3), "order_statistics_share is 0.3 where")
+    plant("share_with_no_judge", lambda cfg: (cfg.pop("judge"), cfg.pop("judge_readings")),
+          "order statistics leave out 0.99")
+    plant("dense_layers_are_not_routed",  # 64 / 4 / 1 at 0.1 reads 0.53
+          lambda cfg: (default_epsilon(0.53)(cfg), cfg.update(num_dense_layers=7),
+                       cfg["judge"].update(router_left_out_share=0.6)))
+    plant("no_experts", lambda cfg: cfg.pop("num_local_experts"), "has `judge` and none of")
+    shutil.rmtree(out)
+    rc, err = check(os.path.join(HERE, "fixtures", "many-experts.json"))
+    assert rc == 2 and all(x in err for x in (
+        "positions_outside: limit", "logprob_gap_request_median_sigmas: limit",
+        "is over 0.8 of the int8 control's lowest reading",
+        "in no number does the int8 control read 3.0 times")) \
+        and "logprob_gap_pooled_mean" not in err, err
+
+
+def test_controls_only_needs_no_program_to_serve():
+    """`run.py --controls-only` on the fixture at rehearsal sizes: four cases a
+    seed from the mix's generator, the int8 control judged by the file's
+    limits, the bf16 control left out on a CPU and said so; then exit code 2,
+    because the fixture's limits do not keep the rule (which is its finding;
+    a file that keeps it ends with 4 here, 0 or 1 on the chip)."""
+    p = subprocess.run(
+        [sys.executable, os.path.join(HERE, "run.py"), "--controls-only", "--rehearsal",
+         "--config-file", os.path.join(HERE, "fixtures", "many-experts.json"),
+         "--traffic", "decode-closed", "--seed", "7", "--seed", "2147483907"],
+        stdout=subprocess.PIPE, stderr=subprocess.PIPE, timeout=600)
+    lines = [json.loads(x) for x in p.stdout.decode().strip().splitlines()]
+    assert p.returncode == 2 and [x["phase"] for x in lines] == [
+        "controls", "controls", "controls_summary"], (p.returncode, p.stderr[-2000:])
+    assert b"in no number does the int8 control read 3.0 times" in p.stderr
+    with open(os.path.join(HERE, "fixtures", "many-experts.json")) as f:
+        limits = json.load(f)["judge"]
+    for one in lines[:2]:
+        assert "skipped" in one["bf16"] and one["positions"] > 0
+        assert {k: lim for k, (_, lim) in one["int8"]["checked"].items()} == {
+            k: v for k, v in limits.items() if k != "router_margin_epsilon"}
+    assert lines[2]["seeds"] == [7, 2147483907] and lines[2]["int8"]["agreed"].endswith("of 2")
+    assert b"checked 7 int8 logprob_gap_pooled_mean_sigmas: " in p.stderr
+
+
+def test_limits_of_its_own_are_a_new_file_alone():
+    """A later PR's configuration with a `judge` key: one new file and entries
+    in BENCHMARK.json, no edit to a file the benchmark has. Built here in a
+    tree of links beside the real one (the new file and BENCHMARK.json alone
+    are real), and `run.py --rehearsal` there judges it by its overrides."""
+    root = os.path.dirname(HERE)
+    tree = os.path.join(root, "chiprun_out", "selftest_tree")
+    shutil.rmtree(tree, ignore_errors=True)
+    os.makedirs(os.path.join(tree, "benchmark", "configs"))
+    for name in os.listdir(root):
+        if name not in ("benchmark", "BENCHMARK.json", "chiprun_out", ".jax_cache", ".git"):
+            os.symlink(os.path.join(root, name), os.path.join(tree, name))
+    for name in os.listdir(HERE):
+        if name not in ("configs", "__pycache__"):
+            os.symlink(os.path.join(HERE, name), os.path.join(tree, "benchmark", name))
+    for name in os.listdir(os.path.join(HERE, "configs")):
+        os.symlink(os.path.join(HERE, "configs", name),
+                   os.path.join(tree, "benchmark", "configs", name))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    entry = copy.deepcopy(bench["configs"][0])  # the routed one
+    with open(os.path.join(root, entry["file"])) as f:
+        cfg = json.load(f)
+    assert cfg["family"] == "moe" and "judge" not in cfg
+    entry.update(name="routed-with-its-own-limits",
+                 file="benchmark/configs/routed-with-its-own-limits.json")
+    cfg.update(planted_judge())
+    with open(os.path.join(tree, entry["file"]), "w") as f:
+        json.dump(cfg, f)
+    cell = dict(bench["workloads"][0], name=entry["name"] + ".decode-closed",
+                config=entry["name"])
+    bench["configs"].append(entry)
+    bench["workloads"].append(cell)
+    with open(os.path.join(tree, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f)
+    p = subprocess.run(
+        [sys.executable, os.path.join(tree, "benchmark", "run.py"), "--rehearsal",
+         "--workload", cell["name"], "--seed", "2147483801"],
+        cwd=tree, stdout=subprocess.PIPE, stderr=subprocess.PIPE, timeout=900)
+    lines = p.stdout.decode().strip().splitlines()
+    line = json.loads(lines[-1]) if lines else {}
+    assert p.returncode == 4 and line.get("reference_agrees"), (p.returncode, lines[-3:])
+    limits = {k: lim for k, (_, lim) in line["checked"].items()}
+    assert limits == {**{k: v for k, v in cfg["judge"].items()
+                         if k != "router_margin_epsilon"}, "wrong_length_requests": 0}, limits
+    assert line["checked"]["router_left_out_share"][0] == 0
+    assert b"checked positions_outside: " in p.stderr and b"(limit 12)" in p.stderr
+    shutil.rmtree(tree)
+
+
 def test_benchmark_files():
     """files_check.py's checks (which every run of run.py makes too), and what
     needs the program: every configuration loads, plain and with its
     rehearsal block laid over, into the dataclass it names."""
     import dataclasses
-    import json
 
     import files_check
     from worker_entry import build_model_config, load_config, lookup

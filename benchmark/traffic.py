@@ -8,8 +8,10 @@ the lengths are the distribution's own quantiles at (i + 0.5) / n, not
 samples, so two seeds differ in who arrives when and not in how much work a
 window holds. That holds for an open loop's block, which is sent whole. A
 closed loop's set is clients x 64 requests, of which a run sends as many as
-the system completes (the first 8 or so of each client's 64), so its window
-holds a seed's sample of the set and not the set.
+the system completes (the first 20 or so of each client's 64), so the set is
+dealt in rounds (`dealt_rounds`): every `closed_round` requests of a client
+hold one length of each stratum of the distribution, whatever the seed, and a
+window holds whole rounds and parts of two, not a seed's sample of the set.
 
 No JAX here: the parent imports this.
 """
@@ -84,6 +86,35 @@ def length_set(dist: dict, n: int, rnd: random.Random) -> List[int]:
     return vals
 
 
+def dealt_rounds(dist: dict, clients: int, per_round: int, rounds: int,
+                 rnd: random.Random) -> List[List[int]]:
+    """A closed loop's lengths, client by client: the same multiset for every
+    seed (the quantiles at (j + 0.5) / N, N = clients x per_round x rounds,
+    what `length_set` gives for N), dealt so that a part of it is the same
+    work too. Round r holds every rounds-th quantile, a grid of its own over
+    the whole distribution; sorted, it is cut into per_round strata of
+    `clients` neighbours, and a client gets one length of each stratum. The
+    seed decides which one, the order within a client's round and the order
+    of the rounds."""
+    n = clients * per_round
+    streams: List[List[int]] = [[] for _ in range(clients)]
+    order = list(range(rounds))
+    rnd.shuffle(order)
+    for r in order:
+        vals = [quantile(dist, (i * rounds + r + 0.5) / (n * rounds))
+                for i in range(n)]
+        hands: List[List[int]] = [[] for _ in range(clients)]
+        for s in range(per_round):
+            stratum = vals[s * clients:(s + 1) * clients]
+            rnd.shuffle(stratum)
+            for hand, v in zip(hands, stratum):
+                hand.append(v)
+        for stream, hand in zip(streams, hands):
+            rnd.shuffle(hand)
+            stream += hand
+    return streams
+
+
 def gap_set(n: int, rnd: random.Random) -> List[float]:
     """n unit-rate exponential gaps: the quantiles, scaled to sum to n, in
     the seed's order (a Poisson stream whose every window holds n arrivals)."""
@@ -138,10 +169,12 @@ class Generator:
     def _rnd(self, what: str) -> random.Random:
         return random.Random(f"{self.seed}:{what}")
 
-    def _requests(self, n: int, tag: str, phase: str) -> List[Request]:
+    def _requests(self, n: int, tag: str, phase: str,
+                  lengths: Optional[tuple] = None) -> List[Request]:
         rnd = self._rnd(f"{tag}:lengths")
-        prompts = length_set(self.mix["prompt_tokens"], n, rnd)
-        outs = length_set(self.mix["output_tokens"], n, rnd)
+        prompts, outs = lengths or (
+            length_set(self.mix["prompt_tokens"], n, rnd),
+            length_set(self.mix["output_tokens"], n, rnd))
         share = self.mix.get("prefix_sharing")
         reqs = []
         for i in range(n):
@@ -209,7 +242,18 @@ class Generator:
     def client_streams(self, per_client: int = 64) -> List[List[Request]]:
         """Closed loop: every client's list of requests (it cycles if it
         runs out, which a window's length never reaches). The whole set is
-        the same for every seed; the part of it a run gets through is not."""
+        the same for every seed, and so is the work in every round of
+        `closed_round` requests a client (`dealt_rounds`; PERF.md, PR 36: with
+        the set merely shuffled a window held 297 to 321 requests by the
+        seed, and `out_tok_s` followed)."""
         clients = int(self.mix["clients"])
-        reqs = self._requests(clients * per_client, "c", "closed")
-        return [reqs[c::clients] for c in range(clients)]
+        per_round = int(self.mix.get("closed_round", 8))
+        rounds = -(-per_client // per_round)
+        rnd = self._rnd("c:deal")
+        by_client = [dealt_rounds(self.mix[k], clients, per_round, rounds, rnd)
+                     for k in ("prompt_tokens", "output_tokens")]
+        # request i is client i % clients' request i // clients
+        flat = [[by_client[k][i % clients][i // clients]
+                 for i in range(clients * per_round * rounds)] for k in (0, 1)]
+        reqs = self._requests(len(flat[0]), "c", "closed", tuple(flat))
+        return [reqs[c::clients][:per_client] for c in range(clients)]
