@@ -103,7 +103,8 @@ COMPILE_SURFACES = {
         "warmup": True,
         "help": "ragged prefill+decode fusion over the token dimension "
                 "(plain and pure-spec packs; spec lanes pack 1+d verify "
-                "rows); the N x P family of a table width is compiled "
+                "rows; decode rows of a piped pack read the decode carry "
+                "by lane); the N x P family of a table width is compiled "
                 "together at its first use (engine._prime_mixed_family)",
     },
     "mixed_step_variant": {
@@ -232,6 +233,21 @@ COMPILE_SURFACES = {
         "help": "masked on-device swap of per-lane decode state at slot "
                 "turnover (no donation: old carry is the fallback for "
                 "unmasked lanes)",
+    },
+    "carry_write": {
+        "module": "dynamo_tpu/engine/engine.py",
+        "kind": "jit",
+        "donate": (),
+        "static": (),
+        "axes": {
+            "B": "config.max_num_seqs",
+            "R": "the mixed step's row bucket (engine._mixed_row_bucket)",
+        },
+        "warmup": True,
+        "dispatch": ("_carry_write",),
+        "help": "a piped mixed step's samples into the decode carry by "
+                "lane, behind it on the same stream; compiled with the "
+                "mixed family (engine._prime_mixed_family)",
     },
     "extract_pages": {
         "module": "dynamo_tpu/engine/engine.py",

@@ -245,6 +245,38 @@ def _auto_num_pages(params, model_cfg, config: EngineConfig,
     return int(n)
 
 
+def carry_read(tokens, pen_rows, row_starts, row_lane, carry_tok, carry_pen):
+    """The mixed step's read of the decode pipeline's device carry: a row
+    whose `row_lane` names a lane (>= 0; decode rows of a piped pack) takes
+    its one token and its penalty window from that lane of the carry, so a
+    block or a mixed step still running ahead is read on the device and the
+    host never waits for the value. Rows with -1 keep what the host packed
+    (prefill rows; every row of a drained pack)."""
+    from_carry = row_lane >= 0
+    lane = jnp.maximum(row_lane, 0)
+    tokens = tokens.at[
+        jnp.where(from_carry, row_starts, tokens.shape[0])
+    ].set(carry_tok[lane], mode="drop")
+    pen_rows = jnp.where(from_carry[:, None], carry_pen[lane], pen_rows)
+    return tokens, pen_rows
+
+
+def carry_write(tok_d, pos_d, sl_d, pen_d, sampled, w_lane, w_pos):
+    """A mixed step's samples into the decode carry, behind it on the same
+    stream: row r's sample becomes lane `w_lane[r]`'s current token at
+    absolute position `w_pos[r]` (a decode row's lane advances one token; a
+    lane whose prompt completed in the step takes its first), and the
+    penalty ring takes it at that position, as decode_block's scan does.
+    Rows with lane -1 write nothing; a lane appears at most once."""
+    B, W = pen_d.shape
+    lane = jnp.where(w_lane >= 0, w_lane, B)  # out of range: dropped
+    tok_d = tok_d.at[lane].set(sampled, mode="drop")
+    pos_d = pos_d.at[lane].set(w_pos, mode="drop")
+    sl_d = sl_d.at[lane].set(w_pos + 1, mode="drop")
+    pen_d = pen_d.at[lane, w_pos % W].set(sampled, mode="drop")
+    return tok_d, pos_d, sl_d, pen_d
+
+
 @dataclass
 class _Slot:
     """One decode slot (host bookkeeping)."""
@@ -271,6 +303,9 @@ class _Slot:
     admit_seq: int = 0  # admission order; preemption victims = newest
     done: bool = False
     resume_token: Optional[int] = None  # preempted: continue with this token
+    first_pending: bool = False  # the prompt completed in a piped mixed step
+    # still in flight: the lane is decode-active on the device, its first
+    # token reaches the host at that entry's fetch
     return_kv: bool = False  # prefill role: ship KV pages with the 1st token
     kv_pull: bool = False  # prefill role: caller can pull via the data plane
     kv_stream: bool = False  # prefill role: caller wants the EARLY-staged
@@ -724,6 +759,10 @@ class JaxEngine:
         # fused path actually taken in production, and what padding does
         # each path pay per step
         self.mixed_steps = 0
+        # of those, the ones that ran as entries of the decode pipeline
+        # with no host round trip on either side: dispatched without a
+        # drain before them, their successor queued before their fetch
+        self.mixed_steps_piped = 0
         self.split_steps = 0
         self.mixed_padded_tokens = 0
         self.mixed_real_tokens = 0
@@ -744,9 +783,11 @@ class JaxEngine:
         self.expert_rows_computed = 0
         self._last_prefill_shape = None  # (padded, real) of the latest dispatch
         self._last_decode_shape = None
-        # set by _dispatch_mixed when only the in-flight decode pipeline
-        # blocks fusing: the step loop holds the split prefill one step so
-        # the drained pipeline fuses next step instead
+        # set by _dispatch_mixed when a pack that needs host-authoritative
+        # lanes (_pack_pipes says no) waits for the pipeline to drain: the
+        # step loop holds the split prefill one step so the drained
+        # pipeline fuses next step instead. A lean pack never sets it: it
+        # queues behind what is in flight
         self._mixed_wait_drain = False
         # speculative decoding (engine/spec.py): host mirror of the device
         # history ring + SpecDecodeStats counters (_core.pyi:269-301 role)
@@ -796,8 +837,13 @@ class JaxEngine:
         self._tables_dev = None
         self._samp_dev = None
         self._pen_dev = None  # [B, W] recent-token ring (penalties)
-        self._inflight: deque = deque()  # [{"active": [...], "toks": dev[K,B]}]
-        # pending prefill completions awaiting their first-token fetch
+        # the pipeline's ONE queue, fetched in dispatch order: decode blocks
+        # {"kind": "block"|"spec", "lanes", "toks": dev[K,B], "adv"} and
+        # piped mixed steps {"kind": "mixed", "first": dev[R], "done",
+        # "progressed", "decode", "spec"}; at most two entries
+        self._inflight: deque = deque()
+        # split prefill dispatches and drained mixed steps awaiting their
+        # first-token fetch, which the step that dispatched them makes
         self._pending_prefill: List[dict] = []
         # all device dispatches run on this single thread so XLA compiles
         # (which can take tens of seconds) never stall the asyncio event
@@ -973,14 +1019,19 @@ class JaxEngine:
         @partial(jax.jit, donate_argnums=(1, 2, 12), out_shardings=prefill_out_sh)
         def mixed_step(params, kv_k, kv_v, tokens, positions, row_ids,
                        page_tables, row_starts, row_lens, ctx_lens, last_flat,
-                       samp, rng, pen_rows):
+                       samp, rng, pen_rows, row_lane, carry_tok, carry_pen):
             """Unified mixed step: ONE ragged forward over a flat buffer
             packing prefill chunks (row_len > 1) and decode lanes
             (row_len == 1), with each row's last-token logits sampled on
             device — the fused replacement for a prefill_batch dispatch
             followed by a decode dispatch (docs/ragged_attention.md).
             Attention rides ops/pallas_ragged_attention on TPU, the XLA
-            ragged reference elsewhere."""
+            ragged reference elsewhere. Decode rows of a piped pack take
+            their token and penalty window from the decode carry by lane
+            (carry_read), so the step queues behind whatever is in flight."""
+            tokens, pen_rows = carry_read(
+                tokens, pen_rows, row_starts, row_lane, carry_tok, carry_pen
+            )
             rng, sub = jax.random.split(rng)
             logits, kv_k, kv_v = self._model.ragged_forward(
                 params, c, tokens, positions, row_ids, kv_k, kv_v,
@@ -1228,17 +1279,18 @@ class JaxEngine:
         # churn). lane_mask patches carry+sampling+table; table_mask extends
         # to lanes whose page table grew mid-decode (their carry values on
         # device are NEWER than host state and must not be overwritten).
-        patch_out_sh = None
+        patch_out_sh = write_out_sh = None
         if self._mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
 
             repl = NamedSharding(self._mesh, PartitionSpec())
-            patch_out_sh = (repl,) * 10
+            patch_out_sh = (repl,) * 12
+            write_out_sh = (repl,) * 4
 
         @partial(jax.jit, out_shardings=patch_out_sh)
         def patch_lanes(
             tokens, positions, seq_lens, tables, temps, top_ks, top_ps, seeds,
-            pens, recent,
+            presence, frequency, repetition, recent,
             lane_mask, table_mask,
             n_tokens, n_positions, n_seq_lens, n_tables, n_temps, n_top_ks,
             n_top_ps, n_seeds, n_pens, n_recent,
@@ -1250,15 +1302,26 @@ class JaxEngine:
             top_ks = jnp.where(lane_mask, n_top_ks, top_ks)
             top_ps = jnp.where(lane_mask, n_top_ps, top_ps)
             seeds = jnp.where(lane_mask, n_seeds, seeds)
-            pens = jnp.where(lane_mask[:, None], n_pens, pens)
+            # the three penalty columns in and out as the sampler holds
+            # them: no stack before the call, no slices of its result
+            presence = jnp.where(lane_mask, n_pens[:, 0], presence)
+            frequency = jnp.where(lane_mask, n_pens[:, 1], frequency)
+            repetition = jnp.where(lane_mask, n_pens[:, 2], repetition)
             recent = jnp.where(lane_mask[:, None], n_recent, recent)
             tables = jnp.where(table_mask[:, None], n_tables, tables)
             return (
                 tokens, positions, seq_lens, tables, temps, top_ks, top_ps,
-                seeds, pens, recent,
+                seeds, presence, frequency, repetition, recent,
             )
 
         self._patch_lanes = patch_lanes
+        # a piped mixed step's samples into the carry (carry_write): one
+        # program, whatever the pack's token bucket. jit keeps its cache
+        # by the function it wraps, so a partial of this engine's own:
+        # `_surface_cache_sizes` then counts this engine's programs only
+        self._carry_write = jax.jit(
+            partial(carry_write), out_shardings=write_out_sh
+        )
 
         # disagg KV movement (host-staged; llm/disagg.py wire format).
         # ops/kv_quant's accessors cover both store shapes: a plain fp
@@ -1308,6 +1371,7 @@ class JaxEngine:
             "prefill_batch_lora": self._prefill_batch_lora,
             "prefill_single": self._prefill_single,
             "patch_lanes": self._patch_lanes,
+            "carry_write": self._carry_write,
             "extract_pages": self._extract_pages,
             "inject_pages": self._inject_pages,
         }
@@ -2353,6 +2417,7 @@ class JaxEngine:
         # (docs/ragged_attention.md; jax_worker republishes these as
         # prometheus gauges)
         out["mixed_steps"] = self.mixed_steps
+        out["mixed_steps_piped"] = self.mixed_steps_piped
         out["split_steps"] = self.split_steps
         # the lean mixed_step family (token buckets x table widths) and
         # how many of its programs the jit cache holds: equal once the
@@ -2492,15 +2557,29 @@ class JaxEngine:
             await asyncio.sleep(0 if progressed else 0.001)
 
     async def _step_once(self) -> bool:
-        """One engine iteration: admit, dispatch (ONE fused mixed step
-        when both prefill and decode are runnable, else prefill batch +
-        decode block), then collect ALL host-needed values in one
-        device_get."""
+        """One engine iteration: admit, dispatch ONE entry of the decode
+        pipeline (a fused mixed step when both prefill and decode are
+        runnable, else prefill batch + decode block), then fetch the oldest
+        entry once another is queued behind it, so its host read overlaps
+        the newer one's compute. A lean mixed step is an entry like a
+        block: it queues behind what is in flight and its successor queues
+        behind it, so the host never holds more than one entry behind the
+        running one, and an arrival admitted at a wake gets the next entry
+        to itself as a mixed step. A pack that needs host-authoritative
+        lanes (_pack_pipes) drains the pipeline first and is fetched in
+        the step that dispatched it."""
         self._admit_waiting()
         progressed = await self._run_injections()
         dispatched = False
         if await self._dispatch_mixed():
             progressed = True
+            if len(self._inflight) == 1 and \
+                    self._inflight[0]["kind"] == "mixed":
+                # nothing ran ahead of it, so it is the running entry:
+                # queue its successor now, as a block's second block is —
+                # the prompt's next chunk if there is one, else a block
+                if not await self._dispatch_mixed():
+                    dispatched = await self._dispatch_decode(chained=True)
         else:
             self._last_prefill_shape = self._last_decode_shape = None
             pf = False
@@ -2511,8 +2590,8 @@ class JaxEngine:
             if pf and dispatched and self._last_prefill_shape \
                     and self._last_decode_shape:
                 # a mixed-shaped step served by the split pair (mixed off,
-                # variant kinds, pipeline in flight, planner refusal):
-                # account its padding beside the fused path's
+                # variant kinds, planner refusal): account its padding
+                # beside the fused path's
                 self.split_steps += 1
                 self.split_padded_tokens += (
                     self._last_prefill_shape[0] + self._last_decode_shape[0]
@@ -2520,8 +2599,8 @@ class JaxEngine:
                 self.split_real_tokens += (
                     self._last_prefill_shape[1] + self._last_decode_shape[1]
                 )
-        # fetch the oldest block only once the pipeline is full or stalled,
-        # so its host read overlaps the newer block's compute
+        # fetch the oldest entry only once the pipeline is full or stalled,
+        # so its host read overlaps the newer entry's compute
         fetch_block = len(self._inflight) >= 2 or (
             bool(self._inflight) and not dispatched
         )
@@ -2752,10 +2831,14 @@ class JaxEngine:
 
     def _dev_mixed(self, p: dict):
         """One mixed step from its operands as the "mixed" broadcast carries
-        them (_blank_mixed_pack's keys). A pack that carries "prime" is a
-        priming call (_prime_mixed_family): it runs on a copy of the
-        sampling key and leaves `_rng` as it was, so that seeded streams do
-        not depend on when the family was compiled."""
+        them (_blank_mixed_pack's keys). A pack that carries "row_lane" is
+        piped (_dispatch_mixed): its decode rows read the decode carry on
+        the device and its samples are written back into it (carry_write),
+        so it runs behind whatever is in flight and the next entry behind
+        it. A pack that carries "prime" is a priming call
+        (_prime_mixed_family): it runs on a copy of the sampling key and
+        leaves `_rng` and the carry as they were, so that seeded streams
+        do not depend on when the family was compiled."""
         prime = "prime" in p
         pens = p["pens"]
         samp = SamplingParams(
@@ -2784,9 +2867,30 @@ class JaxEngine:
             jnp.asarray(p["pen_rows"]),
         )
         if "mask" not in p:
-            # plain pack: the lean program, byte-identical operands to the
-            # pre-variant fused path
-            first, self.kv_k, self.kv_v, rng = self._mixed_step(*args)
+            # plain pack: the lean program. A drained pack reads no lane
+            # (its map is all -1), so any carry of the right shape serves
+            piped = "row_lane" in p
+            if self._carry is not None:
+                carry = (*self._carry, self._pen_dev)
+            else:  # before the first reset
+                B = self.config.max_num_seqs
+                lanes = jnp.zeros((B,), jnp.int32)
+                carry = (lanes, lanes, lanes, jnp.full(
+                    (B, self.config.penalty_window), -1, jnp.int32))
+            none = np.full(p["row_starts"].shape, -1, np.int32)
+            first, self.kv_k, self.kv_v, rng = self._mixed_step(
+                *args, jnp.asarray(p.get("row_lane", none)),
+                carry[0], carry[3],
+            )
+            if piped or prime:
+                # priming compiles the write-back beside the family, on
+                # a map that names no lane, and drops what it returns
+                wrote = self._carry_write(
+                    *carry, first[0], jnp.asarray(p.get("w_lane", none)),
+                    jnp.asarray(p.get("w_pos", none)),
+                )
+                if piped:
+                    self._carry, self._pen_dev = wrote[:3], wrote[3]
         else:
             # variant pack: the mask operand is always present (all-ones
             # for maskless packs — an exact no-op), the LoRA operand rides
@@ -2947,15 +3051,13 @@ class JaxEngine:
                    tables, temps, top_ks, top_ps, seeds, pens, recent,
                    hist=None):
         samp = self._samp_dev
-        pens_cur = jnp.stack(
-            [samp.presence, samp.frequency, samp.repetition], axis=1
-        )
         (
-            tok_d, pos_d, sl_d, tab_d, t_d, k_d, p_d, s_d, pen_d, rec_d,
+            tok_d, pos_d, sl_d, tab_d, t_d, k_d, p_d, s_d,
+            pres_d, freq_d, rep_d, rec_d,
         ) = self._patch_lanes(
             self._carry[0], self._carry[1], self._carry[2], self._tables_dev,
             samp.temperature, samp.top_k, samp.top_p, samp.seed,
-            pens_cur, self._pen_dev,
+            samp.presence, samp.frequency, samp.repetition, self._pen_dev,
             jnp.asarray(lane_mask), jnp.asarray(table_mask),
             jnp.asarray(tokens), jnp.asarray(positions), jnp.asarray(seq_lens),
             jnp.asarray(tables), jnp.asarray(temps), jnp.asarray(top_ks),
@@ -2967,8 +3069,7 @@ class JaxEngine:
         self._pen_dev = rec_d
         self._samp_dev = SamplingParams(
             temperature=t_d, top_k=k_d, top_p=p_d, seed=s_d,
-            presence=pen_d[:, 0], frequency=pen_d[:, 1],
-            repetition=pen_d[:, 2],
+            presence=pres_d, frequency=freq_d, repetition=rep_d,
         )
         if hist is not None and self._hist_dev is not None:
             # dirty lanes take the host ring row; others keep the (newer)
@@ -3751,11 +3852,7 @@ class JaxEngine:
         # reproduces the legacy head-candidate formula exactly; sla scores
         # shapes by slots-served/tokens-granted under the ITL budget and
         # may defer the dispatch entirely to protect decode cadence.
-        has_decode = any(
-            s is not None and s.generated > 0 and s.resume_token is None
-            and s.prefill_pos >= len(s.kv_prompt)
-            for s in self.slots
-        )
+        has_decode = bool(self._active_decode_indices())
         plan = self.scheduler.plan_prefill(cands, decode_active=has_decode)
         if plan is None:
             # ITL budget exhausted and no deadline at risk: prefill yields
@@ -4039,9 +4136,16 @@ class JaxEngine:
 
     def _finish_prefill(self, slot: _Slot, first: int,
                         first_lp: Optional[float] = None,
-                        first_top: Optional[dict] = None):
-        """Prompt KV fully computed; activate the slot for decode."""
+                        first_top: Optional[dict] = None,
+                        piped: bool = False):
+        """Prompt KV fully computed and the first token fetched: what
+        needs the VALUE (emit, finish reason, the sequence, the host's
+        token mirror). What is known at dispatch (the lane is
+        decode-active, its length, its table) was done there for a `piped`
+        mixed step, whose sample is already in the device carry; any other
+        dispatch activates the lane here, from the host's value."""
         self._commit_blocks(slot)
+        slot.first_pending = False
         if slot.done or slot.context.is_stopped():
             self._emit_finish(slot, "cancelled")
             self._release_slot(slot)
@@ -4068,10 +4172,11 @@ class JaxEngine:
             slot.generated = 1
             slot.seq.append(first)
             self.tokens[slot.slot_idx] = first
-            self.seq_lens[slot.slot_idx] = len(slot.kv_prompt) + 1
-            self._fill_hist(slot.slot_idx, slot)
-            self._fill_recent(slot.slot_idx, slot)
-            self._mark_lane_dirty(slot.slot_idx)
+            if not piped:
+                self.seq_lens[slot.slot_idx] = len(slot.kv_prompt) + 1
+                self._fill_hist(slot.slot_idx, slot)
+                self._fill_recent(slot.slot_idx, slot)
+                self._mark_lane_dirty(slot.slot_idx)
             self._maybe_finish(slot, first)
 
     async def _emit_prefill_result(self, slot: _Slot, first_token: int,
@@ -4403,7 +4508,11 @@ class JaxEngine:
         for i, slot in enumerate(self.slots):
             if slot is None:
                 continue
-            if slot.prefill_pos >= len(slot.kv_prompt) and slot.generated > 0 and slot.resume_token is None:
+            if (
+                slot.prefill_pos >= len(slot.kv_prompt)
+                and (slot.generated > 0 or slot.first_pending)
+                and slot.resume_token is None
+            ):
                 out.append(i)
         return out
 
@@ -4463,6 +4572,15 @@ class JaxEngine:
         victim.kv_prompt = list(victim.seq.tokens[:-1])
         victim.prefill_pos = 0
         self._release_slot(victim)
+        # what it has in flight is recomputed by the resume: take its rows
+        # out of the queued entries, which would otherwise find the same
+        # slot in the same lane again if it is re-admitted before their
+        # fetch, and emit those tokens twice
+        for e in self._inflight:
+            if e["kind"] == "mixed":
+                e["decode"] = [r for r in e["decode"] if r[2] is not victim]
+            else:
+                e["lanes"] = [r for r in e["lanes"] if r[1] is not victim]
         self._waiting.insert(0, victim)
         return True
 
@@ -4511,12 +4629,27 @@ class JaxEngine:
         same fetched [R] result. Guided rows carry a packed FSM mask
         operand, lora rows a per-row adapter index, and spec-eligible
         lanes pack 1+d one-token verify rows — the fused path is the
-        default for blended traffic. Returns False (split path runs)
-        whenever the fused step is inapplicable: mixed disabled, a
-        multimodal candidate starved past its SLA (mm stays split-only),
-        decode blocks in flight (their device carry owns lane state — the
-        mixed step needs host-authoritative lanes), or the planner
-        declines.
+        default for blended traffic.
+
+        A lean pack is an ordinary entry of the decode pipeline
+        (_pack_pipes): the host knows every decode lane's position,
+        length, table and sampling parameters at dispatch, so the pack
+        queues behind whatever block or mixed step is in flight, its
+        decode rows take token and penalty window from the device carry
+        by lane, its samples go back into the carry, and it is fetched in
+        dispatch order like a block (_inflight, kind "mixed"). A pack with
+        a row that needs a HOST value — a guided, spec-verify or LoRA
+        row, a preempted resume, the disagg prefill role's completion, or
+        any pack while the carry is invalid — waits for the pipeline to
+        drain (`_mixed_wait_drain` holds the split prefill meanwhile),
+        packs host-authoritative lanes and is fetched in the step that
+        dispatched it (_pending_prefill).
+
+        Returns False (split path runs) whenever the fused step is
+        inapplicable: mixed disabled, a multimodal candidate starved past
+        its SLA (mm stays split-only), a pack that has to wait for the
+        drain, the pipeline already holding its two entries, or the
+        planner declines.
 
         Shapes are a closed family, compiled together at its first use
         (_prime_mixed_family): at most three flat-token buckets
@@ -4579,9 +4712,16 @@ class JaxEngine:
         if plan is None:
             return False  # nothing fuses (e.g. decode lanes fill the
             # budget) — split path runs at full rate, no hold
-        if self._inflight or self._pending_prefill:
-            # a decode block in flight owns these lanes' device carry, so
-            # the fused step can't pack them yet. Signal the step loop to
+        pipes = self._pack_pipes(plan.chosen, active)
+        if pipes and self._carry_valid:
+            if len(self._inflight) >= 2:
+                # never more than one entry queued behind the running one:
+                # an arrival admitted at the next wake gets the next entry
+                return False
+        elif self._inflight or self._pending_prefill:
+            # the entries in flight own these lanes' device carry, and this
+            # pack needs host-authoritative lanes (or the carry is invalid
+            # and is uploaded from them). Signal the step loop to
             # HOLD the split prefill for one step while the pipeline
             # drains (the split dispatch would queue behind the in-flight
             # block on the device stream anyway) — the next step fuses.
@@ -4633,6 +4773,16 @@ class JaxEngine:
         ]
         if not chosen:
             return False
+        if pipes:
+            # a lane the host already knows will be done before this step
+            # runs (what it has generated and what is in flight reaches its
+            # max_tokens) is left out: its row would be sampled for nothing
+            flying = self._tokens_in_flight()
+            active = [
+                i for i in active
+                if self.slots[i].generated + flying.get(id(self.slots[i]), 0)
+                < self.slots[i].max_tokens
+            ]
         # the dispatch is committed from here on — account it (plan_mixed
         # itself is pure, so an abandoned plan never skews the sched_*
         # grant counters the split path's plan_prefill also feeds)
@@ -4666,6 +4816,13 @@ class JaxEngine:
         # budget, mixed_max_tokens floored to the alignment, so that the
         # Pallas kernel's N % tile_q assert holds for every bucket
         payload = self._blank_mixed_pack(total, ctx_pages, variant)
+        if pipes:
+            # row -> lane, for the read of the carry and for the write
+            # back into it; the "mixed" broadcast carries both, so that
+            # followers replay the same programs
+            for key in ("row_lane", "w_lane"):
+                payload[key] = np.full_like(payload["row_lens"], -1)
+            payload["w_pos"] = np.zeros_like(payload["row_lens"])
         N_pad = len(payload["toks"])
         toks, positions, row_ids = (
             payload["toks"], payload["positions"], payload["row_ids"])
@@ -4714,6 +4871,11 @@ class JaxEngine:
                 lora_rows[row] = s.lora_idx
             s.sched_skips = 0
             meta.append((s, chunk, row))
+            if pipes and start + chunk >= len(s.kv_prompt):
+                # the prompt completes in this step: its first token goes
+                # into the lane's carry at the prompt's length
+                payload["w_lane"][row] = s.slot_idx
+                payload["w_pos"][row] = len(s.kv_prompt)
             off += aligned(chunk)
             row += 1
         for i in active:
@@ -4733,7 +4895,7 @@ class JaxEngine:
                 row_starts[row] = off
                 row_lens[row] = 1
                 ctx_lens[row] = L - 1 + j
-                toks[off] = tk
+                toks[off] = tk  # piped: the device's, from the carry
                 positions[off] = L - 1 + j
                 row_ids[off : off + aligned(1)] = row
                 tables[row, :ctx_pages] = self.page_tables[i][:ctx_pages]
@@ -4747,7 +4909,14 @@ class JaxEngine:
                 if not spec_lane:
                     pens[row] = (self.presence[i], self.frequency[i],
                                  self.repetition[i])
-                    # the device pen ring (decode carry) is not
+                if pipes:
+                    # token and penalty window are the carry's, gathered
+                    # by lane on the device (carry_read); the sample goes
+                    # back into the lane at position L (carry_write)
+                    payload["row_lane"][row] = payload["w_lane"][row] = i
+                    payload["w_pos"][row] = L
+                elif not spec_lane:
+                    # drained: the device pen ring (decode carry) is not
                     # host-visible; rebuild this lane's window from the
                     # authoritative token sequence (ring-indexed by
                     # absolute position, so the patch after the fetch
@@ -4776,11 +4945,6 @@ class JaxEngine:
                 else:
                     self.mixed_rows_plain += 1
 
-        self._bcast("mixed", payload)
-        first_dev = await self._run_on_device(
-            partial(self._dev_mixed, payload),
-            tag="mixed", shape=(N_pad, row),
-        )
         completions = []
         progressed = []
         for s, chunk, row_i in meta:
@@ -4788,19 +4952,46 @@ class JaxEngine:
             progressed.append((s, s.prefill_pos))
             if s.prefill_pos >= len(s.kv_prompt):
                 completions.append((s, row_i))
+                if pipes:
+                    # what the host knows of the new decode lane at
+                    # dispatch; the patch below puts it on the device, the
+                    # step's write-back adds the token, and the fetch does
+                    # what needs the value (_finish_prefill)
+                    s.first_pending = True
+                    self.seq_lens[s.slot_idx] = len(s.kv_prompt) + 1
+                    self._fill_recent(s.slot_idx, s)
+                    self._mark_lane_dirty(s.slot_idx)
+        after_drain = not self._carry_valid
+        if pipes:
+            await self._sync_carry(self._active_decode_indices())
+        # advanced at dispatch, before the device call suspends this task:
+        # exact for a plain decode row, and what the next entry packs from.
+        # spec lanes are NOT advanced here: acceptance is data-dependent
+        # (resolved from the fetched [R] tokens), and their packs drain
+        # this same step, so seq_lens stays authoritative for the next
+        # dispatch.
         for row_i, i, s in decode_rows:
             self.seq_lens[i] += 1
-        # spec lanes are NOT advanced here: acceptance is data-dependent
-        # (resolved from the fetched [R] tokens), and mixed dispatches
-        # drain this same step, so seq_lens stays authoritative for the
-        # next dispatch.
-        # rides the prefill-pending fetch (drained THIS step, so no decode
-        # block can dispatch against the stale device carry in between)
-        self._pending_prefill.append({
-            "first": first_dev, "done": completions,
+        self._bcast("mixed", payload)
+        first_dev = await self._run_on_device(
+            partial(self._dev_mixed, payload),
+            tag="mixed", shape=(N_pad, row),
+        )
+        entry = {
+            "kind": "mixed", "first": first_dev, "done": completions,
             "progressed": progressed, "decode": decode_rows,
             "spec": spec_rows,
-        })
+        }
+        if pipes:
+            # an entry of the pipeline: fetched in dispatch order, with
+            # its successor queued behind it
+            entry["after_drain"] = after_drain
+            self._inflight.append(entry)
+        else:
+            # rides the prefill-pending fetch (drained THIS step, so no
+            # decode block can dispatch against the stale device carry in
+            # between)
+            self._pending_prefill.append(entry)
         real = sum(ch for _, ch, _ in meta) + n_rows_decode
         self.mixed_steps += 1
         self.mixed_padded_tokens += N_pad
@@ -4808,6 +4999,40 @@ class JaxEngine:
         self._count_expert_rows(N_pad, real)
         self._step_counter += 1
         return True
+
+    def _pack_pipes(self, chosen, lanes) -> bool:
+        """Whether a mixed pack of these prefill slots and decode lanes
+        joins the decode pipeline, decided from what the pack holds and
+        nothing else: every row's token is either the host's (a prompt's)
+        or the device carry's (a plain decode lane's). A guided row (the
+        FSM's next mask), a spec verify row (the host's n-gram draft), a
+        LoRA row (the variant program), a preempted resume (the discarded
+        sample) and the disagg prefill role's completion (return_kv) need
+        host-authoritative lanes. (An invalid carry is a state of the
+        engine, not of the pack: _dispatch_mixed drains for it, and the
+        pack then pipes on the carry uploaded from the host.)"""
+        if self.config.spec_mode:
+            return False
+        return not any(
+            s.guided_fsm is not None or s.lora_idx or s.return_kv
+            or s.resume_token is not None
+            for s in [*chosen, *(self.slots[i] for i in lanes)]
+        )
+
+    def _tokens_in_flight(self) -> Dict[int, int]:
+        """Tokens dispatched and not yet fetched, by id() of their slot."""
+        out: Dict[int, int] = {}
+        for e in self._inflight:
+            if e["kind"] == "mixed":
+                slots = [s for _, _, s in e["decode"]]
+                slots += [s for s, _ in e["done"]]
+                n = 1
+            else:
+                slots = [s for _, s in e["lanes"]]
+                n = e["adv"]
+            for s in slots:
+                out[id(s)] = out.get(id(s), 0) + n
+        return out
 
     def _blank_mixed_pack(self, tokens: int, pages: int,
                           variant: bool) -> dict:
@@ -4891,53 +5116,26 @@ class JaxEngine:
         self.expert_rows_routed += routed * steps
         self.expert_rows_computed += computed * steps
 
-    async def _dispatch_decode(self) -> bool:
-        cfg = self.config
-        # prefill-priority depth cap: with dispatchable prefill work, keep
-        # only ONE speculative block in flight — a new arrival's prefill
-        # queues behind every in-flight block on the device stream, so
-        # depth-2 doubles its queueing delay (TTFT) to buy decode overlap
-        # it regains once the queue drains. Spec-decode blocks advance
-        # lanes by a DATA-DEPENDENT amount, so host bookkeeping must be
-        # corrected from each block's fetch before the next dispatches:
-        # depth stays 1 (the verify pass amortizes weight streams instead).
-        # guided lanes: the next step's mask depends on the token the
-        # PREVIOUS step emitted, so while any guided slot is decode-active
-        # the pipeline depth is 1 and every block must be fetched+processed
-        # (FSM advanced) before the next dispatch.
-        has_guided = any(
-            s is not None and s.guided_fsm is not None
-            and s.prefill_pos >= len(s.kv_prompt) and s.generated > 0
-            for s in self.slots
-        )
-        depth = 1 if (
-            cfg.spec_mode or has_guided or self._prefill_work_pending()
-        ) else 2
-        if len(self._inflight) >= depth:
-            return False
-        if not self._carry_valid and self._inflight:
-            return False  # drain in-flight blocks before a state reset
-        active = self._active_decode_indices()
-        if not active:
-            return False
-        active = self._grow_pages_for_block(active)
-        if not active:
-            return False
-        if not self._carry_valid and self._inflight:
-            # growth/preemption invalidated the carry mid-pipeline: drain the
-            # in-flight block first (its results update host state), THEN a
-            # fresh upload is consistent
-            return False
+    async def _sync_carry(self, active: List[int]):
+        """Bring the device carry up to date before an entry that reads it
+        (a decode block, a piped mixed step). `active`: the lanes that are
+        decode-active once that entry has run; every other lane goes
+        blank. The DEVICE decode table keeps SCRATCH rows for every lane
+        that is not decode-active: inside a fused block, inactive lanes'
+        seq_lens still advance (lax.scan carries the whole batch), so
+        their KV writes would otherwise land at positions 0..K-1 of
+        whatever the host table row points at — including a PREFILLING
+        slot's pages (possibly shared prefix-cache pages). A scratch row
+        routes all such writes to the reserved scratch page by
+        construction.
 
-        B = cfg.max_num_seqs
-        K = cfg.decode_block_steps
-        # the DEVICE decode table keeps SCRATCH rows for every lane that is
-        # not decode-active: inside a fused block, inactive lanes' seq_lens
-        # still advance (lax.scan carries the whole batch), so their KV
-        # writes would otherwise land at positions 0..K-1 of whatever the
-        # host table row points at — including a PREFILLING slot's pages
-        # (possibly shared prefix-cache pages). A scratch row routes all
-        # such writes to the reserved scratch page by construction.
+        An invalid carry (start-up, after a failed step) is uploaded whole
+        from the host's arrays, which the caller has made authoritative by
+        draining the pipeline. A valid one is patched per lane: just the
+        changed lanes — no pipeline drain, no full re-upload. Untouched
+        lanes keep their (newer) device carry; table_mask covers lanes
+        whose page table grew but whose carry must be preserved."""
+        B = self.config.max_num_seqs
         if not self._carry_valid:
             # TAKE the dirt before building the upload: the dispatch below
             # suspends, and a background KV-pull activation landing during
@@ -4984,56 +5182,105 @@ class JaxEngine:
                 tag="reset",
             )
             self._carry_valid = True
-        elif self._dirty_lanes or self._dirty_tables:
-            # per-lane patch: update just the changed lanes on device — no
-            # pipeline drain, no full re-upload. Untouched lanes keep their
-            # (newer) device carry; table_mask covers lanes whose page table
-            # grew but whose carry must be preserved.  TAKE the dirty sets
-            # atomically with the host-array snapshot (same reasoning as
-            # the reset branch: dirt added during the dispatch await must
-            # survive into the next step, not be cleared with this one).
-            dirty_lanes, dirty_tables = self._dirty_lanes, self._dirty_tables
-            self._dirty_lanes, self._dirty_tables = set(), set()
-            lane_mask = np.zeros((B,), bool)
-            for i in dirty_lanes:
-                lane_mask[i] = True
-            table_mask = lane_mask.copy()
-            for i in dirty_tables:
-                table_mask[i] = True
-            active_mask = np.zeros((B,), bool)
-            for i in active:
-                active_mask[i] = True
-            n_tokens = np.where(active_mask, self.tokens, 0).astype(np.int32)
-            n_positions = np.where(active_mask, self.seq_lens - 1, 0).astype(np.int32)
-            n_seq_lens = np.where(active_mask, self.seq_lens, 0).astype(np.int32)
-            n_tables = np.where(
-                active_mask[:, None], self.page_tables, SCRATCH_PAGE
-            ).astype(np.int32)
-            hist = self.hist.astype(np.int32) if self.hist is not None else None
-            pens = np.stack(
-                [self.presence, self.frequency, self.repetition], axis=1
-            )
-            payload = {
-                "lane_mask": lane_mask, "table_mask": table_mask,
-                "tokens": n_tokens, "positions": n_positions,
-                "seq_lens": n_seq_lens, "page_tables": n_tables,
-                "temps": self.temps, "top_ks": self.top_ks,
-                "top_ps": self.top_ps, "seeds": self.seeds,
-                "pens": pens, "recent": self.recent,
-            }
-            if hist is not None:
-                payload["hist"] = hist
-            self._bcast("patch", payload)
-            await self._run_on_device(
-                partial(
-                    self._dev_patch, lane_mask, table_mask,
-                    n_tokens, n_positions, n_seq_lens,
-                    n_tables, self.temps.copy(),
-                    self.top_ks.copy(), self.top_ps.copy(),
-                    self.seeds.copy(), pens, self.recent.copy(), hist,
-                ),
-                tag="patch",
-            )
+            return
+        if not (self._dirty_lanes or self._dirty_tables):
+            return
+        # TAKE the dirty sets atomically with the host-array snapshot (same
+        # reasoning as above: dirt added during the dispatch await must
+        # survive into the next patch, not be cleared with this one)
+        dirty_lanes, dirty_tables = self._dirty_lanes, self._dirty_tables
+        self._dirty_lanes, self._dirty_tables = set(), set()
+        lane_mask = np.zeros((B,), bool)
+        for i in dirty_lanes:
+            lane_mask[i] = True
+        table_mask = lane_mask.copy()
+        for i in dirty_tables:
+            table_mask[i] = True
+        active_mask = np.zeros((B,), bool)
+        for i in active:
+            active_mask[i] = True
+        n_tokens = np.where(active_mask, self.tokens, 0).astype(np.int32)
+        n_positions = np.where(active_mask, self.seq_lens - 1, 0).astype(np.int32)
+        n_seq_lens = np.where(active_mask, self.seq_lens, 0).astype(np.int32)
+        n_tables = np.where(
+            active_mask[:, None], self.page_tables, SCRATCH_PAGE
+        ).astype(np.int32)
+        hist = self.hist.astype(np.int32) if self.hist is not None else None
+        pens = np.stack(
+            [self.presence, self.frequency, self.repetition], axis=1
+        )
+        payload = {
+            "lane_mask": lane_mask, "table_mask": table_mask,
+            "tokens": n_tokens, "positions": n_positions,
+            "seq_lens": n_seq_lens, "page_tables": n_tables,
+            "temps": self.temps, "top_ks": self.top_ks,
+            "top_ps": self.top_ps, "seeds": self.seeds,
+            "pens": pens, "recent": self.recent,
+        }
+        if hist is not None:
+            payload["hist"] = hist
+        self._bcast("patch", payload)
+        await self._run_on_device(
+            partial(
+                self._dev_patch, lane_mask, table_mask,
+                n_tokens, n_positions, n_seq_lens,
+                n_tables, self.temps.copy(),
+                self.top_ks.copy(), self.top_ps.copy(),
+                self.seeds.copy(), pens, self.recent.copy(), hist,
+            ),
+            tag="patch",
+        )
+
+    async def _dispatch_decode(self, chained: bool = False) -> bool:
+        """Queue one K-step decode block from the device carry, behind
+        whatever entry (block or piped mixed step) is in flight. `chained`:
+        the step loop queues this block right behind a mixed step it has
+        just dispatched with nothing ahead of it (_step_once)."""
+        cfg = self.config
+        # prefill-priority depth cap: with prefill work that does NOT join
+        # the pipeline (a pack that needs host-authoritative lanes and is
+        # waiting for the drain, the split path, a planner refusal), keep
+        # only ONE speculative block in flight — that prefill queues
+        # behind every in-flight block on the device stream, so depth-2
+        # doubles its queueing delay (TTFT) to buy decode overlap it
+        # regains once the queue drains. A lean pack never gets here with
+        # work left: _dispatch_mixed queued it as the pipeline's next
+        # entry. Spec-decode blocks advance lanes by a DATA-DEPENDENT
+        # amount, so host bookkeeping must be corrected from each block's
+        # fetch before the next dispatches: depth stays 1 (the verify
+        # pass amortizes weight streams instead).
+        # guided lanes: the next step's mask depends on the token the
+        # PREVIOUS step emitted, so while any guided slot is decode-active
+        # the pipeline depth is 1 and every block must be fetched+processed
+        # (FSM advanced) before the next dispatch.
+        has_guided = any(
+            s is not None and s.guided_fsm is not None
+            and s.prefill_pos >= len(s.kv_prompt) and s.generated > 0
+            for s in self.slots
+        )
+        depth = 1 if (
+            cfg.spec_mode or has_guided
+            or (not chained and self._prefill_work_pending())
+        ) else 2
+        if len(self._inflight) >= depth:
+            return False
+        if not self._carry_valid and self._inflight:
+            return False  # drain in-flight blocks before a state reset
+        active = self._active_decode_indices()
+        if not active:
+            return False
+        active = self._grow_pages_for_block(active)
+        if not active:
+            return False
+        if not self._carry_valid and self._inflight:
+            # growth/preemption invalidated the carry mid-pipeline: drain the
+            # in-flight block first (its results update host state), THEN a
+            # fresh upload is consistent
+            return False
+
+        B = cfg.max_num_seqs
+        K = cfg.decode_block_steps
+        await self._sync_carry(active)
 
         guided_lanes = [
             i for i in active if self.slots[i].guided_fsm is not None
@@ -5094,7 +5341,7 @@ class JaxEngine:
             self._count_expert_rows(B, len(active), adv)
         entry = {
             "lanes": [(i, self.slots[i]) for i in active],
-            "toks": toks_dev, "kind": kind,
+            "toks": toks_dev, "kind": kind, "adv": adv,
         }
         if kind == "spec":
             # spec blocks advance lanes by a data-dependent amount: record
@@ -5111,147 +5358,168 @@ class JaxEngine:
         return True
 
     async def _fetch_and_process(self, fetch_block: bool) -> bool:
-        """One RTT: fetch pending prefill first-tokens + the oldest in-flight
-        decode block together, then run host bookkeeping/emission."""
-        want_block = self._inflight[0] if (fetch_block and self._inflight) else None
+        """One RTT: fetch pending prefill first-tokens + the oldest entry of
+        the pipeline (a decode block or a piped mixed step) together, then
+        run host bookkeeping/emission. Entries leave in the order they
+        were dispatched."""
+        want = self._inflight[0] if (fetch_block and self._inflight) else None
         prefills = self._pending_prefill
         self._pending_prefill = []
-        if want_block is None and not prefills:
+        if want is None and not prefills:
             return False
+        mixed = want is not None and want["kind"] == "mixed"
+        if mixed and len(self._inflight) >= 2 and not want["after_drain"]:
+            # dispatched without a drain before it, and its successor was
+            # queued before this fetch: no host round trip on either side
+            self.mixed_steps_piped += 1
         tree = (
             [p["first"] for p in prefills],
-            want_block["toks"] if want_block is not None else None,
+            None if want is None else want["first" if mixed else "toks"],
         )
         firsts_np, toks_np = await self._fetch(tree)
 
         for p, first in zip(prefills, firsts_np):
-            for slot, upto in p.get("progressed", []):
-                if slot.slot_idx < 0 or self.slots[slot.slot_idx] is not slot:
-                    continue
-                if slot.prefill_pos < len(slot.kv_prompt):
-                    # mid-prompt: commit the chunk's full pages now so
-                    # concurrent same-prefix requests can skip ahead
-                    self._commit_blocks(slot, upto_tokens=upto)
-            first_toks, first_lps, first_tids, first_tlps = first
-            for slot, lane in p["done"]:
-                if slot.slot_idx < 0 or self.slots[slot.slot_idx] is not slot:
-                    continue  # released meanwhile (cancel)
-                tok = int(first_toks[lane])
-                lp = float(first_lps[lane])
-                top = self._top_entry(slot, first_tids[lane], first_tlps[lane])
-                if slot.return_kv:
-                    await self._emit_prefill_result(slot, tok, lp, top)
-                else:
-                    self._finish_prefill(slot, tok, lp, top)
-            # mixed-step decode rows: each active lane advanced ONE token
-            # inside the fused dispatch — emit it and re-sync the (stale)
-            # device decode carry for this lane via the patch path
-            for row, i, slot_ref in p.get("decode", []):
-                slot = self.slots[i]
-                if slot is None or slot is not slot_ref:
-                    continue  # released/preempted meanwhile
-                if slot.done or slot.context.is_stopped():
-                    self._emit_finish(slot, "cancelled")
-                    self._release_slot(slot)
-                    continue
-                tok = int(first_toks[row])
-                slot.seq.append(tok)
-                slot.generated += 1
-                slot.last_token = tok
-                self.tokens[i] = tok
-                if slot.guided_fsm is not None:
-                    # fused guided decode: the mixed step is host-
-                    # authoritative per step, so the FSM advances here —
-                    # the next dispatch packs the updated mask
-                    slot.guided_state = slot.guided_fsm.advance(
-                        slot.guided_state, tok
-                    )
-                if self.hist is not None:
-                    # keep the spec n-gram ring coherent for lanes that
-                    # advanced outside the spec program (guided/plain
-                    # rows under spec_mode); patch re-uploads it via
-                    # _mark_lane_dirty below
-                    self.hist[
-                        i, (len(slot.seq.tokens) - 1) % self.config.spec_hist
-                    ] = tok
-                lp = float(first_lps[row])
-                top = self._top_entry(slot, first_tids[row], first_tlps[row])
-                self._emit_tokens(
-                    slot, [tok],
-                    [lp] if slot.want_logprobs else [],
-                    [top] if top else [],
-                )
-                finish = self._finish_reason(slot, tok)
-                if finish:
-                    self._emit_finish(slot, finish)
-                    self._release_slot(slot)
-                else:
-                    self._fill_recent(i, slot)
-                    self._mark_lane_dirty(i)
-                    self._maybe_commit_incremental(slot)
-            # fused spec verify rows: lane i packed rows first_row..
-            # first_row+d (current token + draft); row j's sample is the
-            # plain seeded draw at position L-1+j, so accepting the
-            # longest draft prefix matching the verified samples and
-            # emitting n_acc+1 tokens is byte-identical to plain decode
-            for first_row, i, slot_ref, draft in p.get("spec", []):
-                slot = self.slots[i]
-                if slot is None or slot is not slot_ref:
-                    continue
-                if slot.done or slot.context.is_stopped():
-                    self._emit_finish(slot, "cancelled")
-                    self._release_slot(slot)
-                    continue
-                d_n = len(draft)
-                out = [int(first_toks[first_row + j]) for j in range(1 + d_n)]
-                n_acc = 0
-                while n_acc < d_n and out[n_acc] == draft[n_acc]:
-                    n_acc += 1
-                self.spec_num_drafts += 1
-                self.spec_num_draft_tokens += d_n
-                self.spec_num_accepted_tokens += n_acc
-                L = int(self.seq_lens[i])
-                Hc = self.config.spec_hist
-                batch: List[int] = []
-                finish = None
-                for m, tok in enumerate(out[: n_acc + 1]):
-                    slot.seq.append(tok)
-                    slot.generated += 1
-                    slot.last_token = tok
-                    if self.hist is not None:
-                        self.hist[i, (L + m) % Hc] = tok
-                    batch.append(tok)
-                    finish = self._finish_reason(slot, tok)
-                    if finish:
-                        break
-                # seq_lens was NOT advanced at dispatch (acceptance is
-                # data-dependent); commit the true advance now — rejected
-                # rows' KV is garbage past seq_lens and gets overwritten
-                # before it is ever attended
-                self.seq_lens[i] = L + len(batch)
-                self.tokens[i] = batch[-1]
-                self._emit_tokens(slot, batch, [], [])
-                if finish:
-                    self._emit_finish(slot, finish)
-                    self._release_slot(slot)
-                else:
-                    self._fill_recent(i, slot)
-                    self._mark_lane_dirty(i)
-                    self._maybe_commit_incremental(slot)
-
-        if want_block is not None:
+            await self._process_prefill_result(p, first)
+        if want is not None:
             self._inflight.popleft()
+            if mixed:
+                await self._process_prefill_result(want, toks_np, piped=True)
             # route by the block's dispatch kind, not cfg.spec_mode:
             # guided/lora blocks under a spec engine ride the K-step
             # decode_block programs and must drain through _process_block
-            if want_block.get("kind") == "spec":
+            elif want["kind"] == "spec":
                 self._process_spec_block(
-                    want_block["lanes"], toks_np[0], toks_np[1],
-                    want_block["seq_before"],
+                    want["lanes"], toks_np[0], toks_np[1],
+                    want["seq_before"],
                 )
             else:
-                self._process_block(want_block["lanes"], *toks_np)
+                self._process_block(want["lanes"], *toks_np)
         return True
+
+    async def _process_prefill_result(self, p: dict, first,
+                                      piped: bool = False):
+        """Host bookkeeping and emission for one fetched prefill or mixed
+        dispatch: chunk commits, first tokens of completed prompts, and a
+        mixed step's decode and spec-verify rows. `piped`: a mixed step
+        that ran as an entry of the pipeline — lane state went to the
+        device at dispatch and the samples are in its carry, so only what
+        needs the VALUE happens here, and a lane whose slot ended or was
+        re-assigned meanwhile is dropped, as a block's is."""
+        for slot, upto in p.get("progressed", []):
+            if slot.slot_idx < 0 or self.slots[slot.slot_idx] is not slot:
+                continue
+            if slot.prefill_pos < len(slot.kv_prompt):
+                # mid-prompt: commit the chunk's full pages now so
+                # concurrent same-prefix requests can skip ahead
+                self._commit_blocks(slot, upto_tokens=upto)
+        first_toks, first_lps, first_tids, first_tlps = first
+        for slot, lane in p["done"]:
+            if slot.slot_idx < 0 or self.slots[slot.slot_idx] is not slot:
+                continue  # released meanwhile (cancel)
+            tok = int(first_toks[lane])
+            lp = float(first_lps[lane])
+            top = self._top_entry(slot, first_tids[lane], first_tlps[lane])
+            if slot.return_kv:
+                await self._emit_prefill_result(slot, tok, lp, top)
+            else:
+                self._finish_prefill(slot, tok, lp, top, piped)
+        # mixed-step decode rows: each active lane advanced ONE token
+        # inside the fused dispatch — emit it. A piped step has left it
+        # in the device carry already; after a drained one the (stale)
+        # carry is re-synced for this lane via the patch path
+        for row, i, slot_ref in p.get("decode", []):
+            slot = self.slots[i]
+            if slot is None or slot is not slot_ref:
+                continue  # released/preempted meanwhile
+            if slot.done or slot.context.is_stopped():
+                self._emit_finish(slot, "cancelled")
+                self._release_slot(slot)
+                continue
+            tok = int(first_toks[row])
+            slot.seq.append(tok)
+            slot.generated += 1
+            slot.last_token = tok
+            self.tokens[i] = tok
+            if slot.guided_fsm is not None:
+                # fused guided decode: the mixed step is host-
+                # authoritative per step, so the FSM advances here —
+                # the next dispatch packs the updated mask
+                slot.guided_state = slot.guided_fsm.advance(
+                    slot.guided_state, tok
+                )
+            if self.hist is not None:
+                # keep the spec n-gram ring coherent for lanes that
+                # advanced outside the spec program (guided/plain
+                # rows under spec_mode); patch re-uploads it via
+                # _mark_lane_dirty below
+                self.hist[
+                    i, (len(slot.seq.tokens) - 1) % self.config.spec_hist
+                ] = tok
+            lp = float(first_lps[row])
+            top = self._top_entry(slot, first_tids[row], first_tlps[row])
+            self._emit_tokens(
+                slot, [tok],
+                [lp] if slot.want_logprobs else [],
+                [top] if top else [],
+            )
+            finish = self._finish_reason(slot, tok)
+            if finish:
+                self._emit_finish(slot, finish)
+                self._release_slot(slot)
+            else:
+                if not piped:
+                    self._fill_recent(i, slot)
+                    self._mark_lane_dirty(i)
+                self._maybe_commit_incremental(slot)
+        # fused spec verify rows: lane i packed rows first_row..
+        # first_row+d (current token + draft); row j's sample is the
+        # plain seeded draw at position L-1+j, so accepting the
+        # longest draft prefix matching the verified samples and
+        # emitting n_acc+1 tokens is byte-identical to plain decode
+        for first_row, i, slot_ref, draft in p.get("spec", []):
+            slot = self.slots[i]
+            if slot is None or slot is not slot_ref:
+                continue
+            if slot.done or slot.context.is_stopped():
+                self._emit_finish(slot, "cancelled")
+                self._release_slot(slot)
+                continue
+            d_n = len(draft)
+            out = [int(first_toks[first_row + j]) for j in range(1 + d_n)]
+            n_acc = 0
+            while n_acc < d_n and out[n_acc] == draft[n_acc]:
+                n_acc += 1
+            self.spec_num_drafts += 1
+            self.spec_num_draft_tokens += d_n
+            self.spec_num_accepted_tokens += n_acc
+            L = int(self.seq_lens[i])
+            Hc = self.config.spec_hist
+            batch: List[int] = []
+            finish = None
+            for m, tok in enumerate(out[: n_acc + 1]):
+                slot.seq.append(tok)
+                slot.generated += 1
+                slot.last_token = tok
+                if self.hist is not None:
+                    self.hist[i, (L + m) % Hc] = tok
+                batch.append(tok)
+                finish = self._finish_reason(slot, tok)
+                if finish:
+                    break
+            # seq_lens was NOT advanced at dispatch (acceptance is
+            # data-dependent); commit the true advance now — rejected
+            # rows' KV is garbage past seq_lens and gets overwritten
+            # before it is ever attended
+            self.seq_lens[i] = L + len(batch)
+            self.tokens[i] = batch[-1]
+            self._emit_tokens(slot, batch, [], [])
+            if finish:
+                self._emit_finish(slot, finish)
+                self._release_slot(slot)
+            else:
+                self._fill_recent(i, slot)
+                self._mark_lane_dirty(i)
+                self._maybe_commit_incremental(slot)
 
     def _process_spec_block(self, lanes: List[tuple], toks: np.ndarray,
                             n_emit: np.ndarray, seq_before: dict):
@@ -5528,6 +5796,7 @@ class JaxEngine:
             # by a later-dispatched (device-ordered) prefill/inject
             self.allocator.release(slot.pages, slot.committed_hashes)
             idx = slot.slot_idx
+            slot.first_pending = False
             self.slots[idx] = None
             self._free_slots.append(idx)
             self.page_tables[idx, :] = SCRATCH_PAGE

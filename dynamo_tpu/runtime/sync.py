@@ -81,11 +81,15 @@ GUARDED_STATE = {
     "KvbmConnector.onboard_src_local_blocks": "lock:_offload_cv",
     "KvbmConnector.onboard_src_peer_blocks": "lock:_offload_cv",
     "KvbmConnector.onboard_src_recompute_blocks": "lock:_offload_cv",
-    # engine decode pipeline: the step-loop task owns the in-flight block
-    # queue and prefill-completion list; ROADMAP item 1's scheduler must
-    # keep mutations inside the step loop (or take over this entry).
+    # engine decode pipeline: the step-loop task owns the ONE queue of
+    # in-flight entries (decode blocks and piped mixed steps, in dispatch
+    # order), the list of dispatches the same step fetches itself (split
+    # prefill, drained mixed steps) and that drain's hold flag; ROADMAP
+    # item 1's scheduler must keep mutations inside the step loop (or
+    # take over this entry).
     "JaxEngine._inflight": "single-task:_step_loop",
     "JaxEngine._pending_prefill": "single-task:_step_loop",
+    "JaxEngine._mixed_wait_drain": "single-task:_step_loop",
     "JaxEngine._carry_valid": "single-task:_step_loop",
     # per-dispatch-type device occupancy: mutated only inside the `timed`
     # wrapper, which runs on the jax-step device-executor thread; readers

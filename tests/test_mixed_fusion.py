@@ -63,26 +63,34 @@ def _engine(params, adapters=None, mixed=True, spec=False, **over):
     return eng
 
 
+async def _stream(eng, prompt, rid, n=12, ctx=None, stop=None, sampling=None,
+                  eos=(), **req_kw):
+    req = PreprocessedRequest(
+        token_ids=list(prompt),
+        stop_conditions={"max_tokens": n, "ignore_eos": not (eos or stop),
+                         **(stop or {})},
+        sampling_options=dict(sampling or {"temperature": 0.0}),
+        eos_token_ids=list(eos), request_id=rid, **req_kw,
+    ).to_dict()
+    toks, finish = [], None
+    async for item in eng.generate(req, ctx or Context()):
+        data = item.get("data")
+        if data:
+            toks.extend(data["token_ids"])
+            finish = data.get("finish_reason") or finish
+    return toks, finish
+
+
 async def _one(eng, prompt, rid, lora_name=None, guided=None, n=12,
                temperature=0.0, seed=None):
     sampling = {"temperature": temperature}
     if seed is not None:
         sampling["seed"] = seed
-    req = PreprocessedRequest(
-        token_ids=list(prompt),
-        stop_conditions={"max_tokens": n,
-                         **({} if guided else {"ignore_eos": True})},
-        sampling_options=sampling,
-        eos_token_ids=[2] if guided else [],  # ByteTokenizer.EOS
-        lora_name=lora_name,
-        guided=guided,
-        request_id=rid,
-    ).to_dict()
-    toks = []
-    async for item in eng.generate(req, Context()):
-        data = item.get("data")
-        if data:
-            toks.extend(data["token_ids"])
+    toks, _ = await _stream(
+        eng, prompt, rid, n=n, sampling=sampling,
+        eos=[2] if guided else (),  # ByteTokenizer.EOS
+        lora_name=lora_name, guided=guided,
+    )
     return toks
 
 
@@ -519,3 +527,502 @@ def test_xla_reference_keeps_its_rungs_and_primes_a_rung_at_a_time(params):
     assert st["mixed_steps"] >= 1
     assert st["compile_surfaces"]["mixed_step"] % n_buckets == 0
     assert n_buckets <= st["mixed_family_compiled"] < st["mixed_family_size"]
+
+
+# --------------------------------------------------------------------- #
+# the mixed step as an entry of the two-deep decode pipeline (ISSUE 37)
+# --------------------------------------------------------------------- #
+
+PIPE_KW = dict(max_num_seqs=4, num_pages=128, max_model_len=256,
+               decode_block_steps=4)
+SAMPLINGS = {
+    "greedy": {"temperature": 0.0},
+    "seeded": {"temperature": 0.7, "seed": 11},
+    "penalties": {"temperature": 0.7, "seed": 11, "repetition_penalty": 1.3,
+                  "presence_penalty": 0.5, "frequency_penalty": 0.2},
+}
+
+
+def _family_engine(family, params, mixed=True, **over):
+    kw = {**PIPE_KW, **over}
+    if family == "dense":
+        return _engine(params, mixed=mixed, **kw)
+    from dynamo_tpu.models import moe
+
+    mcfg = moe.MoeConfig.tiny_moe(dtype=jnp.float32, capacity_factor=2.0)
+    return JaxEngine(
+        EngineConfig(model="tiny-moe", page_size=PAGE, prefill_buckets=(16, 32),
+                     max_prefill_chunk=32, mixed_dispatch=mixed, **kw),
+        model_config=mcfg, params=moe.init_params(mcfg, jax.random.PRNGKey(3)),
+    )
+
+
+def _drained(eng):
+    """The same engine with every pack on the old road: the test's way to
+    the drain for a lean pack, since no option of the program leads there."""
+    eng._pack_pipes = lambda chosen, lanes: False
+    return eng
+
+
+class _Stepped:
+    """An engine whose step loop the test drives: every `_step_once` is the
+    test's own call, so what is in flight between two steps is known, and
+    an arrival lands exactly where the case wants it."""
+
+    def __init__(self, eng):
+        self.eng = eng
+        self.fetches = []  # kinds in flight at every fetch of an entry
+        self.dispatched = []  # (kind, kinds in flight before it), in order
+        fetch, mixed = eng._fetch_and_process, eng._dev_mixed
+
+        async def fetch_logged(fetch_block):
+            kinds = [e["kind"] for e in eng._inflight]
+            assert len(kinds) <= 2, kinds
+            if fetch_block and kinds:
+                self.fetches.append(kinds)
+            return await fetch(fetch_block)
+
+        def mixed_logged(p):
+            if "prime" not in p:
+                self.dispatched.append((
+                    "piped" if "row_lane" in p else "drained",
+                    [e["kind"] for e in eng._inflight],
+                ))
+            return mixed(p)
+
+        eng._fetch_and_process = fetch_logged
+        eng._dev_mixed = mixed_logged
+
+    async def __aenter__(self):
+        # generate() starts the step loop unless a task is there already
+        self.eng._step_task = asyncio.create_task(asyncio.sleep(3600))
+        return self
+
+    async def __aexit__(self, *exc):
+        await self.eng.close()
+
+    async def submit(self, prompt, rid, **kw):
+        task = asyncio.create_task(_stream(self.eng, prompt, rid, **kw))
+        for _ in range(50):
+            if any(s.request_id == rid for s in self.eng._waiting):
+                return task
+            await asyncio.sleep(0)
+        raise AssertionError(f"{rid} never reached the waiting list")
+
+    async def step(self, n=1):
+        for _ in range(n):
+            await self.eng._step_once()
+            await asyncio.sleep(0)
+
+    async def until(self, cond, limit=400):
+        for _ in range(limit):
+            if cond():
+                return
+            await self.step()
+        raise AssertionError("the engine never got there")
+
+    async def finish(self, *tasks):
+        await self.until(lambda: all(t.done() for t in tasks))
+        return [t.result() for t in tasks]
+
+    def slot(self, rid):
+        return next((s for s in self.eng.slots
+                     if s is not None and s.request_id == rid), None)
+
+    def generated(self, rid):
+        slot = self.slot(rid)
+        return slot.generated if slot is not None else 0
+
+
+def _prompts(n, seed=3, lo=9, hi=30):
+    """Prompts of one chunk each (max_prefill_chunk is 32) unless `hi` says
+    otherwise: an arrival's prompt then completes in its first mixed step."""
+    rng = np.random.RandomState(seed)
+    return [rng.randint(5, 200, size=rng.randint(lo, hi)).tolist()
+            for _ in range(n)]
+
+
+async def _staggered_plain(eng, sampling, ns=(20, 27, 34, 41)):
+    """Four plain requests, each arriving once the one before it decodes:
+    every arrival's prompt shares mixed steps with live decode lanes."""
+    tasks = []
+    for k, (p, n) in enumerate(zip(_prompts(len(ns), hi=60), ns)):
+        samp = dict(sampling)
+        if "seed" in samp:
+            samp["seed"] += k
+        tasks.append(asyncio.create_task(
+            _stream(eng, p, f"r{k}", n=n, sampling=samp)))
+        for _ in range(200):
+            await asyncio.sleep(0.005)
+            if any(s is not None and s.request_id == f"r{k}" and s.generated
+                   for s in eng.slots) or tasks[-1].done():
+                break
+    return [t for t, _ in await asyncio.gather(*tasks)]
+
+
+@pytest.mark.parametrize("sampling", list(SAMPLINGS))
+@pytest.mark.parametrize("family", ["dense", "moe"])
+def test_piped_drained_and_split_streams_are_byte_identical(
+    family, sampling, params
+):
+    """A lean pack as an entry of the pipeline (decode rows read the device
+    carry, samples written back into it) against the same packs drained
+    and against the split pair: the same streams, greedy and seeded, with
+    and without penalties (whose window a piped row takes from the device
+    ring, a drained one from `_fill_recent`)."""
+    out, stats = {}, {}
+    for road in ("piped", "drained", "split"):
+        eng = _family_engine(family, params, mixed=road != "split")
+        if road == "drained":
+            _drained(eng)
+        out[road] = asyncio.run(_staggered_plain(eng, SAMPLINGS[sampling]))
+        stats[road] = eng.stats()
+        asyncio.run(eng.close())
+    assert out["piped"] == out["split"]
+    assert out["drained"] == out["split"]
+    assert [len(t) for t in out["split"]] == [20, 27, 34, 41]
+    assert stats["piped"]["mixed_steps"] > 0
+    assert stats["piped"]["mixed_steps_piped"] > 0
+    assert stats["drained"]["mixed_steps"] > 0
+    assert stats["drained"]["mixed_steps_piped"] == 0
+    assert stats["split"]["mixed_steps"] == 0
+    if sampling == "penalties":
+        plain = _family_engine(family, params)
+        unpenalized = asyncio.run(_staggered_plain(plain, SAMPLINGS["seeded"]))
+        asyncio.run(plain.close())
+        assert unpenalized != out["piped"]  # the penalties do act
+
+
+def test_the_device_ring_after_a_piped_step_is_fill_recents(params):
+    """After every piped mixed step the carry's penalty ring, token,
+    position and length of each lane the step wrote are what the host
+    would have built from the finished streams: `_fill_recent`'s window
+    (ring-indexed by absolute position) up to the sampled token."""
+    eng = _family_engine("dense", params, penalty_window=16)
+    W = eng.config.penalty_window
+    seen = []  # (request id, position, ring row, token, length) per write
+    dev_mixed = eng._dev_mixed
+
+    def spy(p):
+        first = dev_mixed(p)
+        if "row_lane" in p:
+            tok, pos, sl = (np.asarray(x) for x in eng._carry)
+            pen = np.asarray(eng._pen_dev)
+            for lane, at in zip(p["w_lane"], p["w_pos"]):
+                if lane >= 0:
+                    seen.append((eng.slots[lane].request_id, int(at),
+                                 pen[lane].copy(), int(tok[lane]),
+                                 int(pos[lane]), int(sl[lane])))
+        return first
+
+    eng._dev_mixed = spy
+    prompts = _prompts(4, hi=60)
+    outs = asyncio.run(_staggered_plain(eng, SAMPLINGS["penalties"]))
+    asyncio.run(eng.close())
+    assert len(seen) > 4  # completions and decode rows both
+    for rid, at, ring, tok, pos, sl in seen:
+        k = int(rid[1:])
+        full = np.asarray(prompts[k] + outs[k], np.int32)
+        if at >= len(full):
+            continue  # a row sampled past the request's end: dropped
+        want = np.full((W,), -1, np.int32)
+        ps = np.arange(max(0, at + 1 - W), at + 1)
+        want[ps % W] = full[ps]
+        np.testing.assert_array_equal(ring, want)
+        assert (tok, pos, sl) == (full[at], at, at + 1)
+
+
+@pytest.mark.parametrize("blocks_in_flight", [1, 2])
+def test_an_arrival_joins_the_pipeline_behind_what_is_in_flight(
+    blocks_in_flight, params
+):
+    """An arrival while one block is in flight, and while two are: its
+    mixed step is dispatched behind them with no drain, the next entry is
+    queued behind it before it is fetched, and the streams are the split
+    path's."""
+    prompts = _prompts(2, seed=8)
+
+    async def run(eng, piped):
+        async with _Stepped(eng) as st:
+            a = await st.submit(prompts[0], "a", n=40)
+            await st.until(lambda: len(eng._inflight) == 1
+                           and eng._inflight[0]["kind"] == "block")
+            if blocks_in_flight == 2 and piped:
+                # the arrival lands while the step loop waits for a fetch
+                # with two blocks in flight, and is admitted at that wake
+                fetch = eng._fetch
+                armed = [True]
+
+                async def fetch_with_arrival(tree):
+                    if armed[0] and len(eng._inflight) == 2:
+                        armed[0] = False
+                        st.b = await st.submit(prompts[1], "b", n=9)
+                    return await fetch(tree)
+
+                eng._fetch = fetch_with_arrival
+                await st.until(lambda: not armed[0])
+                b = st.b
+            else:
+                b = await st.submit(prompts[1], "b", n=9)
+            await st.until(lambda: bool(st.dispatched) or not piped, 40)
+            (ta, _), (tb, _) = await st.finish(a, b)
+            return ta, tb, list(st.dispatched), list(st.fetches), eng.stats()
+
+    ta, tb, dispatched, fetches, stats = asyncio.run(
+        run(_family_engine("dense", params), True))
+    ref = _family_engine("dense", params, mixed=False)
+    ra, rb, *_ = asyncio.run(run(ref, False))
+    assert (ta, tb) == (ra, rb) and len(tb) == 9
+    # one block ran ahead of the mixed step, and it was not drained for it
+    assert dispatched == [("piped", ["block"])]
+    # fetched with its successor queued behind it
+    assert ["mixed", "block"] in fetches
+    assert stats["mixed_steps"] == stats["mixed_steps_piped"] == 1
+
+
+def test_a_second_arrival_gets_its_own_mixed_step_next(params):
+    """Two arrivals a few steps apart: the second, admitted while the
+    first's mixed step is still in flight, gets the next entry to itself
+    as a mixed step, not a place behind a block chained eagerly; the
+    queue never holds more than two entries (asserted at every fetch)."""
+    prompts = _prompts(3, seed=21)
+
+    async def run(eng, piped):
+        async with _Stepped(eng) as st:
+            a = await st.submit(prompts[0], "a", n=48)
+            await st.until(lambda: len(eng._inflight) == 1
+                           and eng._inflight[0]["kind"] == "block")
+            b = await st.submit(prompts[1], "b", n=20)
+            if piped:
+                await st.until(lambda: len(st.dispatched) == 1, 20)
+                # b's mixed step is queued or running; nothing behind it
+                assert [e["kind"] for e in eng._inflight] == ["mixed"]
+            else:
+                await st.step(2)
+            c = await st.submit(prompts[2], "c", n=16)
+            outs = await st.finish(a, b, c)
+            return [t for t, _ in outs], list(st.dispatched), eng.stats()
+
+    outs, dispatched, stats = asyncio.run(
+        run(_family_engine("dense", params), True))
+    ref, _, _ = asyncio.run(
+        run(_family_engine("dense", params, mixed=False), False))
+    assert outs == ref
+    # c's pack went out right behind b's mixed step
+    assert dispatched[:2] == [("piped", ["block"]), ("piped", ["mixed"])]
+    assert stats["mixed_steps_piped"] == stats["mixed_steps"] == len(dispatched)
+
+
+@pytest.mark.parametrize("how", ["eos", "stop", "max_tokens_1", "decode_lane_stop"])
+def test_a_lane_that_ends_on_the_mixed_steps_token(how, params):
+    """A request whose last token is the mixed step's own (its first
+    token, by EOS, by a stop token or by max_tokens 1; or a decoding
+    lane's, by a stop token): nothing past the end is emitted, the entry
+    queued behind drops its rows, and the pages it gives back serve the
+    next request as they do on the split path."""
+    prompts = _prompts(3, seed=34)
+
+    async def run(eng, b_kw, a_kw, piped):
+        async with _Stepped(eng) as st:
+            a = await st.submit(prompts[0], "a", n=40, **a_kw)
+            await st.until(lambda: len(eng._inflight) == 1
+                           and eng._inflight[0]["kind"] == "block")
+            a_before = st.slot("a").generated
+            b = await st.submit(prompts[1], "b", **b_kw)
+            if piped:
+                await st.until(lambda: bool(st.dispatched), 20)
+                await st.until(lambda: ["mixed", "block"] in st.fetches
+                               or ["mixed", "mixed"] in st.fetches, 20)
+            else:
+                await st.step(3)
+            c = await st.submit(prompts[2], "c", n=12)
+            outs = await st.finish(a, b, c)
+            assert all(s is None for s in eng.slots)
+            return outs, a_before, list(st.dispatched), eng.stats()
+
+    ref_eng = _family_engine("dense", params, mixed=False)
+    (ra, rb, rc), _, _, _ = asyncio.run(
+        run(ref_eng, {"n": 12}, {}, False))
+    assert len(ra[0]) == 40 and len(rb[0]) == 12
+    a_kw, want_a = {}, ra[0]
+    if how == "eos":
+        b_kw, want_b = {"n": 12, "eos": [rb[0][0]]}, rb[0][:1]
+    elif how == "stop":
+        b_kw = {"n": 12, "stop": {"stop_token_ids": [rb[0][0]]}}
+        want_b = rb[0][:1]
+    elif how == "max_tokens_1":
+        b_kw, want_b = {"n": 1}, rb[0][:1]
+    else:
+        b_kw, want_b = {"n": 12}, rb[0]
+    if how == "decode_lane_stop":
+        # the token lane a samples IN the mixed step: what it had when b
+        # arrived (one dry piped run tells; the stepping is
+        # deterministic) and the block of 4 that was in flight
+        _, n_before, _, _ = asyncio.run(run(
+            _family_engine("dense", params), b_kw, {}, True))
+        idx = n_before + 4
+        assert ra[0][idx] not in ra[0][:idx], "pick another seed"
+        a_kw = {"stop": {"stop_token_ids": [ra[0][idx]]}}
+        want_a = ra[0][: idx + 1]
+    (ta, tb, tc), _, dispatched, stats = asyncio.run(run(
+        _family_engine("dense", params), b_kw, a_kw, True))
+    assert ta[0] == want_a and tb[0] == want_b
+    assert tb[1] == ("length" if how in ("max_tokens_1", "decode_lane_stop")
+                     else "eos")
+    assert ta[1] == ("eos" if how == "decode_lane_stop" else "length")
+    assert dispatched[0] == ("piped", ["block"])
+    assert stats["mixed_steps_piped"] >= 1
+    # c took its pages from what the ended lanes gave back
+    c_ref = rc[0] if how != "decode_lane_stop" else None
+    if c_ref is not None:
+        assert tc[0] == c_ref
+    assert len(tc[0]) == 12
+
+
+def test_a_cancel_while_its_mixed_entry_is_queued(params):
+    """The arrival's caller goes away after its mixed step was dispatched
+    and before it was fetched: the request ends cancelled with no token,
+    the block queued behind drops its lane, and the lane beside it reads
+    what it reads on the split path."""
+    prompts = _prompts(2, seed=55)
+
+    async def run(eng, piped):
+        async with _Stepped(eng) as st:
+            a = await st.submit(prompts[0], "a", n=40)
+            await st.until(lambda: len(eng._inflight) == 1
+                           and eng._inflight[0]["kind"] == "block")
+            ctx = Context()
+            b = await st.submit(prompts[1], "b", n=20, ctx=ctx)
+            if piped:
+                await st.until(lambda: bool(st.dispatched), 20)
+                assert eng._inflight[-1]["kind"] == "mixed"
+                assert st.slot("b").first_pending
+            ctx.stop_generating()
+            (ta, _), (tb, fb) = await st.finish(a, b)
+            assert all(s is None for s in eng.slots)
+            return ta, tb, fb
+
+    ta, tb, fb = asyncio.run(run(_family_engine("dense", params), True))
+    ra, _, _ = asyncio.run(
+        run(_family_engine("dense", params, mixed=False), False))
+    assert ta == ra and len(ta) == 40
+    assert tb == [] and fb == "cancelled"
+
+
+@pytest.mark.parametrize("what", ["preemption", "invalid_carry"])
+def test_the_pipeline_drains_for_a_host_lane_and_pipes_again(what, params):
+    """Between two entries a lane is preempted (its resume packs a
+    `resume_token`, whose sample is discarded for the host's token), or
+    the carry is invalidated (what a failed step leaves): the next pack
+    waits for the drain and packs host-authoritative lanes, the one after
+    it pipes again, and every stream is the split path's."""
+    prompts = _prompts(4, seed=89)
+
+    async def run(eng, piped):
+        async with _Stepped(eng) as st:
+            a = await st.submit(prompts[0], "a", n=60)
+            await st.until(lambda: len(eng._inflight) == 1
+                           and eng._inflight[0]["kind"] == "block")
+            b = await st.submit(prompts[1], "b", n=40)
+            await st.until(lambda: st.generated("b") > 0)
+            await st.until(lambda: len(eng._inflight) == 1)
+            if what == "preemption":
+                # the newest decoding lane (b) loses its pages with an
+                # entry in flight and comes back through the waiting list
+                assert eng._preempt_one(exclude_idx=-1)
+                assert eng._waiting[0].resume_token is not None
+            elif piped:
+                eng._carry_valid = False
+            c = await st.submit(prompts[2], "c", n=16)
+            await st.until(lambda: st.generated("c") > 0 or c.done())
+            d = await st.submit(prompts[3], "d", n=12)
+            outs = await st.finish(a, b, c, d)
+            return [t for t, _ in outs], list(st.dispatched), eng.stats()
+
+    outs, dispatched, stats = asyncio.run(
+        run(_family_engine("dense", params), True))
+    ref, _, _ = asyncio.run(
+        run(_family_engine("dense", params, mixed=False), False))
+    assert outs == ref
+    roads = [road for road, _ in dispatched]
+    assert roads[0] == "piped" and roads[-1] == "piped"
+    if what == "preemption":
+        assert "drained" in roads  # the resume's pack
+        assert all(ahead == [] for road, ahead in dispatched
+                   if road == "drained")
+        assert stats["mixed_steps_piped"] <= roads.count("piped")
+    else:
+        # an invalid carry is the engine's state, not the pack's: the pack
+        # waits for the drain, the carry is uploaded whole, and the pack
+        # reads it; it is not counted as piped, the next one is
+        assert stats["mixed_steps_piped"] == len(roads) - 1
+        assert dispatched[1][1] == []
+    assert stats["mixed_steps"] == len(roads)
+
+
+@pytest.mark.parametrize("kind", ["guided", "lora", "spec", "return_kv"])
+def test_packs_that_need_a_host_value_take_the_drain(kind, params, adapters):
+    """A pack with a guided row, a LoRA row, a spec verify row or the
+    disagg prefill role's completion drains as before this PR: dispatched
+    with nothing in flight, fetched in its own step, never counted as
+    piped. (The preempted resume is in the case above.)"""
+    prompts = _prompts(2, seed=144)
+    eng = _family_engine("dense", params, spec=kind == "spec")
+    if kind in ("lora", "guided"):
+        eng.register_adapters(adapters)
+    a_kw = {"guided": {"kind": "choice", "choices": ["yes yes yes", "no"]}} \
+        if kind == "guided" else {}
+    if kind == "lora":
+        a_kw = {"lora_name": "ad1"}
+    b_kw = {"disagg_params": {"return_kv": True}} if kind == "return_kv" else {}
+
+    async def run():
+        async with _Stepped(eng) as st:
+            a = await st.submit(
+                prompts[0], "a", n=24,
+                **({"eos": [2], **a_kw} if kind == "guided" else a_kw))
+            await st.until(lambda: any(
+                s is not None and s.generated > 0 for s in eng.slots))
+            b = await st.submit(prompts[1], "b", n=6, **b_kw)
+            await st.finish(a, b)
+            return list(st.dispatched), eng.stats()
+
+    dispatched, stats = asyncio.run(run())
+    assert dispatched and all(d == ("drained", []) for d in dispatched)
+    assert stats["mixed_steps"] == len(dispatched)
+    assert stats["mixed_steps_piped"] == 0
+
+
+@pytest.mark.parametrize("family", ["dense", "moe"])
+def test_plain_traffic_pipes_every_mixed_step_and_compiles_nothing_new(
+    family, params
+):
+    """On plain traffic with the pipeline running every mixed step is an
+    entry of it (`mixed_steps_piped` equals `mixed_steps`), and after the
+    family is primed no program is compiled: the write-back is compiled
+    with the family, and the patch before the first block."""
+    eng = _one_width(_family_engine(family, params))
+    prompts = _prompts(6, seed=233)
+
+    async def run():
+        async with _Stepped(eng) as st:
+            a = await st.submit(prompts[0], "a", n=80)
+            await st.until(lambda: len(eng._inflight) == 1
+                           and eng._inflight[0]["kind"] == "block")
+            first = await st.submit(prompts[1], "b", n=10)
+            await st.until(lambda: bool(st.dispatched), 20)
+            primed = eng._surface_cache_sizes()
+            assert primed["carry_write"] == 1
+            assert primed["mixed_step"] == len(eng._mixed_token_buckets)
+            tasks = [a, first]
+            for k, p in enumerate(prompts[2:]):
+                await st.step(3 + k)
+                tasks.append(await st.submit(p, f"r{k}", n=8 + 3 * k))
+            outs = await st.finish(*tasks)
+            assert [len(t) for t, _ in outs] == [80, 10, 8, 11, 14, 17]
+            return primed, eng._surface_cache_sizes(), eng.stats()
+
+    primed, after, stats = asyncio.run(run())
+    assert after == primed
+    assert stats["mixed_steps"] >= 5
+    assert stats["mixed_steps_piped"] == stats["mixed_steps"]

@@ -402,3 +402,34 @@ def test_the_cells_mixed_step_family_compiles_with_the_grouped_matmul(
     assert mem.temp_size_in_bytes < expert_matrix, (
         f"{mem.temp_size_in_bytes / 2**20:.0f} MiB of temporaries"
     )
+
+
+def test_the_piped_mixed_steps_carry_programs_compile_at_the_cell_size(
+    one_chip, no_persistent_cache
+):
+    """What ISSUE 37 adds to the lean mixed step, at the Mixtral cell's
+    sizes (32 lanes, 64 rows, the 2,048-token bucket, the default penalty
+    window): the read of the decode carry that opens `mixed_step` (each
+    decode row's token and window gathered by lane) and the write-back
+    program behind it. Both are scatters and gathers over a few KiB: no
+    temporary beyond their own operands."""
+    from dynamo_tpu.engine.config import EngineConfig
+    from dynamo_tpu.engine.engine import carry_read, carry_write
+
+    sds = _shapes(one_chip)
+    i32 = jnp.int32
+    lanes, rows, tokens = 32, 64, 2048
+    window = EngineConfig(model="tiny").penalty_window
+    read = jax.jit(carry_read).lower(
+        sds((tokens,), i32), sds((rows, window), i32), sds((rows,), i32),
+        sds((rows,), i32), sds((lanes,), i32), sds((lanes, window), i32),
+    ).compile()
+    write = jax.jit(carry_write).lower(
+        sds((lanes,), i32), sds((lanes,), i32), sds((lanes,), i32),
+        sds((lanes, window), i32), sds((rows,), i32), sds((rows,), i32),
+        sds((rows,), i32),
+    ).compile()
+    for compiled in (read, write):
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes <= 4 * mem.argument_size_in_bytes
+        assert mem.argument_size_in_bytes < 2**20
