@@ -239,13 +239,47 @@ class StatsWatch:
 # ---------------------------------------------------------------------- #
 
 
+ROUTED_EXPERTS = "routed_experts"  # the annotation asked, and the reply's key
+
+
+def routed_rows(rows: list, inputs: int, geometry: tuple) -> list:
+    """A reply's `routed_experts` held to the wire contract (README.md): one
+    row for each input position of prompt + served[:-1], in order (a row for
+    the last served token, which nothing reads, is dropped), each row [routed
+    layers][experts a token] of distinct expert ids in range. Raises
+    ValueError with the reason; what passes goes to the reference as it is."""
+    experts, per_token, layers = geometry
+    if len(rows) == inputs + 1:
+        rows = rows[:-1]
+    if len(rows) != inputs:
+        raise ValueError(f"{len(rows)} rows of `{ROUTED_EXPERTS}` for {inputs} input "
+                         f"positions (prompt + served[:-1])")
+    for at, row in enumerate(rows):
+        if not (isinstance(row, list) and len(row) == layers):
+            raise ValueError(f"row {at} of `{ROUTED_EXPERTS}` holds "
+                             f"{len(row) if isinstance(row, list) else row!r} layers, the "
+                             f"configuration has {layers} routed ones")
+        for layer, ids in enumerate(row):
+            if not (isinstance(ids, list) and len(ids) == per_token == len(set(ids)) and all(
+                    isinstance(e, int) and not isinstance(e, bool) and 0 <= e < experts
+                    for e in ids)):
+                raise ValueError(f"row {at}, layer {layer} of `{ROUTED_EXPERTS}` is {ids!r}: "
+                                 f"not {per_token} distinct expert ids under {experts}")
+    return rows
+
+
 async def resend_greedy(discovery_addr: str, model: str, vocab_size: int,
                         context_length: int, picks: List[dict],
-                        fail: Callable[[str], Exception]) -> dict:
+                        fail: Callable[[str], Exception],
+                        routed: Optional[tuple] = None) -> dict:
     """The picked requests again, at temperature 0, on the worker's generate
     endpoint, all at once. The preprocessor is the frontend's own, so the
     wire request is what the frontend sends. Token ids and not text: the
-    byte tokenizer's decode does not round-trip."""
+    byte tokenizer's decode does not round-trip. `routed`: the geometry
+    (experts, experts a token, routed layers) of a configuration whose routing
+    is judged forced; the worker is then asked for the experts it chose and
+    the reply is held to them (`routed_rows`): a reply without them fails the
+    run, it is never judged free."""
     from dynamo_tpu.llm.model_card import ModelDeploymentCard
     from dynamo_tpu.llm.preprocessor import OpenAIPreprocessor
     from dynamo_tpu.llm.protocols import CompletionRequest
@@ -277,21 +311,32 @@ async def resend_greedy(discovery_addr: str, model: str, vocab_size: int,
                 "logprobs": 0,
             }
             req = pre.preprocess_completion(CompletionRequest(**body))
-            out, lps = [], []
-            stream = await client.direct(req.to_dict(), instance)
+            wire = req.to_dict()
+            if routed:
+                wire["annotations"] = [*(wire.get("annotations") or []), ROUTED_EXPERTS]
+            out, lps, rows = [], [], []
+            stream = await client.direct(wire, instance)
             async for item in stream:
                 if item.get("event") == "error":
                     raise fail(f"{pick['why']}: {item.get('comment')}")
                 data = item.get("data") or {}
                 out.extend(data.get("token_ids") or [])
                 lps.extend(data.get("log_probs") or [])
+                rows.extend(data.get(ROUTED_EXPERTS) or [])
             if not len(out) == len(lps) == pick["max_tokens"]:
                 raise fail(
                     f"{pick['why']}: {len(out)} token ids and {len(lps)} "
                     f"logprobs over the request plane, {pick['max_tokens']} asked"
                 )
-            return {"prompt_ids": list(req.token_ids), "served_ids": out,
-                    "served_logprobs": lps, "why": pick["why"]}
+            served = {"prompt_ids": list(req.token_ids), "served_ids": out,
+                      "served_logprobs": lps, "why": pick["why"]}
+            if routed:
+                try:
+                    served[ROUTED_EXPERTS] = routed_rows(
+                        rows, len(req.token_ids) + len(out) - 1, routed)
+                except ValueError as e:
+                    raise fail(f"{pick['why']}: forced routing: {e}") from e
+            return served
 
         served = await asyncio.wait_for(
             asyncio.gather(*[one(p) for p in picks]), timeout=180

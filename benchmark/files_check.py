@@ -17,6 +17,7 @@ rule for a routed family's own limits, `check_judge`.
 
 from __future__ import annotations
 
+import ast
 import json
 import math
 import os
@@ -25,7 +26,8 @@ import sys
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE)
 
-from reference import ROUTED_LIMITS, order_statistics_share  # noqa: E402  (no JAX until it runs)
+from reference import (  # noqa: E402  (no JAX until it runs)
+    FORCED_LIMITS, ROUTED_LIMITS, order_statistics_share)
 
 # Each configuration's published sizes, written here from its source and
 # not read from its file: a width that differs is a fault of the file.
@@ -67,6 +69,10 @@ UPPER_READING_TIMES = 3.0
 # 0.0345 over twelve seeds, 0.0205 over twenty-four)
 CONTROL_SIDE_SHARE = 0.8
 SHARE_TOLERANCE = 0.015  # of a stated order-statistics share (20,000 tokens)
+# `judge_routing`: "free" (or absent) judges a routed family with its flips
+# in or left out; "forced" hands the served path's expert choices to the
+# reference (reference.py:FORCED_LIMITS; README.md "A forced family's limits")
+JUDGE_ROUTINGS = ("free", "forced")
 # a routed family's geometry, under the keys most public configurations use
 # (the catalog beside the `model-configs` guide); a file whose source names
 # them otherwise states them under these too
@@ -97,6 +103,69 @@ def routed_geometry(name: str, cfg: dict):
     return experts, per_token, layers
 
 
+def told_why(name: str, told: dict, key: str) -> dict:
+    """`judge_readings[key]`, which has to give one line of `reason`."""
+    entry = told.get(key)
+    need(isinstance(entry, dict), name, "judge_readings has no entry for", key)
+    why = entry.get("reason")
+    need(isinstance(why, str) and why.strip() and "\n" not in why,
+         name, "judge_readings", key, "needs one line of `reason`")
+    return entry
+
+
+def told_readings(name: str, told: dict, key: str) -> tuple:
+    """(`sound`, `control_int8`) of `judge_readings[key]`: each lowest, highest
+    and at least RUNS_MIN runs, the sound side's kind, and no fewer runs of the
+    control than sound ones."""
+    entry = told_why(name, told, key)
+    sound, low = entry.get("sound"), entry.get("control_int8")
+    for side in (sound, low):
+        need(isinstance(side, dict) and number(side.get("lowest"))
+             and number(side.get("highest")) and 0 <= side["lowest"] <= side["highest"]
+             and isinstance(side.get("runs"), int) and side["runs"] >= RUNS_MIN,
+             name, "judge_readings", key, "needs `sound` and `control_int8`, each with "
+             "lowest <= highest and at least", RUNS_MIN, "runs; has", side)
+    need(sound.get("kind") in SOUND_KINDS, name, key, "sound.kind is one of", SOUND_KINDS)
+    need(low["runs"] >= sound["runs"], name, key, "has", low["runs"],
+         "runs of the int8 control for", sound["runs"], "sound ones")
+    return sound, low
+
+
+def reference_takes_forced(family: str) -> bool:
+    """Whether references/<family>.py:logits has a `forced` parameter, read
+    from its source: importing it would start JAX."""
+    try:
+        with open(os.path.join(HERE, "references", f"{family}.py")) as f:
+            tree = ast.parse(f.read())
+    except (OSError, SyntaxError):
+        return False
+    return any(isinstance(node, ast.FunctionDef) and node.name == "logits"
+               and "forced" in [a.arg for a in node.args.args + node.args.kwonlyargs]
+               for node in tree.body)
+
+
+def check_forced(name: str, cfg: dict, limits, geometry) -> None:
+    """`judge_routing`: "forced" is for a routed family that keeps every
+    position, sets FORCED_LIMITS (judged numbers, which `check_judge`'s rule
+    holds to their readings like the rest) and whose reference takes `forced`;
+    a family judged free sets none of them."""
+    routing = cfg.get("judge_routing", "free")
+    need(routing in JUDGE_ROUTINGS, name, "judge_routing is one of", JUDGE_ROUTINGS,
+         "and says", routing)
+    set_here = [k for k in FORCED_LIMITS if k in (limits or {})]
+    if routing == "free":
+        need(not set_here, name, "sets", set_here, "and its routing is judged free")
+        return
+    need(geometry is not None and isinstance(limits, dict)
+         and limits.get("router_margin_epsilon") == 0
+         and limits.get("router_left_out_share") == 0 and len(set_here) == len(FORCED_LIMITS),
+         name, "judge_routing forced is for a routed family whose `judge` keeps every "
+         "position (router_margin_epsilon 0, router_left_out_share 0) and sets",
+         FORCED_LIMITS, "; judge is", limits)
+    need(reference_takes_forced(cfg.get("family", "")), name, "judge_routing forced, and "
+         f"references/{cfg.get('family')}.py:logits takes no `forced`")
+
+
 def check_judge(name: str, cfg: dict) -> None:
     """A routed family's limits, the defaults (reference.py:ROUTED_LIMITS) or
     its own (`judge`), against its geometry and against what they were set from
@@ -121,16 +190,22 @@ def check_judge(name: str, cfg: dict) -> None:
     - for at least one number the control's lowest reading is three times
       the highest sound one or more: an upper reading.
 
-    Every fault of the last two kinds is told, not the first alone.
+    Every fault of the last two kinds is told, not the first alone. Under
+    `judge_routing: "forced"` the same rule holds the same numbers (read with
+    no flip left on either side) and FORCED_LIMITS, judged numbers too.
     """
     geometry, limits = routed_geometry(name, cfg), cfg.get("judge")
     if geometry is None:
         need(limits is None, name, "has `judge` and none of", EXPERTS_KEYS)
+        need(cfg.get("judge_routing", "free") == "free", name,
+             "has `judge_routing` and none of", EXPERTS_KEYS)
         return
+    may_set = sorted([*ROUTED_LIMITS, *FORCED_LIMITS])
     need(limits is None or (isinstance(limits, dict) and limits
-                            and set(limits) <= set(ROUTED_LIMITS)
+                            and set(limits) <= set(may_set)
                             and all(number(v) for v in limits.values())),
-         name, "judge may set", sorted(ROUTED_LIMITS), "each to a number, and sets", limits)
+         name, "judge may set", may_set, "each to a number, and sets", limits)
+    check_forced(name, cfg, limits, geometry)
     in_force = {**ROUTED_LIMITS, **(limits or {})}
     epsilon, share_max = in_force["router_margin_epsilon"], in_force["router_left_out_share"]
     need(epsilon >= 0 and 0 <= share_max <= 1,
@@ -145,33 +220,17 @@ def check_judge(name: str, cfg: dict) -> None:
     told = cfg.get("judge_readings")
     need(isinstance(told, dict), name, "has `judge` and no `judge_readings`")
 
-    def reason(entry, key):
-        need(isinstance(entry, dict), name, "judge_readings has no entry for", key)
-        why = entry.get("reason")
-        need(isinstance(why, str) and why.strip() and "\n" not in why,
-             name, "judge_readings", key, "needs one line of `reason`")
-
     for key in WHICH_POSITIONS:
         if key in limits:
-            reason(told.get(key), key)
-            stated = told[key].get("order_statistics_share")
+            stated = told_why(name, told, key).get("order_statistics_share")
             need(number(stated) and abs(stated - share) <= SHARE_TOLERANCE,
                  name, "judge_readings", key, "order_statistics_share is", stated,
                  "where", geometry, "at an epsilon of", epsilon, "give", share)
     faults, upper_readings = [], 0
-    for key in JUDGED_NUMBERS:
-        limit, entry = in_force[key], told.get(key)
-        reason(entry, key)
-        sound, low = entry.get("sound"), entry.get("control_int8")
-        for side in (sound, low):
-            need(isinstance(side, dict) and number(side.get("lowest"))
-                 and number(side.get("highest")) and 0 <= side["lowest"] <= side["highest"]
-                 and isinstance(side.get("runs"), int) and side["runs"] >= RUNS_MIN,
-                 name, "judge_readings", key, "needs `sound` and `control_int8`, each with "
-                 "lowest <= highest and at least", RUNS_MIN, "runs; has", side)
-        need(sound.get("kind") in SOUND_KINDS, name, key, "sound.kind is one of", SOUND_KINDS)
-        need(low["runs"] >= sound["runs"], name, key, "has", low["runs"],
-             "runs of the int8 control for", sound["runs"], "sound ones")
+    forced = cfg.get("judge_routing") == "forced"
+    for key in JUDGED_NUMBERS + (FORCED_LIMITS if forced else ()):
+        limit = in_force[key]
+        sound, low = told_readings(name, told, key)
         count = key == "positions_outside"  # whole numbers: a run passes at its limit
         middle = math.sqrt(sound["highest"] * low["lowest"])
         for ok, what in (
@@ -222,8 +281,15 @@ def check_loaded(bench: dict, cfgs: dict, root: str) -> None:
     e2e = {m["name"] for m in bench["end_to_end"]}
     for m in bench["end_to_end"]:
         need(0.01 <= m["bound"] <= 0.1, m["name"], "bound", m["bound"], "outside [0.01, 0.1]")
+    reports = {w: {m["name"] for m in bench["end_to_end"] if w in m.get("workloads", cells)}
+               for w in cells}
+    need(all(len(r - {"setup_s"}) >= 1 and "setup_s" in r for r in reports.values()),
+         "every cell reports setup_s and another end-to-end metric:", reports)
     for m in bench["per_layer"]:
         need(m["moves"] in e2e and set(m.get("workloads", [])) <= cells, m)
+        # a cell that a metric lists reports the end-to-end metric it moves
+        need(all(m["moves"] in reports[w] for w in m.get("workloads", [])), m["name"],
+             "lists a cell that does not report", m["moves"])
         need(os.path.exists(os.path.join(HERE, "layer_metrics", m["name"] + ".json")),
              m["name"], "has no reader")
 

@@ -121,6 +121,29 @@ ROUTED_LIMITS = {
     "logprob_gap_pooled_mean_all_sigmas": ROUTED_POOLED_MEAN_ALL_SIGMAS,
     "logprob_gap_request_median_sigmas": ROUTED_REQUEST_MEDIAN_SIGMAS,
 }
+# A family of many small experts has a near-tie at nearly every position (the
+# table above), and judged with its flips in, four requests' numbers swing too
+# far to part a bf16 program from an int8 one (PERF.md section 6, PR 36). Its
+# configuration says `judge_routing: "forced"`: the served path (or a control)
+# hands over the experts it chose, per input position and routed layer, the
+# reference uses THOSE (references/<family>.py, `forced`), so no flip is left
+# on either side and the four numbers above read rounding alone; and each
+# choice is held to the reference's own scores, through its DEFICIT: the
+# reference's k-th best router score less the lowest-scored forced expert's,
+# in deviations of the token's router logits; 0 where the forced set is the
+# reference's own. One limit more, which has no default, since a geometry's
+# deficits are its own:
+#   router_choice_deficit_max_sigmas  the largest deficit of the run. A number
+#                          that precision moves, like the four above, and held
+#                          to the same rule: it reads how far the router's
+#                          INPUT is off, at every layer and position, whatever
+#                          token the head puts first (at 64 experts the int8
+#                          control reads 0.09-0.15 where the bf16 control reads
+#                          0.014-0.028, on every seed; the log-probability
+#                          numbers swing by which token a request settles on:
+#                          PERF.md section 6, PR 38). It is the validity check
+#                          too: a WRONGLY routed token reads 0.9 or more.
+FORCED_LIMITS = ("router_choice_deficit_max_sigmas",)
 # what `--control` takes, in the order they are computed: the reference
 # itself in the program's place at the program's own precision (bf16: has to
 # come out agreeing) and one step down (int8 matrices: NOT agreeing). See
@@ -160,20 +183,31 @@ def order_statistics_share(experts: int, per_token: int, layers: int, epsilon: f
 
 
 def judge(cases: dict, rows_of: dict, margins_of: dict, served_of: dict,
-          overrides: dict | None = None) -> dict:
+          overrides: dict | None = None, deficits_of: dict | None = None) -> dict:
     """The numbers `correct` rests on, each against its limit. `rows_of[name]`
     are the reference's logits at the positions that predict the served
     tokens, `served_of[name]` the (token ids, log-probabilities) judged: the
     program's, or a control's (see `run`). `overrides` is the configuration's
-    `judge` key: a routed family's own limits (ROUTED_LIMITS)."""
+    `judge` key: a routed family's own limits (ROUTED_LIMITS). `deficits_of`,
+    under forced routing alone: name -> [layers, input positions], how far
+    each forced choice lies under the reference's own; `overrides` then sets
+    FORCED_LIMITS too."""
     import numpy as np
 
     out, skipped, positions, outliers = {}, 0, 0, 0
     routed = any(m is not None for m in margins_of.values())
-    if overrides and not (routed and set(overrides) <= set(ROUTED_LIMITS) and all(
+    forced = deficits_of is not None
+    allowed = set(ROUTED_LIMITS) | set(FORCED_LIMITS if forced else ())
+    if forced and not (routed and overrides and set(FORCED_LIMITS) <= set(overrides)
+                       and not overrides.get("router_margin_epsilon", 1)
+                       and not overrides.get("router_left_out_share", 1)):
+        raise ValueError(f"forced routing is for a routed family whose `judge` sets "
+                         f"{FORCED_LIMITS} and keeps every position (router_margin_epsilon "
+                         f"0, router_left_out_share 0); it sets {overrides}")
+    if overrides and not (routed and set(overrides) <= allowed and all(
             isinstance(v, (int, float)) and not isinstance(v, bool)
             for v in overrides.values())):
-        raise ValueError(f"`judge` sets a routed family's limits {sorted(ROUTED_LIMITS)}, "
+        raise ValueError(f"`judge` sets a routed family's limits {sorted(allowed)}, "
                          f"each to a number; it sets {overrides}, and the family is "
                          f"{'routed' if routed else 'dense'}")
     limits = {**ROUTED_LIMITS, **(overrides or {})}
@@ -231,6 +265,9 @@ def judge(cases: dict, rows_of: dict, margins_of: dict, served_of: dict,
             "logprob_gap_request_median_sigmas": max(long_enough, default=0.0),
             "router_left_out_share": skipped / max(positions, 1),
         }
+        if forced:
+            values["router_choice_deficit_max_sigmas"] = max(
+                float(d.max()) for d in deficits_of.values())
         compared = {name: [value, limits[name]] for name, value in values.items()}
     else:  # the worst position, and the worst request's mean
         compared = {
@@ -279,8 +316,9 @@ def int8_weights(params: dict):
 
 
 def load_model(config_file: str, rehearsal: bool) -> tuple:
-    """(configuration, weights, the family's plain reference, the file's
-    `judge` key or None)."""
+    """(configuration, a function that builds the weights, the family's plain
+    reference, the file's `judge` key or None, whether its routing is judged
+    forced)."""
     import jax
 
     from worker_entry import build_model_config, load_config
@@ -291,41 +329,59 @@ def load_model(config_file: str, rehearsal: bool) -> tuple:
     cfg = build_model_config(cfg_file)
     model_mod = importlib.import_module(cfg_file["dataclass"].partition(":")[0])
     reference = importlib.import_module(f"references.{cfg_file['family']}")
-    # the same seeded weights the worker built (JaxEngine: init_params from
-    # PRNGKey(EngineConfig.seed))
-    params = model_mod.init_params(cfg, jax.random.PRNGKey(cfg_file["weight_seed"]))
-    return cfg, params, reference, cfg_file.get("judge")
+
+    def weights():
+        # the same seeded weights the worker built (JaxEngine: init_params
+        # from PRNGKey(EngineConfig.seed))
+        return model_mod.init_params(cfg, jax.random.PRNGKey(cfg_file["weight_seed"]))
+
+    return (cfg, weights, reference, cfg_file.get("judge"),
+            cfg_file.get("judge_routing") == "forced")
 
 
-def forward(reference, cfg, params, cases: dict) -> tuple:
+def forward(reference, cfg, params, cases: dict, forced_of: dict | None = None) -> tuple:
     """name -> the logits [tokens, vocab] that predict the served tokens,
-    teacher-forced on them, and the routing margins there (or None)."""
+    teacher-forced on them; the routing margins there (or None); and, where
+    the family's reference gives them, the experts it used at every INPUT
+    position (prompt + served[:-1]) and their deficits: {"chosen": [layers,
+    inputs, k], "deficits": [layers, inputs]}, else None. `forced_of`: name ->
+    the experts to force, [inputs][layers][k] (a case's `routed_experts`)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     T = max(len(c["prompt_ids"]) + len(c["served_ids"]) for c in cases.values())
     T = -(-T // 64) * 64  # one padded shape: causal, so the tail is inert
-    rows_of, margins_of = {}, {}
+    rows_of, margins_of, routing_of = {}, {}, {}
     fwd = jax.jit(functools.partial(reference.logits, cfg=cfg, n_last=T))
     for name, c in cases.items():
         prompt, served = c["prompt_ids"], c["served_ids"]
         seq = prompt + served[:-1]
         toks = np.zeros((T,), np.int32)
         toks[: len(seq)] = seq
-        logits, margins = fwd(params, tokens=jnp.asarray(toks))
+        more = {}
+        if forced_of is not None:
+            given = np.asarray(forced_of[name], np.int32).transpose(1, 0, 2)
+            forced = np.full((given.shape[0], T, given.shape[2]), -1, np.int32)
+            forced[:, : len(seq)] = given  # the padded tail routes freely
+            more["forced"] = jnp.asarray(forced)
+        logits, margins, *routing = fwd(params, tokens=jnp.asarray(toks), **more)
         # a copy of the rows judged, so that the padded [T, vocab] can go
         rows_of[name] = np.array(np.asarray(logits)[len(prompt) - 1: len(seq)])
         margins_of[name] = (None if margins is None else
                             np.asarray(margins)[len(prompt) - 1: len(seq)])
-    return rows_of, margins_of
+        routing_of[name] = None if not routing else {
+            "chosen": np.array(np.asarray(routing[0])[:, : len(seq)]),
+            "deficits": np.array(np.asarray(routing[1])[:, : len(seq)])}
+    return rows_of, margins_of, routing_of
 
 
 def control_rows(precision: str, reference, cfg, params, cases: dict):
     """A control: the reference itself in the program's place, at each
-    position of the same prompts and tokens. It does not decode: it is judged
-    by the token it puts first and the log-probability it gives that token
-    (`control_choice`). Call it OUTSIDE the `highest` block.
+    position of the same prompts and tokens, routing by its own scores. It
+    does not decode: it is judged by the token it puts first and the
+    log-probability it gives that token (`control_choice`). Returns what
+    `forward` does, or None. Call it OUTSIDE the `highest` block.
 
     bf16: the TPU's default matmul precision, one bf16 pass of the operands
     with float32 accumulation: what the configuration states and a program
@@ -333,16 +389,17 @@ def control_rows(precision: str, reference, cfg, params, cases: dict):
     limit a bf16 program can keep. On a CPU the default is float32 already:
     None, and nothing is judged.
     int8: every matrix rounded to int8 (`int8_weights`: IN PLACE, so it comes
-    last), the nearest precision below bf16, the step that would tempt a
-    later PR. It has to come out NOT agreeing, or the limits let it through."""
+    last, or the weights are built anew after it), the nearest precision below
+    bf16, the step that would tempt a later PR. It has to come out NOT
+    agreeing, or the limits let it through."""
     import jax
 
     if precision == "bf16":
         if jax.devices()[0].platform == "cpu":
             return None
-        return forward(reference, cfg, params, cases)[0]
+        return forward(reference, cfg, params, cases)
     with jax.default_matmul_precision("highest"):
-        return forward(reference, cfg, int8_weights(params), cases)[0]
+        return forward(reference, cfg, int8_weights(params), cases)
 
 
 def control_choice(low_rows: dict) -> dict:
@@ -359,53 +416,90 @@ NOT_ON_A_CPU = ("the reference's device is a CPU, whose default matmul precision
 def run(case_file: str) -> dict:
     """`cases`: name -> prompt and served tokens of ONE run, judged together;
     under `controls_only` seed -> such a set, each judged on its own, with no
-    program's tokens to judge."""
+    program's tokens to judge.
+
+    Free routing: one float32 pass, which every verdict is read against.
+    Forced routing: whoever is judged (the program, a control) brings the
+    experts it chose, and the float32 reference is run with THOSE forced: one
+    pass for the program's `routed_experts`, one more for each control's own
+    choices, so a control is judged against the rows its routing gives, as a
+    program is."""
     import jax
     import numpy as np
 
     with open(case_file) as f:
         spec = json.load(f)
-    cfg, params, reference, overrides = load_model(spec["config_file"], spec["rehearsal"])
+    cfg, weights, reference, overrides, forced = load_model(
+        spec["config_file"], spec["rehearsal"])
+    params = weights()
     only = spec.get("controls_only", False)
     sets = spec["cases"] if only else {"run": spec["cases"]}
     flat = {f"{s}/{name}": c for s, cases in sets.items() for name, c in cases.items()}
-    with jax.default_matmul_precision("highest"):
-        rows_of, margins_of = forward(reference, cfg, params, flat)
 
-    def judged(served_of: dict) -> dict:
-        """seed -> the verdict on that seed's cases."""
+    def highest(forced_of=None) -> tuple:
+        with jax.default_matmul_precision("highest"):
+            return forward(reference, cfg, params, flat, forced_of)
+
+    def judged(served_of: dict, against: tuple) -> dict:
+        """seed -> the verdict on that seed's cases, read against one float32
+        pass: (rows, margins, routing)."""
+        rows_of, margins_of, routing_of = against
         out = {}
         for s, cases in sets.items():
             def own(d):
                 return {name: d[f"{s}/{name}"] for name in cases}
-            out[s] = judge(cases, own(rows_of), own(margins_of), own(served_of), overrides)
+            deficits = ({name: r["deficits"] for name, r in own(routing_of).items()}
+                        if forced else None)
+            out[s] = judge(cases, own(rows_of), own(margins_of), own(served_of),
+                           overrides, deficits)
         return out
 
-    def margin_share(s: str):
+    def margin_share(s: str, margins_of: dict):
         """Whatever epsilon the configuration sets: what its geometry gives."""
         margins = [margins_of[f"{s}/{name}"] for name in sets[s]]
         if any(m is None for m in margins):
             return None
         return float((np.concatenate(margins) < ROUTER_MARGIN_EPSILON).mean())
 
+    against = None
+    if not forced:
+        against = highest()
+    elif not only:
+        missing = [name for name, c in flat.items() if not c.get("routed_experts")]
+        if missing:  # never judged free because its worker sent nothing
+            raise ValueError(f"forced routing, and no `routed_experts` for {missing}")
+        against = highest({name: c["routed_experts"] for name, c in flat.items()})
     if only:
         result = {"controls_only": True, "sets": {s: {
             "positions": sum(len(c["served_ids"]) for c in cases.values()),
-            "margin_under_default_epsilon_share": margin_share(s),
         } for s, cases in sets.items()}}
     else:
         result = judged({name: (c["served_ids"], c["served_logprobs"])
-                         for name, c in flat.items()})["run"]
+                         for name, c in flat.items()}, against)["run"]
     asked = spec.get("controls") or []
     for precision in (c for c in CONTROLS if c in asked):  # int8 last: in place
-        low_rows = control_rows(precision, reference, cfg, params, flat)
-        verdicts = ({s: {"skipped": NOT_ON_A_CPU} for s in sets} if low_rows is None
-                    else judged(control_choice(low_rows)))
+        low = control_rows(precision, reference, cfg, params, flat)
+        if low is None:
+            verdicts = {s: {"skipped": NOT_ON_A_CPU} for s in sets}
+        else:
+            choice = control_choice(low[0])
+            if forced:
+                if precision == "int8":  # rounded in place: the float32 pass needs them whole
+                    params = None
+                    params = weights()
+                against = highest({name: r["chosen"].transpose(1, 0, 2)
+                                   for name, r in low[2].items()})
+            del low
+            verdicts = judged(choice, against)
         if only:
             for s in sets:
                 result["sets"][s][precision] = verdicts[s]
         else:
             result.setdefault("controls", {})[precision] = verdicts["run"]
+    if only:
+        for s in sets:  # of the last float32 pass made (forced, and no control ran: none)
+            result["sets"][s]["margin_under_default_epsilon_share"] = (
+                None if against is None else margin_share(s, against[1]))
     dev = jax.devices()[0]
     result["reference_device"] = {"platform": dev.platform, "kind": dev.device_kind}
     return result

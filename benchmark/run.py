@@ -7,11 +7,12 @@ Starts discovery, the worker (through worker_entry.py, which hands the
 unchanged `dynamo_tpu.jax_worker` main a configuration read from a file) and
 the OpenAI frontend; waits for ready; warms with the cell's own traffic until
 nothing compiles; measures for `--seconds` from a client over HTTP; re-sends
-four of the window's requests greedily and checks them against the plain
-reference in a child of its own, after the worker has exited. The last line
-of standard output is the result: one JSON object with `correct`,
-`attempted`, `failed`, `metrics` and `device` (and `breakdown` when traced).
-Earlier lines are JSON objects too, one per phase.
+four of the window's requests greedily (asking, for a configuration whose
+routing is judged forced, for the experts the worker chose) and checks them
+against the plain reference in a child of its own, after the worker has
+exited. The last line of standard output is the result: one JSON object with
+`correct`, `attempted`, `failed`, `metrics` and `device` (and `breakdown`
+when traced). Earlier lines are JSON objects too, one per phase.
 
     --rehearsal   tiny sizes from the configuration's own file, on the CPU:
                   finds wrong paths and arguments at no chip time. Its line
@@ -26,7 +27,9 @@ Earlier lines are JSON objects too, one per phase.
                   controls (reference.py) judged on them by the file's limits.
                   The table from which a routed family's limits are set, for
                   any configuration file whose `dataclass` the program can
-                  build. Prints no result line.
+                  build. Under `judge_routing: "forced"` each control is
+                  judged against a float32 pass with its OWN expert choices
+                  forced, as a program is. Prints no result line.
 
 One process per chip: this parent never imports JAX. Process supervision is
 copied from chip_smoke.py (PR 21). A run that finds no TPU fails.
@@ -195,12 +198,15 @@ def load_cell(workload: str) -> dict:
     def here(m):
         return "workloads" not in m or workload in m["workloads"]
 
+    end_to_end = [m for m in bench["end_to_end"] if here(m)]
+    reported = {m["name"] for m in end_to_end}
     return {
         "name": workload, "config": cell["config"], "traffic": cell["traffic"],
         "chips": cell["chips"], "config_file": os.path.join(ROOT, config["file"]),
         "run_seconds": float(bench["run_seconds"]),
-        "end_to_end": [m for m in bench["end_to_end"] if here(m)],
-        "per_layer": [m for m in bench["per_layer"] if here(m)],
+        "end_to_end": end_to_end,
+        # a per-layer metric belongs to the cells that report what it moves
+        "per_layer": [m for m in bench["per_layer"] if here(m) and m["moves"] in reported],
     }
 
 
@@ -353,7 +359,9 @@ async def measure(args, cell: dict, mix: dict, cfg: dict, discovery_addr: str,
     result["served"] = await resend_greedy(
         discovery_addr, cell["config"], cfg["vocab_size"],
         int(cfg["worker_args"][cfg["worker_args"].index("--max-model-len") + 1]),
-        picks, BenchFailure)
+        picks, BenchFailure,
+        routed=(files_check.routed_geometry(cell["config"], cfg)
+                if cfg.get("judge_routing") == "forced" else None))
     await watch.close()
     return result
 
@@ -472,7 +480,9 @@ def controls_only(args, seeds: list) -> int:
              for c in CONTROLS}})
     # the readings come before the rule can hold: a file whose `judge` has no
     # sound `judge_readings` yet is judged by it all the same, and told so
-    files_check.check_judge(args.config_file, cfg)
+    # (held at the file's own geometry: a rehearsal's tiny one is not what the
+    # limits were set for)
+    files_check.check_judge(args.config_file, load_config(args.config_file, False))
     if args.rehearsal:
         return REHEARSAL_PASSED
     skipped = [c for c in CONTROLS if len(judged[c]) < len(seeds)]

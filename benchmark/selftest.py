@@ -11,20 +11,26 @@ dense and mixture-of-experts references against the program's own forwards
 (models/llama.py, models/moe.py at capacity_factor 4.0) at the tiny presets
 in float32 on the CPU; the judge's limits on made-up logits, the defaults
 name for name and a configuration's own (`judge`); the rule that holds such
-limits to their readings, by planted files; and that a configuration with
+limits to their readings, by planted files; that a configuration with
 limits of its own is a new file alone (a whole `run.py --rehearsal` in a
-tree of links, some 90 s). (PR 21 found that a wrong page stays under the
-tolerance at tiny widths on the CPU: that sabotage is a chip check.)
+tree of links, some 90 s); a family whose routing is judged FORCED: the
+reference under made-up choices, a wrongly routed token, the client's check
+of a reply's `routed_experts`, the rule for its limit, and such a family
+as new files alone; and when a closed loop's request is due. (PR 21 found that
+a wrong page stays under the tolerance at tiny widths on the CPU: that
+sabotage is a chip check.)
 """
 
 from __future__ import annotations
 
+import asyncio
 import copy
 import json
 import os
 import shutil
 import subprocess
 import sys
+import time
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -134,6 +140,42 @@ def test_same_work_every_seed():
     assert len({r.rid for s in closed for r in s}) == 32 * 64
 
 
+def test_a_closed_loop_request_is_due_when_the_last_one_ended():
+    """What `ttft` is counted from in a closed loop: a client's next request
+    is due, and sent, the moment the one before it ended."""
+    import client
+
+    streams = Generator(dict(load_mix("decode-closed"), clients=3), 11).client_streams()
+
+    async def fake_send(session, base, model, temperature, req):
+        req.t_send = time.monotonic()
+        await asyncio.sleep(0.004)
+        req.t_end = time.monotonic()
+        req.ok = True
+        return req
+
+    async def drive():
+        load = client.Load(None, "", "m", 0.0)
+        load.start_clients(streams)
+        await asyncio.sleep(0.3)
+        load.stop_offering()
+        assert await load.drain(5.0) == 0
+        return load.sent
+
+    real, client.send = client.send, fake_send
+    try:
+        by_client = {}  # request i of the set is client i % clients' (traffic.py)
+        for r in asyncio.run(drive()):
+            owner = int(r.rid.partition("c")[2].partition("r")[0]) % 3
+            by_client.setdefault(owner, []).append(r)
+    finally:
+        client.send = real
+    assert len(by_client) == 3 and all(len(v) >= 4 for v in by_client.values())
+    for sent in by_client.values():
+        for before, after in zip(sent, sent[1:]):
+            assert before.t_end <= after.t_due <= after.t_send < before.t_end + 0.05
+
+
 def test_layer_expressions():
     s0 = {"mixed_steps": 10, "split_steps": 10, "emit_tokens": 100,
           "dispatch_a_count": 5, "dispatch_b_count": 5, "dispatch_a_s": 1.0,
@@ -211,7 +253,9 @@ def test_references_against_the_program():
         params = model.init_params(cfg, jax.random.PRNGKey(0))
         toks = jax.random.randint(jax.random.PRNGKey(1), (T,), 5, cfg.vocab_size)
         with jax.default_matmul_precision("highest"):
-            want, margins = ref.logits(params, cfg, toks, n_last=T)
+            # (a routed family's reference also gives its choices and their
+            # deficits: test_a_forced_family_is_judged_by_the_choices_it_is_handed)
+            want, margins, *_ = ref.logits(params, cfg, toks, n_last=T)
             pages = T // PAGE
             kv = alloc_kv_store(cfg.num_layers, pages + 2, PAGE, cfg.num_kv_heads,
                                 cfg.head_dim, cfg.dtype, "none")
@@ -349,13 +393,15 @@ def test_many_experts_are_judged_with_their_flips_in():
         assert abs(reference.order_statistics_share(*geometry) - share) < 0.015, geometry
 
 
+def told(sound, control, kind="served") -> dict:
+    """An entry of `judge_readings`: twelve runs a side, made up."""
+    return {"sound": {"lowest": sound[0], "highest": sound[1], "runs": 12, "kind": kind},
+            "control_int8": {"lowest": control[0], "highest": control[1], "runs": 12},
+            "reason": "made up by selftest.py"}
+
+
 def planted_judge() -> dict:
     """A `judge` and `judge_readings` that keep the rule, made up."""
-    def told(sound, control):
-        return {"sound": {"lowest": sound[0], "highest": sound[1], "runs": 12,
-                          "kind": "served"},
-                "control_int8": {"lowest": control[0], "highest": control[1], "runs": 12},
-                "reason": "made up by selftest.py"}
     share = {"reason": "made up by selftest.py", "order_statistics_share": 0.0}
     return {
         "judge": {"router_margin_epsilon": 0, "router_left_out_share": 0,
@@ -370,6 +416,18 @@ def planted_judge() -> dict:
             "logprob_gap_request_median_sigmas": told((0.004, 0.006), (0.02, 0.03))}}
 
 
+def planted_forced(sound=(0.02, 0.05), control=(0.16, 0.19)) -> dict:
+    """The same, for a family whose routing is judged forced: the largest
+    deficit as a judged number more, with the readings that sound runs and the
+    int8 control's gave, made up."""
+    planted = planted_judge()
+    planted["judge"].update(  # a limit past the geometric mean, under 0.8 of the control's
+        router_choice_deficit_max_sigmas=0.75 * control[0])
+    planted["judge_readings"].update(
+        router_choice_deficit_max_sigmas=told(sound, control, "bf16_control"))
+    return {"family": "moe", "judge_routing": "forced", **planted}
+
+
 def test_files_check_holds_limits_to_their_readings():
     """files_check.py ends with exit code 2 on each planted file, and says
     why: an override without readings; a limit under the sound runs' highest,
@@ -378,9 +436,9 @@ def test_files_check_holds_limits_to_their_readings():
     which no control reads three times the sound run; a limit that is no
     number; a share stated, or held, under what order statistics leave out at
     the geometry of the file's OWN keys, with or without a `judge`. The sound
-    file passes. The fixture, whose readings are the chip's at 64 experts and
-    4 a token (PR 36), is refused: no limit stands between the controls'
-    counts nor between their medians, and nothing reads three times."""
+    file passes. `judge_routing` and a forced family's limit likewise.
+    The fixtures pass as they stand, forced; the first one judged FREE, by
+    the chip's readings at 64 experts and 4 a token (PR 36), is refused."""
     out = os.path.join(os.path.dirname(HERE), "chiprun_out", "selftest_planted")
     shutil.rmtree(out, ignore_errors=True)
     os.makedirs(out)
@@ -449,8 +507,64 @@ def test_files_check_holds_limits_to_their_readings():
           lambda cfg: (default_epsilon(0.53)(cfg), cfg.update(num_dense_layers=7),
                        cfg["judge"].update(router_left_out_share=0.6)))
     plant("no_experts", lambda cfg: cfg.pop("num_local_experts"), "has `judge` and none of")
+
+    # `judge_routing`: what it may say, what "forced" asks of the file, and the
+    # rule for its limit, the same as for every judged number
+    def forced(spoil):
+        def planted(cfg):
+            cfg.update(planted_forced())
+            spoil(cfg)
+        return planted
+
+    def reading(key, side, **values):
+        return forced(lambda cfg: cfg["judge_readings"][key][side].update(**values))
+
+    deficit = "router_choice_deficit_max_sigmas"
+    plant("forced", forced(lambda cfg: None))
+    plant("routing_unknown", lambda cfg: cfg.update(judge_routing="pinned"),
+          "judge_routing is one of")
+    plant("free_said", lambda cfg: cfg.update(judge_routing="free"))
+    plant("free_with_a_choice_limit", limit(deficit, 0.12), "its routing is judged free")
+    plant("forced_with_near_ties_left_out",
+          forced(lambda cfg: (default_epsilon(0.998)(cfg),
+                              cfg["judge"].update(router_left_out_share=1.0))),
+          "keeps every position")
+    plant("forced_without_its_limit", forced(lambda cfg: cfg["judge"].pop(deficit)),
+          "keeps every position")
+    plant("forced_without_readings", forced(lambda cfg: cfg["judge_readings"].pop(deficit)),
+          "no entry for " + deficit)
+    plant("forced_dense_reference", forced(lambda cfg: cfg.update(family="llama")),
+          "references/llama.py:logits takes no `forced`")
+    plant("deficit_limit_under_a_sound_run", reading(deficit, "sound", highest=0.13),
+          "router_choice_deficit_max_sigmas: limit 0.12 is not above the highest sound")
+    plant("deficit_is_no_budget", forced(limit(deficit, 0.14)),
+          "router_choice_deficit_max_sigmas: limit 0.14 is over 0.8 of the int8 control's")
+    plant("forced_fewer_control_runs", reading(deficit, "control_int8", runs=6),
+          "6 runs of the int8 control for 12 sound ones")
     shutil.rmtree(out)
+    # the first fixture as it stands keeps the rule: under forced routing a
+    # number reads three times (PR 38). The second, 8 experts a token, does
+    # NOT on its two dozen seeds: the worst request's median is 1.54 times
+    # apart, less than the two sides' room takes, and the file stays refused
     rc, err = check(os.path.join(HERE, "fixtures", "many-experts.json"))
+    assert rc == 0, err
+    rc, err = check(os.path.join(HERE, "fixtures", "many-experts-k8.json"))
+    assert rc == 2 and "logprob_gap_request_median_sigmas: limit" in err and all(
+        x not in err for x in ("logprob_gap_pooled_mean", "router_choice_deficit_max_sigmas",
+                               "in no number")), err
+    # ... and the first fixture judged FREE, with PR 36's thirty seeds' readings
+    # laid over it, does not: no limit stands between the controls' counts nor
+    # between their medians, and nothing reads three times
+    os.makedirs(out)
+    with open(os.path.join(HERE, "fixtures", "many-experts.json")) as f:
+        free = json.load(f)
+    with open(os.path.join(HERE, "fixtures", "many-experts.free-readings.json")) as f:
+        free.update({k: v for k, v in json.load(f).items() if k.startswith("judge")})
+    del free["judge_routing"]
+    with open(os.path.join(out, "many-experts-free.json"), "w") as f:
+        json.dump(free, f)
+    rc, err = check(os.path.join(out, "many-experts-free.json"))
+    shutil.rmtree(out)
     assert rc == 2 and all(x in err for x in (
         "positions_outside: limit", "logprob_gap_request_median_sigmas: limit",
         "is over 0.8 of the int8 control's lowest reading",
@@ -458,21 +572,139 @@ def test_files_check_holds_limits_to_their_readings():
         and "logprob_gap_pooled_mean" not in err, err
 
 
+def test_a_forced_family_is_judged_by_the_choices_it_is_handed():
+    """The first fixture at its rehearsal sizes (64 experts, 4 a token, 5
+    layers, float32 on this CPU), judged forced on made-up prompts and tokens.
+    Handed its OWN choices the reference computes what it computes free, and
+    every deficit is 0. Handed choices in which, at 2% of the input positions,
+    one expert of one layer is swapped for another that it did not choose (at
+    64 experts: far down the ranking, as a rule), it computes THAT, so a
+    program that routed so and computed rightly from there agrees in every
+    logit: the number of the choice alone says not correct, the largest
+    deficit. And cases of a forced configuration that carry no
+    `routed_experts` are not judged."""
+    import jax
+    import numpy as np
+
+    import reference
+
+    path = os.path.join(HERE, "fixtures", "many-experts.json")
+    cfg, weights, ref, limits, forced = reference.load_model(path, rehearsal=True)
+    assert forced and "router_choice_deficit_max_sigmas" in limits
+    params = weights()
+    rng = np.random.default_rng(5)
+    cases = {f"{i}.made_up": {
+        "prompt_ids": rng.integers(3, cfg.vocab_size, size=p).tolist(),
+        "served_ids": rng.integers(3, cfg.vocab_size, size=n).tolist()}
+        for i, (p, n) in enumerate(((20, 30), (33, 140), (64, 150), (41, 77)))}
+    with jax.default_matmul_precision("highest"):
+        rows, margins, routing = reference.forward(ref, cfg, params, cases)
+        own = {name: r["chosen"].transpose(1, 0, 2) for name, r in routing.items()}
+        rows2, _, routing2 = reference.forward(ref, cfg, params, cases, own)
+    layers, k = cfg.num_layers, cfg.num_experts_per_tok
+    for name, c in cases.items():
+        inputs = len(c["prompt_ids"]) + len(c["served_ids"]) - 1
+        assert routing[name]["chosen"].shape == (layers, inputs, k)
+        assert routing[name]["deficits"].shape == (layers, inputs)
+        assert not routing[name]["deficits"].any() and not routing2[name]["deficits"].any()
+        assert (routing2[name]["chosen"] == routing[name]["chosen"]).all()
+        assert np.abs(rows2[name] - rows[name]).max() < 1e-4 * rows[name].std()
+    served = reference.control_choice(rows)
+    sound = reference.judge(cases, rows, margins, served, limits,
+                            {n: r["deficits"] for n, r in routing2.items()})
+    assert sound["agrees"] and sound["compared"]["router_choice_deficit_max_sigmas"][0] == 0.0
+    swapped, swaps = {}, 0
+    for name, rows_ in own.items():
+        rows_ = rows_.copy()
+        for at in rng.choice(len(rows_), size=max(len(rows_) // 50, 1), replace=False):
+            layer = rng.integers(layers)
+            left = sorted(set(range(cfg.num_experts)) - set(rows_[at, layer].tolist()))
+            rows_[at, layer, rng.integers(k)] = rng.choice(left)
+            swaps += 1
+        swapped[name] = rows_
+    with jax.default_matmul_precision("highest"):
+        rows3, margins3, routing3 = reference.forward(ref, cfg, params, cases, swapped)
+    wrong = reference.judge(cases, rows3, margins3, reference.control_choice(rows3), limits,
+                            {n: r["deficits"] for n, r in routing3.items()})
+    over = [key for key, (v, lim) in wrong["compared"].items() if v > lim]
+    assert not wrong["agrees"] and over == ["router_choice_deficit_max_sigmas"], \
+        wrong["compared"]
+    assert wrong["compared"]["router_choice_deficit_max_sigmas"][0] > 1.0
+    print(f"  {swaps} swaps of {sum(len(r) for r in own.values())} input positions: the "
+          f"largest deficit {wrong['compared']['router_choice_deficit_max_sigmas'][0]:.2f} "
+          f"(limit {limits['router_choice_deficit_max_sigmas']})")
+    # limits without it, or deficits for a free family: refused, not guessed
+    for bad in ({k_: v for k_, v in limits.items()
+                 if k_ != "router_choice_deficit_max_sigmas"},
+                dict(limits, router_margin_epsilon=0.1)):
+        try:
+            reference.judge(cases, rows, margins, served, bad,
+                            {n: r["deficits"] for n, r in routing.items()})
+        except ValueError:
+            continue
+        raise AssertionError(f"judge took {bad} under forced routing")
+    out = os.path.join(os.path.dirname(HERE), "chiprun_out", "selftest_forced")
+    shutil.rmtree(out, ignore_errors=True)
+    os.makedirs(out)
+    with open(os.path.join(out, "cases.json"), "w") as f:
+        json.dump({"config_file": path, "rehearsal": True, "controls": [],
+                   "cases": {n: dict(c, served_logprobs=[0.0] * len(c["served_ids"]))
+                             for n, c in cases.items()}}, f)
+    try:
+        reference.run(os.path.join(out, "cases.json"))
+    except ValueError as e:
+        assert "no `routed_experts`" in str(e)
+    else:
+        raise AssertionError("a forced configuration was judged with no choices handed over")
+    shutil.rmtree(out)
+
+
+def test_the_client_holds_a_reply_to_the_wire_contract():
+    """client.routed_rows: one row an input position (prompt + served[:-1];
+    a row for the last served token is dropped), each [routed layers][experts
+    a token] of distinct ids in range. Rows missing altogether (a worker that
+    does not know the annotation), too few, a layer short, an expert twice or
+    out of range: each fails with its reason, and run.py then fails the run;
+    nothing falls back to free routing."""
+    import client
+
+    geometry = (64, 4, 5)  # experts, experts a token, routed layers
+    row = [[1, 7, 63, 0]] * 5
+    rows = [row] * 9
+    assert client.routed_rows(list(rows), 9, geometry) == rows
+    assert client.routed_rows(rows + [row], 9, geometry) == rows  # the last token's row
+    for bad, says in (
+            ([], "0 rows"), (rows[:8], "8 rows"), (rows + [row, row], "11 rows"),
+            (rows[:4] + [row[:4]] + rows[5:], "row 4 of `routed_experts` holds 4 layers"),
+            (rows[:8] + [[[1, 7, 63, 0]] * 4 + [[1, 7, 7, 0]]], "row 8, layer 4"),
+            (rows[:8] + [[[1, 7, 64, 0]] + row[1:]], "row 8, layer 0"),
+            (rows[:8] + [[[1, 7, -1, 0]] + row[1:]], "row 8, layer 0"),
+            (rows[:8] + [[[1, 7, 3]] + row[1:]], "not 4 distinct expert ids under 64"),
+            (rows[:8] + [[[1, 7, 3, 2.0]] + row[1:]], "row 8, layer 0"),
+            (rows[:8] + [None], "row 8")):
+        try:
+            client.routed_rows(bad, 9, geometry)
+        except ValueError as e:
+            assert says in str(e), (says, str(e))
+        else:
+            raise AssertionError(f"routed_rows took a reply with {says}")
+
+
 def test_controls_only_needs_no_program_to_serve():
-    """`run.py --controls-only` on the fixture at rehearsal sizes: four cases a
-    seed from the mix's generator, the int8 control judged by the file's
-    limits, the bf16 control left out on a CPU and said so; then exit code 2,
-    because the fixture's limits do not keep the rule (which is its finding;
-    a file that keeps it ends with 4 here, 0 or 1 on the chip)."""
+    """`run.py --controls-only` on the first fixture at rehearsal sizes: four
+    cases a seed from the mix's generator, the int8 control judged by the
+    file's limits against a float32 pass with ITS choices forced (the file
+    says `judge_routing: "forced"`), the bf16 control left out on a CPU and
+    said so; exit code 4, since the file's limits keep the rule at its own
+    geometry (0 or 1 on the chip; 2 where they do not)."""
     p = subprocess.run(
         [sys.executable, os.path.join(HERE, "run.py"), "--controls-only", "--rehearsal",
          "--config-file", os.path.join(HERE, "fixtures", "many-experts.json"),
          "--traffic", "decode-closed", "--seed", "7", "--seed", "2147483907"],
         stdout=subprocess.PIPE, stderr=subprocess.PIPE, timeout=600)
     lines = [json.loads(x) for x in p.stdout.decode().strip().splitlines()]
-    assert p.returncode == 2 and [x["phase"] for x in lines] == [
+    assert p.returncode == 4 and [x["phase"] for x in lines] == [
         "controls", "controls", "controls_summary"], (p.returncode, p.stderr[-2000:])
-    assert b"in no number does the int8 control read 3.0 times" in p.stderr
     with open(os.path.join(HERE, "fixtures", "many-experts.json")) as f:
         limits = json.load(f)["judge"]
     for one in lines[:2]:
@@ -480,7 +712,56 @@ def test_controls_only_needs_no_program_to_serve():
         assert {k: lim for k, (_, lim) in one["int8"]["checked"].items()} == {
             k: v for k, v in limits.items() if k != "router_margin_epsilon"}
     assert lines[2]["seeds"] == [7, 2147483907] and lines[2]["int8"]["agreed"].endswith("of 2")
+    assert len(lines[2]["int8"]["router_choice_deficit_max_sigmas"]) == 2
     assert b"checked 7 int8 logprob_gap_pooled_mean_sigmas: " in p.stderr
+    assert b"checked 7 int8 router_choice_deficit_max_sigmas: " in p.stderr
+
+
+def tree_of_links(*real: str) -> str:
+    """A tree beside the real one in which everything is a link, but for
+    BENCHMARK.json (left out) and the directories of benchmark/ named, which
+    are directories of links: a later PR's new files go there, and no file
+    the benchmark has is touched."""
+    root = os.path.dirname(HERE)
+    tree = os.path.join(root, "chiprun_out", "selftest_tree")
+    shutil.rmtree(tree, ignore_errors=True)
+    os.makedirs(os.path.join(tree, "benchmark"))
+    for name in os.listdir(root):
+        if name not in ("benchmark", "BENCHMARK.json", "chiprun_out", ".jax_cache", ".git"):
+            os.symlink(os.path.join(root, name), os.path.join(tree, name))
+    for name in os.listdir(HERE):
+        if name in real:
+            os.makedirs(os.path.join(tree, "benchmark", name))
+            for inner in os.listdir(os.path.join(HERE, name)):
+                if inner != "__pycache__":
+                    os.symlink(os.path.join(HERE, name, inner),
+                               os.path.join(tree, "benchmark", name, inner))
+        elif name != "__pycache__":
+            os.symlink(os.path.join(HERE, name), os.path.join(tree, "benchmark", name))
+    return tree
+
+
+def new_routed_cell(tree: str, name: str, **keys) -> tuple:
+    """The routed configuration of BENCHMARK.json again under `name`, with
+    `keys` laid over its file, and a cell of it: both written into the tree,
+    real files. Returns the file's content and the cell's name."""
+    root = os.path.dirname(HERE)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    entry = copy.deepcopy(bench["configs"][0])  # the routed one
+    with open(os.path.join(root, entry["file"])) as f:
+        cfg = json.load(f)
+    assert cfg["family"] == "moe" and "judge" not in cfg
+    entry.update(name=name, file=f"benchmark/configs/{name}.json")
+    cfg.update(keys)
+    with open(os.path.join(tree, entry["file"]), "w") as f:
+        json.dump(cfg, f)
+    cell = dict(bench["workloads"][0], name=name + ".decode-closed", config=name)
+    bench["configs"].append(entry)
+    bench["workloads"].append(cell)
+    with open(os.path.join(tree, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f)
+    return cfg, cell["name"]
 
 
 def test_limits_of_its_own_are_a_new_file_alone():
@@ -488,39 +769,11 @@ def test_limits_of_its_own_are_a_new_file_alone():
     in BENCHMARK.json, no edit to a file the benchmark has. Built here in a
     tree of links beside the real one (the new file and BENCHMARK.json alone
     are real), and `run.py --rehearsal` there judges it by its overrides."""
-    root = os.path.dirname(HERE)
-    tree = os.path.join(root, "chiprun_out", "selftest_tree")
-    shutil.rmtree(tree, ignore_errors=True)
-    os.makedirs(os.path.join(tree, "benchmark", "configs"))
-    for name in os.listdir(root):
-        if name not in ("benchmark", "BENCHMARK.json", "chiprun_out", ".jax_cache", ".git"):
-            os.symlink(os.path.join(root, name), os.path.join(tree, name))
-    for name in os.listdir(HERE):
-        if name not in ("configs", "__pycache__"):
-            os.symlink(os.path.join(HERE, name), os.path.join(tree, "benchmark", name))
-    for name in os.listdir(os.path.join(HERE, "configs")):
-        os.symlink(os.path.join(HERE, "configs", name),
-                   os.path.join(tree, "benchmark", "configs", name))
-    with open(os.path.join(root, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    entry = copy.deepcopy(bench["configs"][0])  # the routed one
-    with open(os.path.join(root, entry["file"])) as f:
-        cfg = json.load(f)
-    assert cfg["family"] == "moe" and "judge" not in cfg
-    entry.update(name="routed-with-its-own-limits",
-                 file="benchmark/configs/routed-with-its-own-limits.json")
-    cfg.update(planted_judge())
-    with open(os.path.join(tree, entry["file"]), "w") as f:
-        json.dump(cfg, f)
-    cell = dict(bench["workloads"][0], name=entry["name"] + ".decode-closed",
-                config=entry["name"])
-    bench["configs"].append(entry)
-    bench["workloads"].append(cell)
-    with open(os.path.join(tree, "BENCHMARK.json"), "w") as f:
-        json.dump(bench, f)
+    tree = tree_of_links("configs")
+    cfg, cell = new_routed_cell(tree, "routed-with-its-own-limits", **planted_judge())
     p = subprocess.run(
         [sys.executable, os.path.join(tree, "benchmark", "run.py"), "--rehearsal",
-         "--workload", cell["name"], "--seed", "2147483801"],
+         "--workload", cell, "--seed", "2147483801"],
         cwd=tree, stdout=subprocess.PIPE, stderr=subprocess.PIPE, timeout=900)
     lines = p.stdout.decode().strip().splitlines()
     line = json.loads(lines[-1]) if lines else {}
@@ -530,6 +783,42 @@ def test_limits_of_its_own_are_a_new_file_alone():
                          if k != "router_margin_epsilon"}, "wrong_length_requests": 0}, limits
     assert line["checked"]["router_left_out_share"][0] == 0
     assert b"checked positions_outside: " in p.stderr and b"(limit 12)" in p.stderr
+    shutil.rmtree(tree)
+
+
+def test_a_forced_family_is_new_files_alone():
+    """A later PR's family whose routing is judged forced: a configuration
+    (`judge_routing`, limits of its own) and a `references/<family>.py` that
+    takes `forced`, both new files in a tree of links, and entries in
+    BENCHMARK.json; no edit to a file the benchmark has. There `run.py
+    --controls-only` judges the new reference under its own choices by the
+    file's limits; and a whole `run.py --rehearsal` of its cell asks the worker
+    for the experts it chose, gets none from today's program, and FAILS the
+    run with that reason: it is never judged free."""
+    tree = tree_of_links("configs", "references")
+    shutil.copy(os.path.join(HERE, "references", "moe.py"),
+                os.path.join(tree, "benchmark", "references", "moe_again.py"))
+    cfg, cell = new_routed_cell(
+        tree, "routed-and-forced",
+        **dict(planted_forced((0.01, 0.02), (0.07, 0.08)), family="moe_again"))
+    run_py = os.path.join(tree, "benchmark", "run.py")
+    p = subprocess.run(
+        [sys.executable, run_py, "--controls-only", "--rehearsal", "--config-file",
+         os.path.join(tree, "benchmark", "configs", "routed-and-forced.json"),
+         "--traffic", "decode-closed", "--seed", "2147483803"],
+        cwd=tree, stdout=subprocess.PIPE, stderr=subprocess.PIPE, timeout=600)
+    lines = [json.loads(x) for x in p.stdout.decode().strip().splitlines()]
+    assert p.returncode == 4 and [x["phase"] for x in lines] == [
+        "controls", "controls_summary"], (p.returncode, p.stderr[-2000:])
+    checked = lines[0]["int8"]["checked"]
+    assert {k: lim for k, (_, lim) in checked.items()} == {
+        k: v for k, v in cfg["judge"].items() if k != "router_margin_epsilon"}
+    p = subprocess.run(
+        [sys.executable, run_py, "--rehearsal", "--workload", cell, "--seed", "2147483803"],
+        cwd=tree, stdout=subprocess.PIPE, stderr=subprocess.PIPE, timeout=900)
+    lines = [json.loads(x) for x in p.stdout.decode().strip().splitlines()]
+    assert p.returncode == 1 and lines[-1]["phase"] == "failed", (p.returncode, lines[-2:])
+    assert "forced routing: 0 rows of `routed_experts`" in lines[-1]["error"], lines[-1]
     shutil.rmtree(tree)
 
 
@@ -556,9 +845,28 @@ def test_benchmark_files():
                 assert getattr(built, field) == lookup(cfg, key), (c["name"], field)
     for w in bench["workloads"]:
         load_mix(w["traffic"], False), load_mix(w["traffic"], True)
-    # the checks can fail: a width changed, a bound out of range
+    # a per-layer metric is a cell's where the cell reports what it moves: the
+    # dense cell reports no `ttft_p95_ms` (PERF.md section 2) and none of its movers
+    import run
+    cells = {w["name"]: run.load_cell(w["name"]) for w in bench["workloads"]}
+    for cell in cells.values():
+        reported = {m["name"] for m in cell["end_to_end"]}
+        assert "setup_s" in reported and len(reported) >= 2 and cell["per_layer"]
+        assert all(m["moves"] in reported for m in cell["per_layer"]), cell["name"]
+    dense = cells["mistral-7b-d16.decode-closed"]
+    assert "ttft_p95_ms" not in {m["name"] for m in dense["end_to_end"]}
+    assert {"engine.post_warmup_compiles", "engine.mixed_family_uncompiled"} <= {
+        m["name"] for m in dense["per_layer"]}
+
+    def lists_a_cell_that_lacks_it(b, c):
+        next(m for m in b["per_layer"] if m["moves"] == "ttft_p95_ms")["workloads"] = [
+            "mistral-7b-d16.decode-closed"]
+
+    # the checks can fail: a width changed, a bound out of range, a metric
+    # listed for a cell that does not report what it moves
     for spoil, what in ((lambda b, c: next(iter(c.values())).update(hidden_size=2048), "width"),
-                        (lambda b, c: b["end_to_end"][0].update(bound=0.2), "bound")):
+                        (lambda b, c: b["end_to_end"][0].update(bound=0.2), "bound"),
+                        (lists_a_cell_that_lacks_it, "workloads key")):
         bench2 = json.loads(json.dumps(bench))
         cfgs = {c["name"]: json.load(open(os.path.join(root, c["file"])))
                 for c in bench2["configs"]}
