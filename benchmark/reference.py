@@ -147,7 +147,7 @@ FORCED_LIMITS = ("router_choice_deficit_max_sigmas",)
 # what `--control` takes, in the order they are computed: the reference
 # itself in the program's place at the program's own precision (bf16: has to
 # come out agreeing) and one step down (int8 matrices: NOT agreeing). See
-# `control_rows`.
+# `control_pass`.
 CONTROLS = ("bf16", "int8")
 
 
@@ -231,8 +231,9 @@ def judge(cases: dict, rows_of: dict, margins_of: dict, served_of: dict,
         pos = len(prompt) + np.arange(len(served))  # position written
         at_page = gap[(pos % PAGE_SIZE == 0) & keep]
         skipped += int((~keep).sum())
-        outliers += int((keep & ((gap > LOGIT_TOLERANCE_SIGMAS)
-                                 | (dlp > LOGPROB_TOLERANCE_MAX_SIGMAS))).sum())
+        outside = int((keep & ((gap > LOGIT_TOLERANCE_SIGMAS)
+                               | (dlp > LOGPROB_TOLERANCE_MAX_SIGMAS))).sum())
+        outliers += outside
         positions += len(served)
         dlp_sum_kept += float(dlp[keep].sum())
         dlp_sum_all += float(dlp.sum())
@@ -242,6 +243,7 @@ def judge(cases: dict, rows_of: dict, margins_of: dict, served_of: dict,
             "finite": finite,
             "exact_matches": int((rows.argmax(-1) == served).sum()),
             "router_near_ties_left_out": int((~keep).sum()),
+            "positions_outside": outside,
             "worst_gap_sigmas": float(gap[keep].max()) if keep.any() else 0.0,
             "worst_gap_sigmas_left_out": float(gap[~keep].max()) if (~keep).any() else None,
             "worst_gap_sigmas_after_page_boundary":
@@ -253,6 +255,8 @@ def judge(cases: dict, rows_of: dict, margins_of: dict, served_of: dict,
             "logprob_diff_sigmas_mean_all_positions": float(dlp.mean()),
             "reference_logit_std": float(rows.std()),
         }
+        if forced:  # the request's own largest deficit (sample_sizes.py reads it)
+            out[name]["router_choice_deficit_max_sigmas"] = float(deficits_of[name].max())
     # name -> [value, limit]: every number `correct` rests on (run.py prints
     # them and adds the client's own count of wrong lengths)
     if routed:  # a flip is counted, not sized: no worst token, no worst position
@@ -267,7 +271,7 @@ def judge(cases: dict, rows_of: dict, margins_of: dict, served_of: dict,
         }
         if forced:
             values["router_choice_deficit_max_sigmas"] = max(
-                float(d.max()) for d in deficits_of.values())
+                r["router_choice_deficit_max_sigmas"] for r in out.values())
         compared = {name: [value, limits[name]] for name, value in values.items()}
     else:  # the worst position, and the worst request's mean
         compared = {
@@ -339,21 +343,37 @@ def load_model(config_file: str, rehearsal: bool) -> tuple:
             cfg_file.get("judge_routing") == "forced")
 
 
-def forward(reference, cfg, params, cases: dict, forced_of: dict | None = None) -> tuple:
+def padded_length(cases: dict) -> int:
+    """One padded shape for all of `cases`: causal, so the tail is inert."""
+    longest = max(len(c["prompt_ids"]) + len(c["served_ids"]) for c in cases.values())
+    return -(-longest // 64) * 64
+
+
+@functools.lru_cache(maxsize=None)
+def jitted_logits(reference, cfg, padded: int):
+    """One jitted function a reference, configuration and length, however
+    many passes call it (a matmul precision in force is part of its key)."""
+    import jax
+
+    return jax.jit(functools.partial(reference.logits, cfg=cfg, n_last=padded))
+
+
+def forward(reference, cfg, params, cases: dict, forced_of: dict | None = None,
+            padded: int | None = None) -> tuple:
     """name -> the logits [tokens, vocab] that predict the served tokens,
     teacher-forced on them; the routing margins there (or None); and, where
     the family's reference gives them, the experts it used at every INPUT
     position (prompt + served[:-1]) and their deficits: {"chosen": [layers,
     inputs, k], "deficits": [layers, inputs]}, else None. `forced_of`: name ->
-    the experts to force, [inputs][layers][k] (a case's `routed_experts`)."""
-    import jax
+    the experts to force, [inputs][layers][k] (a case's `routed_experts`).
+    `padded`: the length every case is padded to (`padded_length` of a larger
+    set, so that its parts share one program)."""
     import jax.numpy as jnp
     import numpy as np
 
-    T = max(len(c["prompt_ids"]) + len(c["served_ids"]) for c in cases.values())
-    T = -(-T // 64) * 64  # one padded shape: causal, so the tail is inert
+    T = padded or padded_length(cases)
     rows_of, margins_of, routing_of = {}, {}, {}
-    fwd = jax.jit(functools.partial(reference.logits, cfg=cfg, n_last=T))
+    fwd = jitted_logits(reference, cfg, T)
     for name, c in cases.items():
         prompt, served = c["prompt_ids"], c["served_ids"]
         seq = prompt + served[:-1]
@@ -376,37 +396,44 @@ def forward(reference, cfg, params, cases: dict, forced_of: dict | None = None) 
     return rows_of, margins_of, routing_of
 
 
-def control_rows(precision: str, reference, cfg, params, cases: dict):
-    """A control: the reference itself in the program's place, at each
-    position of the same prompts and tokens, routing by its own scores. It
-    does not decode: it is judged by the token it puts first and the
-    log-probability it gives that token (`control_choice`). Returns what
-    `forward` does, or None. Call it OUTSIDE the `highest` block.
-
-    bf16: the TPU's default matmul precision, one bf16 pass of the operands
-    with float32 accumulation: what the configuration states and a program
-    computes in. It has to come out AGREEING: a limit that it breaks is not a
-    limit a bf16 program can keep. On a CPU the default is float32 already:
-    None, and nothing is judged.
-    int8: every matrix rounded to int8 (`int8_weights`: IN PLACE, so it comes
-    last, or the weights are built anew after it), the nearest precision below
-    bf16, the step that would tempt a later PR. It has to come out NOT
-    agreeing, or the limits let it through."""
-    import jax
-
-    if precision == "bf16":
-        if jax.devices()[0].platform == "cpu":
-            return None
-        return forward(reference, cfg, params, cases)
-    with jax.default_matmul_precision("highest"):
-        return forward(reference, cfg, int8_weights(params), cases)
-
-
 def control_choice(low_rows: dict) -> dict:
     """What a control is judged by: at each position the token it puts
     first and the log-probability it gives that token."""
     return {name: (low.argmax(-1), low.max(-1) - log_normalizer(low))
             for name, low in low_rows.items()}
+
+
+def control_pass(precision: str, reference, cfg, params, cases: dict, padded: int):
+    """A control: the reference itself in the program's place, at each
+    position of the same prompts and tokens, routing by its own scores. It
+    does not decode: it is judged by the token it puts first and the
+    log-probability it gives that token (`control_choice`). Returns (name -> (token ids,
+    log-probabilities), name -> the experts it used [inputs][layers][k] or
+    None): all that is kept of the pass, so its rows go at once.
+
+    bf16: the TPU's default matmul precision, one bf16 pass of the operands
+    with float32 accumulation: what the configuration states and a program
+    computes in. It has to come out AGREEING: a limit that it breaks is not a
+    limit a bf16 program can keep. (On a CPU the default is float32 already,
+    and `run` judges nothing.)
+    int8: `params` are `int8_weights`' (every matrix rounded to int8, the
+    nearest precision below bf16, the step that would tempt a later PR), at
+    float32 otherwise. It has to come out NOT agreeing, or the limits let it
+    through."""
+    import contextlib
+
+    import jax
+
+    lowered = (contextlib.nullcontext() if precision == "bf16"
+               else jax.default_matmul_precision("highest"))
+    choice, chosen = {}, {}
+    for name, c in cases.items():  # case by case: a case's rows are freed before the next
+        with lowered:
+            rows, _, routing = forward(reference, cfg, params, {name: c}, None, padded)
+        choice.update(control_choice(rows))
+        chosen[name] = (None if routing[name] is None
+                        else routing[name]["chosen"].transpose(1, 0, 2))
+    return choice, chosen
 
 
 NOT_ON_A_CPU = ("the reference's device is a CPU, whose default matmul precision is "
@@ -418,12 +445,21 @@ def run(case_file: str) -> dict:
     under `controls_only` seed -> such a set, each judged on its own, with no
     program's tokens to judge.
 
-    Free routing: one float32 pass, which every verdict is read against.
+    Free routing: one float32 pass a set, which every verdict is read against.
     Forced routing: whoever is judged (the program, a control) brings the
     experts it chose, and the float32 reference is run with THOSE forced: one
     pass for the program's `routed_experts`, one more for each control's own
     choices, so a control is judged against the rows its routing gives, as a
-    program is."""
+    program is.
+
+    Set by set, and a set's rows are freed before the next set's are made: a
+    judged position is a row of the vocabulary in float32 (393 KB at 98,304),
+    some 2 GB a pass of sixteen requests. The int8 control rounds the weights
+    IN PLACE (a second copy of the model does not fit), so its pass over every
+    set comes first and keeps tokens, log-probabilities and choices alone;
+    then the weights are built anew for everything else."""
+    import time
+
     import jax
     import numpy as np
 
@@ -431,75 +467,81 @@ def run(case_file: str) -> dict:
         spec = json.load(f)
     cfg, weights, reference, overrides, forced = load_model(
         spec["config_file"], spec["rehearsal"])
-    params = weights()
     only = spec.get("controls_only", False)
     sets = spec["cases"] if only else {"run": spec["cases"]}
-    flat = {f"{s}/{name}": c for s, cases in sets.items() for name, c in cases.items()}
+    padded = max(padded_length(cases) for cases in sets.values())  # one program for all
+    asked = [c for c in CONTROLS if c in (spec.get("controls") or [])]
+    on_cpu = jax.devices()[0].platform == "cpu"
+    seconds = {s: {} for s in sets}  # set -> what each part of its judging took
 
-    def highest(forced_of=None) -> tuple:
-        with jax.default_matmul_precision("highest"):
-            return forward(reference, cfg, params, flat, forced_of)
-
-    def judged(served_of: dict, against: tuple) -> dict:
-        """seed -> the verdict on that seed's cases, read against one float32
-        pass: (rows, margins, routing)."""
-        rows_of, margins_of, routing_of = against
-        out = {}
-        for s, cases in sets.items():
-            def own(d):
-                return {name: d[f"{s}/{name}"] for name in cases}
-            deficits = ({name: r["deficits"] for name, r in own(routing_of).items()}
-                        if forced else None)
-            out[s] = judge(cases, own(rows_of), own(margins_of), own(served_of),
-                           overrides, deficits)
+    def timed(s: str, part: str, fn, *args):
+        t0 = time.monotonic()
+        out = fn(*args)
+        seconds[s][part] = seconds[s].get(part, 0.0) + time.monotonic() - t0
         return out
 
-    def margin_share(s: str, margins_of: dict):
-        """Whatever epsilon the configuration sets: what its geometry gives."""
-        margins = [margins_of[f"{s}/{name}"] for name in sets[s]]
-        if any(m is None for m in margins):
-            return None
-        return float((np.concatenate(margins) < ROUTER_MARGIN_EPSILON).mean())
+    t0 = time.monotonic()
+    params = weights()
+    jax.block_until_ready(params)
+    weights_s = time.monotonic() - t0
+    int8_pass = {}
+    if "int8" in asked:
+        rounded, params = int8_weights(params), None
+        for s, cases in sets.items():
+            int8_pass[s] = timed(s, "int8_pass_s", control_pass, "int8", reference, cfg,
+                                 rounded, cases, padded)
+        del rounded
+        params = weights()
 
-    against = None
-    if not forced:
-        against = highest()
-    elif not only:
-        missing = [name for name, c in flat.items() if not c.get("routed_experts")]
-        if missing:  # never judged free because its worker sent nothing
-            raise ValueError(f"forced routing, and no `routed_experts` for {missing}")
-        against = highest({name: c["routed_experts"] for name, c in flat.items()})
-    if only:
-        result = {"controls_only": True, "sets": {s: {
-            "positions": sum(len(c["served_ids"]) for c in cases.values()),
-        } for s, cases in sets.items()}}
-    else:
-        result = judged({name: (c["served_ids"], c["served_logprobs"])
-                         for name, c in flat.items()}, against)["run"]
-    asked = spec.get("controls") or []
-    for precision in (c for c in CONTROLS if c in asked):  # int8 last: in place
-        low = control_rows(precision, reference, cfg, params, flat)
-        if low is None:
-            verdicts = {s: {"skipped": NOT_ON_A_CPU} for s in sets}
-        else:
-            choice = control_choice(low[0])
+    def float32(cases: dict, forced_of=None) -> tuple:
+        with jax.default_matmul_precision("highest"):
+            return forward(reference, cfg, params, cases, forced_of, padded)
+
+    def judged(cases: dict, served_of: dict, against: tuple) -> dict:
+        rows_of, margins_of, routing_of = against
+        deficits = ({name: r["deficits"] for name, r in routing_of.items()}
+                    if forced else None)
+        return judge(cases, rows_of, margins_of, served_of, overrides, deficits)
+
+    result = {"controls_only": True, "sets": {}} if only else {}
+    for s, cases in sets.items():
+        against = None if forced else timed(s, "float32_pass_s", float32, cases)
+        one = {"positions": sum(len(c["served_ids"]) for c in cases.values())}
+        if not only:
             if forced:
-                if precision == "int8":  # rounded in place: the float32 pass needs them whole
-                    params = None
-                    params = weights()
-                against = highest({name: r["chosen"].transpose(1, 0, 2)
-                                   for name, r in low[2].items()})
-            del low
-            verdicts = judged(choice, against)
+                missing = [name for name, c in cases.items() if not c.get("routed_experts")]
+                if missing:  # never judged free because its worker sent nothing
+                    raise ValueError(f"forced routing, and no `routed_experts` for {missing}")
+                against = timed(s, "float32_pass_s", float32, cases,
+                                {name: c["routed_experts"] for name, c in cases.items()})
+            one = judged(cases, {name: (c["served_ids"], c["served_logprobs"])
+                                 for name, c in cases.items()}, against)
+        verdicts = {}
+        for precision in asked:
+            if precision == "bf16" and on_cpu:
+                verdicts[precision] = {"skipped": NOT_ON_A_CPU}
+                continue
+            choice, chosen = int8_pass[s] if precision == "int8" else timed(
+                s, "bf16_pass_s", control_pass, "bf16", reference, cfg, params, cases, padded)
+            if forced:
+                against = timed(s, "float32_pass_s", float32, cases, chosen)
+            verdicts[precision] = judged(cases, choice, against)
         if only:
-            for s in sets:
-                result["sets"][s][precision] = verdicts[s]
+            # whatever epsilon the configuration sets: what its geometry gives,
+            # of the last float32 pass made (forced, and no control ran: none)
+            margins = None if against is None else list(against[1].values())
+            one["margin_under_default_epsilon_share"] = (
+                None if margins is None or any(m is None for m in margins)
+                else float((np.concatenate(margins) < ROUTER_MARGIN_EPSILON).mean()))
+            one.update(verdicts)
+        elif verdicts:
+            one["controls"] = verdicts
+        one["seconds"] = dict(seconds[s], weights_s=weights_s, requests=len(cases))
+        if only:
+            result["sets"][s] = one
         else:
-            result.setdefault("controls", {})[precision] = verdicts["run"]
-    if only:
-        for s in sets:  # of the last float32 pass made (forced, and no control ran: none)
-            result["sets"][s]["margin_under_default_epsilon_share"] = (
-                None if against is None else margin_share(s, against[1]))
+            result = one
+        del against
     dev = jax.devices()[0]
     result["reference_device"] = {"platform": dev.platform, "kind": dev.device_kind}
     return result

@@ -23,8 +23,10 @@ when traced). Earlier lines are JSON objects too, one per phase.
                   fixed rate is set, once. Prints no result line.
     --controls-only --config-file <file> --traffic <mix> --seed n [--seed m ...]
                   no discovery, worker or frontend: for each seed four cases
-                  drawn from the mix's own generator, and the reference's two
-                  controls (reference.py) judged on them by the file's limits.
+                  drawn from the mix's own generator (`--requests N`: a sizing
+                  run of N a seed, which sample_sizes.py reads at every
+                  smaller count), and the reference's two controls
+                  (reference.py) judged on them by the file's limits.
                   The table from which a routed family's limits are set, for
                   any configuration file whose `dataclass` the program can
                   build. Under `judge_routing: "forced"` each control is
@@ -60,7 +62,8 @@ import files_check  # noqa: E402
 import layer_metrics  # noqa: E402
 import metrics as e2e  # noqa: E402
 from client import Load, StatsWatch, resend_greedy  # noqa: E402
-from reference import CONTROLS  # noqa: E402  (imports no JAX until it runs)
+from reference import (  # noqa: E402  (imports no JAX until it runs)
+    CONTROLS, ROUTED_REQUEST_MEDIAN_MIN_TOKENS)
 from traffic import Generator, load_mix  # noqa: E402
 from worker_entry import load_config  # noqa: E402
 
@@ -75,6 +78,10 @@ REFERENCE_POSITIONS = 2048
 # correct and bf16 correct; the program's own `--quantize int8` cannot start
 # at these sizes: PERF.md, Open questions.)
 
+# how many of a window's finished requests a run re-sends and judges; and the
+# most that a sizing run (`--controls-only --requests N`) judges a seed
+CHECKED_REQUESTS = 4
+SIZING_REQUESTS_MAX = 16
 # how many requests of the mix a --controls-only run picks its cases from: a
 # closed loop's first requests of each client, about what a window finishes
 CONTROLS_ONLY_PER_CLIENT = 16
@@ -210,23 +217,36 @@ def load_cell(workload: str) -> dict:
     }
 
 
-def pick_checked(finished: list, seed: int) -> list:
+def pick_checked(finished: list, seed: int, count: int = CHECKED_REQUESTS) -> list:
     """Four of the window's own finished requests for the reference: the
     shortest, a median one, the longest that decodes across a page boundary,
     and one at random from the seed; each of at most REFERENCE_POSITIONS
-    positions."""
+    positions. A sizing run's `count` over four: every further one is drawn
+    by the same seeded generator from the requests not picked yet, of
+    ROUTED_REQUEST_MEDIAN_MIN_TOKENS served tokens or more while there are
+    such: the ones whose median the judge holds. A draw depends on the draws
+    before it alone, so the picks are nested: the first M of a seed's N are
+    its picks at M, and the first four are a run's."""
     fit = [r for r in finished
            if len(r.prompt) + r.max_tokens <= REFERENCE_POSITIONS]
     if not fit:
         raise BenchFailure("no finished request of the window fits the reference")
     by_len = sorted(fit, key=lambda r: (len(r.prompt) + r.max_tokens, r.rid))
     crossing = [r for r in by_len if len(r.prompt) % 64 + r.max_tokens > 64]
+    rnd = random.Random(f"{seed}:checked")
     picks = [
         ("shortest", by_len[0]),
         ("median", by_len[len(by_len) // 2]),
         ("crosses_page", (crossing or by_len)[-1]),
-        ("random", random.Random(f"{seed}:checked").choice(by_len)),
+        ("random", rnd.choice(by_len)),
     ]
+    while len(picks) < count:
+        left = [r for r in by_len if all(r is not p for _, p in picks)]
+        if not left:
+            raise BenchFailure(f"{len(by_len)} finished requests of the window fit the "
+                               f"reference, and {count} are to be judged")
+        long_enough = [r for r in left if r.max_tokens >= ROUTED_REQUEST_MEDIAN_MIN_TOKENS]
+        picks.append(("further", rnd.choice(long_enough or left)))
     return [{"why": why, "prompt": r.prompt, "max_tokens": r.max_tokens}
             for why, r in picks]
 
@@ -356,12 +376,15 @@ async def measure(args, cell: dict, mix: dict, cfg: dict, discovery_addr: str,
         if r.t_due < t_w1 and (r.t_end is None or r.t_end >= t_w0)]
     result["stats"] = (stats0, stats1, stats2)
     picks = pick_checked([r for r in window if r.ok], args.seed)
+    t_resend = time.monotonic()
     result["served"] = await resend_greedy(
         discovery_addr, cell["config"], cfg["vocab_size"],
         int(cfg["worker_args"][cfg["worker_args"].index("--max-model-len") + 1]),
         picks, BenchFailure,
         routed=(files_check.routed_geometry(cell["config"], cfg)
                 if cfg.get("judge_routing") == "forced" else None))
+    # after the window and in no metric; README.md has what they cost a run
+    result["counts"]["resend_s"] = time.monotonic() - t_resend
     await watch.close()
     return result
 
@@ -411,29 +434,38 @@ async def sweep(args, cell: dict, mix: dict, http_port: int) -> None:
                       "generator_late_ms_max")}})
 
 
-def controls_only(args, seeds: list) -> int:
-    """Both controls with no program behind them (reference.py:control_rows),
-    for any configuration file: per seed four cases picked as a run picks
-    them, from the mix's own generator; the continuation is seeded random
-    ids, since a control is judged by the token IT puts first at each
-    position. One child builds the weights once and judges every seed."""
-    cfg = load_config(args.config_file, args.rehearsal)
-    mix = load_mix(args.traffic, args.rehearsal)
-    if args.seconds is None:
-        with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-            args.seconds = float(json.load(f)["run_seconds"])
+def control_cases(cfg: dict, mix: dict, seeds: list, count: int, seconds: float) -> dict:
+    """seed -> the cases a control is judged on: `count` requests picked as a
+    run picks them, from the mix's own generator; the continuation is seeded
+    random ids, since a control is judged by the token IT puts first at each
+    position."""
     sets = {}
     for seed in seeds:
         gen = Generator(mix, seed)
         pool = ([r for stream in gen.client_streams()
                  for r in stream[:CONTROLS_ONLY_PER_CLIENT]]
-                if mix["loop"] == "closed" else gen.block(args.seconds, "window"))
+                if mix["loop"] == "closed" else gen.block(seconds, "window"))
         rnd = random.Random(f"{seed}:continuation")
         sets[str(seed)] = {f"{i}.{p['why']}": {
             "prompt_ids": [b + BYTE_TOKENIZER_OFFSET for b in p["prompt"].encode()],
             "served_ids": [rnd.randrange(BYTE_TOKENIZER_OFFSET, cfg["vocab_size"])
                            for _ in range(p["max_tokens"])],
-        } for i, p in enumerate(pick_checked(pool, seed))}
+        } for i, p in enumerate(pick_checked(pool, seed, count))}
+    return sets
+
+
+def controls_only(args, seeds: list) -> int:
+    """Both controls with no program behind them (reference.py:control_pass),
+    for any configuration file: per seed four cases (`--requests`: a sizing
+    run's count; `control_cases`). One child builds the weights and judges
+    seed by seed."""
+    cfg = load_config(args.config_file, args.rehearsal)
+    count = args.requests or CHECKED_REQUESTS
+    mix = load_mix(args.traffic, args.rehearsal)
+    if args.seconds is None:
+        with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+            args.seconds = float(json.load(f)["run_seconds"])
+    sets = control_cases(cfg, mix, seeds, count, args.seconds)
     name = os.path.splitext(os.path.basename(args.config_file))[0]
     out_dir = os.path.join(ROOT, "chiprun_out", "benchmark",
                            f"controls.{name}.{args.traffic}"
@@ -454,7 +486,8 @@ def controls_only(args, seeds: list) -> int:
     shares = []
     for seed, one in ref["sets"].items():
         shares.append(one["margin_under_default_epsilon_share"])
-        line = {"phase": "controls", "seed": int(seed), "positions": one["positions"],
+        line = {"phase": "controls", "seed": int(seed), "requests": count,
+                "positions": one["positions"], "seconds": one["seconds"],
                 "margin_under_default_epsilon_share": shares[-1]}
         for c in CONTROLS:
             low = one[c]
@@ -471,7 +504,8 @@ def controls_only(args, seeds: list) -> int:
         emit(line)
     # lowest and highest over the seeds: what `judge_readings` is written from
     emit({"phase": "controls_summary", "config_file": args.config_file,
-          "traffic": args.traffic, "seeds": seeds, "device": ref["reference_device"],
+          "traffic": args.traffic, "seeds": seeds, "requests": count,
+          "device": ref["reference_device"],
           "judge": cfg.get("judge"),
           "margin_under_default_epsilon_share":
               None if None in shares else [min(shares), max(shares)],
@@ -482,6 +516,12 @@ def controls_only(args, seeds: list) -> int:
     # sound `judge_readings` yet is judged by it all the same, and told so
     # (held at the file's own geometry: a rehearsal's tiny one is not what the
     # limits were set for)
+    if args.requests:
+        # a sizing run: its verdicts were read under limits set at four
+        # requests a run, and sample_sizes.py reads its reference_result.json
+        print(f"--requests {count}: a sizing run, nothing is judged by its exit code",
+              file=sys.stderr)
+        return REHEARSAL_PASSED if args.rehearsal else 0
     files_check.check_judge(args.config_file, load_config(args.config_file, False))
     if args.rehearsal:
         return REHEARSAL_PASSED
@@ -514,6 +554,11 @@ def main() -> int:
     ap.add_argument("--controls-only", action="store_true")
     ap.add_argument("--config-file", help="--controls-only: any configuration file")
     ap.add_argument("--traffic", help="--controls-only: a mix under traffic/")
+    ap.add_argument("--requests", type=int, default=None,
+                    choices=range(CHECKED_REQUESTS, SIZING_REQUESTS_MAX + 1), metavar="N",
+                    help="--controls-only: N requests a seed for a run's four: the one run "
+                         "from which sample_sizes.py reads every count up to N (the picks "
+                         "are nested)")
     ap.add_argument("--break", dest="broken", choices=["token"], default=None,
                     help="a fault planted under the timed path (worker_entry.py): "
                          "the run has to come out NOT correct")
@@ -523,6 +568,8 @@ def main() -> int:
     if args.controls_only != bool(args.config_file and args.traffic) or (
             args.controls_only == bool(args.workload)):
         ap.error("either --workload, or --controls-only with --config-file and --traffic")
+    if args.requests and not args.controls_only:
+        ap.error("--requests is for --controls-only: a run judges four")
     try:  # every run guards the files it is driven by
         files_check.check(ROOT)
         if args.controls_only:
@@ -639,9 +686,10 @@ def main() -> int:
         with open(cases, "w") as f:
             json.dump({"config_file": cell["config_file"], "rehearsal": args.rehearsal,
                        "controls": args.control, "cases": res["served"]}, f)
+        t_ref = time.monotonic()
         ref = run_child([os.path.join(HERE, "reference.py"), cases], env,
                         os.path.join(out_dir, "reference.log"), 600)
-        emit({"phase": "reference", **ref})
+        emit({"phase": "reference", "reference_s": time.monotonic() - t_ref, **ref})
         counts = res["counts"]
         why_not = list(ref["why_not"])
         if counts["wrong_length"]:

@@ -16,7 +16,8 @@ limits of its own is a new file alone (a whole `run.py --rehearsal` in a
 tree of links, some 90 s); a family whose routing is judged FORCED: the
 reference under made-up choices, a wrongly routed token, the client's check
 of a reply's `routed_experts`, the rule for its limit, and such a family
-as new files alone; and when a closed loop's request is due. (PR 21 found that
+as new files alone; a sizing run's picks, nested past a run's four, and
+what it found at 8 a token; and when a closed loop's request is due. (PR 21 found that
 a wrong page stays under the tolerance at tiny widths on the CPU: that
 sabotage is a chip check.)
 """
@@ -393,6 +394,56 @@ def test_many_experts_are_judged_with_their_flips_in():
         assert abs(reference.order_statistics_share(*geometry) - share) < 0.015, geometry
 
 
+def pick_checked_before(finished: list, seed: int) -> list:
+    """run.py:pick_checked as it stood before a sizing run's `count` (PR 38),
+    word for word: what every run is still judged by."""
+    import random
+
+    fit = [r for r in finished if len(r.prompt) + r.max_tokens <= 2048]
+    by_len = sorted(fit, key=lambda r: (len(r.prompt) + r.max_tokens, r.rid))
+    crossing = [r for r in by_len if len(r.prompt) % 64 + r.max_tokens > 64]
+    picks = [
+        ("shortest", by_len[0]),
+        ("median", by_len[len(by_len) // 2]),
+        ("crosses_page", (crossing or by_len)[-1]),
+        ("random", random.Random(f"{seed}:checked").choice(by_len)),
+    ]
+    return [{"why": why, "prompt": r.prompt, "max_tokens": r.max_tokens}
+            for why, r in picks]
+
+
+def test_the_picks_are_todays_four_and_nested_beyond():
+    """A run's picks are the four of before, to the letter; a sizing run's
+    twelve (`--controls-only --requests`) begin with those, the rest are further requests
+    that differ from every other pick, ten or more of the twelve have the 128
+    served tokens from which the judge holds a request's median, and the first
+    M of a seed's picks are its picks at M. A pool without long requests gives
+    what it has; a pool too small for the count fails the run."""
+    import run
+
+    mix = load_mix("decode-closed")
+    for seed in (1811111117, 2**31 + 11):
+        pool = [r for stream in Generator(mix, seed).client_streams()
+                for r in stream[:run.CONTROLS_ONLY_PER_CLIENT]]
+        four = run.pick_checked(pool, seed)
+        assert four == pick_checked_before(pool, seed) and len(four) == 4
+        twelve = run.pick_checked(pool, seed, 12)
+        assert twelve[:4] == four and [p["why"] for p in twelve[4:]] == ["further"] * 8
+        assert len({p["prompt"] for p in twelve[3:]}) == 9  # each a request of its own,
+        assert not {p["prompt"] for p in twelve[4:]} & {p["prompt"] for p in four}  # and new
+        assert sum(p["max_tokens"] >= 128 for p in twelve) >= 10
+        for m in range(4, 17):
+            assert run.pick_checked(pool, seed, m) == run.pick_checked(pool, seed, 16)[:m]
+    short = [r for r in pool if r.max_tokens < 128][:9]
+    assert len(run.pick_checked(short, 3, 9)) == 9
+    try:
+        run.pick_checked(short, 3, 10)
+    except run.BenchFailure as e:
+        assert "9 finished requests" in str(e)
+    else:
+        raise AssertionError("ten picks from nine requests")
+
+
 def told(sound, control, kind="served") -> dict:
     """An entry of `judge_readings`: twelve runs a side, made up."""
     return {"sound": {"lowest": sound[0], "highest": sound[1], "runs": 12, "kind": kind},
@@ -541,6 +592,7 @@ def test_files_check_holds_limits_to_their_readings():
           "router_choice_deficit_max_sigmas: limit 0.14 is over 0.8 of the int8 control's")
     plant("forced_fewer_control_runs", reading(deficit, "control_int8", runs=6),
           "6 runs of the int8 control for 12 sound ones")
+
     shutil.rmtree(out)
     # the first fixture as it stands keeps the rule: under forced routing a
     # number reads three times (PR 38). The second, 8 experts a token, does
@@ -552,6 +604,26 @@ def test_files_check_holds_limits_to_their_readings():
     assert rc == 2 and "logprob_gap_request_median_sigmas: limit" in err and all(
         x not in err for x in ("logprob_gap_pooled_mean", "router_choice_deficit_max_sigmas",
                                "in no number")), err
+    # ... and MORE requests a run make no room either (PR 39's sizing run,
+    # fixtures/many-experts-k8.requests-readings.json: the same 24 seeds read
+    # at 4, 8, 12 and 16 requests a run; at four they are the fixture's own
+    # readings): the largest deficit reads three times at every count, and the
+    # pooled mean never the rule's 1.5625 with a served program's 23% over the
+    # bf16 control on top. So the fixture stands as it is, refused
+    with open(os.path.join(HERE, "fixtures", "many-experts-k8.json")) as f:
+        own = json.load(f)["judge_readings"]
+    with open(os.path.join(HERE, "fixtures", "many-experts-k8.requests-readings.json")) as f:
+        by_count = json.load(f)["by_requests_a_run"]
+    assert sorted(by_count, key=int) == ["4", "8", "12", "16"]
+    for number, entry in by_count["4"].items():
+        for side in ("sound", "control_int8") if "sound" in own[number] else ():
+            for end in ("lowest", "highest"):
+                assert close(entry[side][end], own[number][side][end], 1e-9), (number, side)
+    for count, read in by_count.items():
+        times = {number: entry["control_int8"]["lowest"] / entry["sound"]["highest"]
+                 for number, entry in read.items() if entry["sound"]["highest"]}
+        assert times["router_choice_deficit_max_sigmas"] >= 3.0, (count, times)
+        assert times["logprob_gap_pooled_mean_sigmas"] < 1.23 / 0.8 ** 2, (count, times)
     # ... and the first fixture judged FREE, with PR 36's thirty seeds' readings
     # laid over it, does not: no limit stands between the controls' counts nor
     # between their medians, and nothing reads three times
@@ -715,6 +787,58 @@ def test_controls_only_needs_no_program_to_serve():
     assert len(lines[2]["int8"]["router_choice_deficit_max_sigmas"]) == 2
     assert b"checked 7 int8 logprob_gap_pooled_mean_sigmas: " in p.stderr
     assert b"checked 7 int8 router_choice_deficit_max_sigmas: " in p.stderr
+    # a sizing run of eight requests a seed (`--requests`): sample_sizes.py
+    # reads from its per-request numbers what the run itself judged at eight,
+    # and at four what the run above judged: the picks are nested
+    import sample_sizes
+
+    p = subprocess.run(
+        [sys.executable, os.path.join(HERE, "run.py"), "--controls-only", "--rehearsal",
+         "--requests", "8", "--config-file", os.path.join(HERE, "fixtures", "many-experts.json"),
+         "--traffic", "decode-closed", "--seed", "7", "--seed", "2147483907"],
+        stdout=subprocess.PIPE, stderr=subprocess.PIPE, timeout=600)
+    sized = [json.loads(x) for x in p.stdout.decode().strip().splitlines()]
+    assert p.returncode == 4 and sized[0]["requests"] == 8, (p.returncode, p.stderr[-2000:])
+    result = os.path.join(os.path.dirname(HERE), "chiprun_out", "benchmark",
+                          "controls.many-experts.decode-closed.rehearsal",
+                          "reference_result.json")
+    with open(result) as f:
+        sets = json.load(f)["sets"]
+    for at_four, at_eight in zip(lines[:2], sized[:2]):
+        cases = sets[str(at_eight["seed"])]["int8"]["cases"]
+        for count, one in ((4, at_four), (8, at_eight)):
+            for number, value in sample_sizes.numbers_at(cases, count).items():
+                assert close(value, one["int8"]["checked"][number][0], 1e-6), (count, number)
+    # second_way.py (an experiment: the head judged over a control's top eight
+    # tokens) on the same cases: over the first token alone it reads every
+    # request's own numbers of the sizing run, and its swap makes two sets more
+    p = subprocess.run(
+        [sys.executable, os.path.join(HERE, "second_way.py"), "--rehearsal", "--requests", "8",
+         "--config-file", os.path.join(HERE, "fixtures", "many-experts.json"),
+         "--traffic", "decode-closed", "--seed", "7", "--seed", "2147483907",
+         "--swap", "7", "2147483907"],
+        stdout=subprocess.PIPE, stderr=subprocess.PIPE, timeout=600)
+    assert p.returncode == 0, p.stderr[-2000:]
+    with open(os.path.join(os.path.dirname(result), os.pardir,
+                           "second_way.many-experts.decode-closed.rehearsal",
+                           "requests.json")) as f:
+        second = json.load(f)
+    assert list(second) == ["7", "2147483907", "7>2147483907", "2147483907>7"]
+    for seed in ("7", "2147483907"):
+        for name, own in sets[seed]["int8"]["cases"].items():
+            top = second[seed]["int8"][name]
+            assert close(top["d1_mean"], own["logprob_diff_sigmas_mean_all_positions"], 1e-6)
+            assert close(top["d1_median"], own["logprob_diff_sigmas_median"], 1e-6), name
+            assert top["tokens"] == own["tokens"] and top["d8_mean"] > 0
+    p = subprocess.run(
+        [sys.executable, os.path.join(HERE, "second_way.py"), "--counts", "4", "8", "--read",
+         f.name], stdout=subprocess.PIPE, stderr=subprocess.PIPE, timeout=60)
+    tables = [json.loads(x) for x in p.stdout.decode().strip().splitlines()]
+    assert p.returncode == 0 and len(tables) == 10 and "swap" in tables[-1], p.stderr[-2000:]
+    d1 = next(t for t in tables if t["over"] == "d1" and t["requests_a_run"] == 8
+              and t["number"] == "pooled_mean")
+    assert close(d1["int8"][1], max(x["int8"]["checked"]["logprob_gap_pooled_mean_all_sigmas"][0]
+                                    for x in sized[:2]), 1e-6), d1
 
 
 def tree_of_links(*real: str) -> str:
@@ -778,6 +902,8 @@ def test_limits_of_its_own_are_a_new_file_alone():
     lines = p.stdout.decode().strip().splitlines()
     line = json.loads(lines[-1]) if lines else {}
     assert p.returncode == 4 and line.get("reference_agrees"), (p.returncode, lines[-3:])
+    phases = {x["phase"]: x for x in map(json.loads, lines[:-1])}  # what judging cost
+    assert phases["window"]["resend_s"] > 0 and phases["reference"]["reference_s"] > 0
     limits = {k: lim for k, (_, lim) in line["checked"].items()}
     assert limits == {**{k: v for k, v in cfg["judge"].items()
                          if k != "router_margin_epsilon"}, "wrong_length_requests": 0}, limits
