@@ -54,28 +54,33 @@ def bucket_for(n: int, buckets: Sequence[int]) -> int:
 # The mixed step's token axis has at most this many buckets, whatever the
 # configuration: its family of programs is compiled together at the first
 # mixed step (engine._prime_mixed_family), lean and variant, so each bucket
-# is two full-depth compiles of start-up (8 to 25 s apiece cold on a v5e).
-# A fourth would serve only packs under an eighth of mixed_max_tokens, at
-# most a few ms a step on a dense model and nothing on a MoE one.
-MIXED_TOKEN_BUCKETS_MAX = 3
+# is two full-depth programs of start-up (25-30 s apiece cold at 16 layers
+# on a v5e, 4-5 s from the cache; a family's members side by side).
+# A dense layer multiplies every slot of the bucket, so a bucket twice too
+# large doubles the step where the step is over the ridge: at 16 layers of
+# Mistral-7B, 36 ms for 1,024 slots beside a weight stream of 9 (PERF.md,
+# PR 40). Four buckets reach from the floor to the default
+# mixed_max_tokens.
+MIXED_TOKEN_BUCKETS_MAX = 4
+# Under about this many rows a step is bound by the weights it streams, not
+# by what it multiplies (a v5e's ridge is 197 TFLOP/s / 819 GB/s = 240
+# rows), and moe._takes_grouped switches to the capacity einsum under
+# moe.GROUPED_MIN_TOKENS, the same number: nothing smaller is wanted.
+MIXED_TOKEN_BUCKET_FLOOR = 256
 
 
-def mixed_token_buckets(config, align: int) -> tuple:
+def mixed_token_buckets(config) -> tuple:
     """The flat-token buckets of the mixed step, from the configuration
-    alone: powers of two from a floor up to `mixed_max_tokens` (floored to
-    the packer's alignment, as plan_mixed's budget is). The floor is the
-    smallest power of two that holds a full decode batch (every lane's
-    rows, each padded to `align`) beside as much again of prompt, raised
-    where needed so that at most MIXED_TOKEN_BUCKETS_MAX buckets are left.
-    A step's cost hardly grows with its bucket where attention is ragged
-    and the experts multiply routed rows only, so small buckets would buy
-    nothing but programs to compile."""
-    cap = config.mixed_max_tokens - config.mixed_max_tokens % align
-    rows = config.max_num_seqs * (
-        1 + (config.spec_draft_len if config.spec_mode else 0)
-    )
+    alone, counted in REAL tokens (the flat buffer is compact: rows back
+    to back, models/llama.py:ragged_forward): powers of two from
+    MIXED_TOKEN_BUCKET_FLOOR up to `mixed_max_tokens`, which is
+    plan_mixed's budget and the last bucket whatever it is; the floor is
+    raised where needed so that at most MIXED_TOKEN_BUCKETS_MAX buckets
+    are left. A smaller bucket would buy nothing: under the floor the
+    weight stream bounds the step."""
+    cap = config.mixed_max_tokens
     floor = max(
-        2 * next_pow2(rows * align),
+        MIXED_TOKEN_BUCKET_FLOOR,
         next_pow2(cap) >> (MIXED_TOKEN_BUCKETS_MAX - 1),
     )
     buckets = []
@@ -83,6 +88,20 @@ def mixed_token_buckets(config, align: int) -> tuple:
         buckets.append(floor)
         floor *= 2
     return tuple(buckets) + (cap,)
+
+
+def mixed_row_bucket(config) -> int:
+    """The mixed step's ONE row bucket, from the configuration alone: every
+    decode lane's rows (1 + spec_draft_len verify rows under spec) and a
+    prefill batch of chunks, rounded up to whole sublanes. One bucket, so
+    the row axis adds no program; and no power of two, because where the
+    ragged kernel is Pallas every row of the bucket, packed or not, is a q
+    tile of the kernel's grid (models/llama.py:_tiled_layout: some 0.25 ms
+    a step and tile at 16 layers, PERF.md, PR 40)."""
+    rows = config.max_num_seqs * (
+        1 + (config.spec_draft_len if config.spec_mode else 0)
+    ) + config.max_prefill_batch
+    return -(-rows // 8) * 8
 
 
 def table_rungs(max_pages: int) -> tuple:
@@ -121,23 +140,24 @@ BUCKETING_HELPERS = {
     },
     "plan_mixed": {
         "module": "dynamo_tpu/engine/scheduler/policy.py",
-        "bound": "bucket_for(total, mixed_token_buckets(config, align))",
+        "bound": "bucket_for(total, mixed_token_buckets(config))",
         "returns": "MixedPlan with .bucket token dim",
     },
     "mixed_token_buckets": {
         "module": "dynamo_tpu/engine/bucketing.py",
-        "bound": "at most MIXED_TOKEN_BUCKETS_MAX powers of two up to "
-                 "config.mixed_max_tokens",
+        "bound": "at most MIXED_TOKEN_BUCKETS_MAX powers of two from "
+                 "MIXED_TOKEN_BUCKET_FLOOR up to config.mixed_max_tokens",
         "returns": "the mixed step's token buckets",
+    },
+    "mixed_row_bucket": {
+        "module": "dynamo_tpu/engine/bucketing.py",
+        "bound": "config.max_num_seqs * (1 + spec_draft_len) + "
+                 "config.max_prefill_batch, rounded up to 8",
+        "returns": "the mixed step's one row bucket",
     },
     "table_rungs": {
         "module": "dynamo_tpu/engine/bucketing.py",
         "bound": "pow2 ladder clamped to config.max_pages_per_seq",
         "returns": "page-table widths of the mixed step",
-    },
-    "ragged_tile_q": {
-        "module": "dynamo_tpu/ops/pallas_ragged_attention.py",
-        "bound": "dtype-keyed kernel tile constant (8/16/32)",
-        "returns": "mixed-dispatch row alignment unit",
     },
 }

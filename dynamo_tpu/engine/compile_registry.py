@@ -89,12 +89,13 @@ COMPILE_SURFACES = {
         "donate": (1, 2, 12),
         "static": (),
         "axes": {
-            "N": "bucket_for(tokens, mixed_token_buckets(config, align)): "
-                 "at most 3 powers of two from 2 * next_pow2(decode rows * "
-                 "align) up to aligned config.mixed_max_tokens",
-            "R": "next_pow2(config.max_num_seqs * (1 + spec_draft_len if "
-                 "spec_mode else 1) + config.max_prefill_batch) — spec "
-                 "verify rows share the lane row budget",
+            "N": "bucket_for(tokens, mixed_token_buckets(config)): real "
+                 "tokens, at most 4 powers of two from "
+                 "MIXED_TOKEN_BUCKET_FLOOR up to config.mixed_max_tokens",
+            "R": "mixed_row_bucket(config): config.max_num_seqs * (1 + "
+                 "spec_draft_len if spec_mode else 1) + "
+                 "config.max_prefill_batch, rounded up to 8 — spec verify "
+                 "rows share the lane row budget",
             "P": "config.max_pages_per_seq + 1, the ONE width, where the "
                  "ragged kernel is Pallas and R * P * 4 B <= "
                  "MIXED_TABLE_SMEM_BYTES; else table_rungs: "
@@ -113,11 +114,10 @@ COMPILE_SURFACES = {
         "donate": (1, 2, 12),
         "static": (),
         "axes": {
-            "N": "bucket_for(tokens, mixed_token_buckets(config, align)): "
-                 "at most 3 powers of two from 2 * next_pow2(decode rows * "
-                 "align) up to aligned config.mixed_max_tokens",
-            "R": "next_pow2(config.max_num_seqs * (1 + spec_draft_len if "
-                 "spec_mode else 1) + config.max_prefill_batch)",
+            "N": "bucket_for(tokens, mixed_token_buckets(config)): real "
+                 "tokens, at most 4 powers of two from "
+                 "MIXED_TOKEN_BUCKET_FLOOR up to config.mixed_max_tokens",
+            "R": "mixed_row_bucket(config), as mixed_step",
             "P": "as mixed_step: one width under the Pallas ragged "
                  "kernel, else table_rungs",
             "V8": "(vocab_size + 7) // 8 (packed per-row grammar mask; "
@@ -301,7 +301,9 @@ COMPILE_SURFACES = {
         "donate": (),
         "static": ("interpret",),
         "axes": {
-            "N": "caller token bucket (mixed_step N)",
+            "N": "mixed_step's token bucket M laid out to the q tile: "
+                 "M + (ragged_tile_q(dtype) - 1) * R, rounded up to the "
+                 "tile (llama._tiled_layout)",
             "tiles": "N / ragged_tile_q(dtype)",
         },
         "warmup": True,

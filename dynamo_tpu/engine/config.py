@@ -96,8 +96,8 @@ class EngineConfig:
     # page in from the host roster on acquire (LRU eviction of unpinned
     # residents). None = resolve from DYN_LORA_POOL_SLOTS (default 8).
     lora_pool_slots: Optional[int] = None
-    # flat-token budget of one mixed dispatch: decode rows + granted
-    # prefill chunks, in at most three pow2 buckets up to this cap
+    # flat-token budget of one mixed dispatch, in real tokens: decode rows
+    # + granted prefill chunks, in at most four pow2 buckets up to this cap
     # (bucketing.mixed_token_buckets). Bounds the mixed compile-variant
     # space exactly like prefill_buckets bounds prefill's (token bucket x
     # table width, compiled together at first use; the row axis is a

@@ -30,6 +30,7 @@ pipelined; host reads are the expensive unit):
 from __future__ import annotations
 
 import asyncio
+import concurrent.futures
 import logging
 import os
 import time
@@ -61,6 +62,7 @@ from ..runtime.metrics import (
 )
 from .bucketing import (
     bucket_for as _bucket_for,
+    mixed_row_bucket,
     mixed_token_buckets,
     next_pow2 as _next_pow2,
     table_rungs,
@@ -678,7 +680,6 @@ class JaxEngine:
         # speculative rows fuse too (mask / adapter-index operands on the
         # variant program, spec lanes as 1+d one-token verify rows);
         # pp/sp configs keep the split path outright.
-        from ..ops.pallas_ragged_attention import ragged_tile_q
         from ..runtime.config import env_bool
 
         self._mixed_enabled = (
@@ -721,20 +722,11 @@ class JaxEngine:
         self.morphs_rolled_back = 0
         self.morph_drained_sessions = 0
         self.morph_last_duration_s = 0.0
-        # row-start alignment of the flat packer: the Pallas ragged kernel
-        # needs q-tile-aligned rows; the XLA reference packs dense
-        self._mixed_align = (
-            ragged_tile_q(c.dtype)
-            if self.attention_impl["ragged"] == "pallas" else 1
-        )
         # ONE fixed row bucket: the row axis only sizes scalar operands
         # (tables, sampling state), so a single padded variant is free —
         # compile variants stay (token bucket x table bucket). Under spec
         # every decode lane may pack 1 + spec_draft_len verify rows.
-        rows_per_lane = 1 + (config.spec_draft_len if config.spec_mode else 0)
-        self._mixed_row_bucket = _next_pow2(
-            config.max_num_seqs * rows_per_lane + config.max_prefill_batch
-        )
+        self._mixed_row_bucket = mixed_row_bucket(config)
         # the lean mixed_step family: token buckets x table widths, closed
         # and small enough to compile together at the first mixed step
         # (_prime_mixed_family). The Pallas ragged kernel's work follows
@@ -742,9 +734,7 @@ class JaxEngine:
         # scalar-prefetch operand), so there ONE width serves every
         # context — while R_pad x P x 4 B stays within the budget below; the
         # XLA reference gathers P pages a row, so it keeps the pow2 rungs.
-        self._mixed_token_buckets = mixed_token_buckets(
-            config, self._mixed_align
-        )
+        self._mixed_token_buckets = mixed_token_buckets(config)
         one_width = (
             self.attention_impl["ragged"] == "pallas"
             and self._mixed_row_bucket * (config.max_pages_per_seq + 1) * 4
@@ -849,8 +839,6 @@ class JaxEngine:
         # (which can take tens of seconds) never stall the asyncio event
         # loop; host reads run on a separate fetch thread so a blocking
         # device_get (~1 RTT) never delays the next dispatch
-        import concurrent.futures
-
         self._device_exec = concurrent.futures.ThreadPoolExecutor(
             max_workers=1, thread_name_prefix="jax-step"
         )
@@ -2428,6 +2416,10 @@ class JaxEngine:
         out["mixed_family_compiled"] = int(self._mixed_step._cache_size())
         out["expert_rows_routed"] = self.expert_rows_routed
         out["expert_rows_computed"] = self.expert_rows_computed
+        # what the mixed steps' dense layers multiplied: real tokens, and
+        # the slots of the token buckets they ran in
+        out["mixed_real_tokens"] = self.mixed_real_tokens
+        out["mixed_padded_tokens"] = self.mixed_padded_tokens
         out["mixed_padding_frac"] = round(
             1.0 - self.mixed_real_tokens / self.mixed_padded_tokens, 4
         ) if self.mixed_padded_tokens else 0.0
@@ -2829,17 +2821,11 @@ class JaxEngine:
         )
         return first
 
-    def _dev_mixed(self, p: dict):
-        """One mixed step from its operands as the "mixed" broadcast carries
-        them (_blank_mixed_pack's keys). A pack that carries "row_lane" is
-        piped (_dispatch_mixed): its decode rows read the decode carry on
-        the device and its samples are written back into it (carry_write),
-        so it runs behind whatever is in flight and the next entry behind
-        it. A pack that carries "prime" is a priming call
-        (_prime_mixed_family): it runs on a copy of the sampling key and
-        leaves `_rng` and the carry as they were, so that seeded streams
-        do not depend on when the family was compiled."""
-        prime = "prime" in p
+    def _mixed_operands(self, p: dict):
+        """(operands, carry) of one mixed step from its pack as the "mixed"
+        broadcast carries it (_blank_mixed_pack's keys): mixed_step's, with
+        the decode carry it reads by lane, for a plain pack; for a pack
+        with a mask mixed_step_variant's, and None."""
         pens = p["pens"]
         samp = SamplingParams(
             temperature=jnp.asarray(p["temps"]),
@@ -2863,35 +2849,11 @@ class JaxEngine:
             jnp.asarray(p["ctx_lens"]),
             jnp.asarray(p["last_flat"]),
             samp,
-            jnp.copy(self._rng) if prime else self._rng,  # donated
+            # donated: a priming call runs on a copy
+            jnp.copy(self._rng) if "prime" in p else self._rng,
             jnp.asarray(p["pen_rows"]),
         )
-        if "mask" not in p:
-            # plain pack: the lean program. A drained pack reads no lane
-            # (its map is all -1), so any carry of the right shape serves
-            piped = "row_lane" in p
-            if self._carry is not None:
-                carry = (*self._carry, self._pen_dev)
-            else:  # before the first reset
-                B = self.config.max_num_seqs
-                lanes = jnp.zeros((B,), jnp.int32)
-                carry = (lanes, lanes, lanes, jnp.full(
-                    (B, self.config.penalty_window), -1, jnp.int32))
-            none = np.full(p["row_starts"].shape, -1, np.int32)
-            first, self.kv_k, self.kv_v, rng = self._mixed_step(
-                *args, jnp.asarray(p.get("row_lane", none)),
-                carry[0], carry[3],
-            )
-            if piped or prime:
-                # priming compiles the write-back beside the family, on
-                # a map that names no lane, and drops what it returns
-                wrote = self._carry_write(
-                    *carry, first[0], jnp.asarray(p.get("w_lane", none)),
-                    jnp.asarray(p.get("w_pos", none)),
-                )
-                if piped:
-                    self._carry, self._pen_dev = wrote[:3], wrote[3]
-        else:
+        if "mask" in p:
             # variant pack: the mask operand is always present (all-ones
             # for maskless packs — an exact no-op), the LoRA operand rides
             # iff adapters are registered (idx 0 rows are the base no-op),
@@ -2900,12 +2862,67 @@ class JaxEngine:
                 self._lora_operand(p["lora_idx"])
                 if self._lora is not None and "lora_idx" in p else None
             )
-            first, self.kv_k, self.kv_v, rng = self._mixed_step_variant(
-                *args, jnp.asarray(p["mask"]), lora
+            return (*args, jnp.asarray(p["mask"]), lora), None
+        # plain pack: the lean program. A drained pack reads no lane
+        # (its map is all -1), so any carry of the right shape serves
+        if self._carry is not None:
+            carry = (*self._carry, self._pen_dev)
+        else:  # before the first reset
+            B = self.config.max_num_seqs
+            lanes = jnp.zeros((B,), jnp.int32)
+            carry = (lanes, lanes, lanes, jnp.full(
+                (B, self.config.penalty_window), -1, jnp.int32))
+        none = np.full_like(p["row_lens"], -1)
+        row_lane = jnp.asarray(p.get("row_lane", none))
+        return (*args, row_lane, carry[0], carry[3]), carry
+
+    def _dev_mixed(self, p: dict):
+        """One mixed step from its operands as the "mixed" broadcast carries
+        them (_blank_mixed_pack's keys). A pack that carries "row_lane" is
+        piped (_dispatch_mixed): its decode rows read the decode carry on
+        the device and its samples are written back into it (carry_write),
+        so it runs behind whatever is in flight and the next entry behind
+        it. A pack that carries "prime" is a priming call
+        (_prime_mixed_family): it runs on a copy of the sampling key and
+        leaves `_rng` and the carry as they were, so that seeded streams
+        do not depend on when the family was compiled."""
+        prime, piped = "prime" in p, "row_lane" in p
+        args, carry = self._mixed_operands(p)
+        if carry is None:
+            first, self.kv_k, self.kv_v, rng = self._mixed_step_variant(*args)
+        else:
+            first, self.kv_k, self.kv_v, rng = self._mixed_step(*args)
+        if carry is not None and (piped or prime):
+            # priming compiles the write-back beside the family, on
+            # a map that names no lane, and drops what it returns
+            none = np.full_like(p["row_lens"], -1)
+            wrote = self._carry_write(
+                *carry, first[0], jnp.asarray(p.get("w_lane", none)),
+                jnp.asarray(p.get("w_pos", none)),
             )
+            if piped:
+                self._carry, self._pen_dev = wrote[:3], wrote[3]
         if not prime:
             self._rng = rng
         return first
+
+    def _compile_mixed_side_by_side(self, packs: List[dict]):
+        """Compile the programs of these packs, or load them from the
+        compilation cache, all at once: seconds a member either way (25-30
+        s cold, 4-5 s from the cache at 16 layers), nearly all of it the
+        compiler's and the runtime's own work, which holds no interpreter
+        lock. The executables stay with the jit's lowering, so the
+        members' first calls find them and compile nothing."""
+        def compile_one(pack):
+            args, carry = self._mixed_operands(pack)
+            program = (
+                self._mixed_step_variant if carry is None else self._mixed_step
+            )
+            program.lower(*args).compile()
+
+        with concurrent.futures.ThreadPoolExecutor(len(packs)) as pool:
+            for done in [pool.submit(compile_one, pk) for pk in packs]:
+                done.result()
 
     def _dev_prefill_mm(self, toks, positions, tables, ctx_lens, last_idx,
                         temps, top_ks, top_ps, seeds, pens, pen_rows,
@@ -4652,14 +4669,14 @@ class JaxEngine:
         planner declines.
 
         Shapes are a closed family, compiled together at its first use
-        (_prime_mixed_family): at most three flat-token buckets
+        (_prime_mixed_family): at most four flat-token buckets
         (bucketing.mixed_token_buckets), ONE fixed row bucket
         (self._mixed_row_bucket — the row axis only sizes scalar
         operands), and one table width under the Pallas ragged kernel
-        (pow2 rungs on the XLA reference path). Row starts are aligned to
-        the Pallas ragged kernel's q tile exactly when
-        ops._pallas_eligible says the kernel will run; on the XLA
-        reference path the packer is dense."""
+        (pow2 rungs on the XLA reference path). The flat buffer is
+        compact, rows back to back: a bucket counts real tokens, and the
+        q-tile layout the Pallas ragged kernel needs is the forward's
+        own, for q alone (models/llama.py:ragged_forward)."""
         cfg = self.config
         self._mixed_wait_drain = False
         if not self._mixed_enabled:
@@ -4704,10 +4721,8 @@ class JaxEngine:
         if not cands:
             return False
         cands = self.scheduler.order(cands)
-        align = self._mixed_align
         plan = self.scheduler.plan_mixed(
-            cands, n_decode=len(active), align=align,
-            n_spec_rows=n_spec_rows,
+            cands, n_decode=len(active), n_spec_rows=n_spec_rows,
         )
         if plan is None:
             return False  # nothing fuses (e.g. decode lanes fill the
@@ -4795,9 +4810,6 @@ class JaxEngine:
             if id(s) not in granted_slots:
                 s.sched_skips += 1
 
-        def aligned(n: int) -> int:
-            return -(-n // align) * align
-
         # recompute the decode row count against the SURVIVING active set
         # (page growth can preempt lanes out from under the plan)
         spec_lanes = {
@@ -4805,16 +4817,14 @@ class JaxEngine:
             if cfg.spec_mode and self.slots[i].guided_fsm is None
         }
         n_rows_decode = len(active) + d * len(spec_lanes)
-        total = sum(aligned(ch) for _, ch in chosen) \
-            + aligned(1) * n_rows_decode
+        total = sum(ch for _, ch in chosen) + n_rows_decode
         # pure-plain and pure-spec packs keep the LEAN program —
         # byte-identical operands to the split path; any guided or lora
         # row takes the variant (all-ones mask rows and adapter index 0
         # are exact no-ops for the rows beside it)
         variant, ctx_pages = pack_shape(chosen, active)
         # total <= the largest bucket by construction: it is plan_mixed's
-        # budget, mixed_max_tokens floored to the alignment, so that the
-        # Pallas kernel's N % tile_q assert holds for every bucket
+        # budget, mixed_max_tokens
         payload = self._blank_mixed_pack(total, ctx_pages, variant)
         if pipes:
             # row -> lane, for the read of the carry and for the write
@@ -4848,7 +4858,7 @@ class JaxEngine:
             ctx_lens[row] = start
             toks[off : off + chunk] = s.kv_prompt[start : start + chunk]
             positions[off : off + chunk] = np.arange(start, start + chunk)
-            row_ids[off : off + aligned(chunk)] = row
+            row_ids[off : off + chunk] = row
             tables[row, :ctx_pages] = self.page_tables[s.slot_idx][:ctx_pages]
             last_flat[row] = off + chunk - 1
             temps[row] = s.temperature
@@ -4876,7 +4886,7 @@ class JaxEngine:
                 # into the lane's carry at the prompt's length
                 payload["w_lane"][row] = s.slot_idx
                 payload["w_pos"][row] = len(s.kv_prompt)
-            off += aligned(chunk)
+            off += chunk
             row += 1
         for i in active:
             s = self.slots[i]
@@ -4897,7 +4907,7 @@ class JaxEngine:
                 ctx_lens[row] = L - 1 + j
                 toks[off] = tk  # piped: the device's, from the carry
                 positions[off] = L - 1 + j
-                row_ids[off : off + aligned(1)] = row
+                row_ids[off] = row
                 tables[row, :ctx_pages] = self.page_tables[i][:ctx_pages]
                 last_flat[row] = off
                 temps[row] = self.temps[i]
@@ -4931,7 +4941,7 @@ class JaxEngine:
                         )
                 # spec rows keep default pens: penalties/logprobs are
                 # rejected under spec_mode at admission
-                off += aligned(1)
+                off += 1
                 row += 1
             if spec_lane:
                 spec_rows.append((first_row, i, s, draft))
@@ -5036,7 +5046,7 @@ class JaxEngine:
 
     def _blank_mixed_pack(self, tokens: int, pages: int,
                           variant: bool) -> dict:
-        """The operands of one mixed step that holds `tokens` flat slots
+        """The operands of one mixed step that holds `tokens` real tokens
         and contexts of `pages` pages, with no row packed yet, keyed as
         the "mixed" broadcast carries them: every slot padding (position
         at the scratch tail, owned by the last row), every row empty
@@ -5084,7 +5094,9 @@ class JaxEngine:
         so this is the whole family and no later pack meets a shape the
         jit cache lacks; the XLA reference keeps its rungs and is primed a
         rung at a time (its programs cost with their width, and all of
-        them at once would hold a tp=4 worker for minutes). Each member
+        them at once would hold a tp=4 worker for minutes). The members
+        are compiled side by side first (_compile_mixed_side_by_side: four
+        buckets cost a start what one does). Each member then
         runs once on a pack whose one row is one token at position 0 of
         the scratch page (an all-padding pack would hand the grouped
         expert matmul an empty grid): the donated pool is written only in
@@ -5092,17 +5104,31 @@ class JaxEngine:
         replay it; untimed, so the cost model learns no compile time; on a
         copy of `_rng` (_dev_mixed)."""
         self._mixed_primed.add((variant, ctx_pages))
-        for N_pad in list(self._mixed_token_buckets):
+        packs = []
+        for N_pad in self._mixed_token_buckets:
             pack = self._blank_mixed_pack(N_pad, ctx_pages, variant)
             pack["positions"][0] = 0
             pack["row_ids"][0] = 0
             pack["row_starts"][0] = 0
             pack["row_lens"][0] = 1
             pack["prime"] = np.ones((1,), np.int32)
+            packs.append(pack)
+        t0 = time.monotonic()
+        await self._run_on_device(
+            partial(self._compile_mixed_side_by_side, packs)
+        )
+        t1 = time.monotonic()
+        for pack in packs:
             self._bcast("mixed", pack)
             await self._run_on_device(
                 partial(self._dev_mixed, pack), tag="mixed_prime"
             )
+        logger.info(
+            "mixed family primed: %d %s programs of %d pages, compiled in "
+            "%.1f s, first runs %.1f s", len(packs),
+            "variant" if variant else "lean", ctx_pages, t1 - t0,
+            time.monotonic() - t1,
+        )
 
     def _count_expert_rows(self, T: int, real: int, steps: int = 1):
         """Account one dispatch of `steps` forward passes over T token

@@ -337,19 +337,18 @@ class StepPlanner:
         self,
         cands: List,
         n_decode: int,
-        align: int = 1,
         now: Optional[float] = None,
         n_spec_rows: int = 0,
     ) -> Optional[MixedPlan]:
         """Shape the unified mixed dispatch: greedily grant prefill chunks
-        (planner order, each padded to the packer's row alignment) into
-        the flat-token budget left beside `n_decode` one-token decode
-        rows. `n_spec_rows` reserves EXTRA one-token rows for speculative
-        draft verification riding the same buffer (engine spec fusion:
-        each spec-eligible lane packs its current token plus d drafts).
-        Returns None when nothing fits — the engine falls back to
-        the split path for this step. `cands` must already be in planner
-        order.
+        (planner order) into the flat-token budget left beside `n_decode`
+        one-token decode rows. The budget, `mixed_max_tokens`, counts real
+        tokens: the flat buffer is compact. `n_spec_rows` reserves EXTRA
+        one-token rows for speculative draft verification riding the same
+        buffer (engine spec fusion: each spec-eligible lane packs its
+        current token plus d drafts). Returns None when nothing fits — the
+        engine falls back to the split path for this step. `cands` must
+        already be in planner order.
 
         Under sla with an ITL target, the mixed step IS the decode step
         (it advances every decode lane one token), so its predicted wall
@@ -357,8 +356,8 @@ class StepPlanner:
         halved until the CostModel("mixed", bucket, rows) estimate fits or
         the pack is down to the smallest token bucket (a step is priced by
         its bucket, so smaller chunks buy nothing there), floored at one
-        aligned unit per chunk (a mixed step never defers outright —
-        serving the decode lanes is the point).
+        token per chunk (a mixed step never defers outright — serving the
+        decode lanes is the point).
 
         Pure: no counters or decision records — the engine may still
         abandon the plan (pipeline in flight, page-growth preemption);
@@ -367,14 +366,8 @@ class StepPlanner:
         if now is None:
             now = time.monotonic()
 
-        def aligned(n: int) -> int:
-            return -(-n // align) * align
-
-        # floor the budget to the packer alignment: every granted span is
-        # a multiple of `align`, so an aligned budget keeps `space`
-        # aligned throughout and no grant can overpack the flat buffer
-        budget = cfg.mixed_max_tokens - cfg.mixed_max_tokens % align
-        dec_tokens = aligned(1) * (n_decode + n_spec_rows)
+        budget = cfg.mixed_max_tokens
+        dec_tokens = n_decode + n_spec_rows
         if dec_tokens >= budget:
             return None  # too many decode lanes to fuse a chunk beside
 
@@ -388,13 +381,13 @@ class StepPlanner:
                 break
             chosen.append(s)
             chunks.append(take)
-            space -= aligned(take)
+            space -= take
 
         if not chosen:
             return None
 
         total = budget - space
-        buckets = mixed_token_buckets(cfg, align)
+        buckets = mixed_token_buckets(cfg)
         bucket = bucket_for(total, buckets)
         rows = len(chosen) + n_decode + n_spec_rows
         reason = "mixed"
@@ -406,13 +399,13 @@ class StepPlanner:
         ):
             itl_budget = self.sla.itl_target_ms / 1000.0
             while (
-                t is not None and t > itl_budget and max(chunks) > align
+                t is not None and t > itl_budget and max(chunks) > 1
                 and bucket > buckets[0]  # below it no program is cheaper
             ):
-                # halve the biggest chunk (floored at one aligned unit)
+                # halve the biggest chunk (floored at one token)
                 i = max(range(len(chunks)), key=lambda j: chunks[j])
-                chunks[i] = max(align, chunks[i] // 2)
-                total = dec_tokens + sum(aligned(ch) for ch in chunks)
+                chunks[i] = max(1, chunks[i] // 2)
+                total = dec_tokens + sum(chunks)
                 bucket = bucket_for(total, buckets)
                 t = self.cost.predict("mixed", bucket, rows)
                 reason = "mixed-shrunk"

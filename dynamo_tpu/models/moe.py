@@ -427,9 +427,9 @@ def prefill_forward(
 def ragged_forward(
     params: Dict[str, Any],
     config: MoeConfig,
-    tokens: jax.Array,  # [N] flat packed mixed prefill+decode buffer
-    positions: jax.Array,  # [N]
-    row_ids: jax.Array,  # [N]
+    tokens: jax.Array,  # [M] flat packed mixed prefill+decode buffer
+    positions: jax.Array,  # [M]
+    row_ids: jax.Array,  # [M]
     kv_k: jax.Array,
     kv_v: jax.Array,
     page_tables: jax.Array,  # [R, max_pages]
@@ -441,12 +441,10 @@ def ragged_forward(
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Unified mixed-step forward (engine `_dispatch_mixed`), MoE MLP —
     the flat buffer is already [tokens, H], exactly the shape expert
-    dispatch wants. A slot is real when it lies inside its row's
-    [start, start + len): the packer's padding (row tails up to the q
-    tile, the buffer's tail up to its bucket) is routed to no expert."""
-    slot = jnp.arange(tokens.shape[0], dtype=jnp.int32)
-    start = row_starts[row_ids]
-    valid = (slot >= start) & (slot < start + row_lens[row_ids])
+    dispatch wants. The buffer is compact (llama.ragged_forward): the
+    step's real tokens lie back to back from slot 0, and the bucket's
+    padding behind them is routed to no expert."""
+    valid = jnp.arange(tokens.shape[0], dtype=jnp.int32) < row_lens.sum()
     return llama.ragged_forward(
         _whole_expert_stacks(params), config, tokens, positions, row_ids,
         kv_k, kv_v, page_tables, row_starts, row_lens, ctx_lens, last_flat,

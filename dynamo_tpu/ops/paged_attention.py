@@ -313,9 +313,9 @@ def ragged_attention(
     Dispatch: on TPU the Pallas ragged kernel streams only each row's real
     context pages; elsewhere the XLA reference path gathers the (engine-
     bounded) tables. The Pallas path additionally requires row starts
-    aligned to `ragged_tile_q(q.dtype)` — the engine's mixed packer aligns
-    exactly when this gate says the kernel will run
-    (engine/engine.py:_dispatch_mixed)."""
+    aligned to `ragged_tile(...)` below — the model's ragged forward lays
+    q out so, between the q projection and this call alone, exactly when
+    this gate says the kernel will run (models/llama.py:ragged_forward)."""
     if _pallas_eligible(q.shape[-1], is_quant_kv(kv_k_layer.pool)):
         from .pallas_ragged_attention import ragged_paged_attention_pallas
 
@@ -326,3 +326,15 @@ def ragged_attention(
     return ragged_attention_reference(
         q, kv_k_layer, kv_v_layer, page_tables, row_starts, row_lens, ctx_lens
     )
+
+
+def ragged_tile(dtype, head_dim: int, quantized: bool = False) -> int:
+    """What `ragged_attention` needs every row of its flat axis to start
+    on a multiple of, decided at trace time by the same gate: the Pallas
+    ragged kernel's q tile (its tiles may not straddle rows) where the
+    kernel will run, else 1 (the XLA reference takes rows back to back)."""
+    if _pallas_eligible(head_dim, quantized):
+        from .pallas_ragged_attention import ragged_tile_q
+
+        return ragged_tile_q(dtype)
+    return 1

@@ -372,46 +372,61 @@ def test_lora_pool_pinned_full_refuses_and_releases(params, adapters):
 
 
 @pytest.mark.parametrize("spec", [False, True], ids=["plain", "spec"])
-@pytest.mark.parametrize("align", [1, 8, 16])
+@pytest.mark.parametrize("fill", ["one_token", "half", "budget"])
 @pytest.mark.parametrize("max_tokens", [48, 256, 2048, 2050, 8192])
 @pytest.mark.parametrize("seqs", [1, 8, 32, 256])
 def test_token_buckets_are_few_and_hold_what_the_planner_packs(
-    seqs, max_tokens, align, spec
+    seqs, max_tokens, fill, spec
 ):
-    """Over a grid of EngineConfigs: at most three buckets, ascending,
-    every one a multiple of the packer's alignment, the last one
-    plan_mixed's budget (mixed_max_tokens floored to the alignment), the
-    first one holding a full decode batch twice over unless the budget is
-    smaller."""
+    """Over a grid of EngineConfigs: at most four buckets, ascending, in
+    REAL tokens (no alignment in them: the flat buffer is compact), the
+    last one plan_mixed's budget (mixed_max_tokens), the first one the
+    weight stream's floor unless the budget is smaller, powers of two
+    between; and a pack of `fill` tokens lands in the smallest that
+    holds it, never over twice its size above the floor."""
     from dynamo_tpu.engine.bucketing import (
+        MIXED_TOKEN_BUCKET_FLOOR,
         MIXED_TOKEN_BUCKETS_MAX,
+        bucket_for,
         mixed_token_buckets,
-        next_pow2,
     )
 
     kw = dict(spec_mode="ngram", spec_draft_len=3) if spec else {}
     cfg = EngineConfig(max_num_seqs=seqs, mixed_max_tokens=max_tokens, **kw)
-    buckets = mixed_token_buckets(cfg, align)
-    budget = max_tokens - max_tokens % align
-    rows = seqs * (4 if spec else 1)
-    assert 1 <= len(buckets) <= MIXED_TOKEN_BUCKETS_MAX <= 4
+    buckets = mixed_token_buckets(cfg)
+    assert 1 <= len(buckets) <= MIXED_TOKEN_BUCKETS_MAX == 4
     assert list(buckets) == sorted(set(buckets))
-    assert all(b % align == 0 for b in buckets)
-    assert buckets[-1] == budget
-    assert buckets[0] >= min(budget, 2 * next_pow2(rows * align))
+    assert buckets[-1] == max_tokens
+    assert buckets[0] >= min(max_tokens, MIXED_TOKEN_BUCKET_FLOOR)
+    assert all(b & (b - 1) == 0 for b in buckets[:-1])
+    total = {"one_token": 1, "half": max_tokens // 2, "budget": max_tokens}[fill]
+    bucket = bucket_for(total, buckets)
+    assert bucket >= total and bucket in buckets
+    assert all(b < total for b in buckets if b < bucket)
+    assert bucket == buckets[0] or bucket < 2 * total
 
 
-def test_the_cells_family_is_two_token_buckets():
-    """The benchmark's cell: 32 lanes, bf16 (rows aligned to 16), the
-    default mixed_max_tokens."""
+@pytest.mark.parametrize("max_tokens,ladder", [
+    (2048, (256, 512, 1024, 2048)),  # the benchmark's cells: the default
+    (4096, (512, 1024, 2048, 4096)),  # a floor raised to keep four
+    (1024, (256, 512, 1024)),
+    (300, (256, 300)),
+    (256, (256,)),
+    (100, (100,)),
+])
+def test_the_ladder_counts_real_tokens(max_tokens, ladder):
+    """The cells' family (32 lanes, bf16, the default mixed_max_tokens) is
+    four token buckets from the weight stream's floor; the q tile is not
+    in them (it was: (1024, 2048) while rows were packed 16 apart)."""
     from dynamo_tpu.engine.bucketing import mixed_token_buckets
 
-    cfg = EngineConfig(max_num_seqs=32, max_model_len=4096)
-    assert mixed_token_buckets(cfg, 16) == (1024, 2048)
+    cfg = EngineConfig(max_num_seqs=32, max_model_len=4096,
+                       mixed_max_tokens=max_tokens)
+    assert mixed_token_buckets(cfg) == ladder
 
 
 FAMILY_KW = dict(max_num_seqs=4, max_model_len=128, num_pages=96,
-                 max_prefill_batch=2, mixed_max_tokens=256)
+                 max_prefill_batch=2, mixed_max_tokens=300)
 
 
 def _one_width(eng):

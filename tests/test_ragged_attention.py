@@ -481,3 +481,151 @@ def test_pallas_eligible_gate_is_shared():
         assert not ref_ops._pallas_eligible(128)
     finally:
         os.environ.pop("DYNAMO_TPU_PAGED_ATTN", None)
+
+
+# --------------------------------------------------------------------- #
+# the model's ragged forward: the flat axis is compact, and the q-tile
+# layout lives between the q projection and the attention call alone
+# --------------------------------------------------------------------- #
+
+FWD_PAGE = 8
+
+
+def _compact_pack(cfg, rows, M, R_pad, shared=(), seed=0):
+    """The engine packer's operands for `ragged_forward`: rows =
+    [(row_len, ctx_len)] back to back from slot 0 of an [M] buffer, the
+    rest of it padding (scratch-tail position, owned by the last row),
+    R_pad - len(rows) empty rows starting past the buffer, tables with
+    the scratch column last, and a pool whose pages hold random history.
+    `shared` = groups of rows that are one lane's verify staircase: they
+    share the leader's table row, as the engine packs spec lanes."""
+    rng = np.random.RandomState(seed)
+    P = max(-(-(ctx + n) // FWD_PAGE) for n, ctx in rows) + 1
+    pages = 1 + len(rows) * P  # page 0 = scratch
+    pool = (cfg.num_layers, pages, FWD_PAGE, cfg.num_kv_heads * cfg.head_dim)
+    kv_k = jnp.asarray(rng.randn(*pool), cfg.dtype)
+    kv_v = jnp.asarray(rng.randn(*pool), cfg.dtype)
+    tables = np.zeros((R_pad, P + 1), np.int32)
+    tables[: len(rows), :P] = np.arange(1, pages).reshape(len(rows), P)
+    for group in shared:
+        tables[group[1:]] = tables[group[0]]
+    tokens = np.zeros(M, np.int32)
+    positions = np.full(M, (P + 1) * FWD_PAGE - 1, np.int32)
+    row_ids = np.full(M, R_pad - 1, np.int32)
+    row_starts = np.full(R_pad, M, np.int32)
+    row_lens = np.zeros(R_pad, np.int32)
+    ctx_lens = np.zeros(R_pad, np.int32)
+    last_flat = np.zeros(R_pad, np.int32)
+    off = 0
+    for r, (n, ctx) in enumerate(rows):
+        tokens[off : off + n] = rng.randint(5, cfg.vocab_size - 1, size=n)
+        positions[off : off + n] = np.arange(ctx, ctx + n)
+        row_ids[off : off + n] = r
+        row_starts[r], row_lens[r], ctx_lens[r] = off, n, ctx
+        last_flat[r] = off + n - 1
+        off += n
+    assert off <= M
+    ops = (tokens, positions, row_ids, kv_k, kv_v, tables, row_starts,
+           row_lens, ctx_lens, last_flat)
+    return tuple(jnp.asarray(a) for a in ops)
+
+
+def _fuzzed_rows(seed):
+    rng = np.random.RandomState(seed)
+    return [
+        (1, int(rng.randint(1, 60))) if rng.rand() < 0.6
+        else (int(rng.randint(2, 45)), int(rng.randint(0, 30)))
+        for _ in range(rng.randint(2, 9))
+    ]
+
+
+# name -> (rows, M, R_pad, shared): chunk lengths off both tiles, one-token
+# rows, a spec staircase on one table row, empty rows, a tail of padding
+FWD_PACKS = {
+    "chunks_off_the_tile": ([(21, 0), (9, 16), (1, 13), (17, 3)], 64, 4, ()),
+    "one_token_rows": ([(1, 5), (1, 17), (1, 63), (1, 1), (1, 8)], 8, 8, ()),
+    "spec_staircase": (
+        [(13, 2), (1, 20), (1, 21), (1, 22), (1, 9), (1, 30), (1, 31)],
+        32, 8, ([1, 2, 3], [5, 6]),
+    ),
+    "empty_rows_and_tail": ([(3, 0), (1, 40)], 128, 16, ()),
+    "full_bucket": ([(40, 0), (23, 8), (1, 7)], 64, 4, ()),
+    # the grouped expert path (moe.GROUPED_MIN_TOKENS slots and more)
+    "grouped_bucket": ([(33, 4), (1, 12), (1, 50), (70, 0)], 256, 8, ()),
+    "fuzz0": (_fuzzed_rows(0), 256, 16, ()),
+    "fuzz1": (_fuzzed_rows(1), 256, 16, ()),
+    "fuzz2": (_fuzzed_rows(2), 256, 16, ()),
+}
+
+
+@pytest.fixture(scope="module")
+def fwd_families():
+    import jax
+
+    from dynamo_tpu.models import llama, moe
+
+    out = {}
+    for name, mod, cfg in (
+        ("llama", llama, llama.LlamaConfig.tiny(dtype=jnp.float32)),
+        ("tiny-moe", moe, moe.MoeConfig.tiny_moe(dtype=jnp.float32)),
+    ):
+        out[name] = (mod, cfg, mod.init_params(cfg, jax.random.PRNGKey(3)))
+    return out
+
+
+@pytest.mark.parametrize("pack", sorted(FWD_PACKS))
+@pytest.mark.parametrize("tile", [8, 16])
+@pytest.mark.parametrize("family", ["llama", "tiny-moe"])
+def test_ragged_forward_tiled_q_matches_compact(
+    fwd_families, monkeypatch, family, tile, pack
+):
+    """`ragged_forward` with the attention tile forced to 8 and to 16 (the
+    XLA reference then runs on the tile-aligned starts the forward derives,
+    as the Pallas kernel would) against tile 1, where attention takes the
+    compact axis as it is: the same last-token logits on every real row,
+    and the same pool bytes outside the scratch page."""
+    from dynamo_tpu.models import llama
+
+    mod, cfg, params = fwd_families[family]
+    rows, M, R_pad, shared = FWD_PACKS[pack]
+    ops = _compact_pack(cfg, rows, M, R_pad, shared, seed=len(rows))
+
+    def run(t):
+        monkeypatch.setattr(llama, "ragged_tile", lambda *a, **kw: t)
+        return mod.ragged_forward(params, cfg, *ops)
+
+    want, got = run(1), run(tile)
+    n = len(rows)
+    np.testing.assert_allclose(
+        np.asarray(got[0])[:n], np.asarray(want[0])[:n], rtol=1e-5, atol=1e-5
+    )
+    for g, w, before in zip(got[1:], want[1:], ops[3:5]):
+        np.testing.assert_allclose(
+            np.asarray(g)[:, 1:], np.asarray(w)[:, 1:], rtol=1e-5, atol=1e-5
+        )
+        # the step wrote its rows' K and V: the pool is not what it was
+        assert not np.array_equal(np.asarray(g)[:, 1:], np.asarray(before)[:, 1:])
+
+
+def test_tiled_layout_is_the_inverse_pair_the_kernel_contract_wants():
+    """Starts on multiples of the tile in row order, empty rows right
+    behind the last real one, a static length that holds any pack of the
+    bucket, and two indices that are each other's inverse on real slots
+    and out of range elsewhere."""
+    from dynamo_tpu.models.llama import _tiled_layout
+
+    cfg_rows = [(21, 0), (1, 13), (16, 3), (1, 1)]
+    ops = _compact_pack(
+        type("C", (), dict(num_layers=1, num_kv_heads=1, head_dim=8,
+                           vocab_size=64, dtype=jnp.float32)),
+        cfg_rows, 64, 8,
+    )
+    row_ids, row_starts, row_lens = ops[2], ops[6], ops[7]
+    starts, to_tiled, from_tiled = map(
+        np.asarray, _tiled_layout(16, row_ids, row_starts, row_lens))
+    assert list(starts) == [0, 32, 48, 64, 80, 80, 80, 80]
+    assert from_tiled.shape == (192,)  # M + (tile - 1) * R = 184, whole tiles
+    real = 21 + 1 + 16 + 1
+    assert list(from_tiled[to_tiled[:real]]) == list(range(real))
+    assert (to_tiled[real:] == len(from_tiled)).all()
+    assert (from_tiled == 64).sum() == len(from_tiled) - real

@@ -352,49 +352,47 @@ def test_unknown_cost_means_no_constraint():
 
 
 def test_plan_mixed_packs_chunks_beside_decode_rows():
-    """plan_mixed grants aligned prefill chunks into the flat-token budget
-    left beside the decode rows; the bucket is the smallest of the mixed
-    step's token buckets (bucketing.mixed_token_buckets) that holds the
-    packed total."""
+    """plan_mixed grants prefill chunks into the flat-token budget left
+    beside the decode rows, in real tokens; the bucket is the smallest of
+    the mixed step's token buckets (bucketing.mixed_token_buckets) that
+    holds the packed total."""
     p = _planner(policy="fifo", cfg=_FakeCfg(max_num_seqs=4))
     cands = _slots(2, prompt_len=100)
-    plan = p.plan_mixed(cands, n_decode=4, align=8)
+    plan = p.plan_mixed(cands, n_decode=4)
     assert plan is not None and plan.reason == "mixed"
     assert plan.chosen == cands and plan.chunks == [100, 100]
     assert plan.n_decode == 4
-    # 2x ceil(100/8)*8 = 208 chunk span + 4x8 decode span = 240 -> 256
-    # of (128, 256, 512)
+    # 2 x 100 chunk tokens + 4 decode tokens = 204 -> 256 of (256, 512)
     assert plan.bucket == 256
-    # 32 lanes: the floor holds a full decode batch twice over, 512 = the cap
-    assert _planner(policy="fifo").plan_mixed(
-        cands, n_decode=4, align=8).bucket == 512
+    # one chunk more and the pack needs the cap
+    assert p.plan_mixed(_slots(3, prompt_len=100), n_decode=4).bucket == 512
     # plan_mixed is pure — grants count only on engine commit
     assert p.granted_tokens == 0 and p.granted_chunks == 0
     p.commit_mixed(plan, list(zip(plan.chosen, plan.chunks)))
     assert p.granted_tokens == 200 and p.granted_chunks == 2
 
 
-def test_plan_mixed_non_aligned_budget_never_overpacks():
-    """A mixed_max_tokens that is not a multiple of the packer alignment
-    is floored to it: the granted spans can never exceed the flat buffer
-    the engine will actually allocate (regression: 519-token budget with
-    align=8 used to grant a 520-token span, writing past N_pad)."""
-    p = _planner(policy="fifo", cfg=_FakeCfg(mixed_max_tokens=519))
+@pytest.mark.parametrize("budget", [519, 512, 257])
+def test_plan_mixed_budget_is_real_tokens_and_never_overpacks(budget):
+    """mixed_max_tokens bounds the REAL tokens of a pack, whatever it is a
+    multiple of: the flat buffer is compact, so the whole budget is
+    granted and the largest bucket is the budget itself (while rows were
+    packed a q tile apart, a 519-token budget was floored to 512 and a
+    one-token row cost 8)."""
+    p = _planner(policy="fifo", cfg=_FakeCfg(mixed_max_tokens=budget))
     cands = _slots(3, prompt_len=400)
-    plan = p.plan_mixed(cands, n_decode=1, align=8)
+    plan = p.plan_mixed(cands, n_decode=1)
     assert plan is not None
-    span = sum(-(-ch // 8) * 8 for ch in plan.chunks) + 8  # + decode row
-    assert span <= 519 - 519 % 8
-    assert plan.bucket % 8 == 0 and plan.bucket <= 519 - 519 % 8
+    assert sum(plan.chunks) + 1 == budget == plan.bucket
 
 
 def test_plan_mixed_respects_budget_and_declines_when_full():
     p = _planner(policy="fifo")
     # decode rows alone exceed the flat budget -> no fused step
-    assert p.plan_mixed(_slots(1), n_decode=600, align=1) is None
+    assert p.plan_mixed(_slots(1), n_decode=600) is None
     # chunks shrink to what fits beside the decode rows
     cands = _slots(3, prompt_len=400)
-    plan = p.plan_mixed(cands, n_decode=100, align=1)
+    plan = p.plan_mixed(cands, n_decode=100)
     assert plan is not None
     assert 100 + sum(plan.chunks) <= 512
     assert all(ch <= 256 for ch in plan.chunks)  # max_prefill_chunk cap
@@ -402,18 +400,19 @@ def test_plan_mixed_respects_budget_and_declines_when_full():
 
 def test_plan_mixed_itl_budget_shrinks_chunks():
     """Under sla with an ITL target, a too-slow predicted mixed step
-    halves chunks until the estimate fits (never defers outright — the
-    decode lanes ride the same dispatch)."""
+    halves chunks until the estimate fits or the pack is down to the
+    smallest token bucket (never defers outright — the decode lanes ride
+    the same dispatch)."""
     p = _planner(policy="sla", itl_ms=10.0, cfg=_FakeCfg(max_num_seqs=4))
     # teach the model: big mixed dispatches are slow, small ones fast
     for _ in range(12):
         p.cost.observe("mixed", 512, 10, 0.050)
-        p.cost.observe("mixed", 64, 10, 0.004)
-    cands = _slots(1, prompt_len=400)
-    plan = p.plan_mixed(cands, n_decode=8, align=8)
+        p.cost.observe("mixed", 256, 10, 0.004)
+    cands = _slots(2, prompt_len=400)
+    plan = p.plan_mixed(cands, n_decode=8)
     assert plan is not None
     assert plan.reason == "mixed-shrunk"
-    assert plan.chunks[0] < 256
+    assert plan.bucket == 256 and 8 + sum(plan.chunks) <= 256
     assert p.itl_shrunk_steps == 0  # pure until commit
     p.commit_mixed(plan, list(zip(plan.chosen, plan.chunks)))
     assert p.itl_shrunk_steps == 1
@@ -424,29 +423,28 @@ def test_plan_mixed_spec_rows_reserve_row_budget():
     decode rows: chunks shrink to what fits, MixedPlan reports the count,
     and n_spec_rows=0 is byte-identical to the pre-spec plan shape."""
     p = _planner(policy="fifo")
-    cands = _slots(2, prompt_len=100)
-    base = p.plan_mixed(cands, n_decode=4, align=8)
-    spec = p.plan_mixed(cands, n_decode=4, align=8, n_spec_rows=12)
+    cands = _slots(2, prompt_len=300)
+    base = p.plan_mixed(cands, n_decode=4)
+    spec = p.plan_mixed(cands, n_decode=4, n_spec_rows=12)
     assert base is not None and spec is not None
     assert base.n_spec_rows == 0 and spec.n_spec_rows == 12
-    # 12 extra aligned(1)=8-token rows eat 96 flat tokens of chunk space
-    assert sum(spec.chunks) <= sum(base.chunks)
+    # 12 extra one-token rows eat 12 flat tokens of chunk space
+    assert sum(spec.chunks) == sum(base.chunks) - 12
     assert spec.n_decode == base.n_decode == 4
-    # budget math: chunk spans + every one-token row span fit the buffer
-    span = sum(-(-ch // 8) * 8 for ch in spec.chunks) + 8 * (4 + 12)
-    assert span <= 512
+    # budget math: chunk tokens + every one-token row fit the buffer
+    assert sum(spec.chunks) + 4 + 12 == 512
 
 
 def test_plan_mixed_declines_when_spec_rows_fill_budget():
-    """Spec verify rows alone exceeding mixed_max_tokens -> no fused
+    """Spec verify rows alone filling mixed_max_tokens -> no fused
     step (engine rides the split spec path instead)."""
     p = _planner(policy="fifo")
-    # aligned(1)=8 per row: 4 decode + 62 spec rows = 528 > 512 budget
-    assert p.plan_mixed(_slots(1), n_decode=4, align=8,
-                        n_spec_rows=62) is None
-    # one fewer spec row fits again
-    plan = p.plan_mixed(_slots(1), n_decode=4, align=8, n_spec_rows=59)
-    assert plan is not None and plan.n_spec_rows == 59
+    # one token a row: 4 decode + 508 spec rows = 512, the whole budget
+    assert p.plan_mixed(_slots(1), n_decode=4, n_spec_rows=508) is None
+    # one fewer spec row leaves a token for the chunk
+    plan = p.plan_mixed(_slots(1), n_decode=4, n_spec_rows=507)
+    assert plan is not None and plan.n_spec_rows == 507
+    assert plan.chunks == [1]
 
 
 def test_deadline_lifecycle_and_reset():

@@ -349,41 +349,65 @@ def test_tp4_decode_step_shards_over_a_four_chip_mesh(
     assert 0.24 < per_device / total < 0.27, per_device / total
 
 
-@pytest.mark.parametrize("tokens", (1024, 2048))
-def test_the_cells_mixed_step_family_compiles_with_the_grouped_matmul(
-    tokens, one_chip, no_persistent_cache, tpu_gate
-):
-    """Both members of the benchmark cell's lean mixed_step family: Mixtral
-    widths (2 layers), the 8,193-page pool, 64 rows, the ONE table width of
-    65, the grouped expert matmul and the ragged kernel inside, within one
-    v5e. No temporary the size of an expert matrix (0.94 GB): the expert
-    stacks reach the kernel whole, a slice of one would be a copy of it."""
+def _cell_models():
+    """The benchmark's two cells as (module, config, pool pages): Mixtral
+    widths at 2 layers beside --num-pages 8192, Mistral-7B widths at 16
+    layers beside the auto-sized pool the worker finds (PERF.md, section
+    4)."""
     import dataclasses
 
     from dynamo_tpu.models import moe
 
-    cfg = dataclasses.replace(
-        moe.MoeConfig.mixtral_8x7b(), num_layers=MIXTRAL["layers"],
-        capacity_factor=4.0,
-    )
+    return {
+        "mixtral-8x7b-d2": (moe, dataclasses.replace(
+            moe.MoeConfig.mixtral_8x7b(), num_layers=MIXTRAL["layers"],
+            capacity_factor=4.0,
+        ), MIXTRAL["pool"]),
+        "mistral-7b-d16": (llama, llama.LlamaConfig(
+            vocab_size=32768, hidden_size=4096, intermediate_size=14336,
+            num_layers=16, num_heads=32, num_kv_heads=8, head_dim=128,
+            rope_theta=1e6, rms_norm_eps=1e-5, max_position=32768,
+        ), 1377),
+    }
+
+
+@pytest.mark.parametrize("tokens", (256, 2048))
+@pytest.mark.parametrize("cell", ("mixtral-8x7b-d2", "mistral-7b-d16"))
+def test_the_cells_mixed_step_family_compiles_with_its_kernels(
+    cell, tokens, one_chip, no_persistent_cache, tpu_gate
+):
+    """The smallest and the largest member of both benchmark cells' lean
+    mixed_step family: the cell's widths, depth and pool, 40 rows, the ONE
+    table width of 65, a COMPACT token axis of the bucket's length, the
+    ragged kernel inside on the q-tile layout the forward builds (and, on
+    the routed family, the grouped expert matmul), within one v5e. No
+    temporary the size of an expert matrix (0.94 GB): the expert stacks
+    reach the kernel whole, a slice of one would be a copy of it; the
+    dense family's largest temporaries are the bucket's activations."""
+    from dynamo_tpu.engine.bucketing import mixed_row_bucket
+    from dynamo_tpu.engine.config import EngineConfig
+
+    mod, cfg, pool = _cell_models()[cell]
     sds = _shapes(one_chip)
 
     def on_chip(tree):
         return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
 
     params = on_chip(jax.eval_shape(
-        functools.partial(moe.init_params, cfg), jax.random.PRNGKey(0)
+        functools.partial(mod.init_params, cfg), jax.random.PRNGKey(0)
     ))
     kv = on_chip(jax.eval_shape(lambda: alloc_kv_store(
-        cfg.num_layers, MIXTRAL["pool"], PAGE, cfg.num_kv_heads, cfg.head_dim,
+        cfg.num_layers, pool, PAGE, cfg.num_kv_heads, cfg.head_dim,
         cfg.dtype, "none",
     )))
     i32 = jnp.int32
-    rows, width = 64, 4096 // PAGE + 1
+    rows = mixed_row_bucket(EngineConfig(model="tiny", max_num_seqs=32))
+    assert rows == 40  # 32 lanes and a prefill batch of 4, whole sublanes
+    width = 4096 // PAGE + 1
 
     def step(params, kv_k, kv_v, tokens, positions, row_ids, tables,
              row_starts, row_lens, ctx_lens, last_flat):
-        return moe.ragged_forward(
+        return mod.ragged_forward(
             params, cfg, tokens, positions, row_ids, kv_k, kv_v, tables,
             row_starts, row_lens, ctx_lens, last_flat,
         )
@@ -393,12 +417,14 @@ def test_the_cells_mixed_step_family_compiles_with_the_grouped_matmul(
         sds((tokens,), i32), sds((rows, width), i32), sds((rows,), i32),
         sds((rows,), i32), sds((rows,), i32), sds((rows,), i32),
     ).compile()
-    # per layer: the ragged attention kernel and three grouped matmuls
-    assert compiled.as_text().count("tpu_custom_call") >= 4 * cfg.num_layers
+    # per layer: the ragged attention kernel and, routed, three grouped
+    # matmuls
+    kernels = 4 if hasattr(cfg, "num_experts") else 1
+    assert compiled.as_text().count("tpu_custom_call") >= kernels * cfg.num_layers
     mem = compiled.memory_analysis()
     need = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert need < HBM_BYTES, f"mixed step needs {need / 2**30:.2f} GiB"
-    expert_matrix = cfg.num_experts * cfg.hidden_size * cfg.intermediate_size * 2
+    expert_matrix = 8 * cfg.hidden_size * cfg.intermediate_size * 2
     assert mem.temp_size_in_bytes < expert_matrix, (
         f"{mem.temp_size_in_bytes / 2**20:.0f} MiB of temporaries"
     )
@@ -408,7 +434,7 @@ def test_the_piped_mixed_steps_carry_programs_compile_at_the_cell_size(
     one_chip, no_persistent_cache
 ):
     """What ISSUE 37 adds to the lean mixed step, at the Mixtral cell's
-    sizes (32 lanes, 64 rows, the 2,048-token bucket, the default penalty
+    sizes (32 lanes, 40 rows, the 2,048-token bucket, the default penalty
     window): the read of the decode carry that opens `mixed_step` (each
     decode row's token and window gathered by lane) and the write-back
     program behind it. Both are scatters and gathers over a few KiB: no
@@ -418,7 +444,7 @@ def test_the_piped_mixed_steps_carry_programs_compile_at_the_cell_size(
 
     sds = _shapes(one_chip)
     i32 = jnp.int32
-    lanes, rows, tokens = 32, 64, 2048
+    lanes, rows, tokens = 32, 40, 2048
     window = EngineConfig(model="tiny").penalty_window
     read = jax.jit(carry_read).lower(
         sds((tokens,), i32), sds((rows, window), i32), sds((rows,), i32),
