@@ -668,7 +668,7 @@ def main(argv: Optional[List[str]] = None):
             # brief warmup: compile every engine variant before the timed trace
             warm = [TraceRequest(0.0, 32, 8, list(range(5, 37))) for _ in range(2)]
             asyncio.run(run_trace(dep.http_port, warm))
-            # baseline AFTER warmup: engine _dev_time counters are
+            # baseline AFTER warmup: the engine's dispatch_* counters are
             # cumulative, so the diagnostic must diff out warmup + compile
             try:
                 base_dispatch, _ = _scrape_dispatch()

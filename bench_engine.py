@@ -254,6 +254,13 @@ async def _mixed_replay(engine, B: int, isl: int, osl: int, vocab: int,
     return step_times
 
 
+def _padding(s: dict, path: str) -> float:
+    """Padded share of the token slots the `path` ("mixed" | "split")
+    steps ran in, from the engine's two counters."""
+    padded = s[f"{path}_padded_tokens"]
+    return round(1.0 - s[f"{path}_real_tokens"] / padded, 4) if padded else 0.0
+
+
 def _mixed_arm_report(engine, step_times) -> dict:
     s = engine.stats()
     fused = s["mixed_steps"] > 0
@@ -265,8 +272,7 @@ def _mixed_arm_report(engine, step_times) -> dict:
         # the fused path does prefill+decode in ONE call, the split path
         # pays a prefill dispatch AND a decode dispatch
         "dispatches_per_mixed_step": 1 if fused else 2,
-        "padding_frac": s["mixed_padding_frac"] if fused
-        else s["split_padding_frac"],
+        "padding": _padding(s, "mixed" if fused else "split"),
         "step_ms_p50": round(_pct(times, 0.50), 2),
         "step_ms_p99": round(_pct(times, 0.99), 2),
         "dispatch_counts": {
@@ -310,8 +316,8 @@ def run_mixed_bench(args, model: str, vocab: int, B: int, isl: int, osl: int):
         "unit": "dispatches/mixed-step",
         "split_dispatches_per_mixed_step":
             arms["split"]["dispatches_per_mixed_step"],
-        "mixed_padding_frac": arms["unified"]["padding_frac"],
-        "split_padding_frac": arms["split"]["padding_frac"],
+        "mixed_padding": arms["unified"]["padding"],
+        "split_padding": arms["split"]["padding"],
         "mixed_step_ms_p50": arms["unified"]["step_ms_p50"],
         "mixed_step_ms_p99": arms["unified"]["step_ms_p99"],
         "split_step_ms_p50": arms["split"]["step_ms_p50"],
@@ -452,8 +458,7 @@ def run_blend_bench(args, model: str, vocab: int, B: int, isl: int, osl: int):
                 k: s[f"mixed_rows_{k}"]
                 for k in ("plain", "guided", "spec", "lora")
             },
-            "padding_frac": s["mixed_padding_frac"] if fused
-            else s["split_padding_frac"],
+            "padding": _padding(s, "mixed" if fused else "split"),
             "step_ms_p50": round(_pct(step_times["mixed" if fused
                                                  else "split"], 0.50), 2),
         }
@@ -465,7 +470,7 @@ def run_blend_bench(args, model: str, vocab: int, B: int, isl: int, osl: int):
         "split_tokens_per_dispatch": arms["split"]["tokens_per_dispatch"],
         "mixed_coverage_frac": arms["unified"]["mixed_coverage_frac"],
         "mixed_rows": arms["unified"]["mixed_rows"],
-        "mixed_padding_frac": arms["unified"]["padding_frac"],
+        "mixed_padding": arms["unified"]["padding"],
         "mixed_step_ms_p50": arms["unified"]["step_ms_p50"],
         "split_step_ms_p50": arms["split"]["step_ms_p50"],
     }

@@ -69,6 +69,7 @@ from .bucketing import (
 )
 from .config import EngineConfig
 from .kv_cache import PageAllocator, alloc_kv_arrays
+from .recorder import Recorder, Work
 from .sampling import SamplingParams, penalized, sample, sample_lp, unpack_mask
 from .scheduler import SlaConfig, StepPlanner
 
@@ -339,6 +340,10 @@ class _Slot:
     # starvation guard's aging signal) and resets on every granted chunk.
     priority: int = 0
     arrival_s: float = 0.0
+    # the waits ahead of the first token (engine/recorder.py): the first
+    # admission and the first token handed to the stream, time.monotonic()
+    admit_s: float = 0.0
+    first_token_s: float = 0.0
     sched_deadline: float = 0.0
     sched_skips: int = 0
     # dynogate tenant key (docs/overload.md): feeds the StepPlanner's
@@ -546,6 +551,20 @@ class JaxEngine:
         # show about 1/tp of both on every device of its mesh
         self._weight_bytes_per_device = _bytes_per_device(self.params)
         self._kv_bytes_per_device = _bytes_per_device((self.kv_k, self.kv_v))
+        # (useful operations, least HBM bytes) of a pipeline entry from the
+        # shapes the host holds at dispatch (models/<family>.step_work), at
+        # the bytes this engine's weights and pool have: int8 weights are
+        # one byte an element (their scales are not counted: a floor)
+        from ..ops.kv_quant import kv_page_bytes
+
+        self._step_work = partial(
+            (moe if isinstance(c, moe.MoeConfig) else llama).step_work, c,
+            weight_bytes=1 if is_quant(self.params["layers"]["w_gate"])
+            else jnp.dtype(c.dtype).itemsize,
+            kv_bytes=2 * kv_page_bytes(
+                config.page_size, c.num_kv_heads, c.head_dim, c.dtype, kvq
+            ) / config.page_size,
+        )
         self.warmup_seconds = 0.0
         # KVBM host/disk tiers (kvbm/): write-through offload of committed
         # blocks, onboard at admission when the device prefix cache misses
@@ -805,10 +824,11 @@ class JaxEngine:
         self._lora_pool = None  # models/lora_pool.LoraPool when registered
         self.lora_idx = np.zeros((config.max_num_seqs,), np.int32)
         self.lora_requests = 0
-        # per-dispatch-type device occupancy: {tag: (count, seconds)} —
-        # dispatches run serialized on the single device thread, so these
-        # sum to device-stream busy time (the serving-gap diagnostic)
-        self._dev_time: Dict[str, tuple] = {}
+        # the loop's spans and counters (engine/recorder.py): seconds by
+        # phase of an iteration, every pipeline entry's kind, interval and
+        # work, the waits ahead of a first token, and _timed's table of
+        # the host's clock around each device call
+        self._rec = Recorder(self._describe_load)
         # emit batching (tokens-per-delta-batch): mean > 1 in steady decode
         # means the serving plane is getting whole blocks, not singletons —
         # the self-diagnosing coalescing signal on hardware e2e rows
@@ -2420,12 +2440,9 @@ class JaxEngine:
         # the slots of the token buckets they ran in
         out["mixed_real_tokens"] = self.mixed_real_tokens
         out["mixed_padded_tokens"] = self.mixed_padded_tokens
-        out["mixed_padding_frac"] = round(
-            1.0 - self.mixed_real_tokens / self.mixed_padded_tokens, 4
-        ) if self.mixed_padded_tokens else 0.0
-        out["split_padding_frac"] = round(
-            1.0 - self.split_real_tokens / self.split_padded_tokens, 4
-        ) if self.split_padded_tokens else 0.0
+        # ... and the split pair that served a mixed-shaped step
+        out["split_real_tokens"] = self.split_real_tokens
+        out["split_padded_tokens"] = self.split_padded_tokens
         # per-kind fused coverage: which workloads actually ride the fused
         # path (ISSUE 19 CI gate: coverage >= 0.9 on blended traffic)
         out["mixed_rows_plain"] = self.mixed_rows_plain
@@ -2445,14 +2462,9 @@ class JaxEngine:
         est = self.estimated_prefill_wait_ms()
         out[SCHED_EST_TTFT_MS] = round(est, 1) if est is not None else 0.0
         out[SCHED_EST_REQ_MS] = round(self.estimated_req_ms(), 1)
-        recent = self.scheduler.recent_decisions()
-        if recent:
-            out["sched_last_decision"] = recent[-1]
-        # list() is one atomic C-level snapshot: the jax-step thread keeps
-        # inserting while we iterate (GUARDED_STATE: thread-confined)
-        for tag, (cnt, tot) in list(self._dev_time.items()):
-            out[f"dispatch_{tag}_count"] = cnt
-            out[f"dispatch_{tag}_s"] = round(tot, 3)
+        # the loop's own spans and counters: phase_*, step_*, req_*,
+        # engine_clock_s and _timed's dispatch_* (engine/recorder.py)
+        out.update(self._rec.stats())
         # compile telemetry (docs/compilation.md): XLA cache size per
         # staged surface plus the steady-state gate — programs compiled
         # AFTER the warmup baseline snapshot. dynocomp proves warmup
@@ -2531,7 +2543,9 @@ class JaxEngine:
                 and not self._pending_prefill
             ):
                 self._wake.clear()
-                await self._wake.wait()
+                self._rec.entry_kind = "none"
+                with self._rec.span("wait"):
+                    await self._wake.wait()
                 continue
             try:
                 f = faults.FAULTS
@@ -2543,10 +2557,14 @@ class JaxEngine:
             except Exception as e:  # noqa: BLE001 — engine loop must not die silently
                 logger.exception("engine step failed; failing active requests")
                 self._fail_all(f"engine step failed: {type(e).__name__}: {e}")
-                await asyncio.sleep(0.1)
+                with self._rec.span("wait"):
+                    await asyncio.sleep(0.1)
                 continue
-            # yield to the event loop so streams flush between steps
-            await asyncio.sleep(0 if progressed else 0.001)
+            # yield to the event loop so streams flush between steps: the
+            # loop's own share of that time is nothing, the streams' tasks
+            # run in it
+            with self._rec.span("wait"):
+                await asyncio.sleep(0 if progressed else 0.001)
 
     async def _step_once(self) -> bool:
         """One engine iteration: admit, dispatch ONE entry of the decode
@@ -2608,17 +2626,23 @@ class JaxEngine:
     # -- admission ------------------------------------------------------- #
 
     def _admit_waiting(self):
+        if not self._waiting:
+            return
         still: List[_Slot] = []
-        # sla policy: admit earliest-TTFT-deadline first (preempted victims
-        # keep their original arrival, so they stay at the front exactly as
-        # the legacy insert-at-0 intended); fifo: arrival order untouched
-        for slot in self.scheduler.order_waiting(self._waiting):
-            if slot.done or slot.context.is_stopped():
-                self._emit_finish(slot, "cancelled")
-                continue
-            if not self._free_slots or not self._try_admit(slot):
-                still.append(slot)
-        self._waiting = still
+        with self._rec.span("admit"):
+            # sla policy: admit earliest-TTFT-deadline first (preempted
+            # victims keep their original arrival, so they stay at the
+            # front exactly as the legacy insert-at-0 intended); fifo:
+            # arrival order untouched
+            for slot in self.scheduler.order_waiting(self._waiting):
+                if slot.done or slot.context.is_stopped():
+                    self._emit_finish(slot, "cancelled")
+                    continue
+                if not self._free_slots or not self._try_admit(slot):
+                    still.append(slot)
+                else:
+                    self._rec.admitted(slot)
+            self._waiting = still
 
     def _try_admit(self, slot: _Slot) -> bool:
         cfg = self.config
@@ -2749,18 +2773,18 @@ class JaxEngine:
     # -- device helpers -------------------------------------------------- #
 
     def _timed(self, fn, tag: str, shape: Optional[tuple] = None):
-        """Wrap fn so its wall time accrues to self._dev_time[tag] (and,
+        """Wrap fn so its wall time accrues to the recorder's table of
+        device calls under `tag` (stats: dispatch_<tag>_count, _s) and,
         when `shape`=(bucket, lanes) is given, feeds the scheduler's
         per-shape cost model — the EWMA behind ITL budgeting and the
-        disagg router's local-TTFT estimate)."""
+        disagg router's local-TTFT estimate."""
         def timed(*a):
             t0 = time.perf_counter()
             try:
                 return fn(*a)
             finally:
                 dt = time.perf_counter() - t0
-                cnt, tot = self._dev_time.get(tag, (0, 0.0))
-                self._dev_time[tag] = (cnt + 1, tot + dt)
+                self._rec.timed(tag, dt)
                 if shape is not None:
                     self.scheduler.cost.observe(tag, shape[0], shape[1], dt)
         return timed
@@ -2773,11 +2797,26 @@ class JaxEngine:
             self._device_exec, fn, *args
         )
 
+    def _host_read(self, tree):
+        """(the tree on the host, the clock when it was there): the fetch
+        thread waiting for the device."""
+        with self._rec.span("fetch"):
+            out = jax.device_get(tree)
+        return out, time.perf_counter()
+
     async def _fetch(self, tree):
         """One host read (single RTT) for an arbitrary pytree of device
-        arrays, off the dispatch thread."""
+        arrays, off the dispatch thread, and when it returned."""
         return await asyncio.get_running_loop().run_in_executor(
-            self._fetch_exec, self._timed(jax.device_get, "fetch"), tree
+            self._fetch_exec, self._timed(self._host_read, "fetch"), tree
+        )
+
+    def _describe_load(self) -> str:
+        """The engine's load, for the recorder's line on a slow span."""
+        return (
+            f"{len(self._inflight) + len(self._pending_prefill)} in flight, "
+            f"{sum(s is not None for s in self.slots)} running, "
+            f"{len(self._waiting)} waiting"
         )
 
     def _bcast(self, tag: str, arrays: dict):
@@ -2795,9 +2834,10 @@ class JaxEngine:
     # -- replicated device programs (leader dispatches these after a
     # _bcast; followers replay them verbatim in run_follower) ------------ #
 
-    def _dev_prefill(self, toks, positions, tables, ctx_lens, last_idx,
-                     temps, top_ks, top_ps, seeds, pens, pen_rows):
-        samp = SamplingParams(
+    def _samp_operand(self, temps, top_ks, top_ps, seeds, pens):
+        """The rows' sampling parameters on the device (inside a caller's
+        `put` span)."""
+        return SamplingParams(
             temperature=jnp.asarray(temps),
             top_k=jnp.asarray(top_ks),
             top_p=jnp.asarray(top_ps),
@@ -2806,75 +2846,86 @@ class JaxEngine:
             frequency=jnp.asarray(pens[:, 1]),
             repetition=jnp.asarray(pens[:, 2]),
         )
-        first, self.kv_k, self.kv_v, self._rng = self._prefill_batch(
-            self.params,
-            self.kv_k,
-            self.kv_v,
-            jnp.asarray(toks),
-            jnp.asarray(positions),
-            jnp.asarray(tables),
-            jnp.asarray(ctx_lens),
-            jnp.asarray(last_idx),
-            samp,
-            self._rng,
-            jnp.asarray(pen_rows),
-        )
+
+    def _prefill_operands(self, toks, positions, tables, ctx_lens, last_idx,
+                          temps, top_ks, top_ps, seeds, pens, pen_rows,
+                          *more):
+        """A batched prefill program's operands from the host, on the
+        device: (toks, positions, tables, ctx_lens, last_idx, samp,
+        pen_rows) and, behind them, what a variant takes `more` of (a
+        mask, embeddings). One `put` span."""
+        with self._rec.span("put"):
+            return (
+                jnp.asarray(toks),
+                jnp.asarray(positions),
+                jnp.asarray(tables),
+                jnp.asarray(ctx_lens),
+                jnp.asarray(last_idx),
+                self._samp_operand(temps, top_ks, top_ps, seeds, pens),
+                jnp.asarray(pen_rows),
+                *(jnp.asarray(x) for x in more),
+            )
+
+    def _dev_prefill(self, *operands):
+        toks, positions, tables, ctx_lens, last_idx, samp, pen_rows = \
+            self._prefill_operands(*operands)
+        with self._rec.span("launch"):
+            first, self.kv_k, self.kv_v, self._rng = self._prefill_batch(
+                self.params, self.kv_k, self.kv_v, toks, positions, tables,
+                ctx_lens, last_idx, samp, self._rng, pen_rows,
+            )
         return first
 
     def _mixed_operands(self, p: dict):
         """(operands, carry) of one mixed step from its pack as the "mixed"
         broadcast carries it (_blank_mixed_pack's keys): mixed_step's, with
         the decode carry it reads by lane, for a plain pack; for a pack
-        with a mask mixed_step_variant's, and None."""
-        pens = p["pens"]
-        samp = SamplingParams(
-            temperature=jnp.asarray(p["temps"]),
-            top_k=jnp.asarray(p["top_ks"]),
-            top_p=jnp.asarray(p["top_ps"]),
-            seed=jnp.asarray(p["seeds"]),
-            presence=jnp.asarray(pens[:, 0]),
-            frequency=jnp.asarray(pens[:, 1]),
-            repetition=jnp.asarray(pens[:, 2]),
-        )
-        args = (
-            self.params,
-            self.kv_k,
-            self.kv_v,
-            jnp.asarray(p["toks"]),
-            jnp.asarray(p["positions"]),
-            jnp.asarray(p["row_ids"]),
-            jnp.asarray(p["tables"]),
-            jnp.asarray(p["row_starts"]),
-            jnp.asarray(p["row_lens"]),
-            jnp.asarray(p["ctx_lens"]),
-            jnp.asarray(p["last_flat"]),
-            samp,
-            # donated: a priming call runs on a copy
-            jnp.copy(self._rng) if "prime" in p else self._rng,
-            jnp.asarray(p["pen_rows"]),
-        )
-        if "mask" in p:
-            # variant pack: the mask operand is always present (all-ones
-            # for maskless packs — an exact no-op), the LoRA operand rides
-            # iff adapters are registered (idx 0 rows are the base no-op),
-            # so exactly ONE variant program exists per deployment
-            lora = (
-                self._lora_operand(p["lora_idx"])
-                if self._lora is not None and "lora_idx" in p else None
+        with a mask mixed_step_variant's, and None. The step's transfers
+        from the host to the device: one `put` span."""
+        with self._rec.span("put"):
+            pens = p["pens"]
+            samp = self._samp_operand(
+                p["temps"], p["top_ks"], p["top_ps"], p["seeds"], pens
             )
-            return (*args, jnp.asarray(p["mask"]), lora), None
-        # plain pack: the lean program. A drained pack reads no lane
-        # (its map is all -1), so any carry of the right shape serves
-        if self._carry is not None:
-            carry = (*self._carry, self._pen_dev)
-        else:  # before the first reset
-            B = self.config.max_num_seqs
-            lanes = jnp.zeros((B,), jnp.int32)
-            carry = (lanes, lanes, lanes, jnp.full(
-                (B, self.config.penalty_window), -1, jnp.int32))
-        none = np.full_like(p["row_lens"], -1)
-        row_lane = jnp.asarray(p.get("row_lane", none))
-        return (*args, row_lane, carry[0], carry[3]), carry
+            args = (
+                self.params,
+                self.kv_k,
+                self.kv_v,
+                jnp.asarray(p["toks"]),
+                jnp.asarray(p["positions"]),
+                jnp.asarray(p["row_ids"]),
+                jnp.asarray(p["tables"]),
+                jnp.asarray(p["row_starts"]),
+                jnp.asarray(p["row_lens"]),
+                jnp.asarray(p["ctx_lens"]),
+                jnp.asarray(p["last_flat"]),
+                samp,
+                # donated: a priming call runs on a copy
+                jnp.copy(self._rng) if "prime" in p else self._rng,
+                jnp.asarray(p["pen_rows"]),
+            )
+            if "mask" in p:
+                # variant pack: the mask operand is always present (all-ones
+                # for maskless packs — an exact no-op), the LoRA operand rides
+                # iff adapters are registered (idx 0 rows are the base no-op),
+                # so exactly ONE variant program exists per deployment
+                lora = (
+                    self._lora_operand(p["lora_idx"])
+                    if self._lora is not None and "lora_idx" in p else None
+                )
+                return (*args, jnp.asarray(p["mask"]), lora), None
+            # plain pack: the lean program. A drained pack reads no lane
+            # (its map is all -1), so any carry of the right shape serves
+            if self._carry is not None:
+                carry = (*self._carry, self._pen_dev)
+            else:  # before the first reset
+                B = self.config.max_num_seqs
+                lanes = jnp.zeros((B,), jnp.int32)
+                carry = (lanes, lanes, lanes, jnp.full(
+                    (B, self.config.penalty_window), -1, jnp.int32))
+            none = np.full_like(p["row_lens"], -1)
+            row_lane = jnp.asarray(p.get("row_lane", none))
+            return (*args, row_lane, carry[0], carry[3]), carry
 
     def _dev_mixed(self, p: dict):
         """One mixed step from its operands as the "mixed" broadcast carries
@@ -2888,18 +2939,21 @@ class JaxEngine:
         do not depend on when the family was compiled."""
         prime, piped = "prime" in p, "row_lane" in p
         args, carry = self._mixed_operands(p)
-        if carry is None:
-            first, self.kv_k, self.kv_v, rng = self._mixed_step_variant(*args)
-        else:
-            first, self.kv_k, self.kv_v, rng = self._mixed_step(*args)
+        with self._rec.span("launch"):
+            if carry is None:
+                first, self.kv_k, self.kv_v, rng = \
+                    self._mixed_step_variant(*args)
+            else:
+                first, self.kv_k, self.kv_v, rng = self._mixed_step(*args)
         if carry is not None and (piped or prime):
             # priming compiles the write-back beside the family, on
             # a map that names no lane, and drops what it returns
             none = np.full_like(p["row_lens"], -1)
-            wrote = self._carry_write(
-                *carry, first[0], jnp.asarray(p.get("w_lane", none)),
-                jnp.asarray(p.get("w_pos", none)),
-            )
+            with self._rec.span("put", more=True):
+                w_lane = jnp.asarray(p.get("w_lane", none))
+                w_pos = jnp.asarray(p.get("w_pos", none))
+            with self._rec.span("launch", more=True):
+                wrote = self._carry_write(*carry, first[0], w_lane, w_pos)
             if piped:
                 self._carry, self._pen_dev = wrote[:3], wrote[3]
         if not prime:
@@ -2913,72 +2967,40 @@ class JaxEngine:
         compiler's and the runtime's own work, which holds no interpreter
         lock. The executables stay with the jit's lowering, so the
         members' first calls find them and compile nothing."""
-        def compile_one(pack):
-            args, carry = self._mixed_operands(pack)
+        def compile_one(operands):
+            args, carry = operands
             program = (
                 self._mixed_step_variant if carry is None else self._mixed_step
             )
             program.lower(*args).compile()
 
+        # the operands one after another on this, the device thread (their
+        # `put` spans have one writer), the compiles side by side
+        operands = [self._mixed_operands(pk) for pk in packs]
         with concurrent.futures.ThreadPoolExecutor(len(packs)) as pool:
-            for done in [pool.submit(compile_one, pk) for pk in packs]:
+            for done in [pool.submit(compile_one, ops) for ops in operands]:
                 done.result()
 
-    def _dev_prefill_mm(self, toks, positions, tables, ctx_lens, last_idx,
-                        temps, top_ks, top_ps, seeds, pens, pen_rows,
-                        emb, emb_mask):
-        samp = SamplingParams(
-            temperature=jnp.asarray(temps),
-            top_k=jnp.asarray(top_ks),
-            top_p=jnp.asarray(top_ps),
-            seed=jnp.asarray(seeds),
-            presence=jnp.asarray(pens[:, 0]),
-            frequency=jnp.asarray(pens[:, 1]),
-            repetition=jnp.asarray(pens[:, 2]),
-        )
-        first, self.kv_k, self.kv_v, self._rng = self._prefill_batch_mm(
-            self.params,
-            self.kv_k,
-            self.kv_v,
-            jnp.asarray(toks),
-            jnp.asarray(positions),
-            jnp.asarray(tables),
-            jnp.asarray(ctx_lens),
-            jnp.asarray(last_idx),
-            samp,
-            self._rng,
-            jnp.asarray(pen_rows),
-            jnp.asarray(emb),
-            jnp.asarray(emb_mask),
-        )
+    def _dev_prefill_mm(self, *operands):
+        # the last two: the encoder's rows and where they go
+        (toks, positions, tables, ctx_lens, last_idx, samp, pen_rows,
+         emb, emb_mask) = self._prefill_operands(*operands)
+        with self._rec.span("launch"):
+            first, self.kv_k, self.kv_v, self._rng = self._prefill_batch_mm(
+                self.params, self.kv_k, self.kv_v, toks, positions, tables,
+                ctx_lens, last_idx, samp, self._rng, pen_rows, emb, emb_mask,
+            )
         return first
 
-    def _dev_prefill_guided(self, toks, positions, tables, ctx_lens, last_idx,
-                            temps, top_ks, top_ps, seeds, pens, pen_rows,
-                            mask):
-        samp = SamplingParams(
-            temperature=jnp.asarray(temps),
-            top_k=jnp.asarray(top_ks),
-            top_p=jnp.asarray(top_ps),
-            seed=jnp.asarray(seeds),
-            presence=jnp.asarray(pens[:, 0]),
-            frequency=jnp.asarray(pens[:, 1]),
-            repetition=jnp.asarray(pens[:, 2]),
-        )
-        first, self.kv_k, self.kv_v, self._rng = self._prefill_batch_guided(
-            self.params,
-            self.kv_k,
-            self.kv_v,
-            jnp.asarray(toks),
-            jnp.asarray(positions),
-            jnp.asarray(tables),
-            jnp.asarray(ctx_lens),
-            jnp.asarray(last_idx),
-            samp,
-            self._rng,
-            jnp.asarray(pen_rows),
-            jnp.asarray(mask),
-        )
+    def _dev_prefill_guided(self, *operands):
+        # the last one: the rows' packed FSM masks
+        (toks, positions, tables, ctx_lens, last_idx, samp, pen_rows,
+         mask) = self._prefill_operands(*operands)
+        with self._rec.span("launch"):
+            first, self.kv_k, self.kv_v, self._rng = self._prefill_batch_guided(
+                self.params, self.kv_k, self.kv_v, toks, positions, tables,
+                ctx_lens, last_idx, samp, self._rng, pen_rows, mask,
+            )
         return first
 
     def _lora_operand(self, idx):
@@ -2989,98 +3011,82 @@ class JaxEngine:
             "idx": jnp.asarray(idx),
         }
 
-    def _dev_prefill_lora(self, toks, positions, tables, ctx_lens, last_idx,
-                          temps, top_ks, top_ps, seeds, pens, pen_rows, idx):
-        samp = SamplingParams(
-            temperature=jnp.asarray(temps),
-            top_k=jnp.asarray(top_ks),
-            top_p=jnp.asarray(top_ps),
-            seed=jnp.asarray(seeds),
-            presence=jnp.asarray(pens[:, 0]),
-            frequency=jnp.asarray(pens[:, 1]),
-            repetition=jnp.asarray(pens[:, 2]),
-        )
-        first, self.kv_k, self.kv_v, self._rng = self._prefill_batch_lora(
-            self.params,
-            self.kv_k,
-            self.kv_v,
-            jnp.asarray(toks),
-            jnp.asarray(positions),
-            jnp.asarray(tables),
-            jnp.asarray(ctx_lens),
-            jnp.asarray(last_idx),
-            samp,
-            self._rng,
-            jnp.asarray(pen_rows),
-            self._lora_operand(idx),
-        )
+    def _dev_prefill_lora(self, *operands):
+        *operands, idx = operands
+        toks, positions, tables, ctx_lens, last_idx, samp, pen_rows = \
+            self._prefill_operands(*operands)
+        with self._rec.span("put", more=True):
+            lora = self._lora_operand(idx)
+        with self._rec.span("launch"):
+            first, self.kv_k, self.kv_v, self._rng = self._prefill_batch_lora(
+                self.params, self.kv_k, self.kv_v, toks, positions, tables,
+                ctx_lens, last_idx, samp, self._rng, pen_rows, lora,
+            )
         return first
 
     def _dev_block_lora(self, idx):
         carry = self._carry
-        (
-            toks,
-            tok_d,
-            pos_d,
-            sl_d,
-            self.kv_k,
-            self.kv_v,
-            self._rng,
-            self._pen_dev,
-        ) = self._decode_block_lora(
-            self.params,
-            self.kv_k,
-            self.kv_v,
-            carry[0],
-            carry[1],
-            carry[2],
-            self._tables_dev,
-            self._samp_dev,
-            self._rng,
-            self._pen_dev,
-            self._lora_operand(idx),
-        )
+        with self._rec.span("put"):
+            lora = self._lora_operand(idx)
+        with self._rec.span("launch"):
+            (
+                toks, tok_d, pos_d, sl_d,
+                self.kv_k, self.kv_v, self._rng, self._pen_dev,
+            ) = self._decode_block_lora(
+                self.params, self.kv_k, self.kv_v,
+                carry[0], carry[1], carry[2],
+                self._tables_dev, self._samp_dev, self._rng, self._pen_dev,
+                lora,
+            )
         self._carry = (tok_d, pos_d, sl_d)
         return toks
 
     def _dev_reset(self, tokens, positions, seq_lens, page_tables, temps,
                    top_ks, top_ps, seeds, pens, recent, hist=None):
-        self._samp_dev = SamplingParams(
-            temperature=jnp.asarray(temps),
-            top_k=jnp.asarray(top_ks),
-            top_p=jnp.asarray(top_ps),
-            seed=jnp.asarray(seeds),
-            presence=jnp.asarray(pens[:, 0]),
-            frequency=jnp.asarray(pens[:, 1]),
-            repetition=jnp.asarray(pens[:, 2]),
-        )
-        self._carry = (
-            jnp.asarray(tokens),
-            jnp.asarray(positions),
-            jnp.asarray(seq_lens),
-        )
-        self._pen_dev = jnp.asarray(recent)
-        self._tables_dev = jnp.asarray(page_tables)
-        if hist is not None:
-            self._hist_dev = jnp.asarray(hist)
+        with self._rec.span("put"):
+            self._samp_dev = self._samp_operand(
+                temps, top_ks, top_ps, seeds, pens
+            )
+            self._carry = (
+                jnp.asarray(tokens),
+                jnp.asarray(positions),
+                jnp.asarray(seq_lens),
+            )
+            self._pen_dev = jnp.asarray(recent)
+            self._tables_dev = jnp.asarray(page_tables)
+            if hist is not None:
+                self._hist_dev = jnp.asarray(hist)
 
     def _dev_patch(self, lane_mask, table_mask, tokens, positions, seq_lens,
                    tables, temps, top_ks, top_ps, seeds, pens, recent,
                    hist=None):
         samp = self._samp_dev
-        (
-            tok_d, pos_d, sl_d, tab_d, t_d, k_d, p_d, s_d,
-            pres_d, freq_d, rep_d, rec_d,
-        ) = self._patch_lanes(
-            self._carry[0], self._carry[1], self._carry[2], self._tables_dev,
-            samp.temperature, samp.top_k, samp.top_p, samp.seed,
-            samp.presence, samp.frequency, samp.repetition, self._pen_dev,
-            jnp.asarray(lane_mask), jnp.asarray(table_mask),
-            jnp.asarray(tokens), jnp.asarray(positions), jnp.asarray(seq_lens),
-            jnp.asarray(tables), jnp.asarray(temps), jnp.asarray(top_ks),
-            jnp.asarray(top_ps), jnp.asarray(seeds), jnp.asarray(pens),
-            jnp.asarray(recent),
-        )
+        with self._rec.span("put"):
+            (
+                lane_mask, table_mask, tokens, positions, seq_lens, tables,
+                temps, top_ks, top_ps, seeds, pens, recent,
+            ) = (
+                jnp.asarray(x) for x in (
+                    lane_mask, table_mask, tokens, positions, seq_lens,
+                    tables, temps, top_ks, top_ps, seeds, pens, recent,
+                )
+            )
+            hist_d = (
+                jnp.asarray(hist)
+                if hist is not None and self._hist_dev is not None else None
+            )
+        with self._rec.span("launch"):
+            (
+                tok_d, pos_d, sl_d, tab_d, t_d, k_d, p_d, s_d,
+                pres_d, freq_d, rep_d, rec_d,
+            ) = self._patch_lanes(
+                self._carry[0], self._carry[1], self._carry[2],
+                self._tables_dev,
+                samp.temperature, samp.top_k, samp.top_p, samp.seed,
+                samp.presence, samp.frequency, samp.repetition, self._pen_dev,
+                lane_mask, table_mask, tokens, positions, seq_lens, tables,
+                temps, top_ks, top_ps, seeds, pens, recent,
+            )
         self._carry = (tok_d, pos_d, sl_d)
         self._tables_dev = tab_d
         self._pen_dev = rec_d
@@ -3088,65 +3094,71 @@ class JaxEngine:
             temperature=t_d, top_k=k_d, top_p=p_d, seed=s_d,
             presence=pres_d, frequency=freq_d, repetition=rep_d,
         )
-        if hist is not None and self._hist_dev is not None:
+        if hist_d is not None:
             # dirty lanes take the host ring row; others keep the (newer)
             # device rows appended by in-flight spec blocks
-            self._hist_dev = jnp.where(
-                jnp.asarray(lane_mask)[:, None], jnp.asarray(hist),
-                self._hist_dev,
-            )
+            with self._rec.span("launch", more=True):
+                self._hist_dev = jnp.where(
+                    lane_mask[:, None], hist_d, self._hist_dev,
+                )
 
     def _dev_block(self):
         carry = self._carry
         if self._spec_block_fn is not None:
-            (
-                toks, n_emit, tok_d, pos_d, sl_d,
-                self.kv_k, self.kv_v, self._rng, self._hist_dev,
-            ) = self._spec_block_fn(
-                self.params, self.kv_k, self.kv_v,
-                carry[0], carry[1], carry[2],
-                self._tables_dev, self._samp_dev, self._rng, self._hist_dev,
-            )
+            with self._rec.span("launch"):
+                (
+                    toks, n_emit, tok_d, pos_d, sl_d,
+                    self.kv_k, self.kv_v, self._rng, self._hist_dev,
+                ) = self._spec_block_fn(
+                    self.params, self.kv_k, self.kv_v,
+                    carry[0], carry[1], carry[2],
+                    self._tables_dev, self._samp_dev, self._rng,
+                    self._hist_dev,
+                )
             self._carry = (tok_d, pos_d, sl_d)
             return (toks, n_emit)
-        (
-            toks,
-            tok_d,
-            pos_d,
-            sl_d,
-            self.kv_k,
-            self.kv_v,
-            self._rng,
-            self._pen_dev,
-        ) = self._decode_block(
-            self.params,
-            self.kv_k,
-            self.kv_v,
-            carry[0],
-            carry[1],
-            carry[2],
-            self._tables_dev,
-            self._samp_dev,
-            self._rng,
-            self._pen_dev,
-        )
+        with self._rec.span("launch"):
+            (
+                toks,
+                tok_d,
+                pos_d,
+                sl_d,
+                self.kv_k,
+                self.kv_v,
+                self._rng,
+                self._pen_dev,
+            ) = self._decode_block(
+                self.params,
+                self.kv_k,
+                self.kv_v,
+                carry[0],
+                carry[1],
+                carry[2],
+                self._tables_dev,
+                self._samp_dev,
+                self._rng,
+                self._pen_dev,
+            )
         self._carry = (tok_d, pos_d, sl_d)
         return toks
 
     def _dev_block_guided(self, mask, lora_idx=None):
         carry = self._carry
-        args = (
-            self.params, self.kv_k, self.kv_v,
-            carry[0], carry[1], carry[2],
-            self._tables_dev, self._samp_dev, self._rng,
-            jnp.asarray(mask), self._pen_dev,
-        )
-        if lora_idx is not None:
-            out = self._decode_step_guided_lora(
-                *args, self._lora_operand(lora_idx)
+        with self._rec.span("put"):
+            args = (
+                self.params, self.kv_k, self.kv_v,
+                carry[0], carry[1], carry[2],
+                self._tables_dev, self._samp_dev, self._rng,
+                jnp.asarray(mask), self._pen_dev,
             )
-        else:
-            out = self._decode_step_guided(*args)
+            lora = (
+                self._lora_operand(lora_idx) if lora_idx is not None else None
+            )
+        with self._rec.span("launch"):
+            if lora is not None:
+                out = self._decode_step_guided_lora(*args, lora)
+            else:
+                out = self._decode_step_guided(*args)
         (
             toks, tok_d, pos_d, sl_d, self.kv_k, self.kv_v, self._rng,
             self._pen_dev,
@@ -3162,15 +3174,16 @@ class JaxEngine:
         # quantized payloads arrive as packed uint8 [L, n, PB] rows
         # (q bytes + scales, the host/wire layout) and unpack into the
         # QuantKV leaves here; fp payloads are the seed's jnp.asarray
-        self.kv_k, self.kv_v = self._inject_pages(
-            self.kv_k,
-            self.kv_v,
-            jnp.asarray(page_ids),
-            device_pages(k_np, mode, self.config.page_size,
-                         c.num_kv_heads, c.head_dim),
-            device_pages(v_np, mode, self.config.page_size,
-                         c.num_kv_heads, c.head_dim),
-        )
+        with self._rec.span("put"):
+            ids = jnp.asarray(page_ids)
+            k_pages = device_pages(k_np, mode, self.config.page_size,
+                                   c.num_kv_heads, c.head_dim)
+            v_pages = device_pages(v_np, mode, self.config.page_size,
+                                   c.num_kv_heads, c.head_dim)
+        with self._rec.span("launch"):
+            self.kv_k, self.kv_v = self._inject_pages(
+                self.kv_k, self.kv_v, ids, k_pages, v_pages
+            )
 
     def _dev_extract(self, page_ids):
         """Gather pages to host (disagg KV hand-off). On a multi-host mesh
@@ -3798,242 +3811,245 @@ class JaxEngine:
         scratch tail entry for padded positions — so compile variants stay
         few and cacheable."""
         cfg = self.config
-        cands = []
-        for s in self.slots:
-            # prefill_pos has a single writer per LIVE slot (this dispatch
-            # path); the pull-failure fallback rewrite only reaches slots
-            # excluded from cands while their pull is in flight
-            if s is None or s.prefill_pos >= len(s.kv_prompt):  # dynolint: disable=race-await-atomicity -- single writer per live slot; pull-path slots are filtered below
-                continue
-            if s.preloaded is not None or s.onboard is not None:
-                continue
-            if s.done or s.context.is_stopped():
-                self._emit_finish(s, "cancelled")
-                self._release_slot(s)
-                continue
-            self._try_skip_ahead(s)
-            cands.append(s)
-        if not cands:
-            return False
-        # dynosched: candidate order is the planner's call — fifo is the
-        # legacy admit_seq sort bit-for-bit, sla is EDF over TTFT deadlines
-        # with a starvation guard (docs/scheduler.md)
-        cands = self.scheduler.order(cands)
-        # guided / multimodal / LoRA slots ride different dispatch variants
-        # (mask vs embedding splice vs adapter stack) and never share a
-        # prefill batch with each OTHER; plain slots batch with any single
-        # kind (they are exact no-ops under mask=all-true or adapter 0).
-        # The excluded kind waits for a later dispatch — the planner's aging
-        # tiebreak bounds that wait (a kind skipped starve_dispatches times
-        # wins the batch outright, so no kind starves under a steady stream
-        # of another kind).
-        def _kind(s):
-            if s.mm is not None:
-                return "mm"
-            if s.guided_fsm is not None:
-                return "guided"
-            if s.lora_idx:
-                return "lora"
-            return "plain"
-
-        batch_kind = self.scheduler.pick_batch_kind(cands, _kind)
-        if batch_kind != "plain":
-            excluded = [s for s in cands if _kind(s) not in ("plain", batch_kind)]
-            if excluded:
-                for s in excluded:
-                    s.sched_skips += 1
-                cands = [s for s in cands if _kind(s) in ("plain", batch_kind)]
-
-        if self._prefill_single is not None:
-            s0 = cands[0]
-            remaining = len(s0.kv_prompt) - s0.prefill_pos
-            # pp: every prompt goes through the pipelined single-seq path
-            # (layer-sharded weights make the batched path degenerate);
-            # sp: only fresh long prompts ride the ring (history-free).
-            # Multimodal slots never ride it (splice unsupported there —
-            # _check_multimodal rejects those configs up front).
-            use_single = not s0.mm and (
-                cfg.pp_size > 1
-                or (s0.prefill_pos == 0 and remaining >= cfg.ring_prefill_threshold)
-            )
-            if use_single:
-                await self._dispatch_prefill_one(s0)
-                return True
-        # two lane variants per bucket — 1 (the lone-arrival TTFT case:
-        # padding one request to the full lane budget multiplies its
-        # prefill FLOPs by the budget) and the cap (batch case). Exactly
-        # two keeps the lazily-compiled shape set small: every new shape
-        # costs a multi-second XLA compile ON the serving path the first
-        # time it occurs (persistent cache amortizes across restarts).
-        # The planner chooses WITHIN that bounded shape space: fifo
-        # reproduces the legacy head-candidate formula exactly; sla scores
-        # shapes by slots-served/tokens-granted under the ITL budget and
-        # may defer the dispatch entirely to protect decode cadence.
-        has_decode = bool(self._active_decode_indices())
-        plan = self.scheduler.plan_prefill(cands, decode_active=has_decode)
-        if plan is None:
-            # ITL budget exhausted and no deadline at risk: prefill yields
-            # this step; skipped candidates age toward the starvation guard
-            for s in cands:
-                s.sched_skips += 1
-            return False
-        bucket = plan.bucket
-        lanes = plan.lanes
-        chosen = plan.chosen
-        for s in cands[len(chosen):]:
-            s.sched_skips += 1
-        B_pf = lanes
-
-        # shared context-bounded table: pow2 pages covering the largest
-        # (history + chunk), plus one guaranteed-scratch tail entry that
-        # padded positions write to
-        chunk_of = {}
-        max_pages_needed = 1
-        for s in chosen:
-            chunk = min(len(s.kv_prompt) - s.prefill_pos, bucket)
-            chunk_of[s.request_id] = chunk
-            pages_needed = (s.prefill_pos + chunk + cfg.page_size - 1) // cfg.page_size
-            max_pages_needed = max(max_pages_needed, pages_needed)
-        ctx_pages = min(_next_pow2(max_pages_needed), cfg.max_pages_per_seq)
-        P = ctx_pages + 1
-        pad_pos = P * cfg.page_size - 1
-
-        toks = np.zeros((B_pf, bucket), np.int32)
-        positions = np.full((B_pf, bucket), pad_pos, np.int32)
-        tables = np.full((B_pf, P), SCRATCH_PAGE, np.int32)
-        ctx_lens = np.zeros((B_pf,), np.int32)
-        last_idx = np.zeros((B_pf,), np.int32)
-        temps = np.zeros((B_pf,), np.float32)
-        top_ks = np.zeros((B_pf,), np.int32)
-        top_ps = np.ones((B_pf,), np.float32)
-        seeds = np.zeros((B_pf,), np.uint32)
-        pens = np.zeros((B_pf, 3), np.float32)
-        pens[:, 2] = 1.0  # repetition off
-        W = self.config.penalty_window
-        pen_rows = np.full((B_pf, W), -1, np.int32)
-        meta = []
-        for lane, s in enumerate(chosen):
-            chunk = chunk_of[s.request_id]
-            start = s.prefill_pos
-            toks[lane, :chunk] = s.kv_prompt[start : start + chunk]
-            positions[lane, :chunk] = np.arange(start, start + chunk)
-            tables[lane, :ctx_pages] = self.page_tables[s.slot_idx][:ctx_pages]
-            ctx_lens[lane] = start
-            last_idx[lane] = chunk - 1
-            temps[lane] = s.temperature
-            top_ks[lane] = s.top_k
-            top_ps[lane] = s.top_p
-            seeds[lane] = s.sample_seed
-            pens[lane] = (s.presence_penalty, s.frequency_penalty,
-                          s.repetition_penalty)
-            pen_rows[lane] = self.recent[s.slot_idx]
-            s.sched_skips = 0  # granted a chunk: starvation clock restarts
-            meta.append((s, chunk, lane))
-        self._last_prefill_shape = (
-            B_pf * bucket, sum(ch for _, ch, _ in meta)
-        )
-        self._count_expert_rows(*self._last_prefill_shape)
-
-        if any(s.mm for s in chosen):
-            # multimodal splice operands: encoder rows land at their
-            # absolute prompt positions within this chunk window
-            H = self.model_config.hidden_size
-            emb = np.zeros((B_pf, bucket, H), np.float32)
-            emb_mask = np.zeros((B_pf, bucket), bool)
-            for s, chunk, lane in meta:
-                if not s.mm:
+        single = None
+        with self._rec.span("pack", more=True):
+            cands = []
+            for s in self.slots:
+                # prefill_pos has a single writer per LIVE slot (this dispatch
+                # path); the pull-failure fallback rewrite only reaches slots
+                # excluded from cands while their pull is in flight
+                if s is None or s.prefill_pos >= len(s.kv_prompt):  # dynolint: disable=race-await-atomicity -- single writer per live slot; pull-path slots are filtered below
                     continue
-                start = s.prefill_pos  # chunk window [start, start+chunk)
-                for pos0, arr in s.mm:
-                    lo, hi = max(pos0, start), min(pos0 + len(arr), start + chunk)
-                    if lo < hi:
-                        emb[lane, lo - start : hi - start] = arr[lo - pos0 : hi - pos0]
-                        emb_mask[lane, lo - start : hi - start] = True
-            self._bcast(
-                "prefill_mm",
-                {
-                    "toks": toks, "positions": positions, "tables": tables,
-                    "ctx_lens": ctx_lens, "last_idx": last_idx, "temps": temps,
-                    "top_ks": top_ks, "top_ps": top_ps, "seeds": seeds,
-                    "pens": pens, "pen_rows": pen_rows,
-                    "emb": emb, "emb_mask": emb_mask,
-                },
+                if s.preloaded is not None or s.onboard is not None:
+                    continue
+                if s.done or s.context.is_stopped():
+                    self._emit_finish(s, "cancelled")
+                    self._release_slot(s)
+                    continue
+                self._try_skip_ahead(s)
+                cands.append(s)
+            if not cands:
+                return False
+            # dynosched: candidate order is the planner's call — fifo is the
+            # legacy admit_seq sort bit-for-bit, sla is EDF over TTFT deadlines
+            # with a starvation guard (docs/scheduler.md)
+            cands = self.scheduler.order(cands)
+            # guided / multimodal / LoRA slots ride different dispatch variants
+            # (mask vs embedding splice vs adapter stack) and never share a
+            # prefill batch with each OTHER; plain slots batch with any single
+            # kind (they are exact no-ops under mask=all-true or adapter 0).
+            # The excluded kind waits for a later dispatch — the planner's aging
+            # tiebreak bounds that wait (a kind skipped starve_dispatches times
+            # wins the batch outright, so no kind starves under a steady stream
+            # of another kind).
+            def _kind(s):
+                if s.mm is not None:
+                    return "mm"
+                if s.guided_fsm is not None:
+                    return "guided"
+                if s.lora_idx:
+                    return "lora"
+                return "plain"
+
+            batch_kind = self.scheduler.pick_batch_kind(cands, _kind)
+            if batch_kind != "plain":
+                excluded = [s for s in cands if _kind(s) not in ("plain", batch_kind)]
+                if excluded:
+                    for s in excluded:
+                        s.sched_skips += 1
+                    cands = [s for s in cands if _kind(s) in ("plain", batch_kind)]
+
+            if self._prefill_single is not None:
+                s0 = cands[0]
+                remaining = len(s0.kv_prompt) - s0.prefill_pos
+                # pp: every prompt goes through the pipelined single-seq path
+                # (layer-sharded weights make the batched path degenerate);
+                # sp: only fresh long prompts ride the ring (history-free).
+                # Multimodal slots never ride it (splice unsupported there —
+                # _check_multimodal rejects those configs up front).
+                use_single = not s0.mm and (
+                    cfg.pp_size > 1
+                    or (s0.prefill_pos == 0 and remaining >= cfg.ring_prefill_threshold)
+                )
+                if use_single:
+                    single = s0
+        if single is not None:
+            await self._dispatch_prefill_one(single)
+            return True
+        self._rec.entry_kind = "prefill"
+        with self._rec.span("pack"):
+            # two lane variants per bucket — 1 (the lone-arrival TTFT case:
+            # padding one request to the full lane budget multiplies its
+            # prefill FLOPs by the budget) and the cap (batch case). Exactly
+            # two keeps the lazily-compiled shape set small: every new shape
+            # costs a multi-second XLA compile ON the serving path the first
+            # time it occurs (persistent cache amortizes across restarts).
+            # The planner chooses WITHIN that bounded shape space: fifo
+            # reproduces the legacy head-candidate formula exactly; sla scores
+            # shapes by slots-served/tokens-granted under the ITL budget and
+            # may defer the dispatch entirely to protect decode cadence.
+            has_decode = bool(self._active_decode_indices())
+            plan = self.scheduler.plan_prefill(cands, decode_active=has_decode)
+            if plan is None:
+                # ITL budget exhausted and no deadline at risk: prefill yields
+                # this step; skipped candidates age toward the starvation guard
+                for s in cands:
+                    s.sched_skips += 1
+                return False
+            bucket = plan.bucket
+            lanes = plan.lanes
+            chosen = plan.chosen
+            for s in cands[len(chosen):]:
+                s.sched_skips += 1
+            B_pf = lanes
+
+            # shared context-bounded table: pow2 pages covering the largest
+            # (history + chunk), plus one guaranteed-scratch tail entry that
+            # padded positions write to
+            chunk_of = {}
+            max_pages_needed = 1
+            for s in chosen:
+                chunk = min(len(s.kv_prompt) - s.prefill_pos, bucket)
+                chunk_of[s.request_id] = chunk
+                pages_needed = (s.prefill_pos + chunk + cfg.page_size - 1) // cfg.page_size
+                max_pages_needed = max(max_pages_needed, pages_needed)
+            ctx_pages = min(_next_pow2(max_pages_needed), cfg.max_pages_per_seq)
+            P = ctx_pages + 1
+            pad_pos = P * cfg.page_size - 1
+
+            toks = np.zeros((B_pf, bucket), np.int32)
+            positions = np.full((B_pf, bucket), pad_pos, np.int32)
+            tables = np.full((B_pf, P), SCRATCH_PAGE, np.int32)
+            ctx_lens = np.zeros((B_pf,), np.int32)
+            last_idx = np.zeros((B_pf,), np.int32)
+            temps = np.zeros((B_pf,), np.float32)
+            top_ks = np.zeros((B_pf,), np.int32)
+            top_ps = np.ones((B_pf,), np.float32)
+            seeds = np.zeros((B_pf,), np.uint32)
+            pens = np.zeros((B_pf, 3), np.float32)
+            pens[:, 2] = 1.0  # repetition off
+            W = self.config.penalty_window
+            pen_rows = np.full((B_pf, W), -1, np.int32)
+            meta = []
+            for lane, s in enumerate(chosen):
+                chunk = chunk_of[s.request_id]
+                start = s.prefill_pos
+                toks[lane, :chunk] = s.kv_prompt[start : start + chunk]
+                positions[lane, :chunk] = np.arange(start, start + chunk)
+                tables[lane, :ctx_pages] = self.page_tables[s.slot_idx][:ctx_pages]
+                ctx_lens[lane] = start
+                last_idx[lane] = chunk - 1
+                temps[lane] = s.temperature
+                top_ks[lane] = s.top_k
+                top_ps[lane] = s.top_p
+                seeds[lane] = s.sample_seed
+                pens[lane] = (s.presence_penalty, s.frequency_penalty,
+                              s.repetition_penalty)
+                pen_rows[lane] = self.recent[s.slot_idx]
+                s.sched_skips = 0  # granted a chunk: starvation clock restarts
+                meta.append((s, chunk, lane))
+            self._last_prefill_shape = (
+                B_pf * bucket, sum(ch for _, ch, _ in meta)
             )
-            first_dev = await self._run_on_device(
-                partial(
+            self._count_expert_rows(*self._last_prefill_shape)
+
+            if any(s.mm for s in chosen):
+                # multimodal splice operands: encoder rows land at their
+                # absolute prompt positions within this chunk window
+                H = self.model_config.hidden_size
+                emb = np.zeros((B_pf, bucket, H), np.float32)
+                emb_mask = np.zeros((B_pf, bucket), bool)
+                for s, chunk, lane in meta:
+                    if not s.mm:
+                        continue
+                    start = s.prefill_pos  # chunk window [start, start+chunk)
+                    for pos0, arr in s.mm:
+                        lo, hi = max(pos0, start), min(pos0 + len(arr), start + chunk)
+                        if lo < hi:
+                            emb[lane, lo - start : hi - start] = arr[lo - pos0 : hi - pos0]
+                            emb_mask[lane, lo - start : hi - start] = True
+                self._bcast(
+                    "prefill_mm",
+                    {
+                        "toks": toks, "positions": positions, "tables": tables,
+                        "ctx_lens": ctx_lens, "last_idx": last_idx, "temps": temps,
+                        "top_ks": top_ks, "top_ps": top_ps, "seeds": seeds,
+                        "pens": pens, "pen_rows": pen_rows,
+                        "emb": emb, "emb_mask": emb_mask,
+                    },
+                )
+                call = partial(
                     self._dev_prefill_mm,
                     toks, positions, tables, ctx_lens, last_idx,
                     temps, top_ks, top_ps, seeds, pens, pen_rows,
                     emb, emb_mask,
-                ),
-                tag="prefill", shape=(bucket, B_pf),
-            )
-        elif any(s.guided_fsm is not None for s in chosen):
-            # masked first-token sampling: guided lanes constrain the first
-            # generated token the same way decode steps are constrained
-            V = self.model_config.vocab_size
-            mask = np.full((B_pf, (V + 7) // 8), 0xFF, np.uint8)
-            for s, chunk, lane in meta:
-                if s.guided_fsm is not None:
-                    mask[lane] = np.packbits(self._guided_lane_mask(
-                        s.guided_fsm, s.guided_state
-                    ))
-            self._bcast(
-                "prefill_guided",
-                {
-                    "toks": toks, "positions": positions, "tables": tables,
-                    "ctx_lens": ctx_lens, "last_idx": last_idx, "temps": temps,
-                    "top_ks": top_ks, "top_ps": top_ps, "seeds": seeds,
-                    "pens": pens, "pen_rows": pen_rows, "mask": mask,
-                },
-            )
-            first_dev = await self._run_on_device(
-                partial(
+                )
+            elif any(s.guided_fsm is not None for s in chosen):
+                # masked first-token sampling: guided lanes constrain the first
+                # generated token the same way decode steps are constrained
+                V = self.model_config.vocab_size
+                mask = np.full((B_pf, (V + 7) // 8), 0xFF, np.uint8)
+                for s, chunk, lane in meta:
+                    if s.guided_fsm is not None:
+                        mask[lane] = np.packbits(self._guided_lane_mask(
+                            s.guided_fsm, s.guided_state
+                        ))
+                self._bcast(
+                    "prefill_guided",
+                    {
+                        "toks": toks, "positions": positions, "tables": tables,
+                        "ctx_lens": ctx_lens, "last_idx": last_idx, "temps": temps,
+                        "top_ks": top_ks, "top_ps": top_ps, "seeds": seeds,
+                        "pens": pens, "pen_rows": pen_rows, "mask": mask,
+                    },
+                )
+                call = partial(
                     self._dev_prefill_guided,
                     toks, positions, tables, ctx_lens, last_idx,
                     temps, top_ks, top_ps, seeds, pens, pen_rows, mask,
-                ),
-                tag="prefill", shape=(bucket, B_pf),
-            )
-        elif any(s.lora_idx for s in chosen):
-            lane_idx = np.zeros((B_pf,), np.int32)
-            for s, chunk, lane in meta:
-                lane_idx[lane] = s.lora_idx
-            self._bcast(
-                "prefill_lora",
-                {
-                    "toks": toks, "positions": positions, "tables": tables,
-                    "ctx_lens": ctx_lens, "last_idx": last_idx, "temps": temps,
-                    "top_ks": top_ks, "top_ps": top_ps, "seeds": seeds,
-                    "pens": pens, "pen_rows": pen_rows, "idx": lane_idx,
-                },
-            )
-            first_dev = await self._run_on_device(
-                partial(
+                )
+            elif any(s.lora_idx for s in chosen):
+                lane_idx = np.zeros((B_pf,), np.int32)
+                for s, chunk, lane in meta:
+                    lane_idx[lane] = s.lora_idx
+                self._bcast(
+                    "prefill_lora",
+                    {
+                        "toks": toks, "positions": positions, "tables": tables,
+                        "ctx_lens": ctx_lens, "last_idx": last_idx, "temps": temps,
+                        "top_ks": top_ks, "top_ps": top_ps, "seeds": seeds,
+                        "pens": pens, "pen_rows": pen_rows, "idx": lane_idx,
+                    },
+                )
+                call = partial(
                     self._dev_prefill_lora,
                     toks, positions, tables, ctx_lens, last_idx,
                     temps, top_ks, top_ps, seeds, pens, pen_rows, lane_idx,
-                ),
-                tag="prefill", shape=(bucket, B_pf),
-            )
-        else:
-            self._bcast(
-                "prefill",
-                {
-                    "toks": toks, "positions": positions, "tables": tables,
-                    "ctx_lens": ctx_lens, "last_idx": last_idx, "temps": temps,
-                    "top_ks": top_ks, "top_ps": top_ps, "seeds": seeds,
-                    "pens": pens, "pen_rows": pen_rows,
-                },
-            )
-            first_dev = await self._run_on_device(
-                partial(
+                )
+            else:
+                self._bcast(
+                    "prefill",
+                    {
+                        "toks": toks, "positions": positions, "tables": tables,
+                        "ctx_lens": ctx_lens, "last_idx": last_idx, "temps": temps,
+                        "top_ks": top_ks, "top_ps": top_ps, "seeds": seeds,
+                        "pens": pens, "pen_rows": pen_rows,
+                    },
+                )
+                call = partial(
                     self._dev_prefill,
                     toks, positions, tables, ctx_lens, last_idx, temps,
                     top_ks, top_ps, seeds, pens, pen_rows,
-                ),
-                tag="prefill", shape=(bucket, B_pf),
-            )
+                )
+            work = Work()
+            for s, chunk, lane in meta:
+                work.chunk(s.prefill_pos, chunk,
+                           s.prefill_pos + chunk >= len(s.kv_prompt))
+            entry = {}
+            self._rec.dispatched(entry, "prefill", work.of(self._step_work))
+        entry["first"] = await self._run_on_device(
+            call, tag="prefill", shape=(bucket, B_pf)
+        )
         completions = []
         progressed = []
         for s, chunk, lane in meta:
@@ -4042,9 +4058,8 @@ class JaxEngine:
             progressed.append((s, s.prefill_pos))
             if s.prefill_pos >= len(s.kv_prompt):
                 completions.append((s, lane))
-        self._pending_prefill.append(
-            {"first": first_dev, "done": completions, "progressed": progressed}
-        )
+        entry.update(done=completions, progressed=progressed)
+        self._pending_prefill.append(entry)
         return True
 
     async def _dispatch_prefill_one(self, slot: _Slot) -> None:
@@ -4052,38 +4067,44 @@ class JaxEngine:
         parallel path (_prefill_single: ring over sp / pipeline over pp).
         Pads to a pow2 bucket so compile variants stay bounded."""
         cfg = self.config
-        chunk = len(slot.kv_prompt) - slot.prefill_pos
-        unit = max(cfg.sp_size, cfg.pp_size, 1)
-        # pow2 bucket for bounded compile variants, then round UP to a unit
-        # multiple (a non-pow2 sp/pp size would otherwise fail the ring's
-        # divisibility check)
-        T_pad = _next_pow2(chunk)
-        T_pad = -(-T_pad // unit) * unit
-        pages_needed = (slot.prefill_pos + chunk + cfg.page_size - 1) // cfg.page_size
-        P = min(_next_pow2(pages_needed), cfg.max_pages_per_seq) + 1
-        table = np.full((P,), SCRATCH_PAGE, np.int32)
-        table[: min(len(slot.pages), P)] = [p + 1 for p in slot.pages[:P]]
-        toks = np.zeros((T_pad,), np.int32)
-        toks[:chunk] = slot.kv_prompt[slot.prefill_pos :]
-        ctx = np.int32(slot.prefill_pos)
-        real = np.int32(chunk)
-        temps = np.array([slot.temperature], np.float32)
-        top_ks = np.array([slot.top_k], np.int32)
-        top_ps = np.array([slot.top_p], np.float32)
-        seeds = np.array([slot.sample_seed], np.uint32)
-        pens = np.array([[slot.presence_penalty, slot.frequency_penalty,
-                          slot.repetition_penalty]], np.float32)
-        pen_rows = self.recent[slot.slot_idx : slot.slot_idx + 1]
-        self._bcast(
-            "prefill_single",
-            {
-                "toks": toks, "table": table, "ctx": np.array([ctx]),
-                "real": np.array([real]), "temps": temps,
-                "top_ks": top_ks, "top_ps": top_ps, "seeds": seeds,
-                "pens": pens, "pen_rows": pen_rows,
-            },
-        )
-        first_dev = await self._run_on_device(
+        self._rec.entry_kind = "prefill"
+        with self._rec.span("pack"):
+            chunk = len(slot.kv_prompt) - slot.prefill_pos
+            unit = max(cfg.sp_size, cfg.pp_size, 1)
+            # pow2 bucket for bounded compile variants, then round UP to a unit
+            # multiple (a non-pow2 sp/pp size would otherwise fail the ring's
+            # divisibility check)
+            T_pad = _next_pow2(chunk)
+            T_pad = -(-T_pad // unit) * unit
+            pages_needed = (slot.prefill_pos + chunk + cfg.page_size - 1) // cfg.page_size
+            P = min(_next_pow2(pages_needed), cfg.max_pages_per_seq) + 1
+            table = np.full((P,), SCRATCH_PAGE, np.int32)
+            table[: min(len(slot.pages), P)] = [p + 1 for p in slot.pages[:P]]
+            toks = np.zeros((T_pad,), np.int32)
+            toks[:chunk] = slot.kv_prompt[slot.prefill_pos :]
+            ctx = np.int32(slot.prefill_pos)
+            real = np.int32(chunk)
+            temps = np.array([slot.temperature], np.float32)
+            top_ks = np.array([slot.top_k], np.int32)
+            top_ps = np.array([slot.top_p], np.float32)
+            seeds = np.array([slot.sample_seed], np.uint32)
+            pens = np.array([[slot.presence_penalty, slot.frequency_penalty,
+                              slot.repetition_penalty]], np.float32)
+            pen_rows = self.recent[slot.slot_idx : slot.slot_idx + 1]
+            self._bcast(
+                "prefill_single",
+                {
+                    "toks": toks, "table": table, "ctx": np.array([ctx]),
+                    "real": np.array([real]), "temps": temps,
+                    "top_ks": top_ks, "top_ps": top_ps, "seeds": seeds,
+                    "pens": pens, "pen_rows": pen_rows,
+                },
+            )
+            work = Work()
+            work.chunk(slot.prefill_pos, chunk, True)
+            entry = {"done": [(slot, 0)]}
+            self._rec.dispatched(entry, "prefill", work.of(self._step_work))
+        entry["first"] = await self._run_on_device(
             partial(self._dev_prefill_single, toks, table, ctx, real, temps,
                     top_ks, top_ps, seeds, pens, pen_rows),
             tag="prefill", shape=(T_pad, 1),
@@ -4091,25 +4112,20 @@ class JaxEngine:
         self._last_prefill_shape = (T_pad, chunk)
         self._count_expert_rows(T_pad, chunk)
         slot.prefill_pos += chunk
-        self._pending_prefill.append({"first": first_dev, "done": [(slot, 0)]})
+        self._pending_prefill.append(entry)
 
     def _dev_prefill_single(self, toks, table, ctx, real, temps, top_ks,
                             top_ps, seeds, pens, pen_rows):
-        samp = SamplingParams(
-            temperature=jnp.asarray(temps),
-            top_k=jnp.asarray(top_ks),
-            top_p=jnp.asarray(top_ps),
-            seed=jnp.asarray(seeds),
-            presence=jnp.asarray(pens[:, 0]),
-            frequency=jnp.asarray(pens[:, 1]),
-            repetition=jnp.asarray(pens[:, 2]),
-        )
-        first, self.kv_k, self.kv_v, self._rng = self._prefill_single(
-            self.params, self.kv_k, self.kv_v,
-            jnp.asarray(toks), jnp.asarray(table),
-            jnp.asarray(ctx, jnp.int32), jnp.asarray(real, jnp.int32),
-            self._rng, samp, jnp.asarray(pen_rows),
-        )
+        with self._rec.span("put"):
+            toks, table, pen_rows = (
+                jnp.asarray(toks), jnp.asarray(table), jnp.asarray(pen_rows))
+            ctx, real = jnp.asarray(ctx, jnp.int32), jnp.asarray(real, jnp.int32)
+            samp = self._samp_operand(temps, top_ks, top_ps, seeds, pens)
+        with self._rec.span("launch"):
+            first, self.kv_k, self.kv_v, self._rng = self._prefill_single(
+                self.params, self.kv_k, self.kv_v, toks, table, ctx, real,
+                self._rng, samp, pen_rows,
+            )
         return first
 
     def _fill_recent(self, idx: int, slot: _Slot):
@@ -4202,6 +4218,9 @@ class JaxEngine:
         from ..llm.disagg import pack_kv_payload
 
         cfg = self.config
+        # the prefill role's first token leaves with the pages, by whichever
+        # of the three roads below
+        self._rec.first_token(slot)
         n_prompt_pages = (len(slot.prompt) + cfg.page_size - 1) // cfg.page_size
         page_ids = np.array(
             [p + 1 for p in slot.pages[:n_prompt_pages]], np.int32
@@ -4678,320 +4697,328 @@ class JaxEngine:
         q-tile layout the Pallas ragged kernel needs is the forward's
         own, for q alone (models/llama.py:ragged_forward)."""
         cfg = self.config
-        self._mixed_wait_drain = False
-        if not self._mixed_enabled:
-            return False
-        active = self._active_decode_indices()
-        if not active:
-            return False
-        # spec fusion: every spec-eligible decode lane packs 1 + d
-        # one-token verify rows (current token + d host n-gram drafts) —
-        # the verify step IS a ragged mixed batch. Guided lanes stay
-        # single-row (the next mask depends host-side on this token).
-        d = cfg.spec_draft_len if cfg.spec_mode else 0
-        n_spec_rows = sum(
-            d for i in active if self.slots[i].guided_fsm is None
-        ) if d else 0
-        cands = []
-        mm_starved = False
-        for s in self.slots:
-            if s is None or s.prefill_pos >= len(s.kv_prompt):  # dynolint: disable=race-await-atomicity -- single writer per live slot (same shape as _dispatch_prefill); pull-path slots filtered below
-                continue
-            if s.preloaded is not None or s.onboard is not None:
-                continue
-            if s.done or s.context.is_stopped():
-                self._emit_finish(s, "cancelled")
-                self._release_slot(s)
-                continue
-            if s.mm is not None:
-                # multimodal stays split-only (embedding-splice operand):
-                # exclude ONLY this slot — plain + fused kinds still fuse
-                # this step — and age it toward the starvation guard
-                s.sched_skips += 1
-                if s.sched_skips >= self.scheduler.sla.starve_dispatches:
-                    mm_starved = True
-                continue
-            self._try_skip_ahead(s)
-            cands.append(s)
-        if mm_starved:
-            # a starved mm candidate must win the next batch outright:
-            # yield the whole step to the split path, whose
-            # pick_batch_kind starvation override serves it
-            return False
-        if not cands:
-            return False
-        cands = self.scheduler.order(cands)
-        plan = self.scheduler.plan_mixed(
-            cands, n_decode=len(active), n_spec_rows=n_spec_rows,
-        )
-        if plan is None:
-            return False  # nothing fuses (e.g. decode lanes fill the
-            # budget) — split path runs at full rate, no hold
-        pipes = self._pack_pipes(plan.chosen, active)
-        if pipes and self._carry_valid:
-            if len(self._inflight) >= 2:
-                # never more than one entry queued behind the running one:
-                # an arrival admitted at the next wake gets the next entry
+        with self._rec.span("pack", more=True):
+            self._mixed_wait_drain = False
+            if not self._mixed_enabled:
                 return False
-        elif self._inflight or self._pending_prefill:
-            # the entries in flight own these lanes' device carry, and this
-            # pack needs host-authoritative lanes (or the carry is invalid
-            # and is uploaded from them). Signal the step loop to
-            # HOLD the split prefill for one step while the pipeline
-            # drains (the split dispatch would queue behind the in-flight
-            # block on the device stream anyway) — the next step fuses.
-            # Only worth it when a fused step is actually plannable,
-            # hence AFTER the plan check.
-            self._mixed_wait_drain = True
-            # the held step grants nothing: every candidate ages, same as
-            # a plan_prefill defer (the skipped _dispatch_prefill would
-            # otherwise never age them on hold steps)
-            for s in cands:
-                s.sched_skips += 1
-            return False
-        def pack_shape(chunks, lanes):
-            """(variant, ctx_pages) of a pack of these prefill chunks and
-            decode lanes: the lean program or the variant with the mask /
-            adapter operands (a guided or lora row), and the table rung
-            that holds the longest context."""
-            slots = [s for s, _ in chunks] + [self.slots[i] for i in lanes]
-            pages = 1
-            for s, ch in chunks:
-                pages = max(pages, -(-(s.prefill_pos + ch) // cfg.page_size))
-            for i in lanes:
-                extra = d if self.slots[i].guided_fsm is None else 0
-                pages = max(
-                    pages,
-                    (int(self.seq_lens[i]) - 1 + extra) // cfg.page_size + 1,
-                )
-            return (
-                any(s.guided_fsm is not None or s.lora_idx for s in slots),
-                _bucket_for(pages, self._mixed_table_rungs),
+            active = self._active_decode_indices()
+            if not active:
+                return False
+            # spec fusion: every spec-eligible decode lane packs 1 + d
+            # one-token verify rows (current token + d host n-gram drafts) —
+            # the verify step IS a ragged mixed batch. Guided lanes stay
+            # single-row (the next mask depends host-side on this token).
+            d = cfg.spec_draft_len if cfg.spec_mode else 0
+            n_spec_rows = sum(
+                d for i in active if self.slots[i].guided_fsm is None
+            ) if d else 0
+            cands = []
+            mm_starved = False
+            for s in self.slots:
+                if s is None or s.prefill_pos >= len(s.kv_prompt):  # dynolint: disable=race-await-atomicity -- single writer per live slot (same shape as _dispatch_prefill); pull-path slots filtered below
+                    continue
+                if s.preloaded is not None or s.onboard is not None:
+                    continue
+                if s.done or s.context.is_stopped():
+                    self._emit_finish(s, "cancelled")
+                    self._release_slot(s)
+                    continue
+                if s.mm is not None:
+                    # multimodal stays split-only (embedding-splice operand):
+                    # exclude ONLY this slot — plain + fused kinds still fuse
+                    # this step — and age it toward the starvation guard
+                    s.sched_skips += 1
+                    if s.sched_skips >= self.scheduler.sla.starve_dispatches:
+                        mm_starved = True
+                    continue
+                self._try_skip_ahead(s)
+                cands.append(s)
+            if mm_starved:
+                # a starved mm candidate must win the next batch outright:
+                # yield the whole step to the split path, whose
+                # pick_batch_kind starvation override serves it
+                return False
+            if not cands:
+                return False
+            cands = self.scheduler.order(cands)
+            plan = self.scheduler.plan_mixed(
+                cands, n_decode=len(active), n_spec_rows=n_spec_rows,
             )
+            if plan is None:
+                return False  # nothing fuses (e.g. decode lanes fill the
+                # budget) — split path runs at full rate, no hold
+            pipes = self._pack_pipes(plan.chosen, active)
+            if pipes and self._carry_valid:
+                if len(self._inflight) >= 2:
+                    # never more than one entry queued behind the running one:
+                    # an arrival admitted at the next wake gets the next entry
+                    return False
+            elif self._inflight or self._pending_prefill:
+                # the entries in flight own these lanes' device carry, and this
+                # pack needs host-authoritative lanes (or the carry is invalid
+                # and is uploaded from them). Signal the step loop to
+                # HOLD the split prefill for one step while the pipeline
+                # drains (the split dispatch would queue behind the in-flight
+                # block on the device stream anyway) — the next step fuses.
+                # Only worth it when a fused step is actually plannable,
+                # hence AFTER the plan check.
+                self._mixed_wait_drain = True
+                # the held step grants nothing: every candidate ages, same as
+                # a plan_prefill defer (the skipped _dispatch_prefill would
+                # otherwise never age them on hold steps)
+                for s in cands:
+                    s.sched_skips += 1
+                return False
+            def pack_shape(chunks, lanes):
+                """(variant, ctx_pages) of a pack of these prefill chunks and
+                decode lanes: the lean program or the variant with the mask /
+                adapter operands (a guided or lora row), and the table rung
+                that holds the longest context."""
+                slots = [s for s, _ in chunks] + [self.slots[i] for i in lanes]
+                pages = 1
+                for s, ch in chunks:
+                    pages = max(pages, -(-(s.prefill_pos + ch) // cfg.page_size))
+                for i in lanes:
+                    extra = d if self.slots[i].guided_fsm is None else 0
+                    pages = max(
+                        pages,
+                        (int(self.seq_lens[i]) - 1 + extra) // cfg.page_size + 1,
+                    )
+                return (
+                    any(s.guided_fsm is not None or s.lora_idx for s in slots),
+                    _bucket_for(pages, self._mixed_table_rungs),
+                )
 
-        shape = pack_shape(list(zip(plan.chosen, plan.chunks)), active)
+            shape = pack_shape(list(zip(plan.chosen, plan.chunks)), active)
         if shape not in self._mixed_primed:
             # first use: compile the family, then plan again — the await
             # let arrivals and cancellations in, and nothing of this plan
             # is committed yet
             await self._prime_mixed_family(*shape)
             return await self._dispatch_mixed()
-        # one decode step of page headroom (1 + d under spec: draft rows
-        # write KV at speculative positions); growth can preempt —
-        # re-filter both the decode set and the chosen prefill slots
-        active = self._grow_pages_for_block(active, steps=1 + d)
-        if not active:
-            return False
-        chosen = [
-            (s, ch) for s, ch in zip(plan.chosen, plan.chunks)
-            if s.slot_idx >= 0 and self.slots[s.slot_idx] is s
-        ]
-        if not chosen:
-            return False
-        if pipes:
-            # a lane the host already knows will be done before this step
-            # runs (what it has generated and what is in flight reaches its
-            # max_tokens) is left out: its row would be sampled for nothing
-            flying = self._tokens_in_flight()
-            active = [
-                i for i in active
-                if self.slots[i].generated + flying.get(id(self.slots[i]), 0)
-                < self.slots[i].max_tokens
+        self._rec.entry_kind = "mixed"
+        with self._rec.span("pack"):
+            # one decode step of page headroom (1 + d under spec: draft rows
+            # write KV at speculative positions); growth can preempt —
+            # re-filter both the decode set and the chosen prefill slots
+            active = self._grow_pages_for_block(active, steps=1 + d)
+            if not active:
+                return False
+            chosen = [
+                (s, ch) for s, ch in zip(plan.chosen, plan.chunks)
+                if s.slot_idx >= 0 and self.slots[s.slot_idx] is s
             ]
-        # the dispatch is committed from here on — account it (plan_mixed
-        # itself is pure, so an abandoned plan never skews the sched_*
-        # grant counters the split path's plan_prefill also feeds)
-        self.scheduler.commit_mixed(plan, chosen)
-        # candidates the plan passed over age toward the starvation guard,
-        # exactly as on the split path — fused steps must not exempt a
-        # steady tight-deadline stream from starve_dispatches promotion
-        granted_slots = {id(s) for s, _ in chosen}
-        for s in cands:
-            if id(s) not in granted_slots:
-                s.sched_skips += 1
+            if not chosen:
+                return False
+            if pipes:
+                # a lane the host already knows will be done before this step
+                # runs (what it has generated and what is in flight reaches its
+                # max_tokens) is left out: its row would be sampled for nothing
+                flying = self._tokens_in_flight()
+                active = [
+                    i for i in active
+                    if self.slots[i].generated + flying.get(id(self.slots[i]), 0)
+                    < self.slots[i].max_tokens
+                ]
+            # the dispatch is committed from here on — account it (plan_mixed
+            # itself is pure, so an abandoned plan never skews the sched_*
+            # grant counters the split path's plan_prefill also feeds)
+            self.scheduler.commit_mixed(plan, chosen)
+            # candidates the plan passed over age toward the starvation guard,
+            # exactly as on the split path — fused steps must not exempt a
+            # steady tight-deadline stream from starve_dispatches promotion
+            granted_slots = {id(s) for s, _ in chosen}
+            for s in cands:
+                if id(s) not in granted_slots:
+                    s.sched_skips += 1
 
-        # recompute the decode row count against the SURVIVING active set
-        # (page growth can preempt lanes out from under the plan)
-        spec_lanes = {
-            i for i in active
-            if cfg.spec_mode and self.slots[i].guided_fsm is None
-        }
-        n_rows_decode = len(active) + d * len(spec_lanes)
-        total = sum(ch for _, ch in chosen) + n_rows_decode
-        # pure-plain and pure-spec packs keep the LEAN program —
-        # byte-identical operands to the split path; any guided or lora
-        # row takes the variant (all-ones mask rows and adapter index 0
-        # are exact no-ops for the rows beside it)
-        variant, ctx_pages = pack_shape(chosen, active)
-        # total <= the largest bucket by construction: it is plan_mixed's
-        # budget, mixed_max_tokens
-        payload = self._blank_mixed_pack(total, ctx_pages, variant)
-        if pipes:
-            # row -> lane, for the read of the carry and for the write
-            # back into it; the "mixed" broadcast carries both, so that
-            # followers replay the same programs
-            for key in ("row_lane", "w_lane"):
-                payload[key] = np.full_like(payload["row_lens"], -1)
-            payload["w_pos"] = np.zeros_like(payload["row_lens"])
-        N_pad = len(payload["toks"])
-        toks, positions, row_ids = (
-            payload["toks"], payload["positions"], payload["row_ids"])
-        tables, row_starts, row_lens = (
-            payload["tables"], payload["row_starts"], payload["row_lens"])
-        ctx_lens, last_flat = payload["ctx_lens"], payload["last_flat"]
-        temps, top_ks, top_ps, seeds = (
-            payload["temps"], payload["top_ks"], payload["top_ps"],
-            payload["seeds"])
-        pens, pen_rows = payload["pens"], payload["pen_rows"]
-        mask_packed = payload.get("mask")
-        lora_rows = payload.get("lora_idx")
+            # recompute the decode row count against the SURVIVING active set
+            # (page growth can preempt lanes out from under the plan)
+            spec_lanes = {
+                i for i in active
+                if cfg.spec_mode and self.slots[i].guided_fsm is None
+            }
+            n_rows_decode = len(active) + d * len(spec_lanes)
+            total = sum(ch for _, ch in chosen) + n_rows_decode
+            # pure-plain and pure-spec packs keep the LEAN program —
+            # byte-identical operands to the split path; any guided or lora
+            # row takes the variant (all-ones mask rows and adapter index 0
+            # are exact no-ops for the rows beside it)
+            variant, ctx_pages = pack_shape(chosen, active)
+            # total <= the largest bucket by construction: it is plan_mixed's
+            # budget, mixed_max_tokens
+            payload = self._blank_mixed_pack(total, ctx_pages, variant)
+            if pipes:
+                # row -> lane, for the read of the carry and for the write
+                # back into it; the "mixed" broadcast carries both, so that
+                # followers replay the same programs
+                for key in ("row_lane", "w_lane"):
+                    payload[key] = np.full_like(payload["row_lens"], -1)
+                payload["w_pos"] = np.zeros_like(payload["row_lens"])
+            N_pad = len(payload["toks"])
+            toks, positions, row_ids = (
+                payload["toks"], payload["positions"], payload["row_ids"])
+            tables, row_starts, row_lens = (
+                payload["tables"], payload["row_starts"], payload["row_lens"])
+            ctx_lens, last_flat = payload["ctx_lens"], payload["last_flat"]
+            temps, top_ks, top_ps, seeds = (
+                payload["temps"], payload["top_ks"], payload["top_ps"],
+                payload["seeds"])
+            pens, pen_rows = payload["pens"], payload["pen_rows"]
+            mask_packed = payload.get("mask")
+            lora_rows = payload.get("lora_idx")
 
-        off = 0
-        row = 0
-        meta = []  # prefill rows: (slot, chunk, row)
-        decode_rows = []  # (row, lane_idx, slot)
-        spec_rows = []  # (first_row, lane_idx, slot, draft) — 1+d rows each
-        for s, chunk in chosen:
-            start = s.prefill_pos
-            row_starts[row] = off
-            row_lens[row] = chunk
-            ctx_lens[row] = start
-            toks[off : off + chunk] = s.kv_prompt[start : start + chunk]
-            positions[off : off + chunk] = np.arange(start, start + chunk)
-            row_ids[off : off + chunk] = row
-            tables[row, :ctx_pages] = self.page_tables[s.slot_idx][:ctx_pages]
-            last_flat[row] = off + chunk - 1
-            temps[row] = s.temperature
-            top_ks[row] = s.top_k
-            top_ps[row] = s.top_p
-            seeds[row] = s.sample_seed
-            pens[row] = (s.presence_penalty, s.frequency_penalty,
-                         s.repetition_penalty)
-            pen_rows[row] = self.recent[s.slot_idx]
-            if s.guided_fsm is not None:
-                mask_packed[row] = np.packbits(self._guided_lane_mask(
-                    s.guided_fsm, s.guided_state
-                ))
-                self.mixed_rows_guided += 1
-            elif s.lora_idx:
-                self.mixed_rows_lora += 1
-            else:
-                self.mixed_rows_plain += 1
-            if lora_rows is not None:
-                lora_rows[row] = s.lora_idx
-            s.sched_skips = 0
-            meta.append((s, chunk, row))
-            if pipes and start + chunk >= len(s.kv_prompt):
-                # the prompt completes in this step: its first token goes
-                # into the lane's carry at the prompt's length
-                payload["w_lane"][row] = s.slot_idx
-                payload["w_pos"][row] = len(s.kv_prompt)
-            off += chunk
-            row += 1
-        for i in active:
-            s = self.slots[i]
-            L = int(self.seq_lens[i])
-            spec_lane = i in spec_lanes
-            draft = self._host_ngram_draft(s, d) if (spec_lane and d) else []
-            row_toks = [int(self.tokens[i])] + draft
-            first_row = row
-            for j, tk in enumerate(row_toks):
-                # row j carries one token at position L-1+j with ctx
-                # L-1+j: it attends the lane's committed KV plus rows
-                # 0..j-1 of THIS pack (their KV is written before
-                # attention each layer), so row j's sample is exactly the
-                # plain seeded decode draw at that position — the fused
-                # verify's parity lever
+            off = 0
+            row = 0
+            work = Work()  # what the step asks for (llama.step_work)
+            meta = []  # prefill rows: (slot, chunk, row)
+            decode_rows = []  # (row, lane_idx, slot)
+            spec_rows = []  # (first_row, lane_idx, slot, draft) — 1+d rows each
+            for s, chunk in chosen:
+                start = s.prefill_pos
                 row_starts[row] = off
-                row_lens[row] = 1
-                ctx_lens[row] = L - 1 + j
-                toks[off] = tk  # piped: the device's, from the carry
-                positions[off] = L - 1 + j
-                row_ids[off] = row
-                tables[row, :ctx_pages] = self.page_tables[i][:ctx_pages]
-                last_flat[row] = off
-                temps[row] = self.temps[i]
-                top_ks[row] = self.top_ks[i]
-                top_ps[row] = self.top_ps[i]
-                seeds[row] = self.seeds[i]
-                if lora_rows is not None:
-                    lora_rows[row] = s.lora_idx
-                if not spec_lane:
-                    pens[row] = (self.presence[i], self.frequency[i],
-                                 self.repetition[i])
-                if pipes:
-                    # token and penalty window are the carry's, gathered
-                    # by lane on the device (carry_read); the sample goes
-                    # back into the lane at position L (carry_write)
-                    payload["row_lane"][row] = payload["w_lane"][row] = i
-                    payload["w_pos"][row] = L
-                elif not spec_lane:
-                    # drained: the device pen ring (decode carry) is not
-                    # host-visible; rebuild this lane's window from the
-                    # authoritative token sequence (ring-indexed by
-                    # absolute position, so the patch after the fetch
-                    # stays consistent with it)
-                    self._fill_recent(i, s)
-                    pen_rows[row] = self.recent[i]
-                    if s.guided_fsm is not None:
-                        mask_packed[row] = np.packbits(
-                            self._guided_lane_mask(
-                                s.guided_fsm, s.guided_state
-                            )
-                        )
-                # spec rows keep default pens: penalties/logprobs are
-                # rejected under spec_mode at admission
-                off += 1
-                row += 1
-            if spec_lane:
-                spec_rows.append((first_row, i, s, draft))
-                self.mixed_rows_spec += len(row_toks)
-            else:
-                decode_rows.append((first_row, i, s))
+                row_lens[row] = chunk
+                ctx_lens[row] = start
+                toks[off : off + chunk] = s.kv_prompt[start : start + chunk]
+                positions[off : off + chunk] = np.arange(start, start + chunk)
+                row_ids[off : off + chunk] = row
+                tables[row, :ctx_pages] = self.page_tables[s.slot_idx][:ctx_pages]
+                last_flat[row] = off + chunk - 1
+                temps[row] = s.temperature
+                top_ks[row] = s.top_k
+                top_ps[row] = s.top_p
+                seeds[row] = s.sample_seed
+                pens[row] = (s.presence_penalty, s.frequency_penalty,
+                             s.repetition_penalty)
+                pen_rows[row] = self.recent[s.slot_idx]
                 if s.guided_fsm is not None:
+                    mask_packed[row] = np.packbits(self._guided_lane_mask(
+                        s.guided_fsm, s.guided_state
+                    ))
                     self.mixed_rows_guided += 1
                 elif s.lora_idx:
                     self.mixed_rows_lora += 1
                 else:
                     self.mixed_rows_plain += 1
+                if lora_rows is not None:
+                    lora_rows[row] = s.lora_idx
+                s.sched_skips = 0
+                meta.append((s, chunk, row))
+                work.chunk(start, chunk, start + chunk >= len(s.kv_prompt))
+                if pipes and start + chunk >= len(s.kv_prompt):
+                    # the prompt completes in this step: its first token goes
+                    # into the lane's carry at the prompt's length
+                    payload["w_lane"][row] = s.slot_idx
+                    payload["w_pos"][row] = len(s.kv_prompt)
+                off += chunk
+                row += 1
+            for i in active:
+                s = self.slots[i]
+                L = int(self.seq_lens[i])
+                spec_lane = i in spec_lanes
+                work.decode(L)  # sure of one token (a draft is a guess)
+                draft = self._host_ngram_draft(s, d) if (spec_lane and d) else []
+                row_toks = [int(self.tokens[i])] + draft
+                first_row = row
+                for j, tk in enumerate(row_toks):
+                    # row j carries one token at position L-1+j with ctx
+                    # L-1+j: it attends the lane's committed KV plus rows
+                    # 0..j-1 of THIS pack (their KV is written before
+                    # attention each layer), so row j's sample is exactly the
+                    # plain seeded decode draw at that position — the fused
+                    # verify's parity lever
+                    row_starts[row] = off
+                    row_lens[row] = 1
+                    ctx_lens[row] = L - 1 + j
+                    toks[off] = tk  # piped: the device's, from the carry
+                    positions[off] = L - 1 + j
+                    row_ids[off] = row
+                    tables[row, :ctx_pages] = self.page_tables[i][:ctx_pages]
+                    last_flat[row] = off
+                    temps[row] = self.temps[i]
+                    top_ks[row] = self.top_ks[i]
+                    top_ps[row] = self.top_ps[i]
+                    seeds[row] = self.seeds[i]
+                    if lora_rows is not None:
+                        lora_rows[row] = s.lora_idx
+                    if not spec_lane:
+                        pens[row] = (self.presence[i], self.frequency[i],
+                                     self.repetition[i])
+                    if pipes:
+                        # token and penalty window are the carry's, gathered
+                        # by lane on the device (carry_read); the sample goes
+                        # back into the lane at position L (carry_write)
+                        payload["row_lane"][row] = payload["w_lane"][row] = i
+                        payload["w_pos"][row] = L
+                    elif not spec_lane:
+                        # drained: the device pen ring (decode carry) is not
+                        # host-visible; rebuild this lane's window from the
+                        # authoritative token sequence (ring-indexed by
+                        # absolute position, so the patch after the fetch
+                        # stays consistent with it)
+                        self._fill_recent(i, s)
+                        pen_rows[row] = self.recent[i]
+                        if s.guided_fsm is not None:
+                            mask_packed[row] = np.packbits(
+                                self._guided_lane_mask(
+                                    s.guided_fsm, s.guided_state
+                                )
+                            )
+                    # spec rows keep default pens: penalties/logprobs are
+                    # rejected under spec_mode at admission
+                    off += 1
+                    row += 1
+                if spec_lane:
+                    spec_rows.append((first_row, i, s, draft))
+                    self.mixed_rows_spec += len(row_toks)
+                else:
+                    decode_rows.append((first_row, i, s))
+                    if s.guided_fsm is not None:
+                        self.mixed_rows_guided += 1
+                    elif s.lora_idx:
+                        self.mixed_rows_lora += 1
+                    else:
+                        self.mixed_rows_plain += 1
 
-        completions = []
-        progressed = []
-        for s, chunk, row_i in meta:
-            s.prefill_pos += chunk
-            progressed.append((s, s.prefill_pos))
-            if s.prefill_pos >= len(s.kv_prompt):
-                completions.append((s, row_i))
-                if pipes:
-                    # what the host knows of the new decode lane at
-                    # dispatch; the patch below puts it on the device, the
-                    # step's write-back adds the token, and the fetch does
-                    # what needs the value (_finish_prefill)
-                    s.first_pending = True
-                    self.seq_lens[s.slot_idx] = len(s.kv_prompt) + 1
-                    self._fill_recent(s.slot_idx, s)
-                    self._mark_lane_dirty(s.slot_idx)
+            completions = []
+            progressed = []
+            for s, chunk, row_i in meta:
+                s.prefill_pos += chunk
+                progressed.append((s, s.prefill_pos))
+                if s.prefill_pos >= len(s.kv_prompt):
+                    completions.append((s, row_i))
+                    if pipes:
+                        # what the host knows of the new decode lane at
+                        # dispatch; the patch below puts it on the device, the
+                        # step's write-back adds the token, and the fetch does
+                        # what needs the value (_finish_prefill)
+                        s.first_pending = True
+                        self.seq_lens[s.slot_idx] = len(s.kv_prompt) + 1
+                        self._fill_recent(s.slot_idx, s)
+                        self._mark_lane_dirty(s.slot_idx)
         after_drain = not self._carry_valid
         if pipes:
             await self._sync_carry(self._active_decode_indices())
-        # advanced at dispatch, before the device call suspends this task:
-        # exact for a plain decode row, and what the next entry packs from.
-        # spec lanes are NOT advanced here: acceptance is data-dependent
-        # (resolved from the fetched [R] tokens), and their packs drain
-        # this same step, so seq_lens stays authoritative for the next
-        # dispatch.
-        for row_i, i, s in decode_rows:
-            self.seq_lens[i] += 1
-        self._bcast("mixed", payload)
-        first_dev = await self._run_on_device(
+        with self._rec.span("pack", more=True):
+            # advanced at dispatch, before the device call suspends this
+            # task: exact for a plain decode row, and what the next entry
+            # packs from. spec lanes are NOT advanced here: acceptance is
+            # data-dependent (resolved from the fetched [R] tokens), and
+            # their packs drain this same step, so seq_lens stays
+            # authoritative for the next dispatch.
+            for row_i, i, s in decode_rows:
+                self.seq_lens[i] += 1
+            self._bcast("mixed", payload)
+            entry = {
+                "kind": "mixed", "done": completions,
+                "progressed": progressed, "decode": decode_rows,
+                "spec": spec_rows,
+            }
+            self._rec.dispatched(entry, "mixed", work.of(self._step_work))
+        entry["first"] = await self._run_on_device(
             partial(self._dev_mixed, payload),
             tag="mixed", shape=(N_pad, row),
         )
-        entry = {
-            "kind": "mixed", "first": first_dev, "done": completions,
-            "progressed": progressed, "decode": decode_rows,
-            "spec": spec_rows,
-        }
         if pipes:
             # an entry of the pipeline: fetched in dispatch order, with
             # its successor queued behind it
@@ -5169,34 +5196,35 @@ class JaxEngine:
             # would erase their mark and leave stale lane state on device.
             # Taken synchronously with the array snapshot, new dirt simply
             # rides the next step's patch.
-            self._dirty_lanes.clear()
-            self._dirty_tables.clear()
-            mask = np.zeros((B,), bool)
-            for i in active:
-                mask[i] = True
-            positions = np.where(mask, self.seq_lens - 1, 0).astype(np.int32)
-            seq_lens_step = np.where(mask, self.seq_lens, 0).astype(np.int32)
-            tokens = np.where(mask, self.tokens, 0).astype(np.int32)
-            tables = np.where(
-                mask[:, None], self.page_tables, SCRATCH_PAGE
-            ).astype(np.int32)
-            hist = (
-                np.where(mask[:, None], self.hist, 0).astype(np.int32)
-                if self.hist is not None else None
-            )
-            pens = np.stack(
-                [self.presence, self.frequency, self.repetition], axis=1
-            )
-            payload = {
-                "tokens": tokens, "positions": positions,
-                "seq_lens": seq_lens_step, "page_tables": tables,
-                "temps": self.temps, "top_ks": self.top_ks,
-                "top_ps": self.top_ps, "seeds": self.seeds,
-                "pens": pens, "recent": self.recent,
-            }
-            if hist is not None:
-                payload["hist"] = hist
-            self._bcast("reset", payload)
+            with self._rec.span("pack", more=True):
+                self._dirty_lanes.clear()
+                self._dirty_tables.clear()
+                mask = np.zeros((B,), bool)
+                for i in active:
+                    mask[i] = True
+                positions = np.where(mask, self.seq_lens - 1, 0).astype(np.int32)
+                seq_lens_step = np.where(mask, self.seq_lens, 0).astype(np.int32)
+                tokens = np.where(mask, self.tokens, 0).astype(np.int32)
+                tables = np.where(
+                    mask[:, None], self.page_tables, SCRATCH_PAGE
+                ).astype(np.int32)
+                hist = (
+                    np.where(mask[:, None], self.hist, 0).astype(np.int32)
+                    if self.hist is not None else None
+                )
+                pens = np.stack(
+                    [self.presence, self.frequency, self.repetition], axis=1
+                )
+                payload = {
+                    "tokens": tokens, "positions": positions,
+                    "seq_lens": seq_lens_step, "page_tables": tables,
+                    "temps": self.temps, "top_ks": self.top_ks,
+                    "top_ps": self.top_ps, "seeds": self.seeds,
+                    "pens": pens, "recent": self.recent,
+                }
+                if hist is not None:
+                    payload["hist"] = hist
+                self._bcast("reset", payload)
             await self._run_on_device(
                 partial(
                     self._dev_reset,
@@ -5214,38 +5242,39 @@ class JaxEngine:
         # TAKE the dirty sets atomically with the host-array snapshot (same
         # reasoning as above: dirt added during the dispatch await must
         # survive into the next patch, not be cleared with this one)
-        dirty_lanes, dirty_tables = self._dirty_lanes, self._dirty_tables
-        self._dirty_lanes, self._dirty_tables = set(), set()
-        lane_mask = np.zeros((B,), bool)
-        for i in dirty_lanes:
-            lane_mask[i] = True
-        table_mask = lane_mask.copy()
-        for i in dirty_tables:
-            table_mask[i] = True
-        active_mask = np.zeros((B,), bool)
-        for i in active:
-            active_mask[i] = True
-        n_tokens = np.where(active_mask, self.tokens, 0).astype(np.int32)
-        n_positions = np.where(active_mask, self.seq_lens - 1, 0).astype(np.int32)
-        n_seq_lens = np.where(active_mask, self.seq_lens, 0).astype(np.int32)
-        n_tables = np.where(
-            active_mask[:, None], self.page_tables, SCRATCH_PAGE
-        ).astype(np.int32)
-        hist = self.hist.astype(np.int32) if self.hist is not None else None
-        pens = np.stack(
-            [self.presence, self.frequency, self.repetition], axis=1
-        )
-        payload = {
-            "lane_mask": lane_mask, "table_mask": table_mask,
-            "tokens": n_tokens, "positions": n_positions,
-            "seq_lens": n_seq_lens, "page_tables": n_tables,
-            "temps": self.temps, "top_ks": self.top_ks,
-            "top_ps": self.top_ps, "seeds": self.seeds,
-            "pens": pens, "recent": self.recent,
-        }
-        if hist is not None:
-            payload["hist"] = hist
-        self._bcast("patch", payload)
+        with self._rec.span("pack", more=True):
+            dirty_lanes, dirty_tables = self._dirty_lanes, self._dirty_tables
+            self._dirty_lanes, self._dirty_tables = set(), set()
+            lane_mask = np.zeros((B,), bool)
+            for i in dirty_lanes:
+                lane_mask[i] = True
+            table_mask = lane_mask.copy()
+            for i in dirty_tables:
+                table_mask[i] = True
+            active_mask = np.zeros((B,), bool)
+            for i in active:
+                active_mask[i] = True
+            n_tokens = np.where(active_mask, self.tokens, 0).astype(np.int32)
+            n_positions = np.where(active_mask, self.seq_lens - 1, 0).astype(np.int32)
+            n_seq_lens = np.where(active_mask, self.seq_lens, 0).astype(np.int32)
+            n_tables = np.where(
+                active_mask[:, None], self.page_tables, SCRATCH_PAGE
+            ).astype(np.int32)
+            hist = self.hist.astype(np.int32) if self.hist is not None else None
+            pens = np.stack(
+                [self.presence, self.frequency, self.repetition], axis=1
+            )
+            payload = {
+                "lane_mask": lane_mask, "table_mask": table_mask,
+                "tokens": n_tokens, "positions": n_positions,
+                "seq_lens": n_seq_lens, "page_tables": n_tables,
+                "temps": self.temps, "top_ks": self.top_ks,
+                "top_ps": self.top_ps, "seeds": self.seeds,
+                "pens": pens, "recent": self.recent,
+            }
+            if hist is not None:
+                payload["hist"] = hist
+            self._bcast("patch", payload)
         await self._run_on_device(
             partial(
                 self._dev_patch, lane_mask, table_mask,
@@ -5279,109 +5308,127 @@ class JaxEngine:
         # PREVIOUS step emitted, so while any guided slot is decode-active
         # the pipeline depth is 1 and every block must be fetched+processed
         # (FSM advanced) before the next dispatch.
-        has_guided = any(
-            s is not None and s.guided_fsm is not None
-            and s.prefill_pos >= len(s.kv_prompt) and s.generated > 0
-            for s in self.slots
-        )
-        depth = 1 if (
-            cfg.spec_mode or has_guided
-            or (not chained and self._prefill_work_pending())
-        ) else 2
-        if len(self._inflight) >= depth:
-            return False
-        if not self._carry_valid and self._inflight:
-            return False  # drain in-flight blocks before a state reset
-        active = self._active_decode_indices()
-        if not active:
-            return False
-        active = self._grow_pages_for_block(active)
-        if not active:
-            return False
-        if not self._carry_valid and self._inflight:
-            # growth/preemption invalidated the carry mid-pipeline: drain the
-            # in-flight block first (its results update host state), THEN a
-            # fresh upload is consistent
-            return False
+        with self._rec.span("pack", more=True):
+            has_guided = any(
+                s is not None and s.guided_fsm is not None
+                and s.prefill_pos >= len(s.kv_prompt) and s.generated > 0
+                for s in self.slots
+            )
+            depth = 1 if (
+                cfg.spec_mode or has_guided
+                or (not chained and self._prefill_work_pending())
+            ) else 2
+            if len(self._inflight) >= depth:
+                return False
+            if not self._carry_valid and self._inflight:
+                return False  # drain in-flight blocks before a state reset
+            active = self._active_decode_indices()
+            if not active:
+                return False
+            active = self._grow_pages_for_block(active)
+            if not active:
+                return False
+            if not self._carry_valid and self._inflight:
+                # growth/preemption invalidated the carry mid-pipeline: drain
+                # the in-flight block first (its results update host state),
+                # THEN a fresh upload is consistent
+                return False
 
         B = cfg.max_num_seqs
         K = cfg.decode_block_steps
+        self._rec.entry_kind = "block"
         await self._sync_carry(active)
 
-        guided_lanes = [
-            i for i in active if self.slots[i].guided_fsm is not None
-        ]
-        if guided_lanes:
-            # single masked step: guided rows from each lane's FSM state,
-            # unguided rows admit everything. Bitpacked: [B, V/8] uint8
-            # host→device instead of a [B, V] bool (the per-step transfer
-            # would otherwise dominate guided ITL).
-            V = self.model_config.vocab_size
-            packed = np.full((B, (V + 7) // 8), 0xFF, np.uint8)
-            for i in guided_lanes:
-                s = self.slots[i]
-                packed[i] = np.packbits(
-                    self._guided_lane_mask(s.guided_fsm, s.guided_state)
+        with self._rec.span("pack"):
+            guided_lanes = [
+                i for i in active if self.slots[i].guided_fsm is not None
+            ]
+            if guided_lanes:
+                # single masked step: guided rows from each lane's FSM
+                # state, unguided rows admit everything. Bitpacked: [B, V/8]
+                # uint8 host→device instead of a [B, V] bool (the per-step
+                # transfer would otherwise dominate guided ITL).
+                V = self.model_config.vocab_size
+                packed = np.full((B, (V + 7) // 8), 0xFF, np.uint8)
+                for i in guided_lanes:
+                    s = self.slots[i]
+                    packed[i] = np.packbits(
+                        self._guided_lane_mask(s.guided_fsm, s.guided_state)
+                    )
+                lora_idx = (
+                    self.lora_idx.copy()
+                    if any(self.slots[i].lora_idx for i in active) else None
                 )
-            lora_idx = (
-                self.lora_idx.copy()
-                if any(self.slots[i].lora_idx for i in active) else None
-            )
-            payload = {"mask": packed}
-            if lora_idx is not None:
-                payload["lora_idx"] = lora_idx
-            self._bcast("block_guided", payload)
-            toks_dev = await self._run_on_device(
-                partial(self._dev_block_guided, packed, lora_idx),
-                tag="block_guided", shape=(1, B),
-            )
-            adv = 1
-            kind = "block"
-        elif any(self.slots[i].lora_idx for i in active):
-            idx = self.lora_idx.copy()
-            self._bcast("block_lora", {"idx": idx})
-            toks_dev = await self._run_on_device(
-                partial(self._dev_block_lora, idx), tag="block_lora",
-                shape=(K, B),
-            )
-            # decode_block_lora always advances K steps — NOT
-            # cfg.block_advance, which under a spec engine is the spec
-            # program's worst-case spec_rounds*(1+d) bound
-            adv = K
-            kind = "block"
-        else:
-            self._bcast("block", {})
-            toks_dev = await self._run_on_device(
-                self._dev_block, tag="block", shape=(K, B)
-            )
-            adv = cfg.block_advance
-            # only this branch runs the spec program under spec_mode;
-            # guided/lora blocks above drain through _process_block
-            kind = "spec" if cfg.spec_mode else "block"
-        self._last_decode_shape = (B * adv, len(active) * adv)
-        if kind == "spec":
-            # a round verifies 1 + d tokens a lane in one batched pass
-            per = 1 + cfg.spec_draft_len
-            self._count_expert_rows(B * per, len(active) * per, cfg.spec_rounds)
-        else:
-            self._count_expert_rows(B, len(active), adv)
-        entry = {
-            "lanes": [(i, self.slots[i]) for i in active],
-            "toks": toks_dev, "kind": kind, "adv": adv,
-        }
-        if kind == "spec":
-            # spec blocks advance lanes by a data-dependent amount: record
-            # the pre-dispatch seq_lens so the fetch can correct the
-            # worst-case advance below to the device-true values
-            entry["seq_before"] = {i: int(self.seq_lens[i]) for i in active}
-        self._inflight.append(entry)
-        # advance host bookkeeping by the block's max advance for the NEXT
-        # block's page growth (exact for plain decode; an upper bound under
-        # spec, corrected at fetch)
-        for i in active:
-            self.seq_lens[i] += adv
-        self._step_counter += 1
+                payload = {"mask": packed}
+                if lora_idx is not None:
+                    payload["lora_idx"] = lora_idx
+                self._bcast("block_guided", payload)
+                call = partial(self._dev_block_guided, packed, lora_idx)
+                tag, shape, adv, kind = "block_guided", (1, B), 1, "block"
+            elif any(self.slots[i].lora_idx for i in active):
+                idx = self.lora_idx.copy()
+                self._bcast("block_lora", {"idx": idx})
+                call = partial(self._dev_block_lora, idx)
+                # decode_block_lora always advances K steps — NOT
+                # cfg.block_advance, which under a spec engine is the spec
+                # program's worst-case spec_rounds*(1+d) bound
+                tag, shape, adv, kind = "block_lora", (K, B), K, "block"
+            else:
+                self._bcast("block", {})
+                # (partial: the compile registry's reachability check
+                # follows a method named in a call)
+                call = partial(self._dev_block)
+                # only this branch runs the spec program under spec_mode;
+                # guided/lora blocks above drain through _process_block
+                tag, shape, adv = "block", (K, B), cfg.block_advance
+                kind = "spec" if cfg.spec_mode else "block"
+            entry = {
+                "lanes": [(i, self.slots[i]) for i in active],
+                "kind": kind, "adv": adv,
+            }
+            # a spec round is one pass that is sure of one token a lane
+            self._rec.dispatched(entry, "block", self._block_work(
+                active, cfg.spec_rounds if kind == "spec" else adv
+            ))
+        entry["toks"] = await self._run_on_device(call, tag=tag, shape=shape)
+        with self._rec.span("pack", more=True):
+            self._last_decode_shape = (B * adv, len(active) * adv)
+            if kind == "spec":
+                # a round verifies 1 + d tokens a lane in one batched pass
+                per = 1 + cfg.spec_draft_len
+                self._count_expert_rows(
+                    B * per, len(active) * per, cfg.spec_rounds
+                )
+                # spec blocks advance lanes by a data-dependent amount:
+                # record the pre-dispatch seq_lens so the fetch can correct
+                # the worst-case advance below to the device-true values
+                entry["seq_before"] = {
+                    i: int(self.seq_lens[i]) for i in active
+                }
+            else:
+                self._count_expert_rows(B, len(active), adv)
+            self._inflight.append(entry)
+            # advance host bookkeeping by the block's max advance for the
+            # NEXT block's page growth (exact for plain decode; an upper
+            # bound under spec, corrected at fetch)
+            for i in active:
+                self.seq_lens[i] += adv
+            self._step_counter += 1
         return True
+
+    def _block_work(self, active: List[int], steps: int):
+        """(useful operations, least bytes) of a block of `steps` forward
+        passes over these lanes: a lane counts the passes that its
+        max_tokens still asks for after what is in flight (the rest are
+        sampled for nothing), each a token deeper into its context, and
+        the block the passes that some lane still asks for."""
+        flying = self._tokens_in_flight()
+        n = np.array([
+            min(steps, max(s.max_tokens - s.generated - flying.get(id(s), 0), 0))
+            for s in (self.slots[i] for i in active)
+        ])
+        context = n * self.seq_lens[active] + n * (n - 1) // 2
+        return self._step_work(int(n.sum()), int(context.sum()), int(n.max()))
 
     async def _fetch_and_process(self, fetch_block: bool) -> bool:
         """One RTT: fetch pending prefill first-tokens + the oldest entry of
@@ -5402,7 +5449,11 @@ class JaxEngine:
             [p["first"] for p in prefills],
             None if want is None else want["first" if mixed else "toks"],
         )
-        firsts_np, toks_np = await self._fetch(tree)
+        self._rec.entry_kind = (want or prefills[0])["step_kind"]
+        (firsts_np, toks_np), t_ready = await self._fetch(tree)
+        self._rec.fetched(
+            prefills if want is None else [*prefills, want], t_ready
+        )
 
         for p, first in zip(prefills, firsts_np):
             await self._process_prefill_result(p, first)
@@ -5431,13 +5482,14 @@ class JaxEngine:
         device at dispatch and the samples are in its carry, so only what
         needs the VALUE happens here, and a lane whose slot ended or was
         re-assigned meanwhile is dropped, as a block's is."""
-        for slot, upto in p.get("progressed", []):
-            if slot.slot_idx < 0 or self.slots[slot.slot_idx] is not slot:
-                continue
-            if slot.prefill_pos < len(slot.kv_prompt):
-                # mid-prompt: commit the chunk's full pages now so
-                # concurrent same-prefix requests can skip ahead
-                self._commit_blocks(slot, upto_tokens=upto)
+        with self._rec.span("emit"):
+            for slot, upto in p.get("progressed", []):
+                if slot.slot_idx < 0 or self.slots[slot.slot_idx] is not slot:
+                    continue
+                if slot.prefill_pos < len(slot.kv_prompt):
+                    # mid-prompt: commit the chunk's full pages now so
+                    # concurrent same-prefix requests can skip ahead
+                    self._commit_blocks(slot, upto_tokens=upto)
         first_toks, first_lps, first_tids, first_tlps = first
         for slot, lane in p["done"]:
             if slot.slot_idx < 0 or self.slots[slot.slot_idx] is not slot:
@@ -5446,106 +5498,110 @@ class JaxEngine:
             lp = float(first_lps[lane])
             top = self._top_entry(slot, first_tids[lane], first_tlps[lane])
             if slot.return_kv:
+                # awaits the device (the pages' extraction): no span of
+                # the loop's is held across it
                 await self._emit_prefill_result(slot, tok, lp, top)
             else:
-                self._finish_prefill(slot, tok, lp, top, piped)
-        # mixed-step decode rows: each active lane advanced ONE token
-        # inside the fused dispatch — emit it. A piped step has left it
-        # in the device carry already; after a drained one the (stale)
-        # carry is re-synced for this lane via the patch path
-        for row, i, slot_ref in p.get("decode", []):
-            slot = self.slots[i]
-            if slot is None or slot is not slot_ref:
-                continue  # released/preempted meanwhile
-            if slot.done or slot.context.is_stopped():
-                self._emit_finish(slot, "cancelled")
-                self._release_slot(slot)
-                continue
-            tok = int(first_toks[row])
-            slot.seq.append(tok)
-            slot.generated += 1
-            slot.last_token = tok
-            self.tokens[i] = tok
-            if slot.guided_fsm is not None:
-                # fused guided decode: the mixed step is host-
-                # authoritative per step, so the FSM advances here —
-                # the next dispatch packs the updated mask
-                slot.guided_state = slot.guided_fsm.advance(
-                    slot.guided_state, tok
-                )
-            if self.hist is not None:
-                # keep the spec n-gram ring coherent for lanes that
-                # advanced outside the spec program (guided/plain
-                # rows under spec_mode); patch re-uploads it via
-                # _mark_lane_dirty below
-                self.hist[
-                    i, (len(slot.seq.tokens) - 1) % self.config.spec_hist
-                ] = tok
-            lp = float(first_lps[row])
-            top = self._top_entry(slot, first_tids[row], first_tlps[row])
-            self._emit_tokens(
-                slot, [tok],
-                [lp] if slot.want_logprobs else [],
-                [top] if top else [],
-            )
-            finish = self._finish_reason(slot, tok)
-            if finish:
-                self._emit_finish(slot, finish)
-                self._release_slot(slot)
-            else:
-                if not piped:
-                    self._fill_recent(i, slot)
-                    self._mark_lane_dirty(i)
-                self._maybe_commit_incremental(slot)
-        # fused spec verify rows: lane i packed rows first_row..
-        # first_row+d (current token + draft); row j's sample is the
-        # plain seeded draw at position L-1+j, so accepting the
-        # longest draft prefix matching the verified samples and
-        # emitting n_acc+1 tokens is byte-identical to plain decode
-        for first_row, i, slot_ref, draft in p.get("spec", []):
-            slot = self.slots[i]
-            if slot is None or slot is not slot_ref:
-                continue
-            if slot.done or slot.context.is_stopped():
-                self._emit_finish(slot, "cancelled")
-                self._release_slot(slot)
-                continue
-            d_n = len(draft)
-            out = [int(first_toks[first_row + j]) for j in range(1 + d_n)]
-            n_acc = 0
-            while n_acc < d_n and out[n_acc] == draft[n_acc]:
-                n_acc += 1
-            self.spec_num_drafts += 1
-            self.spec_num_draft_tokens += d_n
-            self.spec_num_accepted_tokens += n_acc
-            L = int(self.seq_lens[i])
-            Hc = self.config.spec_hist
-            batch: List[int] = []
-            finish = None
-            for m, tok in enumerate(out[: n_acc + 1]):
+                with self._rec.span("emit", more=True):
+                    self._finish_prefill(slot, tok, lp, top, piped)
+        with self._rec.span("emit", more=True):
+            # mixed-step decode rows: each active lane advanced ONE token
+            # inside the fused dispatch — emit it. A piped step has left it
+            # in the device carry already; after a drained one the (stale)
+            # carry is re-synced for this lane via the patch path
+            for row, i, slot_ref in p.get("decode", []):
+                slot = self.slots[i]
+                if slot is None or slot is not slot_ref:
+                    continue  # released/preempted meanwhile
+                if slot.done or slot.context.is_stopped():
+                    self._emit_finish(slot, "cancelled")
+                    self._release_slot(slot)
+                    continue
+                tok = int(first_toks[row])
                 slot.seq.append(tok)
                 slot.generated += 1
                 slot.last_token = tok
+                self.tokens[i] = tok
+                if slot.guided_fsm is not None:
+                    # fused guided decode: the mixed step is host-
+                    # authoritative per step, so the FSM advances here —
+                    # the next dispatch packs the updated mask
+                    slot.guided_state = slot.guided_fsm.advance(
+                        slot.guided_state, tok
+                    )
                 if self.hist is not None:
-                    self.hist[i, (L + m) % Hc] = tok
-                batch.append(tok)
+                    # keep the spec n-gram ring coherent for lanes that
+                    # advanced outside the spec program (guided/plain
+                    # rows under spec_mode); patch re-uploads it via
+                    # _mark_lane_dirty below
+                    self.hist[
+                        i, (len(slot.seq.tokens) - 1) % self.config.spec_hist
+                    ] = tok
+                lp = float(first_lps[row])
+                top = self._top_entry(slot, first_tids[row], first_tlps[row])
+                self._emit_tokens(
+                    slot, [tok],
+                    [lp] if slot.want_logprobs else [],
+                    [top] if top else [],
+                )
                 finish = self._finish_reason(slot, tok)
                 if finish:
-                    break
-            # seq_lens was NOT advanced at dispatch (acceptance is
-            # data-dependent); commit the true advance now — rejected
-            # rows' KV is garbage past seq_lens and gets overwritten
-            # before it is ever attended
-            self.seq_lens[i] = L + len(batch)
-            self.tokens[i] = batch[-1]
-            self._emit_tokens(slot, batch, [], [])
-            if finish:
-                self._emit_finish(slot, finish)
-                self._release_slot(slot)
-            else:
-                self._fill_recent(i, slot)
-                self._mark_lane_dirty(i)
-                self._maybe_commit_incremental(slot)
+                    self._emit_finish(slot, finish)
+                    self._release_slot(slot)
+                else:
+                    if not piped:
+                        self._fill_recent(i, slot)
+                        self._mark_lane_dirty(i)
+                    self._maybe_commit_incremental(slot)
+            # fused spec verify rows: lane i packed rows first_row..
+            # first_row+d (current token + draft); row j's sample is the
+            # plain seeded draw at position L-1+j, so accepting the
+            # longest draft prefix matching the verified samples and
+            # emitting n_acc+1 tokens is byte-identical to plain decode
+            for first_row, i, slot_ref, draft in p.get("spec", []):
+                slot = self.slots[i]
+                if slot is None or slot is not slot_ref:
+                    continue
+                if slot.done or slot.context.is_stopped():
+                    self._emit_finish(slot, "cancelled")
+                    self._release_slot(slot)
+                    continue
+                d_n = len(draft)
+                out = [int(first_toks[first_row + j]) for j in range(1 + d_n)]
+                n_acc = 0
+                while n_acc < d_n and out[n_acc] == draft[n_acc]:
+                    n_acc += 1
+                self.spec_num_drafts += 1
+                self.spec_num_draft_tokens += d_n
+                self.spec_num_accepted_tokens += n_acc
+                L = int(self.seq_lens[i])
+                Hc = self.config.spec_hist
+                batch: List[int] = []
+                finish = None
+                for m, tok in enumerate(out[: n_acc + 1]):
+                    slot.seq.append(tok)
+                    slot.generated += 1
+                    slot.last_token = tok
+                    if self.hist is not None:
+                        self.hist[i, (L + m) % Hc] = tok
+                    batch.append(tok)
+                    finish = self._finish_reason(slot, tok)
+                    if finish:
+                        break
+                # seq_lens was NOT advanced at dispatch (acceptance is
+                # data-dependent); commit the true advance now — rejected
+                # rows' KV is garbage past seq_lens and gets overwritten
+                # before it is ever attended
+                self.seq_lens[i] = L + len(batch)
+                self.tokens[i] = batch[-1]
+                self._emit_tokens(slot, batch, [], [])
+                if finish:
+                    self._emit_finish(slot, finish)
+                    self._release_slot(slot)
+                else:
+                    self._fill_recent(i, slot)
+                    self._mark_lane_dirty(i)
+                    self._maybe_commit_incremental(slot)
 
     def _process_spec_block(self, lanes: List[tuple], toks: np.ndarray,
                             n_emit: np.ndarray, seq_before: dict):
@@ -5553,57 +5609,58 @@ class JaxEngine:
         Per lane, each round contributes its first n_emit tokens; host
         seq_lens/tokens mirrors are corrected to the device-true values
         (dispatch advanced them by the worst-case bound)."""
-        S, B, T = toks.shape
-        Hc = self.config.spec_hist
-        for i, slot_ref in lanes:
-            slot = self.slots[i]
-            if slot is None or slot is not slot_ref:
-                continue
-            true_adv = int(n_emit[:, i].sum())
-            # device-authoritative mirrors (valid even if the slot finishes
-            # below — the lane is re-patched on the next admission anyway)
-            self.seq_lens[i] = seq_before[i] + true_adv
-            self.tokens[i] = int(toks[S - 1, i, int(n_emit[S - 1, i]) - 1])
-            # stats: engine-level acceptance (device view)
-            self.spec_num_drafts += S
-            self.spec_num_draft_tokens += S * (T - 1)
-            self.spec_num_accepted_tokens += true_adv - S
-            if slot.done or slot.context.is_stopped():
-                self._emit_finish(slot, "cancelled")
-                self._release_slot(slot)
-                continue
-            # the round's current token sits at position seq_before-1 (the
-            # device carry was uploaded with positions = seq_lens - 1), so
-            # emitted token t of a round lands at (pos + 1 + t) with
-            # pos = seq_before - 1 — matching the device ring exactly
-            pos = seq_before[i] - 1
-            # all accepted rounds flow into one delta batch (same O(1)-per-
-            # dispatch contract as _process_block); a stop mid-round
-            # truncates host-side before anything reaches the client
-            batch: List[int] = []
-            finish = None
-            for s in range(S):
-                k = int(n_emit[s, i])
-                for t in range(k):
-                    tok = int(toks[s, i, t])
-                    slot.seq.append(tok)
-                    slot.generated += 1
-                    slot.last_token = tok
-                    if self.hist is not None:
-                        self.hist[i, (pos + 1 + t) % Hc] = tok
-                    batch.append(tok)
-                    finish = self._finish_reason(slot, tok)
+        with self._rec.span("emit"):
+            S, B, T = toks.shape
+            Hc = self.config.spec_hist
+            for i, slot_ref in lanes:
+                slot = self.slots[i]
+                if slot is None or slot is not slot_ref:
+                    continue
+                true_adv = int(n_emit[:, i].sum())
+                # device-authoritative mirrors (valid even if the slot finishes
+                # below — the lane is re-patched on the next admission anyway)
+                self.seq_lens[i] = seq_before[i] + true_adv
+                self.tokens[i] = int(toks[S - 1, i, int(n_emit[S - 1, i]) - 1])
+                # stats: engine-level acceptance (device view)
+                self.spec_num_drafts += S
+                self.spec_num_draft_tokens += S * (T - 1)
+                self.spec_num_accepted_tokens += true_adv - S
+                if slot.done or slot.context.is_stopped():
+                    self._emit_finish(slot, "cancelled")
+                    self._release_slot(slot)
+                    continue
+                # the round's current token sits at position seq_before-1 (the
+                # device carry was uploaded with positions = seq_lens - 1), so
+                # emitted token t of a round lands at (pos + 1 + t) with
+                # pos = seq_before - 1 — matching the device ring exactly
+                pos = seq_before[i] - 1
+                # all accepted rounds flow into one delta batch (same O(1)-per-
+                # dispatch contract as _process_block); a stop mid-round
+                # truncates host-side before anything reaches the client
+                batch: List[int] = []
+                finish = None
+                for s in range(S):
+                    k = int(n_emit[s, i])
+                    for t in range(k):
+                        tok = int(toks[s, i, t])
+                        slot.seq.append(tok)
+                        slot.generated += 1
+                        slot.last_token = tok
+                        if self.hist is not None:
+                            self.hist[i, (pos + 1 + t) % Hc] = tok
+                        batch.append(tok)
+                        finish = self._finish_reason(slot, tok)
+                        if finish:
+                            break
+                    pos += k
                     if finish:
                         break
-                pos += k
+                self._emit_tokens(slot, batch, [], [])
                 if finish:
-                    break
-            self._emit_tokens(slot, batch, [], [])
-            if finish:
-                self._emit_finish(slot, finish)
-                self._release_slot(slot)
-            else:
-                self._maybe_commit_incremental(slot)
+                    self._emit_finish(slot, finish)
+                    self._release_slot(slot)
+                else:
+                    self._maybe_commit_incremental(slot)
 
     def _process_block(self, lanes: List[tuple], toks: np.ndarray,
                        lps: np.ndarray, tids: np.ndarray,
@@ -5613,60 +5670,61 @@ class JaxEngine:
         slot was preempted/released (or re-assigned) meanwhile are skipped —
         their speculated tokens were never emitted, so no client ever sees
         them."""
-        K = toks.shape[0]
-        for i, slot_ref in lanes:
-            slot = self.slots[i]
-            if slot is None or slot is not slot_ref:
-                continue
-            if slot.done or slot.context.is_stopped():
-                self._emit_finish(slot, "cancelled")
-                self._release_slot(slot)
-                continue
-            # the whole K-step block lands in ONE delta batch on the slot
-            # queue: downstream (request plane, detokenizer, SSE) then pays
-            # O(1) work per dispatch instead of per token. A mid-block
-            # stop/eos truncates host-side — tokens past it were speculated
-            # by the device and are never client-visible. The batch commits
-            # atomically: resume/migration accounting counts it all-or-
-            # nothing, exactly like the singleton emissions it replaces.
-            batch: List[int] = []
-            batch_lps: List[float] = []
-            batch_tops: List[Optional[dict]] = []
-            finish = None
-            for k in range(K):
-                tok = int(toks[k, i])
-                slot.seq.append(tok)
-                slot.generated += 1
-                slot.last_token = tok
-                self.tokens[i] = tok
-                if slot.guided_fsm is not None:
-                    slot.guided_state = slot.guided_fsm.advance(
-                        slot.guided_state, tok
-                    )
-                if self.hist is not None:
-                    # spec engine, non-spec block (guided/lora lanes):
-                    # keep the n-gram ring coherent host-side
-                    self.hist[
-                        i, (len(slot.seq.tokens) - 1) % self.config.spec_hist
-                    ] = tok
-                batch.append(tok)
-                if slot.want_logprobs:
-                    batch_lps.append(float(lps[k, i]))
-                    batch_tops.append(
-                        self._top_entry(slot, tids[k, i], tlps[k, i])
-                    )
-                finish = self._finish_reason(slot, tok)
+        with self._rec.span("emit"):
+            K = toks.shape[0]
+            for i, slot_ref in lanes:
+                slot = self.slots[i]
+                if slot is None or slot is not slot_ref:
+                    continue
+                if slot.done or slot.context.is_stopped():
+                    self._emit_finish(slot, "cancelled")
+                    self._release_slot(slot)
+                    continue
+                # the whole K-step block lands in ONE delta batch on the slot
+                # queue: downstream (request plane, detokenizer, SSE) then pays
+                # O(1) work per dispatch instead of per token. A mid-block
+                # stop/eos truncates host-side — tokens past it were speculated
+                # by the device and are never client-visible. The batch commits
+                # atomically: resume/migration accounting counts it all-or-
+                # nothing, exactly like the singleton emissions it replaces.
+                batch: List[int] = []
+                batch_lps: List[float] = []
+                batch_tops: List[Optional[dict]] = []
+                finish = None
+                for k in range(K):
+                    tok = int(toks[k, i])
+                    slot.seq.append(tok)
+                    slot.generated += 1
+                    slot.last_token = tok
+                    self.tokens[i] = tok
+                    if slot.guided_fsm is not None:
+                        slot.guided_state = slot.guided_fsm.advance(
+                            slot.guided_state, tok
+                        )
+                    if self.hist is not None:
+                        # spec engine, non-spec block (guided/lora lanes):
+                        # keep the n-gram ring coherent host-side
+                        self.hist[
+                            i, (len(slot.seq.tokens) - 1) % self.config.spec_hist
+                        ] = tok
+                    batch.append(tok)
+                    if slot.want_logprobs:
+                        batch_lps.append(float(lps[k, i]))
+                        batch_tops.append(
+                            self._top_entry(slot, tids[k, i], tlps[k, i])
+                        )
+                    finish = self._finish_reason(slot, tok)
+                    if finish:
+                        break
+                self._emit_tokens(slot, batch, batch_lps, batch_tops)
                 if finish:
-                    break
-            self._emit_tokens(slot, batch, batch_lps, batch_tops)
-            if finish:
-                self._emit_finish(slot, finish)
-                self._release_slot(slot)
-            else:
-                # durable sessions: newly-full generated blocks publish
-                # now (prefix cache + KVBM + mesh + checkpoint), not at
-                # release — a SIGKILL loses only the un-committed tail
-                self._maybe_commit_incremental(slot)
+                    self._emit_finish(slot, finish)
+                    self._release_slot(slot)
+                else:
+                    # durable sessions: newly-full generated blocks publish
+                    # now (prefix cache + KVBM + mesh + checkpoint), not at
+                    # release — a SIGKILL loses only the un-committed tail
+                    self._maybe_commit_incremental(slot)
 
     def _fail_all(self, message: str):
         """A step raised: the batch state is unreliable. Error every live
@@ -5739,6 +5797,7 @@ class JaxEngine:
                     top: Optional[dict] = None):
         if slot.done:
             return
+        self._rec.first_token(slot)
         out = LLMEngineOutput(
             token_ids=[token],
             log_probs=[lp] if (slot.want_logprobs and lp is not None) else None,
@@ -5754,6 +5813,7 @@ class JaxEngine:
         slot queue — the serving plane never sees a partial block."""
         if slot.done or not tokens:
             return
+        self._rec.first_token(slot)
         out = LLMEngineOutput(
             token_ids=tokens,
             log_probs=lps if (slot.want_logprobs and lps) else None,

@@ -1,0 +1,228 @@
+"""The engine's span-and-counter recorder (docs/observability.md).
+
+One table for the engine's loop, exported through `JaxEngine.stats()`:
+
+* PHASES of an iteration. `with rec.span("pack"):` around the leaf work
+  accrues `phase_pack_count`, `phase_pack_s` and, for a span of
+  SLOW_SPAN_S or more, `phase_pack_slow`, and is a
+  `jax.profiler.TraceAnnotation("engine.pack")`, which records only
+  while a profiler session is open: the spans then lie in the
+  profiler's own trace, on its clock, beside the device's operations.
+  Spans do not nest and none is held across an `await` but `wait`, so
+  the seven sums add up to no more than the clock.
+* STEP_KINDS of pipeline entries. An entry is stamped when it is
+  dispatched and timed when its fetch returns: `step_<kind>_count`,
+  `step_<kind>_interval_s` (ready to ready: the device's time for the
+  entry plus whatever the device waited for the host inside it; an
+  interval of SLOW_SPAN_S or more is a stall of the pipeline and goes to
+  `step_stalled_count`, `step_stalled_s` instead), and the work it was
+  asked for, `step_model_flops` and `step_min_bytes`
+  (models/<family>.step_work).
+* the waits ahead of a first token: `req_admitted`, `req_queue_wait_s`,
+  `req_first_tokens`, `req_admit_to_first_s`.
+* the device calls' host clock by tag (`dispatch_<tag>_count`, `_s`:
+  engine._timed).
+
+Counters are always on; there is no flag. With no profiler open a span
+costs about a microsecond (tests/test_engine_recorder.py holds it under
+three).
+"""
+
+from __future__ import annotations
+
+import logging
+import time
+from typing import Callable, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
+
+logger = logging.getLogger(__name__)
+
+PHASES = ("admit", "pack", "put", "launch", "fetch", "emit", "wait")
+STEP_KINDS = ("block", "mixed", "prefill")
+#: a span this long is a stall of the loop: counted, and logged unless
+#: the phase is `wait` (an idle engine is no stall). An entry's interval
+#: this long is one too (a program compiling inside its launch, the
+#: profiler's stop, a paused guest: no step of a served model takes it)
+SLOW_SPAN_S = 0.5
+
+
+class _Span:
+    """One use of one phase: a context manager, made anew for each use so
+    that the threads that share a phase share no state but its row."""
+
+    __slots__ = ("_rec", "_name", "_more", "_ann", "_t0")
+
+    def __init__(self, rec: "Recorder", name: str, more: bool):
+        self._rec, self._name, self._more = rec, name, more
+
+    def __enter__(self):
+        self._ann = TraceAnnotation(self._rec._labels[self._name])
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        row = self._rec.phases[self._name]
+        row[1] += dt
+        if not self._more:
+            row[0] += 1
+        if dt >= SLOW_SPAN_S:
+            self._rec._slow(self._name, row, dt)
+        return False
+
+
+class Work:
+    """What one forward pass over an entry's rows asks for, summed row by
+    row as models/<family>.step_work takes it: real tokens, positions
+    attended, positions whose K and V are read, tokens sampled."""
+
+    __slots__ = ("real", "context", "kv_tokens", "sampled")
+
+    def __init__(self):
+        self.real = self.context = self.kv_tokens = self.sampled = 0
+
+    def chunk(self, start: int, n: int, completes: bool):
+        """A prompt's chunk of `n` tokens behind `start`: token j attends
+        start + j + 1 positions, the row reads start + n once, and samples
+        if the prompt ends in it."""
+        self.real += n
+        self.context += n * start + n * (n + 1) // 2
+        self.kv_tokens += start + n
+        self.sampled += bool(completes)
+
+    def decode(self, seq_len: int):
+        """A decode row at a context of `seq_len`, its own token in it."""
+        self.real += 1
+        self.context += seq_len
+        self.kv_tokens += seq_len
+        self.sampled += 1
+
+    def of(self, step_work) -> tuple:
+        return step_work(self.real, self.context, 1,
+                         kv_tokens=self.kv_tokens, sampled=self.sampled)
+
+
+class Recorder:
+    def __init__(self, describe: Optional[Callable[[], str]] = None):
+        # [count, seconds, slow] by phase; one writer thread a phase (the
+        # loop's: admit, pack, emit, wait; the device thread's: put,
+        # launch; the fetch thread's: fetch), so no lock
+        self.phases: Dict[str, list] = {p: [0, 0.0, 0] for p in PHASES}
+        self._labels = {p: f"engine.{p}" for p in PHASES}
+        # [entries, seconds ready to ready] by kind
+        self.steps: Dict[str, list] = {k: [0, 0.0] for k in STEP_KINDS}
+        self.stalled = [0, 0.0]  # the same of entries whose interval stalled
+        self.model_flops = 0
+        self.min_bytes = 0
+        # (count, seconds) of the host's clock around a device call, by tag
+        self.dev_time: Dict[str, tuple] = {}
+        self.req_admitted = 0
+        self.req_queue_wait_s = 0.0
+        self.req_first_tokens = 0
+        self.req_admit_to_first_s = 0.0
+        # what the loop is working on, for a slow span's log line
+        self.entry_kind = "none"
+        self._describe = describe
+        self._last_ready = 0.0
+
+    # -- phases ---------------------------------------------------------- #
+
+    def span(self, name: str, more: bool = False) -> _Span:
+        """`more`: the rest of a span that an `await` cut in two: its time
+        counts, and it is not counted again."""
+        return _Span(self, name, more)
+
+    def _slow(self, name: str, row: list, dt: float):
+        row[2] += 1
+        if name != "wait":
+            logger.warning(
+                "engine phase %s took %.3f s: entry %s, %s", name, dt,
+                self.entry_kind,
+                self._describe() if self._describe else "no engine",
+            )
+
+    def timed(self, tag: str, dt: float):
+        cnt, tot = self.dev_time.get(tag, (0, 0.0))
+        self.dev_time[tag] = (cnt + 1, tot + dt)
+
+    # -- pipeline entries ------------------------------------------------ #
+
+    def dispatched(self, entry: dict, kind: str, work: tuple):
+        """Stamp `entry` as it goes to the device: its kind, the host's
+        clock, and the (useful operations, least bytes) it was asked for."""
+        entry["step_kind"] = kind
+        entry["t_dispatch"] = time.perf_counter()
+        self.model_flops += work[0]
+        self.min_bytes += work[1]
+
+    def fetched(self, entries: List[dict], t_ready: float):
+        """The fetch that brought these entries back returned at `t_ready`:
+        their interval runs from the later of the fetch before it and
+        their dispatch. Entries that one fetch brings back together (a
+        split prefill beside a block) share it in equal parts: the host
+        cannot tell them apart. A stalled interval is kept out of its
+        kind's sum, which a window's mean is taken from: one profiler's
+        stop of 2.5 s would move 600 blocks' mean by 4 ms."""
+        if not entries:
+            return
+        since = max(self._last_ready, min(e["t_dispatch"] for e in entries))
+        share = max(t_ready - since, 0.0) / len(entries)
+        for e in entries:
+            row = self.stalled if share >= SLOW_SPAN_S \
+                else self.steps[e["step_kind"]]
+            row[0] += 1
+            row[1] += share
+        self._last_ready = t_ready
+
+    # -- the waits ahead of a first token -------------------------------- #
+
+    def admitted(self, slot) -> None:
+        """The first admission of a request (a preempted resume keeps its
+        first one)."""
+        if slot.admit_s:
+            return
+        slot.admit_s = time.monotonic()
+        self.req_admitted += 1
+        self.req_queue_wait_s += max(slot.admit_s - slot.arrival_s, 0.0)
+
+    def first_token(self, slot) -> None:
+        """The first token of a request handed to its stream."""
+        if slot.first_token_s or not slot.admit_s:
+            return
+        slot.first_token_s = time.monotonic()
+        self.req_first_tokens += 1
+        self.req_admit_to_first_s += slot.first_token_s - slot.admit_s
+
+    # -- export ---------------------------------------------------------- #
+
+    def stats(self) -> dict:
+        out = {
+            # the denominator of every share formed from these counters
+            "engine_clock_s": round(time.monotonic(), 6),
+            # floats on the wire: a busy worker's operations pass 2**63
+            # within days (the sums themselves stay exact integers)
+            "step_model_flops": float(self.model_flops),
+            "step_min_bytes": float(self.min_bytes),
+            "step_stalled_count": self.stalled[0],
+            "step_stalled_s": round(self.stalled[1], 6),
+            "req_admitted": self.req_admitted,
+            "req_queue_wait_s": round(self.req_queue_wait_s, 6),
+            "req_first_tokens": self.req_first_tokens,
+            "req_admit_to_first_s": round(self.req_admit_to_first_s, 6),
+        }
+        for name, (cnt, tot, slow) in self.phases.items():
+            out[f"phase_{name}_count"] = cnt
+            out[f"phase_{name}_s"] = round(tot, 6)
+            out[f"phase_{name}_slow"] = slow
+        for kind, (cnt, tot) in self.steps.items():
+            out[f"step_{kind}_count"] = cnt
+            out[f"step_{kind}_interval_s"] = round(tot, 6)
+        # list() is one atomic C-level snapshot: the device and fetch
+        # threads keep inserting while we iterate
+        for tag, (cnt, tot) in list(self.dev_time.items()):
+            out[f"dispatch_{tag}_count"] = cnt
+            out[f"dispatch_{tag}_s"] = round(tot, 3)
+        return out

@@ -71,6 +71,7 @@ def _candidates(logits: jax.Array) -> tuple:
     return jax.lax.top_k(logits, min(TOPK_CAP, V))
 
 
+@jax.named_scope("head_and_sample")  # with the forwards' head
 def sample(
     logits: jax.Array,  # [B, V] f32
     params: SamplingParams,
@@ -136,6 +137,7 @@ def sample(
 TOP_LOGPROBS_N = 5  # OpenAI caps top_logprobs alternatives at 5
 
 
+@jax.named_scope("head_and_sample")  # with the forwards' head
 def sample_lp(
     logits: jax.Array,  # [B, V] f32 (possibly penalized — the sampling dist)
     params: SamplingParams,
@@ -168,6 +170,7 @@ def sample_lp(
     return tokens, chosen - logz, top_ids, top_vals - logz[:, None]
 
 
+@jax.named_scope("head_and_sample")  # with the forwards' head
 def penalized(logits: jax.Array, params: SamplingParams,
               recent: jax.Array) -> jax.Array:
     """Apply the params' penalties over the lane's recent-token window

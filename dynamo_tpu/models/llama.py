@@ -151,6 +151,66 @@ def init_params(config: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
     return params
 
 
+def attention_params(c: LlamaConfig) -> int:
+    """Elements of one layer's four attention projections."""
+    q_dim, kv_dim = c.num_heads * c.head_dim, c.num_kv_heads * c.head_dim
+    return 2 * c.hidden_size * q_dim + 2 * c.hidden_size * kv_dim
+
+
+def step_work(c: LlamaConfig, real_tokens: int, context_tokens: int,
+              passes: int, *, sampled: Optional[int] = None,
+              kv_tokens: Optional[int] = None,
+              weight_bytes: Optional[float] = None,
+              kv_bytes: Optional[float] = None,
+              layer_params: Optional[int] = None,
+              layer_bytes: Optional[float] = None):
+    """(useful operations, least HBM bytes) of one pipeline entry: host
+    arithmetic for the engine's counters (`step_model_flops`,
+    `step_min_bytes`), from shapes the host holds at dispatch. Floors of
+    the work asked for: padding, recomputation and a second pass over the
+    weights do not count, so a program that wastes less reads higher
+    against a peak and none reads over it.
+
+    `real_tokens`: real tokens over all `passes` forward passes (a block
+    of K steps makes K). `context_tokens`: positions attended, summed over
+    the real tokens (a token attends its own). `sampled`: tokens that go
+    through the head (all of them unless said: a prompt's chunk samples
+    at most once). `kv_tokens`: positions whose K and V are read, summed
+    over passes and rows (`context_tokens` unless said: right for
+    one-token rows; a chunk of T tokens behind c reads c + T once).
+    `weight_bytes`: bytes a weight element (the dtype's unless said),
+    `kv_bytes`: bytes of one position's K and V in one layer.
+
+    Operations: 2 x the matmul parameters a real token passes through
+    (the head for sampled tokens; the embedding is a lookup) + 4 x layers
+    x heads x head size x context. Bytes: per pass the weights once, less
+    the embedding table (a tied head reads it as the head), + the K and V
+    read + the new tokens' written.
+
+    `layer_params`, `layer_bytes`: a routed family's own count of one
+    layer's parameters a token passes through and bytes a pass reads
+    (models/moe.step_work); the dense layer's otherwise."""
+    itemsize = jnp.dtype(c.dtype).itemsize
+    wb = itemsize if weight_bytes is None else weight_bytes
+    if kv_bytes is None:
+        kv_bytes = 2 * c.num_kv_heads * c.head_dim * itemsize
+    sampled = real_tokens if sampled is None else sampled
+    kv_tokens = context_tokens if kv_tokens is None else kv_tokens
+    if layer_params is None:
+        layer_params = attention_params(c) + 3 * c.hidden_size * c.intermediate_size
+        layer_bytes = layer_params * wb
+    head = c.hidden_size * c.vocab_size
+    flops = (
+        2 * (c.num_layers * layer_params * real_tokens + head * sampled)
+        + 4 * c.num_layers * c.num_heads * c.head_dim * context_tokens
+    )
+    nbytes = (
+        passes * (c.num_layers * layer_bytes + head * wb)
+        + c.num_layers * kv_bytes * (kv_tokens + real_tokens)
+    )
+    return int(flops), int(nbytes)
+
+
 def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
     x32 = x.astype(jnp.float32)
     var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
@@ -175,6 +235,7 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     return out.astype(x.dtype)
 
 
+@jax.named_scope("mlp")
 def _mlp(layer, x, c: LlamaConfig):
     h = rms_norm(x, layer["mlp_norm"], c.rms_norm_eps)
     gate = qdot(h, layer["w_gate"])
@@ -204,7 +265,8 @@ def prefill_forward(
     """
     c = config
     mlp_fn = mlp_fn or _mlp
-    x = embed_rows(params["embed"], tokens, c.dtype)  # [T, H]
+    with jax.named_scope("embed"):
+        x = embed_rows(params["embed"], tokens, c.dtype)  # [T, H]
     cos, sin = rope_cos_sin(positions, c.head_dim, c.rope_theta)
     page_size = kv_page_size(kv_k)
     T = tokens.shape[0]
@@ -218,33 +280,37 @@ def prefill_forward(
         new_v_chunks = []
         for li in range(c.num_layers):
             layer = jax.tree.map(lambda p: p[li], params["layers"])
-            h = rms_norm(x, layer["attn_norm"], c.rms_norm_eps)
-            q = qdot(h, layer["wq"]).astype(c.dtype)
-            k = qdot(h, layer["wk"]).astype(c.dtype)
-            v = qdot(h, layer["wv"]).astype(c.dtype)
-            q = q.reshape(-1, c.num_heads, c.head_dim)
-            k = k.reshape(-1, c.num_kv_heads, c.head_dim)
-            v = v.reshape(-1, c.num_kv_heads, c.head_dim)
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
-            # write chunk KV into the pages for this sequence
-            kv_k = _write_chunk(kv_k, li, k, positions, page_table, page_size)
-            kv_v = _write_chunk(kv_v, li, v, positions, page_table, page_size)
-            attn = prefill_attention(
-                q, k, v, kv_layer(kv_k, li), kv_layer(kv_v, li), positions,
-                page_table, context_len, total_len,
-            )
-            attn = attn.reshape(-1, c.num_heads * c.head_dim)
-            x = x + qdot(attn, layer["wo"]).astype(c.dtype)
+            with jax.named_scope("qkv"):
+                h = rms_norm(x, layer["attn_norm"], c.rms_norm_eps)
+                q = qdot(h, layer["wq"]).astype(c.dtype)
+                k = qdot(h, layer["wk"]).astype(c.dtype)
+                v = qdot(h, layer["wv"]).astype(c.dtype)
+                q = q.reshape(-1, c.num_heads, c.head_dim)
+                k = k.reshape(-1, c.num_kv_heads, c.head_dim)
+                v = v.reshape(-1, c.num_kv_heads, c.head_dim)
+                q = apply_rope(q, cos, sin)
+                k = apply_rope(k, cos, sin)
+            with jax.named_scope("attention"):
+                # write chunk KV into the pages for this sequence
+                kv_k = _write_chunk(kv_k, li, k, positions, page_table, page_size)
+                kv_v = _write_chunk(kv_v, li, v, positions, page_table, page_size)
+                attn = prefill_attention(
+                    q, k, v, kv_layer(kv_k, li), kv_layer(kv_v, li), positions,
+                    page_table, context_len, total_len,
+                )
+                attn = attn.reshape(-1, c.num_heads * c.head_dim)
+            with jax.named_scope("o_proj"):
+                x = x + qdot(attn, layer["wo"]).astype(c.dtype)
             x = mlp_fn(layer, x, c)
         return x, kv_k, kv_v
 
     x, kv_k, kv_v = body(x, kv_k, kv_v)
-    x = rms_norm(x, params["final_norm"], c.rms_norm_eps)
-    last = x[-1] if last_idx is None else x[last_idx]
-    head = head_leaf(params)
-    logits = qdot(last, head)
-    return logits, kv_k, kv_v
+    with jax.named_scope("head_and_sample"):
+        x = rms_norm(x, params["final_norm"], c.rms_norm_eps)
+        last = x[-1] if last_idx is None else x[last_idx]
+        head = head_leaf(params)
+        logits = qdot(last, head)
+        return logits, kv_k, kv_v
 
 
 def prefill_forward_batched(
@@ -275,7 +341,8 @@ def prefill_forward_batched(
     c = config
     mlp_fn = mlp_fn or _mlp
     B, T = tokens.shape
-    x = embed_rows(params["embed"], tokens, c.dtype)  # [B, T, H]
+    with jax.named_scope("embed"):
+        x = embed_rows(params["embed"], tokens, c.dtype)  # [B, T, H]
     if emb_override is not None:
         x = jnp.where(emb_mask[..., None], emb_override.astype(c.dtype), x)
     cos, sin = rope_cos_sin(positions, c.head_dim, c.rope_theta)
@@ -296,32 +363,36 @@ def prefill_forward_batched(
     for li in range(c.num_layers):
         layer = jax.tree.map(lambda p: p[li], params["layers"])
         ll = lora_mod.layer_lora(lora, li)
-        h = rms_norm(x, layer["attn_norm"], c.rms_norm_eps)
-        q = lora_mod.proj(h, layer["wq"], qdot, ll, "wq").astype(c.dtype)
-        k = lora_mod.proj(h, layer["wk"], qdot, ll, "wk").astype(c.dtype)
-        v = lora_mod.proj(h, layer["wv"], qdot, ll, "wv").astype(c.dtype)
-        q = q.reshape(B, T, c.num_heads, c.head_dim)
-        k = k.reshape(B, T, c.num_kv_heads, c.head_dim)
-        v = v.reshape(B, T, c.num_kv_heads, c.head_dim)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        kv_k = kv_write(kv_k, li, phys, offs, k)
-        kv_v = kv_write(kv_v, li, phys, offs, v)
-        attn = prefill_attention_batched(
-            q, kv_layer(kv_k, li), kv_layer(kv_v, li), positions, page_tables,
-            total_lens, context_lens
-        )
-        attn = attn.reshape(B, T, c.num_heads * c.head_dim)
-        x = x + lora_mod.proj(attn, layer["wo"], qdot, ll, "wo").astype(c.dtype)
+        with jax.named_scope("qkv"):
+            h = rms_norm(x, layer["attn_norm"], c.rms_norm_eps)
+            q = lora_mod.proj(h, layer["wq"], qdot, ll, "wq").astype(c.dtype)
+            k = lora_mod.proj(h, layer["wk"], qdot, ll, "wk").astype(c.dtype)
+            v = lora_mod.proj(h, layer["wv"], qdot, ll, "wv").astype(c.dtype)
+            q = q.reshape(B, T, c.num_heads, c.head_dim)
+            k = k.reshape(B, T, c.num_kv_heads, c.head_dim)
+            v = v.reshape(B, T, c.num_kv_heads, c.head_dim)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
+        with jax.named_scope("attention"):
+            kv_k = kv_write(kv_k, li, phys, offs, k)
+            kv_v = kv_write(kv_v, li, phys, offs, v)
+            attn = prefill_attention_batched(
+                q, kv_layer(kv_k, li), kv_layer(kv_v, li), positions, page_tables,
+                total_lens, context_lens
+            )
+            attn = attn.reshape(B, T, c.num_heads * c.head_dim)
+        with jax.named_scope("o_proj"):
+            x = x + lora_mod.proj(attn, layer["wo"], qdot, ll, "wo").astype(c.dtype)
         x = mlp_fn(layer, x, c)
 
-    x = rms_norm(x, params["final_norm"], c.rms_norm_eps)
-    head = head_leaf(params)
-    if all_logits:
-        return qdot(x, head), kv_k, kv_v  # [B, T, vocab]
-    last = x[jnp.arange(B), last_idx]  # [B, hidden]
-    logits = qdot(last, head)
-    return logits, kv_k, kv_v
+    with jax.named_scope("head_and_sample"):
+        x = rms_norm(x, params["final_norm"], c.rms_norm_eps)
+        head = head_leaf(params)
+        if all_logits:
+            return qdot(x, head), kv_k, kv_v  # [B, T, vocab]
+        last = x[jnp.arange(B), last_idx]  # [B, hidden]
+        logits = qdot(last, head)
+        return logits, kv_k, kv_v
 
 
 def _tiled_layout(tile: int, row_ids, row_starts, row_lens):
@@ -399,7 +470,8 @@ def ragged_forward(
     lora.proj exactly as in prefill_forward_batched."""
     c = config
     mlp_fn = mlp_fn or _mlp
-    x = embed_rows(params["embed"], tokens, c.dtype)  # [M, H]
+    with jax.named_scope("embed"):
+        x = embed_rows(params["embed"], tokens, c.dtype)  # [M, H]
     cos, sin = rope_cos_sin(positions, c.head_dim, c.rope_theta)
     page_size = kv_page_size(kv_k)
 
@@ -430,34 +502,38 @@ def ragged_forward(
     for li in range(c.num_layers):
         layer = jax.tree.map(lambda p: p[li], params["layers"])
         ll = lora_mod.layer_lora(lora, li)
-        h = rms_norm(x, layer["attn_norm"], c.rms_norm_eps)
-        q = lora_mod.proj(h, layer["wq"], qdot, ll, "wq").astype(c.dtype)
-        k = lora_mod.proj(h, layer["wk"], qdot, ll, "wk").astype(c.dtype)
-        v = lora_mod.proj(h, layer["wv"], qdot, ll, "wv").astype(c.dtype)
-        q = q.reshape(-1, c.num_heads, c.head_dim)
-        k = k.reshape(-1, c.num_kv_heads, c.head_dim)
-        v = v.reshape(-1, c.num_kv_heads, c.head_dim)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        kv_k = kv_write(kv_k, li, phys, offs, k)
-        kv_v = kv_write(kv_v, li, phys, offs, v)
-        if tile > 1:
-            q = _rows_at(q, from_tiled)  # [N, H, D]
-        attn = ragged_attention(
-            q, kv_layer(kv_k, li), kv_layer(kv_v, li), page_tables,
-            attn_starts, row_lens, ctx_lens
-        )
-        if tile > 1:
-            attn = _rows_at(attn, to_tiled)  # [M, H, D]
-        attn = attn.reshape(-1, c.num_heads * c.head_dim)
-        x = x + lora_mod.proj(attn, layer["wo"], qdot, ll, "wo").astype(c.dtype)
+        with jax.named_scope("qkv"):
+            h = rms_norm(x, layer["attn_norm"], c.rms_norm_eps)
+            q = lora_mod.proj(h, layer["wq"], qdot, ll, "wq").astype(c.dtype)
+            k = lora_mod.proj(h, layer["wk"], qdot, ll, "wk").astype(c.dtype)
+            v = lora_mod.proj(h, layer["wv"], qdot, ll, "wv").astype(c.dtype)
+            q = q.reshape(-1, c.num_heads, c.head_dim)
+            k = k.reshape(-1, c.num_kv_heads, c.head_dim)
+            v = v.reshape(-1, c.num_kv_heads, c.head_dim)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
+        with jax.named_scope("attention"):
+            kv_k = kv_write(kv_k, li, phys, offs, k)
+            kv_v = kv_write(kv_v, li, phys, offs, v)
+            if tile > 1:
+                q = _rows_at(q, from_tiled)  # [N, H, D]
+            attn = ragged_attention(
+                q, kv_layer(kv_k, li), kv_layer(kv_v, li), page_tables,
+                attn_starts, row_lens, ctx_lens
+            )
+            if tile > 1:
+                attn = _rows_at(attn, to_tiled)  # [M, H, D]
+            attn = attn.reshape(-1, c.num_heads * c.head_dim)
+        with jax.named_scope("o_proj"):
+            x = x + lora_mod.proj(attn, layer["wo"], qdot, ll, "wo").astype(c.dtype)
         x = mlp_fn(layer, x, c)
 
-    x = rms_norm(x, params["final_norm"], c.rms_norm_eps)
-    last = x[last_flat]  # [R, hidden]
-    head = head_leaf(params)
-    logits = qdot(last, head)
-    return logits, kv_k, kv_v
+    with jax.named_scope("head_and_sample"):
+        x = rms_norm(x, params["final_norm"], c.rms_norm_eps)
+        last = x[last_flat]  # [R, hidden]
+        head = head_leaf(params)
+        logits = qdot(last, head)
+        return logits, kv_k, kv_v
 
 
 def prefill_forward_ring(
@@ -488,7 +564,8 @@ def prefill_forward_ring(
     mlp_fn = mlp_fn or _mlp
     T = tokens.shape[0]
     positions = jnp.arange(T, dtype=jnp.int32)
-    x = embed_rows(params["embed"], tokens, c.dtype)  # [T, H]
+    with jax.named_scope("embed"):
+        x = embed_rows(params["embed"], tokens, c.dtype)  # [T, H]
     cos, sin = rope_cos_sin(positions, c.head_dim, c.rope_theta)
     page_size = kv_page_size(kv_k)
 
@@ -499,27 +576,31 @@ def prefill_forward_ring(
 
     for li in range(c.num_layers):
         layer = jax.tree.map(lambda p: p[li], params["layers"])
-        h = rms_norm(x, layer["attn_norm"], c.rms_norm_eps)
-        q = qdot(h, layer["wq"]).astype(c.dtype)
-        k = qdot(h, layer["wk"]).astype(c.dtype)
-        v = qdot(h, layer["wv"]).astype(c.dtype)
-        q = q.reshape(T, c.num_heads, c.head_dim)
-        k = k.reshape(T, c.num_kv_heads, c.head_dim)
-        v = v.reshape(T, c.num_kv_heads, c.head_dim)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        kv_k = kv_write(kv_k, li, phys, offs, k)
-        kv_v = kv_write(kv_v, li, phys, offs, v)
-        attn = ring_attention(q, k, v, mesh, axis_name=axis_name, causal=True)
-        attn = attn.reshape(T, c.num_heads * c.head_dim)
-        x = x + qdot(attn, layer["wo"]).astype(c.dtype)
+        with jax.named_scope("qkv"):
+            h = rms_norm(x, layer["attn_norm"], c.rms_norm_eps)
+            q = qdot(h, layer["wq"]).astype(c.dtype)
+            k = qdot(h, layer["wk"]).astype(c.dtype)
+            v = qdot(h, layer["wv"]).astype(c.dtype)
+            q = q.reshape(T, c.num_heads, c.head_dim)
+            k = k.reshape(T, c.num_kv_heads, c.head_dim)
+            v = v.reshape(T, c.num_kv_heads, c.head_dim)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
+        with jax.named_scope("attention"):
+            kv_k = kv_write(kv_k, li, phys, offs, k)
+            kv_v = kv_write(kv_v, li, phys, offs, v)
+            attn = ring_attention(q, k, v, mesh, axis_name=axis_name, causal=True)
+            attn = attn.reshape(T, c.num_heads * c.head_dim)
+        with jax.named_scope("o_proj"):
+            x = x + qdot(attn, layer["wo"]).astype(c.dtype)
         x = mlp_fn(layer, x, c)
 
-    x = rms_norm(x, params["final_norm"], c.rms_norm_eps)
-    last = x[jnp.maximum(real_len - 1, 0)]
-    head = head_leaf(params)
-    logits = qdot(last, head)
-    return logits, kv_k, kv_v
+    with jax.named_scope("head_and_sample"):
+        x = rms_norm(x, params["final_norm"], c.rms_norm_eps)
+        last = x[jnp.maximum(real_len - 1, 0)]
+        head = head_leaf(params)
+        logits = qdot(last, head)
+        return logits, kv_k, kv_v
 
 
 def _stage_layers_decode(local_params, local_kv, x, aux, valid, c, mlp_fn):
@@ -541,22 +622,25 @@ def _stage_layers_decode(local_params, local_kv, x, aux, valid, c, mlp_fn):
     n_local = kv_k_loc.shape[0]
     for li in range(n_local):
         layer = jax.tree.map(lambda p: p[li], local_params)
-        h = rms_norm(x, layer["attn_norm"], c.rms_norm_eps)
-        q = qdot(h, layer["wq"]).astype(c.dtype)
-        k = qdot(h, layer["wk"]).astype(c.dtype)
-        v = qdot(h, layer["wv"]).astype(c.dtype)
-        q = q.reshape(-1, c.num_heads, c.head_dim)
-        k = k.reshape(-1, c.num_kv_heads, c.head_dim)
-        v = v.reshape(-1, c.num_kv_heads, c.head_dim)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        kv_k_loc = kv_write(kv_k_loc, li, phys, offs, k)
-        kv_v_loc = kv_write(kv_v_loc, li, phys, offs, v)
-        attn = paged_attention_decode(
-            q, kv_layer(kv_k_loc, li), kv_layer(kv_v_loc, li), tables, seq_lens
-        )
-        attn = attn.reshape(-1, c.num_heads * c.head_dim)
-        x = x + qdot(attn, layer["wo"]).astype(c.dtype)
+        with jax.named_scope("qkv"):
+            h = rms_norm(x, layer["attn_norm"], c.rms_norm_eps)
+            q = qdot(h, layer["wq"]).astype(c.dtype)
+            k = qdot(h, layer["wk"]).astype(c.dtype)
+            v = qdot(h, layer["wv"]).astype(c.dtype)
+            q = q.reshape(-1, c.num_heads, c.head_dim)
+            k = k.reshape(-1, c.num_kv_heads, c.head_dim)
+            v = v.reshape(-1, c.num_kv_heads, c.head_dim)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
+        with jax.named_scope("attention"):
+            kv_k_loc = kv_write(kv_k_loc, li, phys, offs, k)
+            kv_v_loc = kv_write(kv_v_loc, li, phys, offs, v)
+            attn = paged_attention_decode(
+                q, kv_layer(kv_k_loc, li), kv_layer(kv_v_loc, li), tables, seq_lens
+            )
+            attn = attn.reshape(-1, c.num_heads * c.head_dim)
+        with jax.named_scope("o_proj"):
+            x = x + qdot(attn, layer["wo"]).astype(c.dtype)
         x = mlp_fn(layer, x, c)
     return x, (kv_k_loc, kv_v_loc)
 
@@ -597,7 +681,8 @@ def decode_forward_pp(
         kv_k.reshape(S, L // S, *kv_k.shape[1:]),
         kv_v.reshape(S, L // S, *kv_v.shape[1:]),
     )
-    x = embed_rows(params["embed"], tokens, c.dtype)  # [B, H]
+    with jax.named_scope("embed"):
+        x = embed_rows(params["embed"], tokens, c.dtype)  # [B, H]
     x_mb = x.reshape(M, mb, -1)
     aux_mb = {
         "positions": positions.reshape(M, mb),
@@ -614,10 +699,11 @@ def decode_forward_pp(
     kv_k = kv_k_s.reshape(L, *kv_k.shape[1:])
     kv_v = kv_v_s.reshape(L, *kv_v.shape[1:])
     x = out.reshape(B, -1)
-    x = rms_norm(x, params["final_norm"], c.rms_norm_eps)
-    head = head_leaf(params)
-    logits = qdot(x, head)
-    return logits, kv_k, kv_v
+    with jax.named_scope("head_and_sample"):
+        x = rms_norm(x, params["final_norm"], c.rms_norm_eps)
+        head = head_leaf(params)
+        logits = qdot(x, head)
+        return logits, kv_k, kv_v
 
 
 def _stage_layers_prefill(local_params, local_kv, x, aux, valid, c, mlp_fn):
@@ -641,24 +727,27 @@ def _stage_layers_prefill(local_params, local_kv, x, aux, valid, c, mlp_fn):
     n_local = kv_k_loc.shape[0]
     for li in range(n_local):
         layer = jax.tree.map(lambda p: p[li], local_params)
-        h = rms_norm(x, layer["attn_norm"], c.rms_norm_eps)
-        q = qdot(h, layer["wq"]).astype(c.dtype)
-        k = qdot(h, layer["wk"]).astype(c.dtype)
-        v = qdot(h, layer["wv"]).astype(c.dtype)
-        q = q.reshape(-1, c.num_heads, c.head_dim)
-        k = k.reshape(-1, c.num_kv_heads, c.head_dim)
-        v = v.reshape(-1, c.num_kv_heads, c.head_dim)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        kv_k_loc = kv_write(kv_k_loc, li, phys, offs, k)
-        kv_v_loc = kv_write(kv_v_loc, li, phys, offs, v)
-        attn = prefill_attention(
-            q, k, v, kv_layer(kv_k_loc, li), kv_layer(kv_v_loc, li),
-            positions, table,
-            context_len, total_len,
-        )
-        attn = attn.reshape(-1, c.num_heads * c.head_dim)
-        x = x + qdot(attn, layer["wo"]).astype(c.dtype)
+        with jax.named_scope("qkv"):
+            h = rms_norm(x, layer["attn_norm"], c.rms_norm_eps)
+            q = qdot(h, layer["wq"]).astype(c.dtype)
+            k = qdot(h, layer["wk"]).astype(c.dtype)
+            v = qdot(h, layer["wv"]).astype(c.dtype)
+            q = q.reshape(-1, c.num_heads, c.head_dim)
+            k = k.reshape(-1, c.num_kv_heads, c.head_dim)
+            v = v.reshape(-1, c.num_kv_heads, c.head_dim)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
+        with jax.named_scope("attention"):
+            kv_k_loc = kv_write(kv_k_loc, li, phys, offs, k)
+            kv_v_loc = kv_write(kv_v_loc, li, phys, offs, v)
+            attn = prefill_attention(
+                q, k, v, kv_layer(kv_k_loc, li), kv_layer(kv_v_loc, li),
+                positions, table,
+                context_len, total_len,
+            )
+            attn = attn.reshape(-1, c.num_heads * c.head_dim)
+        with jax.named_scope("o_proj"):
+            x = x + qdot(attn, layer["wo"]).astype(c.dtype)
         x = mlp_fn(layer, x, c)
     return x, (kv_k_loc, kv_v_loc)
 
@@ -697,7 +786,8 @@ def prefill_forward_pp(
         kv_v.reshape(S, L // S, *kv_v.shape[1:]),
     )
     positions = context_len + jnp.arange(T, dtype=jnp.int32)
-    x = embed_rows(params["embed"], tokens, c.dtype).reshape(M, t, -1)
+    with jax.named_scope("embed"):
+        x = embed_rows(params["embed"], tokens, c.dtype).reshape(M, t, -1)
     span_starts = context_len + jnp.arange(M, dtype=jnp.int32) * t
     span_real = jnp.clip(real_len - jnp.arange(M) * t, 0, t)  # real tokens/span
     aux_mb = {
@@ -753,45 +843,50 @@ def decode_forward(
 
     c = config
     mlp_fn = mlp_fn or _mlp
-    x = embed_rows(params["embed"], tokens, c.dtype)  # [B, H]
+    with jax.named_scope("embed"):
+        x = embed_rows(params["embed"], tokens, c.dtype)  # [B, H]
     cos, sin = rope_cos_sin(positions, c.head_dim, c.rope_theta)
     page_size = kv_page_size(kv_k)
 
     for li in range(c.num_layers):
         layer = jax.tree.map(lambda p: p[li], params["layers"])
         ll = lora_mod.layer_lora(lora, li)
-        h = rms_norm(x, layer["attn_norm"], c.rms_norm_eps)
-        q = lora_mod.proj(h, layer["wq"], qdot, ll, "wq").astype(c.dtype)
-        k = lora_mod.proj(h, layer["wk"], qdot, ll, "wk").astype(c.dtype)
-        v = lora_mod.proj(h, layer["wv"], qdot, ll, "wv").astype(c.dtype)
-        q = q.reshape(-1, c.num_heads, c.head_dim)
-        k = k.reshape(-1, c.num_kv_heads, c.head_dim)
-        v = v.reshape(-1, c.num_kv_heads, c.head_dim)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        # write each slot's new KV at its position. Positions past the table
-        # (fused-block speculation overshooting max_model_len) route to
-        # physical page 0 — the engine's reserved scratch page — instead of
-        # XLA's silent clamp-to-last-page, which could corrupt a real
-        # (possibly shared/committed) KV page.
-        max_positions = page_tables.shape[1] * page_size
-        logical = jnp.minimum(positions // page_size, page_tables.shape[1] - 1)
-        phys = jnp.take_along_axis(page_tables, logical[:, None], axis=1)[:, 0]
-        phys = jnp.where(positions < max_positions, phys, 0)
-        offs = positions % page_size
-        kv_k = kv_write(kv_k, li, phys, offs, k[:, 0] if k.ndim == 4 else k)
-        kv_v = kv_write(kv_v, li, phys, offs, v[:, 0] if v.ndim == 4 else v)
-        attn = paged_attention_decode(
-            q, kv_layer(kv_k, li), kv_layer(kv_v, li), page_tables, seq_lens
-        )
-        attn = attn.reshape(-1, c.num_heads * c.head_dim)
-        x = x + lora_mod.proj(attn, layer["wo"], qdot, ll, "wo").astype(c.dtype)
+        with jax.named_scope("qkv"):
+            h = rms_norm(x, layer["attn_norm"], c.rms_norm_eps)
+            q = lora_mod.proj(h, layer["wq"], qdot, ll, "wq").astype(c.dtype)
+            k = lora_mod.proj(h, layer["wk"], qdot, ll, "wk").astype(c.dtype)
+            v = lora_mod.proj(h, layer["wv"], qdot, ll, "wv").astype(c.dtype)
+            q = q.reshape(-1, c.num_heads, c.head_dim)
+            k = k.reshape(-1, c.num_kv_heads, c.head_dim)
+            v = v.reshape(-1, c.num_kv_heads, c.head_dim)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
+        with jax.named_scope("attention"):
+            # write each slot's new KV at its position. Positions past the table
+            # (fused-block speculation overshooting max_model_len) route to
+            # physical page 0 — the engine's reserved scratch page — instead of
+            # XLA's silent clamp-to-last-page, which could corrupt a real
+            # (possibly shared/committed) KV page.
+            max_positions = page_tables.shape[1] * page_size
+            logical = jnp.minimum(positions // page_size, page_tables.shape[1] - 1)
+            phys = jnp.take_along_axis(page_tables, logical[:, None], axis=1)[:, 0]
+            phys = jnp.where(positions < max_positions, phys, 0)
+            offs = positions % page_size
+            kv_k = kv_write(kv_k, li, phys, offs, k[:, 0] if k.ndim == 4 else k)
+            kv_v = kv_write(kv_v, li, phys, offs, v[:, 0] if v.ndim == 4 else v)
+            attn = paged_attention_decode(
+                q, kv_layer(kv_k, li), kv_layer(kv_v, li), page_tables, seq_lens
+            )
+            attn = attn.reshape(-1, c.num_heads * c.head_dim)
+        with jax.named_scope("o_proj"):
+            x = x + lora_mod.proj(attn, layer["wo"], qdot, ll, "wo").astype(c.dtype)
         x = mlp_fn(layer, x, c)
 
-    x = rms_norm(x, params["final_norm"], c.rms_norm_eps)
-    head = head_leaf(params)
-    logits = qdot(x, head)
-    return logits, kv_k, kv_v
+    with jax.named_scope("head_and_sample"):
+        x = rms_norm(x, params["final_norm"], c.rms_norm_eps)
+        head = head_leaf(params)
+        logits = qdot(x, head)
+        return logits, kv_k, kv_v
 
 
 def param_count(params) -> int:

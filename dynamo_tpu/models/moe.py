@@ -296,6 +296,33 @@ def expert_rows(c: MoeConfig, T: int, real: int, quantized: bool):
     return routed, max(tiles, 0) * _GMM_ROWS
 
 
+def step_work(c: MoeConfig, real_tokens: int, context_tokens: int,
+              passes: int, *, weight_bytes: Optional[float] = None, **kw):
+    """(useful operations, least HBM bytes) of one pipeline entry, as
+    llama.step_work, with a routed layer's count: a token passes through
+    the attention projections, the router and the K experts it is sent to
+    (not the capacity the einsum pads to), and a pass reads at most
+    min(E, its real rows x K) experts a layer."""
+    wb = jnp.dtype(c.dtype).itemsize if weight_bytes is None \
+        else weight_bytes
+    expert = 3 * c.hidden_size * c.intermediate_size
+    router = c.hidden_size * c.num_experts  # kept in f32
+    rows = -(-real_tokens // max(passes, 1))  # real rows of one pass
+    read = min(c.num_experts, rows * c.num_experts_per_tok)
+    return llama.step_work(
+        c, real_tokens, context_tokens, passes, weight_bytes=wb,
+        layer_params=(
+            llama.attention_params(c) + router
+            + c.num_experts_per_tok * expert
+        ),
+        layer_bytes=(
+            llama.attention_params(c) * wb + router * 4 + read * expert * wb
+        ),
+        **kw,
+    )
+
+
+@jax.named_scope("experts")
 def moe_mlp(
     layer: Dict[str, Any], x: jax.Array, c: MoeConfig,
     valid: Optional[jax.Array] = None,

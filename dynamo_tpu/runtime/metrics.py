@@ -141,8 +141,49 @@ METRICS = {
     "compile_surfaces": {"kind": "info", "layer": "engine", "help": "Per-surface XLA executable counts (COMPILE_SURFACES keys).", "dynamic": True},
     "compiled_variants": {"kind": "gauge", "layer": "engine", "unit": "programs", "help": "Total XLA executables across staged surfaces.", "export": True},
     "post_warmup_compiles": {"kind": "counter", "layer": "engine", "unit": "programs", "help": "XLA programs compiled after the warmup baseline (steady-state debt; 0 is the contract).", "export": True},
-    "mixed_padding_frac": {"kind": "gauge", "layer": "engine", "unit": "fraction", "help": "Padding fraction paid by the mixed path.", "export": True},
-    "split_padding_frac": {"kind": "gauge", "layer": "engine", "unit": "fraction", "help": "Padding fraction paid by the split path.", "export": True},
+    "split_real_tokens": {"kind": "counter", "layer": "engine", "unit": "tokens", "help": "Real tokens of the split prefill+decode pairs that served a mixed-shaped step.", "export": True},
+    "split_padded_tokens": {"kind": "counter", "layer": "engine", "unit": "slots", "help": "Token slots those split pairs ran in.", "export": True},
+    # ---- the engine loop's recorder (engine/recorder.py, docs/observability.md
+    # "The engine's iteration"): seconds by phase of an iteration, every pipeline
+    # entry's kind, interval and work, the waits ahead of a first token. All
+    # monotonic: a share over a window is two differences, the second of
+    # engine_clock_s. Emitted by loops over PHASES and STEP_KINDS (dynamic)
+    "engine_clock_s": {"kind": "counter", "layer": "engine", "unit": "seconds", "help": "time.monotonic() when the stats were taken: the denominator of every share formed from two of the engine's counters.", "export": True},
+    "phase_admit_count": {"kind": "counter", "layer": "engine", "unit": "spans", "help": "Spans of the engine loop's `admit` phase: ordering, page allocation and prefix-cache lookup of waiting requests.", "dynamic": True, "export": True},
+    "phase_admit_s": {"kind": "counter", "layer": "engine", "unit": "seconds", "help": "Seconds inside the `admit` phase (profiler span engine.admit).", "dynamic": True, "export": True},
+    "phase_admit_slow": {"kind": "counter", "layer": "engine", "unit": "spans", "help": "Spans of the `admit` phase that took 0.5 s or more (each logs one WARNING line naming the phase).", "dynamic": True, "export": True},
+    "phase_pack_count": {"kind": "counter", "layer": "engine", "unit": "spans", "help": "Spans of the engine loop's `pack` phase: the host's planning and packing of one entry up to the device call.", "dynamic": True, "export": True},
+    "phase_pack_s": {"kind": "counter", "layer": "engine", "unit": "seconds", "help": "Seconds inside the `pack` phase (profiler span engine.pack).", "dynamic": True, "export": True},
+    "phase_pack_slow": {"kind": "counter", "layer": "engine", "unit": "spans", "help": "Spans of the `pack` phase that took 0.5 s or more (each logs one WARNING line naming the phase).", "dynamic": True, "export": True},
+    "phase_put_count": {"kind": "counter", "layer": "engine", "unit": "spans", "help": "Spans of the engine loop's `put` phase: host-to-device operands of one entry.", "dynamic": True, "export": True},
+    "phase_put_s": {"kind": "counter", "layer": "engine", "unit": "seconds", "help": "Seconds inside the `put` phase (profiler span engine.put).", "dynamic": True, "export": True},
+    "phase_put_slow": {"kind": "counter", "layer": "engine", "unit": "spans", "help": "Spans of the `put` phase that took 0.5 s or more (each logs one WARNING line naming the phase).", "dynamic": True, "export": True},
+    "phase_launch_count": {"kind": "counter", "layer": "engine", "unit": "spans", "help": "Spans of the engine loop's `launch` phase: the jitted calls themselves (they return at once unless the runtime's queue is full or a program compiles).", "dynamic": True, "export": True},
+    "phase_launch_s": {"kind": "counter", "layer": "engine", "unit": "seconds", "help": "Seconds inside the `launch` phase (profiler span engine.launch).", "dynamic": True, "export": True},
+    "phase_launch_slow": {"kind": "counter", "layer": "engine", "unit": "spans", "help": "Spans of the `launch` phase that took 0.5 s or more (each logs one WARNING line naming the phase).", "dynamic": True, "export": True},
+    "phase_fetch_count": {"kind": "counter", "layer": "engine", "unit": "spans", "help": "Spans of the engine loop's `fetch` phase: jax.device_get of an entry's result: the host waiting for the device.", "dynamic": True, "export": True},
+    "phase_fetch_s": {"kind": "counter", "layer": "engine", "unit": "seconds", "help": "Seconds inside the `fetch` phase (profiler span engine.fetch).", "dynamic": True, "export": True},
+    "phase_fetch_slow": {"kind": "counter", "layer": "engine", "unit": "spans", "help": "Spans of the `fetch` phase that took 0.5 s or more (each logs one WARNING line naming the phase).", "dynamic": True, "export": True},
+    "phase_emit_count": {"kind": "counter", "layer": "engine", "unit": "spans", "help": "Spans of the engine loop's `emit` phase: bookkeeping and the streams' frames of a fetched entry.", "dynamic": True, "export": True},
+    "phase_emit_s": {"kind": "counter", "layer": "engine", "unit": "seconds", "help": "Seconds inside the `emit` phase (profiler span engine.emit).", "dynamic": True, "export": True},
+    "phase_emit_slow": {"kind": "counter", "layer": "engine", "unit": "spans", "help": "Spans of the `emit` phase that took 0.5 s or more (each logs one WARNING line naming the phase).", "dynamic": True, "export": True},
+    "phase_wait_count": {"kind": "counter", "layer": "engine", "unit": "spans", "help": "Spans of the engine loop's `wait` phase: the loop idle or yielding to the event loop's other tasks.", "dynamic": True, "export": True},
+    "phase_wait_s": {"kind": "counter", "layer": "engine", "unit": "seconds", "help": "Seconds inside the `wait` phase (profiler span engine.wait).", "dynamic": True, "export": True},
+    "phase_wait_slow": {"kind": "counter", "layer": "engine", "unit": "spans", "help": "Spans of the `wait` phase that took 0.5 s or more (an idle engine: not logged).", "dynamic": True, "export": True},
+    "step_block_count": {"kind": "counter", "layer": "engine", "unit": "entries", "help": "Pipeline entries fetched: decode blocks (plain, guided, LoRA, spec).", "dynamic": True, "export": True},
+    "step_block_interval_s": {"kind": "counter", "layer": "engine", "unit": "seconds", "help": "Ready-to-ready seconds of those entries: the device's time for each plus whatever the device waited for the host inside it.", "dynamic": True, "export": True},
+    "step_mixed_count": {"kind": "counter", "layer": "engine", "unit": "entries", "help": "Pipeline entries fetched: mixed steps.", "dynamic": True, "export": True},
+    "step_mixed_interval_s": {"kind": "counter", "layer": "engine", "unit": "seconds", "help": "Ready-to-ready seconds of those entries: the device's time for each plus whatever the device waited for the host inside it.", "dynamic": True, "export": True},
+    "step_prefill_count": {"kind": "counter", "layer": "engine", "unit": "entries", "help": "Pipeline entries fetched: split prefill dispatches.", "dynamic": True, "export": True},
+    "step_prefill_interval_s": {"kind": "counter", "layer": "engine", "unit": "seconds", "help": "Ready-to-ready seconds of those entries: the device's time for each plus whatever the device waited for the host inside it.", "dynamic": True, "export": True},
+    "step_stalled_count": {"kind": "counter", "layer": "engine", "unit": "entries", "help": "Pipeline entries whose ready-to-ready interval was 0.5 s or more: a stall of the pipeline (a program compiling inside the launch, the profiler's stop, a paused guest), kept out of their kind's count and seconds.", "export": True},
+    "step_stalled_s": {"kind": "counter", "layer": "engine", "unit": "seconds", "help": "Ready-to-ready seconds of those stalled entries.", "export": True},
+    "step_model_flops": {"kind": "counter", "layer": "engine", "unit": "flop", "help": "Useful operations of the entries dispatched (models/<family>.step_work): 2 x matmul parameters a real token passes through + attention over its context; no padding, no recomputation.", "export": True},
+    "step_min_bytes": {"kind": "counter", "layer": "engine", "unit": "bytes", "help": "Least HBM bytes the entries dispatched must move: per forward pass the weights once (a routed model: the experts its rows can reach), the live context's K and V read once, the new tokens' written.", "export": True},
+    "req_admitted": {"kind": "counter", "layer": "engine", "unit": "requests", "help": "Requests admitted to a slot for the first time (a preempted resume is not counted again).", "export": True},
+    "req_queue_wait_s": {"kind": "counter", "layer": "engine", "unit": "seconds", "help": "Summed arrival-to-first-admission wait of those requests.", "export": True},
+    "req_first_tokens": {"kind": "counter", "layer": "engine", "unit": "requests", "help": "Requests whose first token was handed to their stream.", "export": True},
+    "req_admit_to_first_s": {"kind": "counter", "layer": "engine", "unit": "seconds", "help": "Summed first-admission-to-first-token time of those requests.", "export": True},
     # per-kind fused coverage (docs/ragged_attention.md "Row classes"):
     # proves blended guided/spec/lora traffic actually rides the fused
     # path; the blended-trace CI smoke gates mixed_coverage_frac >= 0.9
@@ -184,7 +225,6 @@ METRICS = {
     "sched_tenants_served": {"kind": "gauge", "layer": "sched", "help": "Distinct tenants the fairness tiebreak has served.", "export": True},
     "sched_last_budget_tokens": {"kind": "gauge", "layer": "sched", "unit": "tokens", "help": "Last step's granted token budget."},
     "sched_last_slack_ms": {"kind": "gauge", "layer": "sched", "unit": "ms", "help": "Last step's tightest deadline slack."},
-    "sched_last_decision": {"kind": "info", "layer": "sched", "help": "Last scheduling decision tag."},
     SCHED_EST_TTFT_MS: {"kind": "gauge", "layer": "sched", "unit": "ms", "help": "Projected TTFT for one more admitted request — the gate's admission ceiling and the disagg router's routing signal.", "wire": True, "export": True},
     SCHED_EST_REQ_MS: {"kind": "gauge", "layer": "sched", "unit": "ms", "help": "Marginal TTFT cost of one more admitted request (the gate's optimism debt between publishes).", "wire": True, "export": True},
     SCHED_EST_PREFILL_TOK_S: {"kind": "gauge", "layer": "sched", "unit": "tok/s", "help": "Per-worker marginal prefill throughput estimate from the cost-model EWMAs — prices the planner's re-role (morph vs spawn) decision.", "wire": True, "export": True},

@@ -91,10 +91,11 @@ GUARDED_STATE = {
     "JaxEngine._pending_prefill": "single-task:_step_loop",
     "JaxEngine._mixed_wait_drain": "single-task:_step_loop",
     "JaxEngine._carry_valid": "single-task:_step_loop",
-    # per-dispatch-type device occupancy: mutated only inside the `timed`
-    # wrapper, which runs on the jax-step device-executor thread; readers
+    # per-dispatch-type device occupancy (engine/recorder.py): mutated only
+    # inside `timed`, which the engine's wrapper of the same name calls on
+    # the jax-step and jax-fetch executor threads, one tag a thread; readers
     # (stats) take a list() snapshot.
-    "JaxEngine._dev_time": "thread:timed",
+    "Recorder.dev_time": "thread:timed",
     # dynosched (engine/scheduler/): the cost model's per-shape EWMA is
     # written on the jax-step thread (the `timed` wrapper observes every
     # dispatch) and read on the event loop (planning, stats, the disagg

@@ -722,7 +722,7 @@ def test_seeded_use_after_donate_fires_comp_donation(tmp_path):
     pat = re.compile(
         r"(first, )self\.kv_k"
         r"(, self\.kv_v, self\._rng = self\._prefill_batch\("
-        r"(?:.*\n)*?        \)\n)"
+        r"(?:.*\n)*? +\)\n)"  # (inside the `launch` span since PR 41)
         r"(        return first)"
     )
     text, n = pat.subn(

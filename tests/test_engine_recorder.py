@@ -1,0 +1,516 @@
+"""The engine's span-and-counter recorder (dynamo_tpu/engine/recorder.py):
+phases of an iteration, every pipeline entry's kind, interval and work,
+the waits ahead of a first token, and the benchmark's readers of them
+(benchmark/layer_metrics/*.json added with it). CPU, tiny models."""
+
+import asyncio
+import glob
+import importlib.util
+import json
+import logging
+import os
+import sys
+import time
+
+import jax
+import numpy as np
+import pytest
+
+from dynamo_tpu.engine import EngineConfig, JaxEngine
+from dynamo_tpu.engine import recorder as recorder_mod
+from dynamo_tpu.engine.recorder import PHASES, STEP_KINDS, Recorder
+from dynamo_tpu.llm.protocols import PreprocessedRequest
+from dynamo_tpu.models import llama, moe
+from dynamo_tpu.runtime.engine import Context
+from dynamo_tpu.runtime.metrics import METRICS
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+PAGE = 8
+K = 4  # decode_block_steps of the engines below
+DENSE = llama.LlamaConfig.tiny()
+ROUTED = moe.MoeConfig.tiny_moe(capacity_factor=2.0)
+
+
+# -- the recorder alone --------------------------------------------------- #
+
+def test_a_span_costs_under_three_microseconds_with_no_profiler_open():
+    rec = Recorder()
+    n = 50_000
+    best = float("inf")
+    for _ in range(5):
+        t0 = time.perf_counter()
+        for _ in range(n):
+            with rec.span("pack"):
+                pass
+        best = min(best, (time.perf_counter() - t0) / n)
+    assert rec.phases["pack"][0] == 5 * n
+    assert best < 3e-6, f"{best * 1e6:.2f} us a span"
+
+
+def test_a_span_counts_once_and_its_continuation_only_adds_time():
+    rec = Recorder()
+    with rec.span("pack"):
+        time.sleep(0.002)
+    with rec.span("pack", more=True):
+        time.sleep(0.002)
+    count, seconds, slow = rec.phases["pack"]
+    assert count == 1 and 0.004 <= seconds < 0.2 and slow == 0
+    out = rec.stats()
+    assert out["phase_pack_count"] == 1 and out["phase_pack_s"] == round(seconds, 6)
+    assert all(out[f"phase_{p}_s"] == 0 for p in PHASES if p != "pack")
+
+
+@pytest.mark.parametrize("phase", ["put", "wait"])
+def test_a_planted_sleep_raises_slow_and_logs_the_phase(phase, caplog, monkeypatch):
+    """0.6 s inside one phase: its `_slow` rises by one and, unless the
+    phase is `wait` (an idle engine is no stall), one WARNING line names
+    it with the entry's kind and the engine's load."""
+    assert recorder_mod.SLOW_SPAN_S == 0.5
+    rec = Recorder(lambda: "1 in flight, 3 running, 2 waiting")
+    rec.entry_kind = "mixed"
+    with caplog.at_level(logging.WARNING, logger=recorder_mod.__name__):
+        with rec.span(phase):
+            time.sleep(0.6)
+        with rec.span(phase):
+            pass
+    assert rec.phases[phase][2] == 1 and rec.phases[phase][0] == 2
+    lines = [r.getMessage() for r in caplog.records]
+    if phase == "wait":
+        assert lines == []
+    else:
+        assert len(lines) == 1
+        assert lines[0].startswith("engine phase put took 0.6")
+        assert lines[0].endswith(
+            " s: entry mixed, 1 in flight, 3 running, 2 waiting")
+
+
+def test_an_entrys_interval_runs_from_the_later_of_last_ready_and_dispatch():
+    rec = Recorder()
+    a, b, c, d = {}, {}, {}, {}
+    rec.dispatched(a, "block", (10, 100))
+    rec.dispatched(b, "mixed", (1, 1))
+    t0 = a["t_dispatch"]
+    # a idle pipeline: a's interval starts at its dispatch
+    rec.fetched([a], t0 + 0.100)
+    # b was queued behind a: ready to ready
+    rec.fetched([b], t0 + 0.130)
+    assert rec.steps["block"] == [1, pytest.approx(0.100)]
+    assert rec.steps["mixed"][0] == 1
+    assert rec.steps["mixed"][1] == pytest.approx(0.130 - 0.100, abs=1e-4)
+    # the engine sat idle, then one fetch brings a prefill and a block back
+    # together: from the earlier dispatch, in equal parts
+    rec.dispatched(c, "prefill", (0, 0))
+    rec.dispatched(d, "block", (0, 0))
+    c["t_dispatch"], d["t_dispatch"] = t0 + 5.0, t0 + 5.01
+    rec.fetched([c, d], t0 + 5.2)
+    assert rec.steps["prefill"] == [1, pytest.approx(0.1)]
+    assert rec.steps["block"] == [2, pytest.approx(0.2)]
+    # an interval of half a second or more is a stall, not a step: it is
+    # kept out of its kind's mean (the profiler's stop, a compile)
+    e = {}
+    rec.dispatched(e, "block", (0, 0))
+    e["t_dispatch"] = t0 + 6.0
+    rec.fetched([e], t0 + 8.5)
+    assert rec.steps["block"] == [2, pytest.approx(0.2)]
+    out = rec.stats()
+    assert out["step_stalled_count"] == 1 and out["step_stalled_s"] == 2.5
+    assert out["step_model_flops"] == 11 and out["step_min_bytes"] == 101
+    assert sum(out[f"step_{k}_count"] for k in STEP_KINDS) == 4
+
+
+# -- the work of an entry, by hand ---------------------------------------- #
+
+def test_step_work_dense_by_hand():
+    c = DENSE  # hidden 64, mlp 128, 2 layers, 4 heads / 2 kv heads of 16
+    attn = 64 * 64 + 2 * 64 * 32 + 64 * 64  # q, k and v, o
+    layer = attn + 3 * 64 * 128
+    head = 64 * 512
+    wb = 2  # bf16
+    # a block of 4 passes over 3 lanes at contexts 10, 20, 30: 12 tokens
+    ctx = sum(4 * L + 6 for L in (10, 20, 30))
+    flops, nbytes = llama.step_work(c, 12, ctx, 4)
+    assert flops == 2 * (2 * layer * 12 + head * 12) + 4 * 2 * 4 * 16 * ctx
+    kv = 2 * 2 * 16 * wb  # one position's K and V in one layer
+    assert nbytes == 4 * (2 * layer + head) * wb + 2 * kv * (ctx + 12)
+    # a prompt's chunk of 8 tokens behind 5: one sample, 13 positions read
+    flops, nbytes = llama.step_work(c, 8, 8 * 5 + 36, 1, sampled=1, kv_tokens=13)
+    assert flops == 2 * (2 * layer * 8 + head) + 4 * 2 * 4 * 16 * 76
+    assert nbytes == (2 * layer + head) * wb + 2 * kv * (13 + 8)
+    # int8 weights and a quantized pool at their own bytes
+    _, q = llama.step_work(c, 1, 1, 1, weight_bytes=1, kv_bytes=40.0)
+    assert q == (2 * layer + head) + 2 * 40 * 2
+    assert llama.step_work(c, 0, 0, 0) == (0, 0)
+
+
+def test_step_work_routed_by_hand():
+    c = ROUTED  # 4 experts of width 96, 2 a token
+    attn = 64 * 64 + 2 * 64 * 32 + 64 * 64
+    expert, router, head, wb = 3 * 64 * 96, 64 * 4, 64 * 512, 2
+    per_token = attn + router + 2 * expert  # the experts it is sent to
+    kv = 2 * 2 * 16 * wb
+    # one row a pass reaches at most 2 of the 4 experts
+    flops, nbytes = moe.step_work(c, 3, 30, 3)
+    assert flops == 2 * (2 * per_token * 3 + head * 3) + 4 * 2 * 4 * 16 * 30
+    one_pass = 2 * (attn * wb + router * 4 + 2 * expert * wb) + head * wb
+    assert nbytes == 3 * one_pass + 2 * kv * (30 + 3)
+    # 5 rows a pass could reach 10: capped at the 4 there are
+    _, nbytes = moe.step_work(c, 5, 5, 1)
+    assert nbytes == 2 * (attn * wb + router * 4 + 4 * expert * wb) \
+        + head * wb + 2 * kv * 10
+
+
+# -- a tiny engine -------------------------------------------------------- #
+
+def _engine(family="dense", **over):
+    cfg, mod = (DENSE, llama) if family == "dense" else (ROUTED, moe)
+    kw = dict(
+        model="tiny", max_num_seqs=4, page_size=PAGE, num_pages=128,
+        max_model_len=256, prefill_buckets=(16, 32), max_prefill_chunk=32,
+        decode_block_steps=K, mixed_dispatch=True,
+    )
+    kw.update(over)
+    eng = JaxEngine(EngineConfig(**kw), model_config=cfg,
+                    params=mod.init_params(cfg, jax.random.PRNGKey(0)))
+    # one table width, as under the Pallas ragged kernel: the first mixed
+    # step then primes one family (tests/test_mixed_fusion.py:_one_width)
+    eng._mixed_table_rungs = (eng.config.max_pages_per_seq,)
+    eng.dispatched = []  # (kind, work) of every entry, in order
+    stamp = eng._rec.dispatched
+
+    def logged(entry, kind, work):
+        eng.dispatched.append((kind, work))
+        return stamp(entry, kind, work)
+
+    eng._rec.dispatched = logged
+    return eng
+
+
+async def _stream(eng, prompt, rid, n):
+    req = PreprocessedRequest(
+        token_ids=list(prompt),
+        stop_conditions={"max_tokens": n, "ignore_eos": True},
+        sampling_options={"temperature": 0.0}, request_id=rid,
+    ).to_dict()
+    return [t async for out in eng.generate(req, Context())
+            for t in (out.get("data") or {}).get("token_ids", [])]
+
+
+async def _settled(eng):
+    """Close the engine once its loop has fetched what it dispatched (a
+    block queued behind a request's last one outlives the stream)."""
+    for _ in range(500):
+        if not (eng._inflight or eng._pending_prefill):
+            break
+        await asyncio.sleep(0.01)
+    await eng.close()
+
+
+class _Stepped:
+    """The test makes every `_step_once` itself, so what is in flight when
+    an arrival lands is known."""
+
+    def __init__(self, eng):
+        self.eng = eng
+
+    async def __aenter__(self):
+        # generate() starts the step loop unless a task is there already
+        self.eng._step_task = asyncio.create_task(asyncio.sleep(3600))
+        return self
+
+    async def __aexit__(self, *exc):
+        await self.eng.close()
+
+    async def submit(self, prompt, rid, n):
+        task = asyncio.create_task(_stream(self.eng, prompt, rid, n))
+        for _ in range(50):
+            if any(s.request_id == rid for s in self.eng._waiting):
+                return task
+            await asyncio.sleep(0)
+        raise AssertionError(f"{rid} never reached the waiting list")
+
+    async def until(self, cond, limit=400):
+        for _ in range(limit):
+            if cond():
+                return
+            await self.eng._step_once()
+            await asyncio.sleep(0)
+        raise AssertionError("the engine never got there")
+
+
+def _prompt(n, seed):
+    return np.random.RandomState(seed).randint(5, 200, size=n).tolist()
+
+
+def _by_hand(family, entries):
+    """The formula of models/<family>.step_work for a sequence of entries
+    given as (real, context, passes, sampled, kv_tokens)."""
+    cfg, mod = (DENSE, llama) if family == "dense" else (ROUTED, moe)
+    flops = nbytes = 0
+    for real, ctx, passes, sampled, kv_tokens in entries:
+        f, b = mod.step_work(cfg, real, ctx, passes, sampled=sampled,
+                             kv_tokens=kv_tokens)
+        flops, nbytes = flops + f, nbytes + b
+    return flops, nbytes
+
+
+@pytest.mark.parametrize("family", ["dense", "routed"])
+def test_a_prefill_two_blocks_and_a_mixed_step_by_hand(family):
+    """Request a (20 tokens, 9 to make) alone: a split prefill of one chunk,
+    then two blocks of K = 4 that its 8 tokens left fill exactly. Request b
+    (12 tokens, 1 to make) beside c's decode lane: one mixed step."""
+    eng = _engine(family)
+
+    async def run():
+        async with _Stepped(eng) as st:
+            a = await st.submit(_prompt(20, 1), "a", 9)
+            await st.until(a.done)
+            kinds = [k for k, _ in eng.dispatched]
+            assert kinds[:3] == ["prefill", "block", "block"], kinds
+            # a block queued after a's last useful one asks for nothing
+            assert all(w == (0, 0) for _, w in eng.dispatched[3:])
+            n_a = len(eng.dispatched)
+            c = await st.submit(_prompt(10, 2), "c", 40)
+            await st.until(lambda: any(
+                s is not None and s.request_id == "c" and s.generated > 0
+                for s in eng.slots))
+            await st.until(lambda: not eng._inflight)
+            lane = next(s for s in eng.slots if s is not None)
+            seq_len = int(eng.seq_lens[lane.slot_idx])
+            n_c = len(eng.dispatched)
+            b = await st.submit(_prompt(12, 3), "b", 1)
+            await st.until(b.done)
+            mixed = [w for k, w in eng.dispatched[n_c:] if k == "mixed"]
+            assert len(mixed) == 1
+            c.cancel()
+            return n_a, mixed[0], seq_len
+
+    n_a, mixed, seq_len = asyncio.run(run())
+    want = _by_hand(family, [
+        (20, 20 * 21 // 2, 1, 1, 20),  # the prompt: token j attends j + 1
+        (4, 4 * 21 + 6, 4, None, None),  # tokens 2 to 5, from a context of 21
+        (4, 4 * 25 + 6, 4, None, None),  # tokens 6 to 9, queued behind it
+    ])
+    got = tuple(map(sum, zip(*(w for _, w in eng.dispatched[:n_a]))))
+    assert got == want
+    # b's whole prompt and c's one decode row at its context, in one pass
+    assert mixed == _by_hand(family, [
+        (12 + 1, 12 * 13 // 2 + seq_len, 1, 2, 12 + seq_len)])
+    out = eng.stats()
+    assert out["step_model_flops"] == sum(w[0] for _, w in eng.dispatched)
+    assert out["step_min_bytes"] == sum(w[1] for _, w in eng.dispatched)
+
+
+def test_served_requests_leave_the_counters_whole():
+    """A handful of requests: every entry dispatched is counted once it is
+    fetched, every request admitted once and given a first token once, both
+    waits non-negative, and nothing runs backwards between two stats()."""
+    eng = _engine("dense")
+
+    async def run():
+        first = eng.stats()
+        outs = await asyncio.gather(*(
+            _stream(eng, _prompt(9 + 5 * i, 10 + i), f"r{i}", 6 + i)
+            for i in range(5)))
+        mid = eng.stats()
+        await _stream(eng, _prompt(11, 99), "last", 5)
+        await _settled(eng)
+        return first, mid, eng.stats(), outs
+
+    first, mid, last, outs = asyncio.run(run())
+    assert [len(o) for o in outs] == [6 + i for i in range(5)]
+    # (the entries that met a compile are counted as stalled, not as steps)
+    assert sum(last[f"step_{k}_count"] for k in STEP_KINDS) \
+        + last["step_stalled_count"] == len(eng.dispatched)
+    assert last["step_stalled_count"] > 0 and last["step_stalled_s"] > 0.5
+    assert last["req_admitted"] == last["req_first_tokens"] == 6
+    assert mid["req_admitted"] == 5
+    assert last["req_queue_wait_s"] >= 0 and last["req_admit_to_first_s"] > 0
+    keys = ["engine_clock_s", "step_model_flops", "step_min_bytes",
+            "step_stalled_count", "step_stalled_s",
+            *(f"phase_{p}_{x}" for p in PHASES for x in ("count", "s", "slow")),
+            *(f"step_{k}_{x}" for k in STEP_KINDS for x in ("count", "interval_s"))]
+    for key in keys:
+        assert first[key] <= mid[key] <= last[key], key
+    assert mid["engine_clock_s"] > first["engine_clock_s"]
+    for p in ("admit", "pack", "put", "launch", "fetch", "emit"):
+        assert last[f"phase_{p}_count"] > 0 and last[f"phase_{p}_s"] > 0, p
+    # nothing counted twice: the phases fit into the clock that held them
+    phases = sum(last[f"phase_{p}_s"] - first[f"phase_{p}_s"] for p in PHASES)
+    assert phases <= last["engine_clock_s"] - first["engine_clock_s"]
+    # _timed's table moved into the recorder and reads as before
+    assert last["dispatch_fetch_count"] == last["phase_fetch_count"]
+    assert last["dispatch_block_count"] >= last["step_block_count"] > 0
+    for gone in ("mixed_padding_frac", "split_padding_frac", "sched_last_decision"):
+        assert gone not in last and gone not in METRICS
+    assert last["split_real_tokens"] <= last["split_padded_tokens"]
+
+
+def test_a_preempted_resume_is_not_admitted_twice():
+    """Two requests whose contexts outgrow the pool: one is preempted and
+    resumes. It keeps its first admission and its first token."""
+    eng = _engine("dense", num_pages=10, max_num_seqs=2,
+                  enable_prefix_caching=False)
+    preempted = []
+    preempt = eng._preempt_one
+
+    def logged(exclude_idx):
+        done = preempt(exclude_idx)
+        preempted.append(done)
+        return done
+
+    eng._preempt_one = logged
+
+    async def run():
+        outs = await asyncio.gather(
+            _stream(eng, _prompt(20, 1), "p0", 36),
+            _stream(eng, _prompt(20, 2), "p1", 36))
+        await _settled(eng)
+        return outs
+
+    outs = asyncio.run(run())
+    assert [len(o) for o in outs] == [36, 36]
+    assert any(preempted), "the pool was to run out: make the case tighter"
+    out = eng.stats()
+    assert out["req_admitted"] == out["req_first_tokens"] == 2
+
+
+def test_every_stats_key_of_the_recorder_is_registered():
+    out = _engine("dense")._rec.stats()
+    missing = [k for k in out if k not in METRICS and not k.startswith("dispatch_")]
+    assert missing == []
+    for key in out:
+        if key in METRICS:
+            assert METRICS[key]["kind"] == "counter" and METRICS[key]["export"]
+    with open(os.path.join(ROOT, "docs", "observability.md")) as f:
+        doc = f.read()
+    assert all(f"`{k}`" in doc for k in out if k in METRICS)
+
+
+def test_the_spans_lie_in_the_profilers_own_trace(tmp_path):
+    """A profiler session on the CPU around a few iterations: the recorder's
+    spans are events of the host plane, on the profiler's clock."""
+    from jax.profiler import ProfileData
+
+    eng = _engine("dense")
+
+    async def run():
+        await _stream(eng, _prompt(12, 5), "warm", 6)  # compile outside it
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            await asyncio.gather(_stream(eng, _prompt(12, 6), "t0", 9),
+                                 _stream(eng, _prompt(17, 7), "t1", 9))
+        finally:
+            jax.profiler.stop_trace()
+        await eng.close()
+
+    asyncio.run(run())
+    found = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                      recursive=True)
+    assert found
+    pd = ProfileData.from_file(found[-1])
+    seen = {}
+    for plane in pd.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("engine."):
+                    seen.setdefault(ev.name, []).append(
+                        (line.name, ev.start_ns, ev.duration_ns))
+    for name in ("engine.pack", "engine.put", "engine.launch", "engine.fetch",
+                 "engine.emit"):
+        assert name in seen, sorted(seen)
+        assert all(d > 0 for _, _, d in seen[name])
+    # spans of one thread do not nest: the device thread's put and launch,
+    # the loop's pack and emit
+    for names in (("engine.put", "engine.launch"), ("engine.pack", "engine.emit")):
+        spans = sorted((s, s + d) for n in names for _, s, d in seen[n])
+        assert all(a[1] <= b[0] for a, b in zip(spans, spans[1:])), names
+
+
+# -- the benchmark's readers ---------------------------------------------- #
+
+def _layer_metrics():
+    sys.path.insert(0, os.path.join(ROOT, "benchmark"))
+    try:
+        spec = importlib.util.spec_from_file_location(
+            "bench_layer_metrics", os.path.join(ROOT, "benchmark", "layer_metrics.py"))
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod
+    finally:
+        sys.path.pop(0)
+
+
+S0 = {"engine_clock_s": 100.0, "phase_admit_s": 0.5, "phase_pack_s": 1.0,
+      "phase_put_s": 1.0, "phase_launch_s": 0.5, "phase_emit_s": 1.0,
+      "phase_fetch_s": 30.0, "phase_wait_s": 2.0,
+      "phase_pack_count": 100, "phase_put_count": 200, "phase_emit_count": 100,
+      "step_block_count": 10, "step_block_interval_s": 1.0,
+      "step_mixed_count": 5, "step_mixed_interval_s": 0.1,
+      "step_model_flops": 1e12, "step_min_bytes": 1e12,
+      "req_admitted": 10, "req_queue_wait_s": 0.2,
+      "req_first_tokens": 9, "req_admit_to_first_s": 0.5,
+      **{f"phase_{p}_slow": 0 for p in PHASES}}
+S1 = {"engine_clock_s": 150.0, "phase_admit_s": 1.5, "phase_pack_s": 4.0,
+      "phase_put_s": 3.0, "phase_launch_s": 1.5, "phase_emit_s": 3.0,
+      "phase_fetch_s": 65.0, "phase_wait_s": 4.0,
+      "phase_pack_count": 600, "phase_put_count": 1200, "phase_emit_count": 500,
+      "step_block_count": 410, "step_block_interval_s": 41.4,
+      "step_mixed_count": 205, "step_mixed_interval_s": 3.3,
+      "step_model_flops": 1e12 + 0.12 * 50 * 197e12,
+      "step_min_bytes": 1e12 + 0.75 * 50 * 819e9,
+      "req_admitted": 210, "req_queue_wait_s": 6.2,
+      "req_first_tokens": 209, "req_admit_to_first_s": 14.5,
+      **{f"phase_{p}_slow": 0 for p in PHASES},
+      "phase_fetch_slow": 2, "phase_put_slow": 1, "phase_wait_slow": 7}
+TPU = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+WANT = {
+    "engine.host_busy_share": 100 * (1.0 + 3.0 + 2.0 + 1.0 + 2.0) / 50,
+    "engine.pack_ms": 1000 * 3.0 / 500,
+    "engine.put_ms": 1000 * 2.0 / 1000,
+    "engine.emit_ms": 1000 * 2.0 / 400,
+    "engine.slow_spans": 3.0,
+    "step.decode_block_ms": 1000 * 40.4 / 400,
+    "step.mixed_ms": 1000 * 3.2 / 200,
+    "step_mfu": 12.0,
+    "step.hbm_roofline_share": 75.0,
+    "sched.queue_wait_ms": 1000 * 6.0 / 200,
+    "engine.admit_to_first_token_ms": 1000 * 14.0 / 200,
+}
+
+
+@pytest.mark.parametrize("name", sorted(WANT))
+def test_a_new_layer_metric_reads_the_tables_arithmetic(name):
+    lm = _layer_metrics()
+    ctx = {"stats0": S0, "stats1": S1, "stats2": S1, "trace": None,
+           "seconds": 50.0, "device": TPU, "client": {}, "end_to_end": {}}
+    assert lm.read(name, ctx) == pytest.approx(WANT[name], rel=1e-9)
+    # a program without the spans and counters (the parent of the PR that
+    # brought them; benchmark/selftest.py's context, which has no `device`):
+    # nothing to read, and nothing raised
+    old = {"mixed_steps": 4, "emit_tokens": 9, "compiled_variants": 38}
+    assert lm.read(name, {"stats0": old, "stats1": old, "stats2": old,
+                          "trace": None, "seconds": 50.0}) is None
+    assert lm.read(name, dict(ctx, stats0=old, stats1=old, stats2=old)) is None
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        entry = next(m for m in json.load(f)["per_layer"] if m["name"] == name)
+    spec = lm.load(name)
+    assert {k: entry[k] for k in ("unit", "better", "source", "layer", "moves")} \
+        == {k: spec[k] for k in ("unit", "better", "source", "layer", "moves")}
+    assert entry["workloads"]
+
+
+def test_a_share_of_a_peak_needs_a_chip_the_table_knows():
+    lm = _layer_metrics()
+    ctx = {"stats0": S0, "stats1": S1, "stats2": S1, "trace": None}
+    for name in ("step_mfu", "step.hbm_roofline_share"):
+        # a CPU run has no share of a chip's peak
+        cpu = {"platform": "cpu", "kind": "cpu", "count": 1}
+        assert lm.read(name, dict(ctx, device=cpu)) is None
+        with pytest.raises(KeyError):  # an error, not a default
+            lm.read(name, dict(ctx, device=dict(TPU, kind="TPU v9 mega")))
+    with open(os.path.join(ROOT, "benchmark", "peaks.json")) as f:
+        peaks = json.load(f)
+    assert peaks["TPU v5 lite"]["bf16_flops"] == 197e12
+    assert peaks["TPU v5 lite"]["hbm_bytes_s"] == 819e9
