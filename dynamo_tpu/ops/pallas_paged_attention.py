@@ -2,7 +2,7 @@
 
 Role of the reference's paged-attention CUDA kernels (inside vLLM) and of
 `block_copy.cu` (lib/llm/src/kernels/block_copy.cu:41) — done the TPU way:
-the KV cache stays in HBM, each grid step streams ONE slot's pages through
+the KV cache stays in HBM, each grid step streams ONE lane's pages through
 a double-buffered VMEM window with async DMA, and a flash-style running
 softmax accumulates the output. This avoids the XLA fallback's materialized
 [B, S, KH, D] gather (which costs an extra HBM round-trip for the whole
@@ -16,14 +16,35 @@ Layouts (match ops/paged_attention.py and engine/kv_cache.py):
     seq_lens:    [B] int32             (valid positions incl. current token)
 
 Design notes:
-  * grid = (B,); the layer index and page_tables/seq_lens ride
-    scalar-prefetch (SMEM) so DMA source indices (`pool[li, page]`) are
-    known ahead of the body. No per-layer slice or reshape of the pool
-    exists outside the kernel: on the TPU either is a pool-sized copy.
-  * pages are streamed in chunks of CHUNK = max(128, page_size) positions so
-    the score lane dimension is a full 128-lane register tile.
-  * physical page ids are clamped to the valid range: tail chunks may DMA a
-    garbage page, but masking (additive NEG) keeps them out of the softmax.
+  * grid = (B,), SEQUENTIAL ("arbitrary"): the window, its semaphores and
+    the slot counter live across grid steps. The layer index and
+    page_tables/seq_lens ride scalar-prefetch (SMEM) so DMA source indices
+    (`pool[li, page]`) are known ahead of the body. No per-layer slice or
+    reshape of the pool exists outside the kernel: on the TPU either is a
+    pool-sized copy.
+  * the page stream follows the lane's length: page `lp` of lane `b` is
+    copied only where `lp * page_size < seq_lens[b]`, and a copy is waited
+    for under the same predicate, so every semaphore waited on was
+    signalled. An empty lane (`seq_lens[b]` 0) copies nothing, multiplies
+    nothing and returns zeros. No page id past a lane's length is read:
+    the clamp to the pool's last page guards a copied page alone.
+  * the stream runs one chunk ahead ACROSS lanes: the copy of chunk ci + 1
+    is started before chunk ci is waited for, and where ci is the lane's
+    last chunk the copy started is chunk 0 of lane b + 1, into the buffer
+    slot this lane is not computing from. Grid step 0 starts its own first
+    chunk and the last step starts none. `slot_ref` (SMEM) carries the slot
+    of a lane's first chunk from one grid step to the next (the schedule of
+    jax.experimental.pallas.ops.tpu.paged_attention).
+  * pages are streamed in chunks of `_chunk_positions` positions (512 at
+    KH*D of 1,024): the grain at which copy and multiply alternate; most
+    lanes of a few hundred positions are one chunk, and overlap with their
+    neighbours.
+  * rows of the window that no copy of this lane wrote hold what an earlier
+    lane left there. Their scores are masked (a select, so whatever K holds
+    is dropped) and their `p` is an exact 0, but 0 x NaN is not 0: the V
+    window is ZEROED ONCE, at grid step 0, and from then on holds only what
+    was copied from pages a table names below its lane's length, which the
+    engine wrote and are finite.
   * all softmax state is f32; QK^T and PV ride the MXU in bf16 with f32
     accumulation (preferred_element_type).
 """
@@ -78,8 +99,8 @@ def _decode_kernel(
     # K/V scales [num_pages, KH] f32 when kv_bits > 0), then q [1, H, D]
     # VMEM, kv_k/kv_v [L, num_pages, rows, KH*D] ANY/HBM (the whole pool;
     # rows = page_size, or page_size//2 int4-packed along the sublane
-    # axis), the out block, and the double-buffered VMEM window + DMA
-    # semaphores.
+    # axis), the out block, the double-buffered VMEM window + DMA
+    # semaphores, and the SMEM slot counter.
     *refs,
     page_size: int,
     chunk_pages: int,
@@ -91,55 +112,72 @@ def _decode_kernel(
 ):
     if kv_bits:
         (li_ref, pt_ref, sl_ref, ks_ref, vs_ref, q_ref, kv_k_hbm, kv_v_hbm,
-         out_ref, k_buf, v_buf, k_sem, v_sem) = refs
+         out_ref, k_buf, v_buf, k_sem, v_sem, slot_ref) = refs
     else:
         (li_ref, pt_ref, sl_ref, q_ref, kv_k_hbm, kv_v_hbm,
-         out_ref, k_buf, v_buf, k_sem, v_sem) = refs
+         out_ref, k_buf, v_buf, k_sem, v_sem, slot_ref) = refs
         ks_ref = vs_ref = None
     b = pl.program_id(0)
+    num_lanes = pl.num_programs(0)
     li = li_ref[0]
     chunk = chunk_pages * page_size
     num_phys = kv_k_hbm.shape[1]
     page_rows = kv_k_hbm.shape[2]
     kh, g, d = num_kv_heads, num_heads // num_kv_heads, head_dim
 
-    seq_len = jnp.maximum(sl_ref[b], 1)  # empty slots behave as len-1
+    seq_len = sl_ref[b]
     n_chunks = pl.cdiv(seq_len, chunk)
-    max_chunks = pl.cdiv(max_pages, chunk_pages)
 
-    def start_chunk(ci, slot):
-        """Kick off DMAs for all pages of chunk ci into buffer `slot`."""
+    def chunk_copies(lane, ci, slot, wait=False):
+        """(predicate, K copy, V copy) of each page of chunk ci of `lane`
+        into buffer `slot`; a page is copied where it holds a position. A
+        wait takes its byte count from the window's rows, so it names no
+        page."""
+        lane_len = sl_ref[lane]
         for p in range(chunk_pages):
             lp = ci * chunk_pages + p
-            lp_safe = jnp.minimum(lp, max_pages - 1)
-            phys = jnp.minimum(pt_ref[b, lp_safe], num_phys - 1)
-            pltpu.make_async_copy(
-                kv_k_hbm.at[li, phys],
-                k_buf.at[slot, pl.ds(p * page_rows, page_rows)],
-                k_sem.at[slot, p],
-            ).start()
-            pltpu.make_async_copy(
-                kv_v_hbm.at[li, phys],
-                v_buf.at[slot, pl.ds(p * page_rows, page_rows)],
-                v_sem.at[slot, p],
-            ).start()
+            # lp < max_pages wherever the predicate holds
+            phys = 0 if wait else jnp.minimum(
+                pt_ref[lane, jnp.minimum(lp, max_pages - 1)], num_phys - 1
+            )
+            rows = pl.ds(p * page_rows, page_rows)
+            yield (
+                lp * page_size < lane_len,
+                pltpu.make_async_copy(
+                    kv_k_hbm.at[li, phys], k_buf.at[slot, rows], k_sem.at[slot, p]
+                ),
+                pltpu.make_async_copy(
+                    kv_v_hbm.at[li, phys], v_buf.at[slot, rows], v_sem.at[slot, p]
+                ),
+            )
 
-    def wait_chunk(ci, slot):
-        for p in range(chunk_pages):
-            lp_safe = jnp.minimum(ci * chunk_pages + p, max_pages - 1)
-            phys = jnp.minimum(pt_ref[b, lp_safe], num_phys - 1)
-            pltpu.make_async_copy(
-                kv_k_hbm.at[li, phys],
-                k_buf.at[slot, pl.ds(p * page_rows, page_rows)],
-                k_sem.at[slot, p],
-            ).wait()
-            pltpu.make_async_copy(
-                kv_v_hbm.at[li, phys],
-                v_buf.at[slot, pl.ds(p * page_rows, page_rows)],
-                v_sem.at[slot, p],
-            ).wait()
+    def start_chunk(lane, ci, slot):
+        for holds, k_copy, v_copy in chunk_copies(lane, ci, slot):
+            @pl.when(holds)
+            def _():
+                k_copy.start()
+                v_copy.start()
 
-    start_chunk(0, 0)
+    def wait_chunk(lane, ci, slot):
+        for holds, k_copy, v_copy in chunk_copies(lane, ci, slot, wait=True):
+            @pl.when(holds)
+            def _():
+                k_copy.wait()
+                v_copy.wait()
+
+    @pl.when(b == 0)
+    def _():
+        # what no copy wrote must stay finite under p == 0 (module docstring)
+        v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
+        slot_ref[0] = 0
+        start_chunk(0, 0, 0)
+
+    slot0 = slot_ref[0]  # where this lane's first chunk is, or is arriving
+    succ = jnp.minimum(b + 1, num_lanes - 1)
+
+    @pl.when((n_chunks == 0) & (b + 1 < num_lanes))
+    def _():
+        start_chunk(succ, 0, slot0)  # an empty lane hands the slot on
 
     # GQA as ONE matmul pair per chunk: q arrives pre-packed block-diagonal
     # [KH*G, KH*D] (head h's G queries in column block h, built by XLA in
@@ -155,13 +193,18 @@ def _decode_kernel(
 
     def body(ci, carry):
         m, l, acc = carry
-        slot = jax.lax.rem(ci, 2)
+        slot = jax.lax.rem(slot0 + ci, 2)
 
-        @pl.when(ci + 1 < n_chunks)
+        # one chunk ahead: this lane's next chunk, or the next lane's first
+        last = ci + 1 == n_chunks
+
+        @pl.when(jnp.logical_not(last) | (b + 1 < num_lanes))
         def _():
-            start_chunk(ci + 1, jax.lax.rem(ci + 1, 2))
+            start_chunk(
+                jnp.where(last, succ, b), jnp.where(last, 0, ci + 1), 1 - slot
+            )
 
-        wait_chunk(ci, slot)
+        wait_chunk(b, ci, slot)
         if kv_bits:
             k, v = _window_dequant(
                 b, ci, slot, k_buf, v_buf, pt_ref, ks_ref, vs_ref,
@@ -196,6 +239,7 @@ def _decode_kernel(
         return m_n, l_n, acc * alpha + pv_all
 
     m, l, acc = jax.lax.fori_loop(0, n_chunks, body, (m0, l0, acc0))
+    slot_ref[0] = jax.lax.rem(slot0 + n_chunks, 2)  # the successor's first
     # extract head h's D-block from row block h of acc: static slices per kv
     # head (no [HG,KH*D]->[HG,KH,D] reshape — unsupported Mosaic shape cast)
     row_head = jax.lax.broadcasted_iota(jnp.int32, (hg, 1), 0) // g
@@ -220,6 +264,21 @@ def _block_diagonal_q(q: jax.Array, num_kv_heads: int) -> jax.Array:
     return jnp.where(lane_head == row_head, tiled, jnp.zeros((), q.dtype))
 
 
+def _chunk_positions(page_size: int, kv_width: int) -> int:
+    """Positions a chunk of the window holds, from the page's size and
+    KH*D alone: 512 where the window (K and V, two slots each) stays within
+    4 MiB of VMEM, which is KH*D up to 1,024 in bf16; half of that for each
+    doubling of the width, never under 128 (a full lane register of scores)
+    nor under a page. Read on a v5e at Mistral-7B's widths, 32 lanes of 146
+    to 794 positions, 16 layers a step (PERF.md, PR 42): 1.42 ms at 128,
+    1.30 at 256, 1.22 at 512, 1.32 and more at 1,024. The copies follow the
+    length whatever the chunk, and at 512 they run at 600 GB/s, which is
+    what this chip's HBM gives a plain pass; a smaller chunk pays 0.17 us
+    an iteration more often, a larger one multiplies 1,024 masked positions
+    for a lane of 300."""
+    return max(min(512, max(128, (1 << 19) // kv_width)), page_size)
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention_decode_pallas(
     q: jax.Array,  # [B, H, D]
@@ -239,11 +298,7 @@ def paged_attention_decode_pallas(
         kernel_operands(kv_k_layer, kv_v_layer, D)
     )
     max_pages = page_tables.shape[1]
-    # chunk target: big enough to amortize per-iteration overhead, small
-    # enough that 2 double-buffered K+V chunks fit comfortably in VMEM
-    target = 512 if KH * D * page_size <= 131072 else 256
-    chunk_pages = max(1, target // page_size)
-    chunk_pages = min(chunk_pages, max_pages)
+    chunk_pages = min(_chunk_positions(page_size, KH * D) // page_size, max_pages)
 
     KHG = KH * (H // KH)
     q_bd = _block_diagonal_q(q, KH)
@@ -269,6 +324,7 @@ def paged_attention_decode_pallas(
             pltpu.VMEM((2, chunk_pages * rows, KH * D), kv_v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, chunk_pages)),
             pltpu.SemaphoreType.DMA((2, chunk_pages)),
+            pltpu.SMEM((1,), jnp.int32),
         ],
     )
     kernel = functools.partial(
@@ -281,15 +337,22 @@ def paged_attention_decode_pallas(
         head_dim=D,
         kv_bits=kv_bits,
     )
+    # What XLA's scheduler takes the custom call to cost when it orders the
+    # step around it. The kernel reads what the lanes hold, which only the
+    # run knows: reckon with tables half full (max_pages is the bucket the
+    # longest lane needs, so most lanes hold less).
+    ctx = B * max_pages * page_size // 2
     cost = pl.CostEstimate(
-        flops=4 * B * H * D * max_pages * page_size,
-        bytes_accessed=2 * B * max_pages * page_size * KH * D * 2,
-        transcendentals=B * H * max_pages * page_size,
+        flops=4 * H * KH * D * ctx,  # the block-diagonal pair: [H, KH*D] wide
+        bytes_accessed=2 * ctx * KH * D * kv_k_pool.dtype.itemsize
+        + B * H * (KH + 1) * D * q.dtype.itemsize,
+        transcendentals=H * ctx,
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         cost_estimate=cost,
         interpret=interpret,
     )(*prefetch, q_bd, kv_k_pool, kv_v_pool)

@@ -141,6 +141,81 @@ def test_pallas_bf16_gqa():
 
 
 # --------------------------------------------------------------------- #
+# the page stream (PR 42): copies follow the lane's length, and a lane's
+# first chunk is started by the lane before it
+# --------------------------------------------------------------------- #
+
+# lane lengths of a batch, from the page size and the chunk's positions
+_STREAM_CASES = {
+    "empty": lambda ps, c: [0],
+    "one-position": lambda ps, c: [1],
+    "one-page": lambda ps, c: [ps],
+    "one-page-and-one": lambda ps, c: [ps + 1],
+    "one-chunk": lambda ps, c: [c],
+    "one-chunk-and-one": lambda ps, c: [c + 1],
+    "three-chunks": lambda ps, c: [3 * c],
+    "long-before-short": lambda ps, c: [2 * c + ps + 3, 5, c + 9],
+    "empty-between-live": lambda ps, c: [ps + 7, 0, c - 3],
+    "empty-first-and-last": lambda ps, c: [0, c + 2, 0, 0],
+    "every-edge": lambda ps, c: [0, 1, ps, ps + 1, c, c + 1, 3 * c, 0, 2],
+}
+
+
+@pytest.mark.parametrize("heads", [(4, 2), (24, 8)], ids=["G2", "G3"])
+@pytest.mark.parametrize("case", list(_STREAM_CASES))
+def test_decode_streams_only_the_pages_a_lane_holds(case, heads):
+    """Every pool page that no lane's first ceil(len / page_size) table
+    entries name is NaN, and the table's entries past a lane's length
+    point at such pages: the kernel copies none of them, nothing it did
+    not copy reaches the sums, and live lanes agree with the XLA path
+    (which gathers the whole table, so it is given the pool with zeros
+    where the NaN is). An empty lane returns zeros."""
+    from dynamo_tpu.ops.pallas_paged_attention import _chunk_positions
+
+    H, KH = heads
+    D, ps = 16, 64
+    chunk = _chunk_positions(ps, KH * D)
+    lens = _STREAM_CASES[case](ps, chunk)
+    B = len(lens)
+    held = [-(-n // ps) for n in lens]
+    max_pages = max(max(held) + 2, chunk // ps + 1)  # never clamps the chunk
+    poison = 3
+    pages = sum(held) + poison
+    rng = np.random.RandomState(len(case) + H)
+    order = rng.permutation(pages)
+    bad, good = order[:poison], list(order[poison:])
+    pt = np.empty((B, max_pages), np.int32)
+    for b in range(B):
+        pt[b] = bad[rng.randint(0, poison, size=max_pages)]
+        for lp in range(held[b]):
+            pt[b, lp] = good.pop()
+    clean_k = rng.randn(pages, ps, KH, D).astype(np.float32)
+    clean_v = rng.randn(pages, ps, KH, D).astype(np.float32)
+    clean_k[bad] = 0.0
+    clean_v[bad] = 0.0
+    nan_k, nan_v = clean_k.copy(), clean_v.copy()
+    nan_k[bad] = np.nan
+    nan_v[bad] = np.nan
+    q = jnp.asarray(rng.randn(B, H, D), jnp.float32)
+    pt, seq_lens = jnp.asarray(pt), jnp.asarray(lens, jnp.int32)
+
+    want = _xla(
+        ref_ops.paged_attention_decode, q, L(jnp.asarray(clean_k)),
+        L(jnp.asarray(clean_v)), pt, seq_lens,
+    )
+    got = np.asarray(paged_attention_decode_pallas(
+        q, L(jnp.asarray(nan_k)), L(jnp.asarray(nan_v)), pt, seq_lens,
+        interpret=True,
+    ))
+    assert np.isfinite(got).all()
+    live = np.asarray(lens) > 0
+    np.testing.assert_allclose(
+        got[live], np.asarray(want)[live], rtol=2e-3, atol=2e-3
+    )
+    assert not got[~live].any()
+
+
+# --------------------------------------------------------------------- #
 # whole pool + layer index (PR 26): the kernels are handed the pool as it
 # lies in HBM, [L, pages, rows, KH*D], and DMA pool[li, page]
 # --------------------------------------------------------------------- #

@@ -28,8 +28,16 @@ from dynamo_tpu.ops.kv_quant import QuantKV, alloc_kv_store, kv_layer
 H, KH, D = 24, 8, 128
 PAGE, B, TABLE, POOL, LAYERS = 64, 64, 128, 1024, 28
 HBM_BYTES = 16 * 2**30  # one v5e chip
-# the benchmark's cell: Mixtral-8x7B widths, 2 layers, --num-pages 8192
+# the benchmark's cells: Mixtral-8x7B widths, 2 layers, --num-pages 8192
+# (at this file's lanes and table), and Mistral-7B widths, 16 layers, the
+# auto pool's 1,377 pages and a spare, at the cell's own 32 lanes of at most
+# 4,096 positions: 64 pages, the width of the table the decode block carries
+# (max_pages_per_seq) and the mixed step's one width
 MIXTRAL = {"H": 32, "KH": 8, "layers": 2, "pool": 8193}
+CELLS = {
+    "mixtral": MIXTRAL,
+    "mistral": {"H": 32, "KH": 8, "layers": 16, "pool": 1378, "B": 32, "table": 64},
+}
 
 
 @pytest.fixture(scope="module")
@@ -100,14 +108,14 @@ def _pool(sds, mode="none", layers=LAYERS, pool=POOL, kh=KH):
     )
 
 
-def _op_cases(sds, mode, H=H, KH=KH, layers=LAYERS, pool=POOL):
+def _op_cases(sds, mode, H=H, KH=KH, layers=LAYERS, pool=POOL, B=B, table=TABLE):
     """(name, fn, args) for the three serving attention ops through the
     dispatch gate, with a `mode` KV pool."""
     k = _pool(sds, mode, layers, pool, KH)
     v = _pool(sds, mode, layers, pool, KH)
     i32 = jnp.int32
     q1 = sds((B, H, D), jnp.bfloat16)
-    tables = sds((B, TABLE), i32)
+    tables = sds((B, table), i32)
     lens = sds((B,), i32)
     T = 128
     Bp = 8
@@ -118,11 +126,11 @@ def _op_cases(sds, mode, H=H, KH=KH, layers=LAYERS, pool=POOL):
         "prefill_batched": (
             ops.prefill_attention_batched,
             (sds((Bp, T, H, D), jnp.bfloat16), k, v, sds((Bp, T), i32),
-             sds((Bp, TABLE), i32), sds((Bp,), i32), sds((Bp,), i32)),
+             sds((Bp, table), i32), sds((Bp,), i32), sds((Bp,), i32)),
         ),
         "ragged": (
             ops.ragged_attention,
-            (sds((N, H, D), jnp.bfloat16), k, v, sds((R, TABLE), i32),
+            (sds((N, H, D), jnp.bfloat16), k, v, sds((R, table), i32),
              sds((R,), i32), sds((R,), i32), sds((R,), i32)),
         ),
     }
@@ -140,21 +148,21 @@ def test_fp_kernels_compile_for_v5e(op, one_chip, no_persistent_cache, tpu_gate)
     )
 
 
+@pytest.mark.parametrize("cell", list(CELLS))
 @pytest.mark.parametrize("op", OPS)
 def test_whole_pool_kernels_compile_at_the_cell_size(
-    op, one_chip, no_persistent_cache, tpu_gate
+    op, cell, one_chip, no_persistent_cache, tpu_gate
 ):
-    """Mixtral widths, 2 layers, 8,192 pages (4.3 GB of K and V): each
-    kernel takes the whole pool operand, and the program holds no
-    temporary the size of even one layer of one pool (1.07 GB): before
-    PR 26 the wrappers' slice + reshape made three such copies."""
-    fn, args = _op_cases(
-        _shapes(one_chip), "none", H=MIXTRAL["H"], KH=MIXTRAL["KH"],
-        layers=MIXTRAL["layers"], pool=MIXTRAL["pool"],
-    )[op]
+    """A cell's widths, depth, pool and table (Mixtral: 8,192 pages, 4.3 GB
+    of K and V; Mistral: 16 layers of 1,377): each kernel takes the whole
+    pool operand, and the program holds no temporary the size of even one
+    layer of one pool (1.07 GB, 0.18 GB): before PR 26 the wrappers' slice +
+    reshape made three such copies."""
+    at = CELLS[cell]
+    fn, args = _op_cases(_shapes(one_chip), "none", **at)[op]
     compiled = _compile(fn, *args)
     assert "tpu_custom_call" in compiled.as_text()
-    layer_bytes = MIXTRAL["pool"] * PAGE * MIXTRAL["KH"] * D * 2
+    layer_bytes = at["pool"] * PAGE * at["KH"] * D * 2
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < layer_bytes // 8, f"{op}: {temp / 2**20:.0f} MiB of temporaries"
 
