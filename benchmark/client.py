@@ -12,9 +12,11 @@ from __future__ import annotations
 
 import asyncio
 import json
+import math
 import time
 from typing import Callable, List, Optional
 
+from reference import TOP_TOKENS  # (no JAX until it runs)
 from traffic import Request
 
 PAGE_SIZE = 64  # the worker's default --page-size
@@ -242,6 +244,12 @@ class StatsWatch:
 ROUTED_EXPERTS = "routed_experts"  # the annotation asked, and the reply's key
 
 
+def distinct_ids(ids, count: int, under: int) -> bool:
+    """`count` distinct whole numbers in [0, under), as a list."""
+    return (isinstance(ids, list) and len(ids) == count == len(set(ids)) and all(
+        isinstance(i, int) and not isinstance(i, bool) and 0 <= i < under for i in ids))
+
+
 def routed_rows(rows: list, inputs: int, geometry: tuple) -> list:
     """A reply's `routed_experts` held to the wire contract (README.md): one
     row for each input position of prompt + served[:-1], in order (a row for
@@ -260,12 +268,66 @@ def routed_rows(rows: list, inputs: int, geometry: tuple) -> list:
                              f"{len(row) if isinstance(row, list) else row!r} layers, the "
                              f"configuration has {layers} routed ones")
         for layer, ids in enumerate(row):
-            if not (isinstance(ids, list) and len(ids) == per_token == len(set(ids)) and all(
-                    isinstance(e, int) and not isinstance(e, bool) and 0 <= e < experts
-                    for e in ids)):
+            if not distinct_ids(ids, per_token, experts):
                 raise ValueError(f"row {at}, layer {layer} of `{ROUTED_EXPERTS}` is {ids!r}: "
                                  f"not {per_token} distinct expert ids under {experts}")
     return rows
+
+
+TOP_LOGPROBS = "top_logprobs"  # the reply's key, one entry a served token
+
+
+def top_tokens(entries: list, served_ids: list, vocab_size: int) -> tuple:
+    """A reply's `top_logprobs` held to the top-token contract (README.md):
+    one entry for each served token, in order, each {"ids": [...], "logprobs":
+    [...]} of TOP_TOKENS distinct token ids in range, the served token among
+    them, and as many finite log-probabilities. Raises ValueError with the
+    reason; what passes goes to the reference as (ids, log-probabilities), each
+    [served tokens][TOP_TOKENS]."""
+    if len(entries) != len(served_ids):
+        raise ValueError(f"{len(entries)} entries of `{TOP_LOGPROBS}` for "
+                         f"{len(served_ids)} served tokens")
+    for at, (entry, served) in enumerate(zip(entries, served_ids)):
+        ids, lps = ((entry.get("ids"), entry.get("logprobs")) if isinstance(entry, dict)
+                    else (None, None))
+        if not (distinct_ids(ids, TOP_TOKENS, vocab_size) and served in ids):
+            raise ValueError(f"entry {at} of `{TOP_LOGPROBS}` has the ids {ids!r}: not "
+                             f"{TOP_TOKENS} distinct token ids under {vocab_size} with the "
+                             f"served token {served} among them")
+        if not (isinstance(lps, list) and len(lps) == TOP_TOKENS and all(
+                isinstance(v, (int, float)) and not isinstance(v, bool) and math.isfinite(v)
+                for v in lps)):
+            raise ValueError(f"entry {at} of `{TOP_LOGPROBS}` has the log-probabilities "
+                             f"{lps!r}: not {TOP_TOKENS} finite numbers")
+    return ([e["ids"] for e in entries], [e["logprobs"] for e in entries])
+
+
+def resend_body(model: str, pick: dict, forced: bool) -> dict:
+    """The OpenAI body of a re-sent request. A family judged free sends what
+    it always sent (`logprobs` 0: the served token's log-probability alone);
+    one judged forced asks for the TOP_TOKENS tokens a position puts first,
+    over the same option."""
+    return {
+        "model": model, "prompt": pick["prompt"],
+        "max_tokens": pick["max_tokens"], "temperature": 0,
+        "stream": False, "nvext": {"ignore_eos": True},
+        # the served log-probability of each served token rides the
+        # existing logprobs option, and so do a forced family's top tokens
+        "logprobs": TOP_TOKENS if forced else 0,
+    }
+
+
+def resend_wire(pre, model: str, pick: dict, forced: bool) -> tuple:
+    """(the preprocessed request, the dictionary that goes over the request
+    plane): the frontend's own preprocessing of `resend_body`, and for a
+    family judged forced the annotation that asks for the experts chosen."""
+    from dynamo_tpu.llm.protocols import CompletionRequest
+
+    req = pre.preprocess_completion(CompletionRequest(**resend_body(model, pick, forced)))
+    wire = req.to_dict()
+    if forced:
+        wire["annotations"] = [*(wire.get("annotations") or []), ROUTED_EXPERTS]
+    return req, wire
 
 
 async def resend_greedy(discovery_addr: str, model: str, vocab_size: int,
@@ -279,10 +341,11 @@ async def resend_greedy(discovery_addr: str, model: str, vocab_size: int,
     (experts, experts a token, routed layers) of a configuration whose routing
     is judged forced; the worker is then asked for the experts it chose and
     the reply is held to them (`routed_rows`): a reply without them fails the
-    run, it is never judged free."""
+    run, it is never judged free. Such a family is also asked for the tokens
+    each position puts first and held to them (`top_tokens`): never judged
+    over one token instead."""
     from dynamo_tpu.llm.model_card import ModelDeploymentCard
     from dynamo_tpu.llm.preprocessor import OpenAIPreprocessor
-    from dynamo_tpu.llm.protocols import CompletionRequest
     from dynamo_tpu.llm.tokenizers import load_tokenizer
     from dynamo_tpu.runtime import DistributedRuntime, RuntimeConfig
 
@@ -302,19 +365,8 @@ async def resend_greedy(discovery_addr: str, model: str, vocab_size: int,
         (instance,) = await client.wait_for_instances(timeout=30)
 
         async def one(pick: dict) -> dict:
-            body = {
-                "model": model, "prompt": pick["prompt"],
-                "max_tokens": pick["max_tokens"], "temperature": 0,
-                "stream": False, "nvext": {"ignore_eos": True},
-                # the served log-probability of each served token rides the
-                # existing logprobs option
-                "logprobs": 0,
-            }
-            req = pre.preprocess_completion(CompletionRequest(**body))
-            wire = req.to_dict()
-            if routed:
-                wire["annotations"] = [*(wire.get("annotations") or []), ROUTED_EXPERTS]
-            out, lps, rows = [], [], []
+            req, wire = resend_wire(pre, model, pick, bool(routed))
+            out, lps, rows, tops = [], [], [], []
             stream = await client.direct(wire, instance)
             async for item in stream:
                 if item.get("event") == "error":
@@ -323,6 +375,7 @@ async def resend_greedy(discovery_addr: str, model: str, vocab_size: int,
                 out.extend(data.get("token_ids") or [])
                 lps.extend(data.get("log_probs") or [])
                 rows.extend(data.get(ROUTED_EXPERTS) or [])
+                tops.extend(data.get(TOP_LOGPROBS) or [])
             if not len(out) == len(lps) == pick["max_tokens"]:
                 raise fail(
                     f"{pick['why']}: {len(out)} token ids and {len(lps)} "
@@ -332,6 +385,8 @@ async def resend_greedy(discovery_addr: str, model: str, vocab_size: int,
                       "served_logprobs": lps, "why": pick["why"]}
             if routed:
                 try:
+                    served["served_top_ids"], served["served_top_logprobs"] = top_tokens(
+                        tops, out, vocab_size)
                     served[ROUTED_EXPERTS] = routed_rows(
                         rows, len(req.token_ids) + len(out) - 1, routed)
                 except ValueError as e:

@@ -79,6 +79,11 @@ JUDGE_ROUTINGS = ("free", "forced")
 EXPERTS_KEYS = ("num_local_experts", "num_experts", "n_routed_experts")
 PER_TOKEN_KEYS = ("num_experts_per_tok",)
 DENSE_LAYERS_KEYS = ("num_dense_layers", "first_k_dense_replace")
+# where a chip holds a SHARE of every layer's experts (the experts key listed
+# in `reduced`): the id of the first one it holds; it holds [first, first + the
+# key's count) of the router's published width (README.md, "The reference's
+# protocol"); 0 where absent
+FIRST_HELD_KEY = "first_expert_held"
 
 
 def number(x) -> bool:
@@ -86,14 +91,29 @@ def number(x) -> bool:
 
 
 def routed_geometry(name: str, cfg: dict):
-    """(experts, experts a token, routed layers) from the configuration's own
-    keys, or None where it has no experts."""
+    """(the router's width, experts a token, routed layers) from the
+    configuration's own keys, or None where it has no experts. The width is
+    the experts key's count, but where the file lists that key in `reduced`:
+    the key then counts the experts HELD here, a share of every layer's, and
+    the router keeps the PUBLISHED count (`published.<key>`) of outputs, which
+    is what a reply's expert ids lie under and what order statistics are drawn
+    at; the share held is [first, first + held) of them (FIRST_HELD_KEY)."""
     def first(keys, default=None):
         return next((cfg[k] for k in keys if k in cfg), default)
 
-    experts = first(EXPERTS_KEYS)
-    if experts is None:
+    key = next((k for k in EXPERTS_KEYS if k in cfg), None)
+    if key is None:
         return None
+    experts = held = cfg[key]
+    if key in (cfg.get("reduced") or []):
+        experts, at = (cfg.get("published") or {}).get(key), cfg.get(FIRST_HELD_KEY, 0)
+        need(all(isinstance(x, int) and not isinstance(x, bool) for x in (experts, held, at))
+             and 0 <= at and 0 < held and at + held <= experts,
+             name, key, "is listed as reduced: it counts the experts held,", held, "from",
+             FIRST_HELD_KEY, at, ", of the router's published width published." + key, experts)
+    else:
+        need(FIRST_HELD_KEY not in cfg, name, "states", FIRST_HELD_KEY,
+             "and does not list", key, "in `reduced`")
     per_token = first(PER_TOKEN_KEYS)
     layers = cfg.get("num_hidden_layers", 0) - first(DENSE_LAYERS_KEYS, 0)
     need(all(isinstance(x, int) and x > 0 for x in (experts, per_token, layers))

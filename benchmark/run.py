@@ -8,7 +8,8 @@ unchanged `dynamo_tpu.jax_worker` main a configuration read from a file) and
 the OpenAI frontend; waits for ready; warms with the cell's own traffic until
 nothing compiles; measures for `--seconds` from a client over HTTP; re-sends
 four of the window's requests greedily (asking, for a configuration whose
-routing is judged forced, for the experts the worker chose) and checks them
+routing is judged forced, for the experts the worker chose and for the top
+tokens of each position) and checks them
 against the plain reference in a child of its own, after the worker has
 exited. The last line of standard output is the result: one JSON object with
 `correct`, `attempted`, `failed`, `metrics` and `device` (and `breakdown`
@@ -31,7 +32,8 @@ when traced). Earlier lines are JSON objects too, one per phase.
                   any configuration file whose `dataclass` the program can
                   build. Under `judge_routing: "forced"` each control is
                   judged against a float32 pass with its OWN expert choices
-                  forced, as a program is. Prints no result line.
+                  forced, over the top tokens IT puts first, as a program
+                  is. Prints no result line.
 
 One process per chip: this parent never imports JAX. Process supervision is
 copied from chip_smoke.py (PR 21). A run that finds no TPU fails.

@@ -15,7 +15,9 @@ limits to their readings, by planted files; that a configuration with
 limits of its own is a new file alone (a whole `run.py --rehearsal` in a
 tree of links, some 90 s); a family whose routing is judged FORCED: the
 reference under made-up choices, a wrongly routed token, the client's check
-of a reply's `routed_experts`, the rule for its limit, and such a family
+of a reply's `routed_experts` and of its top tokens, the head judged over
+those, a free family's re-sent request as it always was, the rule for its
+limit, a router wider than the experts held, and such a family
 as new files alone; a sizing run's picks, nested past a run's four, and
 what it found at 8 a token; and when a closed loop's request is due. (PR 21 found that
 a wrong page stays under the tolerance at tiny widths on the CPU: that
@@ -488,8 +490,11 @@ def test_files_check_holds_limits_to_their_readings():
     number; a share stated, or held, under what order statistics leave out at
     the geometry of the file's OWN keys, with or without a `judge`. The sound
     file passes. `judge_routing` and a forced family's limit likewise.
-    The fixtures pass as they stand, forced; the first one judged FREE, by
-    the chip's readings at 64 experts and 4 a token (PR 36), is refused."""
+    A router's width is the published count where the experts key is listed
+    as reduced. The fixtures pass as they stand, forced and judged over the
+    top tokens; the second judged over the first token alone (PR 38's
+    readings, kept in the file) and the first judged FREE, by the chip's
+    readings at 64 experts and 4 a token (PR 36), are refused."""
     out = os.path.join(os.path.dirname(HERE), "chiprun_out", "selftest_planted")
     shutil.rmtree(out, ignore_errors=True)
     os.makedirs(out)
@@ -593,26 +598,58 @@ def test_files_check_holds_limits_to_their_readings():
     plant("forced_fewer_control_runs", reading(deficit, "control_int8", runs=6),
           "6 runs of the int8 control for 12 sound ones")
 
+    # a router's width is the PUBLISHED count where the file lists its experts
+    # key in `reduced` (the key then counts the experts held): 8 held of 16
+    # leave out 0.446 at the default epsilon, of 64 0.579, over the share's
+    # limit; held alone (8 / 2 / 2, the Mixtral cell's) they would read 0.321
+    def held_of(width, **more):
+        def spoil(cfg):
+            cfg.pop("judge"), cfg.pop("judge_readings")
+            cfg.update(num_local_experts=8, num_experts_per_tok=2, num_hidden_layers=2,
+                       reduced=["num_local_experts"], published={"num_local_experts": width},
+                       **more)
+        return spoil
+
+    plant("eight_held_of_sixteen", held_of(16))
+    plant("eight_held_of_sixteen_from_the_eighth", held_of(16, first_expert_held=8))
+    plant("eight_held_of_sixty_four", held_of(64), "order statistics leave out 0.57",
+          "at (64, 2, 2) experts")
+    plant("eight_held_of_four", held_of(4), "it counts the experts held")
+    plant("held_past_the_width", held_of(16, first_expert_held=9), "it counts the experts held")
+    plant("no_published_width", lambda cfg: (held_of(16)(cfg), cfg.pop("published")),
+          "it counts the experts held")
+    plant("first_held_and_no_share", lambda cfg: cfg.update(first_expert_held=0),
+          "and does not list")
+
     shutil.rmtree(out)
-    # the first fixture as it stands keeps the rule: under forced routing a
-    # number reads three times (PR 38). The second, 8 experts a token, does
-    # NOT on its two dozen seeds: the worst request's median is 1.54 times
-    # apart, less than the two sides' room takes, and the file stays refused
-    rc, err = check(os.path.join(HERE, "fixtures", "many-experts.json"))
-    assert rc == 0, err
-    rc, err = check(os.path.join(HERE, "fixtures", "many-experts-k8.json"))
+    # the fixtures as they stand keep the rule, forced and with the head judged
+    # over the top tokens (PR 43): at 4, at 8 and at 10 experts a token
+    fixtures = os.path.join(HERE, "fixtures")
+    for name in ("many-experts", "many-experts-k8", "many-experts-k10"):
+        rc, err = check(os.path.join(fixtures, name + ".json"))
+        assert rc == 0, (name, err)
+    # ... where the second, judged over the FIRST token alone on two dozen seeds
+    # (PR 38's readings, which stay in the file as the finding they were), did
+    # not: the worst request's median 1.54 times apart, less than the two sides'
+    # room takes. Laid over the file again, they are refused as they were
+    with open(os.path.join(fixtures, "many-experts-k8.json")) as f:
+        k8 = json.load(f)
+    own = k8["first_token_readings"]["judge_readings"]
+    os.makedirs(out)
+    with open(os.path.join(out, "many-experts-k8-first-token.json"), "w") as f:
+        json.dump(dict(k8, judge=k8["first_token_readings"]["judge"], judge_readings=own), f)
+    rc, err = check(os.path.join(out, "many-experts-k8-first-token.json"))
+    shutil.rmtree(out)
     assert rc == 2 and "logprob_gap_request_median_sigmas: limit" in err and all(
         x not in err for x in ("logprob_gap_pooled_mean", "router_choice_deficit_max_sigmas",
                                "in no number")), err
-    # ... and MORE requests a run make no room either (PR 39's sizing run,
+    # ... and MORE requests a run made no room either (PR 39's sizing run,
     # fixtures/many-experts-k8.requests-readings.json: the same 24 seeds read
-    # at 4, 8, 12 and 16 requests a run; at four they are the fixture's own
-    # readings): the largest deficit reads three times at every count, and the
-    # pooled mean never the rule's 1.5625 with a served program's 23% over the
-    # bf16 control on top. So the fixture stands as it is, refused
-    with open(os.path.join(HERE, "fixtures", "many-experts-k8.json")) as f:
-        own = json.load(f)["judge_readings"]
-    with open(os.path.join(HERE, "fixtures", "many-experts-k8.requests-readings.json")) as f:
+    # at 4, 8, 12 and 16 requests a run, first token alone; at four they are
+    # those readings): the largest deficit reads three times at every count, and
+    # the pooled mean never the rule's 1.5625 with a served program's 23% over
+    # the bf16 control on top
+    with open(os.path.join(fixtures, "many-experts-k8.requests-readings.json")) as f:
         by_count = json.load(f)["by_requests_a_run"]
     assert sorted(by_count, key=int) == ["4", "8", "12", "16"]
     for number, entry in by_count["4"].items():
@@ -681,7 +718,7 @@ def test_a_forced_family_is_judged_by_the_choices_it_is_handed():
         assert not routing[name]["deficits"].any() and not routing2[name]["deficits"].any()
         assert (routing2[name]["chosen"] == routing[name]["chosen"]).all()
         assert np.abs(rows2[name] - rows[name]).max() < 1e-4 * rows[name].std()
-    served = reference.control_choice(rows)
+    served = reference.control_choice(rows, reference.TOP_TOKENS)
     sound = reference.judge(cases, rows, margins, served, limits,
                             {n: r["deficits"] for n, r in routing2.items()})
     assert sound["agrees"] and sound["compared"]["router_choice_deficit_max_sigmas"][0] == 0.0
@@ -696,7 +733,8 @@ def test_a_forced_family_is_judged_by_the_choices_it_is_handed():
         swapped[name] = rows_
     with jax.default_matmul_precision("highest"):
         rows3, margins3, routing3 = reference.forward(ref, cfg, params, cases, swapped)
-    wrong = reference.judge(cases, rows3, margins3, reference.control_choice(rows3), limits,
+    wrong = reference.judge(cases, rows3, margins3,
+                            reference.control_choice(rows3, reference.TOP_TOKENS), limits,
                             {n: r["deficits"] for n, r in routing3.items()})
     over = [key for key, (v, lim) in wrong["compared"].items() if v > lim]
     assert not wrong["agrees"] and over == ["router_choice_deficit_max_sigmas"], \
@@ -718,17 +756,108 @@ def test_a_forced_family_is_judged_by_the_choices_it_is_handed():
     out = os.path.join(os.path.dirname(HERE), "chiprun_out", "selftest_forced")
     shutil.rmtree(out, ignore_errors=True)
     os.makedirs(out)
+    # ... nor cases that carry the choices and no top tokens: never over one token
+    for more, says in (({}, "no `routed_experts`"),
+                       ({"routed_experts": [[[0] * k] * layers]}, "no `served_top_ids`")):
+        with open(os.path.join(out, "cases.json"), "w") as f:
+            json.dump({"config_file": path, "rehearsal": True, "controls": [],
+                       "cases": {n: dict(c, served_logprobs=[0.0] * len(c["served_ids"]), **more)
+                                 for n, c in cases.items()}}, f)
+        try:
+            reference.run(os.path.join(out, "cases.json"))
+        except ValueError as e:
+            assert says in str(e), (says, str(e))
+        else:
+            raise AssertionError(f"a forced configuration was judged with {says}")
+    # ... and cases that carry both, as a program's `reference_cases.json` will
+    # (here the reference's own choices and its log-probabilities of the made-up
+    # tokens and of the tokens it puts first), are judged through `run`: every
+    # log-probability number and every deficit 0, over TOP_TOKENS tokens
+    def own_logprobs(n, c):
+        ids = np.asarray(c["served_ids"])
+        return (rows[n][np.arange(len(ids)), ids] - reference.log_normalizer(rows[n])).tolist()
+
     with open(os.path.join(out, "cases.json"), "w") as f:
-        json.dump({"config_file": path, "rehearsal": True, "controls": [],
-                   "cases": {n: dict(c, served_logprobs=[0.0] * len(c["served_ids"]))
-                             for n, c in cases.items()}}, f)
-    try:
-        reference.run(os.path.join(out, "cases.json"))
-    except ValueError as e:
-        assert "no `routed_experts`" in str(e)
-    else:
-        raise AssertionError("a forced configuration was judged with no choices handed over")
+        json.dump({"config_file": path, "rehearsal": True, "controls": [], "cases": {
+            n: dict(c, served_logprobs=own_logprobs(n, c),
+                    served_top_ids=served[n][2][0].tolist(),
+                    served_top_logprobs=served[n][2][1].tolist(),
+                    routed_experts=own[n].tolist()) for n, c in cases.items()}}, f)
+    whole = reference.run(os.path.join(out, "cases.json"))
+    # (made-up tokens are not the ones the model puts first: the count of
+    # positions outside, which reads the served token's logit, says so alone)
+    assert [k for k, (v, _) in whole["compared"].items() if v] == ["positions_outside"], \
+        whole["compared"]
+    assert all(r["top_tokens"] == reference.TOP_TOKENS for r in whole["cases"].values())
     shutil.rmtree(out)
+
+
+def test_a_forced_family_is_judged_over_the_top_tokens():
+    """reference.judge, forced, on made-up logits: a position's number is the
+    mean over the TOP_TOKENS tokens handed over of |their log-probability - the
+    reference's| in deviations of the position's logits, and the two pooled
+    means and the worst request's median are read from those; the count of
+    positions outside stays on the first token. ONE request of four 0.2
+    deviations off at every top token fails by the median alone; top tokens
+    missing, or of another count than TOP_TOKENS, are refused, not judged over
+    one token. A family judged free reads what it read, whatever comes third."""
+    import numpy as np
+
+    import reference
+
+    N = reference.TOP_TOKENS
+    cases, rows_of, served_of, margins = made_up_run(lambda rng, n: rng.uniform(0.2, 1.0, size=n))
+    rng = np.random.default_rng(1)
+    limits = {"router_margin_epsilon": 0, "router_left_out_share": 0,
+              "router_choice_deficit_max_sigmas": 0.05}
+    deficits = {name: np.zeros((2, len(rows) + 99)) for name, rows in rows_of.items()}
+    exact = reference.control_choice(rows_of, N)
+    tops, by_hand = {}, {}
+    for name, (ids, lp, (top_ids, top_lp)) in exact.items():
+        assert (top_ids[:, 0] == ids).all() and np.allclose(top_lp[:, 0], lp)
+        assert (np.diff(top_lp, axis=-1) <= 0).all() and top_ids.shape == (len(ids), N)
+        off = rng.normal(scale=0.012, size=top_lp.shape)  # bf16's own rounding, a token
+        tops[name] = (ids, served_of[name][1], (top_ids, top_lp + off * rows_of[name].std(-1)[:, None]))
+        by_hand[name] = np.abs(off).mean(-1)
+    sound = reference.judge(cases, rows_of, margins, tops, limits, deficits)
+    got = {k: v for k, (v, _) in sound["compared"].items()}
+    assert sound["agrees"], sound["why_not"]
+    assert close(got["logprob_gap_pooled_mean_sigmas"],
+                 np.concatenate(list(by_hand.values())).mean(), 1e-5)
+    assert got["logprob_gap_pooled_mean_all_sigmas"] == got["logprob_gap_pooled_mean_sigmas"]
+    assert close(got["logprob_gap_request_median_sigmas"],
+                 max(np.median(d) for d in by_hand.values() if len(d) >= 128), 1e-5)
+    one = sound["cases"]["1.median"]
+    first = np.abs(served_of["1.median"][1] - exact["1.median"][1]) / rows_of["1.median"].std(-1)
+    assert one["top_tokens"] == N and close(one["logprob_diff_sigmas_first_token_mean"],
+                                            first.mean(), 1e-5)
+    assert close(one["logprob_diff_sigmas_max"], first.max(), 1e-5)  # the first token's
+    # the same cases judged FREE read the first token alone, top tokens or none
+    free = reference.judge(cases, rows_of, margins, tops)
+    assert free["compared"] == reference.judge(cases, rows_of, margins, served_of)["compared"]
+    ids, lp, (top_ids, top_lp) = tops["1.median"]
+    shifted = dict(tops)
+    shifted["1.median"] = (ids, lp - 0.2 * rows_of["1.median"].std(-1),
+                           (top_ids, top_lp - 0.2 * rows_of["1.median"].std(-1)[:, None]))
+    broken = reference.judge(cases, rows_of, margins, shifted, limits, deficits)
+    over = [k for k, (v, lim) in broken["compared"].items() if v > lim]
+    assert not broken["agrees"] and over == ["logprob_gap_request_median_sigmas"], broken["compared"]
+    assert broken["compared"]["logprob_gap_request_median_sigmas"][0] > 0.19
+    # 0.3 off at the first token alone: outside at every position of the
+    # request (the count reads the first token), a fifth of it in the mean
+    first_only = dict(tops)
+    first_only["1.median"] = (ids, lp - 0.3 * rows_of["1.median"].std(-1), (top_ids, top_lp))
+    counted = reference.judge(cases, rows_of, margins, first_only, limits, deficits)
+    assert counted["compared"]["positions_outside"][0] == len(ids)
+    assert counted["compared"]["logprob_gap_request_median_sigmas"][0] < 0.02
+    for bad in (served_of, {n: (i, l, (t[0][:, :N - 1], t[1][:, :N - 1]))
+                            for n, (i, l, t) in tops.items()}):
+        try:
+            reference.judge(cases, rows_of, margins, bad, limits, deficits)
+        except ValueError as e:
+            assert "top" in str(e)
+        else:
+            raise AssertionError("a forced family was judged without its top tokens")
 
 
 def test_the_client_holds_a_reply_to_the_wire_contract():
@@ -760,6 +889,91 @@ def test_the_client_holds_a_reply_to_the_wire_contract():
             assert says in str(e), (says, str(e))
         else:
             raise AssertionError(f"routed_rows took a reply with {says}")
+
+
+def test_the_client_holds_a_reply_to_the_top_token_contract():
+    """client.top_tokens: one entry a served token, each TOP_TOKENS distinct
+    ids in range with the served token among them and as many finite
+    log-probabilities. Entries missing, one short, a duplicate, the served
+    token not among them, an id out of range, a log-probability that is no
+    finite number: each fails with its reason (run.py then fails the run)."""
+    import client
+    from reference import TOP_TOKENS as N
+
+    served = [9, 4, 300]
+    entry = [{"ids": [t, *range(100, 100 + N - 1)], "logprobs": [-0.5 - i for i in range(N)]}
+             for t in served]
+    ids, lps = client.top_tokens(copy.deepcopy(entry), served, 512)
+    assert ids == [e["ids"] for e in entry] and lps == [e["logprobs"] for e in entry]
+
+    def spoiled(at, **keys):
+        bad = copy.deepcopy(entry)
+        bad[at].update(keys)
+        return bad
+
+    for bad, says in (
+            ([], f"0 entries of `top_logprobs` for 3 served tokens"),
+            (entry[:2], "2 entries"),
+            (entry[:2] + [None], "entry 2 of `top_logprobs` has the ids None"),
+            (spoiled(1, ids=entry[1]["ids"][:-1], logprobs=entry[1]["logprobs"][:-1]),
+             f"entry 1 of `top_logprobs` has the ids {entry[1]['ids'][:-1]}: not {N} distinct"),
+            (spoiled(1, ids=[4, *[100] * (N - 1)]), f"not {N} distinct token ids under 512"),
+            (spoiled(2, ids=list(range(100, 100 + N))), "with the served token 300 among them"),
+            (spoiled(0, ids=[9, 512, *range(100, 100 + N - 2)]), "entry 0"),
+            (spoiled(0, ids=[9, 1.0, *range(100, 100 + N - 2)]), "entry 0"),
+            (spoiled(0, logprobs=[-0.5] * (N - 1)), f"not {N} finite numbers"),
+            (spoiled(0, logprobs=[float("nan")] + [-0.5] * (N - 1)), f"not {N} finite numbers"),
+            (spoiled(0, logprobs=[float("-inf")] + [-0.5] * (N - 1)), f"not {N} finite numbers")):
+        try:
+            client.top_tokens(bad, served, 512)
+        except ValueError as e:
+            assert says in str(e), (says, str(e))
+        else:
+            raise AssertionError(f"top_tokens took a reply with {says}")
+
+
+BODY_BEFORE = {  # client.py:resend_greedy's body as PRs 21 to 42 sent it, key for key
+    "model": "a-cell", "prompt": "hello, world", "max_tokens": 7, "temperature": 0,
+    "stream": False, "nvext": {"ignore_eos": True}, "logprobs": 0}
+
+
+def test_a_free_family_sends_what_it_always_sent():
+    """The re-sent request of a family judged free (both cells): the body is
+    the one of before, key for key, and the dictionary that goes over the
+    request plane is the frontend's own preprocessing of it, with nothing
+    added. A family judged forced differs in two places: the count of top
+    tokens under the same option, and the annotation that asks for the
+    experts chosen."""
+    import client
+    from dynamo_tpu.llm.model_card import ModelDeploymentCard
+    from dynamo_tpu.llm.preprocessor import OpenAIPreprocessor
+    from dynamo_tpu.llm.protocols import CompletionRequest
+    from dynamo_tpu.llm.tokenizers import load_tokenizer
+    from reference import TOP_TOKENS
+
+    pre = OpenAIPreprocessor(
+        ModelDeploymentCard(name="a-cell", tokenizer="byte",
+                            kv_cache_block_size=client.PAGE_SIZE, context_length=1024),
+        load_tokenizer("byte:512"))
+    pick = {"why": "made_up", "prompt": "hello, world", "max_tokens": 7}
+    body = client.resend_body("a-cell", pick, False)
+    assert body == BODY_BEFORE and list(body) == list(BODY_BEFORE)
+    assert json.dumps(body) == json.dumps(BODY_BEFORE)  # byte for byte
+
+    def less_its_id(wire):  # the request's id is drawn anew each time
+        assert wire.pop("request_id")
+        return wire
+
+    before = less_its_id(pre.preprocess_completion(CompletionRequest(**BODY_BEFORE)).to_dict())
+    req, wire = client.resend_wire(pre, "a-cell", pick, False)
+    assert less_its_id(wire) == before and "annotations" not in wire
+    assert "top_logprobs" not in wire["sampling_options"]
+    assert list(req.token_ids) == before["token_ids"]
+    _, forced = client.resend_wire(pre, "a-cell", pick, True)
+    less_its_id(forced)
+    assert forced.pop("annotations") == [client.ROUTED_EXPERTS]
+    assert forced["sampling_options"].pop("top_logprobs") == TOP_TOKENS
+    assert forced == before
 
 
 def test_controls_only_needs_no_program_to_serve():
@@ -809,36 +1023,6 @@ def test_controls_only_needs_no_program_to_serve():
         for count, one in ((4, at_four), (8, at_eight)):
             for number, value in sample_sizes.numbers_at(cases, count).items():
                 assert close(value, one["int8"]["checked"][number][0], 1e-6), (count, number)
-    # second_way.py (an experiment: the head judged over a control's top eight
-    # tokens) on the same cases: over the first token alone it reads every
-    # request's own numbers of the sizing run, and its swap makes two sets more
-    p = subprocess.run(
-        [sys.executable, os.path.join(HERE, "second_way.py"), "--rehearsal", "--requests", "8",
-         "--config-file", os.path.join(HERE, "fixtures", "many-experts.json"),
-         "--traffic", "decode-closed", "--seed", "7", "--seed", "2147483907",
-         "--swap", "7", "2147483907"],
-        stdout=subprocess.PIPE, stderr=subprocess.PIPE, timeout=600)
-    assert p.returncode == 0, p.stderr[-2000:]
-    with open(os.path.join(os.path.dirname(result), os.pardir,
-                           "second_way.many-experts.decode-closed.rehearsal",
-                           "requests.json")) as f:
-        second = json.load(f)
-    assert list(second) == ["7", "2147483907", "7>2147483907", "2147483907>7"]
-    for seed in ("7", "2147483907"):
-        for name, own in sets[seed]["int8"]["cases"].items():
-            top = second[seed]["int8"][name]
-            assert close(top["d1_mean"], own["logprob_diff_sigmas_mean_all_positions"], 1e-6)
-            assert close(top["d1_median"], own["logprob_diff_sigmas_median"], 1e-6), name
-            assert top["tokens"] == own["tokens"] and top["d8_mean"] > 0
-    p = subprocess.run(
-        [sys.executable, os.path.join(HERE, "second_way.py"), "--counts", "4", "8", "--read",
-         f.name], stdout=subprocess.PIPE, stderr=subprocess.PIPE, timeout=60)
-    tables = [json.loads(x) for x in p.stdout.decode().strip().splitlines()]
-    assert p.returncode == 0 and len(tables) == 10 and "swap" in tables[-1], p.stderr[-2000:]
-    d1 = next(t for t in tables if t["over"] == "d1" and t["requests_a_run"] == 8
-              and t["number"] == "pooled_mean")
-    assert close(d1["int8"][1], max(x["int8"]["checked"]["logprob_gap_pooled_mean_all_sigmas"][0]
-                                    for x in sized[:2]), 1e-6), d1
 
 
 def tree_of_links(*real: str) -> str:
@@ -876,8 +1060,8 @@ def new_routed_cell(tree: str, name: str, **keys) -> tuple:
     with open(os.path.join(root, entry["file"])) as f:
         cfg = json.load(f)
     assert cfg["family"] == "moe" and "judge" not in cfg
-    entry.update(name=name, file=f"benchmark/configs/{name}.json")
     cfg.update(keys)
+    entry.update(name=name, file=f"benchmark/configs/{name}.json", reduced=cfg["reduced"])
     with open(os.path.join(tree, entry["file"]), "w") as f:
         json.dump(cfg, f)
     cell = dict(bench["workloads"][0], name=name + ".decode-closed", config=name)
@@ -912,25 +1096,156 @@ def test_limits_of_its_own_are_a_new_file_alone():
     shutil.rmtree(tree)
 
 
+# What a later PR's `references/<family>.py` adds to a copy of references/moe.py
+# where a chip holds a SHARE of every layer's experts: the program's dataclass
+# with the router's width and the first expert held beside the count held, the
+# uncut model's weights cut to the share, and (`held_share_reference`) the scan
+# over the experts held alone.
+HELD_SHARE = '''
+
+# -- appended by selftest.py: a share of every layer's experts ------------- #
+import dataclasses  # noqa: E402
+
+from dynamo_tpu.models import moe as _program  # noqa: E402
+
+
+@dataclasses.dataclass(frozen=True)
+class HeldShare(_program.MoeConfig):
+    """`num_experts` counts the experts HELD: [first_expert_held, + num_experts)
+    of a router `router_width` wide."""
+    router_width: int = 0
+    first_expert_held: int = 0
+
+
+def init_params(cfg, key):
+    """The uncut model's weights, of which the share keeps its experts' (the
+    router whole)."""
+    whole = _program.init_params(dataclasses.replace(cfg, num_experts=cfg.router_width), key)
+    lo, hi = cfg.first_expert_held, cfg.first_expert_held + cfg.num_experts
+    return dict(whole, layers={k: v[:, lo:hi] if k in ("w_gate", "w_up", "w_down") else v
+                               for k, v in whole["layers"].items()})
+'''
+SCAN_OVER_ALL = '        (w["w_gate"], w["w_up"], w["w_down"], weight.T),\n'
+SCAN_OVER_THE_SHARE = (  # the routing weights of the experts held: the others' part is not here
+    '        (w["w_gate"], w["w_up"], w["w_down"],\n'
+    '         weight[:, getattr(cfg, "first_expert_held", 0):][:, :w["w_gate"].shape[0]].T),\n')
+
+
+def held_share_reference(path: str) -> None:
+    """references/moe.py again at `path`, given the share: it routes over the
+    router's full width, computes the part of the experts whose weights it
+    holds, and reads margins and deficits over the full width."""
+    with open(os.path.join(HERE, "references", "moe.py")) as f:
+        source = f.read()
+    assert source.count(SCAN_OVER_ALL) == 1, "references/moe.py scans otherwise: cut it anew"
+    with open(path, "w") as f:
+        f.write(source.replace(SCAN_OVER_ALL, SCAN_OVER_THE_SHARE) + HELD_SHARE)
+
+
 def test_a_forced_family_is_new_files_alone():
-    """A later PR's family whose routing is judged forced: a configuration
-    (`judge_routing`, limits of its own) and a `references/<family>.py` that
-    takes `forced`, both new files in a tree of links, and entries in
-    BENCHMARK.json; no edit to a file the benchmark has. There `run.py
-    --controls-only` judges the new reference under its own choices by the
-    file's limits; and a whole `run.py --rehearsal` of its cell asks the worker
-    for the experts it chose, gets none from today's program, and FAILS the
-    run with that reason: it is never judged free."""
+    """A later PR's family whose routing is judged forced, one chip holding a
+    SHARE of every layer's experts: a configuration (`judge_routing`, limits of
+    its own, its experts key in `reduced`: the count held, the router at its
+    published width) and a `references/<family>.py` that takes `forced` and is
+    given the share, both new files in a tree of links, and entries in
+    BENCHMARK.json; no edit to a file the benchmark has. There the router's
+    width is the published count: expert ids past the count held pass the
+    client's check, ids at or over the width fail it; the parts that the two
+    shares compute add up to the uncut reference's; `run.py --controls-only`
+    judges the new reference under its own choices (over the full width) by
+    the file's limits; and a whole `run.py --rehearsal` of its cell asks the
+    worker for its top tokens, which today's program gives, and for the
+    experts it chose, gets none, and FAILS the run with that reason: it is
+    never judged free."""
+    import importlib.util
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import client
+    import files_check
+    from worker_entry import build_model_config, load_config
+
     tree = tree_of_links("configs", "references")
-    shutil.copy(os.path.join(HERE, "references", "moe.py"),
-                os.path.join(tree, "benchmark", "references", "moe_again.py"))
+    family = os.path.join(tree, "benchmark", "references", "moe_again.py")
+    held_share_reference(family)
+    root = os.path.dirname(HERE)
+    with open(os.path.join(root, "benchmark", "configs", "mixtral-8x7b-d2.json")) as f:
+        plain = json.load(f)
+    share = {  # the second half of a router twice as wide as the experts held
+        "reduced": [*plain["reduced"], "num_local_experts"],
+        "published": dict(plain["published"], num_local_experts=2 * plain["num_local_experts"]),
+        "first_expert_held": plain["num_local_experts"],
+        "dataclass": "benchmark.references.moe_again:HeldShare",
+        "dataclass_fields": dict(plain["dataclass_fields"], first_expert_held="first_expert_held",
+                                 router_width="published.num_local_experts"),
+        "rehearsal": dict(plain["rehearsal"], first_expert_held=4, published=dict(
+            plain["published"], num_local_experts=8))}
     cfg, cell = new_routed_cell(
         tree, "routed-and-forced",
-        **dict(planted_forced((0.01, 0.02), (0.07, 0.08)), family="moe_again"))
+        **dict(planted_forced((0.01, 0.02), (0.07, 0.08)), family="moe_again", **share))
+    path = os.path.join(tree, "benchmark", "configs", "routed-and-forced.json")
+    # the router's width: the published count where the key is in `reduced`,
+    # the key's own count where it is not (the cell as it stands)
+    small = load_config(path, True)
+    assert files_check.routed_geometry("share", cfg) == (16, 2, 2)
+    assert files_check.routed_geometry("share", small) == (8, 2, 2)
+    assert files_check.routed_geometry("plain", plain) == (8, 2, 2)
+    assert files_check.routed_geometry("plain", dict(plain, **plain["rehearsal"])) == (4, 2, 2)
+    rows = [[[5, 7], [4, 0]]] * 3  # ids in [held, width) and under held
+    assert client.routed_rows(list(rows), 3, files_check.routed_geometry("share", small)) == rows
+    for geometry, bad in ((files_check.routed_geometry("share", small), [[[5, 8], [4, 0]]]),
+                          ((4, 2, 2), rows[:1])):
+        try:
+            client.routed_rows(bad, 1, geometry)
+        except ValueError as e:
+            assert f"distinct expert ids under {geometry[0]}" in str(e), str(e)
+        else:
+            raise AssertionError(f"an expert id at or over the width {geometry[0]} passed")
+    for spoil, says in ((dict(first_expert_held=5), "counts the experts held"),
+                        (dict(published={"num_hidden_layers": 32}), "counts the experts held"),
+                        (dict(reduced=["num_hidden_layers"]), "and does not list")):
+        try:
+            files_check.routed_geometry("share", dict(small, **spoil))
+        except files_check.BenchmarkFilesError as e:
+            assert says in str(e), str(e)
+        else:
+            raise AssertionError(f"routed_geometry took {spoil}")
+    # the parts that the two shares compute add up to the uncut block's
+    spec = importlib.util.spec_from_file_location("moe_again_for_selftest", family)
+    again = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(again)
+    from references import moe as uncut
+
+    second = build_model_config(dict(small, dataclass="dynamo_tpu.models.moe:MoeConfig",
+                                     dataclass_fields=plain["dataclass_fields"]))
+    second = again.HeldShare(**vars(second), router_width=8, first_expert_held=4)
+    first = again.dataclasses.replace(second, first_expert_held=0)
+    whole_cfg = again.dataclasses.replace(second, num_experts=8)
+    key = jax.random.PRNGKey(3)
+    whole = again._program.init_params(whole_cfg, key)
+    x = jax.random.normal(jax.random.PRNGKey(4), (24, second.hidden_size), jnp.float32)
+    free = jnp.full((24, 2), -1, jnp.int32)
+
+    def block(module, cfg_, params):
+        layer = jax.tree.map(lambda v: v[0], params["layers"])
+        with jax.default_matmul_precision("highest"):
+            out, (margin, chosen, deficit) = module.routed_mlp(x, layer, cfg_, free)
+        return np.asarray(out - x), np.asarray(margin), np.asarray(chosen)
+
+    want, margin, chosen = block(uncut, whole_cfg, whole)
+    parts = [block(again, c, again.init_params(c, key)) for c in (first, second)]
+    assert chosen.max() >= 4 and chosen.min() < 4  # both halves are routed to
+    for part in parts:  # each share routes over the full width, as the uncut block does
+        assert (part[2] == chosen).all() and np.allclose(part[1], margin)
+        assert np.abs(part[0]).max() > 0.1 * np.abs(want).max()
+    gap = np.abs(parts[0][0] + parts[1][0] - want).max() / np.abs(want).max()
+    assert gap < 1e-3, gap  # (float32's rounding of x + part, less x; a part is a tenth or more)
+
     run_py = os.path.join(tree, "benchmark", "run.py")
     p = subprocess.run(
-        [sys.executable, run_py, "--controls-only", "--rehearsal", "--config-file",
-         os.path.join(tree, "benchmark", "configs", "routed-and-forced.json"),
+        [sys.executable, run_py, "--controls-only", "--rehearsal", "--config-file", path,
          "--traffic", "decode-closed", "--seed", "2147483803"],
         cwd=tree, stdout=subprocess.PIPE, stderr=subprocess.PIPE, timeout=600)
     lines = [json.loads(x) for x in p.stdout.decode().strip().splitlines()]
