@@ -280,6 +280,30 @@ COMPILE_SURFACES = {
         "help": "allocates a mesh-sharded KV pool shard by shard at engine "
                 "construction (the whole pool does not fit one device)",
     },
+    "dense_leaf": {
+        "module": "dynamo_tpu/models/hybrid.py",
+        "kind": "jit",
+        "donate": (),
+        "static": (1, 2),
+        "axes": {"shape": "one program per distinct stacked leaf shape "
+                          "and dtype (a dozen per model)"},
+        "warmup": False,
+        "help": "one stacked leaf of seeded random weights at model "
+                "construction (hybrid.init_params), built in its own "
+                "dtype with no float32 copy beside it",
+    },
+    "expert_stack_leaf": {
+        "module": "dynamo_tpu/models/hybrid.py",
+        "kind": "jit",
+        "donate": (),
+        "static": (1, 2, 3, 4),
+        "axes": {"shape": "two programs per model: an expert's up and "
+                          "down matrix shapes"},
+        "warmup": False,
+        "help": "a [layers, held experts, ...] stack of seeded weights "
+                "keyed by each expert's global id, at model construction "
+                "(hybrid.init_params)",
+    },
     # ----------------------------------------------------------------- #
     # ops/ — attention kernels (jit wrappers staging pallas_call bodies)
     # ----------------------------------------------------------------- #

@@ -46,8 +46,9 @@ import numpy as np
 from ..llm.mocker.kv_manager import KvEvent
 from ..llm.protocols import Annotated, LLMEngineOutput, PreprocessedRequest
 from ..llm.tokens import TokenBlockSequence, compute_seq_hashes, salt_hash
-from ..models import llama, moe
+from ..models import hybrid, llama, moe
 from ..models.quant import is_quant
+from ..ops.state_cache import state_bytes_per_lane
 from ..native import native_available
 from ..runtime import faults
 from ..runtime.engine import Context
@@ -68,7 +69,7 @@ from .bucketing import (
     table_rungs,
 )
 from .config import EngineConfig
-from .kv_cache import PageAllocator, alloc_kv_arrays
+from .kv_cache import PageAllocator, alloc_kv_arrays, alloc_state_cache
 from .recorder import Recorder, Work
 from .sampling import SamplingParams, penalized, sample, sample_lp, unpack_mask
 from .scheduler import SlaConfig, StepPlanner
@@ -160,6 +161,32 @@ def _kv_shard_div(kv_sharding) -> int:
     return max(div, 1)
 
 
+#: the module that serves a configuration, by the configuration's class
+#: (the first entry the class is an instance of: a subclass stands before
+#: its base)
+MODEL_FAMILIES = (
+    (hybrid.HybridConfig, hybrid),
+    (moe.MoeConfig, moe),
+    (llama.LlamaConfig, llama),
+)
+
+
+def model_family(model_cfg):
+    """The family module (models/<family>.py) whose forwards serve
+    `model_cfg`."""
+    for klass, module in MODEL_FAMILIES:
+        if isinstance(model_cfg, klass):
+            return module
+    raise ValueError(f"no model family serves a {type(model_cfg).__name__}")
+
+
+def _weights_quantized(params) -> bool:
+    """Whether any weight leaf is an int8 leaf of models/quant.py."""
+    return any(
+        is_quant(x) for x in jax.tree_util.tree_leaves(params, is_leaf=is_quant)
+    )
+
+
 def _auto_num_pages(params, model_cfg, config: EngineConfig,
                     kv_sharding=None, multihost: bool = False) -> int:
     """Size the KV page pool from free device memory (the role vLLM's
@@ -211,9 +238,18 @@ def _auto_num_pages(params, model_cfg, config: EngineConfig,
         # quantized pages shrink the per-page bytes (int8 ~2x, int4 ~4x
         # incl. the f32 per-head scales), so the SAME free-HBM budget
         # yields ~2x/4x the pages — the resident-session density win
+        # a family with a recurrent state keeps pages for its attention
+        # layers alone, and its state store (one slot a lane and a scratch
+        # slot) comes out of what the pool may take
+        kv_layers, state_bytes = model_cfg.num_layers, 0
+        if isinstance(model_cfg, hybrid.HybridConfig):
+            kv_layers = hybrid.periods(model_cfg)[2]
+            state_bytes = (
+                (config.max_num_seqs + 1) * state_bytes_per_lane(model_cfg)
+            )
         page_bytes = (
             2  # K and V
-            * model_cfg.num_layers
+            * kv_layers
             * kv_page_bytes(
                 config.page_size, model_cfg.num_kv_heads,
                 model_cfg.head_dim, model_cfg.dtype,
@@ -221,7 +257,7 @@ def _auto_num_pages(params, model_cfg, config: EngineConfig,
             )
         )
         page_bytes_dev = page_bytes // _kv_shard_div(kv_sharding)
-        free = int(limit * util) - int(in_use) - reserve
+        free = int(limit * util) - int(in_use) - reserve - state_bytes
         n = free // page_bytes_dev
         logger.info(
             "auto-sized KV pool: %d pages (%.2f GiB resident of %.2f GiB free"
@@ -356,6 +392,17 @@ class _Slot:
     # exactly once (a later preemption re-admit must not re-count).
     migration: int = 0
     migration_counted: bool = False
+    # the request plane's `routed_experts` annotation (a routed family
+    # that records its choices: models/hybrid.py): one row [routed
+    # layers][k] of expert ids for each input position, riding the frame
+    # that carries the token the position produced. `routed_sent`: input
+    # positions whose rows were handed over; `routed_seen`: input positions
+    # computed since the last admission (a preempted sequence recomputes
+    # from 0 and sends no row twice)
+    want_routed: bool = False
+    routed_pending: List[Any] = field(default_factory=list)
+    routed_sent: int = 0
+    routed_seen: int = 0
 
 
 class StreamedPullHandle:
@@ -409,7 +456,7 @@ class StreamedPullHandle:
 
 
 class _ScopedModel:
-    """The model family module (models/llama.py or models/moe.py) as one
+    """The model family module (models/<family>.py) as one
     engine sees it: every forward it traces runs inside that engine's
     attention scope, so the kernel-or-XLA decision follows the engine's
     own mesh (ops/paged_attention.mesh_allows_kernels) wherever the trace
@@ -485,9 +532,16 @@ class JaxEngine:
         _enable_compile_cache()
         self.model_config = model_config or _resolve_model(config.model)
         c = self.model_config
-        # family dispatch: MoeConfig subclasses LlamaConfig, and models/moe.py
+        # family dispatch by the configuration's class: every family module
         # exposes the same init/decode/prefill signatures
-        from ..models import moe
+        family = model_family(c)
+        # a family that keeps a recurrent state per lane beside the pages
+        # (models/hybrid.py; docs/hybrid_models.md)
+        self._stateful = family is hybrid
+        # a routed family counts the rows its expert matmuls multiply
+        self._counts_expert_rows = hasattr(family, "expert_rows")
+        if self._stateful:
+            self._refuse_what_state_cannot_follow(config, mesh, multihost)
 
         from ..ops.paged_attention import (
             attention_scope,
@@ -496,9 +550,7 @@ class JaxEngine:
         )
 
         allows_kernels = mesh_allows_kernels(mesh)
-        self._model = _ScopedModel(
-            moe if isinstance(c, moe.MoeConfig) else llama, allows_kernels
-        )
+        self._model = _ScopedModel(family, allows_kernels)
         with attention_scope(allows_kernels):
             # one visible decision per engine: which implementation each
             # attention op takes for this model, mesh and KV format
@@ -534,16 +586,29 @@ class JaxEngine:
             names = axes if isinstance(axes, tuple) else (axes,)
             div = int(np.prod([kv_sharding.mesh.shape[a] for a in names]))
             total_pages = -(-total_pages // div) * div
-        self.kv_k, self.kv_v = alloc_kv_arrays(
-            c.num_layers,
-            total_pages,
-            config.page_size,
-            c.num_kv_heads,
-            c.head_dim,
-            dtype=c.dtype,
-            sharding=kv_sharding,
-            kv_quant=config.kv_quant,
-        )
+        if self._stateful:
+            # the state store beside the pages, in kv_k's place: sized for
+            # the most rows and token slots a dispatch packs
+            self.kv_k, self.kv_v = alloc_state_cache(
+                c, total_pages, config.page_size, config.max_num_seqs,
+                max_tokens=max(
+                    config.mixed_max_tokens, config.prefill_batch_tokens,
+                    config.max_prefill_chunk,
+                ),
+                row_slots=max(mixed_row_bucket(config),
+                              config.max_prefill_batch),
+            )
+        else:
+            self.kv_k, self.kv_v = alloc_kv_arrays(
+                c.num_layers,
+                total_pages,
+                config.page_size,
+                c.num_kv_heads,
+                c.head_dim,
+                dtype=c.dtype,
+                sharding=kv_sharding,
+                kv_quant=config.kv_quant,
+            )
         self.allocator = PageAllocator(
             config.num_pages, config.page_size, event_sink=event_sink
         )
@@ -557,9 +622,10 @@ class JaxEngine:
         # one byte an element (their scales are not counted: a floor)
         from ..ops.kv_quant import kv_page_bytes
 
+        self._quantized = _weights_quantized(self.params)
         self._step_work = partial(
-            (moe if isinstance(c, moe.MoeConfig) else llama).step_work, c,
-            weight_bytes=1 if is_quant(self.params["layers"]["w_gate"])
+            family.step_work, c,
+            weight_bytes=1 if self._quantized
             else jnp.dtype(c.dtype).itemsize,
             kv_bytes=2 * kv_page_bytes(
                 config.page_size, c.num_kv_heads, c.head_dim, c.dtype, kvq
@@ -790,6 +856,13 @@ class JaxEngine:
         # expert matmuls multiply; both stay 0 on a dense model
         self.expert_rows_routed = 0
         self.expert_rows_computed = 0
+        # a stateful family's own counters (stats() exports them for it
+        # alone): first chunks dispatched, each of which starts its lane's
+        # state from zero; cached blocks the prefix index was not allowed
+        # to hand out; rows of chosen experts sent to annotated requests
+        self.state_lanes_reset = 0
+        self.state_prefix_hits_declined = 0
+        self.routed_rows_emitted = 0
         self._last_prefill_shape = None  # (padded, real) of the latest dispatch
         self._last_decode_shape = None
         # set by _dispatch_mixed when a pack that needs host-authoritative
@@ -866,6 +939,114 @@ class JaxEngine:
             max_workers=1, thread_name_prefix="jax-fetch"
         )
         self._compile()
+
+    # ------------------------------------------------------------------ #
+    # a family with a recurrent state beside the pages
+    # ------------------------------------------------------------------ #
+
+    STATE_FAMILY = "the hybrid family (models/hybrid.py: a recurrent state per lane)"
+
+    @property
+    def stateful(self) -> bool:
+        """Whether the model keeps a recurrent state per lane beside the
+        pages (docs/hybrid_models.md)."""
+        return self._stateful
+
+    def _refuse_state(self, what: str, why: str):
+        raise ValueError(f"{self.STATE_FAMILY} cannot run {what}: {why}")
+
+    def _refuse_what_state_cannot_follow(self, config: EngineConfig, mesh,
+                                         multihost: bool):
+        """What cannot keep the invariant yet (a lane's state stands at
+        exactly the tokens whose keys and values are written) is switched
+        off for this family alone, here, at start and by name; the prefix
+        index is declined per admission (_try_admit). ROADMAP.md B has
+        what each needs."""
+        if config.kvbm_host_blocks > 0 or config.kvbm_disk_blocks > 0:
+            self._refuse_state(
+                "KVBM offload and onboard (and the migration checkpoints "
+                "that ride its tiers)",
+                "a block's pages come back without the state that stood "
+                "at its end",
+            )
+        if config.spec_mode:
+            self._refuse_state(
+                f"speculative decoding (--spec {config.spec_mode})",
+                "a rejected draft cannot be rolled back out of a state",
+            )
+        if config.role == "prefill":
+            self._refuse_state(
+                "the disaggregated hand-off (--role prefill)",
+                "the pages would leave without the lane's state",
+            )
+        if config.quantize or (config.kv_quant or "none") != "none":
+            self._refuse_state(
+                "--quantize / --kv-quant", "its leaves have no int8 form yet")
+        if mesh is not None or multihost or max(
+                config.tp_size, config.pp_size, config.sp_size,
+                config.dp_size) > 1:
+            self._refuse_state(
+                "over a mesh (tp / pp / sp / dp / multi-host)",
+                "the state store has no sharding",
+            )
+        logger.info(
+            "%s: the prefix index hands out no cached pages (counter "
+            "state_prefix_hits_declined); KVBM, the disaggregated hand-off, "
+            "migration checkpoints and speculation are refused",
+            self.STATE_FAMILY,
+        )
+
+    def _with_lanes(self, call, lanes, entry: Optional[dict] = None):
+        """`call`, a dispatch that packs rows (a prefill batch, a mixed
+        step), for a stateful family: on the device thread, right before
+        it, the cache learns the lane of each row (`lanes`; rows past it
+        and every padded row: the scratch slot); right behind it, where
+        `entry` says a row's request asked for the experts it chose
+        (`want_routed`), they are copied out of the cache before the next
+        dispatch takes it (fetched with the entry). Any other family:
+        `call` as it is."""
+        if not self._stateful:
+            return call
+        lanes = np.asarray(lanes, np.int32)
+
+        def with_lanes(*a):
+            with self._rec.span("put", more=True):
+                self.kv_k = self.kv_k.with_lanes(lanes)
+            out = call(*a)
+            if entry is not None and entry.get("want_routed"):
+                with self._rec.span("launch", more=True):
+                    entry["routed"] = jnp.copy(self.kv_k.routed_flat)
+            return out
+
+        return with_lanes
+
+    def _note_first_chunks(self, starts) -> None:
+        """Rows of a dispatch that begin a sequence (context 0): the
+        forward starts each from a zero state, whatever its lane held."""
+        if self._stateful:
+            self.state_lanes_reset += sum(1 for st in starts if st == 0)
+
+    def _grab_ring(self, call, entry: dict):
+        """`call`, a decode block of a stateful family some lane of which
+        asked for the experts it chose: the ring they are written to,
+        copied out right behind the block (fetched with `entry`)."""
+        def with_ring(*a):
+            out = call(*a)
+            with self._rec.span("launch", more=True):
+                entry["routed"] = jnp.copy(self.kv_k.routed_ring)
+            return out
+
+        return with_ring
+
+    def _take_routed(self, slot: "_Slot", rows) -> None:
+        """Rows [n][routed layers][k] of chosen experts for the next n
+        input positions of `slot`, to ride its next frame; positions sent
+        before (a preempted sequence's recomputation) are left out."""
+        for row in rows:
+            if slot.routed_seen >= slot.routed_sent:
+                slot.routed_pending.append(row)
+                slot.routed_sent += 1
+            slot.routed_seen += 1
 
     # ------------------------------------------------------------------ #
     # compiled programs
@@ -2072,6 +2253,8 @@ class JaxEngine:
             else _secrets.randbits(32)
         )
         slot.want_top_logprobs = min(int(sampling.get("top_logprobs") or 0), 5)
+        slot.want_routed = self._stateful and (
+            "routed_experts" in (req.annotations or []))
         if req.guided:
             slot.guided_fsm = (
                 getattr(req, "_compiled_fsm", None)
@@ -2138,8 +2321,15 @@ class JaxEngine:
         if l_err is not None:
             yield Annotated.from_error(l_err).to_dict()
             return
-        slot = self._new_slot(req, context)
         disagg = req.disagg_params or {}
+        if self._stateful and any(
+                disagg.get(k) for k in ("return_kv", "kv_pull", "kv_stream")):
+            yield Annotated.from_error(
+                f"{self.STATE_FAMILY} cannot run the disaggregated hand-off: "
+                "the pages would leave without the lane's state"
+            ).to_dict()
+            return
+        slot = self._new_slot(req, context)
         slot.return_kv = bool(disagg.get("return_kv"))
         slot.kv_pull = bool(disagg.get("kv_pull"))
         slot.kv_stream = bool(disagg.get("kv_stream"))
@@ -2168,6 +2358,11 @@ class JaxEngine:
         from_pull): coerce + validate the request, build the "-d" slot,
         and catch the guided FSM up to the prefill worker's already-emitted
         first token. Returns (slot, None) or (None, error_string)."""
+        if self._stateful:
+            return None, (
+                f"{self.STATE_FAMILY} cannot run the disaggregated hand-off: "
+                "injected pages bring no state for the lane"
+            )
         self._morph_guard()
         self.start()
         req = (
@@ -2274,6 +2469,8 @@ class JaxEngine:
         transfer serially after prefill. Returns None for request kinds
         the preload path doesn't carry (guided/multimodal/bad-lora); the
         handler then rides the serial path."""
+        if self._stateful:
+            return None
         self.start()
         req = (
             request
@@ -2436,6 +2633,12 @@ class JaxEngine:
         out["mixed_family_compiled"] = int(self._mixed_step._cache_size())
         out["expert_rows_routed"] = self.expert_rows_routed
         out["expert_rows_computed"] = self.expert_rows_computed
+        if self._stateful:
+            # the state store beside the pages (docs/hybrid_models.md)
+            out["state_bytes"] = self.kv_k.state_nbytes
+            out["state_lanes_reset"] = self.state_lanes_reset
+            out["state_prefix_hits_declined"] = self.state_prefix_hits_declined
+            out["routed_rows_emitted"] = self.routed_rows_emitted
         # what the mixed steps' dense layers multiplied: real tokens, and
         # the slots of the token buckets they ran in
         out["mixed_real_tokens"] = self.mixed_real_tokens
@@ -2677,8 +2880,14 @@ class JaxEngine:
             return True
         kv_prompt = slot.kv_prompt
         hashes = slot.seq.block_hashes()
+        # a stateful family takes no cached pages: nobody kept the state
+        # that stood at their end (counted below, once admission is certain)
+        declined = 0
+        if self._stateful and cfg.enable_prefix_caching:
+            declined = len(self.allocator.cached_prefix(hashes))
         cached_pages = (
-            self.allocator.acquire_cached(hashes) if cfg.enable_prefix_caching else []
+            self.allocator.acquire_cached(hashes)
+            if cfg.enable_prefix_caching and not self._stateful else []
         )
         n_cached = len(cached_pages)
         # KVBM: probe G2/G3 for the hashes the device cache missed; tier hits
@@ -2732,6 +2941,15 @@ class JaxEngine:
         n_onboard = len(onboard_hashes)
         if slot.migration:
             self._count_resume(slot, hashes, n_cached, onboard_hashes)
+        if declined:
+            if not self.state_prefix_hits_declined:
+                logger.info(
+                    "%s: %d cached blocks of request %s declined (each such "
+                    "admission counts in state_prefix_hits_declined)",
+                    self.STATE_FAMILY, declined, slot.request_id,
+                )
+            self.state_prefix_hits_declined += declined
+        slot.routed_seen = 0  # the prompt is (re)computed from position 0
         idx = self._free_slots.pop()
         slot.slot_idx = idx
         slot.pages = cached_pages + fresh
@@ -3776,8 +3994,9 @@ class JaxEngine:
         whole-page-aligned progress can splice; fresh slots only (resume/
         disagg/onboard slots carry their own page provenance)."""
         cfg = self.config
-        if not cfg.enable_prefix_caching:
-            return  # caching disabled must disable ALL reuse paths
+        if not cfg.enable_prefix_caching or self._stateful:
+            return  # caching disabled must disable ALL reuse paths (a
+            # stateful family reuses no page: _try_admit)
         if s.generated or s.resume_token is not None or s.onboard is not None:
             return
         n_known = len(s.committed_hashes)
@@ -4046,6 +4265,15 @@ class JaxEngine:
                 work.chunk(s.prefill_pos, chunk,
                            s.prefill_pos + chunk >= len(s.kv_prompt))
             entry = {}
+            if self._stateful:
+                self._note_first_chunks(s.prefill_pos for s, _, _ in meta)
+                # flat slots of each asking row's chunk, for the fetch
+                entry["want_routed"] = [
+                    (s, lane * bucket, chunk) for s, chunk, lane in meta
+                    if s.want_routed
+                ]
+                call = self._with_lanes(
+                    call, [s.slot_idx for s, _, _ in meta], entry)
             self._rec.dispatched(entry, "prefill", work.of(self._step_work))
         entry["first"] = await self._run_on_device(
             call, tag="prefill", shape=(bucket, B_pf)
@@ -4166,6 +4394,14 @@ class JaxEngine:
             "ids": [int(t) for t in tids[:n]],
             "logprobs": [float(v) for v in tlps[:n]],
         }
+
+    @staticmethod
+    def _served_among(top: Optional[dict], token: int, lp) -> None:
+        """A top entry names the token that was served: where sampling
+        took one outside the request's n most likely, the last of them
+        gives way to it and its own log-probability."""
+        if top and lp is not None and token not in top["ids"]:
+            top["ids"][-1], top["logprobs"][-1] = int(token), float(lp)
 
     def _finish_prefill(self, slot: _Slot, first: int,
                         first_lp: Optional[float] = None,
@@ -5014,10 +5250,24 @@ class JaxEngine:
                 "progressed": progressed, "decode": decode_rows,
                 "spec": spec_rows,
             }
+            call = partial(self._dev_mixed, payload)
+            if self._stateful:
+                self._note_first_chunks(ctx_lens[r] for _, _, r in meta)
+                # flat slots of each asking row, for the fetch
+                entry["want_routed"] = [
+                    (s, int(row_starts[r]), int(row_lens[r]))
+                    for s, r in (
+                        *((s, r) for s, _, r in meta),
+                        *((s, r) for r, _, s in decode_rows),
+                    ) if s.want_routed
+                ]
+                call = self._with_lanes(call, [
+                    *(s.slot_idx for s, _, _ in meta),
+                    *(i for _, i, _ in decode_rows),
+                ], entry)
             self._rec.dispatched(entry, "mixed", work.of(self._step_work))
         entry["first"] = await self._run_on_device(
-            partial(self._dev_mixed, payload),
-            tag="mixed", shape=(N_pad, row),
+            call, tag="mixed", shape=(N_pad, row),
         )
         if pipes:
             # an entry of the pipeline: fetched in dispatch order, with
@@ -5147,8 +5397,10 @@ class JaxEngine:
         t1 = time.monotonic()
         for pack in packs:
             self._bcast("mixed", pack)
+            # (a stateful family: the one row is the scratch slot's)
             await self._run_on_device(
-                partial(self._dev_mixed, pack), tag="mixed_prime"
+                self._with_lanes(partial(self._dev_mixed, pack), []),
+                tag="mixed_prime",
             )
         logger.info(
             "mixed family primed: %d %s programs of %d pages, compiled in "
@@ -5159,12 +5411,12 @@ class JaxEngine:
 
     def _count_expert_rows(self, T: int, real: int, steps: int = 1):
         """Account one dispatch of `steps` forward passes over T token
-        slots, `real` of them real, to the expert-row counters (MoE only)."""
-        if not isinstance(self.model_config, moe.MoeConfig):
+        slots, `real` of them real, to the expert-row counters (routed
+        families only)."""
+        if not self._counts_expert_rows:
             return
         routed, computed = self._model.expert_rows(
-            self.model_config, T, real,
-            is_quant(self.params["layers"]["w_gate"]),
+            self.model_config, T, real, self._quantized,
         )
         self.expert_rows_routed += routed * steps
         self.expert_rows_computed += computed * steps
@@ -5386,6 +5638,14 @@ class JaxEngine:
                 "lanes": [(i, self.slots[i]) for i in active],
                 "kind": kind, "adv": adv,
             }
+            if self._stateful and any(
+                    self.slots[i].want_routed for i in active):
+                # each asking lane's first input position in this block
+                entry["want_routed"] = {
+                    i: int(self.seq_lens[i]) - 1 for i in active
+                    if self.slots[i].want_routed
+                }
+                call = self._grab_ring(call, entry)
             # a spec round is one pass that is sure of one token a lane
             self._rec.dispatched(entry, "block", self._block_work(
                 active, cfg.spec_rounds if kind == "spec" else adv
@@ -5445,15 +5705,18 @@ class JaxEngine:
             # dispatched without a drain before it, and its successor was
             # queued before this fetch: no host round trip on either side
             self.mixed_steps_piped += 1
+        entries = prefills if want is None else [*prefills, want]
         tree = (
             [p["first"] for p in prefills],
             None if want is None else want["first" if mixed else "toks"],
+            # a stateful family's chosen experts, where a request asked
+            [e.pop("routed", None) for e in entries],
         )
         self._rec.entry_kind = (want or prefills[0])["step_kind"]
-        (firsts_np, toks_np), t_ready = await self._fetch(tree)
-        self._rec.fetched(
-            prefills if want is None else [*prefills, want], t_ready
-        )
+        (firsts_np, toks_np, routed_np), t_ready = await self._fetch(tree)
+        for e, routed in zip(entries, routed_np):
+            e["routed"] = routed
+        self._rec.fetched(entries, t_ready)
 
         for p, first in zip(prefills, firsts_np):
             await self._process_prefill_result(p, first)
@@ -5470,7 +5733,11 @@ class JaxEngine:
                     want["seq_before"],
                 )
             else:
-                self._process_block(want["lanes"], *toks_np)
+                self._process_block(
+                    want["lanes"], *toks_np,
+                    routed=None if want.get("routed") is None
+                    else (want["routed"], want["want_routed"]),
+                )
         return True
 
     async def _process_prefill_result(self, p: dict, first,
@@ -5483,6 +5750,13 @@ class JaxEngine:
         needs the VALUE happens here, and a lane whose slot ended or was
         re-assigned meanwhile is dropped, as a block's is."""
         with self._rec.span("emit"):
+            if p.get("routed") is not None:
+                # [routed layers, token slots, k] of the dispatch: each
+                # asking row's slots, a row [layers][k] an input position
+                for slot, start, n in p["want_routed"]:
+                    if slot.slot_idx >= 0 and self.slots[slot.slot_idx] is slot:
+                        self._take_routed(slot, np.swapaxes(
+                            p["routed"][:, start:start + n], 0, 1).tolist())
             for slot, upto in p.get("progressed", []):
                 if slot.slot_idx < 0 or self.slots[slot.slot_idx] is not slot:
                     continue
@@ -5664,12 +5938,15 @@ class JaxEngine:
 
     def _process_block(self, lanes: List[tuple], toks: np.ndarray,
                        lps: np.ndarray, tids: np.ndarray,
-                       tlps: np.ndarray):
+                       tlps: np.ndarray, routed: Optional[tuple] = None):
         """Emit a fetched K-step block: per lane, append/emit tokens until a
         stop condition; excess speculated tokens are discarded. Lanes whose
         slot was preempted/released (or re-assigned) meanwhile are skipped —
         their speculated tokens were never emitted, so no client ever sees
-        them."""
+        them. `routed`: a stateful family's (ring of chosen experts [ring,
+        routed layers, lanes, k], each asking lane's first input position
+        in the block): the rows of the inputs whose tokens are emitted ride
+        the block's frame."""
         with self._rec.span("emit"):
             K = toks.shape[0]
             for i, slot_ref in lanes:
@@ -5716,6 +5993,12 @@ class JaxEngine:
                     finish = self._finish_reason(slot, tok)
                     if finish:
                         break
+                if routed is not None and i in routed[1]:
+                    ring, pos0 = routed[0], routed[1][i]
+                    self._take_routed(slot, [
+                        ring[(pos0 + k) % ring.shape[0], :, i].tolist()
+                        for k in range(len(batch))
+                    ])
                 self._emit_tokens(slot, batch, batch_lps, batch_tops)
                 if finish:
                     self._emit_finish(slot, finish)
@@ -5798,12 +6081,23 @@ class JaxEngine:
         if slot.done:
             return
         self._rec.first_token(slot)
+        self._served_among(top, token, lp)
         out = LLMEngineOutput(
             token_ids=[token],
             log_probs=[lp] if (slot.want_logprobs and lp is not None) else None,
             top_logprobs=[top] if top else None,
+            routed_experts=self._routed_rows_for_frame(slot),
         ).to_dict()
         slot.queue.put_nowait(Annotated(data=out).to_dict())
+
+    def _routed_rows_for_frame(self, slot: _Slot) -> Optional[list]:
+        """The rows of chosen experts that wait for this slot's next frame
+        (None for a request that did not ask)."""
+        if not slot.routed_pending:
+            return None
+        rows, slot.routed_pending = slot.routed_pending, []
+        self.routed_rows_emitted += len(rows)
+        return rows
 
     def _emit_tokens(self, slot: _Slot, tokens: List[int],
                      lps: List[float], tops: List[Optional[dict]]):
@@ -5814,10 +6108,13 @@ class JaxEngine:
         if slot.done or not tokens:
             return
         self._rec.first_token(slot)
+        for top, token, lp in zip(tops, tokens, lps):
+            self._served_among(top, token, lp)
         out = LLMEngineOutput(
             token_ids=tokens,
             log_probs=lps if (slot.want_logprobs and lps) else None,
             top_logprobs=tops if any(tops) else None,
+            routed_experts=self._routed_rows_for_frame(slot),
         ).to_dict()
         slot.queue.put_nowait(Annotated(data=out).to_dict())
         self.emit_batches += 1
@@ -5972,9 +6269,8 @@ class JaxEngine:
 
 
 def _resolve_model(name: str) -> llama.LlamaConfig:
-    from ..models import moe
-
     registry = {
+        "tiny-hybrid": hybrid.HybridConfig.tiny_hybrid,
         "tiny": llama.LlamaConfig.tiny,
         "llama3-3b": llama.LlamaConfig.llama3_2_3b,
         "llama3-8b": llama.LlamaConfig.llama3_8b,

@@ -53,6 +53,21 @@ def alloc_kv_arrays(
     return kv_k, kv_v
 
 
+def alloc_state_cache(model_cfg, num_pages: int, page_size: int,
+                      max_seqs: int, max_tokens: int, row_slots: int = 0):
+    """(K store, V store) of a family that keeps a recurrent state beside
+    its pages (models/hybrid.py): the K store is an ops/state_cache.
+    StateCache, which holds the K pages of the layers that attend, the
+    state store `[linear layers, max_seqs + 1, ...]` indexed by LANE (the
+    last slot scratch) and what a dispatch says of its rows; the V store is
+    a plain pool. docs/hybrid_models.md."""
+    from ..ops import state_cache
+
+    return state_cache.alloc_state_cache(
+        model_cfg, num_pages, page_size, max_seqs, max_tokens, row_slots
+    )
+
+
 @dataclass
 class _CachedPage:
     page_id: int

@@ -17,7 +17,8 @@ One table for the engine's loop, exported through `JaxEngine.stats()`:
   interval of SLOW_SPAN_S or more is a stall of the pipeline and goes to
   `step_stalled_count`, `step_stalled_s` instead), and the work it was
   asked for, `step_model_flops` and `step_min_bytes`
-  (models/<family>.step_work).
+  (models/<family>.step_work), and of the bytes a recurrent state's,
+  `step_state_bytes` (exported once it is not 0: models/hybrid.py).
 * the waits ahead of a first token: `req_admitted`, `req_queue_wait_s`,
   `req_first_tokens`, `req_admit_to_first_s`.
 * the device calls' host clock by tag (`dispatch_<tag>_count`, `_s`:
@@ -79,10 +80,11 @@ class Work:
     row as models/<family>.step_work takes it: real tokens, positions
     attended, positions whose K and V are read, tokens sampled."""
 
-    __slots__ = ("real", "context", "kv_tokens", "sampled")
+    __slots__ = ("real", "context", "kv_tokens", "sampled", "rows")
 
     def __init__(self):
         self.real = self.context = self.kv_tokens = self.sampled = 0
+        self.rows = 0
 
     def chunk(self, start: int, n: int, completes: bool):
         """A prompt's chunk of `n` tokens behind `start`: token j attends
@@ -92,6 +94,7 @@ class Work:
         self.context += n * start + n * (n + 1) // 2
         self.kv_tokens += start + n
         self.sampled += bool(completes)
+        self.rows += 1
 
     def decode(self, seq_len: int):
         """A decode row at a context of `seq_len`, its own token in it."""
@@ -99,10 +102,12 @@ class Work:
         self.context += seq_len
         self.kv_tokens += seq_len
         self.sampled += 1
+        self.rows += 1
 
     def of(self, step_work) -> tuple:
         return step_work(self.real, self.context, 1,
-                         kv_tokens=self.kv_tokens, sampled=self.sampled)
+                         kv_tokens=self.kv_tokens, sampled=self.sampled,
+                         rows=self.rows)
 
 
 class Recorder:
@@ -117,6 +122,7 @@ class Recorder:
         self.stalled = [0, 0.0]  # the same of entries whose interval stalled
         self.model_flops = 0
         self.min_bytes = 0
+        self.state_bytes = 0  # of min_bytes, a recurrent state's (hybrid)
         # (count, seconds) of the host's clock around a device call, by tag
         self.dev_time: Dict[str, tuple] = {}
         self.req_admitted = 0
@@ -152,11 +158,15 @@ class Recorder:
 
     def dispatched(self, entry: dict, kind: str, work: tuple):
         """Stamp `entry` as it goes to the device: its kind, the host's
-        clock, and the (useful operations, least bytes) it was asked for."""
+        clock, and the (useful operations, least bytes) it was asked for; a
+        family with a recurrent state says third how many of the bytes are
+        the state's (models/hybrid.step_work)."""
         entry["step_kind"] = kind
         entry["t_dispatch"] = time.perf_counter()
         self.model_flops += work[0]
         self.min_bytes += work[1]
+        if len(work) > 2:
+            self.state_bytes += work[2]
 
     def fetched(self, entries: List[dict], t_ready: float):
         """The fetch that brought these entries back returned at `t_ready`:
@@ -225,4 +235,6 @@ class Recorder:
         for tag, (cnt, tot) in list(self.dev_time.items()):
             out[f"dispatch_{tag}_count"] = cnt
             out[f"dispatch_{tag}_s"] = round(tot, 3)
+        if self.state_bytes:
+            out["step_state_bytes"] = float(self.state_bytes)
         return out

@@ -265,6 +265,13 @@ async def main():
         spmd=spmd,
         multihost=multihost,
     )
+    if args.role != "aggregated" and engine.stateful:
+        # (--role prefill is the engine's own refusal; the decode role's
+        # requests would each be refused at its entries)
+        raise SystemExit(
+            f"{engine.STATE_FAMILY} cannot run the disaggregated hand-off "
+            f"(--role {args.role}): injected pages bring no state for the lane"
+        )
     # guided decoding compiles token FSMs against the SERVED vocabulary:
     # GGUF checkpoints carry their own; everything else uses the byte
     # tokenizer the model card advertises (llm/guided.py)

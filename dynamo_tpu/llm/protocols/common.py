@@ -181,6 +181,11 @@ class LLMEngineOutput:
     # set by the detokenizer backend when the request asked for logprobs:
     # [{"token": <delta text>, "logprob": f}] aligned with token_ids
     logprob_entries: Optional[List[Dict[str, Any]]] = None
+    # for a request annotated `routed_experts`, by an engine whose family
+    # records its routing (models/hybrid.py): one row [routed layers][k] of
+    # expert ids (under the router's full width) for each input position
+    # whose output this emission carries; the prompt's rows with the first
+    routed_experts: Optional[List[Any]] = None
 
     def to_dict(self) -> dict:
         d: Dict[str, Any] = {"token_ids": self.token_ids}
@@ -196,6 +201,7 @@ class LLMEngineOutput:
             "tool_calls",
             "reasoning_content",
             "logprob_entries",
+            "routed_experts",
         ):
             v = getattr(self, k)
             if v is not None:

@@ -163,7 +163,8 @@ def step_work(c: LlamaConfig, real_tokens: int, context_tokens: int,
               weight_bytes: Optional[float] = None,
               kv_bytes: Optional[float] = None,
               layer_params: Optional[int] = None,
-              layer_bytes: Optional[float] = None):
+              layer_bytes: Optional[float] = None,
+              rows: Optional[int] = None):
     """(useful operations, least HBM bytes) of one pipeline entry: host
     arithmetic for the engine's counters (`step_model_flops`,
     `step_min_bytes`), from shapes the host holds at dispatch. Floors of
@@ -189,7 +190,9 @@ def step_work(c: LlamaConfig, real_tokens: int, context_tokens: int,
 
     `layer_params`, `layer_bytes`: a routed family's own count of one
     layer's parameters a token passes through and bytes a pass reads
-    (models/moe.step_work); the dense layer's otherwise."""
+    (models/moe.step_work); the dense layer's otherwise. `rows`: the (row,
+    pass) pairs of the entry, which only a family that keeps a state per
+    row reads (models/hybrid.step_work)."""
     itemsize = jnp.dtype(c.dtype).itemsize
     wb = itemsize if weight_bytes is None else weight_bytes
     if kv_bytes is None:

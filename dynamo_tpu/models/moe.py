@@ -209,13 +209,16 @@ def _tile(n: int) -> int:
     return next(t for t in (1024, 512, 256, 128) if n % t == 0)
 
 
-def _grouped_matmul(lhs: jax.Array, w: ExpertStack, group_sizes: jax.Array):
+def _grouped_matmul(lhs: jax.Array, w: ExpertStack, group_sizes: jax.Array,
+                    rows: int = _GMM_ROWS):
     """lhs [M, k] (rows sorted by group) x rhs [G, k, n] -> [M, n] f32,
     rhs the whole stack of `w` as [L*E, k, n]: the first group_sizes[0]
     rows against rhs[0], the next against rhs[1], and so on; an empty
     group costs nothing. Rows past sum(group_sizes) are left unwritten.
     The Pallas grouped matmul where its tiles fit (a TPU, 128-aligned
-    widths), else XLA's ragged_dot."""
+    widths), else XLA's ragged_dot. `rows`: the kernel's row tile (a
+    family whose decode step takes this road with a handful of rows an
+    expert asks for a smaller one: models/hybrid.py)."""
     rhs = w.stack.reshape(-1, *w.stack.shape[2:])
     m, k = lhs.shape
     n = rhs.shape[-1]
@@ -223,10 +226,10 @@ def _grouped_matmul(lhs: jax.Array, w: ExpertStack, group_sizes: jax.Array):
         from jax.experimental.pallas.ops.tpu.megablox import gmm
 
         # whole row tiles: the rows added lie past every group
-        lhs = jnp.pad(lhs, ((0, -m % _GMM_ROWS), (0, 0)))
+        lhs = jnp.pad(lhs, ((0, -m % rows), (0, 0)))
         return gmm(
             lhs, rhs, group_sizes, preferred_element_type=jnp.float32,
-            tiling=(_GMM_ROWS, _tile(k), _tile(n)),
+            tiling=(rows, _tile(k), _tile(n)),
         )[:m]
     return jax.lax.ragged_dot(
         lhs, rhs, group_sizes, preferred_element_type=jnp.float32

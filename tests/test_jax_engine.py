@@ -316,6 +316,43 @@ def test_greedy_logprobs_match_full_recompute(params):
     assert all("log_probs" not in o for o in asyncio.run(plain()))
 
 
+def test_a_sampled_token_is_among_its_top_entries(params):
+    """A top entry names the served token: where a hot sampler takes one
+    outside the n most likely, the last of them gives way to it and to its
+    own log-probability (first token and decode blocks alike)."""
+    async def main():
+        cfg = EngineConfig(
+            model="tiny", max_num_seqs=4, page_size=PAGE, num_pages=64,
+            max_model_len=128, prefill_buckets=(16, 32),
+        )
+        eng = JaxEngine(cfg, model_config=CFG, params=params)
+        req = PreprocessedRequest(
+            token_ids=[5, 9, 17, 33, 101, 7, 250, 3],
+            stop_conditions={"max_tokens": 24, "ignore_eos": True},
+            sampling_options={"logprobs": True, "top_logprobs": 2,
+                              "temperature": 8.0, "seed": 11},
+            request_id="hot",
+        ).to_dict()
+        toks, lps, tops = [], [], []
+        async for item in eng.generate(req, Context()):
+            data = item.get("data")
+            if data:
+                toks.extend(data["token_ids"])
+                lps.extend(data.get("log_probs") or [])
+                tops.extend(data.get("top_logprobs") or [])
+        await eng.close()
+        return toks, lps, tops
+
+    toks, lps, tops = asyncio.run(main())
+    assert len(toks) == len(lps) == len(tops) == 24
+    gave_way = 0
+    for tok, lp, top in zip(toks, lps, tops):
+        assert len(set(top["ids"])) == 2 and tok in top["ids"], (tok, top)
+        assert top["logprobs"][top["ids"].index(tok)] == pytest.approx(lp, abs=1e-5)
+        gave_way += top["ids"][-1] == tok and top["logprobs"][0] > lp
+    assert gave_way, "the sampler never left the two most likely: raise the heat"
+
+
 def test_penalties_match_naive_oracle(params):
     """Greedy + penalties through the engine == naive full-recompute with
     apply_logit_penalties at every step (the penalties actually bite:
