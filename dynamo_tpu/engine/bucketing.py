@@ -94,10 +94,12 @@ def mixed_row_bucket(config) -> int:
     """The mixed step's ONE row bucket, from the configuration alone: every
     decode lane's rows (1 + spec_draft_len verify rows under spec) and a
     prefill batch of chunks, rounded up to whole sublanes. One bucket, so
-    the row axis adds no program; and no power of two, because where the
-    ragged kernel is Pallas every row of the bucket, packed or not, is a q
-    tile of the kernel's grid (models/llama.py:_tiled_layout: some 0.25 ms
-    a step and tile at 16 layers, PERF.md, PR 40)."""
+    the row axis adds no program; and no power of two, because where
+    attention is the Pallas kernels every row of the bucket, packed or
+    not, is a grid step of the decode kernel (an empty one copies and
+    multiplies nothing) and a row of the tables both kernels prefetch. The
+    ragged kernel's q tiles follow the prefill batch alone
+    (ops/paged_attention.ragged_tiles)."""
     rows = config.max_num_seqs * (
         1 + (config.spec_draft_len if config.spec_mode else 0)
     ) + config.max_prefill_batch

@@ -313,11 +313,32 @@ COMPILE_SURFACES = {
         "donate": (),
         "static": ("interpret",),
         "axes": {
-            "B": "caller lane count",
+            "B": "caller lane count: decode_block's lanes, or a mixed "
+                 "step's row bucket (its one-token rows; every other row "
+                 "an empty lane)",
             "pages": "caller page-table bucket",
         },
         "warmup": True,
         "help": "paged flash decode attention over the scattered pool",
+    },
+    "ragged_attention_kernels": {
+        "module": "dynamo_tpu/ops/paged_attention.py",
+        "kind": "jit",
+        "donate": (),
+        "static": ("tile", "long_rows"),
+        "axes": {
+            "M": "mixed_step's token bucket",
+            "R": "mixed_row_bucket(config)",
+            "tile": "ragged_tile_q(dtype)",
+            "long_rows": "config.max_prefill_batch",
+        },
+        "warmup": True,
+        "help": "a mixed step's attention call where the gate resolves to "
+                "the Pallas kernels: one-token rows to "
+                "paged_attention_decode_pallas, the rest to "
+                "ragged_paged_attention_pallas on the q-tile layout; a jit "
+                "of its own so that a step's layers trace and lower it "
+                "once",
     },
     "ragged_paged_attention_pallas": {
         "module": "dynamo_tpu/ops/pallas_ragged_attention.py",
@@ -325,14 +346,18 @@ COMPILE_SURFACES = {
         "donate": (),
         "static": ("interpret",),
         "axes": {
-            "N": "mixed_step's token bucket M laid out to the q tile: "
-                 "M + (ragged_tile_q(dtype) - 1) * R, rounded up to the "
-                 "tile (llama._tiled_layout)",
-            "tiles": "N / ragged_tile_q(dtype)",
+            "N": "tiles * ragged_tile_q(dtype): mixed_step's token bucket "
+                 "M with the rows of more than one token laid out to the "
+                 "q tile (paged_attention._tiled_layout)",
+            "tiles": "paged_attention.ragged_tiles: (M + "
+                     "(ragged_tile_q(dtype) - 1) * config.max_prefill_batch)"
+                     " / ragged_tile_q(dtype), rounded up",
         },
         "warmup": True,
-        "help": "ragged paged attention over mixed prefill+decode token "
-                "rows",
+        "help": "ragged paged attention over a mixed step's rows of more "
+                "than one token (its one-token rows are lanes of "
+                "paged_attention_decode_pallas, which mixed_step holds "
+                "beside it)",
     },
     "paged_prefill_attention_pallas_batched": {
         "module": "dynamo_tpu/ops/pallas_prefill_attention.py",

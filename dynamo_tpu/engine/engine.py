@@ -48,6 +48,7 @@ from ..llm.protocols import Annotated, LLMEngineOutput, PreprocessedRequest
 from ..llm.tokens import TokenBlockSequence, compute_seq_hashes, salt_hash
 from ..models import hybrid, llama, moe
 from ..models.quant import is_quant
+from ..ops.paged_attention import ragged_tiles
 from ..ops.state_cache import state_bytes_per_lane
 from ..native import native_available
 from ..runtime import faults
@@ -546,6 +547,7 @@ class JaxEngine:
         from ..ops.paged_attention import (
             attention_scope,
             mesh_allows_kernels,
+            ragged_tile,
             resolved_attention,
         )
 
@@ -557,6 +559,9 @@ class JaxEngine:
             self.attention_impl = resolved_attention(
                 c.head_dim, c.num_kv_heads, kvq != "none"
             )
+            # the ragged kernel's q tile, 1 on the XLA path: what the
+            # mixed_attn_* counts of _dispatch_mixed are reckoned in
+            self._ragged_tile = ragged_tile(c.dtype, c.head_dim, kvq != "none")
         self.device = device_info()
         logger.info(
             "engine device %s; attention %s (mesh %s, kv_quant %s)",
@@ -841,6 +846,13 @@ class JaxEngine:
         self.split_steps = 0
         self.mixed_padded_tokens = 0
         self.mixed_real_tokens = 0
+        # where the mixed step's attention is the two Pallas kernels
+        # (ops/paged_attention.ragged_attention): q tiles the ragged grid
+        # launched, those of them that hold a real q row, and the
+        # one-token rows that went to the decode kernel; 0 on the XLA path
+        self.mixed_attn_tiles = 0
+        self.mixed_attn_tiles_real = 0
+        self.mixed_rows_decode_kernel = 0
         self.split_padded_tokens = 0
         self.split_real_tokens = 0
         # per-kind fused coverage (docs/observability.md): which row
@@ -1225,6 +1237,7 @@ class JaxEngine:
             logits, kv_k, kv_v = self._model.ragged_forward(
                 params, c, tokens, positions, row_ids, kv_k, kv_v,
                 page_tables, row_starts, row_lens, ctx_lens, last_flat,
+                long_rows=cfg.max_prefill_batch,
             )
             plogits = penalized(logits, samp, pen_rows)
             # the sampled token's position counter: the row's last real
@@ -1260,7 +1273,7 @@ class JaxEngine:
             logits, kv_k, kv_v = self._model.ragged_forward(
                 params, c, tokens, positions, row_ids, kv_k, kv_v,
                 page_tables, row_starts, row_lens, ctx_lens, last_flat,
-                lora=lora,
+                lora=lora, long_rows=cfg.max_prefill_batch,
             )
             plogits = penalized(logits, samp, pen_rows)
             mask = unpack_mask(mask_packed, c.vocab_size)
@@ -2643,6 +2656,10 @@ class JaxEngine:
         # the slots of the token buckets they ran in
         out["mixed_real_tokens"] = self.mixed_real_tokens
         out["mixed_padded_tokens"] = self.mixed_padded_tokens
+        # ... and what their attention launched (0 on the XLA path)
+        out["mixed_attn_tiles"] = self.mixed_attn_tiles
+        out["mixed_attn_tiles_real"] = self.mixed_attn_tiles_real
+        out["mixed_rows_decode_kernel"] = self.mixed_rows_decode_kernel
         # ... and the split pair that served a mixed-shaped step
         out["split_real_tokens"] = self.split_real_tokens
         out["split_padded_tokens"] = self.split_padded_tokens
@@ -4930,8 +4947,9 @@ class JaxEngine:
         operands), and one table width under the Pallas ragged kernel
         (pow2 rungs on the XLA reference path). The flat buffer is
         compact, rows back to back: a bucket counts real tokens, and the
-        q-tile layout the Pallas ragged kernel needs is the forward's
-        own, for q alone (models/llama.py:ragged_forward)."""
+        q-tile layout the Pallas ragged kernel needs is the attention
+        call's own, for q alone and for the rows of more than one token
+        (ops/paged_attention.py:ragged_attention)."""
         cfg = self.config
         with self._rec.span("pack", more=True):
             self._mixed_wait_drain = False
@@ -5283,6 +5301,16 @@ class JaxEngine:
         self.mixed_steps += 1
         self.mixed_padded_tokens += N_pad
         self.mixed_real_tokens += real
+        tile = self._ragged_tile
+        if tile > 1:
+            chunks = [ch for _, ch, _ in meta]
+            self.mixed_attn_tiles += ragged_tiles(
+                N_pad, len(row_lens), tile, cfg.max_prefill_batch
+            )
+            self.mixed_attn_tiles_real += sum(
+                -(-ch // tile) for ch in chunks if ch > 1
+            )
+            self.mixed_rows_decode_kernel += n_rows_decode + chunks.count(1)
         self._count_expert_rows(N_pad, real)
         self._step_counter += 1
         return True

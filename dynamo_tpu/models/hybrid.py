@@ -38,12 +38,12 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops.kv_quant import is_quant_kv, kv_layer, kv_page_size, kv_write
+from ..ops.kv_quant import kv_layer, kv_page_size, kv_write
 from ..ops.paged_attention import (
     paged_attention_decode,
     prefill_attention_batched,
     ragged_attention,
-    ragged_tile,
+    rows_at,
 )
 from ..ops.state_cache import StateCache, conv_channels, state_bytes_per_lane
 from . import llama, moe
@@ -631,7 +631,7 @@ def _flat_linear_fn(c: HybridConfig, lanes, row_ids, row_starts, row_lens,
         for back in range(1, taps):
             before = jnp.where(
                 (t >= back)[:, None],
-                llama._rows_at(mixed, jnp.maximum(slot - back, 0)),
+                rows_at(mixed, jnp.maximum(slot - back, 0)),
                 tails[row_ids, jnp.clip(taps - 1 + t - back, 0, taps - 2)],
             )
             y = y + before.astype(f32) * w[:, taps - 1 - back]
@@ -639,7 +639,7 @@ def _flat_linear_fn(c: HybridConfig, lanes, row_ids, row_starts, row_lens,
         end = row_lens[:, None] - (taps - 1) + jnp.arange(taps - 1)  # [R, 3]
         new_tails = jnp.where(
             (end >= 0)[..., None],
-            llama._rows_at(mixed, row_starts[:, None] + jnp.maximum(end, 0)),
+            rows_at(mixed, row_starts[:, None] + jnp.maximum(end, 0)),
             jnp.take_along_axis(
                 tails, jnp.clip(end + taps - 1, 0, taps - 2)[..., None],
                 axis=1),
@@ -653,7 +653,7 @@ def _flat_linear_fn(c: HybridConfig, lanes, row_ids, row_starts, row_lens,
         def at_slots(at):
             """q, k, v, g, beta at flat slots `at` (slot M: a zero row,
             whose beta and g of 0 leave a state as it was)."""
-            return tuple(llama._rows_at(a, at) for a in (q, k, v, g, beta))
+            return tuple(rows_at(a, at) for a in (q, k, v, g, beta))
 
         # pass one: every row's first token, the step form
         first = jnp.where(row_lens > 0, row_starts, M)
@@ -708,11 +708,15 @@ def ragged_forward(
     ctx_lens: jax.Array,  # [R]
     last_flat: jax.Array,  # [R]
     lora=None,
+    long_rows: Optional[int] = None,
 ) -> Tuple[jax.Array, StateCache, jax.Array]:
     """The mixed step's forward over a compact flat buffer (see
     models/llama.py:ragged_forward): rows of one token go through the same
     chunked recurrence as a prompt's chunk, each from its own lane's
-    state. Returns (logits of each row's last token [R, vocab], cache,
+    state. `long_rows`: the rows of more than one token a pack holds at
+    most, for the ragged kernel's grid (None: any row may) and for the
+    recurrence's chunked pass (None: the rows that the lanes' decode rows
+    leave of R). Returns (logits of each row's last token [R, vocab], cache,
     kv_v)."""
     _refuse(lora)
     c = config
@@ -729,23 +733,13 @@ def ragged_forward(
     offs = positions % page_size
     valid = jnp.arange(M, dtype=jnp.int32) < row_lens.sum()
 
-    tile = ragged_tile(c.dtype, c.head_dim, is_quant_kv(kv_k.pages))
-    attn_starts = row_starts
-    if tile > 1:
-        attn_starts, to_tiled, from_tiled = llama._tiled_layout(
-            tile, row_ids, row_starts, row_lens)
-
     def full_fn(layer, h, pages, kv_v, lf):
         q, k, v, gate = _qkv_gate(layer, h, positions, c)
         pages = kv_write(pages, lf, phys, offs, k)
         kv_v = kv_write(kv_v, lf, phys, offs, v)
-        if tile > 1:
-            q = llama._rows_at(q, from_tiled)
         attn = ragged_attention(
             q, kv_layer(pages, lf), kv_layer(kv_v, lf), page_tables,
-            attn_starts, row_lens, ctx_lens)
-        if tile > 1:
-            attn = llama._rows_at(attn, to_tiled)
+            row_starts, row_lens, ctx_lens, long_rows=long_rows)
         return _gated_out(layer, attn, gate, c), pages, kv_v
 
     x, cache, kv_v, chosen = _layer_stack(
@@ -753,7 +747,8 @@ def ragged_forward(
         _flat_linear_fn(
             c, lanes, row_ids, row_starts, row_lens, ctx_lens,
             # a mixed step's rows: a decode row a lane and a prefill batch
-            long_rows=max(R - kv_k.scratch_lane, 1)),
+            long_rows if long_rows is not None
+            else max(R - kv_k.scratch_lane, 1)),
         full_fn, valid)
     flat = _note_chosen(cache.routed_flat, chosen)
     return _head(params, c, x[last_flat]), cache.replace(routed_flat=flat), kv_v

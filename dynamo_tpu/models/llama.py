@@ -29,13 +29,12 @@ import jax
 import jax.numpy as jnp
 
 from .quant import embed_rows, head_leaf, qdot
-from ..ops.kv_quant import is_quant_kv, kv_layer, kv_page_size, kv_write
+from ..ops.kv_quant import kv_layer, kv_page_size, kv_write
 from ..ops.paged_attention import (
     paged_attention_decode,
     prefill_attention,
     prefill_attention_batched,
     ragged_attention,
-    ragged_tile,
 )
 from ..parallel.mesh import PP_AXIS, SP_AXIS
 
@@ -398,39 +397,6 @@ def prefill_forward_batched(
         return logits, kv_k, kv_v
 
 
-def _tiled_layout(tile: int, row_ids, row_starts, row_lens):
-    """The flat axis the ragged kernel wants, beside the compact one the
-    step is packed on: every row starts on a multiple of `tile`, rows in
-    the same order, empty rows right behind the last real one (so the
-    kernel's tail tiles belong to a row of no context). Its length is
-    static, N = M + (tile - 1) * R rounded up to the tile: R rows lose at
-    most tile - 1 slots each, so every pack of the bucket fits and a
-    token bucket still names one program. Returns (starts [R] on the
-    tiled axis, to_tiled [M]: each compact slot's tiled slot, N for
-    padding; from_tiled [N]: each tiled slot's compact slot, M where the
-    slot holds no token). Built once a step; the layers gather through
-    the two indices, and an out-of-range index reads a zero row."""
-    M, R = row_ids.shape[0], row_lens.shape[0]
-    N = -(-(M + (tile - 1) * R) // tile) * tile
-    spans = -(-row_lens // tile) * tile
-    starts = jnp.cumsum(spans) - spans
-    slot = jnp.arange(M, dtype=jnp.int32)
-    to_tiled = jnp.where(
-        slot < row_lens.sum(),
-        starts[row_ids] + slot - row_starts[row_ids],
-        N,
-    )
-    from_tiled = jnp.full((N,), M, jnp.int32).at[to_tiled].set(
-        slot, mode="drop"
-    )
-    return starts, to_tiled, from_tiled
-
-
-def _rows_at(x: jax.Array, idx: jax.Array) -> jax.Array:
-    """x[idx] along axis 0, a zero row where idx is out of range."""
-    return jnp.take(x, idx, axis=0, mode="fill", fill_value=0)
-
-
 def ragged_forward(
     params: Dict[str, Any],
     config: LlamaConfig,
@@ -446,6 +412,7 @@ def ragged_forward(
     last_flat: jax.Array,  # [R] flat index of each row's LAST real token
     mlp_fn=None,
     lora=None,  # models/lora.py stack + PER-ROW idx (fused multi-LoRA)
+    long_rows: Optional[int] = None,  # static: rows of > 1 token, at most
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """The unified mixed-step forward: ONE pass over a flat ragged token
     buffer that packs prefill chunks (row_len > 1) and decode slots
@@ -459,12 +426,12 @@ def ragged_forward(
 
     The flat axis is COMPACT: rows back to back from slot 0, the bucket's
     padding behind them, so every dense layer multiplies real rows and
-    the tail alone. Where the attention gate resolves to the Pallas
-    kernel, whose q tiles may not straddle rows (`ragged_tile` > 1), q
-    alone is laid out tile-aligned for the call and the kernel's output
-    is brought back before `wo` (`_tiled_layout`); K and V reach the
-    kernel through the pool. Where it resolves to the XLA reference the
-    tile is 1 and attention takes the flat axis as it is.
+    the tail alone, and attention takes that axis as it is: what layout
+    its kernels want of q is `ragged_attention`'s own, behind its gate; K
+    and V reach them through the pool. `long_rows` is the engine's
+    promise of how many rows hold more than one token at most (its
+    `max_prefill_batch`; None: any row may), which sizes the ragged
+    kernel's grid.
 
     `lora`: the engine's stacked adapter pair with `idx` a PER-ROW [R]
     adapter index; base rows carry index 0 (the all-zero adapter — an
@@ -487,13 +454,6 @@ def ragged_forward(
     phys = jnp.take_along_axis(tab_tok, logical[:, None], axis=1)[:, 0]
     phys = jnp.where(positions < P_tab * page_size, phys, 0)
     offs = positions % page_size
-
-    tile = ragged_tile(c.dtype, c.head_dim, is_quant_kv(kv_k))
-    attn_starts = row_starts
-    if tile > 1:
-        attn_starts, to_tiled, from_tiled = _tiled_layout(
-            tile, row_ids, row_starts, row_lens
-        )
 
     from . import lora as lora_mod
 
@@ -518,14 +478,10 @@ def ragged_forward(
         with jax.named_scope("attention"):
             kv_k = kv_write(kv_k, li, phys, offs, k)
             kv_v = kv_write(kv_v, li, phys, offs, v)
-            if tile > 1:
-                q = _rows_at(q, from_tiled)  # [N, H, D]
             attn = ragged_attention(
                 q, kv_layer(kv_k, li), kv_layer(kv_v, li), page_tables,
-                attn_starts, row_lens, ctx_lens
+                row_starts, row_lens, ctx_lens, long_rows=long_rows,
             )
-            if tile > 1:
-                attn = _rows_at(attn, to_tiled)  # [M, H, D]
             attn = attn.reshape(-1, c.num_heads * c.head_dim)
         with jax.named_scope("o_proj"):
             x = x + lora_mod.proj(attn, layer["wo"], qdot, ll, "wo").astype(c.dtype)

@@ -468,6 +468,7 @@ def ragged_forward(
     ctx_lens: jax.Array,  # [R]
     last_flat: jax.Array,  # [R]
     lora=None,
+    long_rows: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Unified mixed-step forward (engine `_dispatch_mixed`), MoE MLP —
     the flat buffer is already [tokens, H], exactly the shape expert
@@ -479,6 +480,7 @@ def ragged_forward(
         _whole_expert_stacks(params), config, tokens, positions, row_ids,
         kv_k, kv_v, page_tables, row_starts, row_lens, ctx_lens, last_flat,
         mlp_fn=functools.partial(moe_mlp, valid=valid), lora=lora,
+        long_rows=long_rows,
     )
 
 

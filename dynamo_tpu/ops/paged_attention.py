@@ -2,8 +2,9 @@
 
 The role of the reference's engine attention kernels + block_copy.cu, done
 the TPU way: static-shaped gathers + einsums that XLA fuses well on the MXU,
-with a Pallas decode kernel (ops/pallas_paged_attention.py) swapped in on
-TPU for the HBM-bound gather.
+with the Pallas kernels (ops/pallas_*.py) swapped in on TPU behind ONE gate
+(`_pallas_eligible`); what layout a kernel wants of its operands is built
+here, behind that gate, not in the models.
 
 Layouts:
   kv_k_layer / kv_v_layer: ops/kv_quant.KVLayer — the WHOLE lane-dense
@@ -15,6 +16,7 @@ Layouts:
 from __future__ import annotations
 
 import contextlib
+import functools
 import contextvars
 import os
 from typing import Optional
@@ -244,6 +246,16 @@ def paged_attention_decode(
     return out.reshape(B, H, D)
 
 
+def _owning_rows(slots: jax.Array, row_starts: jax.Array) -> jax.Array:
+    """The owning row of each flat slot: the last row whose start is at or
+    before it (starts ascending; padding slots fold into the nearest
+    preceding row, where the callers mask them to nothing)."""
+    return jnp.clip(
+        jnp.sum(slots[:, None] >= row_starts[None, :], axis=1) - 1,
+        0, row_starts.shape[0] - 1,
+    )
+
+
 def ragged_attention_reference(
     q: jax.Array,  # [N, H, D] flat packed tokens (rope applied)
     kv_k_layer: KVLayer,  # whole pool + layer index (kv_quant.kv_layer)
@@ -266,11 +278,7 @@ def ragged_attention_reference(
     page_size, KH = layer_dims(kv_k_layer, D)
     S = P * page_size
     idx = jnp.arange(N)
-    # owning row per token: the last row whose start <= idx (padding
-    # tokens fold into the nearest preceding row and mask to nothing)
-    row_ids = jnp.clip(
-        jnp.sum(idx[:, None] >= row_starts[None, :], axis=1) - 1, 0, R - 1
-    )
+    row_ids = _owning_rows(idx, row_starts)
     local = idx - row_starts[row_ids]
     positions = ctx_lens[row_ids] + local
     totals = ctx_lens[row_ids] + row_lens[row_ids]
@@ -297,42 +305,140 @@ def ragged_attention_reference(
     return out.reshape(N, H, D)
 
 
+def ragged_tiles(tokens: int, rows: int, tile: int, long_rows=None) -> int:
+    """Q tiles of the ragged kernel's grid for a pack of `tokens` compact
+    slots over `rows` rows of which at most `long_rows` (every row when
+    None) hold more than one token: each such row loses at most tile - 1
+    slots to its alignment, so any pack of the bucket fits. Static: the
+    engine counts its launched tiles by this, tests/test_tpu_compile.py
+    the grid."""
+    long_rows = rows if long_rows is None else min(long_rows, rows)
+    return -(-(tokens + (tile - 1) * long_rows) // tile)
+
+
+def rows_at(x: jax.Array, idx: jax.Array) -> jax.Array:
+    """x[idx] along axis 0, a zero row where idx is out of range."""
+    return jnp.take(x, idx, axis=0, mode="fill", fill_value=0)
+
+
+def _tiled_layout(tile: int, tiles: int, slots: int, row_starts, row_lens):
+    """The flat axis the ragged kernel wants, beside the compact one the
+    step is packed on (`slots` wide): every row of `row_lens` > 0 starts on
+    a multiple of `tile`, rows in the same order, rows of no length where
+    the next row starts (they own no tile, and the kernel's tail tiles
+    belong to the last row, of no length unless the pack is full).
+    `row_lens` are the KERNEL's: the caller has zeroed the rows it serves
+    elsewhere. Returns (starts [R] on the tiled axis, to_tiled [slots]:
+    each compact slot's tiled slot, tiles * tile where it has none;
+    from_tiled [tiles * tile]: each tiled slot's compact slot, `slots`
+    where it holds no token)."""
+    N = tiles * tile
+    spans = -(-row_lens // tile) * tile
+    starts = (jnp.cumsum(spans) - spans).astype(jnp.int32)
+    slot = jnp.arange(slots, dtype=jnp.int32)
+    row_ids = _owning_rows(slot, row_starts)
+    local = slot - row_starts[row_ids]
+    to_tiled = jnp.where(
+        (local >= 0) & (local < row_lens[row_ids]), starts[row_ids] + local, N
+    )
+    from_tiled = jnp.full((N,), slots, jnp.int32).at[to_tiled].set(
+        slot, mode="drop"
+    )
+    return starts, to_tiled, from_tiled
+
+
 def ragged_attention(
-    q: jax.Array,  # [N, H, D]
+    q: jax.Array,  # [M, H, D] on the step's compact axis
     kv_k_layer: KVLayer,  # whole pool + layer index (kv_quant.kv_layer)
     kv_v_layer: KVLayer,
     page_tables: jax.Array,  # [R, max_pages]
-    row_starts: jax.Array,  # [R]
+    row_starts: jax.Array,  # [R] ascending; padding rows sit at M
     row_lens: jax.Array,  # [R]
     ctx_lens: jax.Array,  # [R]
+    long_rows: Optional[int] = None,
 ) -> jax.Array:
     """Ragged mixed prefill+decode attention over paged KV: one call for a
-    flat buffer packing prefill chunks (T>1) and decode slots (T=1).
-    Returns [N, H, D].
+    flat buffer packing prefill chunks (T>1) and decode slots (T=1), rows
+    back to back. Every row's K and V are in its pages already. Returns
+    [M, H, D]; slots of no row return finite garbage or zeros.
 
-    Dispatch: on TPU the Pallas ragged kernel streams only each row's real
-    context pages; elsewhere the XLA reference path gathers the (engine-
-    bounded) tables. The Pallas path additionally requires row starts
-    aligned to `ragged_tile(...)` below — the model's ragged forward lays
-    q out so, between the q projection and this call alone, exactly when
-    this gate says the kernel will run (models/llama.py:ragged_forward)."""
-    if _pallas_eligible(q.shape[-1], is_quant_kv(kv_k_layer.pool)):
-        from .pallas_ragged_attention import ragged_paged_attention_pallas
+    Dispatch, by `ragged_tile` (the module's one gate): where it is 1 the
+    XLA reference gathers the (engine-bounded) tables and takes the flat
+    axis as it is. Where the Pallas kernels run, the call is two kernels,
+    chosen by what the operands say, a row's length:
 
-        return ragged_paged_attention_pallas(
-            q, kv_k_layer, kv_v_layer, page_tables,
-            row_starts, row_lens, ctx_lens,
+      * rows of ONE token (decode lanes, spec verify rows, a prompt's
+        one-token chunk) are lanes of the paged decode kernel: q gathered
+        at `row_starts`, the pack's tables, `seq_lens = ctx_lens + 1` and
+        0 for every other row (an empty lane copies and multiplies
+        nothing);
+      * rows of more tokens alone own q tiles of the ragged kernel
+        (ops/pallas_ragged_attention.py), whose tiles may not straddle
+        rows: q is laid out tile-aligned for the call (`_tiled_layout`) on
+        an axis of `ragged_tiles(M, R, tile, long_rows)` tiles, and a tile
+        that holds no row returns before its first copy.
+
+    `long_rows`: how many rows of more than one token a pack can hold at
+    most (the engine's `max_prefill_batch`; every row when None). It is
+    static and sizes the tiled axis: a pack that breaks it loses rows.
+    Both results come back to the compact axis in one gather."""
+    tile = ragged_tile(q.dtype, q.shape[-1], is_quant_kv(kv_k_layer.pool))
+    if tile == 1:
+        return ragged_attention_reference(
+            q, kv_k_layer, kv_v_layer, page_tables, row_starts, row_lens,
+            ctx_lens,
         )
-    return ragged_attention_reference(
-        q, kv_k_layer, kv_v_layer, page_tables, row_starts, row_lens, ctx_lens
+    return ragged_attention_kernels(
+        q, kv_k_layer, kv_v_layer, page_tables, row_starts, row_lens,
+        ctx_lens, tile=tile, long_rows=long_rows,
     )
 
 
+@functools.partial(jax.jit, static_argnames=("tile", "long_rows"))
+def ragged_attention_kernels(
+    q, kv_k_layer, kv_v_layer, page_tables, row_starts, row_lens, ctx_lens,
+    *, tile: int, long_rows: Optional[int],
+):
+    """`ragged_attention` where the gate has resolved to the Pallas kernels
+    (see there). A jit of its own, so that a step's layers, which call it
+    with the same shapes, are traced and lowered once a program: the
+    layout's index arithmetic and the two kernels' Mosaic bodies are no
+    small part of a start (PERF.md, PR 45). Inlined by XLA, where the
+    layers' copies of the layout fold into one."""
+    from .pallas_paged_attention import paged_attention_decode_pallas
+    from .pallas_ragged_attention import ragged_paged_attention_pallas
+
+    M, R = q.shape[0], row_lens.shape[0]
+    one = row_lens == 1
+    tiled_lens = jnp.where(one, 0, row_lens)
+    tiles = ragged_tiles(M, R, tile, long_rows)
+    starts, to_tiled, from_tiled = _tiled_layout(
+        tile, tiles, M, row_starts, tiled_lens
+    )
+    N = tiles * tile
+    lane_slots = jnp.where(one, row_starts, M).astype(jnp.int32)
+    # one gather out of the compact axis and one back into it
+    q_both = rows_at(q, jnp.concatenate([from_tiled, lane_slots]))
+    tiled = ragged_paged_attention_pallas(
+        q_both[:N], kv_k_layer, kv_v_layer, page_tables, starts, tiled_lens,
+        ctx_lens,
+    )
+    lanes = paged_attention_decode_pallas(
+        q_both[N:], kv_k_layer, kv_v_layer, page_tables,
+        jnp.where(one, ctx_lens + 1, 0),
+    )
+    back = to_tiled.at[lane_slots].set(
+        N + jnp.arange(R, dtype=jnp.int32), mode="drop"
+    )
+    return rows_at(jnp.concatenate([tiled, lanes]), back)
+
+
 def ragged_tile(dtype, head_dim: int, quantized: bool = False) -> int:
-    """What `ragged_attention` needs every row of its flat axis to start
-    on a multiple of, decided at trace time by the same gate: the Pallas
-    ragged kernel's q tile (its tiles may not straddle rows) where the
-    kernel will run, else 1 (the XLA reference takes rows back to back)."""
+    """The gate of `ragged_attention`, decided at trace time: the Pallas
+    ragged kernel's q tile (what it lays every row of more than one token
+    out to: tiles may not straddle rows) where the kernels will run, else
+    1 (the XLA reference takes rows back to back). The engine reckons its
+    `mixed_attn_*` counts in it."""
     if _pallas_eligible(head_dim, quantized):
         from .pallas_ragged_attention import ragged_tile_q
 

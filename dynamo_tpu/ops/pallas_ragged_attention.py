@@ -1,11 +1,18 @@
-"""Pallas TPU kernel: ragged paged-attention over mixed prefill+decode rows.
+"""Pallas TPU kernel: ragged paged-attention over a mixed step's prompt rows.
 
-One kernel for what used to be two dispatches: the engine's mixed step
-(engine/engine.py:_dispatch_mixed) packs the StepPlanner's chosen prefill
-chunks (T > 1) and the active decode lanes (T = 1) into ONE flat token
-buffer, and this kernel runs attention for every row in one grid — the
-"Ragged Paged Attention" shape (PAPERS.md) folding the roles of
-ops/pallas_prefill_attention.py and ops/pallas_paged_attention.py.
+The engine's mixed step (engine/engine.py:_dispatch_mixed) packs the
+StepPlanner's chosen prefill chunks (T > 1) and the active decode lanes
+(T = 1) into ONE flat token buffer, and one call a layer,
+ops/paged_attention.py:ragged_attention, runs attention for every row of it
+(the "Ragged Paged Attention" shape, PAPERS.md). Behind that call's gate
+this kernel owns the rows of MORE than one token: its grid is their q
+tiles. A one-token row (a decode lane, a spec verify row, a prompt's
+one-token chunk) is a lane of the paged decode kernel
+(ops/pallas_paged_attention.py), which does such a row in one grid step
+over all KV heads with the next lane's pages already in flight, where a q
+tile here costs a grid step a KV head, each with its own exposed first
+copy. The caller passes such rows with a length of 0: they own no tile.
+The kernel itself takes any row length, 1 included (the tests run it so).
 
 Layouts (match ops/paged_attention.py and engine/kv_cache.py):
     q:           [N, H, D]  flat packed tokens (rope applied, chunk KV
@@ -14,10 +21,11 @@ Layouts (match ops/paged_attention.py and engine/kv_cache.py):
                  as it lies in HBM, + the layer index as scalar prefetch)
     page_tables: [R, max_pages] int32 (per-row logical -> physical)
     row_starts:  [R] int32 — flat index of row r's first token, ascending,
-                 ALIGNED to the q tile (ragged_tile_q); padding rows sit
-                 at N (they own no tiles)
-    row_lens:    [R] int32 — real tokens in row r (1 for decode rows;
-                 0 for padding rows)
+                 ALIGNED to the q tile (ragged_tile_q); a row of no length
+                 sits where the next row starts, or at the end of the last
+                 row's tiles (it owns no tile)
+    row_lens:    [R] int32 — real tokens in row r (0 for padding rows and
+                 for rows served elsewhere)
     ctx_lens:    [R] int32 — history length before the row's chunk (the
                  absolute position of its token 0)
 
@@ -28,17 +36,24 @@ Design notes:
     because the packer aligns row starts to TQ. Per (tile, kv-head) step
     the kernel streams ONLY that row's real context pages (history +
     chunk, causally bounded per tile) through a double-buffered VMEM
-    window and flash-accumulates, exactly like the prefill kernel; a
-    decode row is simply a one-tile row with ctx = seq_len - 1 and
-    row_len = 1.
+    window and flash-accumulates, exactly like the prefill kernel.
+  * the tiled axis is static (paged_attention.ragged_tiles: the token
+    bucket and TQ - 1 slots for each row of a prefill batch), so its tail
+    holds tiles of no row. The map gives them to the last row whose start
+    they follow, and such a tile (its first in-row offset is not under the
+    row's length, both in SMEM) returns before its first copy: no DMA, no
+    multiply, and an out block that is never written and never gathered.
+    The grid's cost follows the prompt rows.
   * per-head DMA: each step fetches only kv-head k0's D-wide column slice
     of a page, so total HBM bytes equal one pass over the real context.
   * q tiles are pre-arranged [num_tiles, KH, TQ, G*D] by the wrapper; the
     G query heads of the group are static column slices (no Mosaic
     reshapes of minor dims).
   * masking: a q row is real iff its in-row offset < row_len; keys are
-    valid iff key_pos <= q_pos and key_pos < ctx + row_len. Rows that are
-    pure padding produce finite garbage (discarded by the caller).
+    valid iff key_pos <= q_pos and key_pos < ctx + row_len. The padding
+    behind a row's last token in its last tile comes out as finite
+    garbage, a tile of no row as whatever its out block held: the caller
+    reads real slots only.
   * REQUIRES head_dim % 128 == 0 (the per-head DMA slices the flattened
     KH*D lane dim in head_dim-wide columns) — the dispatcher
     (ops/paged_attention.py:_pallas_eligible) falls back to
@@ -111,35 +126,33 @@ def _ragged_kernel(
     limit = jnp.minimum(total_len, ctx + local0 + tq)
     n_chunks = pl.cdiv(jnp.maximum(limit, 1), chunk)
 
-    def start_chunk(ci, slot):
-        for p in range(chunk_pages):
+    def chunk_copies(ci, slot, wait: bool):
+        """Start (or wait for) the K and V copies of chunk ci's pages into
+        buffer `slot`: a loop over the pages, not its unrolling, because a
+        start lowers this body for every program that holds the kernel
+        (eight pages a chunk, three call sites: PERF.md, PR 45)."""
+        def page(p, carry):
             lp = jnp.minimum(ci * chunk_pages + p, max_pages - 1)
             phys = jnp.minimum(pt_ref[r, lp], num_phys - 1)
-            pltpu.make_async_copy(
-                kv_k_hbm.at[li, phys, :, pl.ds(k0 * d, d)],
-                k_buf.at[slot, pl.ds(p * page_rows, page_rows)],
-                k_sem.at[slot, p],
-            ).start()
-            pltpu.make_async_copy(
-                kv_v_hbm.at[li, phys, :, pl.ds(k0 * d, d)],
-                v_buf.at[slot, pl.ds(p * page_rows, page_rows)],
-                v_sem.at[slot, p],
-            ).start()
+            rows = pl.ds(pl.multiple_of(p * page_rows, page_rows), page_rows)
+            for hbm, buf, sem in (
+                (kv_k_hbm, k_buf, k_sem), (kv_v_hbm, v_buf, v_sem)
+            ):
+                copy = pltpu.make_async_copy(
+                    hbm.at[li, phys, :, pl.ds(k0 * d, d)],
+                    buf.at[slot, rows],
+                    sem.at[slot, p],
+                )
+                copy.wait() if wait else copy.start()
+            return carry
+
+        jax.lax.fori_loop(0, chunk_pages, page, 0)
+
+    def start_chunk(ci, slot):
+        chunk_copies(ci, slot, wait=False)
 
     def wait_chunk(ci, slot):
-        for p in range(chunk_pages):
-            lp = jnp.minimum(ci * chunk_pages + p, max_pages - 1)
-            phys = jnp.minimum(pt_ref[r, lp], num_phys - 1)
-            pltpu.make_async_copy(
-                kv_k_hbm.at[li, phys, :, pl.ds(k0 * d, d)],
-                k_buf.at[slot, pl.ds(p * page_rows, page_rows)],
-                k_sem.at[slot, p],
-            ).wait()
-            pltpu.make_async_copy(
-                kv_v_hbm.at[li, phys, :, pl.ds(k0 * d, d)],
-                v_buf.at[slot, pl.ds(p * page_rows, page_rows)],
-                v_sem.at[slot, p],
-            ).wait()
+        chunk_copies(ci, slot, wait=True)
 
     def dequant_window(ci, slot, compute_dtype):
         """Quantized window -> [chunk, D] full-precision K and V: per page,
@@ -166,64 +179,69 @@ def _ragged_kernel(
             jnp.concatenate(v_segs, axis=0),
         )
 
-    start_chunk(0, 0)
+    # a tile that holds no real q row (the tail of the tiled axis, which
+    # belongs to the last row: local0 >= its length) returns before its
+    # first copy: no DMA, no multiply, and an out block nobody gathers
+    @pl.when(local0 < row_len)
+    def _():
+        start_chunk(0, 0)
 
-    q_tile = q_ref[0, 0]  # [TQ, G*D], pre-scaled by 1/sqrt(D)
-    local = local0 + jax.lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
-    q_pos = ctx + local
-    q_real = local < row_len  # [TQ, 1]
+        q_tile = q_ref[0, 0]  # [TQ, G*D], pre-scaled by 1/sqrt(D)
+        local = local0 + jax.lax.broadcasted_iota(jnp.int32, (tq, 1), 0)
+        q_pos = ctx + local
+        q_real = local < row_len  # [TQ, 1]
 
-    m0 = tuple(jnp.full((tq, 1), NEG, jnp.float32) for _ in range(g))
-    l0 = tuple(jnp.zeros((tq, 1), jnp.float32) for _ in range(g))
-    acc0 = tuple(jnp.zeros((tq, d), jnp.float32) for _ in range(g))
+        m0 = tuple(jnp.full((tq, 1), NEG, jnp.float32) for _ in range(g))
+        l0 = tuple(jnp.zeros((tq, 1), jnp.float32) for _ in range(g))
+        acc0 = tuple(jnp.zeros((tq, d), jnp.float32) for _ in range(g))
 
-    def body(ci, carry):
-        m, l, acc = carry
-        slot = jax.lax.rem(ci, 2)
+        def body(ci, carry):
+            m, l, acc = carry
+            slot = jax.lax.rem(ci, 2)
 
-        @pl.when(ci + 1 < n_chunks)
-        def _():
-            start_chunk(ci + 1, jax.lax.rem(ci + 1, 2))
+            @pl.when(ci + 1 < n_chunks)
+            def _():
+                start_chunk(ci + 1, jax.lax.rem(ci + 1, 2))
 
-        wait_chunk(ci, slot)
-        if kv_bits:
-            k, v = dequant_window(ci, slot, q_ref.dtype)  # [C, D]
-        else:
-            k = k_buf[slot]  # [C, D]
-            v = v_buf[slot]
+            wait_chunk(ci, slot)
+            if kv_bits:
+                k, v = dequant_window(ci, slot, q_ref.dtype)  # [C, D]
+            else:
+                k = k_buf[slot]  # [C, D]
+                v = v_buf[slot]
 
-        key_pos = ci * chunk + jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
-        valid = q_real & (key_pos <= q_pos) & (key_pos < total_len)  # [TQ, C]
+            key_pos = ci * chunk + jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
+            valid = q_real & (key_pos <= q_pos) & (key_pos < total_len)  # [TQ, C]
 
-        m_n, l_n, acc_n = [], [], []
+            m_n, l_n, acc_n = [], [], []
+            for gi in range(g):
+                qg = q_tile[:, gi * d : (gi + 1) * d]  # [TQ, D] static slice
+                s = jax.lax.dot_general(
+                    qg.astype(k.dtype),
+                    k,
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )  # [TQ, C]
+                s = jnp.where(valid, s, NEG)
+                mg = jnp.maximum(m[gi], jnp.max(s, axis=-1, keepdims=True))
+                alpha = jnp.exp(m[gi] - mg)
+                p = jnp.exp(s - mg)
+                lg = l[gi] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+                pv = jax.lax.dot_general(
+                    p.astype(v.dtype),
+                    v,
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )  # [TQ, D]
+                m_n.append(mg)
+                l_n.append(lg)
+                acc_n.append(acc[gi] * alpha + pv)
+            return tuple(m_n), tuple(l_n), tuple(acc_n)
+
+        m, l, acc = jax.lax.fori_loop(0, n_chunks, body, (m0, l0, acc0))
         for gi in range(g):
-            qg = q_tile[:, gi * d : (gi + 1) * d]  # [TQ, D] static slice
-            s = jax.lax.dot_general(
-                qg.astype(k.dtype),
-                k,
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [TQ, C]
-            s = jnp.where(valid, s, NEG)
-            mg = jnp.maximum(m[gi], jnp.max(s, axis=-1, keepdims=True))
-            alpha = jnp.exp(m[gi] - mg)
-            p = jnp.exp(s - mg)
-            lg = l[gi] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            pv = jax.lax.dot_general(
-                p.astype(v.dtype),
-                v,
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [TQ, D]
-            m_n.append(mg)
-            l_n.append(lg)
-            acc_n.append(acc[gi] * alpha + pv)
-        return tuple(m_n), tuple(l_n), tuple(acc_n)
-
-    m, l, acc = jax.lax.fori_loop(0, n_chunks, body, (m0, l0, acc0))
-    for gi in range(g):
-        out = acc[gi] / jnp.maximum(l[gi], 1e-30)
-        out_ref[0, 0, :, gi * d : (gi + 1) * d] = out.astype(out_ref.dtype)
+            out = acc[gi] / jnp.maximum(l[gi], 1e-30)
+            out_ref[0, 0, :, gi * d : (gi + 1) * d] = out.astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -239,8 +257,9 @@ def ragged_paged_attention_pallas(
     interpret: bool = False,
 ) -> jax.Array:
     """Ragged flash attention over paged KV; returns [N, H, D] (q.dtype).
-    Rows outside every [row_start, row_start+row_len) span return finite
-    garbage — the caller only reads real rows. The pools may be QuantKV
+    Slots outside every [row_start, row_start+row_len) span are undefined
+    (finite garbage in a row's last tile, unwritten in a tile of no row) —
+    the caller only reads real rows. The pools may be QuantKV
     stores (ops/kv_quant.py): the int8/int4 pages
     DMA at their packed width and dequantize inside the VMEM window, with
     the per-page-per-head scales scalar-prefetched beside the page

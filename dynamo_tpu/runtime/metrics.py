@@ -130,6 +130,9 @@ METRICS = {
     "mixed_steps_piped": {"kind": "counter", "layer": "engine", "help": "Mixed steps that ran as entries of the decode pipeline: dispatched without a drain before them and with their successor queued before their fetch.", "export": True},
     "mixed_real_tokens": {"kind": "counter", "layer": "engine", "unit": "tokens", "help": "Real tokens (prefill chunks and one-token decode rows) that mixed steps packed.", "export": True},
     "mixed_padded_tokens": {"kind": "counter", "layer": "engine", "unit": "slots", "help": "Flat slots of the token buckets that mixed steps ran in: what every dense layer of a mixed step multiplies.", "export": True},
+    "mixed_attn_tiles": {"kind": "counter", "layer": "engine", "unit": "tiles", "help": "Q tiles of the ragged attention kernel's grid that mixed steps launched (0 where attention is the XLA reference).", "export": True},
+    "mixed_attn_tiles_real": {"kind": "counter", "layer": "engine", "unit": "tiles", "help": "Of those, the tiles that hold a real q row: whole tiles over the packs' rows of more than one token.", "export": True},
+    "mixed_rows_decode_kernel": {"kind": "counter", "layer": "engine", "unit": "rows", "help": "One-token rows of mixed steps (decode lanes, verify rows, one-token chunks) that the paged decode kernel served.", "export": True},
     "split_steps": {"kind": "counter", "layer": "engine", "help": "Split prefill/decode dispatch steps.", "export": True},
     "mixed_family_size": {"kind": "gauge", "layer": "engine", "unit": "programs", "help": "Programs in the lean mixed_step family (token buckets x table widths).", "export": True},
     "mixed_family_compiled": {"kind": "gauge", "layer": "engine", "unit": "programs", "help": "Lean mixed_step programs the jit cache holds (the whole family after the first mixed step).", "export": True},

@@ -383,6 +383,57 @@ def test_the_engine_serves_the_references_tokens_and_says_what_it_routed(params)
     assert set(stats["attention_impl"]) == {"decode", "prefill", "ragged"}
 
 
+def test_the_mixed_steps_attention_counters_follow_the_packs(params):
+    """The three counts of a mixed step's attention (q tiles the ragged
+    grid launched, those that hold a real q row, one-token rows served by
+    the decode kernel) for this family's packs, against the same arithmetic
+    on the operands its device calls were handed. Host arithmetic in the
+    kernel's tile, forced here: the CPU's engine resolves the XLA path,
+    whose tile is 1 and whose counts stay 0."""
+    from dynamo_tpu.ops.paged_attention import ragged_tiles
+
+    from .test_mixed_fusion import _Stepped
+
+    # a prompt of two chunks whose second is ONE token (33 = 32 + 1), and
+    # two of one chunk, each arriving beside the lanes that decode
+    prompts = [sequence(40, 20), sequence(41, 33), sequence(42, 21),
+               sequence(43, 30)]
+    packs = []
+
+    async def run():
+        eng = engine(params)
+        assert eng._ragged_tile == 1
+        eng._ragged_tile = 16
+        async with _Stepped(eng) as st:
+            dev_mixed = eng._dev_mixed
+
+            def kept(p):
+                if "prime" not in p:
+                    packs.append((len(p["toks"]), np.array(p["row_lens"])))
+                return dev_mixed(p)
+
+            eng._dev_mixed = kept
+            tasks = [await st.submit(prompts[0], "a", n=40)]
+            await st.until(lambda: any(
+                s is not None and s.generated > 0 for s in eng.slots))
+            for k, prompt in enumerate(prompts[1:]):
+                tasks.append(await st.submit(prompt, f"r{k}", n=6))
+                await st.step(4)
+            await st.finish(*tasks)
+            return eng.stats()
+
+    stats = asyncio.run(run())
+    assert len(packs) == stats["mixed_steps"] > 0
+    batch = EngineConfig(model="tiny-hybrid").max_prefill_batch
+    assert stats["mixed_attn_tiles"] == sum(
+        ragged_tiles(M, len(lens), 16, batch) for M, lens in packs)
+    assert stats["mixed_attn_tiles_real"] == sum(
+        int(-(-n // 16)) for _, lens in packs for n in lens if n > 1)
+    assert stats["mixed_rows_decode_kernel"] == sum(
+        int((lens == 1).sum()) for _, lens in packs)
+    assert 0 < stats["mixed_attn_tiles_real"] < stats["mixed_attn_tiles"]
+
+
 def test_a_lane_reused_and_a_sequence_resumed_give_a_fresh_engines_tokens(params):
     """One lane: the second request takes the lane the first one left its
     state in. Then a pool too small for three sequences: one is preempted,

@@ -1041,3 +1041,61 @@ def test_plain_traffic_pipes_every_mixed_step_and_compiles_nothing_new(
     assert after == primed
     assert stats["mixed_steps"] >= 5
     assert stats["mixed_steps_piped"] == stats["mixed_steps"]
+
+
+@pytest.mark.parametrize("tile", [1, 16])
+@pytest.mark.parametrize("family", ["dense", "moe"])
+def test_the_attention_counters_follow_the_packs(family, tile, params):
+    """`mixed_attn_tiles`, `mixed_attn_tiles_real` and
+    `mixed_rows_decode_kernel` over a scripted run, against the same
+    arithmetic on the packs the device calls were handed: q tiles of the
+    ragged grid a step (the token bucket and tile - 1 slots for each row of
+    a prefill batch), whole tiles over the rows of more than one token, and
+    the one-token rows. All host arithmetic, in the kernel's tile (forced
+    here: the CPU's engine resolves the XLA path, whose tile is 1 and
+    whose counts stay 0)."""
+    from dynamo_tpu.ops.paged_attention import ragged_tiles
+
+    eng = _one_width(_family_engine(family, params))
+    assert eng._ragged_tile == 1 and eng.attention_impl["ragged"] == "xla"
+    eng._ragged_tile = tile
+    # one arrival of one chunk, one of two chunks whose second is ONE token
+    # (33 = 32 + 1), and a short one, each beside the lanes decoding
+    prompts = _prompts(2, seed=77) + [list(range(5, 38))]
+    packs = []
+
+    async def run():
+        async with _Stepped(eng) as st:
+            dev_mixed = eng._dev_mixed
+
+            def kept(p):
+                if "prime" not in p:
+                    packs.append((len(p["toks"]), np.array(p["row_lens"])))
+                return dev_mixed(p)
+
+            eng._dev_mixed = kept
+            a = await st.submit(prompts[0], "a", n=40)
+            await st.until(lambda: any(
+                s is not None and s.generated > 0 for s in eng.slots))
+            tasks = [a]
+            for k, prompt in enumerate(prompts[1:]):
+                tasks.append(await st.submit(prompt, f"r{k}", n=6))
+                await st.step(4)
+            await st.finish(*tasks)
+            return eng.stats()
+
+    stats = asyncio.run(run())
+    assert len(packs) == stats["mixed_steps"] >= 3
+    if tile == 1:
+        want = (0, 0, 0)
+    else:
+        batch = eng.config.max_prefill_batch
+        want = (
+            sum(ragged_tiles(M, len(lens), tile, batch) for M, lens in packs),
+            sum(int(-(-n // tile)) for _, lens in packs for n in lens if n > 1),
+            sum(int((lens == 1).sum()) for _, lens in packs),
+        )
+        assert 0 < want[1] < want[0] and want[2] >= len(packs)
+    assert (stats["mixed_attn_tiles"], stats["mixed_attn_tiles_real"],
+            stats["mixed_rows_decode_kernel"]) == want
+

@@ -558,6 +558,23 @@ FWD_PACKS = {
 }
 
 
+@pytest.fixture
+def kernels_as(monkeypatch):
+    """Put `ragged` and `decode` in the two Pallas kernels' places behind
+    `ragged_attention`'s gate. The split path is a jit of its own, whose
+    cache knows nothing of what a test patched: cleared on both sides."""
+    from dynamo_tpu.ops import pallas_paged_attention as decode_mod
+    from dynamo_tpu.ops import pallas_ragged_attention as ragged_mod
+
+    def patch(ragged, decode):
+        monkeypatch.setattr(ragged_mod, "ragged_paged_attention_pallas", ragged)
+        monkeypatch.setattr(decode_mod, "paged_attention_decode_pallas", decode)
+        ref_ops.ragged_attention_kernels.clear_cache()
+
+    yield patch
+    ref_ops.ragged_attention_kernels.clear_cache()
+
+
 @pytest.fixture(scope="module")
 def fwd_families():
     import jax
@@ -577,21 +594,25 @@ def fwd_families():
 @pytest.mark.parametrize("tile", [8, 16])
 @pytest.mark.parametrize("family", ["llama", "tiny-moe"])
 def test_ragged_forward_tiled_q_matches_compact(
-    fwd_families, monkeypatch, family, tile, pack
+    fwd_families, monkeypatch, kernels_as, family, tile, pack
 ):
     """`ragged_forward` with the attention tile forced to 8 and to 16 (the
-    XLA reference then runs on the tile-aligned starts the forward derives,
-    as the Pallas kernel would) against tile 1, where attention takes the
-    compact axis as it is: the same last-token logits on every real row,
-    and the same pool bytes outside the scratch page."""
-    from dynamo_tpu.models import llama
-
+    split path of `ragged_attention`: the XLA ragged reference on the
+    tile-aligned starts it derives for the rows of more than one token, as
+    the Pallas kernel would, and the decode op for the one-token rows)
+    against tile 1, where attention takes the compact axis as it is: the
+    same last-token logits on every real row, and the same pool bytes
+    outside the scratch page."""
     mod, cfg, params = fwd_families[family]
     rows, M, R_pad, shared = FWD_PACKS[pack]
     ops = _compact_pack(cfg, rows, M, R_pad, shared, seed=len(rows))
+    # the kernels' places taken by their XLA references: the layout, the
+    # split by row length and the merge are what runs here
+    kernels_as(ref_ops.ragged_attention_reference,
+               ref_ops.paged_attention_decode)
 
     def run(t):
-        monkeypatch.setattr(llama, "ragged_tile", lambda *a, **kw: t)
+        monkeypatch.setattr(ref_ops, "ragged_tile", lambda *a, **kw: t)
         return mod.ragged_forward(params, cfg, *ops)
 
     want, got = run(1), run(tile)
@@ -608,11 +629,12 @@ def test_ragged_forward_tiled_q_matches_compact(
 
 
 def test_tiled_layout_is_the_inverse_pair_the_kernel_contract_wants():
-    """Starts on multiples of the tile in row order, empty rows right
-    behind the last real one, a static length that holds any pack of the
-    bucket, and two indices that are each other's inverse on real slots
-    and out of range elsewhere."""
-    from dynamo_tpu.models.llama import _tiled_layout
+    """Starts on multiples of the tile in row order, rows of no length
+    (empty ones, and the one-token rows the caller zeroed) where the next
+    row starts, a static length that holds any pack of the bucket, and two
+    indices that are each other's inverse on the slots of the rows that
+    keep tiles and out of range elsewhere."""
+    from dynamo_tpu.ops.paged_attention import _tiled_layout, ragged_tiles
 
     cfg_rows = [(21, 0), (1, 13), (16, 3), (1, 1)]
     ops = _compact_pack(
@@ -620,12 +642,178 @@ def test_tiled_layout_is_the_inverse_pair_the_kernel_contract_wants():
                            vocab_size=64, dtype=jnp.float32)),
         cfg_rows, 64, 8,
     )
-    row_ids, row_starts, row_lens = ops[2], ops[6], ops[7]
+    row_starts, row_lens = ops[6], ops[7]
+    # every row may be long: M + (tile - 1) * R = 184 slots, whole tiles
+    assert ragged_tiles(64, 8, 16) == 12
+    # two rows may: 64 + 15 * 2 = 94 slots, 6 tiles
+    tiles = ragged_tiles(64, 8, 16, long_rows=2)
+    assert tiles == 6
+    tiled_lens = jnp.where(row_lens == 1, 0, row_lens)
     starts, to_tiled, from_tiled = map(
-        np.asarray, _tiled_layout(16, row_ids, row_starts, row_lens))
-    assert list(starts) == [0, 32, 48, 64, 80, 80, 80, 80]
-    assert from_tiled.shape == (192,)  # M + (tile - 1) * R = 184, whole tiles
-    real = 21 + 1 + 16 + 1
-    assert list(from_tiled[to_tiled[:real]]) == list(range(real))
-    assert (to_tiled[real:] == len(from_tiled)).all()
-    assert (from_tiled == 64).sum() == len(from_tiled) - real
+        np.asarray, _tiled_layout(16, tiles, 64, row_starts, tiled_lens))
+    assert list(starts) == [0, 32, 32, 48, 48, 48, 48, 48]
+    assert from_tiled.shape == (96,)
+    kept = [*range(21), *range(22, 38)]  # compact slots of rows 0 and 2
+    assert list(from_tiled[to_tiled[kept]]) == kept
+    others = sorted(set(range(64)) - set(kept))
+    assert (to_tiled[others] == len(from_tiled)).all()
+    assert (from_tiled == 64).sum() == len(from_tiled) - len(kept)
+
+
+# --------------------------------------------------------------------- #
+# the split path: one-token rows through the paged decode kernel, the
+# rest through the ragged kernel, both Pallas in interpret mode
+# --------------------------------------------------------------------- #
+
+
+def _split_case(rows, H, KH, D, dtype=jnp.float32, M=None, R_pad=None,
+                shared=(), seed=0):
+    """A compact pack for `ragged_attention`: rows = [(row_len, ctx_len)],
+    a row of no length starting where the next row does (ascending starts,
+    padding rows at M). -> (q [M, H, D], kv_k, kv_v, tables, row_starts,
+    row_lens, ctx_lens)."""
+    rng = np.random.RandomState(seed)
+    P = max(-(-(ctx + n) // FWD_PAGE) for n, ctx in rows) + 1
+    R = len(rows)
+    R_pad = R_pad or R
+    real = sum(n for n, _ in rows)
+    M = M or -(-real // 8) * 8
+    pages = 1 + R * P
+    kv_k = jnp.asarray(rng.randn(pages, FWD_PAGE, KH, D), dtype)
+    kv_v = jnp.asarray(rng.randn(pages, FWD_PAGE, KH, D), dtype)
+    tables = np.zeros((R_pad, P + 1), np.int32)
+    tables[:R, :P] = rng.permutation(np.arange(1, pages)).reshape(R, P)
+    for group in shared:
+        tables[group[1:]] = tables[group[0]]
+    row_starts = np.full(R_pad, M, np.int32)
+    row_lens = np.zeros(R_pad, np.int32)
+    ctx_lens = np.zeros(R_pad, np.int32)
+    off = 0
+    for r, (n, ctx) in enumerate(rows):
+        row_starts[r], row_lens[r], ctx_lens[r] = off, n, ctx
+        off += n
+    q = jnp.asarray(rng.randn(M, H, D), dtype)
+    return (q, kv_k, kv_v, jnp.asarray(tables), jnp.asarray(row_starts),
+            jnp.asarray(row_lens), jnp.asarray(ctx_lens))
+
+
+# name -> (rows, kwargs of _split_case, long_rows)
+SPLIT_PACKS = {
+    "decode_rows_only": (
+        [(1, 5), (1, 17), (1, 64), (1, 1), (1, 8)], dict(R_pad=8), 2),
+    "one_prompt_beside_31_rows": (
+        [(45, 0)] + [(1, 3 + 5 * i) for i in range(31)],
+        dict(M=128, R_pad=40), 8),
+    "a_chunk_of_one_token": ([(1, 0), (19, 8), (1, 40)], dict(R_pad=4), 2),
+    "verify_rows_on_one_table": (
+        [(13, 2), (1, 20), (1, 21), (1, 22), (1, 23), (1, 9)],
+        dict(R_pad=8, shared=([1, 2, 3, 4],)), 1),
+    "empty_rows_in_the_middle": (
+        [(1, 12), (0, 0), (21, 4), (0, 0), (0, 0), (1, 30), (7, 0)],
+        dict(R_pad=8), 3),
+    "a_full_prefill_batch_off_the_tile": (
+        [(17, 0), (3, 9), (33, 16), (9, 1), (21, 40), (2, 7), (31, 3),
+         (5, 0), (1, 11), (1, 50)],
+        dict(M=128, R_pad=16), 8),
+    "no_one_token_row": ([(23, 0), (9, 16)], dict(M=64, R_pad=8), 2),
+}
+
+
+@pytest.fixture
+def interpreted_kernels(monkeypatch, kernels_as):
+    """`ragged_attention` resolved to its Pallas kernels, both in interpret
+    mode: the gate says yes, and the two jitted kernels are asked to
+    interpret."""
+    import functools
+
+    from dynamo_tpu.ops.pallas_paged_attention import (
+        paged_attention_decode_pallas,
+    )
+
+    monkeypatch.setattr(ref_ops, "_pallas_eligible", lambda *a, **kw: True)
+    kernels_as(
+        functools.partial(ragged_paged_attention_pallas, interpret=True),
+        functools.partial(paged_attention_decode_pallas, interpret=True),
+    )
+    return kernels_as
+
+
+# the cells' widths (heads of 128 over 8 KV heads, of 256 over 2) on the
+# small packs, where interpret mode is quick; every pack at D = 32
+SPLIT_CASES = [
+    pytest.param(pack, heads, id=f"{pack}-{name}")
+    for pack in sorted(SPLIT_PACKS)
+    for name, heads in (("128x8kv", (32, 8, 128)), ("256x2kv", (16, 2, 256)),
+                        ("32x4kv", (8, 4, 32)))
+    if heads[2] == 32 or len(SPLIT_PACKS[pack][0]) <= 8
+]
+
+
+@pytest.mark.parametrize("pack,heads", SPLIT_CASES)
+def test_split_path_matches_reference(interpreted_kernels, pack, heads):
+    """One-token rows through the decode kernel and the rest through the
+    ragged kernel give, on every real row of the compact axis, what the
+    XLA reference gives for the whole pack."""
+    H, KH, D = heads
+    rows, kw, long_rows = SPLIT_PACKS[pack]
+    q, kv_k, kv_v, pt, rs, rl, cl = _split_case(
+        rows, H, KH, D, seed=len(rows), **kw)
+    want = ref_ops.ragged_attention_reference(
+        q, L(kv_k), L(kv_v), pt, rs, rl, cl)
+    got = ref_ops.ragged_attention(
+        q, L(kv_k), L(kv_v), pt, rs, rl, cl, long_rows=long_rows)
+    assert np.isfinite(np.asarray(got)).all()
+    _assert_real_rows_close(
+        got, want, np.asarray(rs), np.asarray(rl), rtol=2e-3, atol=2e-3)
+
+
+def test_split_path_bf16_at_the_cells_tile(interpreted_kernels):
+    """bf16 packs at the 16-row tile, as the cells run them."""
+    rows, kw, long_rows = SPLIT_PACKS["a_chunk_of_one_token"]
+    q, kv_k, kv_v, pt, rs, rl, cl = _split_case(
+        rows, 8, 2, 128, dtype=jnp.bfloat16, seed=5, **kw)
+    want = ref_ops.ragged_attention_reference(
+        q, L(kv_k), L(kv_v), pt, rs, rl, cl)
+    got = ref_ops.ragged_attention(
+        q, L(kv_k), L(kv_v), pt, rs, rl, cl, long_rows=long_rows)
+    _assert_real_rows_close(
+        got, want, np.asarray(rs), np.asarray(rl), rtol=3e-2, atol=3e-2)
+
+
+def test_skipped_tail_tile_is_never_read(interpreted_kernels):
+    """A tile that holds no real q row returns before its first copy and
+    writes nothing: whatever its out block holds reaches no slot of the
+    compact axis. The ragged kernel's result is poisoned outside the
+    tiles of real rows before the merge reads it."""
+    import functools
+
+    from dynamo_tpu.ops.pallas_paged_attention import (
+        paged_attention_decode_pallas,
+    )
+
+    seen = {}
+
+    def poisoned(q, kv_k, kv_v, pt, starts, lens, ctx):
+        out = ragged_paged_attention_pallas(
+            q, kv_k, kv_v, pt, starts, lens, ctx, interpret=True)
+        slot = jnp.arange(q.shape[0])
+        tile = ragged_tile_q(q.dtype)
+        spans = -(-lens // tile) * tile
+        held = ((slot[:, None] >= starts[None, :])
+                & (slot[:, None] < (starts + spans)[None, :])).any(axis=1)
+        seen["tiles"] = q.shape[0] // tile
+        return jnp.where(held[:, None, None], out, jnp.nan)
+
+    interpreted_kernels(
+        poisoned,
+        functools.partial(paged_attention_decode_pallas, interpret=True))
+    rows, kw, long_rows = SPLIT_PACKS["empty_rows_in_the_middle"]
+    q, kv_k, kv_v, pt, rs, rl, cl = _split_case(rows, 8, 4, 32, seed=3, **kw)
+    want = ref_ops.ragged_attention_reference(
+        q, L(kv_k), L(kv_v), pt, rs, rl, cl)
+    got = ref_ops.ragged_attention(
+        q, L(kv_k), L(kv_v), pt, rs, rl, cl, long_rows=long_rows)
+    assert seen["tiles"] == 7  # for 4 tiles of real rows (21 and 7 tokens)
+    assert np.isfinite(np.asarray(got)).all()
+    _assert_real_rows_close(
+        got, want, np.asarray(rs), np.asarray(rl), rtol=2e-3, atol=2e-3)

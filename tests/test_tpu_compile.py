@@ -379,6 +379,52 @@ def _cell_models():
     }
 
 
+def _q_tile_operands(lowered_text, kv_heads, lanes_wide):
+    """The tile counts of the ragged kernel's q operands in a lowered
+    program: tensor<tiles x KH x 16 x G*D x bf16>."""
+    import re
+
+    return {
+        int(m) for m in re.findall(
+            rf"tensor<(\d+)x{kv_heads}x16x{lanes_wide}xbf16>", lowered_text)
+    }
+
+
+# the three cells' attention: (H, KH, D, attention layers, pool pages)
+CELL_ATTENTION = {
+    "mistral-7b-d16": (32, 8, 128, 16, 1378),
+    "mixtral-8x7b-d2": (32, 8, 128, 2, 8193),
+    "qwen3-next-80b-a3b-ep4-d8": (16, 2, 256, 2, 4096),
+}
+
+
+@pytest.mark.parametrize("cell", list(CELL_ATTENTION))
+def test_the_cells_mixed_attention_is_two_kernels_on_a_prompts_tiles(
+    cell, one_chip, no_persistent_cache, tpu_gate
+):
+    """A mixed step's attention call at each cell's widths and pool, 40
+    rows, the ONE table width of 65, every token bucket: the paged decode
+    kernel over the 40 rows as lanes and the ragged kernel over a grid of
+    (M + 15 x max_prefill_batch) / 16 q tiles, both compiled for the
+    described v5e (their tables in SMEM side by side with nothing else)."""
+    H, KH, D, layers, pool = CELL_ATTENTION[cell]
+    sds = _shapes(one_chip)
+    i32 = jnp.int32
+    kv = kv_layer(sds((layers, pool, PAGE, KH * D), jnp.bfloat16), layers - 1)
+    rows, width = 40, 4096 // PAGE + 1
+    for tokens, tiles in ((256, 24), (512, 40), (1024, 72), (2048, 136)):
+        assert ops.ragged_tiles(tokens, rows, 16, 8) == tiles
+        lowered = jax.jit(
+            functools.partial(ops.ragged_attention, long_rows=8)
+        ).lower(
+            sds((tokens, H, D), jnp.bfloat16), kv, kv, sds((rows, width), i32),
+            sds((rows,), i32), sds((rows,), i32), sds((rows,), i32),
+        )
+        assert _q_tile_operands(lowered.as_text(), KH, H // KH * D) == {tiles}
+        compiled = lowered.compile()
+        assert compiled.as_text().count("tpu_custom_call") == 2
+
+
 @pytest.mark.parametrize("tokens", (256, 2048))
 @pytest.mark.parametrize("cell", ("mixtral-8x7b-d2", "mistral-7b-d16"))
 def test_the_cells_mixed_step_family_compiles_with_its_kernels(
@@ -386,12 +432,14 @@ def test_the_cells_mixed_step_family_compiles_with_its_kernels(
 ):
     """The smallest and the largest member of both benchmark cells' lean
     mixed_step family: the cell's widths, depth and pool, 40 rows, the ONE
-    table width of 65, a COMPACT token axis of the bucket's length, the
-    ragged kernel inside on the q-tile layout the forward builds (and, on
-    the routed family, the grouped expert matmul), within one v5e. No
-    temporary the size of an expert matrix (0.94 GB): the expert stacks
-    reach the kernel whole, a slice of one would be a copy of it; the
-    dense family's largest temporaries are the bucket's activations."""
+    table width of 65, a COMPACT token axis of the bucket's length, both
+    attention kernels inside (the paged decode kernel for the one-token
+    rows, the ragged kernel on the q-tile layout of a prefill batch of 8:
+    24 and 136 tiles) and, on the routed family, the grouped expert
+    matmul, within one v5e. No temporary the size of an expert matrix
+    (0.94 GB): the expert stacks reach the kernel whole, a slice of one
+    would be a copy of it; the dense family's largest temporaries are the
+    bucket's activations."""
     from dynamo_tpu.engine.bucketing import mixed_row_bucket
     from dynamo_tpu.engine.config import EngineConfig
 
@@ -409,8 +457,9 @@ def test_the_cells_mixed_step_family_compiles_with_its_kernels(
         cfg.dtype, "none",
     )))
     i32 = jnp.int32
-    rows = mixed_row_bucket(EngineConfig(model="tiny", max_num_seqs=32))
-    assert rows == 40  # 32 lanes and a prefill batch of 4, whole sublanes
+    engine_config = EngineConfig(model="tiny", max_num_seqs=32)
+    rows = mixed_row_bucket(engine_config)
+    assert rows == 40  # 32 lanes and a prefill batch of 8, whole sublanes
     width = 4096 // PAGE + 1
 
     def step(params, kv_k, kv_v, tokens, positions, row_ids, tables,
@@ -418,16 +467,22 @@ def test_the_cells_mixed_step_family_compiles_with_its_kernels(
         return mod.ragged_forward(
             params, cfg, tokens, positions, row_ids, kv_k, kv_v, tables,
             row_starts, row_lens, ctx_lens, last_flat,
+            long_rows=engine_config.max_prefill_batch,
         )
 
-    compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+    lowered = jax.jit(step, donate_argnums=(1, 2)).lower(
         params, kv, kv, sds((tokens,), i32), sds((tokens,), i32),
         sds((tokens,), i32), sds((rows, width), i32), sds((rows,), i32),
         sds((rows,), i32), sds((rows,), i32), sds((rows,), i32),
-    ).compile()
-    # per layer: the ragged attention kernel and, routed, three grouped
+    )
+    group_lanes = cfg.num_heads // cfg.num_kv_heads * cfg.head_dim
+    assert _q_tile_operands(
+        lowered.as_text(), cfg.num_kv_heads, group_lanes
+    ) == {ops.ragged_tiles(tokens, rows, 16, engine_config.max_prefill_batch)}
+    compiled = lowered.compile()
+    # per layer: the two attention kernels and, routed, three grouped
     # matmuls
-    kernels = 4 if hasattr(cfg, "num_experts") else 1
+    kernels = 5 if hasattr(cfg, "num_experts") else 2
     assert compiled.as_text().count("tpu_custom_call") >= kernels * cfg.num_layers
     mem = compiled.memory_analysis()
     need = mem.argument_size_in_bytes + mem.temp_size_in_bytes
