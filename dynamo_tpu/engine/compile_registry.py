@@ -289,20 +289,21 @@ COMPILE_SURFACES = {
                           "and dtype (a dozen per model)"},
         "warmup": False,
         "help": "one stacked leaf of seeded random weights at model "
-                "construction (hybrid.init_params), built in its own "
-                "dtype with no float32 copy beside it",
+                "construction (hybrid.init_params, nemotron_h."
+                "init_params), built in its own dtype with no float32 copy "
+                "beside it",
     },
     "expert_stack_leaf": {
         "module": "dynamo_tpu/models/hybrid.py",
         "kind": "jit",
         "donate": (),
         "static": (1, 2, 3, 4),
-        "axes": {"shape": "two programs per model: an expert's up and "
-                          "down matrix shapes"},
+        "axes": {"shape": "two programs per model: the shapes of an "
+                          "expert's matrices into and out of its width"},
         "warmup": False,
         "help": "a [layers, held experts, ...] stack of seeded weights "
                 "keyed by each expert's global id, at model construction "
-                "(hybrid.init_params)",
+                "(hybrid.init_params, nemotron_h.init_params)",
     },
     # ----------------------------------------------------------------- #
     # ops/ — attention kernels (jit wrappers staging pallas_call bodies)

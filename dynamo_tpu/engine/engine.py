@@ -46,7 +46,7 @@ import numpy as np
 from ..llm.mocker.kv_manager import KvEvent
 from ..llm.protocols import Annotated, LLMEngineOutput, PreprocessedRequest
 from ..llm.tokens import TokenBlockSequence, compute_seq_hashes, salt_hash
-from ..models import hybrid, llama, moe
+from ..models import hybrid, llama, moe, nemotron_h
 from ..models.quant import is_quant
 from ..ops.paged_attention import ragged_tiles
 from ..ops.state_cache import state_bytes_per_lane
@@ -166,6 +166,7 @@ def _kv_shard_div(kv_sharding) -> int:
 #: (the first entry the class is an instance of: a subclass stands before
 #: its base)
 MODEL_FAMILIES = (
+    (nemotron_h.NemotronHConfig, nemotron_h),
     (hybrid.HybridConfig, hybrid),
     (moe.MoeConfig, moe),
     (llama.LlamaConfig, llama),
@@ -243,8 +244,8 @@ def _auto_num_pages(params, model_cfg, config: EngineConfig,
         # layers alone, and its state store (one slot a lane and a scratch
         # slot) comes out of what the pool may take
         kv_layers, state_bytes = model_cfg.num_layers, 0
-        if isinstance(model_cfg, hybrid.HybridConfig):
-            kv_layers = hybrid.periods(model_cfg)[2]
+        if hasattr(model_family(model_cfg), "STATE_FAMILY"):
+            kv_layers = model_cfg.state_spec().attention_layers
             state_bytes = (
                 (config.max_num_seqs + 1) * state_bytes_per_lane(model_cfg)
             )
@@ -537,8 +538,11 @@ class JaxEngine:
         # exposes the same init/decode/prefill signatures
         family = model_family(c)
         # a family that keeps a recurrent state per lane beside the pages
-        # (models/hybrid.py; docs/hybrid_models.md)
-        self._stateful = family is hybrid
+        # says so of itself, in the words its refusals are worded in
+        # (`STATE_FAMILY`: models/hybrid.py, models/nemotron_h.py;
+        # docs/hybrid_models.md)
+        self.STATE_FAMILY = getattr(family, "STATE_FAMILY", None)
+        self._stateful = self.STATE_FAMILY is not None
         # a routed family counts the rows its expert matmuls multiply
         self._counts_expert_rows = hasattr(family, "expert_rows")
         if self._stateful:
@@ -955,8 +959,6 @@ class JaxEngine:
     # ------------------------------------------------------------------ #
     # a family with a recurrent state beside the pages
     # ------------------------------------------------------------------ #
-
-    STATE_FAMILY = "the hybrid family (models/hybrid.py: a recurrent state per lane)"
 
     @property
     def stateful(self) -> bool:
@@ -6299,6 +6301,7 @@ class JaxEngine:
 def _resolve_model(name: str) -> llama.LlamaConfig:
     registry = {
         "tiny-hybrid": hybrid.HybridConfig.tiny_hybrid,
+        "tiny-nemotron-h": nemotron_h.NemotronHConfig.tiny_nemotron_h,
         "tiny": llama.LlamaConfig.tiny,
         "llama3-3b": llama.LlamaConfig.llama3_2_3b,
         "llama3-8b": llama.LlamaConfig.llama3_8b,

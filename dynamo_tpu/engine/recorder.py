@@ -18,7 +18,8 @@ One table for the engine's loop, exported through `JaxEngine.stats()`:
   `step_stalled_count`, `step_stalled_s` instead), and the work it was
   asked for, `step_model_flops` and `step_min_bytes`
   (models/<family>.step_work), and of the bytes a recurrent state's,
-  `step_state_bytes` (exported once it is not 0: models/hybrid.py).
+  `step_state_bytes`, and the held experts', `step_expert_bytes` (each
+  exported once it is not 0: models/hybrid.py, models/nemotron_h.py).
 * the waits ahead of a first token: `req_admitted`, `req_queue_wait_s`,
   `req_first_tokens`, `req_admit_to_first_s`.
 * the device calls' host clock by tag (`dispatch_<tag>_count`, `_s`:
@@ -123,6 +124,7 @@ class Recorder:
         self.model_flops = 0
         self.min_bytes = 0
         self.state_bytes = 0  # of min_bytes, a recurrent state's (hybrid)
+        self.expert_bytes = 0  # of min_bytes, the held experts' (nemotron_h)
         # (count, seconds) of the host's clock around a device call, by tag
         self.dev_time: Dict[str, tuple] = {}
         self.req_admitted = 0
@@ -160,13 +162,16 @@ class Recorder:
         """Stamp `entry` as it goes to the device: its kind, the host's
         clock, and the (useful operations, least bytes) it was asked for; a
         family with a recurrent state says third how many of the bytes are
-        the state's (models/hybrid.step_work)."""
+        the state's (models/hybrid.step_work), and may say fourth how many
+        are the held experts' (models/nemotron_h.step_work)."""
         entry["step_kind"] = kind
         entry["t_dispatch"] = time.perf_counter()
         self.model_flops += work[0]
         self.min_bytes += work[1]
         if len(work) > 2:
             self.state_bytes += work[2]
+        if len(work) > 3:
+            self.expert_bytes += work[3]
 
     def fetched(self, entries: List[dict], t_ready: float):
         """The fetch that brought these entries back returned at `t_ready`:
@@ -237,4 +242,6 @@ class Recorder:
             out[f"dispatch_{tag}_s"] = round(tot, 3)
         if self.state_bytes:
             out["step_state_bytes"] = float(self.state_bytes)
+        if self.expert_bytes:
+            out["step_expert_bytes"] = float(self.expert_bytes)
         return out

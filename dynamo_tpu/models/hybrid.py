@@ -43,22 +43,21 @@ from ..ops.paged_attention import (
     paged_attention_decode,
     prefill_attention_batched,
     ragged_attention,
-    rows_at,
 )
-from ..ops.state_cache import StateCache, conv_channels, state_bytes_per_lane
+from ..ops.row_recurrence import flat_conv, rows_recurrence
+from ..ops.state_cache import StateCache, StateSpec, state_bytes_per_lane
 from . import llama, moe
 from .llama import LlamaConfig
 from .quant import embed_rows, qdot
 
 f32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
+#: what the engine calls a family that keeps a recurrent state per lane
+#: beside the pages, in its refusals and its log (engine._stateful)
+STATE_FAMILY = "the hybrid family (models/hybrid.py: a recurrent state per lane)"
 #: tokens a chunk of the chunked recurrence holds (the step form's
 #: sequential depth divides by it; the work inside a chunk grows with it)
 CHUNK = 64
-#: megablox row tile of a decode step's routed rows: a step's real rows
-#: times k, of which a chip's share is held, fit one tile, and every
-#: expert with a row in it streams its weights once
-DECODE_GMM_ROWS = 128
 #: deviation of a seeded random matrix's elements (`init_params`)
 INIT_SCALE = 0.02
 #: the chunked recurrence's matmuls: float32 operands in three bf16 passes
@@ -107,6 +106,16 @@ class HybridConfig(LlamaConfig):
                 f"router's width {self.router_width}"
             )
 
+    def state_spec(self) -> StateSpec:
+        P, Ll, Lf = periods(self)
+        return StateSpec(
+            state_layers=Ll, attention_layers=Lf, routed_layers=self.num_layers,
+            state_shape=(self.linear_num_value_heads, self.linear_key_head_dim,
+                         self.linear_value_head_dim),
+            conv_shape=(self.linear_conv_kernel_dim - 1, conv_channels(self)),
+            state_dtype=self.state_dtype,
+            experts_per_token=self.num_experts_per_tok)
+
     @classmethod
     def tiny_hybrid(cls, **overrides):
         """CPU-test scale: two periods, a router twice as wide as the
@@ -129,6 +138,14 @@ def periods(c: HybridConfig) -> Tuple[int, int, int]:
     """(periods, linear layers, full-attention layers)."""
     P = c.num_layers // c.full_attention_interval
     return P, c.num_layers - P, P
+
+
+def conv_channels(c: HybridConfig) -> int:
+    """Channels of the linear mixer's convolution: q, k and v side by side."""
+    return (
+        2 * c.linear_num_key_heads * c.linear_key_head_dim
+        + c.linear_num_value_heads * c.linear_value_head_dim
+    )
 
 
 # ---------------------------------------------------------------------- #
@@ -391,39 +408,6 @@ def route(h, router, c: HybridConfig):
     return idx, jnp.exp(top - jax.nn.logsumexp(logits, -1, keepdims=True))
 
 
-def experts_held(stacks, li, h, idx, weight, valid, c: HybridConfig,
-                 rows: int):
-    """sum_e w_e down_e(silu(gate_e(h)) * up_e(h)) over the chosen experts
-    this chip holds: the (token, expert) pairs that fall on a held expert
-    sorted by expert, three grouped matmuls over those rows, each token's
-    rows weighed and summed. No pair is dropped, whatever the batch; pairs
-    on experts held elsewhere (and padding, where `valid` [T] marks real
-    tokens) reach no expert. `stacks`: the WHOLE [L, E, ., .] stacks;
-    layer `li`'s experts are groups li * E ... of them. -> [T, H] f32."""
-    T, H = h.shape
-    E, K = c.num_experts, c.num_experts_per_tok
-    local = idx - c.first_expert_held
-    held = (local >= 0) & (local < E)
-    if valid is not None:
-        held &= valid[:, None]
-    G = stacks["w_gate"].shape[0] * E
-    group = jnp.where(held, li * E + local, G).reshape(T * K)
-    weight = jnp.where(held, weight, 0.0)
-    order = jnp.argsort(group, stable=True)
-    sizes = jnp.zeros((G,), jnp.int32).at[group].add(1, mode="drop")
-    x = h[order // K]
-    w_gate, w_up, w_down = (
-        moe.ExpertStack(stacks[n]) for n in ("w_gate", "w_up", "w_down"))
-    gate = moe._grouped_matmul(x, w_gate, sizes, rows=rows)
-    up = moe._grouped_matmul(x, w_up, sizes, rows=rows)
-    act = (jax.nn.silu(gate) * up).astype(c.dtype)
-    down = moe._grouped_matmul(act, w_down, sizes, rows=rows)
-    # rows no group owns were never written
-    down = jnp.where((jnp.arange(T * K) < sizes.sum())[:, None], down, 0.0)
-    back = jnp.zeros((T * K,), jnp.int32).at[order].set(jnp.arange(T * K))
-    return jnp.einsum("tkh,tk->th", down[back].reshape(T, K, H), weight)
-
-
 def routed_block(layer, stacks, li, x, c: HybridConfig, valid=None):
     """y = x + routed(rms(x)) + shared(rms(x)) for x [T, H]; also the
     experts chosen [T, K] (ids under the router's full width, held here or
@@ -431,9 +415,9 @@ def routed_block(layer, stacks, li, x, c: HybridConfig, valid=None):
     h = norm(x, layer["norm"], c.rms_norm_eps)
     with jax.named_scope("experts"):
         idx, weight = route(h, layer["router"], c)
-        rows = DECODE_GMM_ROWS if h.shape[0] < moe.GROUPED_MIN_TOKENS \
-            else moe._GMM_ROWS
-        out = experts_held(stacks, li, h, idx, weight, valid, c, rows)
+        out = moe.experts_held(
+            stacks, li, h, idx, weight, valid, form="gated_silu",
+            held=c.num_experts, first=c.first_expert_held, dtype=c.dtype)
     with jax.named_scope("shared_expert"):
         act = (jax.nn.silu(qdot(h, layer["ws_gate"]))
                * qdot(h, layer["ws_up"])).astype(c.dtype)
@@ -600,93 +584,32 @@ def _flat_linear_fn(c: HybridConfig, lanes, row_ids, row_starts, row_lens,
     share (row r: slots row_starts[r] ... + row_lens[r], lane lanes[r],
     ctx_lens[r] tokens of its sequence before it). A row starts from its
     lane's state, or from zero where its context is 0, and leaves the
-    state behind its last token in the lane.
-
-    Two passes over the recurrence. Every row's FIRST token goes through
-    the step form, all rows at once: a decode row is done with that. What
-    is left of the rows of more tokens goes through the chunked form,
-    CHUNK tokens an iteration, as many iterations as the longest of them
-    needs. `long_rows`: how many rows of more than one token the caller
-    expects at most (a mixed step's prefill batch; every row of a batched
-    prefill): the chunked pass runs over the `long_rows` longest rows
-    alone, so that the decode rows of a mixed step cost it nothing, and
-    over every row where more than `long_rows` turn out to be long."""
-    R = row_lens.shape[0]
-    nv = c.linear_num_value_heads
-    taps = c.linear_conv_kernel_dim
+    state behind its last token in the lane. The convolution and the two
+    passes over the recurrence (the step form for every row's first token,
+    the chunked form for what is left of the `long_rows` longest) are
+    ops/row_recurrence.py's."""
     fresh = ctx_lens == 0
 
     def linear_fn(layer, h, state, conv, ll):
-        M = h.shape[0]
         mixed, z, beta, g = _mixer_inputs(layer, h, c)
-        slot = jnp.arange(M, dtype=jnp.int32)
-        t = slot - row_starts[row_ids]  # offset in the slot's row
-        # the convolution: taps - 1 inputs before a row's first token come
-        # from the lane's tail (zero for a sequence's first chunk)
+        # the taps - 1 inputs before a row's first token come from the
+        # lane's tail (zero for a sequence's first chunk)
         tails = jnp.where(
             fresh[:, None, None], 0,
             jax.lax.dynamic_index_in_dim(conv, ll, 0, False)[lanes])
-        w = layer["w_conv"].astype(f32)
-        y = mixed.astype(f32) * w[:, taps - 1]
-        for back in range(1, taps):
-            before = jnp.where(
-                (t >= back)[:, None],
-                rows_at(mixed, jnp.maximum(slot - back, 0)),
-                tails[row_ids, jnp.clip(taps - 1 + t - back, 0, taps - 2)],
-            )
-            y = y + before.astype(f32) * w[:, taps - 1 - back]
-        # the tail a row leaves: the last taps - 1 inputs of tail ++ row
-        end = row_lens[:, None] - (taps - 1) + jnp.arange(taps - 1)  # [R, 3]
-        new_tails = jnp.where(
-            (end >= 0)[..., None],
-            rows_at(mixed, row_starts[:, None] + jnp.maximum(end, 0)),
-            jnp.take_along_axis(
-                tails, jnp.clip(end + taps - 1, 0, taps - 2)[..., None],
-                axis=1),
-        )
+        y, new_tails = flat_conv(
+            mixed, layer["w_conv"].astype(f32), tails, row_ids, row_starts,
+            row_lens)
         q, k, v = _split_qkv(jax.nn.silu(y), c)
         S = jnp.where(
             fresh[:, None, None, None], 0,
             jax.lax.dynamic_index_in_dim(state, ll, 0, False)[lanes],
         ).astype(f32)
-
-        def at_slots(at):
-            """q, k, v, g, beta at flat slots `at` (slot M: a zero row,
-            whose beta and g of 0 leave a state as it was)."""
-            return tuple(rows_at(a, at) for a in (q, k, v, g, beta))
-
-        # pass one: every row's first token, the step form
-        first = jnp.where(row_lens > 0, row_starts, M)
-        S, o_first = delta_step(S, *at_slots(first))
-        o = jnp.zeros((M, nv, c.linear_value_head_dim), f32)
-        o = o.at[first].set(o_first, mode="drop")
-
-        def chunks(rows, S, o):
-            """Pass two over `rows` [n] (indices of rows): their tokens
-            from the second on, CHUNK an iteration."""
-            starts, left = row_starts[rows] + 1, row_lens[rows] - 1
-
-            def chunk(j, carry):
-                S_rows, o = carry
-                offset = j * CHUNK + jnp.arange(CHUNK)
-                at = jnp.where(offset[None, :] < left[:, None],
-                               starts[:, None] + offset, M)  # [n, C]
-                S_rows, oc = delta_chunk(S_rows, *at_slots(at))
-                return S_rows, o.at[at].set(oc, mode="drop")
-
-            S_rows, o = jax.lax.fori_loop(
-                0, -(-jnp.max(left) // CHUNK), chunk, (S[rows], o))
-            return S.at[rows].set(S_rows), o
-
-        every = jnp.arange(R, dtype=jnp.int32)
-        if long_rows >= R:
-            S, o = chunks(every, S, o)
-        else:
-            longest = jnp.argsort(-row_lens)[:long_rows].astype(jnp.int32)
-            S, o = jax.lax.cond(
-                jnp.sum(row_lens > 1) > long_rows,
-                lambda S, o: chunks(every, S, o),
-                lambda S, o: chunks(longest, S, o), S, o)
+        # (a zero row's beta and g of 0 leave a state as it was)
+        S, o = rows_recurrence(
+            S, (q, k, v, g, beta),
+            (c.linear_num_value_heads, c.linear_value_head_dim),
+            delta_step, delta_chunk, CHUNK, row_starts, row_lens, long_rows)
         state = state.at[ll, lanes].set(S.astype(state.dtype))
         conv = conv.at[ll, lanes].set(new_tails.astype(conv.dtype))
         return _mixer_out(layer, o, z, c), state, conv
@@ -847,20 +770,9 @@ def held_share(c: HybridConfig) -> float:
 
 def expert_rows(c: HybridConfig, T: int, real: int, quantized: bool = False):
     """(routed, computed) expert rows of one layer over T token slots of
-    which `real` are real. Routed: the (token, expert) pairs that fall on
-    a held expert, in expectation under a router that spreads its choices
-    evenly (real x K x the share held; the choice itself stays on the
-    device). Computed: the rows the grouped matmul multiplies, whole row
-    tiles and one more for every expert whose rows start inside
-    another's tile (an upper bound, as moe.expert_rows), with the experts
-    touched in expectation too."""
-    routed = real * c.num_experts_per_tok * held_share(c)
-    if not real:
-        return 0, 0
-    tile = DECODE_GMM_ROWS if T < moe.GROUPED_MIN_TOKENS else moe._GMM_ROWS
-    touched = c.num_experts * (1.0 - (1.0 - 1.0 / c.num_experts) ** routed)
-    tiles = -(-routed // tile) + max(touched - 1.0, 0.0)
-    return int(round(routed)), int(round(tiles * tile))
+    which `real` are real (moe.held_expert_rows)."""
+    return moe.held_expert_rows(
+        c.num_experts, c.router_width, c.num_experts_per_tok, T, real)
 
 
 def step_work(c: HybridConfig, real_tokens: int, context_tokens: int,

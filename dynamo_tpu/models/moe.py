@@ -205,8 +205,10 @@ _GMM_ROWS = 256
 def _tile(n: int) -> int:
     """Largest k/n tile of the grouped matmul that divides n: a 1024 x
     1024 bf16 block of expert weights is 2 MB, double-buffered in VMEM
-    (2048 does not fit beside it; 512 is a quarter slower on the chip)."""
-    return next(t for t in (1024, 512, 256, 128) if n % t == 0)
+    (2048 does not fit beside it; 512 is a quarter slower on the chip).
+    896 and 384 are for a width of 21 x 128 (models/nemotron_h.py's 2,688),
+    which no power of two over 128 divides."""
+    return next(t for t in (1024, 896, 512, 384, 256, 128) if n % t == 0)
 
 
 def _grouped_matmul(lhs: jax.Array, w: ExpertStack, group_sizes: jax.Array,
@@ -269,6 +271,86 @@ def _experts_grouped(layer, h, topi, probs, valid, c: MoeConfig) -> jax.Array:
     down = jnp.where(live[:, None], down, 0.0)
     back = jnp.zeros((T * K,), jnp.int32).at[order].set(jnp.arange(T * K))
     return jnp.einsum("tkh,tk->th", down[back].reshape(T, K, H), probs)
+
+
+#: megablox row tile of a decode step's routed rows on a chip that holds a
+#: SHARE of the experts: a step's real rows times k, of which the share is
+#: held, fit one tile, and every expert with a row in it streams its
+#: weights once
+DECODE_GMM_ROWS = 128
+#: an expert's form, by the names of its stacked matrices [L, E, k, n]
+EXPERT_FORMS = {
+    "gated_silu": ("w_gate", "w_up", "w_down"),  # down(silu(gate(h)) * up(h))
+    "relu2": ("w1", "w2"),  # w2(relu(w1(h)) ** 2)
+}
+
+
+def held_rows_tile(T: int) -> int:
+    """The grouped matmul's row tile for a forward over T token slots."""
+    return DECODE_GMM_ROWS if T < GROUPED_MIN_TOKENS else _GMM_ROWS
+
+
+def experts_held(stacks, li, h, idx, weight, valid, *, form: str, held: int,
+                 first: int, dtype):
+    """sum_e w_e expert_e(h) over the chosen experts this chip holds,
+    `[first, first + held)` of the router's width: the (token, expert) pairs
+    that fall on a held expert sorted by expert, the form's grouped matmuls
+    over those rows, each token's rows weighed and summed. No pair is
+    dropped, whatever the batch; pairs on experts held elsewhere (and
+    padding, where `valid` [T] marks real tokens) reach no expert.
+    `stacks`: the WHOLE [L, E, ., .] stacks under the names of
+    EXPERT_FORMS[form]; layer `li`'s experts are groups li * E ... of them.
+    h [T, width in]; idx, weight [T, K]. -> [T, width out] f32."""
+    T, K = idx.shape
+    local = idx - first
+    here = (local >= 0) & (local < held)
+    if valid is not None:
+        here &= valid[:, None]
+    names = EXPERT_FORMS[form]
+    G = stacks[names[0]].shape[0] * held
+    group = jnp.where(here, li * held + local, G).reshape(T * K)
+    weight = jnp.where(here, weight, 0.0)
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.zeros((G,), jnp.int32).at[group].add(1, mode="drop")
+    x = h[order // K]
+    rows = held_rows_tile(T)
+
+    def mm(lhs, name):
+        return _grouped_matmul(lhs, ExpertStack(stacks[name]), sizes, rows=rows)
+
+    if form == "gated_silu":
+        act = (jax.nn.silu(mm(x, "w_gate")) * mm(x, "w_up")).astype(dtype)
+    else:
+        act = jnp.square(jax.nn.relu(mm(x, "w1"))).astype(dtype)
+    out = mm(act, names[-1])
+    # rows no group owns were never written
+    out = jnp.where((jnp.arange(T * K) < sizes.sum())[:, None], out, 0.0)
+    back = jnp.zeros((T * K,), jnp.int32).at[order].set(jnp.arange(T * K))
+    return jnp.einsum(
+        "tkh,tk->th", out[back].reshape(T, K, out.shape[-1]), weight)
+
+
+def experts_touched(held: int, pairs: float) -> float:
+    """Of `held` experts, those that `pairs` (token, expert) pairs spread
+    evenly over them touch, in expectation."""
+    return held * (1.0 - (1.0 - 1.0 / held) ** pairs)
+
+
+def held_expert_rows(held: int, width: int, per_token: int, T: int, real: int):
+    """(routed, computed) expert rows of one layer of `experts_held` over T
+    token slots of which `real` are real. Routed: the (token, expert) pairs
+    that fall on a held expert, in expectation under a router that spreads
+    its choices evenly (real x K x the share held; the choice itself stays
+    on the device). Computed: the rows the grouped matmul multiplies, whole
+    row tiles and one more for every expert whose rows start inside
+    another's tile (an upper bound, as `expert_rows`), with the experts
+    touched in expectation too."""
+    if not real:
+        return 0, 0
+    routed = real * per_token * held / width
+    tile = held_rows_tile(T)
+    tiles = -(-routed // tile) + max(experts_touched(held, routed) - 1.0, 0.0)
+    return int(round(routed)), int(round(tiles * tile))
 
 
 def _on_one_device() -> bool:

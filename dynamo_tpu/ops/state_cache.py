@@ -1,20 +1,22 @@
 """The cache of a family that keeps a recurrent state beside its pages
-(docs/hybrid_models.md).
+(docs/hybrid_models.md: models/hybrid.py, models/nemotron_h.py).
 
-Three layers in four of such a model keep no keys and values: each keeps,
-for every sequence, a matrix-valued state of fixed size and the last inputs
-of a short convolution. Those belong to the LANE a sequence occupies, not
-to its pages. `StateCache` is the K store of such a family: a registered
-pytree that holds the K page pool of the layers that do attend, the state
-store `[linear layers, lanes + 1, ...]` (the last slot is scratch: padded
+Most layers of such a model keep no keys and values: each keeps, for every
+sequence, a matrix-valued state of fixed size and the last inputs of a
+short convolution. Those belong to the LANE a sequence occupies, not to its
+pages. The family's configuration says how many layers of each kind it has
+and what one lane keeps in one of them (`StateSpec`). `StateCache` is the K
+store of such a family: a registered pytree that holds the K page pool of
+the layers that do attend, the state store `[state layers, lanes + 1, ...]`
+(the last slot is scratch: padded
 rows and lanes that are not decoding read and write there), and what a
 dispatch says of its rows. It rides every jitted program in `kv_k`'s place
 (as ops/kv_quant.QuantKV does for a quantized pool), is donated with it and
 comes back updated, so no program of the engine takes an argument more.
 
-    pages   [full layers, pages, rows, KH*D]   K pool (V is a plain pool)
-    state   [linear layers, lanes + 1, heads, dk, dv]   float32
-    conv    [linear layers, lanes + 1, taps - 1, channels]
+    pages   [attention layers, pages, rows, KH*D]   K pool (V is a plain pool)
+    state   [state layers, lanes + 1, *state_shape]   float32
+    conv    [state layers, lanes + 1, taps - 1, channels]
     lanes   [row slots] i32: the lane of each row of the NEXT dispatch that
             packs rows (a prefill batch, a mixed step), set by the host
             (`with_lanes`); the scratch slot for padding. A decode block's
@@ -30,6 +32,10 @@ the host copies them out only for a dispatch that holds such a request.
 """
 
 from __future__ import annotations
+
+import math
+from dataclasses import dataclass
+from typing import Any, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -90,58 +96,58 @@ class StateCache:
         ) + ")"
 
 
+@dataclass(frozen=True)
+class StateSpec:
+    """What a stateful family's configuration says of its caches
+    (`<Config>.state_spec()`): three counts of layers, none of which need be
+    `num_layers`, and the shapes ONE lane keeps in ONE state layer."""
+
+    state_layers: int  # layers that keep a recurrent state
+    attention_layers: int  # layers that keep pages
+    routed_layers: int  # layers whose chosen experts are recorded
+    state_shape: Tuple[int, ...]  # the matrix state, in `state_dtype`
+    conv_shape: Tuple[int, int]  # (taps - 1, channels), in the model's dtype
+    state_dtype: Any
+    experts_per_token: int
+
+
 def state_bytes_per_lane(c) -> int:
-    """Bytes ONE lane's state takes over all the linear layers of `c` (a
-    models/hybrid.HybridConfig): the matrix state and the convolution's
-    tail. What pool sizing takes out of the pages' room, a lane at a time."""
-    n_linear = c.num_layers - c.num_layers // c.full_attention_interval
-    state = (
-        c.linear_num_value_heads * c.linear_key_head_dim
-        * c.linear_value_head_dim * jnp.dtype(c.state_dtype).itemsize
-    )
-    conv = (
-        (c.linear_conv_kernel_dim - 1) * conv_channels(c)
-        * jnp.dtype(c.dtype).itemsize
-    )
-    return n_linear * (state + conv)
-
-
-def conv_channels(c) -> int:
-    """Channels of the linear mixer's convolution: q, k and v side by side."""
-    return (
-        2 * c.linear_num_key_heads * c.linear_key_head_dim
-        + c.linear_num_value_heads * c.linear_value_head_dim
-    )
+    """Bytes ONE lane's state takes over all the state layers of `c` (a
+    configuration with `state_spec()`): the matrix state and the
+    convolution's tail. What pool sizing takes out of the pages' room, a
+    lane at a time."""
+    spec = c.state_spec()
+    state = math.prod(spec.state_shape) * jnp.dtype(spec.state_dtype).itemsize
+    conv = math.prod(spec.conv_shape) * jnp.dtype(c.dtype).itemsize
+    return spec.state_layers * (state + conv)
 
 
 def alloc_state_cache(c, num_pages: int, page_size: int, max_seqs: int,
                       max_tokens: int, row_slots: int = 0):
-    """(StateCache, V pool) of a models/hybrid.HybridConfig `c`: the K and V
-    pools of its full-attention layers, `num_pages` pages each, and the
+    """(StateCache, V pool) of a configuration `c` with `state_spec()`: the K
+    and V pools of its attention layers, `num_pages` pages each, and the
     zeroed state store of `max_seqs` lanes and one scratch slot.
     `max_tokens`: the most token slots one prefill batch or mixed step
     packs; `row_slots`: the most rows (`max_seqs` where smaller)."""
     from .kv_quant import alloc_kv_store
 
-    n_full = c.num_layers // c.full_attention_interval
-    n_linear = c.num_layers - n_full
+    spec = c.state_spec()
     pools = [
-        alloc_kv_store(n_full, num_pages, page_size, c.num_kv_heads,
-                       c.head_dim, c.dtype, "none")
+        alloc_kv_store(spec.attention_layers, num_pages, page_size,
+                       c.num_kv_heads, c.head_dim, c.dtype, "none")
         for _ in range(2)
     ]
-    K = c.num_experts_per_tok
+    K = spec.experts_per_token
     cache = StateCache(
         pages=pools[0],
         state=jnp.zeros(
-            (n_linear, max_seqs + 1, c.linear_num_value_heads,
-             c.linear_key_head_dim, c.linear_value_head_dim), c.state_dtype),
+            (spec.state_layers, max_seqs + 1, *spec.state_shape),
+            spec.state_dtype),
         conv=jnp.zeros(
-            (n_linear, max_seqs + 1, c.linear_conv_kernel_dim - 1,
-             conv_channels(c)), c.dtype),
+            (spec.state_layers, max_seqs + 1, *spec.conv_shape), c.dtype),
         lanes=jnp.full((max(row_slots, max_seqs),), max_seqs, jnp.int32),
         routed_ring=jnp.zeros(
-            (ROUTED_RING, c.num_layers, max_seqs, K), jnp.int32),
-        routed_flat=jnp.zeros((c.num_layers, max_tokens, K), jnp.int32),
+            (ROUTED_RING, spec.routed_layers, max_seqs, K), jnp.int32),
+        routed_flat=jnp.zeros((spec.routed_layers, max_tokens, K), jnp.int32),
     )
     return cache, pools[1]

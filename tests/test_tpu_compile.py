@@ -37,6 +37,10 @@ MIXTRAL = {"H": 32, "KH": 8, "layers": 2, "pool": 8193}
 CELLS = {
     "mixtral": MIXTRAL,
     "mistral": {"H": 32, "KH": 8, "layers": 16, "pool": 1378, "B": 32, "table": 64},
+    # the state-space family's one attention layer: 32 query heads over 2
+    # key-value heads (a group of 16, KH*D = 256), beside the auto pool of
+    # some 58,000 pages that 9.3 GB of weights and the state store leave
+    "nemotron": {"H": 32, "KH": 2, "layers": 1, "pool": 58001, "B": 32, "table": 64},
 }
 
 
@@ -395,6 +399,7 @@ CELL_ATTENTION = {
     "mistral-7b-d16": (32, 8, 128, 16, 1378),
     "mixtral-8x7b-d2": (32, 8, 128, 2, 8193),
     "qwen3-next-80b-a3b-ep4-d8": (16, 2, 256, 2, 4096),
+    "nemotron-3-super-120b-a12b-ep4-d11": (32, 2, 128, 1, 58001),
 }
 
 
@@ -491,6 +496,78 @@ def test_the_cells_mixed_step_family_compiles_with_its_kernels(
     assert mem.temp_size_in_bytes < expert_matrix, (
         f"{mem.temp_size_in_bytes / 2**20:.0f} MiB of temporaries"
     )
+
+
+@pytest.mark.parametrize("program", ("decode", "mixed_256", "mixed_2048"))
+def test_the_state_space_familys_steps_compile_at_the_cell_size(
+    program, one_chip, no_persistent_cache, tpu_gate
+):
+    """models/nemotron_h.py at its cell's configuration (11 layers, 128 of
+    512 experts held, 9.3 GB of weights), its state store of 33 slots and
+    the auto pool, for the described v5e: the decode step (32 lanes) and
+    the smallest and the largest member of the mixed_step family (40 rows,
+    a prefill batch of 8). Each routed layer brings two grouped matmuls
+    (k/n tiles of 1,024 and 896: 2,688 is 21 x 128), the one attention layer
+    one kernel a decode step and two a mixed step. The decode step updates
+    the state in the donated store: its temporaries stay under one layer's
+    state (138 MB at 33 slots); a mixed step's are those of a prefill batch
+    of 8 whatever its 40 rows hold, and the whole fits the chip."""
+    import os
+    import sys
+
+    from dynamo_tpu.models import nemotron_h
+    from dynamo_tpu.ops.state_cache import alloc_state_cache
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, os.path.join(root, "benchmark"))
+    from worker_entry import build_model_config, load_config
+
+    cfg = build_model_config(load_config(os.path.join(
+        root, "benchmark", "configs", "nemotron-3-super-120b-a12b-ep4-d11.json"),
+        False))
+    sds = _shapes(one_chip)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
+
+    params = on_chip(jax.eval_shape(
+        functools.partial(nemotron_h.init_params, cfg), jax.random.PRNGKey(0)))
+    cache, kv_v = jax.eval_shape(
+        lambda: alloc_state_cache(cfg, 58001, PAGE, 32, 2048, 40))
+    cache, kv_v = on_chip(cache), on_chip(kv_v)
+    i32 = jnp.int32
+    if program == "decode":
+        def step(params, tokens, positions, kv_k, kv_v, tables, seq_lens):
+            return nemotron_h.decode_forward(
+                params, cfg, tokens, positions, kv_k, kv_v, tables, seq_lens)
+
+        compiled = jax.jit(step, donate_argnums=(3, 4)).lower(
+            params, sds((32,), i32), sds((32,), i32), cache, kv_v,
+            sds((32, 64), i32), sds((32,), i32)).compile()
+        kernels = 5 * 2 + 1
+    else:
+        tokens, rows = int(program.split("_")[1]), 40
+
+        def step(params, tokens, positions, row_ids, kv_k, kv_v, tables,
+                 row_starts, row_lens, ctx_lens, last_flat):
+            return nemotron_h.ragged_forward(
+                params, cfg, tokens, positions, row_ids, kv_k, kv_v, tables,
+                row_starts, row_lens, ctx_lens, last_flat, long_rows=8)
+
+        compiled = jax.jit(step, donate_argnums=(4, 5)).lower(
+            params, sds((tokens,), i32), sds((tokens,), i32),
+            sds((tokens,), i32), cache, kv_v, sds((rows, 65), i32),
+            sds((rows,), i32), sds((rows,), i32), sds((rows,), i32),
+            sds((rows,), i32)).compile()
+        kernels = 5 * 2 + 2
+    assert compiled.as_text().count("tpu_custom_call") == kernels
+    mem = compiled.memory_analysis()
+    need = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert need < HBM_BYTES, f"{program} needs {need / 2**30:.2f} GiB"
+    one_layers_state = 33 * 128 * 64 * 128 * 4
+    limit = one_layers_state if program == "decode" else 12 * one_layers_state
+    assert mem.temp_size_in_bytes < limit, (
+        f"{mem.temp_size_in_bytes / 2**20:.0f} MiB of temporaries")
 
 
 def test_the_piped_mixed_steps_carry_programs_compile_at_the_cell_size(
