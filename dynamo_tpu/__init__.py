@@ -22,6 +22,10 @@ Layer map (mirrors reference SURVEY.md §1):
                                                prefill+decode (one flat
                                                buffer, one dispatch;
                                                docs/ragged_attention.md)
+                 pallas_delta_step.py        — the hybrid family's decode
+                                               recurrence, a lane's state
+                                               read and written once in
+                                               place (docs/hybrid_models.md)
                  ring_attention.py           — sequence-parallel ring prefill
   parallel/  — mesh construction, shardings (tp/dp/pp/ep/sp)
   planner/   — SLA planner: load prediction, perf interpolation, autoscale

@@ -372,6 +372,20 @@ COMPILE_SURFACES = {
         "warmup": True,
         "help": "batched causal prefill attention against the paged pool",
     },
+    "delta_step_pallas": {
+        "module": "dynamo_tpu/ops/pallas_delta_step.py",
+        "kind": "jit",
+        "donate": (),
+        "static": ("heads", "interpret"),
+        "axes": {
+            "B": "decode_block's lanes (the hybrid family's decode step "
+                 "alone calls it, once a linear layer)",
+        },
+        "warmup": True,
+        "help": "one token of the gated delta rule a lane, the state store "
+                "updated in place (aliased to the result, not donated: the "
+                "caller's step owns the store)",
+    },
     "ring_attention_local": {
         "module": "dynamo_tpu/ops/ring_attention.py",
         "kind": "shard_map",

@@ -563,6 +563,12 @@ class JaxEngine:
             self.attention_impl = resolved_attention(
                 c.head_dim, c.num_kv_heads, kvq != "none"
             )
+            if self._stateful:
+                # and which a decode step's recurrence takes (a family
+                # with no kernel of its own for it has none to resolve)
+                resolve = getattr(family, "recurrence_impl", None)
+                self.attention_impl["recurrence"] = (
+                    resolve(c) if resolve else "xla")
             # the ragged kernel's q tile, 1 on the XLA path: what the
             # mixed_attn_* counts of _dispatch_mixed are reckoned in
             self._ragged_tile = ragged_tile(c.dtype, c.head_dim, kvq != "none")

@@ -40,10 +40,12 @@ import jax.numpy as jnp
 
 from ..ops.kv_quant import kv_layer, kv_page_size, kv_write
 from ..ops.paged_attention import (
+    _pallas_eligible,
     paged_attention_decode,
     prefill_attention_batched,
     ragged_attention,
 )
+from ..ops.pallas_delta_step import delta_step_pallas, takes as kernel_takes
 from ..ops.row_recurrence import flat_conv, rows_recurrence
 from ..ops.state_cache import StateCache, StateSpec, state_bytes_per_lane
 from . import llama, moe
@@ -351,6 +353,22 @@ def delta_step(S, q, k, v, g, beta):
     return S, jnp.sum(S * q[..., :, None], axis=-2)
 
 
+def recurrence_impl(c: HybridConfig) -> str:
+    """Which implementation a decode step's recurrence takes: "pallas"
+    (ops/pallas_delta_step.py: a lane's state read once and written once, in
+    place in the store) under the gate the attention kernels have (a TPU
+    backend, an engine of one device, the value head's width whole lane
+    registers: paged_attention._pallas_eligible) and where the kernel takes
+    the state's shape and dtype; "xla" (`delta_step`) everywhere else. The
+    mixed step and the batched prefill keep `delta_step` for every row's
+    first token whatever this says. What the engine logs at start and
+    publishes as stats()["attention_impl"]["recurrence"]."""
+    spec = c.state_spec()
+    eligible = (kernel_takes(spec.state_shape, spec.state_dtype)
+                and _pallas_eligible(c.linear_value_head_dim))
+    return "pallas" if eligible else "xla"
+
+
 def delta_chunk(S, q, k, v, g, beta):
     """CHUNK tokens of the same recurrence in closed form (the chunked
     gated delta rule): S [R, nv, dk, dv]; q, k [R, C, nv, dk]; v [R, C, nv,
@@ -540,6 +558,7 @@ def decode_forward(
     phys = jnp.take_along_axis(page_tables, logical[:, None], axis=1)[:, 0]
     phys = jnp.where(positions < P_tab * page_size, phys, 0)
     offs = positions % page_size
+    in_place = recurrence_impl(c) == "pallas"
 
     def linear_fn(layer, h, state, conv, ll):
         mixed, z, beta, g = _mixer_inputs(layer, h, c)
@@ -548,12 +567,16 @@ def decode_forward(
         y = jnp.einsum("btc,ct->bc", window.astype(f32),
                        layer["w_conv"].astype(f32))
         q, k, v = _split_qkv(jax.nn.silu(y), c)
-        S = jax.lax.dynamic_index_in_dim(state, ll, 0, False)[:B]
-        S_new, o = delta_step(S.astype(f32), q, k, v, g, beta)
-        S_new = jnp.where(live[:, None, None, None], S_new.astype(S.dtype), S)
+        if in_place:
+            state, o = delta_step_pallas(state, ll, q, k, v, g, beta, live)
+        else:
+            S = jax.lax.dynamic_index_in_dim(state, ll, 0, False)[:B]
+            S_new, o = delta_step(S.astype(f32), q, k, v, g, beta)
+            S_new = jnp.where(
+                live[:, None, None, None], S_new.astype(S.dtype), S)
+            state = jax.lax.dynamic_update_slice(
+                state, S_new[None], (ll, 0, 0, 0, 0))
         tail = jnp.where(live[:, None, None], window[:, 1:], tail)
-        state = jax.lax.dynamic_update_slice(
-            state, S_new[None], (ll, 0, 0, 0, 0))
         conv = jax.lax.dynamic_update_slice(conv, tail[None], (ll, 0, 0, 0))
         return _mixer_out(layer, o, z, c), state, conv
 

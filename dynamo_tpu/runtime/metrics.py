@@ -84,7 +84,7 @@ METRICS = {
     # bring-up surface: what the engine runs on and which paths it resolved
     # (chip_smoke.py reads these off the worker metrics topic)
     "device": {"kind": "info", "layer": "engine", "help": "Device as JAX reports it (platform, device_kind, device_count) plus jax/jaxlib/libtpu versions."},
-    "attention_impl": {"kind": "info", "layer": "engine", "help": "Implementation (pallas/xla) the decode, prefill and ragged attention ops resolved to."},
+    "attention_impl": {"kind": "info", "layer": "engine", "help": "Implementation (pallas/xla) the decode, prefill and ragged attention ops resolved to, and a stateful family's decode recurrence."},
     "decode_pool_mode": {"kind": "info", "layer": "engine", "help": "KV-write strategy of the fused decode block: always scatter (kept for the readers of stats())."},
     "native_core": {"kind": "info", "layer": "engine", "help": "True when the C++ core (csrc/) is loaded, False on the pure-Python twin."},
     "device_memory": {"kind": "info", "layer": "engine", "help": "Per local device: bytes_limit, bytes_in_use, peak_bytes_in_use from memory_stats()."},

@@ -238,6 +238,45 @@ def test_the_chunked_and_the_step_form_of_the_recurrence_agree():
         < 1e-4 * float(jnp.abs(want).max())
 
 
+def test_a_decode_step_through_the_kernel_is_the_xla_steps(monkeypatch):
+    """`decode_forward` with ops/pallas_delta_step.py forced on
+    (interpreted; value heads of 128 x 128, which the kernel takes) gives
+    the logits and the cache of the path through `delta_step`: lanes that
+    decode from a prompt's state, one that does not and keeps its own."""
+    cfg = hybrid.HybridConfig.tiny_hybrid(
+        dtype=jnp.float32, linear_key_head_dim=128, linear_value_head_dim=128)
+    params = hybrid.init_params(cfg, jax.random.PRNGKey(3))
+    cache, kv_v = alloc_state_cache(cfg, 40, PAGE, 4, 128, 8)
+    fill = jax.jit(lambda *a: hybrid.prefill_forward_batched(a[0], cfg, *a[1:]))
+    seqs = {lane: sequence(10 + lane, 30 + lane) for lane in (0, 1, 3)}
+    _, cache, kv_v = prefill(
+        params, cache, kv_v,
+        [(lane, seq, 0, table_of(lane)) for lane, seq in seqs.items()], 64,
+        fn=fill)
+    assert hybrid.recurrence_impl(cfg) == "xla"  # the CPU's own answer
+    xla = jax.jit(lambda *a: hybrid.decode_forward(a[0], cfg, *a[1:]))
+    monkeypatch.setattr(hybrid, "recurrence_impl", lambda c: "pallas")
+    monkeypatch.setattr(hybrid, "delta_step_pallas", functools.partial(
+        hybrid.delta_step_pallas, interpret=True))
+    kernel = jax.jit(lambda *a: hybrid.decode_forward(a[0], cfg, *a[1:]))
+    assert "pallas_call" in str(jax.make_jaxpr(kernel)(
+        params, *(jnp.zeros((4,), jnp.int32),) * 2, cache, kv_v,
+        jnp.zeros((4, 8), jnp.int32), jnp.zeros((4,), jnp.int32)))
+    a = b = (cache, kv_v)
+    for t in range(4):  # lane 1 sits out: between two chunks of its prompt
+        lanes = {lane: (7 + t + lane, len(seqs[lane]) + t) for lane in (0, 3)}
+        want, *a = decode(params, *a, lanes, fn=xla)
+        got, *b = decode(params, *b, lanes, fn=kernel)
+        assert off(got, want) < TOL, t
+    for name in hybrid.StateCache.FIELDS:
+        x, y = np.asarray(getattr(a[0], name)), np.asarray(getattr(b[0], name))
+        assert np.abs(x - y).max() <= 1e-5 * max(np.abs(x).max(), 1), name
+    assert np.abs(np.asarray(a[1]) - np.asarray(b[1])).max() < 1e-5
+    state = np.asarray(b[0].state)
+    assert (state[:, [1, 2, 4]] == np.asarray(cache.state)[:, [1, 2, 4]]).all()
+    assert np.abs(state[:, [0, 3]] - np.asarray(cache.state)[:, [0, 3]]).max() > 0
+
+
 def routed_parts(params_of, cfg_of, x, shares):
     """Each share's routed part of one layer's block over x (its output
     less x and the shared expert's part), and the experts chosen."""
@@ -381,7 +420,8 @@ def test_the_engine_serves_the_references_tokens_and_says_what_it_routed(params)
     assert stats["state_lanes_reset"] == 3 and stats["mixed_steps"] > 0
     assert stats["step_state_bytes"] > 0 and stats["state_bytes"] > 0
     assert stats["expert_rows_routed"] > 0
-    assert set(stats["attention_impl"]) == {"decode", "prefill", "ragged"}
+    assert stats["attention_impl"] == dict.fromkeys(
+        ("decode", "prefill", "ragged", "recurrence"), "xla")
 
 
 def test_the_mixed_steps_attention_counters_follow_the_packs(params):

@@ -14,6 +14,7 @@ the worker that is handed this file loads it.
 """
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -171,9 +172,10 @@ def test_whole_pool_kernels_compile_at_the_cell_size(
     assert temp < layer_bytes // 8, f"{op}: {temp / 2**20:.0f} MiB of temporaries"
 
 
-def _pool_sized_ops(lowered, layer_numel):
+def _pool_sized_ops(lowered, layer_numel, tail=()):
     """Operation names of a lowered program whose result holds at least
-    one layer of the pool, counted (stablehlo, before any backend)."""
+    one layer of the pool (and, where `tail` is given, ends in those
+    dimensions), counted (stablehlo, before any backend)."""
     import collections
 
     import numpy as np
@@ -185,6 +187,7 @@ def _pool_sized_ops(lowered, layer_numel):
         for r in op.results:
             if isinstance(r.type, ir.RankedTensorType) and (
                 int(np.prod(r.type.shape)) >= layer_numel
+                and tuple(r.type.shape[len(r.type.shape) - len(tail):]) == tail
             ):
                 found[op.name] += 1
         return ir.WalkResult.ADVANCE
@@ -599,3 +602,96 @@ def test_the_piped_mixed_steps_carry_programs_compile_at_the_cell_size(
         mem = compiled.memory_analysis()
         assert mem.temp_size_in_bytes <= 4 * mem.argument_size_in_bytes
         assert mem.argument_size_in_bytes < 2**20
+
+
+# the hybrid cell's state store: 6 linear layers, 32 lanes and the scratch
+# slot, 32 value heads of a 128 x 128 float32 state
+DELTA_STORE = (6, 33, 32, 128, 128)
+
+
+@pytest.mark.parametrize("heads", (8, 16, 32))
+def test_the_delta_step_kernel_compiles_at_the_cell_size(
+    heads, one_chip, no_persistent_cache
+):
+    """ops/pallas_delta_step.py alone for the described v5e, the whole store
+    as its operand and a layer in the middle, at each block of heads that
+    was timed on the chip (PERF.md, PR 47; 32 is what the shapes give): the
+    store is aliased to the result, so the program holds no second one."""
+    from dynamo_tpu.ops.pallas_delta_step import delta_step_pallas
+
+    sds = _shapes(one_chip)
+    f32 = jnp.float32
+    B, nv, dk, dv = 32, *DELTA_STORE[2:]
+    compiled = jax.jit(
+        functools.partial(delta_step_pallas, heads=heads), donate_argnums=(0,)
+    ).lower(
+        sds(DELTA_STORE, f32), sds((), jnp.int32), sds((B, nv, dk), f32),
+        sds((B, nv, dk), f32), sds((B, nv, dv), f32), sds((B, nv), f32),
+        sds((B, nv), f32), sds((B,), jnp.bool_)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    mem = compiled.memory_analysis()
+    store = 4 * math.prod(DELTA_STORE)
+    assert mem.alias_size_in_bytes >= store
+    assert mem.temp_size_in_bytes < 2**20
+
+
+def test_the_hybrid_decode_step_compiles_with_the_delta_kernel_inside(
+    one_chip, no_persistent_cache, tpu_gate
+):
+    """models/hybrid.py's decode step at its cell's configuration (8
+    layers, 128 of 512 experts held, 32 lanes, the state store of 33 slots)
+    for the described v5e: every linear layer's recurrence is the kernel
+    (6), beside three grouped matmuls a layer and the two attention
+    layers' decode kernel. Nothing of the store's size is produced but the
+    kernel's own aliased result (no slice of a layer's slots, no
+    `dynamic_update_slice` back: the shape of
+    test_lowered_programs_touch_the_pool_only_to_update_it), and the
+    compiled step's temporaries stay well under the store's size."""
+    import os
+    import sys
+
+    from dynamo_tpu.models import hybrid
+    from dynamo_tpu.ops.state_cache import alloc_state_cache
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, os.path.join(root, "benchmark"))
+    from worker_entry import build_model_config, load_config
+
+    cfg = build_model_config(load_config(os.path.join(
+        root, "benchmark", "configs", "qwen3-next-80b-a3b-ep4-d8.json"), False))
+    assert hybrid.recurrence_impl(cfg) == "pallas"
+    sds = _shapes(one_chip)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
+
+    params = on_chip(jax.eval_shape(
+        functools.partial(hybrid.init_params, cfg), jax.random.PRNGKey(0)))
+    cache, kv_v = jax.eval_shape(
+        lambda: alloc_state_cache(cfg, 4096, PAGE, 32, 2048, 40))
+    assert cache.state.shape == DELTA_STORE
+    cache, kv_v = on_chip(cache), on_chip(kv_v)
+    i32 = jnp.int32
+
+    def step(params, tokens, positions, kv_k, kv_v, tables, seq_lens):
+        return hybrid.decode_forward(
+            params, cfg, tokens, positions, kv_k, kv_v, tables, seq_lens)
+
+    lowered = jax.jit(step, donate_argnums=(3, 4)).lower(
+        params, sds((32,), i32), sds((32,), i32), cache, kv_v,
+        sds((32, 64), i32), sds((32,), i32))
+    # 32 lanes' states of one layer, or more: the kernel's result in the
+    # one function the six calls share, and each call's
+    one_layers_slots = math.prod(DELTA_STORE[1:])
+    found = _pool_sized_ops(
+        lowered, one_layers_slots // 33 * 32, tail=DELTA_STORE[2:])
+    assert found == {"stablehlo.custom_call": 1, "func.call": 6}, found
+    compiled = lowered.compile()
+    assert compiled.as_text().count("tpu_custom_call") == 6 + 3 * 8 + 2
+    mem = compiled.memory_analysis()
+    need = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert need < HBM_BYTES, f"the decode step needs {need / 2**30:.2f} GiB"
+    # 248 MiB with the kernel or without (a period's `w_qkvz` copied a
+    # step: ROADMAP.md A15): under the store's 396 MiB, so no second store
+    assert mem.temp_size_in_bytes < 4 * 6 * one_layers_slots * 3 // 4, (
+        f"{mem.temp_size_in_bytes / 2**20:.0f} MiB of temporaries")
