@@ -24,9 +24,10 @@ context is 0 starts from a zero state whatever the lane held, so admission,
 a lane's reuse and a preempted sequence's recomputation need no program of
 their own.
 
-Layers are stacked by period and scanned over periods; the expert stacks
-stay whole (a slice handed to a Pallas call is a copy: moe.ExpertStack)
-and a layer's experts are groups `layer * held ...` of one grouped matmul.
+Layers are stacked by kind and walked in the periods' order, each leaf
+indexed once where it is used; the expert stacks stay whole (a slice handed
+to a Pallas call is a copy: moe.ExpertStack) and a layer's experts are
+groups `layer * held ...` of one grouped matmul.
 """
 
 from __future__ import annotations
@@ -66,13 +67,6 @@ INIT_SCALE = 0.02
 #: (errors of 1e-5 of a product; `highest`, six passes, reads the same to
 #: the tests' tolerance at twice the time)
 CHUNK_PRECISION = jax.lax.Precision.HIGH
-#: the periods as a `lax.scan` (one period's program, compiled once) or
-#: unrolled (every slice of a stacked weight static). A scan hands each
-#: period its slice of every stacked weight as a COPY (0.9 ms of a 9.9 ms
-#: decode step at two periods of the cell's widths: PERF.md, PR 44), so the
-#: stack is unrolled while it is a few periods deep; the scan is for a
-#: depth at which compile time matters more
-SCAN_PERIODS = False
 
 
 @dataclass(frozen=True)
@@ -451,58 +445,43 @@ def routed_block(layer, stacks, li, x, c: HybridConfig, valid=None):
 
 def _layer_stack(params, c: HybridConfig, x, cache: StateCache, kv_v,
                  linear_fn, full_fn, valid=None):
-    """Scan x [T, H] (any leading shape the two mixers take) over the
-    periods: `linear_fn(layer, h, state, conv, ll) -> (out, state, conv)`
-    on the normed input of linear layer `ll`, `full_fn(layer, h, pages,
-    kv_v, lf) -> (out, pages, kv_v)` of full-attention layer `lf`.
+    """x [T, H] (any leading shape the two mixers take) through the layers
+    in the periods' order: `linear_fn(layer, h, state, conv, ll) -> (out,
+    state, conv)` on the normed input of linear layer `ll`, `full_fn(layer,
+    h, pages, kv_v, lf) -> (out, pages, kv_v)` of full-attention layer `lf`.
+    A layer's leaves are taken from the STORED stacks with one static index
+    each (a stack reshaped by period and indexed twice is a period's
+    weights copied every step: PERF.md, PR 48).
     -> (x, cache with pages, state and conv as the layers left them, kv_v,
     the experts chosen [L, tokens, K])."""
     n = c.full_attention_interval
-    P = c.num_layers // n
     layers = params["layers"]
-    lin = jax.tree.map(lambda a: a.reshape(P, n - 1, *a.shape[1:]),
-                       layers["linear"])
     stacks = {k: layers["moe"][k] for k in ("w_gate", "w_up", "w_down")}
-    small = {k: v.reshape(P, n, *v.shape[1:])
-             for k, v in layers["moe"].items() if k not in stacks}
+    small = {k: v for k, v in layers["moe"].items() if k not in stacks}
     lead = x.shape[:-1]
-
-    def period(carry, xs):
-        x, pages, kv_v, state, conv = carry
-        p, lin_p, full_p, moe_p = xs
-        chosen = []
-        for j in range(n):
-            if j < n - 1:
-                layer = jax.tree.map(lambda a: a[j], lin_p)
-                with jax.named_scope("linear_mixer"):
-                    h = norm(x, layer["norm"], c.rms_norm_eps)
-                    out, state, conv = linear_fn(
-                        layer, h, state, conv, p * (n - 1) + j)
-            else:
-                with jax.named_scope("gated_attention"):
-                    h = norm(x, full_p["norm"], c.rms_norm_eps)
-                    out, pages, kv_v = full_fn(full_p, h, pages, kv_v, p)
-            x = x + out
-            y, idx = routed_block(
-                jax.tree.map(lambda a: a[j], moe_p), stacks, p * n + j,
-                x.reshape(-1, x.shape[-1]), c, valid)
-            x = y.reshape(*lead, -1)
-            chosen.append(idx)
-        return (x, pages, kv_v, state, conv), jnp.stack(chosen)
-
-    carry = (x, cache.pages, kv_v, cache.state, cache.conv)
-    xs = (jnp.arange(P, dtype=jnp.int32), lin, layers["full"], small)
-    if SCAN_PERIODS:
-        carry, chosen = jax.lax.scan(period, carry, xs)
-    else:
-        kept = []
-        for p in range(P):
-            carry, ids = period(carry, jax.tree.map(lambda a: a[p], xs))
-            kept.append(ids)
-        chosen = jnp.stack(kept)
-    x, pages, kv_v, state, conv = carry
+    pages, state, conv = cache.pages, cache.state, cache.conv
+    chosen = []
+    for li in range(c.num_layers):
+        p, j = divmod(li, n)
+        if j < n - 1:
+            ll = p * (n - 1) + j
+            layer = jax.tree.map(lambda a: a[ll], layers["linear"])
+            with jax.named_scope("linear_mixer"):
+                h = norm(x, layer["norm"], c.rms_norm_eps)
+                out, state, conv = linear_fn(layer, h, state, conv, ll)
+        else:
+            layer = jax.tree.map(lambda a: a[p], layers["full"])
+            with jax.named_scope("gated_attention"):
+                h = norm(x, layer["norm"], c.rms_norm_eps)
+                out, pages, kv_v = full_fn(layer, h, pages, kv_v, p)
+        x = x + out
+        y, idx = routed_block(
+            jax.tree.map(lambda a: a[li], small), stacks, li,
+            x.reshape(-1, x.shape[-1]), c, valid)
+        x = y.reshape(*lead, -1)
+        chosen.append(idx)
     cache = cache.replace(pages=pages, state=state, conv=conv)
-    return x, cache, kv_v, chosen.reshape(c.num_layers, *chosen.shape[2:])
+    return x, cache, kv_v, jnp.stack(chosen)
 
 
 def _head(params, c: HybridConfig, x):
@@ -617,17 +596,15 @@ def _flat_linear_fn(c: HybridConfig, lanes, row_ids, row_starts, row_lens,
         mixed, z, beta, g = _mixer_inputs(layer, h, c)
         # the taps - 1 inputs before a row's first token come from the
         # lane's tail (zero for a sequence's first chunk)
-        tails = jnp.where(
-            fresh[:, None, None], 0,
-            jax.lax.dynamic_index_in_dim(conv, ll, 0, False)[lanes])
+        tails = jnp.where(fresh[:, None, None], 0, conv[ll, lanes])
         y, new_tails = flat_conv(
             mixed, layer["w_conv"].astype(f32), tails, row_ids, row_starts,
             row_lens)
         q, k, v = _split_qkv(jax.nn.silu(y), c)
+        # one gather on the stored arrays (a layer's slots sliced out first
+        # are copied whole before the rows are read out of the copy)
         S = jnp.where(
-            fresh[:, None, None, None], 0,
-            jax.lax.dynamic_index_in_dim(state, ll, 0, False)[lanes],
-        ).astype(f32)
+            fresh[:, None, None, None], 0, state[ll, lanes]).astype(f32)
         # (a zero row's beta and g of 0 leave a state as it was)
         S, o = rows_recurrence(
             S, (q, k, v, g, beta),

@@ -510,17 +510,15 @@ def _flat_mamba_fn(c: NemotronHConfig, lanes, row_ids, row_starts, row_lens,
 
     def mamba_fn(layer, h, state, conv, lm):
         z, mixed, dt = _mamba_inputs(layer, h, c)
-        tails = jnp.where(
-            fresh[:, None, None], 0,
-            jax.lax.dynamic_index_in_dim(conv, lm, 0, False)[lanes])
+        tails = jnp.where(fresh[:, None, None], 0, conv[lm, lanes])
         y, new_tails = flat_conv(
             mixed, layer["w_conv"].astype(f32), tails, row_ids, row_starts,
             row_lens)
         xs, Bm, Cm = _split_xbc(y + layer["conv_bias"], c)
+        # one gather on the stored arrays (a layer's slots sliced out first
+        # are copied whole before the rows are read out of the copy)
         S = jnp.where(
-            fresh[:, None, None, None], 0,
-            jax.lax.dynamic_index_in_dim(state, lm, 0, False)[lanes],
-        ).astype(f32)
+            fresh[:, None, None, None], 0, state[lm, lanes]).astype(f32)
         A = -jnp.exp(layer["a_log"].astype(f32))
         # (a zero row's dt of 0 leaves a state as it was)
         S, ys = rows_recurrence(

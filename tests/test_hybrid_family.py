@@ -19,6 +19,7 @@ import dataclasses
 import functools
 import json
 import os
+import re
 import sys
 
 import jax
@@ -117,6 +118,27 @@ def decode(params, cache, kv_v, lanes, fn=None):
     return np.asarray(logits), cache, kv_v
 
 
+def packed(rows, cache, kv_v, R, M):
+    """A mixed step's operands after the weights: rows of (lane, tokens,
+    context) packed into a flat buffer of M slots and R rows, the slots
+    past the pack on the last row's scratch position."""
+    toks, pos = np.zeros((M,), np.int32), np.full((M,), 8 * PAGE - 1, np.int32)
+    row_ids = np.full((M,), R - 1, np.int32)
+    starts, lens, ctx, last = (np.zeros((R,), np.int32) for _ in range(4))
+    starts[:] = M
+    tables = np.zeros((R, 8), np.int32)
+    at = 0
+    for r, (lane, tk, c0) in enumerate(rows):
+        m = len(tk)
+        toks[at: at + m], pos[at: at + m], row_ids[at: at + m] = tk, c0 + np.arange(m), r
+        starts[r], lens[r], ctx[r], last[r], tables[r] = at, m, c0, at + m - 1, table_of(lane)
+        at += m
+    return (
+        *(jnp.asarray(a) for a in (toks, pos, row_ids)),
+        cache.with_lanes([r[0] for r in rows]), kv_v,
+        *(jnp.asarray(a) for a in (tables, starts, lens, ctx, last)))
+
+
 def test_chunks_then_decode_steps_equal_the_full_forward(params):
     """(i) One prefill chunk, a second chunk from the state the first left,
     then decode steps through pages and state: the reference's full forward
@@ -164,22 +186,7 @@ def test_a_mixed_step_of_prefill_rows_and_decode_rows(params, n):
         (4, fresh, 0), (3, seqs[3][24:61], 24),
         (0, seqs[0][40:40 + n], 40), (1, seqs[1][40:40 + n], 40),
         (2, seqs[2][40:40 + n], 40)]
-    R, M = 8, 96
-    toks, pos = np.zeros((M,), np.int32), np.full((M,), 8 * PAGE - 1, np.int32)
-    row_ids = np.full((M,), R - 1, np.int32)
-    starts, lens, ctx, last = (np.zeros((R,), np.int32) for _ in range(4))
-    starts[:] = M
-    tables = np.zeros((R, 8), np.int32)
-    at = 0
-    for r, (lane, tk, c0) in enumerate(rows):
-        m = len(tk)
-        toks[at: at + m], pos[at: at + m], row_ids[at: at + m] = tk, c0 + np.arange(m), r
-        starts[r], lens[r], ctx[r], last[r], tables[r] = at, m, c0, at + m - 1, table_of(lane)
-        at += m
-    logits, cache, kv_v = RAGGED(
-        params, *(jnp.asarray(a) for a in (toks, pos, row_ids)),
-        cache.with_lanes([r[0] for r in rows]), kv_v,
-        *(jnp.asarray(a) for a in (tables, starts, lens, ctx, last)))
+    logits, cache, kv_v = RAGGED(params, *packed(rows, cache, kv_v, 8, 96))
     logits = np.asarray(logits)
     assert off(logits[0], want_fresh[32]) < TOL  # the stale state was not read
     assert off(logits[1], want[3][60]) < TOL
@@ -193,23 +200,122 @@ def test_a_mixed_step_of_prefill_rows_and_decode_rows(params, n):
         assert off(got[lane], want[lane][t]) < TOL
 
 
-def test_the_periods_scanned_are_the_periods_unrolled(params, monkeypatch):
-    """`hybrid.SCAN_PERIODS`: one period's program scanned over the periods
-    (copies of each period's weights on the chip) or the stack unrolled;
-    the same numbers either way."""
+FORWARDS = ("decode_forward", "ragged_forward", "prefill_forward_batched",
+            "prefill_forward")
+
+
+def forward_case(name, params):
+    """The operands of one call of the named forward after the weights, on
+    lanes 0 to 2 that hold 24 tokens' state and pages each and a lane 3
+    that holds a finished sequence's."""
+    seqs = {lane: sequence(30 + lane, 40) for lane in range(4)}
     cache, kv_v = alloc_state_cache(CFG, 40, PAGE, 4, 128, 8)
-    seq = sequence(2, 40)
-    outs = []
-    for scan in (False, True):
-        monkeypatch.setattr(hybrid, "SCAN_PERIODS", scan)
-        step = jax.jit(lambda *a: hybrid.prefill_forward_batched(a[0], CFG, *a[1:]))
-        logits, after, _ = step(
-            params, jnp.asarray([seq]), jnp.arange(40)[None],
-            cache.with_lanes([1]), kv_v, jnp.asarray(table_of(1))[None],
-            jnp.zeros((1,), jnp.int32), jnp.full((1,), 39, jnp.int32))
-        outs.append((np.asarray(logits), np.asarray(after.state)))
-    assert np.abs(outs[0][0] - outs[1][0]).max() < 1e-5
-    assert np.abs(outs[0][1] - outs[1][1]).max() < 1e-5
+    _, cache, kv_v = prefill(params, cache, kv_v, [
+        (lane, seq[:24], 0, table_of(lane)) for lane, seq in seqs.items()], 32)
+    if name == "decode_forward":  # lane 3 sits out
+        tok, pos = np.zeros((4,), np.int32), np.zeros((4,), np.int32)
+        tables = np.zeros((4, 8), np.int32)
+        for lane in range(3):
+            tok[lane], pos[lane], tables[lane] = seqs[lane][24], 24, table_of(lane)
+        return (jnp.asarray(tok), jnp.asarray(pos), cache, kv_v,
+                jnp.asarray(tables), jnp.asarray(np.where(pos > 0, pos + 1, 0)))
+    if name == "ragged_forward":
+        return packed([(3, sequence(40, 20), 0), (0, seqs[0][24:40], 24),
+                       (1, seqs[1][24:25], 24), (2, seqs[2][24:25], 24)],
+                      cache, kv_v, 8, 64)
+    toks, pos = np.zeros((2, 32), np.int32), np.zeros((2, 32), np.int32)
+    toks[0, :16], toks[1, :20] = seqs[0][24:40], sequence(40, 20)
+    pos[0], pos[1] = 24 + np.arange(32), np.arange(32)
+    if name == "prefill_forward_batched":
+        return (jnp.asarray(toks), jnp.asarray(pos), cache.with_lanes([0, 3]),
+                kv_v, jnp.asarray(np.stack([table_of(0), table_of(3)])),
+                jnp.asarray([24, 0], jnp.int32), jnp.asarray([15, 19], jnp.int32))
+    return (jnp.asarray(toks[0]), jnp.asarray(pos[0]), cache.with_lanes([0]),
+            kv_v, jnp.asarray(table_of(0)), jnp.int32(24), jnp.int32(15))
+
+
+def plain_stack(params, c, x, cache, kv_v, linear_fn, full_fn, valid=None):
+    """The layers one after another, each kind counted as it comes and its
+    leaves taken as `a[i]` from the stored stacks: what
+    `hybrid._layer_stack` has to compute."""
+    stored = params["layers"]
+    stacks = {k: stored["moe"][k] for k in ("w_gate", "w_up", "w_down")}
+    pages, state, conv = cache.pages, cache.state, cache.conv
+    linear = full = 0
+    chosen = []
+    for i in range(c.num_layers):
+        if (i + 1) % c.full_attention_interval:
+            layer = {k: a[linear] for k, a in stored["linear"].items()}
+            h = hybrid.norm(x, layer["norm"], c.rms_norm_eps)
+            out, state, conv = linear_fn(layer, h, state, conv, linear)
+            linear += 1
+        else:
+            layer = {k: a[full] for k, a in stored["full"].items()}
+            h = hybrid.norm(x, layer["norm"], c.rms_norm_eps)
+            out, pages, kv_v = full_fn(layer, h, pages, kv_v, full)
+            full += 1
+        x = x + out
+        routed = {k: a[i] for k, a in stored["moe"].items() if k not in stacks}
+        y, idx = hybrid.routed_block(
+            routed, stacks, i, x.reshape(-1, x.shape[-1]), c, valid)
+        x = y.reshape(x.shape)
+        chosen.append(idx)
+    cache = cache.replace(pages=pages, state=state, conv=conv)
+    return x, cache, kv_v, jnp.stack(chosen)
+
+
+@pytest.mark.parametrize("name", FORWARDS)
+def test_a_forward_is_a_plain_loop_over_the_stored_layers(params, name, monkeypatch):
+    """The named forward's logits and every leaf of the cache it leaves,
+    bit for bit those of the same forward over `plain_stack` (ISSUE 48: the
+    layers' order, the index of each kind's stack and of the state store
+    are all that `_layer_stack` decides)."""
+    args = forward_case(name, params)
+
+    def run():
+        fn = getattr(hybrid, name)
+        return jax.jit(lambda p, *a: fn(p, CFG, *a))(params, *args)
+
+    got = run()
+    monkeypatch.setattr(hybrid, "_layer_stack", plain_stack)
+    want = run()
+    assert np.abs(np.asarray(got[0])).max() > 0
+    for x, y in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
+        assert (np.asarray(x) == np.asarray(y)).all()
+    before = next(a for a in args if isinstance(a, hybrid.StateCache))
+    assert (np.asarray(got[1].state) != np.asarray(before.state)).any()
+
+
+@pytest.mark.parametrize("name", FORWARDS)
+def test_a_forward_slices_each_stored_leaf_once_a_layer(params, name):
+    """The lowered StableHLO of the named forward (no TPU compiler needed):
+    a stacked leaf of `params["layers"]` is never the operand of a
+    `reshape` (a stack reshaped by period and indexed twice is a period's
+    weights copied every step on the chip: PERF.md, PR 48), and is sliced
+    exactly once for each layer that uses it. The three expert stacks go
+    whole to the grouped matmul: their one use a layer merges layers and
+    experts into groups."""
+    fn = getattr(hybrid, name)
+    text = jax.jit(lambda p, *a: fn(p, CFG, *a), keep_unused=True).lower(
+        params, *forward_case(name, params)).as_text()
+    main = text[text.index("func.func public @main("):]
+    main = main[:main.index("\n  }\n") + 1]
+    _, linear, full = hybrid.periods(CFG)
+    users = {"linear": linear, "full": full, "moe": CFG.num_layers}
+    seen = 0
+    for i, (path, _) in enumerate(jax.tree_util.tree_flatten_with_path(params)[0]):
+        keys = [k.key for k in path]
+        if keys[0] != "layers":
+            continue
+        seen += 1
+        ops = re.findall(
+            rf"= \"?([\w.]+)\"? [^\n]*%arg{i}\b(?!:)", main)
+        if keys[2] in ("w_gate", "w_up", "w_down"):
+            assert ops == ["stablehlo.reshape"] * CFG.num_layers, (keys, ops)
+            assert f"tensor<{CFG.num_layers * CFG.num_experts}x" in main
+        else:
+            assert ops == ["stablehlo.slice"] * users[keys[1]], (name, keys, ops)
+    assert seen == sum(len(kind) for kind in params["layers"].values())
 
 
 def test_the_chunked_and_the_step_form_of_the_recurrence_agree():

@@ -15,6 +15,9 @@ the worker that is handed this file loads it.
 
 import functools
 import math
+import os
+import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -389,8 +392,6 @@ def _cell_models():
 def _q_tile_operands(lowered_text, kv_heads, lanes_wide):
     """The tile counts of the ragged kernel's q operands in a lowered
     program: tensor<tiles x KH x 16 x G*D x bf16>."""
-    import re
-
     return {
         int(m) for m in re.findall(
             rf"tensor<(\d+)x{kv_heads}x16x{lanes_wide}xbf16>", lowered_text)
@@ -515,29 +516,11 @@ def test_the_state_space_familys_steps_compile_at_the_cell_size(
     the state in the donated store: its temporaries stay under one layer's
     state (138 MB at 33 slots); a mixed step's are those of a prefill batch
     of 8 whatever its 40 rows hold, and the whole fits the chip."""
-    import os
-    import sys
-
     from dynamo_tpu.models import nemotron_h
-    from dynamo_tpu.ops.state_cache import alloc_state_cache
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    sys.path.insert(0, os.path.join(root, "benchmark"))
-    from worker_entry import build_model_config, load_config
-
-    cfg = build_model_config(load_config(os.path.join(
-        root, "benchmark", "configs", "nemotron-3-super-120b-a12b-ep4-d11.json"),
-        False))
     sds = _shapes(one_chip)
-
-    def on_chip(tree):
-        return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
-
-    params = on_chip(jax.eval_shape(
-        functools.partial(nemotron_h.init_params, cfg), jax.random.PRNGKey(0)))
-    cache, kv_v = jax.eval_shape(
-        lambda: alloc_state_cache(cfg, 58001, PAGE, 32, 2048, 40))
-    cache, kv_v = on_chip(cache), on_chip(kv_v)
+    cfg, params, cache, kv_v = _stateful_cell(
+        sds, nemotron_h, "nemotron-3-super-120b-a12b-ep4-d11", 58001)
     i32 = jnp.int32
     if program == "decode":
         def step(params, tokens, positions, kv_k, kv_v, tables, seq_lens):
@@ -635,6 +618,41 @@ def test_the_delta_step_kernel_compiles_at_the_cell_size(
     assert mem.temp_size_in_bytes < 2**20
 
 
+def _stateful_cell(sds, family, config, pages):
+    """(a stateful family's cell configuration, its weights, its cache and
+    its V pool as shapes on the described chip): `config` of
+    benchmark/configs at 32 lanes, a table of 2,048 positions and 40 rows."""
+    from dynamo_tpu.ops.state_cache import alloc_state_cache
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, os.path.join(root, "benchmark"))
+    from worker_entry import build_model_config, load_config
+
+    cfg = build_model_config(load_config(os.path.join(
+        root, "benchmark", "configs", config + ".json"), False))
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
+
+    params = on_chip(jax.eval_shape(
+        functools.partial(family.init_params, cfg), jax.random.PRNGKey(0)))
+    cache, kv_v = jax.eval_shape(
+        lambda: alloc_state_cache(cfg, pages, PAGE, 32, 2048, 40))
+    return cfg, params, on_chip(cache), on_chip(kv_v)
+
+
+def _hybrid_cell(sds):
+    """The hybrid cell: 8 layers, 128 of 512 experts held, the state store
+    of 33 slots, the recurrence through the kernel."""
+    from dynamo_tpu.models import hybrid
+
+    cfg, params, cache, kv_v = _stateful_cell(
+        sds, hybrid, "qwen3-next-80b-a3b-ep4-d8", 4096)
+    assert hybrid.recurrence_impl(cfg) == "pallas"
+    assert cache.state.shape == DELTA_STORE
+    return cfg, params, cache, kv_v
+
+
 def test_the_hybrid_decode_step_compiles_with_the_delta_kernel_inside(
     one_chip, no_persistent_cache, tpu_gate
 ):
@@ -646,31 +664,12 @@ def test_the_hybrid_decode_step_compiles_with_the_delta_kernel_inside(
     kernel's own aliased result (no slice of a layer's slots, no
     `dynamic_update_slice` back: the shape of
     test_lowered_programs_touch_the_pool_only_to_update_it), and the
-    compiled step's temporaries stay well under the store's size."""
-    import os
-    import sys
-
+    compiled step's temporaries are a few activations' (15.8 MiB; 247.6
+    while a period's weights were copied every step: PERF.md, PR 48)."""
     from dynamo_tpu.models import hybrid
-    from dynamo_tpu.ops.state_cache import alloc_state_cache
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    sys.path.insert(0, os.path.join(root, "benchmark"))
-    from worker_entry import build_model_config, load_config
-
-    cfg = build_model_config(load_config(os.path.join(
-        root, "benchmark", "configs", "qwen3-next-80b-a3b-ep4-d8.json"), False))
-    assert hybrid.recurrence_impl(cfg) == "pallas"
     sds = _shapes(one_chip)
-
-    def on_chip(tree):
-        return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
-
-    params = on_chip(jax.eval_shape(
-        functools.partial(hybrid.init_params, cfg), jax.random.PRNGKey(0)))
-    cache, kv_v = jax.eval_shape(
-        lambda: alloc_state_cache(cfg, 4096, PAGE, 32, 2048, 40))
-    assert cache.state.shape == DELTA_STORE
-    cache, kv_v = on_chip(cache), on_chip(kv_v)
+    cfg, params, cache, kv_v = _hybrid_cell(sds)
     i32 = jnp.int32
 
     def step(params, tokens, positions, kv_k, kv_v, tables, seq_lens):
@@ -691,7 +690,109 @@ def test_the_hybrid_decode_step_compiles_with_the_delta_kernel_inside(
     mem = compiled.memory_analysis()
     need = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert need < HBM_BYTES, f"the decode step needs {need / 2**30:.2f} GiB"
-    # 248 MiB with the kernel or without (a period's `w_qkvz` copied a
-    # step: ROADMAP.md A15): under the store's 396 MiB, so no second store
-    assert mem.temp_size_in_bytes < 4 * 6 * one_layers_slots * 3 // 4, (
+    assert mem.temp_size_in_bytes < 32 * 2**20, (
         f"{mem.temp_size_in_bytes / 2**20:.0f} MiB of temporaries")
+
+
+HLO_ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
+                "u16": 2, "f32": 4, "s32": 4, "u32": 4}
+#: what a mixed step of the hybrid cell still moves whole, by shape, and why
+#: it is no weight and no layer's states (PERF.md section 7; the parent of
+#: PR 48 had it too, and so has the state-space family's mixed step)
+MIXED_STEPS_OWN = {
+    "bf16[6,33,3,8192]": "the convolution's tails, relaid once a step for "
+                         "the six layers' scatters and once back",
+}
+
+
+def _hbm_copies(hlo_text, at_least):
+    """{name: (MiB, shape)} of the ops of every computation of a compiled
+    module (a loop's body as the entry; not the inside of a fusion, which
+    never reaches memory) that yield an array of `at_least` bytes or more
+    in HBM (no `S(1)` in its layout) and only move it: `copy`, `copy-done`,
+    `slice-done`, or a fusion named for slices, copies and bitcasts
+    alone."""
+    fused = set(re.findall(r"\bfusion\([^\n]*calls=%([\w.\-]+)", hlo_text))
+    op = re.compile(
+        r"^\s*(?:ROOT\s+)?%([\w.\-]+) = (\w+)\[([\d,]*)\](\{[^}]*\})? "
+        r"([\w\-]+)\(")
+    moves = re.compile(r"(?:(?:dynamic-slice|slice|copy|bitcast)_)+fusion")
+    found, inside = {}, None
+    for line in hlo_text.split("\n"):
+        head = re.match(r"^(?:ENTRY\s+)?%([\w.\-]+) \(.*\{$", line)
+        if head:
+            inside = head.group(1)
+            continue
+        m = op.match(line)
+        if not m or inside in fused:
+            continue
+        name, dtype, dims, layout, kind = m.groups()
+        if dtype not in HLO_ITEMSIZE or "S(1)" in (layout or ""):
+            continue
+        size = HLO_ITEMSIZE[dtype] * math.prod(int(d) for d in dims.split(",") if d)
+        if size >= at_least and (
+                kind in ("copy", "copy-done", "slice-done")
+                or kind == "fusion" and moves.fullmatch(name.split(".")[0])):
+            found[name] = (size / 2**20, f"{dtype}[{dims}]")
+    return found
+
+
+@pytest.mark.parametrize("program", ("decode", "block_of_8", "mixed_256"))
+def test_the_hybrid_steps_copy_no_weight(
+    program, one_chip, no_persistent_cache, tpu_gate
+):
+    """The hybrid cell's decode step, a block of eight of them under one
+    `lax.scan` (the shape of engine.py's `decode_block`, greedy in the
+    sampler's place) and the smallest mixed step (256 tokens, 40 rows, a
+    prefill batch of 8), for the described v5e: no op of any computation
+    yields 8 MiB or more in HBM by a copy or a slice alone. A stored stack
+    indexed twice (by period, then by layer) was 232 MiB of weights in six
+    such ops of a decode step and of a block's loop body, and a layer's
+    state store sliced before 40 rows were gathered from it six times 66
+    MiB a mixed step beside them (PERF.md, PR 48)."""
+    from dynamo_tpu.models import hybrid
+
+    sds = _shapes(one_chip)
+    cfg, params, cache, kv_v = _hybrid_cell(sds)
+    i32 = jnp.int32
+    lanes = (sds((32,), i32), sds((32,), i32))
+    if program == "mixed_256":
+        tokens, rows = 256, 40
+
+        def step(params, tokens, positions, row_ids, kv_k, kv_v, tables,
+                 row_starts, row_lens, ctx_lens, last_flat):
+            return hybrid.ragged_forward(
+                params, cfg, tokens, positions, row_ids, kv_k, kv_v, tables,
+                row_starts, row_lens, ctx_lens, last_flat, long_rows=8)
+
+        compiled = jax.jit(step, donate_argnums=(4, 5)).lower(
+            params, sds((tokens,), i32), sds((tokens,), i32),
+            sds((tokens,), i32), cache, kv_v, sds((rows, 65), i32),
+            *(sds((rows,), i32),) * 4).compile()
+    else:
+        def step(params, tokens, positions, kv_k, kv_v, tables, seq_lens):
+            logits, kv_k, kv_v = hybrid.decode_forward(
+                params, cfg, tokens, positions, kv_k, kv_v, tables, seq_lens)
+            return jnp.argmax(logits, -1).astype(i32), kv_k, kv_v
+
+        def block(params, tokens, positions, kv_k, kv_v, tables, seq_lens):
+            def one(carry, _):
+                tokens, positions, seq_lens, kv_k, kv_v = carry
+                nxt, kv_k, kv_v = step(
+                    params, tokens, positions, kv_k, kv_v, tables, seq_lens)
+                return (nxt, positions + 1, seq_lens + 1, kv_k, kv_v), nxt
+
+            return jax.lax.scan(
+                one, (tokens, positions, seq_lens, kv_k, kv_v), None, length=8)
+
+        compiled = jax.jit(
+            step if program == "decode" else block, donate_argnums=(3, 4)
+        ).lower(params, *lanes, cache, kv_v, sds((32, 64), i32),
+                sds((32,), i32)).compile()
+    text = compiled.as_text()
+    if program == "block_of_8":  # one step's kernels, in the loop's body
+        assert text.count("tpu_custom_call") == 6 + 3 * 8 + 2 and "while(" in text
+    copies = _hbm_copies(text, 8 * 2**20)
+    if program == "mixed_256":
+        copies = {k: v for k, v in copies.items() if v[1] not in MIXED_STEPS_OWN}
+    assert not copies, copies
