@@ -30,9 +30,7 @@ metric key is born or read:
     `env.get(k)`, `env[k]`, and `k in env`; unresolvable keys make the
     consumer direction INCOMPLETE and absence findings stay quiet.
   * literal scrape consumers — planner/metrics_source.py call-argument
-    strings (prometheus series names the planner differences), and
-    repo-root bench_*.py parsers (match-only: bench files live outside
-    the lint project, so they earn consumer credit but never fire).
+    strings (prometheus series names the planner differences).
 """
 
 from __future__ import annotations
@@ -40,8 +38,7 @@ from __future__ import annotations
 import ast
 import dataclasses
 import re
-from pathlib import Path
-from typing import Dict, List, Optional, Sequence, Set, Tuple
+from typing import Dict, List, Optional, Set, Tuple
 
 from ..core import Project, SourceFile, call_name, str_const
 from ..shard.callgraph import (
@@ -146,7 +143,6 @@ def build_scan(project: Project, index: FunctionIndex) -> MetScan:
             continue
         _scan_file(src, index, scan, envelopes)
     _scan_scrapers(project, index, scan)
-    _scan_bench(project, scan)
     return scan
 
 
@@ -578,7 +574,7 @@ def _record_consumer_key(
 
 
 # --------------------------------------------------------------------- #
-# literal scrape + bench consumers
+# literal scrape consumers
 # --------------------------------------------------------------------- #
 
 
@@ -607,38 +603,3 @@ def _scan_scrapers(
                 scan.consumers.setdefault(name, []).append(
                     (src.rel, arg.lineno)
                 )
-
-
-def bench_files(root: Path) -> Sequence[Path]:
-    return sorted(Path(root).glob("bench_*.py"))
-
-
-def _scan_bench(project: Project, scan: MetScan) -> None:
-    """Repo-root bench parsers earn consumer credit (a stats key a bench
-    asserts on IS consumed), but never fire: bench files live outside
-    the lint project, so there is no suppression channel for them."""
-    for path in bench_files(project.root):
-        try:
-            tree = ast.parse(path.read_text())
-        except (OSError, SyntaxError):  # pragma: no cover - bench parses
-            continue
-        rel = path.name
-        for node in ast.walk(tree):
-            if (
-                isinstance(node, ast.Call)
-                and isinstance(node.func, ast.Attribute)
-                and node.func.attr in ("get", "startswith")
-                and node.args
-            ):
-                key = str_const(node.args[0])
-                if key:
-                    scan.consumers.setdefault(key, []).append(
-                        (rel, node.lineno)
-                    )
-            elif isinstance(node, ast.Constant) and isinstance(
-                node.value, str
-            ):
-                if node.value.startswith("dynamo_"):
-                    scan.consumers.setdefault(node.value, []).append(
-                        (rel, node.lineno)
-                    )

@@ -57,13 +57,10 @@ class MetConsumeSymmetryRule(Rule):
             seen.add(key)
             return Violation(rule=self.name, path=path, line=line, message=msg)
 
-        in_project = {f.rel for f in project.files}
         for key, sites in sorted(scan.consumers.items()):
             if strip_series_suffix(key, entries) is not None:
                 continue
             for path, line in sites:
-                if path not in in_project:
-                    continue  # bench credit is match-only, never a finding
                 v = fire(
                     path, line,
                     f"consumer reads metric key '{key}' that METRICS does "

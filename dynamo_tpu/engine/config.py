@@ -88,9 +88,8 @@ class EngineConfig:
     # of a prefill dispatch followed by a decode dispatch. Guided rows
     # (packed FSM-mask operand), multi-LoRA rows (adapter-index operand)
     # and speculative verify rows (1+d one-token rows per lane) fuse too;
-    # only mm and pp/sp layouts ride their split variants. None = resolve
-    # from DYN_MIXED_DISPATCH (default on).
-    mixed_dispatch: Optional[bool] = None
+    # only mm and pp/sp layouts ride their split variants.
+    mixed_dispatch: bool = True
     # LoRA adapter tier (models/lora_pool.py, docs/multi_lora.md): device
     # slots in the fixed-size HBM adapter stack; adapters beyond this
     # page in from the host roster on acquire (LRU eviction of unpinned

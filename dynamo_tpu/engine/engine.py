@@ -751,8 +751,8 @@ class JaxEngine:
         self.kvbm_g1_hit_blocks = 0
         self.kvbm_g1_miss_blocks = 0
         # onboard latency histogram (ms buckets) + recompute comparison
-        # inputs: the bench and planner read these to judge whether tier
-        # onboarding actually beats recompute
+        # inputs, on the worker's metrics: whether tier onboarding beats
+        # recompute
         self._onboard_hist_bounds = (1.0, 5.0, 20.0, 100.0, 500.0)
         self.kvbm_onboard_hist = [0] * (len(self._onboard_hist_bounds) + 1)
         self.kvbm_onboard_ms_sum = 0.0
@@ -780,13 +780,9 @@ class JaxEngine:
         # speculative rows fuse too (mask / adapter-index operands on the
         # variant program, spec lanes as 1+d one-token verify rows);
         # pp/sp configs keep the split path outright.
-        from ..runtime.config import env_bool
-
         self._mixed_enabled = (
-            config.mixed_dispatch
-            if config.mixed_dispatch is not None
-            else env_bool("DYN_MIXED_DISPATCH", True)
-        ) and config.pp_size == 1 and config.sp_size == 1
+            config.mixed_dispatch and config.pp_size == 1 and config.sp_size == 1
+        )
         # durable decode sessions (docs/fault_tolerance.md "Request
         # migration"): commit newly-FULL generated blocks during the step
         # loop rather than only at _release_slot, so a live session's
@@ -795,6 +791,8 @@ class JaxEngine:
         # session-checkpoint replicator. The commit logic is the same
         # _commit_generated_blocks call release uses — byte-identical
         # blocks either way, incremental just runs it earlier.
+        from ..runtime.config import env_bool
+
         self._incremental_commit = (
             config.incremental_commit
             if config.incremental_commit is not None
@@ -1619,8 +1617,8 @@ class JaxEngine:
             t.cancel()
         if self.kvbm is not None:
             # flush any staged commits, drain in-flight write-through
-            # offloads (staged + queued + legacy inline), stop the tier
-            # thread, then persist the G3 index
+            # offloads (staged + queued), stop the tier thread, then
+            # persist the G3 index
             self.kvbm.flush_step()
             for _ in range(500):
                 if self.kvbm.pending_offloads() == 0:
@@ -1855,8 +1853,7 @@ class JaxEngine:
                 n += 2
         # steady-state contract line: every XLA program compiled from
         # here on counts as a post-warmup recompile
-        # (stats()['post_warmup_compiles']); the replay compile smoke
-        # (bench_serving_overhead --compile-smoke) gates on it staying 0
+        # (stats()['post_warmup_compiles'], 0 is the contract)
         self._warmup_compile_baseline = self._surface_cache_sizes()
         self.warmup_seconds = time.monotonic() - t_start
         return n
@@ -2540,9 +2537,9 @@ class JaxEngine:
             "gpu_cache_usage_perc": self.allocator.active_pages / self.allocator.num_pages,
             "request_total_slots": self.config.max_num_seqs,
             # quantized KV density surface (docs/kvbm.md): the format, the
-            # resident pool bytes (incl. scales), and the typed
-            # mixed-precision rejections — what the bench's sessions-per-
-            # HBM-budget gate and a fleet-misconfig alert read
+            # resident pool bytes (incl. scales, benchmark/run.py's
+            # setup line), and the typed mixed-precision rejections (a
+            # fleet-misconfig alert)
             "kv_quant": self.config.kv_quant,
             "kv_pool_bytes": kv_nbytes,
             # bring-up surface (chip_smoke.py reads these off the metrics
@@ -2571,7 +2568,7 @@ class JaxEngine:
         if self.kvbm is not None:
             out.update(self.kvbm.stats())
             # tier-chain effectiveness (docs/kvbm.md): G1 admission hit/miss
-            # plus the onboard latency histogram the bench/planner read
+            # plus the onboard latency histogram
             out["kvbm_g1_hit_blocks"] = self.kvbm_g1_hit_blocks
             out["kvbm_g1_miss_blocks"] = self.kvbm_g1_miss_blocks
             out["kvbm_onboard_count"] = self.kvbm_onboard_count
@@ -5105,8 +5102,10 @@ class JaxEngine:
             # pure-plain and pure-spec packs keep the LEAN program —
             # byte-identical operands to the split path; any guided or lora
             # row takes the variant (all-ones mask rows and adapter index 0
-            # are exact no-ops for the rows beside it)
-            variant, ctx_pages = pack_shape(chosen, active)
+            # are exact no-ops for the rows beside it). The shape is the
+            # plan's, primed above: what the filters left may fit a smaller
+            # member of the family that nothing has compiled yet
+            variant, ctx_pages = shape
             # total <= the largest bucket by construction: it is plan_mixed's
             # budget, mixed_max_tokens
             payload = self._blank_mixed_pack(total, ctx_pages, variant)

@@ -32,10 +32,6 @@ copies:
     DYN_SCHED_POLICY=sla the engine first compares the tiers' observed
     per-block load latency against the slot's TTFT headroom and falls
     back to recompute when onboarding would blow the deadline.
-
-DYN_KVBM_PIPELINE=0 restores the seed's inline per-commit offload (one
-gather + store per `_commit_blocks` call, all on the device executor) —
-kept as the bench_kv_cache.py before/after arm and as a safety valve.
 """
 
 from __future__ import annotations
@@ -137,8 +133,7 @@ class KvBlockManager:
         # CostModel: never-observed = no constraint).
         self._load_ms: dict = {"host": None, "disk": None}
 
-    # -- store path (kvbm-tier thread; device-exec thread on the legacy
-    # inline path) ------------------------------------------------------- #
+    # -- store path (kvbm-tier thread) ----------------------------------- #
 
     def store(self, seq_hash: int, k: np.ndarray, v: np.ndarray,
               parent: Optional[int] = None):
@@ -388,7 +383,6 @@ class KvbmConnector:
 
         self.engine = engine
         self.manager = manager
-        self.pipelined = env_bool("DYN_KVBM_PIPELINE", True)
         # cluster KV fabric (docs/kvbm.md): admission may onboard blocks
         # from a PEER worker's tiers over the data plane. Off = local
         # tiers only (the pre-fabric behavior).
@@ -401,8 +395,6 @@ class KvbmConnector:
             )
         except ValueError:
             self.queue_cap = 8
-        self._pending = 0
-        self._pending_lock = threading.Lock()  # legacy inline path only
         # pipeline state — ALL of it guarded by _offload_cv's lock: the
         # event loop stages and flushes, the device-exec thread marks
         # batches ready, the kvbm-tier thread consumes (GUARDED_STATE)
@@ -435,14 +427,9 @@ class KvbmConnector:
     def offload_commit(self, seq_hashes: List[int], phys_pages: List[int],
                        parent: Optional[int] = None):
         """Write-through: snapshot the just-committed device pages into G2.
-        Pipelined (default): stage the pairs; the engine's end-of-step
-        `flush_step()` coalesces every stage from this step into one
-        gather. Legacy (DYN_KVBM_PIPELINE=0): one gather + inline store per
-        call on the device executor. `parent` = hash chained immediately
-        before `seq_hashes[0]` (None at a chain head)."""
-        if not self.pipelined:
-            self._offload_commit_inline(seq_hashes, phys_pages, parent)
-            return
+        Stage the pairs; the engine's end-of-step `flush_step()` coalesces
+        every stage from this step into one gather. `parent` = hash chained
+        immediately before `seq_hashes[0]` (None at a chain head)."""
         # probe the tiers BEFORE taking the cv: manager._lock nests under
         # _offload_cv nowhere (one global lock order, race-lock-order)
         missing = {h for h in seq_hashes if not self.manager.has(h)}
@@ -518,7 +505,7 @@ class KvbmConnector:
 
         # the device executor orders this gather before any later rewrite
         # of the same pages; _timed accrues its (dispatch-only) cost to
-        # dispatch_kvbm_offload_* so the bench can see the µs stolen
+        # dispatch_kvbm_offload_* (stats(), the worker's metrics topic)
         eng._device_exec.submit(eng._timed(run_gather, "kvbm_offload"))
 
     def stage_promotion(self, hashes: Sequence[int],
@@ -650,61 +637,6 @@ class KvbmConnector:
         evicted = self.manager.drain_evicted()
         if evicted:
             self.distributed.announce_threadsafe("evicted", evicted)
-
-    def _offload_commit_inline(self, seq_hashes: List[int], phys_pages: List[int],
-                               parent: Optional[int] = None):
-        """Seed-shaped inline path (DYN_KVBM_PIPELINE=0): one gather +
-        synchronous store per commit call, all on the device executor.
-        Parents chain through exactly like the pipeline, so prefix-aware
-        eviction behaves identically on both arms."""
-        todo = []
-        prev = parent
-        for h, p in zip(seq_hashes, phys_pages):
-            if not self.manager.has(h):
-                todo.append((h, p, prev))
-            prev = h
-        if not todo:
-            return
-        with self._offload_cv:
-            self.offload_commit_calls += 1
-            self.offload_gathers += 1
-        eng = self.engine
-        hashes = [h for h, _, _ in todo]
-        parents = [par for _, _, par in todo]
-        pages = np.array([p for _, p, _ in todo], np.int32)
-
-        def run_extract():
-            import jax.numpy as jnp
-
-            from ..ops.kv_quant import host_pack_pages
-
-            k, v = eng._extract_pages(eng.kv_k, eng.kv_v, jnp.asarray(pages))
-            # [layers, n, ...] -> per-block [layers, ...] (fp typed rows
-            # or quantized packed uint8 rows, same as the pipelined path)
-            k_np = host_pack_pages(k).swapaxes(0, 1)
-            v_np = host_pack_pages(v).swapaxes(0, 1)
-            for i, h in enumerate(hashes):
-                self.manager.store(h, k_np[i], v_np[i], parent=parents[i])
-            if self.distributed is not None:
-                self.distributed.announce_threadsafe("stored", hashes)
-                self._announce_evictions()
-                ck = self.distributed.checkpointer
-                if ck is not None:
-                    ck.stage_threadsafe(hashes, parents)
-
-        with self._pending_lock:
-            self._pending += 1
-
-        def done(fut):
-            with self._pending_lock:
-                self._pending -= 1
-            exc = fut.exception()
-            if exc is not None:
-                logger.warning("KVBM offload failed: %s", exc)
-
-        eng._device_exec.submit(
-            eng._timed(run_extract, "kvbm_offload")
-        ).add_done_callback(done)
 
     # -- onboard (called at admission) ----------------------------------- #
 
@@ -889,19 +821,12 @@ class KvbmConnector:
                 # "prefill that span instead"
                 raise KeyError(f"kvbm remote pull failed: {e}") from e
 
-            if self.pipelined:
-                # promotion rides the tier thread, not the onboard
-                # critical path (stage_promotion) — the slot's inject
-                # proceeds immediately
-                self.stage_promotion(
-                    remote, [parent_of[h] for h in remote], rk, rv
-                )
-            else:
-                def promote():
-                    for i, h in enumerate(remote):
-                        self.manager.store(h, rk[i], rv[i], parent=parent_of[h])
-
-                await run(promote)
+            # promotion rides the tier thread, not the onboard critical
+            # path (stage_promotion) — the slot's inject proceeds
+            # immediately
+            self.stage_promotion(
+                remote, [parent_of[h] for h in remote], rk, rv
+            )
             if not local:
                 # pull_blocks stacked in `hashes` order already — skip
                 # the per-block restack copy (admission latency path)
@@ -932,17 +857,14 @@ class KvbmConnector:
 
     def pending_offloads(self) -> int:
         """In-flight write-through count: staged pairs + queued batches'
-        blocks + the batch mid-store on the tier thread (pipeline) +
-        legacy inline jobs (engine close() drains on this)."""
+        blocks + the batch mid-store on the tier thread (engine close()
+        drains on this)."""
         with self._offload_cv:
-            n = (
+            return (
                 len(self._staged)
                 + sum(len(b.hashes) for b in self._queue)
                 + self._processing
             )
-        with self._pending_lock:
-            n += self._pending
-        return n
 
     def drain(self, timeout_s: float = 5.0) -> bool:
         """Block (event-loop-free callers only) until every staged/queued

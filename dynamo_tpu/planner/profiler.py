@@ -7,9 +7,8 @@ throughput across (kv-cache usage, context length) operating points, then
 write npz files in the exact raw_data layout the planner's interpolators
 load (selected_prefill_interpolation/raw_data.npz and
 selected_decode_interpolation/raw_data.npz, field names per
-perf_interpolation.py — "gpu" in names reads "chip").
-
-Timing follows bench.py: every timed region ends in a fence.
+perf_interpolation.py — "gpu" in names reads "chip"). Every timed region
+ends in a fence.
 """
 
 from __future__ import annotations

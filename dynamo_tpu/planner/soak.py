@@ -808,8 +808,7 @@ class RampLoad:
 
 
 def attainment(records: Sequence[StreamRecord], ttft_slo_ms: float) -> float:
-    """Fraction of records meeting the TTFT target (failures count as
-    misses) — the bench_e2e `sla_fields` definition."""
+    """Fraction of records meeting the TTFT target (failures are misses)."""
     if not records:
         return 1.0
     met = [r for r in records if r.ok and r.ttft_ms() <= ttft_slo_ms]

@@ -385,12 +385,6 @@ ENV_REGISTRY: tuple = (
            "for an engine on one device, XLA for a multi-device mesh; "
            "quantized KV pools always take XLA.",
            "ops/paged_attention.py"),
-    EnvVar("DYN_MIXED_DISPATCH", "bool", "1",
-           "Ragged unified mixed dispatch: fuse the step's prefill chunks "
-           "and active decode lanes into one device call "
-           "(docs/ragged_attention.md). EngineConfig.mixed_dispatch "
-           "overrides.",
-           "engine/engine.py"),
     EnvVar("DYN_LORA_POOL_SLOTS", "int", "8",
            "Device slots in the LoRA adapter tier (models/lora_pool.py): "
            "the fixed-size HBM adapter stack pages against the host "
@@ -413,14 +407,6 @@ ENV_REGISTRY: tuple = (
            "Requires tp/pp/sp == 1.",
            "ops/kv_quant.py"),
     # -- KVBM tier pipeline (kvbm/, docs/kvbm.md) ----------------------- #
-    EnvVar("DYN_KVBM_PIPELINE", "bool", "1",
-           "Batched KVBM offload pipeline: coalesce a step's block "
-           "commits into one device gather and run tier stores on the "
-           "dedicated kvbm-tier thread. 0 restores the inline "
-           "per-commit offload (one gather + store per commit, all on "
-           "the device executor) — the bench_kv_cache.py before/after "
-           "arm and a safety valve.",
-           "kvbm/manager.py"),
     EnvVar("DYN_KVBM_OFFLOAD_QUEUE", "int", "8",
            "Max in-flight offload batches between the per-step gather "
            "and the kvbm-tier thread's stores. When the tier thread "

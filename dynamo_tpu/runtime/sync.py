@@ -47,8 +47,7 @@ from __future__ import annotations
 #: literals: the race rules parse this file's AST and never import it.
 GUARDED_STATE = {
     # KVBM tier state: written on the kvbm-tier thread (batched offload
-    # stores; the device-exec thread on the DYN_KVBM_PIPELINE=0 inline
-    # path), read on the event loop (admission probe) — the lock is the
+    # stores), read on the event loop (admission probe) — the lock is the
     # only thing standing between them.
     "KvBlockManager.host": "lock:_lock",
     "KvBlockManager.disk": "lock:_lock",
@@ -61,9 +60,6 @@ GUARDED_STATE = {
     # `evicted` mesh retraction — appended on the kvbm-tier thread's
     # store path, drained wherever announcements fire.
     "KvBlockManager._evicted_pending": "lock:_lock",
-    # legacy inline offload count: bumped on the event loop, dropped in
-    # the executor's done-callback thread.
-    "KvbmConnector._pending": "lock:_pending_lock",
     # kvbm offload pipeline (docs/kvbm.md): the event loop stages commits
     # and flushes them into batches, the device-exec thread marks a
     # batch's gather ready, the kvbm-tier thread consumes — three

@@ -1,9 +1,8 @@
 """Bring-up rules that must not drift back (ISSUE 21): where the compile
 cache lives, how the attention gate decides, what a device without memory
-stats gets, and how many worker processes a chip may be given. Fast: no
-engine is built and no process is started."""
+stats gets, and which settings are gone for good. Fast: one tiny engine is
+built (the last test) and no process is started."""
 
-import sys
 import types
 from pathlib import Path
 
@@ -132,21 +131,32 @@ def test_accelerator_without_memory_stats_is_an_error(monkeypatch):
     assert engine_mod._auto_num_pages({}, llama.LlamaConfig.tiny(), cfg) > 0
 
 
-# ---- one process per chip --------------------------------------------- #
+# ---- settings that are gone ------------------------------------------- #
 
 
-@pytest.mark.parametrize("mode", ["disagg", "kv"])
-def test_bench_e2e_refuses_two_tpu_workers_on_one_chip(mode):
-    sys.path.insert(0, str(REPO))
-    import bench_e2e
-
-    with pytest.raises(RuntimeError, match="one process"):
-        bench_e2e.launch(mode, "tiny", cpu=False)
-
-
-def test_retired_settings_are_gone():
+@pytest.mark.parametrize("name", [
+    "DYNAMO_TPU_COMPILE_CACHE", "DYN_WORKERS_PER_DEVICE",
+    "DYN_KVBM_PIPELINE", "DYN_MIXED_DISPATCH",
+])
+def test_retired_settings_are_gone(name):
     from dynamo_tpu.runtime.config import ENV_REGISTRY
 
-    names = {e.name for e in ENV_REGISTRY}
-    assert "DYNAMO_TPU_COMPILE_CACHE" not in names
-    assert "DYN_WORKERS_PER_DEVICE" not in names
+    assert name not in {e.name for e in ENV_REGISTRY}
+    read = [
+        str(f.relative_to(REPO)) for f in sorted((REPO / "dynamo_tpu").rglob("*.py"))
+        if name in f.read_text()
+    ]
+    assert read == [], f"{name} is still spelled in {read}"
+
+
+def test_the_environment_no_longer_turns_the_mixed_step_off(monkeypatch):
+    """`EngineConfig.mixed_dispatch` (and the pp/sp layout) decide, and
+    nothing else: DYN_MIXED_DISPATCH=0 in a worker's environment is not
+    read."""
+    from dynamo_tpu.engine import EngineConfig, JaxEngine
+
+    monkeypatch.setenv("DYN_MIXED_DISPATCH", "0")
+    kw = dict(model="tiny", max_num_seqs=2, page_size=8, num_pages=16,
+              max_model_len=64, prefill_buckets=(16,))
+    assert EngineConfig(**kw).mixed_dispatch is True
+    assert JaxEngine(EngineConfig(**kw))._mixed_enabled is True

@@ -426,8 +426,8 @@ def _hashes(prompt):
 
 
 async def _drain_offloads(eng):
-    """Flush + wait out the offload pipeline (staged pairs, queued batches
-    and legacy inline jobs alike)."""
+    """Flush + wait out the offload pipeline (staged pairs and queued
+    batches)."""
     if eng.kvbm is None:
         return
     eng.kvbm.flush_step()
@@ -627,6 +627,109 @@ def test_pipeline_coalesces_stages_into_one_gather(monkeypatch):
     assert conn.manager.host._parent.get(102) == 101
     assert conn.manager.host._parent.get(103) == 102
     conn.shutdown()
+
+
+@pytest.mark.parametrize("steps", [(3, 1, 0, 2), (1, 1, 1)])
+def test_one_gather_a_step_however_many_commits(steps, monkeypatch):
+    """`offload_gathers` (and the device's gathers) rise by one per
+    `flush_step()` that has something staged, not per `offload_commit`;
+    a step that committed nothing gathers nothing."""
+    eng, conn = _mk_connector(host_blocks=32, monkeypatch=monkeypatch)
+    h = 700
+    for n_step, commits in enumerate(steps):
+        before = conn.offload_gathers
+        for _ in range(commits):
+            conn.offload_commit([h], [2 + h % 32])
+            h += 1
+        conn.flush_step()
+        assert conn.offload_gathers - before == (1 if commits else 0), n_step
+    assert conn.drain(5.0)
+    assert conn.offload_commit_calls == sum(steps)
+    assert eng.dev_calls == conn.offload_gathers == sum(1 for c in steps if c)
+    assert len(conn.manager.host) == sum(steps)
+    conn.shutdown()
+
+
+@pytest.mark.parametrize("held_here", [0, 2], ids=["all_remote", "local_head"])
+def test_remote_onboard_promotes_through_the_tier_queue(held_here, monkeypatch):
+    """Blocks pulled from a peer at admission are handed to
+    `stage_promotion` (a READY batch on the kvbm-tier thread, parents
+    chained); the onboard itself stores nothing: `run` carries only the
+    local tier's read."""
+    eng, conn = _mk_connector(monkeypatch=monkeypatch)
+    hashes = [901, 902, 903, 904]
+    blocks = {h: _blk(h) for h in hashes}
+    for i, h in enumerate(hashes[:held_here]):
+        conn.manager.store(h, *blocks[h], parent=hashes[i - 1] if i else None)
+    pulled, promoted, ran = [], [], []
+
+    class _Dist:
+        checkpointer = None
+
+        async def pull_blocks(self, remote, hint_instance=None):
+            pulled.append(list(remote))
+            return (np.stack([blocks[h][0] for h in remote]),
+                    np.stack([blocks[h][1] for h in remote]))
+
+        def announce_threadsafe(self, *a, **k):
+            pass
+
+    conn.distributed = _Dist()
+    stage = conn.stage_promotion
+
+    def spy(hs, parents, k, v):
+        promoted.append((list(hs), list(parents)))
+        stage(hs, parents, k, v)
+
+    monkeypatch.setattr(conn, "stage_promotion", spy)
+
+    async def run(fn, *a):
+        ran.append(fn)
+        return fn(*a)
+
+    ks, vs = asyncio.run(conn.load_async(hashes, run))
+    remote = hashes[held_here:]
+    assert pulled == [remote]
+    assert promoted == [(remote, [None, *hashes][held_here:held_here + len(remote)])]
+    assert ran == ([conn.manager.load_blocks] if held_here else [])
+    np.testing.assert_array_equal(ks, np.stack([blocks[h][0] for h in hashes]))
+    np.testing.assert_array_equal(vs, np.stack([blocks[h][1] for h in hashes]))
+    assert conn.drain(5.0)
+    assert all(conn.manager.has(h) for h in hashes)
+    assert conn.offload_gathers == 0 and eng.dev_calls == 0
+    conn.shutdown()
+
+
+def test_engine_gathers_once_a_step_under_concurrent_commits(params):
+    """Through JaxEngine: three prompts admitted together commit their
+    blocks in the same steps, and every `_step_once` ends in one
+    `flush_step()`: gathers never outnumber flushes, and commits
+    outnumber gathers."""
+    async def main():
+        eng = _engine(params, host_blocks=64, num_pages=64)
+        flushes = []
+        flush = eng.kvbm.flush_step
+
+        def counted():
+            before = eng.kvbm.offload_gathers
+            flush()
+            flushes.append(eng.kvbm.offload_gathers - before)
+
+        eng.kvbm.flush_step = counted
+        prompts = [list(range(20 + 50 * i, 20 + 50 * i + 3 * PAGE)) for i in range(3)]
+        await asyncio.gather(*[
+            _gen(eng, p, 10, f"c{i}") for i, p in enumerate(prompts)])
+        await _drain_offloads(eng)
+        st = eng.stats()
+        await eng.close()
+        return flushes, st
+
+    flushes, st = asyncio.run(main())
+    assert set(flushes) <= {0, 1}
+    assert st["kvbm_offload_gathers"] == sum(flushes) >= 1
+    assert st["kvbm_offload_commit_calls"] > st["kvbm_offload_gathers"]
+    assert st["kvbm_offloaded_blocks"] >= 3 * 3
+    assert st["kvbm_offload_blocks_dropped"] == 0
 
 
 def test_pipeline_backpressure_drops_oldest(monkeypatch):

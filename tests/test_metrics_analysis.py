@@ -649,14 +649,11 @@ def test_real_tree_met_pack_clean():
 
 def _real_tree(tmp_path: Path) -> Path:
     """A lintable copy of the real package: dynamo_tpu/ minus the
-    analysis subtree (Project.load skips it anyway), plus the repo-root
-    bench parsers (they carry consumer credit for wire entries)."""
+    analysis subtree (Project.load skips it anyway)."""
     shutil.copytree(
         REPO / "dynamo_tpu", tmp_path / "dynamo_tpu",
         ignore=shutil.ignore_patterns("__pycache__", "analysis"),
     )
-    for bench in sorted(REPO.glob("bench_*.py")):
-        shutil.copy(bench, tmp_path / bench.name)
     return tmp_path
 
 

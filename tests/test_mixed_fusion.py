@@ -148,6 +148,61 @@ def test_blended_guided_lora_fused_vs_split_byte_identical(params, adapters):
     assert st["lora_pool_hits"] + st["lora_pool_misses"] > 0
 
 
+async def _blend_beside_a_decoding_lane(eng, rounds=2):
+    """A round: a plain stream decodes; once it has emitted, a LoRA request
+    and then a guided one arrive and run to their ends beside it. Arrivals
+    wait on an event, so each one's prefill chunk meets a live decode lane
+    whatever the host's load."""
+    rng = np.random.RandomState(5)
+    out = []
+    for r in range(rounds):
+        started = asyncio.Event()
+
+        async def plain():
+            req = PreprocessedRequest(
+                token_ids=rng.randint(5, 200, size=24).tolist(),
+                stop_conditions={"max_tokens": 64, "ignore_eos": True},
+                sampling_options={"temperature": 0.0}, request_id=f"p{r}",
+            ).to_dict()
+            toks = []
+            async for item in eng.generate(req, Context()):
+                toks.extend((item.get("data") or {}).get("token_ids", ()))
+                if toks:
+                    started.set()
+            return toks
+
+        lane = asyncio.create_task(plain())
+        await started.wait()
+        out.append(await _one(eng, rng.randint(5, 200, size=20).tolist(),
+                              f"l{r}", lora_name="ad1", n=6))
+        out.append(await _one(eng, rng.randint(5, 200, size=20).tolist(),
+                              f"g{r}", n=6, guided={"kind": "choice",
+                                                    "choices": ["yes", "no"]}))
+        out.append(await lane)
+    return out
+
+
+def test_every_blended_arrival_rides_one_fused_step(params, adapters):
+    """Tokens per dispatch on blended traffic, as counts: each guided or
+    LoRA arrival beside a decoding lane is ONE mixed step and no split
+    prefill + decode pair; with `mixed_dispatch=False` the same trace is
+    that many split pairs, and the streams are the same."""
+    stats, streams = {}, {}
+    for mixed in (True, False):
+        eng = _engine(params, adapters, mixed=mixed)
+        streams[mixed] = asyncio.run(_blend_beside_a_decoding_lane(eng))
+        stats[mixed] = eng.stats()
+        asyncio.run(eng.close())
+    fused, split = stats[True], stats[False]
+    assert streams[True] == streams[False] and all(streams[True])
+    assert (fused["mixed_steps"], fused["split_steps"]) == (4, 0)
+    assert (split["mixed_steps"], split["split_steps"]) == (0, 4)
+    assert fused["mixed_rows_lora"] == fused["mixed_rows_guided"] == 2
+    assert fused["mixed_rows_plain"] == 4  # the lane's row in each step
+    assert fused["mixed_coverage_frac"] == 1.0
+    assert split["mixed_coverage_frac"] == 0.0
+
+
 def test_spec_fused_verify_rows_vs_split_spec_and_plain(params):
     """Spec engine, plain traffic: the fused path packs 1+d verify rows
     per lane and must reproduce BOTH the split spec lane and the plain

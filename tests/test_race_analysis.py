@@ -531,11 +531,13 @@ _REAL_GUARD_SITES = [
     ),
     (
         "dynamo_tpu/kvbm/manager.py",
-        "with self._pending_lock:\n"
-        "            n += self._pending",
+        "with self._offload_cv:\n"
+        "            return (\n"
+        "                len(self._staged)",
         "if True:\n"
-        "            n += self._pending",
-        "KvbmConnector._pending",
+        "            return (\n"
+        "                len(self._staged)",
+        "KvbmConnector._staged",
     ),
 ]
 
@@ -829,7 +831,7 @@ def test_guarded_state_registry_entries_resolve_against_real_tree():
     keys = {e.key for e in entries}
     # the load-bearing minimum: kvbm cross-thread counters, engine step
     # bookkeeping, and the discovery instance table
-    assert {"KvBlockManager.offloaded_blocks", "KvbmConnector._pending",
+    assert {"KvBlockManager.offloaded_blocks", "KvbmConnector._staged",
             "JaxEngine._inflight", "Client.instances"} <= keys
 
 

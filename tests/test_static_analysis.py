@@ -12,6 +12,7 @@ Two jobs:
 """
 
 import json
+import re
 import subprocess
 import sys
 import textwrap
@@ -557,6 +558,24 @@ def test_env_docs_are_up_to_date():
 
     on_disk = (REPO / "docs" / "configuration.md").read_text()
     assert on_disk == emit_env_docs(REPO)
+
+
+def test_documents_cite_scripts_that_exist():
+    """Every `python <path>.py` a document or a recipe spells names a file
+    of the tree (the records of past PRs keep their history and are not
+    read)."""
+    records = {"CHANGES.md", "ROADMAP.md", "PERF.md", "ISSUE.md"}
+    skip = {".git", ".jax_cache", ".scratch", ".proof", "chiprun_out"}
+    docs = [
+        f for ext in ("*.md", "*.yaml") for f in REPO.rglob(ext)
+        if f.name not in records and not skip & set(f.relative_to(REPO).parts)
+    ]
+    cited = {
+        (str(f.relative_to(REPO)), m.group(1)) for f in docs
+        for m in re.finditer(r"python3? ((?:[\w./-]+/)?[\w-]+\.py)\b", f.read_text())
+    }
+    assert len(cited) > 10
+    assert sorted(c for c in cited if not (REPO / c[1]).exists()) == []
 
 
 def test_directive_quoted_in_docstring_is_inert(tmp_path):
