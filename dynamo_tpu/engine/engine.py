@@ -46,7 +46,7 @@ import numpy as np
 from ..llm.mocker.kv_manager import KvEvent
 from ..llm.protocols import Annotated, LLMEngineOutput, PreprocessedRequest
 from ..llm.tokens import TokenBlockSequence, compute_seq_hashes, salt_hash
-from ..models import hybrid, llama, moe, nemotron_h
+from ..models import exaone_moe, hybrid, llama, moe, nemotron_h
 from ..models.quant import is_quant
 from ..ops.paged_attention import ragged_tiles
 from ..ops.state_cache import state_bytes_per_lane
@@ -166,6 +166,7 @@ def _kv_shard_div(kv_sharding) -> int:
 #: (the first entry the class is an instance of: a subclass stands before
 #: its base)
 MODEL_FAMILIES = (
+    (exaone_moe.ExaoneMoeConfig, exaone_moe),
     (nemotron_h.NemotronHConfig, nemotron_h),
     (hybrid.HybridConfig, hybrid),
     (moe.MoeConfig, moe),
@@ -539,8 +540,8 @@ class JaxEngine:
         family = model_family(c)
         # a family that keeps a recurrent state per lane beside the pages
         # says so of itself, in the words its refusals are worded in
-        # (`STATE_FAMILY`: models/hybrid.py, models/nemotron_h.py;
-        # docs/hybrid_models.md)
+        # (`STATE_FAMILY`: models/hybrid.py, models/nemotron_h.py,
+        # models/exaone_moe.py; docs/hybrid_models.md)
         self.STATE_FAMILY = getattr(family, "STATE_FAMILY", None)
         self._stateful = self.STATE_FAMILY is not None
         # a routed family counts the rows its expert matmuls multiply
@@ -6307,6 +6308,7 @@ def _resolve_model(name: str) -> llama.LlamaConfig:
     registry = {
         "tiny-hybrid": hybrid.HybridConfig.tiny_hybrid,
         "tiny-nemotron-h": nemotron_h.NemotronHConfig.tiny_nemotron_h,
+        "tiny-exaone-moe": exaone_moe.ExaoneMoeConfig.tiny_exaone_moe,
         "tiny": llama.LlamaConfig.tiny,
         "llama3-3b": llama.LlamaConfig.llama3_2_3b,
         "llama3-8b": llama.LlamaConfig.llama3_8b,

@@ -59,8 +59,9 @@ def alloc_state_cache(model_cfg, num_pages: int, page_size: int,
     its pages (models/hybrid.py): the K store is an ops/state_cache.
     StateCache, which holds the K pages of the layers that attend, the
     state store `[linear layers, max_seqs + 1, ...]` indexed by LANE (the
-    last slot scratch) and what a dispatch says of its rows; the V store is
-    a plain pool. docs/hybrid_models.md."""
+    last slot scratch; for a family of window layers, models/exaone_moe.py,
+    the K and V rings of a lane's last W positions) and what a dispatch
+    says of its rows; the V store is a plain pool. docs/hybrid_models.md."""
     from ..ops import state_cache
 
     return state_cache.alloc_state_cache(

@@ -18,8 +18,11 @@ One table for the engine's loop, exported through `JaxEngine.stats()`:
   `step_stalled_count`, `step_stalled_s` instead), and the work it was
   asked for, `step_model_flops` and `step_min_bytes`
   (models/<family>.step_work), and of the bytes a recurrent state's,
-  `step_state_bytes`, and the held experts', `step_expert_bytes` (each
-  exported once it is not 0: models/hybrid.py, models/nemotron_h.py).
+  `step_state_bytes`, and the held experts', `step_expert_bytes`; beside
+  them what the window layers' K and V cost and would cost without the
+  window, `step_window_kv_bytes` and `step_window_kv_whole_bytes` (each
+  exported once it is not 0: models/hybrid.py, models/nemotron_h.py,
+  models/exaone_moe.py).
 * the waits ahead of a first token: `req_admitted`, `req_queue_wait_s`,
   `req_first_tokens`, `req_admit_to_first_s`.
 * the device calls' host clock by tag (`dispatch_<tag>_count`, `_s`:
@@ -125,6 +128,9 @@ class Recorder:
         self.min_bytes = 0
         self.state_bytes = 0  # of min_bytes, a recurrent state's (hybrid)
         self.expert_bytes = 0  # of min_bytes, the held experts' (nemotron_h)
+        # K and V bytes the window layers read, and would at the whole
+        # context (exaone_moe)
+        self.window_kv_bytes = self.window_kv_whole_bytes = 0
         # (count, seconds) of the host's clock around a device call, by tag
         self.dev_time: Dict[str, tuple] = {}
         self.req_admitted = 0
@@ -162,8 +168,10 @@ class Recorder:
         """Stamp `entry` as it goes to the device: its kind, the host's
         clock, and the (useful operations, least bytes) it was asked for; a
         family with a recurrent state says third how many of the bytes are
-        the state's (models/hybrid.step_work), and may say fourth how many
-        are the held experts' (models/nemotron_h.step_work)."""
+        the state's (models/hybrid.step_work), may say fourth how many
+        are the held experts' (models/nemotron_h.step_work), and fifth and
+        sixth the window layers' K and V bytes read and what they would be
+        at the whole context (models/exaone_moe.step_work)."""
         entry["step_kind"] = kind
         entry["t_dispatch"] = time.perf_counter()
         self.model_flops += work[0]
@@ -172,6 +180,9 @@ class Recorder:
             self.state_bytes += work[2]
         if len(work) > 3:
             self.expert_bytes += work[3]
+        if len(work) > 5:
+            self.window_kv_bytes += work[4]
+            self.window_kv_whole_bytes += work[5]
 
     def fetched(self, entries: List[dict], t_ready: float):
         """The fetch that brought these entries back returned at `t_ready`:
@@ -244,4 +255,8 @@ class Recorder:
             out["step_state_bytes"] = float(self.state_bytes)
         if self.expert_bytes:
             out["step_expert_bytes"] = float(self.expert_bytes)
+        if self.window_kv_whole_bytes:
+            out["step_window_kv_bytes"] = float(self.window_kv_bytes)
+            out["step_window_kv_whole_bytes"] = float(
+                self.window_kv_whole_bytes)
         return out

@@ -330,6 +330,23 @@ def experts_held(stacks, li, h, idx, weight, valid, *, form: str, held: int,
         "tkh,tk->th", out[back].reshape(T, K, out.shape[-1]), weight)
 
 
+def sigmoid_route(h, router, bias, per_token: int, norm_topk_prob: bool,
+                  scaling_factor: float):
+    """(experts chosen [T, K] under the router's full width, their weights
+    [T, K]) of the router that balances by a choice bias and no auxiliary
+    loss (models/nemotron_h.py, models/exaone_moe.py): sigmoid scores over
+    all the router's outputs in float32; the K largest of score + choice
+    bias; the weights are the SCORES at the chosen, over their sum under
+    `norm_topk_prob`, times the scaling factor."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        h.astype(jnp.float32), router, precision=jax.lax.Precision.HIGHEST))
+    _, idx = jax.lax.top_k(scores + bias, per_token)
+    weight = jnp.take_along_axis(scores, idx, axis=-1)
+    if norm_topk_prob:
+        weight = weight / weight.sum(-1, keepdims=True)
+    return idx, weight * scaling_factor
+
+
 def experts_touched(held: int, pairs: float) -> float:
     """Of `held` experts, those that `pairs` (token, expert) pairs spread
     evenly over them touch, in expectation."""

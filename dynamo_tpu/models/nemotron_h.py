@@ -44,7 +44,6 @@ from ..ops.state_cache import StateCache, StateSpec, state_bytes_per_lane
 from . import moe
 from .hybrid import (
     CHUNK_PRECISION,
-    HIGHEST,
     INIT_SCALE,
     _note_chosen,
     dense_leaf,
@@ -330,17 +329,10 @@ def _attn_out(layer, attn, c: NemotronHConfig):
 
 def route(h, layer, c: NemotronHConfig):
     """(experts chosen [T, K] under the router's full width, their weights
-    [T, K]): sigmoid scores over all the router's outputs in float32; the K
-    largest of score + choice bias; the weights are the SCORES at the
-    chosen, over their sum under `norm_topk_prob`, times the scaling
-    factor."""
-    scores = jax.nn.sigmoid(
-        jnp.dot(h.astype(f32), layer["router"], precision=HIGHEST))
-    _, idx = jax.lax.top_k(scores + layer["router_bias"], c.num_experts_per_tok)
-    weight = jnp.take_along_axis(scores, idx, axis=-1)
-    if c.norm_topk_prob:
-        weight = weight / weight.sum(-1, keepdims=True)
-    return idx, weight * c.routed_scaling_factor
+    [T, K]): moe.sigmoid_route at this family's sizes."""
+    return moe.sigmoid_route(
+        h, layer["router"], layer["router_bias"], c.num_experts_per_tok,
+        c.norm_topk_prob, c.routed_scaling_factor)
 
 
 def routed_block(layer, stacks, le, x, c: NemotronHConfig, valid=None):

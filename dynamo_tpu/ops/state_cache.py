@@ -1,5 +1,6 @@
 """The cache of a family that keeps a recurrent state beside its pages
-(docs/hybrid_models.md: models/hybrid.py, models/nemotron_h.py).
+(docs/hybrid_models.md: models/hybrid.py, models/nemotron_h.py), or a ring
+of its window layers' last keys and values (models/exaone_moe.py).
 
 Most layers of such a model keep no keys and values: each keeps, for every
 sequence, a matrix-valued state of fixed size and the last inputs of a
@@ -25,6 +26,13 @@ comes back updated, so no program of the engine takes an argument more.
             step chose for each lane, at `position % ring`
     routed_flat [routed layers, token slots, k] i32: the experts the last
             prefill batch or mixed step chose for each of its token slots
+
+A family whose layers attend under a sliding window keeps no recurrence:
+what a lane keeps in one of those layers is the K and V of its last W
+positions, and the same two leaves hold them (`state` the K ring, `conv` the
+V ring, both `[window layers, lanes + 1, W, KH*D]` in the model's dtype:
+"the last n rows of a lane, carried across chunks" with n = the window;
+ops/window_attention.py).
 
 The two `routed_*` leaves are what the request plane's `routed_experts`
 annotation is answered from (benchmark/README.md, "The wire contract");
@@ -102,7 +110,7 @@ class StateSpec:
     (`<Config>.state_spec()`): three counts of layers, none of which need be
     `num_layers`, and the shapes ONE lane keeps in ONE state layer."""
 
-    state_layers: int  # layers that keep a recurrent state
+    state_layers: int  # layers that keep a recurrent state (or a ring)
     attention_layers: int  # layers that keep pages
     routed_layers: int  # layers whose chosen experts are recorded
     state_shape: Tuple[int, ...]  # the matrix state, in `state_dtype`

@@ -139,14 +139,16 @@ METRICS = {
     "expert_rows_routed": {"kind": "counter", "layer": "engine", "unit": "rows", "help": "Expert rows routed per layer: real tokens x experts per token, summed over dispatches (MoE).", "export": True},
     "expert_rows_computed": {"kind": "counter", "layer": "engine", "unit": "rows", "help": "Expert rows the expert matmuls multiply per layer: E x capacity, or the grouped matmul's tile-padded rows (MoE).", "export": True},
     # a family with a recurrent state per lane beside the pages
-    # (models/hybrid.py, models/nemotron_h.py; docs/hybrid_models.md):
-    # exported for those alone
+    # (models/hybrid.py, models/nemotron_h.py, models/exaone_moe.py;
+    # docs/hybrid_models.md): exported for those alone
     "state_bytes": {"kind": "gauge", "layer": "engine", "unit": "bytes", "help": "Bytes of the recurrent-state store beside the pages (lanes + 1 slots over the layers that keep a state; the stateful families).", "export": True},
     "state_lanes_reset": {"kind": "counter", "layer": "engine", "unit": "rows", "help": "First chunks dispatched, each of which starts its lane's recurrent state from zero (the stateful families).", "export": True},
     "state_prefix_hits_declined": {"kind": "counter", "layer": "engine", "unit": "blocks", "help": "Cached blocks the prefix index was not allowed to hand a sequence because nobody kept the state that stood at their end (the stateful families).", "export": True},
     "routed_rows_emitted": {"kind": "counter", "layer": "engine", "unit": "rows", "help": "Rows of chosen expert ids sent to requests annotated routed_experts (the stateful families).", "export": True},
     "step_state_bytes": {"kind": "counter", "layer": "engine", "unit": "bytes", "help": "Of step_min_bytes, the recurrent state's: read and written once for each (row, pass) of the entries dispatched (exported once it is not 0: the stateful families).", "export": True},
-    "step_expert_bytes": {"kind": "counter", "layer": "engine", "unit": "bytes", "help": "Of step_min_bytes, the weights of the held experts that the entries dispatched touch, in expectation under an even router (exported once it is not 0: Nemotron-H family).", "export": True},
+    "step_expert_bytes": {"kind": "counter", "layer": "engine", "unit": "bytes", "help": "Of step_min_bytes, the weights of the held experts that the entries dispatched touch, in expectation under an even router (exported once it is not 0: the Nemotron-H and EXAONE-MoE families).", "export": True},
+    "step_window_kv_bytes": {"kind": "counter", "layer": "engine", "unit": "bytes", "help": "K and V bytes the window layers of the entries dispatched read: at most a window of positions for each (row, pass) (exported once it is not 0: the EXAONE-MoE family).", "export": True},
+    "step_window_kv_whole_bytes": {"kind": "counter", "layer": "engine", "unit": "bytes", "help": "K and V bytes the same window layers would read at every row's whole context, as layers that keep pages do (exported once it is not 0: the EXAONE-MoE family).", "export": True},
     # compile telemetry (engine/compile_registry.py, docs/compilation.md):
     # XLA cache growth per staged surface. post_warmup_compiles is THE
     # steady-state contract number — the compile smoke gates on 0

@@ -556,6 +556,74 @@ def test_the_state_space_familys_steps_compile_at_the_cell_size(
         f"{mem.temp_size_in_bytes / 2**20:.0f} MiB of temporaries")
 
 
+@pytest.mark.parametrize("program", ("block_of_8", "mixed_256", "mixed_2048"))
+def test_the_window_familys_steps_compile_at_the_published_widths(
+    program, one_chip, no_persistent_cache, tpu_gate
+):
+    """models/exaone_moe.py at its cell's configuration (8 layers at the
+    published widths, 16 of 128 experts held, 12 GB of weights), its rings
+    of 33 slots and the auto pool of the two full layers, for the described
+    v5e: a decode block's scan of 8 decode steps (32 lanes) and the smallest
+    and the largest member of the mixed_step family (40 rows, a prefill batch
+    of 8). Each sparse layer brings three grouped matmuls, a
+    full layer one kernel a decode step and two a mixed step; the window
+    layers are plain XLA. The decode step writes the lanes' slots in the
+    donated rings: its temporaries stay under the rings themselves (104 MB);
+    a mixed step's window attention is scores of [blocks, heads, 128, 256]
+    whatever a lane's context, and the whole fits the chip."""
+    from dynamo_tpu.models import exaone_moe
+
+    sds = _shapes(one_chip)
+    cfg, params, cache, kv_v = _stateful_cell(
+        sds, exaone_moe, "k-exaone-236b-a23b-ep8-d8", 3381)
+    assert cache.state.shape == cache.conv.shape == (6, 33, 128, 1024)
+    assert cache.pages.shape[0] == kv_v.shape[0] == 2
+    i32 = jnp.int32
+    if program == "block_of_8":
+        def one(params, tokens, positions, kv_k, kv_v, tables, seq_lens):
+            return exaone_moe.decode_forward(
+                params, cfg, tokens, positions, kv_k, kv_v, tables, seq_lens)
+
+        def block(params, tokens, positions, kv_k, kv_v, tables, seq_lens):
+            def body(carry, _):
+                tokens, positions, kv_k, kv_v, seq_lens = carry
+                logits, kv_k, kv_v = one(
+                    params, tokens, positions, kv_k, kv_v, tables, seq_lens)
+                return (logits.argmax(-1).astype(i32), positions + 1, kv_k,
+                        kv_v, seq_lens + 1), None
+
+            return jax.lax.scan(
+                body, (tokens, positions, kv_k, kv_v, seq_lens), None, 8)[0]
+
+        compiled = jax.jit(block, donate_argnums=(3, 4)).lower(
+            params, sds((32,), i32), sds((32,), i32), cache, kv_v,
+            sds((32, 64), i32), sds((32,), i32)).compile()
+        kernels = 7 * 3 + 2
+    else:
+        tokens, rows = int(program.split("_")[1]), 40
+
+        def step(params, tokens, positions, row_ids, kv_k, kv_v, tables,
+                 row_starts, row_lens, ctx_lens, last_flat):
+            return exaone_moe.ragged_forward(
+                params, cfg, tokens, positions, row_ids, kv_k, kv_v, tables,
+                row_starts, row_lens, ctx_lens, last_flat, long_rows=8)
+
+        compiled = jax.jit(step, donate_argnums=(4, 5)).lower(
+            params, sds((tokens,), i32), sds((tokens,), i32),
+            sds((tokens,), i32), cache, kv_v, sds((rows, 65), i32),
+            sds((rows,), i32), sds((rows,), i32), sds((rows,), i32),
+            sds((rows,), i32)).compile()
+        kernels = 7 * 3 + 2 * 2
+    assert compiled.as_text().count("tpu_custom_call") == kernels
+    mem = compiled.memory_analysis()
+    need = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert need < HBM_BYTES, f"{program} needs {need / 2**30:.2f} GiB"
+    rings = 2 * 6 * 33 * 128 * 1024 * 2
+    limit = rings if program == "block_of_8" else 1.5 * 2**30
+    assert mem.temp_size_in_bytes < limit, (
+        f"{mem.temp_size_in_bytes / 2**20:.0f} MiB of temporaries")
+
+
 def test_the_piped_mixed_steps_carry_programs_compile_at_the_cell_size(
     one_chip, no_persistent_cache
 ):
