@@ -86,8 +86,8 @@ COMPILE_SURFACES = {
     "mixed_step": {
         "module": "dynamo_tpu/engine/engine.py",
         "kind": "jit",
-        "donate": (1, 2, 12),
-        "static": (),
+        "donate": (1, 2, 4),
+        "static": (7,),
         "axes": {
             "N": "bucket_for(tokens, mixed_token_buckets(config)): real "
                  "tokens, at most 4 powers of two from "
@@ -111,8 +111,8 @@ COMPILE_SURFACES = {
     "mixed_step_variant": {
         "module": "dynamo_tpu/engine/engine.py",
         "kind": "jit",
-        "donate": (1, 2, 12),
-        "static": (),
+        "donate": (1, 2, 4),
+        "static": (7,),
         "axes": {
             "N": "bucket_for(tokens, mixed_token_buckets(config)): real "
                  "tokens, at most 4 powers of two from "
@@ -227,8 +227,11 @@ COMPILE_SURFACES = {
         "module": "dynamo_tpu/engine/engine.py",
         "kind": "jit",
         "donate": (),
-        "static": (),
-        "axes": {"B": "config.max_num_seqs"},
+        "static": (13,),
+        "axes": {
+            "B": "config.max_num_seqs",
+            "layout": "the one layout of a patch's buffer (engine.PATCH_KEYS)",
+        },
         "warmup": True,
         "help": "masked on-device swap of per-lane decode state at slot "
                 "turnover (no donation: old carry is the fallback for "
@@ -238,10 +241,12 @@ COMPILE_SURFACES = {
         "module": "dynamo_tpu/engine/engine.py",
         "kind": "jit",
         "donate": (),
-        "static": (),
+        "static": (6,),
         "axes": {
             "B": "config.max_num_seqs",
             "R": "the mixed step's row bucket (engine._mixed_row_bucket)",
+            "layout": "the step's own buffer (engine.MIXED_KEYS + CARRY_KEYS): "
+                      "one a member of the mixed family",
         },
         "warmup": True,
         "dispatch": ("_carry_write",),

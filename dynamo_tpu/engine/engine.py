@@ -287,6 +287,69 @@ def _auto_num_pages(params, model_cfg, config: EngineConfig,
     return int(n)
 
 
+def pack_words(arrays):
+    """The host side of a hot dispatch's one transfer (JaxEngine.
+    _put_words): these arrays laid end to end as ONE int32 buffer, and the
+    layout that takes it apart again. An array of 4-byte elements rides as
+    its own bits (a view: seeds stay uint32, temperatures and penalties
+    float32); a bool mask is widened, a word an element."""
+    for a in arrays:
+        assert a.dtype == np.bool_ or a.dtype.itemsize == 4, a.dtype
+    layout = tuple((a.shape, a.dtype.str) for a in arrays)
+    words = [
+        a.astype(np.int32).ravel() if a.dtype == np.bool_
+        else np.ascontiguousarray(a).view(np.int32).ravel()
+        for a in arrays
+    ]
+    return np.concatenate(words), layout
+
+
+#: what rides a mixed step's buffer, by the pack's keys (_blank_mixed_pack)
+#: in their order; a plain pack adds the carry's maps (CARRY_KEYS), a variant
+#: pack the rows' adapter indices where adapters are registered; a stateful
+#: family's row lanes ("lanes") ride last
+MIXED_KEYS = (
+    "toks", "positions", "row_ids", "tables", "row_starts", "row_lens",
+    "ctx_lens", "last_flat", "temps", "top_ks", "top_ps", "seeds", "pens",
+    "pen_rows",
+)
+CARRY_KEYS = ("row_lane", "w_lane", "w_pos")
+#: what rides a lane patch's buffer, in _dev_patch's order
+PATCH_KEYS = (
+    "lane_mask", "table_mask", "tokens", "positions", "seq_lens", "tables",
+    "temps", "top_ks", "top_ps", "seeds", "pens", "recent",
+)
+
+
+def riders(names, buf, layout) -> dict:
+    """The device side, inside a program that takes a dispatch's buffer:
+    the arrays `pack_words` laid end to end in `buf`, under `names` in the
+    order the host laid them, by static slices, each in the shape and
+    dtype it had on the host and to the bit (a bitcast, never a cast; a
+    mask is narrowed back)."""
+    assert len(names) == len(layout), (names, layout)
+    out, off = {}, 0
+    for name, (shape, dtype) in zip(names, layout):
+        n, dtype = int(np.prod(shape, dtype=np.int64)), np.dtype(dtype)
+        x = jax.lax.slice(buf, (off,), (off + n,)).reshape(shape)
+        off += n
+        if dtype == np.bool_:
+            x = x != 0
+        elif dtype != np.int32:
+            x = jax.lax.bitcast_convert_type(x, dtype)
+        out[name] = x
+    return out
+
+
+def rows_sampling(o: dict) -> SamplingParams:
+    """The rows' sampling parameters out of a step's riders."""
+    pens = o["pens"]
+    return SamplingParams(
+        o["temps"], o["top_ks"], o["top_ps"], o["seeds"],
+        pens[:, 0], pens[:, 1], pens[:, 2],
+    )
+
+
 def carry_read(tokens, pen_rows, row_starts, row_lane, carry_tok, carry_pen):
     """The mixed step's read of the decode pipeline's device carry: a row
     whose `row_lane` names a lane (>= 0; decode rows of a piped pack) takes
@@ -1015,29 +1078,22 @@ class JaxEngine:
             self.STATE_FAMILY,
         )
 
-    def _with_lanes(self, call, lanes, entry: Optional[dict] = None):
-        """`call`, a dispatch that packs rows (a prefill batch, a mixed
-        step), for a stateful family: on the device thread, right before
-        it, the cache learns the lane of each row (`lanes`; rows past it
-        and every padded row: the scratch slot); right behind it, where
+    def _routed_behind(self, call, entry: dict):
+        """`call`, a stateful family's dispatch that packs rows (a prefill
+        batch, a mixed step): right behind it, on the device thread, where
         `entry` says a row's request asked for the experts it chose
         (`want_routed`), they are copied out of the cache before the next
-        dispatch takes it (fetched with the entry). Any other family:
-        `call` as it is."""
-        if not self._stateful:
+        dispatch takes it (fetched with the entry)."""
+        if not entry.get("want_routed"):
             return call
-        lanes = np.asarray(lanes, np.int32)
 
-        def with_lanes(*a):
-            with self._rec.span("put", more=True):
-                self.kv_k = self.kv_k.with_lanes(lanes)
+        def routed_behind(*a):
             out = call(*a)
-            if entry is not None and entry.get("want_routed"):
-                with self._rec.span("launch", more=True):
-                    entry["routed"] = jnp.copy(self.kv_k.routed_flat)
+            with self._rec.span("launch", more=True):
+                entry["routed"] = jnp.copy(self.kv_k.routed_flat)
             return out
 
-        return with_lanes
+        return routed_behind
 
     def _note_first_chunks(self, starts) -> None:
         """Rows of a dispatch that begin a sequence (context 0): the
@@ -1224,10 +1280,14 @@ class JaxEngine:
 
         self._prefill_batch = prefill_batch
 
-        @partial(jax.jit, donate_argnums=(1, 2, 12), out_shardings=prefill_out_sh)
-        def mixed_step(params, kv_k, kv_v, tokens, positions, row_ids,
-                       page_tables, row_starts, row_lens, ctx_lens, last_flat,
-                       samp, rng, pen_rows, row_lane, carry_tok, carry_pen):
+        # a stateful family's cache learns the lane of each row from the
+        # step's own buffer (the pack's "lanes": _blank_mixed_pack)
+        lanes_key = ("lanes",) if self._stateful else ()
+
+        @partial(jax.jit, donate_argnums=(1, 2, 4), static_argnums=(7,),
+                 out_shardings=prefill_out_sh)
+        def mixed_step(params, kv_k, kv_v, ops, rng, carry_tok, carry_pen,
+                       layout):
             """Unified mixed step: ONE ragged forward over a flat buffer
             packing prefill chunks (row_len > 1) and decode lanes
             (row_len == 1), with each row's last-token logits sampled on
@@ -1236,15 +1296,23 @@ class JaxEngine:
             Attention rides ops/pallas_ragged_attention on TPU, the XLA
             ragged reference elsewhere. Decode rows of a piped pack take
             their token and penalty window from the decode carry by lane
-            (carry_read), so the step queues behind whatever is in flight."""
+            (carry_read), so the step queues behind whatever is in flight.
+            The host's operands arrive as ONE buffer (`ops`, JaxEngine.
+            _put_words) and are taken apart here by static slices."""
+            o = riders(MIXED_KEYS + CARRY_KEYS + lanes_key, ops, layout)
+            if lanes_key:
+                kv_k = kv_k.replace(lanes=o["lanes"])
+            samp = rows_sampling(o)
+            row_lens, ctx_lens = o["row_lens"], o["ctx_lens"]
             tokens, pen_rows = carry_read(
-                tokens, pen_rows, row_starts, row_lane, carry_tok, carry_pen
+                o["toks"], o["pen_rows"], o["row_starts"], o["row_lane"],
+                carry_tok, carry_pen,
             )
             rng, sub = jax.random.split(rng)
             logits, kv_k, kv_v = self._model.ragged_forward(
-                params, c, tokens, positions, row_ids, kv_k, kv_v,
-                page_tables, row_starts, row_lens, ctx_lens, last_flat,
-                long_rows=cfg.max_prefill_batch,
+                params, c, tokens, o["positions"], o["row_ids"], kv_k, kv_v,
+                o["tables"], o["row_starts"], row_lens, ctx_lens,
+                o["last_flat"], long_rows=cfg.max_prefill_batch,
             )
             plogits = penalized(logits, samp, pen_rows)
             # the sampled token's position counter: the row's last real
@@ -1259,11 +1327,10 @@ class JaxEngine:
 
         self._mixed_step = mixed_step
 
-        @partial(jax.jit, donate_argnums=(1, 2, 12), out_shardings=prefill_out_sh)
-        def mixed_step_variant(params, kv_k, kv_v, tokens, positions, row_ids,
-                               page_tables, row_starts, row_lens, ctx_lens,
-                               last_flat, samp, rng, pen_rows, mask_packed,
-                               lora):
+        @partial(jax.jit, donate_argnums=(1, 2, 4), static_argnums=(7,),
+                 out_shardings=prefill_out_sh)
+        def mixed_step_variant(params, kv_k, kv_v, ops, rng, mask_packed,
+                               lora, layout):
             """Mixed step for VARIANT row classes (guided / multi-LoRA /
             speculative): same ragged forward + per-row sampling as
             mixed_step plus a bitpacked per-row FSM admissibility mask
@@ -1275,14 +1342,24 @@ class JaxEngine:
             ordinary one-token rows whose ctx includes their sibling
             draft rows' KV (written before attention each layer).
             A separate lazy jit so plain blended-free traffic never
-            carries the mask/adapter operands."""
+            carries the mask/adapter operands. The mask (R x V/8 bytes)
+            is an operand of its own beside the step's buffer; the rows'
+            adapter indices ride the buffer."""
+            idx_key = ("lora_idx",) if lora is not None else ()
+            o = riders(MIXED_KEYS + idx_key + lanes_key, ops, layout)
+            if lanes_key:
+                kv_k = kv_k.replace(lanes=o["lanes"])
+            if lora is not None:
+                lora = dict(lora, idx=o["lora_idx"])
+            samp = rows_sampling(o)
+            row_lens, ctx_lens = o["row_lens"], o["ctx_lens"]
             rng, sub = jax.random.split(rng)
             logits, kv_k, kv_v = self._model.ragged_forward(
-                params, c, tokens, positions, row_ids, kv_k, kv_v,
-                page_tables, row_starts, row_lens, ctx_lens, last_flat,
-                lora=lora, long_rows=cfg.max_prefill_batch,
+                params, c, o["toks"], o["positions"], o["row_ids"], kv_k,
+                kv_v, o["tables"], o["row_starts"], row_lens, ctx_lens,
+                o["last_flat"], lora=lora, long_rows=cfg.max_prefill_batch,
             )
-            plogits = penalized(logits, samp, pen_rows)
+            plogits = penalized(logits, samp, o["pen_rows"])
             mask = unpack_mask(mask_packed, c.vocab_size)
             first = sample_lp(
                 plogits, samp, sub, mask=mask,
@@ -1496,42 +1573,47 @@ class JaxEngine:
             patch_out_sh = (repl,) * 12
             write_out_sh = (repl,) * 4
 
-        @partial(jax.jit, out_shardings=patch_out_sh)
+        @partial(jax.jit, static_argnums=(13,), out_shardings=patch_out_sh)
         def patch_lanes(
             tokens, positions, seq_lens, tables, temps, top_ks, top_ps, seeds,
-            presence, frequency, repetition, recent,
-            lane_mask, table_mask,
-            n_tokens, n_positions, n_seq_lens, n_tables, n_temps, n_top_ks,
-            n_top_ps, n_seeds, n_pens, n_recent,
+            presence, frequency, repetition, recent, ops, layout,
         ):
-            tokens = jnp.where(lane_mask, n_tokens, tokens)
-            positions = jnp.where(lane_mask, n_positions, positions)
-            seq_lens = jnp.where(lane_mask, n_seq_lens, seq_lens)
-            temps = jnp.where(lane_mask, n_temps, temps)
-            top_ks = jnp.where(lane_mask, n_top_ks, top_ks)
-            top_ps = jnp.where(lane_mask, n_top_ps, top_ps)
-            seeds = jnp.where(lane_mask, n_seeds, seeds)
+            # the host's side arrives as ONE buffer (_dev_patch)
+            n = riders(PATCH_KEYS, ops, layout)
+            lane_mask, table_mask = n["lane_mask"], n["table_mask"]
+            tokens = jnp.where(lane_mask, n["tokens"], tokens)
+            positions = jnp.where(lane_mask, n["positions"], positions)
+            seq_lens = jnp.where(lane_mask, n["seq_lens"], seq_lens)
+            temps = jnp.where(lane_mask, n["temps"], temps)
+            top_ks = jnp.where(lane_mask, n["top_ks"], top_ks)
+            top_ps = jnp.where(lane_mask, n["top_ps"], top_ps)
+            seeds = jnp.where(lane_mask, n["seeds"], seeds)
             # the three penalty columns in and out as the sampler holds
             # them: no stack before the call, no slices of its result
+            n_pens = n["pens"]
             presence = jnp.where(lane_mask, n_pens[:, 0], presence)
             frequency = jnp.where(lane_mask, n_pens[:, 1], frequency)
             repetition = jnp.where(lane_mask, n_pens[:, 2], repetition)
-            recent = jnp.where(lane_mask[:, None], n_recent, recent)
-            tables = jnp.where(table_mask[:, None], n_tables, tables)
+            recent = jnp.where(lane_mask[:, None], n["recent"], recent)
+            tables = jnp.where(table_mask[:, None], n["tables"], tables)
             return (
                 tokens, positions, seq_lens, tables, temps, top_ks, top_ps,
                 seeds, presence, frequency, repetition, recent,
             )
 
         self._patch_lanes = patch_lanes
-        # a piped mixed step's samples into the carry (carry_write): one
-        # program, whatever the pack's token bucket. jit keeps its cache
-        # by the function it wraps, so a partial of this engine's own:
-        # `_surface_cache_sizes` then counts this engine's programs only
-        self._carry_write = jax.jit(
-            partial(carry_write), out_shardings=write_out_sh
-        )
+        # a piped mixed step's samples into the carry (carry_write), by
+        # the two maps that rode the step's buffer: a program a layout of
+        # the family. A def of this engine's own (jit keeps its cache by
+        # the function it wraps): `_surface_cache_sizes` then counts this
+        # engine's programs only
+        @partial(jax.jit, static_argnums=(6,), out_shardings=write_out_sh)
+        def _carry_write(tok_d, pos_d, sl_d, pen_d, sampled, ops, layout):
+            o = riders(MIXED_KEYS + CARRY_KEYS + lanes_key, ops, layout)
+            return carry_write(
+                tok_d, pos_d, sl_d, pen_d, sampled, o["w_lane"], o["w_pos"])
 
+        self._carry_write = _carry_write
         # disagg KV movement (host-staged; llm/disagg.py wire format).
         # ops/kv_quant's accessors cover both store shapes: a plain fp
         # pool, or a QuantKV whose q pages AND per-page scales
@@ -3075,41 +3157,61 @@ class JaxEngine:
     # -- replicated device programs (leader dispatches these after a
     # _bcast; followers replay them verbatim in run_follower) ------------ #
 
-    def _samp_operand(self, temps, top_ks, top_ps, seeds, pens):
-        """The rows' sampling parameters on the device (inside a caller's
-        `put` span)."""
-        return SamplingParams(
-            temperature=jnp.asarray(temps),
-            top_k=jnp.asarray(top_ks),
-            top_p=jnp.asarray(top_ps),
-            seed=jnp.asarray(seeds),
-            presence=jnp.asarray(pens[:, 0]),
-            frequency=jnp.asarray(pens[:, 1]),
-            repetition=jnp.asarray(pens[:, 2]),
-        )
+    def _put(self, *arrays):
+        """One dispatch's host arrays handed to the runtime in ONE call,
+        inside the caller's `put` span, and back on the device in the order
+        given; `put_arrays` counts them. The call costs 0.17 ms an array on
+        the chip whatever the bytes (PERF.md section 6, PR 51): the cold
+        dispatches (the split prefills, a guided or adapter block, the
+        carry's reset) pay that, a hot one lays its arrays end to end first
+        (_put_words)."""
+        self._rec.put_arrays += len(arrays)
+        return jax.device_put(arrays)
+
+    def _put_words(self, riding, beside=()):
+        """`_put` of a hot dispatch (a mixed step, a lane patch): `riding`
+        (4-byte elements, bool masks) as ONE int32 buffer (pack_words),
+        `beside` as they are. The program takes the buffer and its layout
+        and slices it itself (riders): an array a program returns costs
+        another 0.1 ms, so nothing takes the buffer apart on the way.
+        Returns (buffer, layout, *beside), on the device."""
+        buf, layout = pack_words(riding)
+        buf, *beside = self._put(buf, *beside)
+        return (buf, layout, *beside)
+
+    @staticmethod
+    def _samp_host(temps, top_ks, top_ps, seeds, pens):
+        """The rows' sampling parameters on the host as SamplingParams'
+        seven fields, for a dispatch's `_put`."""
+        return temps, top_ks, top_ps, seeds, pens[:, 0], pens[:, 1], pens[:, 2]
 
     def _prefill_operands(self, toks, positions, tables, ctx_lens, last_idx,
                           temps, top_ks, top_ps, seeds, pens, pen_rows,
-                          *more):
+                          *more, lanes=None):
         """A batched prefill program's operands from the host, on the
         device: (toks, positions, tables, ctx_lens, last_idx, samp,
         pen_rows) and, behind them, what a variant takes `more` of (a
-        mask, embeddings). One `put` span."""
+        mask, embeddings, adapter indices). A stateful family's cache
+        learns the lane of each row from the same call (`lanes`: every row
+        the scratch slot's where the caller names none, a program compiled
+        ahead of its first call). One `put` span."""
         with self._rec.span("put"):
-            return (
-                jnp.asarray(toks),
-                jnp.asarray(positions),
-                jnp.asarray(tables),
-                jnp.asarray(ctx_lens),
-                jnp.asarray(last_idx),
-                self._samp_operand(temps, top_ks, top_ps, seeds, pens),
-                jnp.asarray(pen_rows),
-                *(jnp.asarray(x) for x in more),
+            if self._stateful:
+                more = (*more, self.kv_k.row_lanes(()) if lanes is None
+                        else lanes)
+            dev = self._put(
+                toks, positions, tables, ctx_lens, last_idx,
+                *self._samp_host(temps, top_ks, top_ps, seeds, pens),
+                pen_rows, *more,
             )
+            if self._stateful:
+                *dev, rows = dev
+                self.kv_k = self.kv_k.replace(lanes=rows)
+            return (*dev[:5], SamplingParams(*dev[5:12]), *dev[12:])
 
-    def _dev_prefill(self, *operands):
+    def _dev_prefill(self, *operands, lanes=None):
         toks, positions, tables, ctx_lens, last_idx, samp, pen_rows = \
-            self._prefill_operands(*operands)
+            self._prefill_operands(*operands, lanes=lanes)
         with self._rec.span("launch"):
             first, self.kv_k, self.kv_v, self._rng = self._prefill_batch(
                 self.params, self.kv_k, self.kv_v, toks, positions, tables,
@@ -3121,40 +3223,36 @@ class JaxEngine:
         """(operands, carry) of one mixed step from its pack as the "mixed"
         broadcast carries it (_blank_mixed_pack's keys): mixed_step's, with
         the decode carry it reads by lane, for a plain pack; for a pack
-        with a mask mixed_step_variant's, and None. The step's transfers
-        from the host to the device: one `put` span."""
+        with a mask mixed_step_variant's, and None. The step's one
+        transfer from the host to the device (_put_words; the programs
+        take the buffer apart by MIXED_KEYS): one `put` span."""
         with self._rec.span("put"):
-            pens = p["pens"]
-            samp = self._samp_operand(
-                p["temps"], p["top_ks"], p["top_ps"], p["seeds"], pens
-            )
-            args = (
-                self.params,
-                self.kv_k,
-                self.kv_v,
-                jnp.asarray(p["toks"]),
-                jnp.asarray(p["positions"]),
-                jnp.asarray(p["row_ids"]),
-                jnp.asarray(p["tables"]),
-                jnp.asarray(p["row_starts"]),
-                jnp.asarray(p["row_lens"]),
-                jnp.asarray(p["ctx_lens"]),
-                jnp.asarray(p["last_flat"]),
-                samp,
-                # donated: a priming call runs on a copy
-                jnp.copy(self._rng) if "prime" in p else self._rng,
-                jnp.asarray(p["pen_rows"]),
-            )
-            if "mask" in p:
-                # variant pack: the mask operand is always present (all-ones
-                # for maskless packs — an exact no-op), the LoRA operand rides
-                # iff adapters are registered (idx 0 rows are the base no-op),
-                # so exactly ONE variant program exists per deployment
-                lora = (
-                    self._lora_operand(p["lora_idx"])
-                    if self._lora is not None and "lora_idx" in p else None
-                )
-                return (*args, jnp.asarray(p["mask"]), lora), None
+            variant = "mask" in p
+            riding = [p[k] for k in MIXED_KEYS]
+            # variant pack: the mask operand is always present (all-ones
+            # for maskless packs — an exact no-op), the adapter indices ride
+            # iff adapters are registered (idx 0 rows are the base no-op),
+            # so exactly ONE variant program exists per deployment
+            with_idx = variant and self._lora is not None and "lora_idx" in p
+            if with_idx:
+                riding.append(p["lora_idx"])
+            if not variant:
+                # every plain pack carries the carry's three maps, so a
+                # shape of mixed_step has one layout and carry_write finds
+                # its two in the step's buffer: a drained pack names no lane
+                none = np.full_like(p["row_lens"], -1)
+                riding += [p.get(k, none) for k in CARRY_KEYS]
+            if self._stateful:  # the lane of each row, with its pack
+                riding.append(p["lanes"])
+            # a mask is R x V/8 bytes: an operand of its own, same call
+            ops, layout, *mask = self._put_words(
+                riding, [p["mask"]] if variant else [])
+            # donated: a priming call runs on a copy
+            rng = jnp.copy(self._rng) if "prime" in p else self._rng
+            if variant:
+                lora = self._lora_operand() if with_idx else None
+                return (self.params, self.kv_k, self.kv_v, ops, rng, *mask,
+                        lora, layout), None
             # plain pack: the lean program. A drained pack reads no lane
             # (its map is all -1), so any carry of the right shape serves
             if self._carry is not None:
@@ -3164,9 +3262,8 @@ class JaxEngine:
                 lanes = jnp.zeros((B,), jnp.int32)
                 carry = (lanes, lanes, lanes, jnp.full(
                     (B, self.config.penalty_window), -1, jnp.int32))
-            none = np.full_like(p["row_lens"], -1)
-            row_lane = jnp.asarray(p.get("row_lane", none))
-            return (*args, row_lane, carry[0], carry[3]), carry
+            return (self.params, self.kv_k, self.kv_v, ops, rng, carry[0],
+                    carry[3], layout), carry
 
     def _dev_mixed(self, p: dict):
         """One mixed step from its operands as the "mixed" broadcast carries
@@ -3186,17 +3283,14 @@ class JaxEngine:
                     self._mixed_step_variant(*args)
             else:
                 first, self.kv_k, self.kv_v, rng = self._mixed_step(*args)
-        if carry is not None and (piped or prime):
-            # priming compiles the write-back beside the family, on
-            # a map that names no lane, and drops what it returns
-            none = np.full_like(p["row_lens"], -1)
-            with self._rec.span("put", more=True):
-                w_lane = jnp.asarray(p.get("w_lane", none))
-                w_pos = jnp.asarray(p.get("w_pos", none))
-            with self._rec.span("launch", more=True):
-                wrote = self._carry_write(*carry, first[0], w_lane, w_pos)
-            if piped:
-                self._carry, self._pen_dev = wrote[:3], wrote[3]
+            if carry is not None and (piped or prime):
+                # priming compiles the write-back beside the family, on
+                # a map that names no lane, and drops what it returns; its
+                # two maps rode the step's buffer
+                ops, layout = args[3], args[7]
+                wrote = self._carry_write(*carry, first[0], ops, layout)
+                if piped:
+                    self._carry, self._pen_dev = wrote[:3], wrote[3]
         if not prime:
             self._rng = rng
         return first
@@ -3222,10 +3316,10 @@ class JaxEngine:
             for done in [pool.submit(compile_one, ops) for ops in operands]:
                 done.result()
 
-    def _dev_prefill_mm(self, *operands):
+    def _dev_prefill_mm(self, *operands, lanes=None):
         # the last two: the encoder's rows and where they go
         (toks, positions, tables, ctx_lens, last_idx, samp, pen_rows,
-         emb, emb_mask) = self._prefill_operands(*operands)
+         emb, emb_mask) = self._prefill_operands(*operands, lanes=lanes)
         with self._rec.span("launch"):
             first, self.kv_k, self.kv_v, self._rng = self._prefill_batch_mm(
                 self.params, self.kv_k, self.kv_v, toks, positions, tables,
@@ -3233,10 +3327,10 @@ class JaxEngine:
             )
         return first
 
-    def _dev_prefill_guided(self, *operands):
+    def _dev_prefill_guided(self, *operands, lanes=None):
         # the last one: the rows' packed FSM masks
         (toks, positions, tables, ctx_lens, last_idx, samp, pen_rows,
-         mask) = self._prefill_operands(*operands)
+         mask) = self._prefill_operands(*operands, lanes=lanes)
         with self._rec.span("launch"):
             first, self.kv_k, self.kv_v, self._rng = self._prefill_batch_guided(
                 self.params, self.kv_k, self.kv_v, toks, positions, tables,
@@ -3244,20 +3338,18 @@ class JaxEngine:
             )
         return first
 
-    def _lora_operand(self, idx):
-        return {
-            "a": self._lora["a"],
-            "b": self._lora["b"],
-            "scale": self._lora["scale"],
-            "idx": jnp.asarray(idx),
-        }
+    def _lora_operand(self, idx=None):
+        """The adapter stack with the rows' indices, already on the device
+        (they rode their dispatch's `_put`); without them for a variant
+        mixed step, which finds them in its own buffer."""
+        lora = {k: self._lora[k] for k in ("a", "b", "scale")}
+        return lora if idx is None else dict(lora, idx=idx)
 
-    def _dev_prefill_lora(self, *operands):
-        *operands, idx = operands
-        toks, positions, tables, ctx_lens, last_idx, samp, pen_rows = \
-            self._prefill_operands(*operands)
-        with self._rec.span("put", more=True):
-            lora = self._lora_operand(idx)
+    def _dev_prefill_lora(self, *operands, lanes=None):
+        # the last one: the rows' adapter indices
+        (toks, positions, tables, ctx_lens, last_idx, samp, pen_rows,
+         idx) = self._prefill_operands(*operands, lanes=lanes)
+        lora = self._lora_operand(idx)
         with self._rec.span("launch"):
             first, self.kv_k, self.kv_v, self._rng = self._prefill_batch_lora(
                 self.params, self.kv_k, self.kv_v, toks, positions, tables,
@@ -3268,7 +3360,7 @@ class JaxEngine:
     def _dev_block_lora(self, idx):
         carry = self._carry
         with self._rec.span("put"):
-            lora = self._lora_operand(idx)
+            lora = self._lora_operand(*self._put(idx))
         with self._rec.span("launch"):
             (
                 toks, tok_d, pos_d, sl_d,
@@ -3285,37 +3377,29 @@ class JaxEngine:
     def _dev_reset(self, tokens, positions, seq_lens, page_tables, temps,
                    top_ks, top_ps, seeds, pens, recent, hist=None):
         with self._rec.span("put"):
-            self._samp_dev = self._samp_operand(
-                temps, top_ks, top_ps, seeds, pens
+            dev = self._put(
+                tokens, positions, seq_lens, recent, page_tables,
+                *self._samp_host(temps, top_ks, top_ps, seeds, pens),
+                *(() if hist is None else (hist,)),
             )
-            self._carry = (
-                jnp.asarray(tokens),
-                jnp.asarray(positions),
-                jnp.asarray(seq_lens),
-            )
-            self._pen_dev = jnp.asarray(recent)
-            self._tables_dev = jnp.asarray(page_tables)
+            self._carry = dev[:3]
+            self._pen_dev, self._tables_dev = dev[3:5]
+            self._samp_dev = SamplingParams(*dev[5:12])
             if hist is not None:
-                self._hist_dev = jnp.asarray(hist)
+                self._hist_dev = dev[12]
 
     def _dev_patch(self, lane_mask, table_mask, tokens, positions, seq_lens,
                    tables, temps, top_ks, top_ps, seeds, pens, recent,
                    hist=None):
         samp = self._samp_dev
         with self._rec.span("put"):
-            (
+            # PATCH_KEYS' order; a speculating engine's ring, and the mask
+            # that patches it, go beside the buffer in the same call
+            spec = hist is not None and self._hist_dev is not None
+            ops, layout, *ring = self._put_words([
                 lane_mask, table_mask, tokens, positions, seq_lens, tables,
                 temps, top_ks, top_ps, seeds, pens, recent,
-            ) = (
-                jnp.asarray(x) for x in (
-                    lane_mask, table_mask, tokens, positions, seq_lens,
-                    tables, temps, top_ks, top_ps, seeds, pens, recent,
-                )
-            )
-            hist_d = (
-                jnp.asarray(hist)
-                if hist is not None and self._hist_dev is not None else None
-            )
+            ], [lane_mask, hist] if spec else [])
         with self._rec.span("launch"):
             (
                 tok_d, pos_d, sl_d, tab_d, t_d, k_d, p_d, s_d,
@@ -3325,8 +3409,7 @@ class JaxEngine:
                 self._tables_dev,
                 samp.temperature, samp.top_k, samp.top_p, samp.seed,
                 samp.presence, samp.frequency, samp.repetition, self._pen_dev,
-                lane_mask, table_mask, tokens, positions, seq_lens, tables,
-                temps, top_ks, top_ps, seeds, pens, recent,
+                ops, layout,
             )
         self._carry = (tok_d, pos_d, sl_d)
         self._tables_dev = tab_d
@@ -3335,10 +3418,11 @@ class JaxEngine:
             temperature=t_d, top_k=k_d, top_p=p_d, seed=s_d,
             presence=pres_d, frequency=freq_d, repetition=rep_d,
         )
-        if hist_d is not None:
+        if spec:
             # dirty lanes take the host ring row; others keep the (newer)
             # device rows appended by in-flight spec blocks
             with self._rec.span("launch", more=True):
+                lane_mask, hist_d = ring
                 self._hist_dev = jnp.where(
                     lane_mask[:, None], hist_d, self._hist_dev,
                 )
@@ -3386,15 +3470,15 @@ class JaxEngine:
     def _dev_block_guided(self, mask, lora_idx=None):
         carry = self._carry
         with self._rec.span("put"):
+            mask, *idx = self._put(
+                mask, *(() if lora_idx is None else (lora_idx,)))
             args = (
                 self.params, self.kv_k, self.kv_v,
                 carry[0], carry[1], carry[2],
                 self._tables_dev, self._samp_dev, self._rng,
-                jnp.asarray(mask), self._pen_dev,
+                mask, self._pen_dev,
             )
-            lora = (
-                self._lora_operand(lora_idx) if lora_idx is not None else None
-            )
+            lora = self._lora_operand(*idx) if idx else None
         with self._rec.span("launch"):
             if lora is not None:
                 out = self._decode_step_guided_lora(*args, lora)
@@ -4295,8 +4379,9 @@ class JaxEngine:
                     (s, lane * bucket, chunk) for s, chunk, lane in meta
                     if s.want_routed
                 ]
-                call = self._with_lanes(
-                    call, [s.slot_idx for s, _, _ in meta], entry)
+                call = self._routed_behind(partial(
+                    call, lanes=self.kv_k.row_lanes(
+                        [s.slot_idx for s, _, _ in meta])), entry)
             self._rec.dispatched(entry, "prefill", work.of(self._step_work))
         entry["first"] = await self._run_on_device(
             call, tag="prefill", shape=(bucket, B_pf)
@@ -4368,10 +4453,12 @@ class JaxEngine:
     def _dev_prefill_single(self, toks, table, ctx, real, temps, top_ks,
                             top_ps, seeds, pens, pen_rows):
         with self._rec.span("put"):
-            toks, table, pen_rows = (
-                jnp.asarray(toks), jnp.asarray(table), jnp.asarray(pen_rows))
-            ctx, real = jnp.asarray(ctx, jnp.int32), jnp.asarray(real, jnp.int32)
-            samp = self._samp_operand(temps, top_ks, top_ps, seeds, pens)
+            toks, table, pen_rows, ctx, real, *samp = self._put(
+                toks, table, pen_rows,
+                np.asarray(ctx, np.int32), np.asarray(real, np.int32),
+                *self._samp_host(temps, top_ks, top_ps, seeds, pens),
+            )
+            samp = SamplingParams(*samp)
         with self._rec.span("launch"):
             first, self.kv_k, self.kv_v, self._rng = self._prefill_single(
                 self.params, self.kv_k, self.kv_v, toks, table, ctx, real,
@@ -5129,6 +5216,7 @@ class JaxEngine:
             pens, pen_rows = payload["pens"], payload["pen_rows"]
             mask_packed = payload.get("mask")
             lora_rows = payload.get("lora_idx")
+            row_lanes = payload.get("lanes")  # a stateful family's
 
             off = 0
             row = 0
@@ -5164,6 +5252,8 @@ class JaxEngine:
                     self.mixed_rows_plain += 1
                 if lora_rows is not None:
                     lora_rows[row] = s.lora_idx
+                if row_lanes is not None:
+                    row_lanes[row] = s.slot_idx
                 s.sched_skips = 0
                 meta.append((s, chunk, row))
                 work.chunk(start, chunk, start + chunk >= len(s.kv_prompt))
@@ -5203,6 +5293,8 @@ class JaxEngine:
                     seeds[row] = self.seeds[i]
                     if lora_rows is not None:
                         lora_rows[row] = s.lora_idx
+                    if row_lanes is not None:
+                        row_lanes[row] = i
                     if not spec_lane:
                         pens[row] = (self.presence[i], self.frequency[i],
                                      self.repetition[i])
@@ -5287,10 +5379,7 @@ class JaxEngine:
                         *((s, r) for r, _, s in decode_rows),
                     ) if s.want_routed
                 ]
-                call = self._with_lanes(call, [
-                    *(s.slot_idx for s, _, _ in meta),
-                    *(i for _, i, _ in decode_rows),
-                ], entry)
+                call = self._routed_behind(call, entry)
             self._rec.dispatched(entry, "mixed", work.of(self._step_work))
         entry["first"] = await self._run_on_device(
             call, tag="mixed", shape=(N_pad, row),
@@ -5391,6 +5480,9 @@ class JaxEngine:
             "pens": pens,
             "pen_rows": np.full((R_pad, cfg.penalty_window), -1, np.int32),
         }
+        if self._stateful:
+            # the lane of each row: the scratch slot's until one is packed
+            pack["lanes"] = self.kv_k.row_lanes()
         if variant:
             V = self.model_config.vocab_size
             pack["mask"] = np.full((R_pad, (V + 7) // 8), 0xFF, np.uint8)
@@ -5435,8 +5527,7 @@ class JaxEngine:
             self._bcast("mixed", pack)
             # (a stateful family: the one row is the scratch slot's)
             await self._run_on_device(
-                self._with_lanes(partial(self._dev_mixed, pack), []),
-                tag="mixed_prime",
+                partial(self._dev_mixed, pack), tag="mixed_prime",
             )
         logger.info(
             "mixed family primed: %d %s programs of %d pages, compiled in "

@@ -9,7 +9,11 @@ One table for the engine's loop, exported through `JaxEngine.stats()`:
   while a profiler session is open: the spans then lie in the
   profiler's own trace, on its clock, beside the device's operations.
   Spans do not nest and none is held across an `await` but `wait`, so
-  the seven sums add up to no more than the clock.
+  the seven sums add up to no more than the clock. Beside the `put`
+  spans, `put_arrays`: the host arrays handed to the runtime inside them
+  (engine._put counts its own; a transfer made some other way it cannot
+  see), so that `put_arrays / phase_put_count` says how many transfers a
+  dispatch makes.
 * STEP_KINDS of pipeline entries. An entry is stamped when it is
   dispatched and timed when its fetch returns: `step_<kind>_count`,
   `step_<kind>_interval_s` (ready to ready: the device's time for the
@@ -120,6 +124,7 @@ class Recorder:
         # loop's: admit, pack, emit, wait; the device thread's: put,
         # launch; the fetch thread's: fetch), so no lock
         self.phases: Dict[str, list] = {p: [0, 0.0, 0] for p in PHASES}
+        self.put_arrays = 0  # host arrays handed over inside `put` spans
         self._labels = {p: f"engine.{p}" for p in PHASES}
         # [entries, seconds ready to ready] by kind
         self.steps: Dict[str, list] = {k: [0, 0.0] for k in STEP_KINDS}
@@ -234,6 +239,7 @@ class Recorder:
             "step_min_bytes": float(self.min_bytes),
             "step_stalled_count": self.stalled[0],
             "step_stalled_s": round(self.stalled[1], 6),
+            "put_arrays": self.put_arrays,
             "req_admitted": self.req_admitted,
             "req_queue_wait_s": round(self.req_queue_wait_s, 6),
             "req_first_tokens": self.req_first_tokens,

@@ -19,8 +19,8 @@ comes back updated, so no program of the engine takes an argument more.
     state   [state layers, lanes + 1, *state_shape]   float32
     conv    [state layers, lanes + 1, taps - 1, channels]
     lanes   [row slots] i32: the lane of each row of the NEXT dispatch that
-            packs rows (a prefill batch, a mixed step), set by the host
-            (`with_lanes`); the scratch slot for padding. A decode block's
+            packs rows (a prefill batch, a mixed step), from the host
+            (`row_lanes`); the scratch slot for padding. A decode block's
             row IS its lane and reads nothing here.
     routed_ring [ring, routed layers, lanes, k] i32: the experts a decode
             step chose for each lane, at `position % ring`
@@ -78,12 +78,14 @@ class StateCache:
             *(leaves.get(f, getattr(self, f)) for f in self.FIELDS)
         )
 
-    def with_lanes(self, lanes) -> "StateCache":
-        """The cache for a dispatch whose row r belongs to lane `lanes[r]`
-        (rows past the list: the scratch slot)."""
+    def row_lanes(self, lanes=()) -> np.ndarray:
+        """`lanes` for a dispatch whose row r belongs to lane `lanes[r]`,
+        on the host (rows past the list: the scratch slot): the engine lays
+        it into the dispatch's one transfer, and the program that takes the
+        transfer apart puts it into the cache (`replace`)."""
         full = np.full(self.lanes.shape, self.scratch_lane, np.int32)
         full[: len(lanes)] = lanes
-        return self.replace(lanes=jnp.asarray(full))
+        return full
 
     @property
     def scratch_lane(self) -> int:

@@ -184,6 +184,7 @@ METRICS = {
     "phase_wait_count": {"kind": "counter", "layer": "engine", "unit": "spans", "help": "Spans of the engine loop's `wait` phase: the loop idle or yielding to the event loop's other tasks.", "dynamic": True, "export": True},
     "phase_wait_s": {"kind": "counter", "layer": "engine", "unit": "seconds", "help": "Seconds inside the `wait` phase (profiler span engine.wait).", "dynamic": True, "export": True},
     "phase_wait_slow": {"kind": "counter", "layer": "engine", "unit": "spans", "help": "Spans of the `wait` phase that took 0.5 s or more (an idle engine: not logged).", "dynamic": True, "export": True},
+    "put_arrays": {"kind": "counter", "layer": "engine", "unit": "arrays", "help": "Host arrays handed to the runtime inside `put` spans, as engine._put counts them: over phase_put_count, the transfers a dispatch makes. A transfer made some other way goes uncounted.", "export": True},
     "step_block_count": {"kind": "counter", "layer": "engine", "unit": "entries", "help": "Pipeline entries fetched: decode blocks (plain, guided, LoRA, spec).", "dynamic": True, "export": True},
     "step_block_interval_s": {"kind": "counter", "layer": "engine", "unit": "seconds", "help": "Ready-to-ready seconds of those entries: the device's time for each plus whatever the device waited for the host inside it.", "dynamic": True, "export": True},
     "step_mixed_count": {"kind": "counter", "layer": "engine", "unit": "entries", "help": "Pipeline entries fetched: mixed steps.", "dynamic": True, "export": True},

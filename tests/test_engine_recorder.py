@@ -305,6 +305,17 @@ def test_served_requests_leave_the_counters_whole():
     fetched, every request admitted once and given a first token once, both
     waits non-negative, and nothing runs backwards between two stats()."""
     eng = _engine("dense")
+    # the first block's launch holds the device thread as a program that
+    # compiles inside it does: how long a compile takes is the CPU's (and
+    # the compilation cache's) business, so the test plants one
+    block, planted = eng._dev_block, []
+
+    def compiling():
+        if not planted:
+            planted.append(time.sleep(recorder_mod.SLOW_SPAN_S))
+        return block()
+
+    eng._dev_block = compiling
 
     async def run():
         first = eng.stats()
@@ -318,10 +329,14 @@ def test_served_requests_leave_the_counters_whole():
 
     first, mid, last, outs = asyncio.run(run())
     assert [len(o) for o in outs] == [6 + i for i in range(5)]
-    # (the entries that met a compile are counted as stalled, not as steps)
+    # an entry that met a compile is counted as stalled and not as a step:
+    # every entry is in one of the two counts, the planted one among the
+    # stalled, and a stalled entry brings its whole interval with it
     assert sum(last[f"step_{k}_count"] for k in STEP_KINDS) \
         + last["step_stalled_count"] == len(eng.dispatched)
-    assert last["step_stalled_count"] > 0 and last["step_stalled_s"] > 0.5
+    assert last["step_stalled_count"] >= len(planted) == 1
+    assert last["step_stalled_s"] \
+        >= recorder_mod.SLOW_SPAN_S * last["step_stalled_count"]
     assert last["req_admitted"] == last["req_first_tokens"] == 6
     assert mid["req_admitted"] == 5
     assert last["req_queue_wait_s"] >= 0 and last["req_admit_to_first_s"] > 0
@@ -372,6 +387,106 @@ def test_a_preempted_resume_is_not_admitted_twice():
     assert any(preempted), "the pool was to run out: make the case tighter"
     out = eng.stats()
     assert out["req_admitted"] == out["req_first_tokens"] == 2
+
+
+@pytest.mark.parametrize("family", ["dense", "routed"])
+def test_a_dispatch_hands_the_runtime_one_array(family):
+    """A few served requests: every mixed step and lane patch hands the
+    runtime ONE host array (its operands laid end to end in one buffer:
+    engine._put_words); a carry reset, which is rare and has no program to
+    slice a buffer, hands over its twelve in one call, and a split prefill
+    its thirteen. Each inside ONE counted `put` span."""
+    eng = _engine(family)
+    seen = {}  # helper -> [(arrays, spans) of each call]
+
+    def counted(name):
+        fn = getattr(eng, name)
+
+        def call(*a):
+            before = eng._rec.put_arrays, eng._rec.phases["put"][0]
+            out = fn(*a)
+            seen.setdefault(name, []).append(
+                (eng._rec.put_arrays - before[0],
+                 eng._rec.phases["put"][0] - before[1]))
+            return out
+
+        setattr(eng, name, call)
+
+    want = {"_dev_mixed": (1, 1), "_dev_patch": (1, 1), "_dev_reset": (12, 1),
+            "_dev_prefill": (13, 1)}
+    for name in want:
+        counted(name)
+
+    async def run():
+        outs = await asyncio.gather(*(
+            _stream(eng, _prompt(9 + 5 * i, 10 + i), f"r{i}", 6 + i)
+            for i in range(5)))
+        await _settled(eng)
+        return outs
+
+    outs = asyncio.run(run())
+    assert [len(o) for o in outs] == [6 + i for i in range(5)]
+    assert set(seen) == set(want)
+    for name, calls in seen.items():
+        assert set(calls) == {want[name]}, (name, calls)
+    out = eng.stats()
+    # (what is left: the mixed family's packs, put as the family compiles)
+    left = [out[k] - sum(c[i] for calls in seen.values() for c in calls)
+            for i, k in enumerate(("put_arrays", "phase_put_count"))]
+    assert left[0] == left[1] == len(eng._mixed_token_buckets)
+
+
+#: every helper that takes a dispatch's operands from the host to the device
+PUT_HELPERS = (
+    "_prefill_operands", "_mixed_operands", "_dev_mixed", "_dev_reset",
+    "_dev_patch", "_dev_block_guided", "_dev_block_lora", "_lora_operand",
+    "_dev_prefill_single",
+)
+
+
+@pytest.mark.parametrize("helper", PUT_HELPERS)
+def test_a_helpers_transfers_all_go_through_put(helper):
+    """`put_arrays` counts what `JaxEngine._put` hands over and cannot see
+    a transfer made round it, so no helper makes one: no `jnp.asarray`,
+    `jnp.array` or `jax.device_put` of its own."""
+    import inspect
+
+    src = inspect.getsource(getattr(JaxEngine, helper))
+    for call in ("jnp.asarray(", "jnp.array(", "device_put("):
+        assert call not in src, (helper, call)
+
+
+def test_a_dispatchs_buffer_comes_apart_to_the_bit():
+    """pack_words / riders: seeds stay uint32 and temperatures float32 by
+    their bits (a NaN's payload and a negative zero survive: a bitcast,
+    never a cast), masks come back bool, shapes as they were; an array of
+    another element size is refused, not reinterpreted."""
+    from dynamo_tpu.engine.engine import pack_words, riders
+
+    nan = np.array([0x7FC00123, 0x80000000, 0x3F800000], np.uint32)
+    arrays = [
+        np.array([0, 2**31, 2**32 - 1], np.uint32),
+        nan.view(np.float32),
+        np.array([True, False, True, True]),
+        np.arange(-6, 6, dtype=np.int32).reshape(3, 4),
+        np.asarray(7, np.int32),
+        np.zeros((0,), np.int32),
+        np.arange(12, dtype=np.float32).reshape(4, 3)[:, 1],  # a column
+    ]
+    names = tuple(f"a{i}" for i in range(len(arrays)))
+    buf, layout = pack_words(arrays)
+    assert buf.dtype == np.int32 and buf.ndim == 1
+    assert buf.size == sum(a.size for a in arrays)
+    got = jax.jit(riders, static_argnums=(0, 2))(names, buf, layout)
+    assert tuple(got) == names
+    for g, a in zip(got.values(), arrays):
+        assert g.dtype == a.dtype and g.shape == a.shape
+        bits = lambda x: np.asarray(x).astype(np.uint8) \
+            if a.dtype == np.bool_ else np.ascontiguousarray(x).view(np.uint32)
+        assert np.array_equal(bits(g), bits(a))
+    for odd in (np.zeros(3, np.int64), np.zeros(3, np.uint8)):
+        with pytest.raises(AssertionError):
+            pack_words([odd])
 
 
 def test_every_stats_key_of_the_recorder_is_registered():
@@ -446,7 +561,7 @@ S0 = {"engine_clock_s": 100.0, "phase_admit_s": 0.5, "phase_pack_s": 1.0,
       "phase_put_s": 1.0, "phase_launch_s": 0.5, "phase_emit_s": 1.0,
       "phase_fetch_s": 30.0, "phase_wait_s": 2.0,
       "phase_pack_count": 100, "phase_put_count": 200, "phase_emit_count": 100,
-      "step_block_count": 10, "step_block_interval_s": 1.0,
+      "put_arrays": 250, "step_block_count": 10, "step_block_interval_s": 1.0,
       "step_mixed_count": 5, "step_mixed_interval_s": 0.1,
       "step_model_flops": 1e12, "step_min_bytes": 1e12,
       "req_admitted": 10, "req_queue_wait_s": 0.2,
@@ -456,7 +571,7 @@ S1 = {"engine_clock_s": 150.0, "phase_admit_s": 1.5, "phase_pack_s": 4.0,
       "phase_put_s": 3.0, "phase_launch_s": 1.5, "phase_emit_s": 3.0,
       "phase_fetch_s": 65.0, "phase_wait_s": 4.0,
       "phase_pack_count": 600, "phase_put_count": 1200, "phase_emit_count": 500,
-      "step_block_count": 410, "step_block_interval_s": 41.4,
+      "put_arrays": 1500, "step_block_count": 410, "step_block_interval_s": 41.4,
       "step_mixed_count": 205, "step_mixed_interval_s": 3.3,
       "step_model_flops": 1e12 + 0.12 * 50 * 197e12,
       "step_min_bytes": 1e12 + 0.75 * 50 * 819e9,
@@ -469,6 +584,7 @@ WANT = {
     "engine.host_busy_share": 100 * (1.0 + 3.0 + 2.0 + 1.0 + 2.0) / 50,
     "engine.pack_ms": 1000 * 3.0 / 500,
     "engine.put_ms": 1000 * 2.0 / 1000,
+    "engine.put_arrays_per_span": 1250 / 1000,
     "engine.emit_ms": 1000 * 2.0 / 400,
     "engine.slow_spans": 3.0,
     "step.decode_block_ms": 1000 * 40.4 / 400,

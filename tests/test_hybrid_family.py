@@ -83,6 +83,12 @@ DECODE = jax.jit(lambda *a: hybrid.decode_forward(a[0], CFG, *a[1:]))
 RAGGED = jax.jit(lambda *a: hybrid.ragged_forward(a[0], CFG, *a[1:]))
 
 
+def with_lanes(cache, lanes):
+    """The cache for a dispatch whose row r belongs to lane `lanes[r]`, as
+    the engine's programs make it from their buffer."""
+    return cache.replace(lanes=jnp.asarray(cache.row_lanes(lanes)))
+
+
 def prefill(params, cache, kv_v, rows, width, fn=None):
     """One batched prefill dispatch: rows of (lane, tokens, start, table)
     (`fn`: another family's jitted forward; tests/test_nemotron_h_family.py)."""
@@ -95,7 +101,7 @@ def prefill(params, cache, kv_v, rows, width, fn=None):
         pos[b] = start + np.arange(width)
     logits, cache, kv_v = (fn or PREFILL)(
         params, jnp.asarray(toks), jnp.asarray(pos),
-        cache.with_lanes([r[0] for r in rows]), kv_v, jnp.asarray(tables),
+        with_lanes(cache, [r[0] for r in rows]), kv_v, jnp.asarray(tables),
         jnp.asarray([r[2] for r in rows], jnp.int32),
         jnp.asarray([len(r[1]) - 1 for r in rows], jnp.int32))
     return np.asarray(logits), cache, kv_v
@@ -135,7 +141,7 @@ def packed(rows, cache, kv_v, R, M):
         at += m
     return (
         *(jnp.asarray(a) for a in (toks, pos, row_ids)),
-        cache.with_lanes([r[0] for r in rows]), kv_v,
+        with_lanes(cache, [r[0] for r in rows]), kv_v,
         *(jnp.asarray(a) for a in (tables, starts, lens, ctx, last)))
 
 
@@ -227,10 +233,10 @@ def forward_case(name, params):
     toks[0, :16], toks[1, :20] = seqs[0][24:40], sequence(40, 20)
     pos[0], pos[1] = 24 + np.arange(32), np.arange(32)
     if name == "prefill_forward_batched":
-        return (jnp.asarray(toks), jnp.asarray(pos), cache.with_lanes([0, 3]),
+        return (jnp.asarray(toks), jnp.asarray(pos), with_lanes(cache, [0, 3]),
                 kv_v, jnp.asarray(np.stack([table_of(0), table_of(3)])),
                 jnp.asarray([24, 0], jnp.int32), jnp.asarray([15, 19], jnp.int32))
-    return (jnp.asarray(toks[0]), jnp.asarray(pos[0]), cache.with_lanes([0]),
+    return (jnp.asarray(toks[0]), jnp.asarray(pos[0]), with_lanes(cache, [0]),
             kv_v, jnp.asarray(table_of(0)), jnp.int32(24), jnp.int32(15))
 
 

@@ -39,7 +39,9 @@ sys.path.insert(0, os.path.join(ROOT, "benchmark"))
 from references import nemotron_h as ref  # noqa: E402
 
 from . import test_hybrid_family as hybrid_tests  # noqa: E402
-from .test_hybrid_family import off, sequence, stream, table_of  # noqa: E402
+from .test_hybrid_family import (  # noqa: E402
+    off, sequence, stream, table_of, with_lanes,
+)
 
 PAGE = 16
 TOL = 1e-3  # deviations of the reference's logits (see the module's text)
@@ -157,7 +159,7 @@ def test_a_mixed_step_of_prefill_rows_and_decode_rows(params, n):
         at += m
     logits, cache, kv_v = RAGGED(
         params, *(jnp.asarray(a) for a in (toks, pos, row_ids)),
-        cache.with_lanes([r[0] for r in rows]), kv_v,
+        with_lanes(cache, [r[0] for r in rows]), kv_v,
         *(jnp.asarray(a) for a in (tables, starts, lens, ctx, last)))
     logits = np.asarray(logits)
     assert off(logits[0], want_fresh[32]) < TOL  # the stale state was not read
