@@ -609,6 +609,9 @@ class JaxEngine:
         self._stateful = self.STATE_FAMILY is not None
         # a routed family counts the rows its expert matmuls multiply
         self._counts_expert_rows = hasattr(family, "expert_rows")
+        # a family with a recurrence steps a mixed step's one-token rows
+        # over the lanes in place (its `lanes_step`) and counts them
+        self._steps_lanes = hasattr(family, "lanes_step")
         if self._stateful:
             self._refuse_what_state_cannot_follow(config, mesh, multihost)
 
@@ -947,6 +950,12 @@ class JaxEngine:
         self.state_lanes_reset = 0
         self.state_prefix_hits_declined = 0
         self.routed_rows_emitted = 0
+        # ... and a mixed step's rows by the road their recurrence takes
+        # (ops/row_recurrence.rows_recurrence, its own rule): one token that
+        # goes on from the lane's state, stepped in the store; every other
+        # row, gathered out of it and scattered back
+        self.state_rows_in_place = 0
+        self.state_rows_gathered = 0
         self._last_prefill_shape = None  # (padded, real) of the latest dispatch
         self._last_decode_shape = None
         # set by _dispatch_mixed when a pack that needs host-authoritative
@@ -2740,6 +2749,9 @@ class JaxEngine:
             out["state_lanes_reset"] = self.state_lanes_reset
             out["state_prefix_hits_declined"] = self.state_prefix_hits_declined
             out["routed_rows_emitted"] = self.routed_rows_emitted
+        if self._steps_lanes:
+            out["state_rows_in_place"] = self.state_rows_in_place
+            out["state_rows_gathered"] = self.state_rows_gathered
         # what the mixed steps' dense layers multiplied: real tokens, and
         # the slots of the token buckets they ran in
         out["mixed_real_tokens"] = self.mixed_real_tokens
@@ -5408,6 +5420,11 @@ class JaxEngine:
                 -(-ch // tile) for ch in chunks if ch > 1
             )
             self.mixed_rows_decode_kernel += n_rows_decode + chunks.count(1)
+        if self._steps_lanes:
+            going_on = sum(
+                1 for _, ch, r in meta if ch == 1 and ctx_lens[r] > 0)
+            self.state_rows_in_place += n_rows_decode + going_on
+            self.state_rows_gathered += len(meta) - going_on
         self._count_expert_rows(N_pad, real)
         self._step_counter += 1
         return True

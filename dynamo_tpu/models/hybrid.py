@@ -47,7 +47,7 @@ from ..ops.paged_attention import (
     ragged_attention,
 )
 from ..ops.pallas_delta_step import delta_step_pallas, takes as kernel_takes
-from ..ops.row_recurrence import flat_conv, rows_recurrence
+from ..ops.row_recurrence import flat_conv, rows_recurrence, step_in_store
 from ..ops.state_cache import StateCache, StateSpec, state_bytes_per_lane
 from . import llama, moe
 from .llama import LlamaConfig
@@ -353,14 +353,30 @@ def recurrence_impl(c: HybridConfig) -> str:
     place in the store) under the gate the attention kernels have (a TPU
     backend, an engine of one device, the value head's width whole lane
     registers: paged_attention._pallas_eligible) and where the kernel takes
-    the state's shape and dtype; "xla" (`delta_step`) everywhere else. The
-    mixed step and the batched prefill keep `delta_step` for every row's
-    first token whatever this says. What the engine logs at start and
-    publishes as stats()["attention_impl"]["recurrence"]."""
+    the state's shape and dtype; "xla" (`delta_step`) everywhere else. A
+    mixed step's one-token rows that go on from a lane's state take the
+    same (`lanes_step`); the rows it gathers and the batched prefill keep
+    `delta_step` for a row's first token whatever this says. What the
+    engine logs at start and publishes as
+    stats()["attention_impl"]["recurrence"]."""
     spec = c.state_spec()
     eligible = (kernel_takes(spec.state_shape, spec.state_dtype)
                 and _pallas_eligible(c.linear_value_head_dim))
     return "pallas" if eligible else "xla"
+
+
+def lanes_step(state, ll, q, k, v, g, beta, live, *, impl: str):
+    """One token a lane of the gated delta rule over the state store IN
+    PLACE, the one function the decode step and a mixed step's one-token
+    rows share (ops/row_recurrence.py): state [state layers, lanes + 1, nv,
+    dk, dv] at layer `ll`; q, k [B, nv, dk]; v [B, nv, dv]; g, beta [B, nv];
+    live [B] (row b IS lane b: a lane that is not keeps its state bit for
+    bit). -> (state, o [B, nv, dv]). `impl`: what `recurrence_impl` said:
+    the Pallas kernel, or `delta_step` on the layer's first B slots
+    (row_recurrence.step_in_store)."""
+    if impl == "pallas":
+        return delta_step_pallas(state, ll, q, k, v, g, beta, live)
+    return step_in_store(delta_step, state, ll, q, k, v, g, beta, live=live)
 
 
 def delta_chunk(S, q, k, v, g, beta):
@@ -537,7 +553,7 @@ def decode_forward(
     phys = jnp.take_along_axis(page_tables, logical[:, None], axis=1)[:, 0]
     phys = jnp.where(positions < P_tab * page_size, phys, 0)
     offs = positions % page_size
-    in_place = recurrence_impl(c) == "pallas"
+    step_lanes = functools.partial(lanes_step, impl=recurrence_impl(c))
 
     def linear_fn(layer, h, state, conv, ll):
         mixed, z, beta, g = _mixer_inputs(layer, h, c)
@@ -546,15 +562,7 @@ def decode_forward(
         y = jnp.einsum("btc,ct->bc", window.astype(f32),
                        layer["w_conv"].astype(f32))
         q, k, v = _split_qkv(jax.nn.silu(y), c)
-        if in_place:
-            state, o = delta_step_pallas(state, ll, q, k, v, g, beta, live)
-        else:
-            S = jax.lax.dynamic_index_in_dim(state, ll, 0, False)[:B]
-            S_new, o = delta_step(S.astype(f32), q, k, v, g, beta)
-            S_new = jnp.where(
-                live[:, None, None, None], S_new.astype(S.dtype), S)
-            state = jax.lax.dynamic_update_slice(
-                state, S_new[None], (ll, 0, 0, 0, 0))
+        state, o = step_lanes(state, ll, q, k, v, g, beta, live)
         tail = jnp.where(live[:, None, None], window[:, 1:], tail)
         conv = jax.lax.dynamic_update_slice(conv, tail[None], (ll, 0, 0, 0))
         return _mixer_out(layer, o, z, c), state, conv
@@ -586,11 +594,14 @@ def _flat_linear_fn(c: HybridConfig, lanes, row_ids, row_starts, row_lens,
     share (row r: slots row_starts[r] ... + row_lens[r], lane lanes[r],
     ctx_lens[r] tokens of its sequence before it). A row starts from its
     lane's state, or from zero where its context is 0, and leaves the
-    state behind its last token in the lane. The convolution and the two
-    passes over the recurrence (the step form for every row's first token,
-    the chunked form for what is left of the `long_rows` longest) are
-    ops/row_recurrence.py's."""
+    state behind its last token in the lane. The convolution and the
+    recurrence's two roads (a row of one token that goes on from its lane's
+    state: `lanes_step` over the store, as in a decode step; the other
+    rows, of which the caller expects `long_rows` at most: gathered a few
+    a group, the step form for the first token and the chunked form for
+    what is left) are ops/row_recurrence.py's."""
     fresh = ctx_lens == 0
+    step_lanes = functools.partial(lanes_step, impl=recurrence_impl(c))
 
     def linear_fn(layer, h, state, conv, ll):
         mixed, z, beta, g = _mixer_inputs(layer, h, c)
@@ -601,16 +612,12 @@ def _flat_linear_fn(c: HybridConfig, lanes, row_ids, row_starts, row_lens,
             mixed, layer["w_conv"].astype(f32), tails, row_ids, row_starts,
             row_lens)
         q, k, v = _split_qkv(jax.nn.silu(y), c)
-        # one gather on the stored arrays (a layer's slots sliced out first
-        # are copied whole before the rows are read out of the copy)
-        S = jnp.where(
-            fresh[:, None, None, None], 0, state[ll, lanes]).astype(f32)
         # (a zero row's beta and g of 0 leave a state as it was)
-        S, o = rows_recurrence(
-            S, (q, k, v, g, beta),
+        state, o = rows_recurrence(
+            state, ll, lanes, ctx_lens, (q, k, v, g, beta),
             (c.linear_num_value_heads, c.linear_value_head_dim),
-            delta_step, delta_chunk, CHUNK, row_starts, row_lens, long_rows)
-        state = state.at[ll, lanes].set(S.astype(state.dtype))
+            step_lanes, delta_step, delta_chunk, CHUNK, row_starts, row_lens,
+            long_rows)
         conv = conv.at[ll, lanes].set(new_tails.astype(conv.dtype))
         return _mixer_out(layer, o, z, c), state, conv
 
@@ -634,13 +641,13 @@ def ragged_forward(
     long_rows: Optional[int] = None,
 ) -> Tuple[jax.Array, StateCache, jax.Array]:
     """The mixed step's forward over a compact flat buffer (see
-    models/llama.py:ragged_forward): rows of one token go through the same
-    chunked recurrence as a prompt's chunk, each from its own lane's
-    state. `long_rows`: the rows of more than one token a pack holds at
-    most, for the ragged kernel's grid (None: any row may) and for the
-    recurrence's chunked pass (None: the rows that the lanes' decode rows
-    leave of R). Returns (logits of each row's last token [R, vocab], cache,
-    kv_v)."""
+    models/llama.py:ragged_forward): a row of one token takes the decode
+    step's recurrence over its lane, a prompt's chunk the gathered one,
+    each from its own lane's state. `long_rows`: the rows of more than one
+    token a pack holds at most, for the ragged kernel's grid (None: any row
+    may) and for the recurrence's gathered rows (None: the rows that the
+    lanes' decode rows leave of R). Returns (logits of each row's last
+    token [R, vocab], cache, kv_v)."""
     _refuse(lora)
     c = config
     M, R = tokens.shape[0], row_lens.shape[0]

@@ -39,7 +39,7 @@ from ..ops.paged_attention import (
     prefill_attention_batched,
     ragged_attention,
 )
-from ..ops.row_recurrence import flat_conv, rows_recurrence
+from ..ops.row_recurrence import flat_conv, rows_recurrence, step_in_store
 from ..ops.state_cache import StateCache, StateSpec, state_bytes_per_lane
 from . import moe
 from .hybrid import (
@@ -274,6 +274,17 @@ def ssm_step(S, x, B, C, dt, *, A):
     return S, jnp.sum(S * C[..., None, :], axis=-1)
 
 
+def lanes_step(state, lm, x, B, C, dt, live, *, A):
+    """One token a lane of the same recurrence over the state store IN
+    PLACE, the one function the decode step and a mixed step's one-token
+    rows share (ops/row_recurrence.py): state [state layers, lanes + 1,
+    heads, head dim, N]; x, B, C, dt as `ssm_step` takes them, [n, ...] for
+    the layer's first n lanes (row b IS lane b); live [n]: a lane that is
+    not keeps its state bit for bit. -> (state, y [n, heads, head dim])."""
+    return step_in_store(
+        functools.partial(ssm_step, A=A), state, lm, x, B, C, dt, live=live)
+
+
 def ssd_chunk(S, x, B, C, dt, *, A):
     """A chunk of tokens of the same recurrence in closed form (the
     state-space dual form): S [R, heads, head dim, N]; x [R, L, heads, head
@@ -458,13 +469,10 @@ def decode_forward(
         y = jnp.einsum("btc,ct->bc", window.astype(f32),
                        layer["w_conv"].astype(f32)) + layer["conv_bias"]
         xs, Bm, Cm = _split_xbc(y, c)
-        S = jax.lax.dynamic_index_in_dim(state, lm, 0, False)[:B]
-        S_new, ys = ssm_step(S.astype(f32), xs, Bm, Cm, dt,
-                             A=-jnp.exp(layer["a_log"].astype(f32)))
-        S_new = jnp.where(live[:, None, None, None], S_new.astype(S.dtype), S)
+        state, ys = lanes_step(
+            state, lm, xs, Bm, Cm, dt, live,
+            A=-jnp.exp(layer["a_log"].astype(f32)))
         tail = jnp.where(live[:, None, None], window[:, 1:], tail)
-        state = jax.lax.dynamic_update_slice(
-            state, S_new[None], (lm, 0, 0, 0, 0))
         conv = jax.lax.dynamic_update_slice(conv, tail[None], (lm, 0, 0, 0))
         return _mamba_out(layer, ys, xs, z, c), state, conv
 
@@ -495,9 +503,12 @@ def _flat_mamba_fn(c: NemotronHConfig, lanes, row_ids, row_starts, row_lens,
     share (row r: slots row_starts[r] ... + row_lens[r], lane lanes[r],
     ctx_lens[r] tokens of its sequence before it). A row starts from its
     lane's state, or from zero where its context is 0, and leaves the
-    state behind its last token in the lane (ops/row_recurrence.py: the
-    step form for every row's first token, the chunked form, `chunk_size`
-    tokens an iteration, for what is left of the `long_rows` longest)."""
+    state behind its last token in the lane (ops/row_recurrence.py: a row
+    of one token that goes on from its lane's state takes `lanes_step` over
+    the store, as in a decode step; the other rows, of which the caller
+    expects `long_rows` at most, are gathered a few a group: the step form
+    for the first token, the chunked form, `chunk_size` tokens an
+    iteration, for what is left)."""
     fresh = ctx_lens == 0
 
     def mamba_fn(layer, h, state, conv, lm):
@@ -507,18 +518,14 @@ def _flat_mamba_fn(c: NemotronHConfig, lanes, row_ids, row_starts, row_lens,
             mixed, layer["w_conv"].astype(f32), tails, row_ids, row_starts,
             row_lens)
         xs, Bm, Cm = _split_xbc(y + layer["conv_bias"], c)
-        # one gather on the stored arrays (a layer's slots sliced out first
-        # are copied whole before the rows are read out of the copy)
-        S = jnp.where(
-            fresh[:, None, None, None], 0, state[lm, lanes]).astype(f32)
         A = -jnp.exp(layer["a_log"].astype(f32))
         # (a zero row's dt of 0 leaves a state as it was)
-        S, ys = rows_recurrence(
-            S, (xs, Bm, Cm, dt),
+        state, ys = rows_recurrence(
+            state, lm, lanes, ctx_lens, (xs, Bm, Cm, dt),
             (c.mamba_num_heads, c.mamba_head_dim),
+            functools.partial(lanes_step, A=A),
             functools.partial(ssm_step, A=A), functools.partial(ssd_chunk, A=A),
             c.chunk_size, row_starts, row_lens, long_rows)
-        state = state.at[lm, lanes].set(S.astype(state.dtype))
         conv = conv.at[lm, lanes].set(new_tails.astype(conv.dtype))
         return _mamba_out(layer, ys, xs, z, c), state, conv
 

@@ -174,9 +174,10 @@ def test_a_mixed_step_of_prefill_rows_and_decode_rows(params, n):
     """(ii) Two prefill rows (a sequence's first chunk in a lane that holds
     another's stale state, and a second chunk) and three decode rows in
     one flat buffer: each row starts from its own lane's state and leaves
-    its own behind. With n = 2 tokens a "decode" row, five rows are long
-    where the forward expects three (8 rows less 5 lanes): its chunked
-    pass then runs over every row, not over the longest three."""
+    its own behind. With n = 1 the decode rows take the decode step's
+    recurrence over their lanes and the two prompts are gathered; with n =
+    2 tokens a "decode" row, five rows are gathered where the forward
+    expects three (8 rows less 5 lanes): three groups of two."""
     seqs = {lane: sequence(10 + lane, 70) for lane in range(4)}
     fresh = sequence(20, 33)
     want = {lane: reference_logits(params, CFG, s)[0] for lane, s in seqs.items()}
@@ -204,6 +205,40 @@ def test_a_mixed_step_of_prefill_rows_and_decode_rows(params, n):
         2: (seqs[2][40 + n], 40 + n), 3: (seqs[3][61], 61)})
     for lane, t in ((0, 40 + n), (1, 40 + n), (2, 40 + n), (3, 61)):
         assert off(got[lane], want[lane][t]) < TOL
+
+
+def one_token_either_way(params, cfg, prefill, decode, ragged):
+    """(the state store a decode step leaves, the one a mixed step leaves,
+    their logits [3, vocab] each) when both feed lanes 0 to 2 the same
+    token from the same cache; the mixed step packs a first chunk on lane
+    3 beside them (tests/test_nemotron_h_family.py runs its family
+    through this too)."""
+    seqs = {lane: sequence(50 + lane, 41) for lane in range(3)}
+    cache, kv_v = alloc_state_cache(cfg, 48, PAGE, 5, 256, 8)
+    _, cache, kv_v = prefill(params, cache, kv_v, [
+        (lane, seqs[lane][:40], 0, table_of(lane)) for lane in range(3)], 64)
+    by_step, stepped, _ = decode(
+        params, cache, kv_v, {lane: (seqs[lane][40], 40) for lane in range(3)})
+    rows = [(3, sequence(60, 21), 0),
+            *((lane, seqs[lane][40:], 40) for lane in range(3))]
+    by_pack, packed_, _ = ragged(params, *packed(rows, cache, kv_v, 8, 96))
+    return (np.asarray(stepped.state), np.asarray(packed_.state),
+            by_step[:3], np.asarray(by_pack)[1:4])
+
+
+def test_a_mixed_steps_one_token_rows_are_the_decode_steps(params):
+    """A decode step and a mixed step share `lanes_step`: fed the same
+    token, they leave a lane the same state (what differs is how the
+    token's q, k and v were multiplied: a batch of 4 against 96 slots),
+    and the same logits. The mixed step's prompt lane holds a state; the
+    lane nobody packed and the scratch lane hold none."""
+    stepped, packed_, by_step, by_pack = one_token_either_way(
+        params, CFG, prefill, decode, RAGGED)
+    scale = np.abs(stepped[:, :3]).max()
+    assert scale > 0 and np.abs(packed_[:, :3] - stepped[:, :3]).max() < 1e-5 * scale
+    assert off(by_pack, by_step) < 1e-4
+    assert packed_[:, 3].any() and not stepped[:, 3].any()
+    assert not packed_[:, 4:].any()
 
 
 FORWARDS = ("decode_forward", "ragged_forward", "prefill_forward_batched",
@@ -551,7 +586,7 @@ def test_the_mixed_steps_attention_counters_follow_the_packs(params):
     # two of one chunk, each arriving beside the lanes that decode
     prompts = [sequence(40, 20), sequence(41, 33), sequence(42, 21),
                sequence(43, 30)]
-    packs = []
+    packs, going_on = [], []
 
     async def run():
         eng = engine(params)
@@ -563,6 +598,8 @@ def test_the_mixed_steps_attention_counters_follow_the_packs(params):
             def kept(p):
                 if "prime" not in p:
                     packs.append((len(p["toks"]), np.array(p["row_lens"])))
+                    going_on.append(int(((np.array(p["row_lens"]) == 1)
+                                         & (np.array(p["ctx_lens"]) > 0)).sum()))
                 return dev_mixed(p)
 
             eng._dev_mixed = kept
@@ -585,6 +622,14 @@ def test_the_mixed_steps_attention_counters_follow_the_packs(params):
     assert stats["mixed_rows_decode_kernel"] == sum(
         int((lens == 1).sum()) for _, lens in packs)
     assert 0 < stats["mixed_attn_tiles_real"] < stats["mixed_attn_tiles"]
+    # ... and the two roads of the recurrence (ops/row_recurrence.py): a
+    # row of one token that goes on from its lane's state, every other row
+    assert stats["state_rows_in_place"] == sum(going_on)
+    assert stats["state_rows_in_place"] + stats["state_rows_gathered"] == sum(
+        int((lens > 0).sum()) for _, lens in packs)
+    # (the one-token chunk goes on from a state; every first chunk does not)
+    assert stats["mixed_rows_decode_kernel"] == stats["state_rows_in_place"]
+    assert stats["state_rows_gathered"] > 0
 
 
 def test_a_lane_reused_and_a_sequence_resumed_give_a_fresh_engines_tokens(params):

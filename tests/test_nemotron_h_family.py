@@ -129,9 +129,10 @@ def test_a_mixed_step_of_prefill_rows_and_decode_rows(params, n):
     """(ii) Two prefill rows (a sequence's first chunk in a lane that holds
     another's stale state, and a second chunk) and three decode rows in
     one flat buffer: each row starts from its own lane's state and leaves
-    its own behind. With n = 2 tokens a "decode" row, five rows are long
-    where the forward expects three: its chunked pass then runs over every
-    row, not over the longest three."""
+    its own behind. With n = 1 the decode rows take the decode step's
+    recurrence over their lanes and the two prompts are gathered; with n =
+    2 tokens a "decode" row, five rows are gathered where the forward
+    expects three: three groups of two."""
     seqs = {lane: sequence(10 + lane, 70) for lane in range(4)}
     fresh = sequence(20, 33)
     want = {lane: reference_logits(params, CFG, s)[0] for lane, s in seqs.items()}
@@ -172,6 +173,19 @@ def test_a_mixed_step_of_prefill_rows_and_decode_rows(params, n):
         2: (seqs[2][40 + n], 40 + n), 3: (seqs[3][61], 61)})
     for lane, t in ((0, 40 + n), (1, 40 + n), (2, 40 + n), (3, 61)):
         assert off(got[lane], want[lane][t]) < TOL
+
+
+def test_a_mixed_steps_one_token_rows_are_the_decode_steps(params):
+    """A decode step and a mixed step share `lanes_step`: fed the same
+    token, they leave a lane the same state and the same logits (the
+    sibling family's test has the shape of the pack)."""
+    stepped, packed_, by_step, by_pack = hybrid_tests.one_token_either_way(
+        params, CFG, prefill, decode, RAGGED)
+    scale = np.abs(stepped[:, :3]).max()
+    assert scale > 0 and np.abs(packed_[:, :3] - stepped[:, :3]).max() < 1e-5 * scale
+    assert off(by_pack, by_step) < 1e-4
+    assert packed_[:, 3].any() and not stepped[:, 3].any()
+    assert not packed_[:, 4:].any()
 
 
 def test_the_chunked_and_the_step_form_of_the_recurrence_agree():
@@ -362,6 +376,10 @@ def test_the_engine_serves_the_references_tokens_and_says_what_it_routed(params)
     assert stats["routed_rows_emitted"] == len(out[0][1]) + len(out[1][1])
     assert stats["state_lanes_reset"] == 3 and stats["mixed_steps"] > 0
     assert stats["state_bytes"] == 5 * state_bytes_per_lane(CFG)
+    # every row a mixed step packed took one of the recurrence's two roads
+    assert stats["state_rows_in_place"] + stats["state_rows_gathered"] == (
+        stats["mixed_rows_plain"])
+    assert stats["state_rows_in_place"] > stats["state_rows_gathered"] > 0
     assert 0 < stats["step_state_bytes"] < stats["step_min_bytes"]
     assert 0 < stats["step_expert_bytes"] < stats["step_min_bytes"]
     assert 0 < stats["expert_rows_routed"] <= stats["expert_rows_computed"]

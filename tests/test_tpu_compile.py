@@ -514,8 +514,9 @@ def test_the_state_space_familys_steps_compile_at_the_cell_size(
     (k/n tiles of 1,024 and 896: 2,688 is 21 x 128), the one attention layer
     one kernel a decode step and two a mixed step. The decode step updates
     the state in the donated store: its temporaries stay under one layer's
-    state (138 MB at 33 slots); a mixed step's are those of a prefill batch
-    of 8 whatever its 40 rows hold, and the whole fits the chip."""
+    state (138 MB at 33 slots); a mixed step's are those of a group of
+    gathered rows whatever its 40 rows hold (no array of 40 rows' states is
+    in the program: ops/row_recurrence.py), and the whole fits the chip."""
     from dynamo_tpu.models import nemotron_h
 
     sds = _shapes(one_chip)
@@ -546,7 +547,17 @@ def test_the_state_space_familys_steps_compile_at_the_cell_size(
             sds((rows,), i32), sds((rows,), i32), sds((rows,), i32),
             sds((rows,), i32)).compile()
         kernels = 5 * 2 + 2
-    assert compiled.as_text().count("tpu_custom_call") == kernels
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == kernels
+    # a mixed step gathers a prefill batch's states, not its 40 rows': the
+    # one-token rows are stepped over the lanes in the store
+    assert "f32[40,128,64,128]" not in text
+    if program == "mixed_256":
+        # ... and both of the store's updates a layer (the lanes' slice
+        # written back, the gathered rows' scatter) are in place: what a
+        # step moves whole is activations and the convolution's tails,
+        # under 10 MiB each, where a layer's states are 132
+        assert not _hbm_copies(text, 16 * 2**20)
     mem = compiled.memory_analysis()
     need = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert need < HBM_BYTES, f"{program} needs {need / 2**30:.2f} GiB"
@@ -817,7 +828,9 @@ def test_the_hybrid_steps_copy_no_weight(
     indexed twice (by period, then by layer) was 232 MiB of weights in six
     such ops of a decode step and of a block's loop body, and a layer's
     state store sliced before 40 rows were gathered from it six times 66
-    MiB a mixed step beside them (PERF.md, PR 48)."""
+    MiB a mixed step beside them (PERF.md, PR 48). Since PR 52 the mixed
+    step's store takes two updates a layer, the kernel's aliased result
+    and the gathered rows' scatter: both in place, or this finds the copy."""
     from dynamo_tpu.models import hybrid
 
     sds = _shapes(one_chip)
@@ -863,4 +876,10 @@ def test_the_hybrid_steps_copy_no_weight(
     copies = _hbm_copies(text, 8 * 2**20)
     if program == "mixed_256":
         copies = {k: v for k, v in copies.items() if v[1] not in MIXED_STEPS_OWN}
+        # the one-token rows' recurrence is the decode step's kernel over
+        # the lanes (six calls beside the grouped matmuls and the two
+        # attention layers' two kernels), and only a prefill batch's states
+        # are gathered: no array of 40 rows' states anywhere
+        assert text.count("tpu_custom_call") == 6 + 3 * 8 + 2 * 2
+        assert "f32[40,32,128,128]" not in text
     assert not copies, copies
