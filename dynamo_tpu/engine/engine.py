@@ -46,7 +46,7 @@ import numpy as np
 from ..llm.mocker.kv_manager import KvEvent
 from ..llm.protocols import Annotated, LLMEngineOutput, PreprocessedRequest
 from ..llm.tokens import TokenBlockSequence, compute_seq_hashes, salt_hash
-from ..models import exaone_moe, hybrid, llama, moe, nemotron_h
+from ..models import exaone_moe, hybrid, llama, mla_moe, moe, nemotron_h
 from ..models.quant import is_quant
 from ..ops.paged_attention import ragged_tiles
 from ..ops.state_cache import state_bytes_per_lane
@@ -166,12 +166,20 @@ def _kv_shard_div(kv_sharding) -> int:
 #: (the first entry the class is an instance of: a subclass stands before
 #: its base)
 MODEL_FAMILIES = (
+    (mla_moe.MlaMoeConfig, mla_moe),
     (exaone_moe.ExaoneMoeConfig, exaone_moe),
     (nemotron_h.NemotronHConfig, nemotron_h),
     (hybrid.HybridConfig, hybrid),
     (moe.MoeConfig, moe),
     (llama.LlamaConfig, llama),
 )
+
+
+def kv_stores(model_cfg) -> int:
+    """Stores of pages a layer keeps: K and V, or the ONE latent store of a
+    family whose `state_spec()` says `value_store` False."""
+    spec = getattr(model_cfg, "state_spec", None)
+    return 2 if spec is None or spec().value_store else 1
 
 
 def model_family(model_cfg):
@@ -251,7 +259,8 @@ def _auto_num_pages(params, model_cfg, config: EngineConfig,
                 (config.max_num_seqs + 1) * state_bytes_per_lane(model_cfg)
             )
         page_bytes = (
-            2  # K and V
+            # K and V; a latent layer keeps one row a token and no V store
+            kv_stores(model_cfg)
             * kv_layers
             * kv_page_bytes(
                 config.page_size, model_cfg.num_kv_heads,
@@ -607,6 +616,12 @@ class JaxEngine:
         # models/exaone_moe.py; docs/hybrid_models.md)
         self.STATE_FAMILY = getattr(family, "STATE_FAMILY", None)
         self._stateful = self.STATE_FAMILY is not None
+        # ... and of those, the ones whose lanes do keep something beside
+        # the pages (a family of latent layers keeps pages alone, and takes
+        # a StateCache for the leaves its chosen experts are recorded in):
+        # only these are declined the prefix index
+        self._lane_state = (
+            self._stateful and c.state_spec().state_layers > 0)
         # a routed family counts the rows its expert matmuls multiply
         self._counts_expert_rows = hasattr(family, "expert_rows")
         # a family with a recurrence steps a mixed step's one-token rows
@@ -630,7 +645,10 @@ class JaxEngine:
             self.attention_impl = resolved_attention(
                 c.head_dim, c.num_kv_heads, kvq != "none"
             )
-            if self._stateful:
+            if hasattr(family, "attention_impl"):
+                # a family with attention ops of its own names what runs
+                self.attention_impl = family.attention_impl(c)
+            if self._lane_state:
                 # and which a decode step's recurrence takes (a family
                 # with no kernel of its own for it has none to resolve)
                 resolve = getattr(family, "recurrence_impl", None)
@@ -709,7 +727,7 @@ class JaxEngine:
             family.step_work, c,
             weight_bytes=1 if self._quantized
             else jnp.dtype(c.dtype).itemsize,
-            kv_bytes=2 * kv_page_bytes(
+            kv_bytes=kv_stores(c) * kv_page_bytes(
                 config.page_size, c.num_kv_heads, c.head_dim, c.dtype, kvq
             ) / config.page_size,
         )
@@ -900,11 +918,14 @@ class JaxEngine:
         # context — while R_pad x P x 4 B stays within the budget below; the
         # XLA reference gathers P pages a row, so it keeps the pow2 rungs.
         self._mixed_token_buckets = mixed_token_buckets(config)
+        # ... and so does an XLA walk that ends at the longest context of
+        # the call (ops/latent_attention.py), in the split prefill too
+        self._walks_contexts = getattr(family, "ONE_TABLE_WIDTH", False)
         one_width = (
             self.attention_impl["ragged"] == "pallas"
             and self._mixed_row_bucket * (config.max_pages_per_seq + 1) * 4
             <= MIXED_TABLE_SMEM_BYTES
-        )
+        ) or self._walks_contexts
         self._mixed_table_rungs = (
             (config.max_pages_per_seq,) if one_width
             else table_rungs(config.max_pages_per_seq)
@@ -1043,8 +1064,16 @@ class JaxEngine:
         pages (docs/hybrid_models.md)."""
         return self._stateful
 
-    def _refuse_state(self, what: str, why: str):
-        raise ValueError(f"{self.STATE_FAMILY} cannot run {what}: {why}")
+    def _why_refused(self, key: str, why: str) -> str:
+        """`why`, or the family's own words for `key` where it has some
+        (models/mla_moe.WHY_REFUSED: it keeps no state to lose)."""
+        return getattr(
+            model_family(self.model_config), "WHY_REFUSED", {}).get(key, why)
+
+    def _refuse_state(self, what: str, why: str, key: str = ""):
+        raise ValueError(
+            f"{self.STATE_FAMILY} cannot run {what}: "
+            f"{self._why_refused(key, why)}")
 
     def _refuse_what_state_cannot_follow(self, config: EngineConfig, mesh,
                                          multihost: bool):
@@ -1058,33 +1087,37 @@ class JaxEngine:
                 "KVBM offload and onboard (and the migration checkpoints "
                 "that ride its tiers)",
                 "a block's pages come back without the state that stood "
-                "at its end",
+                "at its end", "kvbm",
             )
         if config.spec_mode:
             self._refuse_state(
                 f"speculative decoding (--spec {config.spec_mode})",
                 "a rejected draft cannot be rolled back out of a state",
+                "spec",
             )
         if config.role == "prefill":
             self._refuse_state(
                 "the disaggregated hand-off (--role prefill)",
-                "the pages would leave without the lane's state",
+                "the pages would leave without the lane's state", "disagg",
             )
         if config.quantize or (config.kv_quant or "none") != "none":
             self._refuse_state(
-                "--quantize / --kv-quant", "its leaves have no int8 form yet")
+                "--quantize / --kv-quant", "its leaves have no int8 form yet",
+                "quant")
         if mesh is not None or multihost or max(
                 config.tp_size, config.pp_size, config.sp_size,
                 config.dp_size) > 1:
             self._refuse_state(
                 "over a mesh (tp / pp / sp / dp / multi-host)",
-                "the state store has no sharding",
+                "the state store has no sharding", "mesh",
             )
         logger.info(
-            "%s: the prefix index hands out no cached pages (counter "
-            "state_prefix_hits_declined); KVBM, the disaggregated hand-off, "
-            "migration checkpoints and speculation are refused",
+            "%s: %s; KVBM, the disaggregated hand-off, migration checkpoints "
+            "and speculation are refused",
             self.STATE_FAMILY,
+            "the prefix index hands out no cached pages (counter "
+            "state_prefix_hits_declined)" if self._lane_state
+            else "the prefix index serves its pages",
         )
 
     def _routed_behind(self, call, entry: dict):
@@ -2436,7 +2469,8 @@ class JaxEngine:
                 disagg.get(k) for k in ("return_kv", "kv_pull", "kv_stream")):
             yield Annotated.from_error(
                 f"{self.STATE_FAMILY} cannot run the disaggregated hand-off: "
-                "the pages would leave without the lane's state"
+                + self._why_refused(
+                    "disagg", "the pages would leave without the lane's state")
             ).to_dict()
             return
         slot = self._new_slot(req, context)
@@ -2471,7 +2505,8 @@ class JaxEngine:
         if self._stateful:
             return None, (
                 f"{self.STATE_FAMILY} cannot run the disaggregated hand-off: "
-                "injected pages bring no state for the lane"
+                + self._why_refused(
+                    "disagg", "injected pages bring no state for the lane")
             )
         self._morph_guard()
         self.start()
@@ -3000,11 +3035,14 @@ class JaxEngine:
         # a stateful family takes no cached pages: nobody kept the state
         # that stood at their end (counted below, once admission is certain)
         declined = 0
-        if self._stateful and cfg.enable_prefix_caching:
+        if self._lane_state and cfg.enable_prefix_caching:
             declined = len(self.allocator.cached_prefix(hashes))
+        # ... and a request that asked for the experts chosen at EVERY input
+        # position (`routed_experts`) computes every position
         cached_pages = (
             self.allocator.acquire_cached(hashes)
-            if cfg.enable_prefix_caching and not self._stateful else []
+            if cfg.enable_prefix_caching and not self._lane_state
+            and not slot.want_routed else []
         )
         n_cached = len(cached_pages)
         # KVBM: probe G2/G3 for the hashes the device cache missed; tier hits
@@ -4113,9 +4151,10 @@ class JaxEngine:
         whole-page-aligned progress can splice; fresh slots only (resume/
         disagg/onboard slots carry their own page provenance)."""
         cfg = self.config
-        if not cfg.enable_prefix_caching or self._stateful:
+        if not cfg.enable_prefix_caching or self._lane_state or s.want_routed:
             return  # caching disabled must disable ALL reuse paths (a
-            # stateful family reuses no page: _try_admit)
+            # family with a state of a lane reuses no page, nor does a
+            # request that wants every position's experts: _try_admit)
         if s.generated or s.resume_token is not None or s.onboard is not None:
             return
         n_known = len(s.committed_hashes)
@@ -4252,6 +4291,10 @@ class JaxEngine:
                 pages_needed = (s.prefill_pos + chunk + cfg.page_size - 1) // cfg.page_size
                 max_pages_needed = max(max_pages_needed, pages_needed)
             ctx_pages = min(_next_pow2(max_pages_needed), cfg.max_pages_per_seq)
+            if self._walks_contexts:
+                # the family's attention ends at the longest context of the
+                # call, whatever the table's width: one width, one program
+                ctx_pages = cfg.max_pages_per_seq
             P = ctx_pages + 1
             pad_pos = P * cfg.page_size - 1
 
@@ -6417,6 +6460,7 @@ def _resolve_model(name: str) -> llama.LlamaConfig:
         "tiny-hybrid": hybrid.HybridConfig.tiny_hybrid,
         "tiny-nemotron-h": nemotron_h.NemotronHConfig.tiny_nemotron_h,
         "tiny-exaone-moe": exaone_moe.ExaoneMoeConfig.tiny_exaone_moe,
+        "tiny-mla-moe": mla_moe.MlaMoeConfig.tiny_mla_moe,
         "tiny": llama.LlamaConfig.tiny,
         "llama3-3b": llama.LlamaConfig.llama3_2_3b,
         "llama3-8b": llama.LlamaConfig.llama3_8b,

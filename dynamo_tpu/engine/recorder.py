@@ -26,7 +26,9 @@ One table for the engine's loop, exported through `JaxEngine.stats()`:
   them what the window layers' K and V cost and would cost without the
   window, `step_window_kv_bytes` and `step_window_kv_whole_bytes` (each
   exported once it is not 0: models/hybrid.py, models/nemotron_h.py,
-  models/exaone_moe.py).
+  models/exaone_moe.py). A family may instead end its count with a dict
+  that names its shares (models/mla_moe.py: `step_latent_kv_bytes`,
+  `step_latent_kv_expanded_bytes`, `step_expert_bytes`).
 * the waits ahead of a first token: `req_admitted`, `req_queue_wait_s`,
   `req_first_tokens`, `req_admit_to_first_s`.
 * the device calls' host clock by tag (`dispatch_<tag>_count`, `_s`:
@@ -132,6 +134,10 @@ class Recorder:
         self.model_flops = 0
         self.min_bytes = 0
         self.state_bytes = 0  # of min_bytes, a recurrent state's (hybrid)
+        # of min_bytes, a latent cache's rows, and what the same positions
+        # would cost as heads of K and V (mla_moe)
+        self.latent_kv_bytes = 0
+        self.latent_kv_expanded_bytes = 0
         self.expert_bytes = 0  # of min_bytes, the held experts' (nemotron_h)
         # K and V bytes the window layers read, and would at the whole
         # context (exaone_moe)
@@ -176,9 +182,17 @@ class Recorder:
         the state's (models/hybrid.step_work), may say fourth how many
         are the held experts' (models/nemotron_h.step_work), and fifth and
         sixth the window layers' K and V bytes read and what they would be
-        at the whole context (models/exaone_moe.step_work)."""
+        at the whole context (models/exaone_moe.step_work); or, last, a
+        dict that names its shares (models/mla_moe.step_work)."""
         entry["step_kind"] = kind
         entry["t_dispatch"] = time.perf_counter()
+        if isinstance(work[-1], dict):
+            # a family that names its shares of the bytes (models/mla_moe.
+            # step_work) in place of counting on their order
+            *work, named = work
+            self.expert_bytes += named["expert_bytes"]
+            self.latent_kv_bytes += named["latent_kv_bytes"]
+            self.latent_kv_expanded_bytes += named["latent_kv_expanded_bytes"]
         self.model_flops += work[0]
         self.min_bytes += work[1]
         if len(work) > 2:
@@ -261,6 +275,10 @@ class Recorder:
             out["step_state_bytes"] = float(self.state_bytes)
         if self.expert_bytes:
             out["step_expert_bytes"] = float(self.expert_bytes)
+        if self.latent_kv_expanded_bytes:
+            out["step_latent_kv_bytes"] = float(self.latent_kv_bytes)
+            out["step_latent_kv_expanded_bytes"] = float(
+                self.latent_kv_expanded_bytes)
         if self.window_kv_whole_bytes:
             out["step_window_kv_bytes"] = float(self.window_kv_bytes)
             out["step_window_kv_whole_bytes"] = float(

@@ -19,6 +19,24 @@ of the pool is ever sliced or reshaped. Readers that want heads apart get
 the `[.., KH, D]` view of the PAGES they gathered (`gather_dequant`,
 `extract_pages`), never of the pool.
 
+A family of LATENT layers (models/mla_moe.py, docs/latent_cache.md) keeps
+ONE store in the same layout and no V store: a token's row is the normed
+latent and the rotated shared key side by side, `[L, pages, rows, width]`,
+which is `KH` 1 and `D` `width` to every function here (`alloc_kv_store`,
+`kv_write`, `gather_dequant`, `extract_pages` and `inject_pages` take it
+as they take a K store). `width` is `latent_row_width(rank + rope)`,
+the next multiple of 128 lanes: 640 for 512 + 64, of which the last 64 are
+zeros, and the counters count 640 (models/mla_moe.step_work). A row of 576
+lanes is no saving on a TPU: arrays lie there in tiles of 128 lanes, so a
+row-major `[.., 64, 576]` takes 640 lanes a row all the same, and the
+compiler's own layout for that shape avoids the padding by making the PAGE
+axis the lane axis (`{1,3,2,0}`), from which every program copies the whole
+pool into row-major order and back (compiled for a described v5e: two
+pool-sized copies and a pool of temporaries a step, PERF.md section 6,
+PR 54). In `kv_v`'s place rides `no_value_store`, a store of one page of
+one value. A quantized mode is refused: one scale a page would round the
+latent and the rotated key together.
+
 Representation — `QuantKV`, a registered pytree replacing the raw
 [L, pages, page_size, KH*D] kv_k/kv_v arrays:
 
@@ -183,6 +201,23 @@ def alloc_kv_store(num_layers: int, num_pages: int, page_size: int,
     )
     s = jnp.zeros((num_layers, num_pages, num_kv_heads), jnp.float32)
     return QuantKV(q, s, bits, page_size)
+
+
+LATENT_ROW_LANES = 128
+
+
+def latent_row_width(values: int) -> int:
+    """Lanes of the row a latent layer keeps a token for `values` values
+    (kv_lora_rank + rope): the next multiple of 128 (the module's text
+    says why), the lanes past `values` zeros."""
+    return -(-values // LATENT_ROW_LANES) * LATENT_ROW_LANES
+
+
+def no_value_store(num_layers: int, page_size: int, dtype):
+    """What rides in `kv_v`'s place for a family that keeps no V store
+    (every program takes and returns a `kv_v`): one page of one value a
+    layer."""
+    return alloc_kv_store(num_layers, 1, page_size, 1, 1, dtype, "none")
 
 
 def kv_page_size(store) -> int:

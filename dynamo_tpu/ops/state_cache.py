@@ -34,6 +34,13 @@ V ring, both `[window layers, lanes + 1, W, KH*D]` in the model's dtype:
 "the last n rows of a lane, carried across chunks" with n = the window;
 ops/window_attention.py).
 
+A family of latent layers (models/mla_moe.py) keeps NO state of a lane
+(`state_layers` 0: both leaves are empty) and ONE store of pages
+(`value_store` False: `pages` is the latent store, and the V pool that rides
+beside the cache is one page of one value): pages are all there is to a
+sequence, so the engine serves them from the prefix index as it serves K
+and V pages. It takes a StateCache for the two leaves below.
+
 The two `routed_*` leaves are what the request plane's `routed_experts`
 annotation is answered from (benchmark/README.md, "The wire contract");
 the host copies them out only for a dispatch that holds such a request.
@@ -119,6 +126,9 @@ class StateSpec:
     conv_shape: Tuple[int, int]  # (taps - 1, channels), in the model's dtype
     state_dtype: Any
     experts_per_token: int
+    #: False: the family keeps ONE store a layer and no V store (a latent
+    #: row, models/mla_moe.py); `pages` is sized by `head_dim` alone
+    value_store: bool = True
 
 
 def state_bytes_per_lane(c) -> int:
@@ -139,14 +149,16 @@ def alloc_state_cache(c, num_pages: int, page_size: int, max_seqs: int,
     zeroed state store of `max_seqs` lanes and one scratch slot.
     `max_tokens`: the most token slots one prefill batch or mixed step
     packs; `row_slots`: the most rows (`max_seqs` where smaller)."""
-    from .kv_quant import alloc_kv_store
+    from .kv_quant import alloc_kv_store, no_value_store
 
     spec = c.state_spec()
     pools = [
         alloc_kv_store(spec.attention_layers, num_pages, page_size,
                        c.num_kv_heads, c.head_dim, c.dtype, "none")
-        for _ in range(2)
+        for _ in range(2 if spec.value_store else 1)
     ]
+    if not spec.value_store:
+        pools.append(no_value_store(spec.attention_layers, page_size, c.dtype))
     K = spec.experts_per_token
     cache = StateCache(
         pages=pools[0],

@@ -635,6 +635,68 @@ def test_the_window_familys_steps_compile_at_the_published_widths(
         f"{mem.temp_size_in_bytes / 2**20:.0f} MiB of temporaries")
 
 
+@pytest.mark.parametrize("program", ("block_of_8", "mixed_256", "mixed_2048"))
+def test_the_latent_familys_steps_keep_the_pool_where_it_lies(
+    program, one_chip, no_persistent_cache, tpu_gate
+):
+    """models/mla_moe.py at its cell's configuration (8 layers at the
+    published widths, 64 experts, the whole vocabulary, 10.3 GB of weights)
+    and its auto pool of 5,300 latent pages of 320 a lane, for the described
+    v5e: a decode block's scan of 8 decode steps (32 lanes, every lane
+    absorbed) and the smallest and the largest member of the mixed_step
+    family (40 rows). The only kernels are the sparse layers' three grouped
+    matmuls; both attention walks are XLA loops over blocks of gathered
+    pages, so what is pinned is that the pool stays where it lies: the
+    temporaries stay far under the pool (3.5 GB; a row of 576 lanes in
+    place of 640 made the compiler copy the whole pool into another layout
+    and back, a pool of temporaries a step: ops/kv_quant.py), and the whole
+    fits the chip."""
+    from dynamo_tpu.models import mla_moe
+
+    sds = _shapes(one_chip)
+    cfg, params, cache, kv_v = _stateful_cell(
+        sds, mla_moe, "glm-4.7-flash-d8", 5301)
+    assert cache.pages.shape == (8, 5301, PAGE, 640)
+    assert cache.state.size == 0 and kv_v.shape == (8, 1, PAGE, 1)
+    i32 = jnp.int32
+    if program == "block_of_8":
+        def block(params, tokens, positions, kv_k, kv_v, tables, seq_lens):
+            def body(carry, _):
+                tokens, positions, kv_k, kv_v, seq_lens = carry
+                logits, kv_k, kv_v = mla_moe.decode_forward(
+                    params, cfg, tokens, positions, kv_k, kv_v, tables, seq_lens)
+                return (logits.argmax(-1).astype(i32), positions + 1, kv_k,
+                        kv_v, seq_lens + 1), None
+
+            return jax.lax.scan(
+                body, (tokens, positions, kv_k, kv_v, seq_lens), None, 8)[0]
+
+        compiled = jax.jit(block, donate_argnums=(3, 4)).lower(
+            params, sds((32,), i32), sds((32,), i32), cache, kv_v,
+            sds((32, 321), i32), sds((32,), i32)).compile()
+    else:
+        tokens, rows = int(program.split("_")[1]), 40
+
+        def step(params, tokens, positions, row_ids, kv_k, kv_v, tables,
+                 row_starts, row_lens, ctx_lens, last_flat):
+            return mla_moe.ragged_forward(
+                params, cfg, tokens, positions, row_ids, kv_k, kv_v, tables,
+                row_starts, row_lens, ctx_lens, last_flat, long_rows=8)
+
+        compiled = jax.jit(step, donate_argnums=(4, 5)).lower(
+            params, sds((tokens,), i32), sds((tokens,), i32),
+            sds((tokens,), i32), cache, kv_v, sds((rows, 321), i32),
+            sds((rows,), i32), sds((rows,), i32), sds((rows,), i32),
+            sds((rows,), i32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 7 * 3
+    mem = compiled.memory_analysis()
+    need = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert need < HBM_BYTES, f"{program} needs {need / 2**30:.2f} GiB"
+    pool = 8 * 5301 * PAGE * 640 * 2
+    assert mem.temp_size_in_bytes < pool / 8, (
+        f"{mem.temp_size_in_bytes / 2**20:.0f} MiB of temporaries")
+
+
 def test_the_piped_mixed_steps_carry_programs_compile_at_the_cell_size(
     one_chip, no_persistent_cache
 ):
