@@ -627,6 +627,10 @@ class JaxEngine:
         # a family with a recurrence steps a mixed step's one-token rows
         # over the lanes in place (its `lanes_step`) and counts them
         self._steps_lanes = hasattr(family, "lanes_step")
+        # a latent family's chunks attend absorbed up to so many tokens and
+        # expanded past them (the family's own rule, read here to count)
+        limit_of = getattr(family, "absorbed_row_limit", None)
+        self._absorbed_row_limit = limit_of(c) if limit_of else None
         if self._stateful:
             self._refuse_what_state_cannot_follow(config, mesh, multihost)
 
@@ -977,6 +981,10 @@ class JaxEngine:
         # row, gathered out of it and scattered back
         self.state_rows_in_place = 0
         self.state_rows_gathered = 0
+        # ... and a latent family's chunks of more than one token by the
+        # side of the score W_kvb stood on (models/mla_moe.rows_attention)
+        self.mla_rows_absorbed_tokens = 0
+        self.mla_rows_expanded_tokens = 0
         self._last_prefill_shape = None  # (padded, real) of the latest dispatch
         self._last_decode_shape = None
         # set by _dispatch_mixed when a pack that needs host-authoritative
@@ -2787,6 +2795,9 @@ class JaxEngine:
         if self._steps_lanes:
             out["state_rows_in_place"] = self.state_rows_in_place
             out["state_rows_gathered"] = self.state_rows_gathered
+        if self._absorbed_row_limit:
+            out["mla_rows_absorbed_tokens"] = self.mla_rows_absorbed_tokens
+            out["mla_rows_expanded_tokens"] = self.mla_rows_expanded_tokens
         # what the mixed steps' dense layers multiplied: real tokens, and
         # the slots of the token buckets they ran in
         out["mixed_real_tokens"] = self.mixed_real_tokens
@@ -5468,6 +5479,11 @@ class JaxEngine:
                 1 for _, ch, r in meta if ch == 1 and ctx_lens[r] > 0)
             self.state_rows_in_place += n_rows_decode + going_on
             self.state_rows_gathered += len(meta) - going_on
+        if self._absorbed_row_limit:
+            chunks = [ch for _, ch, _ in meta if ch > 1]
+            short = sum(ch for ch in chunks if ch <= self._absorbed_row_limit)
+            self.mla_rows_absorbed_tokens += short
+            self.mla_rows_expanded_tokens += sum(chunks) - short
         self._count_expert_rows(N_pad, real)
         self._step_counter += 1
         return True

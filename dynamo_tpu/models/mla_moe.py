@@ -21,11 +21,19 @@ attn(rmsnorm(x))` then `x + ffn(rmsnorm(x))`.
       models/exaone_moe.py's own (sigmoid scores, the k largest of score +
       choice bias, weights the scores over their sum times a factor).
 
-Which path a row takes is read off the row, not a switch: a row of ONE token
-(a decode lane, a mixed step's decode row, a prompt's one-token chunk)
+Which path a row takes is read off the row's COST, not a switch (three kinds
+of row; ops/latent_attention.py has the walks and the rule): a row of ONE
+token (a decode lane, a mixed step's decode row, a prompt's one-token chunk)
 attends absorbed, in the latent space, where a cached row is read as it
-lies; a row of more tokens (a prefill chunk) expands its context's cached
-latents through W_kvb and attends at `heads` heads (ops/latent_attention.py).
+lies, a lane of the lanes' walk; a SHORT row of 2 to `absorbed_row_limit(c)`
+tokens (a request's tail behind a cached prefix, a split prompt's last
+chunk) attends absorbed too, its tokens folded into the head axis over the
+row's own pages, read once; a row of more tokens (a prefill chunk) expands
+its context's cached latents through W_kvb and attends at `heads` heads. The
+limit is where the two forms cost the same multiply-adds: expanding costs
+`rank x heads x (nope + v)` a CACHED position whatever the chunk holds, so
+it pays only for a chunk long enough to share it (358 tokens at the
+published widths). No flag, environment variable or field chooses.
 
 The forwards keep models/llama.py's signatures. `kv_k` is the latent store
 `[L, pages, rows, width]` (ops/kv_quant.latent_row_width: the dataclass's
@@ -46,7 +54,12 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.kv_quant import kv_layer, kv_page_size, kv_write, latent_row_width
-from ..ops.latent_attention import absorbed_attention, expanded_attention
+from ..ops.latent_attention import absorbed_row_limit as row_limit_of_widths
+from ..ops.latent_attention import (
+    absorbed_attention,
+    absorbed_rows_attention,
+    expanded_attention,
+)
 from ..ops.paged_attention import rows_at
 from ..ops.state_cache import StateCache, StateSpec
 from . import moe
@@ -283,21 +296,38 @@ def absorbed(layer, q, latent, page_tables, seq_lens, c: MlaMoeConfig):
                           preferred_element_type=f32).astype(c.dtype)
 
 
+def absorbed_row_limit(c: MlaMoeConfig) -> int:
+    """The most tokens a row may hold and still attend absorbed, from the
+    configuration's widths by ops/latent_attention's rule (the engine's
+    counters read it here: mla_rows_absorbed_tokens)."""
+    return row_limit_of_widths(
+        c.kv_lora_rank, c.num_heads, c.qk_nope_head_dim, c.qk_rope_head_dim,
+        c.v_head_dim, c.head_dim)
+
+
 def rows_attention(layer, q, latent, page_tables, row_starts, row_lens,
                    ctx_lens, c: MlaMoeConfig):
     """q [M, heads, nope + rope] on a flat axis of rows -> [M, heads,
-    v_head_dim]: rows of one token absorbed, as lanes (length 0 for every
-    other row: an empty lane reads nothing), rows of more expanded."""
+    v_head_dim], each row by what it costs: rows of one token absorbed, as
+    lanes (length 0 for every other row: an empty lane reads nothing); rows
+    of 2 to `absorbed_row_limit(c)` tokens absorbed, a row at a time; rows
+    of more expanded (a buffer too short to hold one has no such walk)."""
     M = q.shape[0]
     one = row_lens == 1
     slots = jnp.where(one, row_starts, M).astype(jnp.int32)
     lanes = absorbed(layer, rows_at(q, slots), latent, page_tables,
                      jnp.where(one, ctx_lens + 1, 0), c)
-    with jax.named_scope("mla_expand"):
-        out = expanded_attention(
-            q, latent, layer["wkv_b"], page_tables, row_starts, row_lens,
+    limit = absorbed_row_limit(c)
+    rows = (q, latent, layer["wkv_b"], page_tables, row_starts, row_lens,
             ctx_lens, c.kv_lora_rank, c.qk_nope_head_dim,
             c.qk_head_dim ** -0.5)
+    if M > limit:
+        with jax.named_scope("mla_expand"):
+            out = expanded_attention(*rows, longer_than=limit)
+    else:
+        out = jnp.zeros((M, c.num_heads, c.v_head_dim), q.dtype)
+    with jax.named_scope("mla_absorb_rows"):
+        out = absorbed_rows_attention(*rows, upto=limit, out=out)
     return out.at[slots].set(lanes, mode="drop")
 
 
@@ -402,7 +432,7 @@ def _flat_rows(params, c: MlaMoeConfig, kv_k, kv_v, x, positions, phys, offs,
     """x [M, H] on a flat axis that rows share (a mixed step's buffer, a
     prefill batch's chunks laid end to end): every row's latents are
     written (a slot that is not `valid` writes to the scratch page), then
-    its one-token rows attend absorbed and its chunks expanded. -> (logits
+    each row attends by its length (`rows_attention`). -> (logits
     of the slots `last` [R, vocab], kv_k, kv_v)."""
     pages, cache = _pool(kv_k)
     phys = jnp.where(valid, phys, 0)
@@ -441,8 +471,8 @@ def ragged_forward(
     long_rows: Optional[int] = None,
 ):
     """The mixed step's forward over a compact flat buffer (models/llama.py:
-    ragged_forward's contract): every row's latents are written, then its
-    one-token rows attend absorbed and its chunks expanded. Returns (logits
+    ragged_forward's contract): every row's latents are written, then each
+    row attends by its length (`rows_attention`). Returns (logits
     of each row's last token [R, vocab], kv_k, kv_v)."""
     _refuse(lora)
     c = config
@@ -523,10 +553,12 @@ def prefill_forward(
 
 def attention_impl(c: MlaMoeConfig) -> Dict[str, str]:
     """What ran, by the engine's three attention surfaces (stats()
-    ["attention_impl"]): both walks are XLA over gathered blocks of pages."""
+    ["attention_impl"]): every walk is XLA over gathered blocks of pages,
+    and a prefill batch's chunks and a mixed step's rows each take the one
+    their length gives them (`rows_attention`)."""
     return {
         "decode": "xla-latent-absorbed",
-        "prefill": "xla-latent-expanded",
+        "prefill": "xla-latent-by-row",
         "ragged": "xla-latent-by-row",
     }
 

@@ -6,20 +6,42 @@ side, `[c | k_r | 0...]`, in a row of `width` lanes, the next multiple of
 128 (ops/kv_quant.latent_row_width: 640 for 512 + 64; the module's text
 there says what a row of 576 lanes costs on a TPU): a pool `[L, pages,
 rows, width]`, which is ops/kv_quant's lane-dense layout with one "head" of
-`width` and no V store. Two walks over it, both plain XLA that streams a
-bounded block of pages a step and keeps a running softmax, so neither holds
-a context-sized temporary and neither cares how wide the page table is (a
-walk ends at the longest context of the call, not at the table's end):
+`width` and no V store. Three walks over it, all plain XLA that streams a
+bounded block of pages a step and keeps a running softmax, so none holds a
+context-sized temporary and none cares how wide the page table is (a walk
+ends at the longest context of what it serves, not at the table's end).
+Which side of the score `W_kvb` stands on is read off a row's COST:
 
-  * `absorbed_attention`: one query token a row, in the LATENT space. The
-    query arrives with `W_kvb`'s key half folded in (`q~ = q_n W^K`, `rank`
-    wide, beside `q_r`), so a cached row is read as it lies: the score is
-    `q~ . c + q_r . k_r`, the value is `c`. An MQA of group `heads`: the
-    bytes of a context are read once for all heads.
-  * `expanded_attention`: rows of many query tokens, in the EXPANDED space.
-    A block of the row's cached latents goes through `W_kvb` (a head's
-    `nope` key values and `vdim` values), and the row's tokens attend at
-    `heads` heads of `nope + rope` / `vdim`, causally, a row at a time.
+  * `absorbed_attention`: one query token a row, in the LATENT space, every
+    row a lane of one walk. The query arrives with `W_kvb`'s key half folded
+    in (`q~ = q_n W^K`, `rank` wide, beside `q_r`), so a cached row is read
+    as it lies: the score is `q~ . c + q_r . k_r`, the value is `c`. An MQA
+    of group `heads`: the bytes of a context are read once for all heads.
+  * `absorbed_rows_attention`: a SHORT row of `1 < n <= T*` tokens (a
+    request's tail behind a cached prefix, a split prompt's last chunk), in
+    the latent space too, a row at a time over the row's OWN pages up to its
+    own last position. The row's tokens are folded into the head axis: a
+    tile of them gives `[tile x heads, width]` query rows against one
+    gathered block, each token under its own causal limit, and `u W^V` on
+    the way out. An MQA of group `n x heads`: the context's bytes are read
+    once for all the row's tokens and heads.
+  * `expanded_attention`: a row of MORE than `T*` tokens, in the EXPANDED
+    space. A block of the row's cached latents goes through `W_kvb` (a
+    head's `nope` key values and `vdim` values), and the row's tokens attend
+    at `heads` heads of `nope + rope` / `vdim`, causally, a row at a time.
+
+`T*` is `absorbed_row_limit`: a CACHED position costs the expanded form
+`rank x heads x (nope + vdim)` multiply-adds whatever the row holds, and a
+cached position and QUERY token costs it `heads x (nope + rope + vdim)`
+where the absorbed form pays `heads x (width + rank)`; the two cross at
+`rank x (nope + vdim) / (width + rank - nope - rope - vdim)` query tokens,
+whatever the context's length (358 at 512 / 192 / 64 / 256 in a row of 640).
+
+Two forms of the short row's walk that look right and are not: its tokens as
+so many more lanes of `absorbed_attention` (every lane gathers its own copy
+of the context: 13 copies of 21 MB a layer for a tail of 13 behind 16k), and
+a token axis on every lane of the lanes' walk (lanes x tile times the flops
+for one row's sake).
 
 No Pallas kernel reads the latent row yet (the decode kernel's values are
 as wide as its keys, and the prefill kernels read K and V of equal heads);
@@ -27,6 +49,8 @@ ROADMAP.md M4 has what one needs.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -41,6 +65,29 @@ ABSORBED_PAGES = 16
 EXPANDED_POSITIONS = 1024
 #: query tokens of one row that the expanded walk holds at a time
 EXPANDED_QUERIES = 1024
+#: query tokens of one SHORT row that its absorbed walk holds at a time, each
+#: at every head: `ABSORBED_ROW_QUERIES x heads` query rows a gathered block.
+#: On a v5e, one row behind 16,384 positions at the published widths, a layer
+#: (PERF.md section 5, PR 55): tiles of 16 / 32 / 64 take 0.39 / 0.43 / 0.59
+#: ms at 13 tokens and 3.90 / 2.67 / 2.48 at 352, where expanding takes 1.97
+#: and 2.95: at 32 the chip's crossing is not under the rule's 358
+ABSORBED_ROW_QUERIES = 32
+
+
+def absorbed_row_limit(rank: int, heads: int, nope: int, rope: int, vdim: int,
+                       width: int) -> int:
+    """`T*`, the most tokens a row may hold and still attend absorbed: the
+    query tokens at which the two forms cost the same multiply-adds a cached
+    position (the module's text), rounded down. Expanded: `rank x heads x
+    (nope + vdim)` for the position + `heads x (nope + rope + vdim)` a
+    query token. Absorbed: `heads x (width + rank)` a query token, the
+    row's zeros among them. Widths whose absorbed form is the cheaper one a
+    query token have no crossing: every row attends absorbed."""
+    a_token = heads * (width + rank)
+    e_token = heads * (nope + rope + vdim)
+    if a_token <= e_token:
+        return 2**31 - 1
+    return rank * heads * (nope + vdim) // (a_token - e_token)
 
 
 def _blocked_tables(page_tables, pages: int):
@@ -98,32 +145,29 @@ def absorbed_attention(
     return (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
 
 
-def expanded_attention(
-    q: jax.Array,  # [M, H, nope + rope] on a flat axis that rows share
-    latent: KVLayer,
-    w_kvb: jax.Array,  # [rank, H * (nope + vdim)]
-    page_tables: jax.Array,  # [R, max_pages]
-    row_starts: jax.Array,  # [R] slot of each row's first token
-    row_lens: jax.Array,  # [R] the row's tokens; rows of 0 or 1 are skipped
-    ctx_lens: jax.Array,  # [R] positions of the row's sequence before it
-    rank: int,
-    nope: int,
-    scale: float,
-) -> jax.Array:
-    """Causal attention of every row of MORE than one token over its own
-    pages (its history and itself: the row's latents are written already),
-    expanded through `w_kvb` a block at a time -> [M, H, vdim]; slots of
-    the other rows return zeros."""
-    pool, li = latent
-    M, H, D = q.shape
-    W, rope = pool.shape[3], D - nope
-    vdim = w_kvb.shape[1] // H - nope
-    Tq = min(M, EXPANDED_QUERIES)
-    tables, pb = _blocked_tables(
-        page_tables, max(EXPANDED_POSITIONS // pool.shape[2], 1))
-    S = pb * pool.shape[2]
-    long = row_lens > 1
-    order = jnp.argsort(~long, stable=True)  # the rows to serve come first
+def _one_function_a_program(*static):
+    """jax.jit with the keywords `static`: a program calls a by-row walk
+    once a layer, and as a jitted function of the layer's index (data, with
+    the pool) all its layers share ONE traced and lowered function, which
+    the compiler inlines. A start pays for a program's text before its
+    cache is asked (on a v5e at the published widths, a mixed step of 2,048
+    slots: 4.2 s from the cache with both walks written out a layer, 3.7 s
+    so, 3.4 s before there was a second walk; to compile, 42 / 39 / 36 s:
+    PERF.md section 6, PR 55). The block and tile sizes are this module's
+    constants where a caller reads them, static arguments in here."""
+    return functools.partial(jax.jit, static_argnames=static)
+
+
+def _by_row(q, out, serve, Tq, tables, row_starts, row_lens, ctx_lens,
+            attend):
+    """`out` [M, H, vdim] with the slots of the rows that `serve` [R] marks
+    filled: a walk by row (as many trips as it marks: none, and `out` comes
+    back as it went), then by tile of `Tq` of the row's tokens, `attend(qt,
+    table, pos_q, real, last) -> [Tq, H, vdim]` for a tile's queries
+    `qt` [Tq, H, D] at positions `pos_q` (the `real` ones the row's own)
+    over the row's blocked `table` up to position `last`."""
+    M = q.shape[0]
+    order = jnp.argsort(~serve, stable=True)  # the rows to serve come first
     qp = jnp.pad(q, ((0, Tq), (0, 0), (0, 0)))
     steps = jnp.arange(Tq)
 
@@ -135,35 +179,144 @@ def expanded_attention(
         def tile(t, out):  # the row's tokens t * Tq ...
             at = start + t * Tq
             qt = jax.lax.dynamic_slice_in_dim(qp, at, Tq, axis=0)
-            pos_q = ctx + t * Tq + steps
             real = t * Tq + steps < n
             last = jnp.minimum(ctx + (t + 1) * Tq, ctx + n)  # keys a tile sees
-
-            def block(j, carry):
-                tb = jax.lax.dynamic_slice_in_dim(table, j * pb, pb)
-                rows = pool[li, tb].reshape(S, W)
-                kv = jnp.dot(rows[:, :rank], w_kvb).astype(q.dtype)
-                kv = kv.reshape(S, H, nope + vdim)
-                s = jnp.einsum("thn,shn->hts", qt[..., :nope], kv[..., :nope],
-                               preferred_element_type=f32)
-                s += jnp.einsum("thr,sr->hts", qt[..., nope:],
-                                rows[:, rank:rank + rope],
-                                preferred_element_type=f32)
-                pos_k = j * S + jnp.arange(S)
-                mask = (pos_k[None, :] <= pos_q[:, None]) & real[:, None]
-                return _online(carry, s * scale, mask[None], kv[..., nope:],
-                               "hts,shv->htv")
-
-            init = (jnp.full((H, Tq), NEG_INF, f32), jnp.zeros((H, Tq), f32),
-                    jnp.zeros((H, Tq, vdim), f32))
-            _, l, acc = jax.lax.fori_loop(0, -(-last // S), block, init)
-            o = (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
-            o = jnp.moveaxis(o, 0, 1)  # [Tq, H, vdim]
+            o = attend(qt, table, ctx + t * Tq + steps, real, last)
             old = jax.lax.dynamic_slice_in_dim(out, at, Tq, axis=0)
             return jax.lax.dynamic_update_slice_in_dim(
                 out, jnp.where(real[:, None, None], o, old), at, axis=0)
 
         return jax.lax.fori_loop(0, -(-n // Tq), tile, out)
 
-    out = jnp.zeros((M + Tq, H, vdim), q.dtype)
-    return jax.lax.fori_loop(0, long.sum(), row, out)[:M]
+    out = jnp.pad(out, ((0, Tq), (0, 0), (0, 0)))
+    return jax.lax.fori_loop(0, serve.sum(), row, out)[:M]
+
+
+def expanded_attention(
+    q: jax.Array,  # [M, H, nope + rope] on a flat axis that rows share
+    latent: KVLayer,
+    w_kvb: jax.Array,  # [rank, H * (nope + vdim)]
+    page_tables: jax.Array,  # [R, max_pages]
+    row_starts: jax.Array,  # [R] slot of each row's first token
+    row_lens: jax.Array,  # [R] the row's tokens
+    ctx_lens: jax.Array,  # [R] positions of the row's sequence before it
+    rank: int,
+    nope: int,
+    scale: float,
+    longer_than: int = 1,  # rows of this many tokens and fewer are skipped
+) -> jax.Array:
+    """Causal attention of every row of MORE than `longer_than` tokens over
+    its own pages (its history and itself: the row's latents are written
+    already), expanded through `w_kvb` a block at a time -> [M, H, vdim];
+    slots of the other rows return zeros."""
+    return _expanded_rows(
+        q, *latent, w_kvb, page_tables, row_starts, row_lens, ctx_lens,
+        rank=rank, nope=nope, scale=scale, longer_than=longer_than,
+        positions=EXPANDED_POSITIONS, queries=EXPANDED_QUERIES)
+
+
+@_one_function_a_program(
+    "rank", "nope", "scale", "longer_than", "positions", "queries")
+def _expanded_rows(q, pool, li, w_kvb, page_tables, row_starts, row_lens,
+                   ctx_lens, *, rank, nope, scale, longer_than, positions,
+                   queries):
+    M, H, D = q.shape
+    W, rope = pool.shape[3], D - nope
+    vdim = w_kvb.shape[1] // H - nope
+    Tq = min(M, queries)
+    tables, pb = _blocked_tables(
+        page_tables, max(positions // pool.shape[2], 1))
+    S = pb * pool.shape[2]
+
+    def attend(qt, table, pos_q, real, last):
+        def block(j, carry):
+            tb = jax.lax.dynamic_slice_in_dim(table, j * pb, pb)
+            rows = pool[li, tb].reshape(S, W)
+            kv = jnp.dot(rows[:, :rank], w_kvb).astype(q.dtype)
+            kv = kv.reshape(S, H, nope + vdim)
+            s = jnp.einsum("thn,shn->hts", qt[..., :nope], kv[..., :nope],
+                           preferred_element_type=f32)
+            s += jnp.einsum("thr,sr->hts", qt[..., nope:],
+                            rows[:, rank:rank + rope],
+                            preferred_element_type=f32)
+            pos_k = j * S + jnp.arange(S)
+            mask = (pos_k[None, :] <= pos_q[:, None]) & real[:, None]
+            return _online(carry, s * scale, mask[None], kv[..., nope:],
+                           "hts,shv->htv")
+
+        init = (jnp.full((H, Tq), NEG_INF, f32), jnp.zeros((H, Tq), f32),
+                jnp.zeros((H, Tq, vdim), f32))
+        _, l, acc = jax.lax.fori_loop(0, -(-last // S), block, init)
+        o = (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
+        return jnp.moveaxis(o, 0, 1)  # [Tq, H, vdim]
+
+    return _by_row(
+        q, jnp.zeros((M, H, vdim), q.dtype), row_lens > longer_than, Tq,
+        tables, row_starts, row_lens, ctx_lens, attend)
+
+
+def absorbed_rows_attention(
+    q: jax.Array,  # [M, H, nope + rope] on a flat axis that rows share
+    latent: KVLayer,
+    w_kvb: jax.Array,  # [rank, H * (nope + vdim)]
+    page_tables: jax.Array,  # [R, max_pages]
+    row_starts: jax.Array,  # [R] slot of each row's first token
+    row_lens: jax.Array,  # [R] the row's tokens
+    ctx_lens: jax.Array,  # [R] positions of the row's sequence before it
+    rank: int,
+    nope: int,
+    scale: float,
+    upto: int,  # rows of 2 ... `upto` tokens are served
+    out: jax.Array,  # [M, H, vdim]: what the other rows' slots keep
+) -> jax.Array:
+    """Causal attention of every row of 2 to `upto` tokens over its own
+    pages (its history and itself), in the latent space -> `out` with those
+    rows' slots filled. By row, by tile of the row's tokens, by block of the
+    row's own pages up to the tile's last position: `q~ = q_n W^K` of the
+    tile's tokens at every head against the gathered block as it lies, each
+    token under its own causal limit, then `u W^V`. `q~` and `u` are rounded
+    to q's dtype, as the lanes' walk rounds them."""
+    return _absorbed_rows(
+        q, *latent, w_kvb, page_tables, row_starts, row_lens, ctx_lens, out,
+        rank=rank, nope=nope, scale=scale, upto=upto, pages=ABSORBED_PAGES,
+        queries=ABSORBED_ROW_QUERIES)
+
+
+@_one_function_a_program(
+    "rank", "nope", "scale", "upto", "pages", "queries")
+def _absorbed_rows(q, pool, li, w_kvb, page_tables, row_starts, row_lens,
+                   ctx_lens, out, *, rank, nope, scale, upto, pages, queries):
+    M, H, D = q.shape
+    W, rope = pool.shape[3], D - nope
+    w = w_kvb.reshape(rank, H, -1)
+    Tq = min(M, queries)
+    tables, pb = _blocked_tables(page_tables, pages)
+    S = pb * pool.shape[2]
+
+    def attend(qt, table, pos_q, real, last):
+        q_lat = jnp.einsum("thn,rhn->thr", qt[..., :nope], w[..., :nope],
+                           preferred_element_type=f32).astype(q.dtype)
+        qw = jnp.concatenate(  # [Tq, H, W]: (q~ | q_r | 0...), the row's
+            [q_lat, qt[..., nope:],
+             jnp.zeros((Tq, H, W - rank - rope), q.dtype)], axis=-1)
+
+        def block(j, carry):
+            tb = jax.lax.dynamic_slice_in_dim(table, j * pb, pb)
+            rows = pool[li, tb].reshape(S, W)
+            s = jnp.einsum("thw,sw->ths", qw, rows,
+                           preferred_element_type=f32)
+            pos_k = j * S + jnp.arange(S)
+            mask = (pos_k[None, :] <= pos_q[:, None]) & real[:, None]
+            return _online(carry, s * scale, mask[:, None, :],
+                           rows[:, :rank], "ths,sr->thr")
+
+        init = (jnp.full((Tq, H), NEG_INF, f32), jnp.zeros((Tq, H), f32),
+                jnp.zeros((Tq, H, rank), f32))
+        _, l, acc = jax.lax.fori_loop(0, -(-last // S), block, init)
+        u = (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
+        return jnp.einsum("thr,rhv->thv", u, w[..., nope:],
+                          preferred_element_type=f32).astype(q.dtype)
+
+    return _by_row(
+        q, out, (row_lens > 1) & (row_lens <= upto), Tq, tables, row_starts,
+        row_lens, ctx_lens, attend)

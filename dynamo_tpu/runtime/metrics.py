@@ -145,6 +145,8 @@ METRICS = {
     "state_lanes_reset": {"kind": "counter", "layer": "engine", "unit": "rows", "help": "First chunks dispatched, each of which starts its lane's recurrent state from zero (the stateful families).", "export": True},
     "state_rows_in_place": {"kind": "counter", "layer": "engine", "unit": "rows", "help": "Rows of mixed steps whose recurrence was stepped over the lane in the state store: one token that goes on from the lane's state (the stateful families).", "export": True},
     "state_rows_gathered": {"kind": "counter", "layer": "engine", "unit": "rows", "help": "Rows of mixed steps whose state was gathered out of the store, stepped and scattered back: more than one token, or a sequence's first (the stateful families).", "export": True},
+    "mla_rows_absorbed_tokens": {"kind": "counter", "layer": "engine", "unit": "tokens", "help": "Tokens of mixed steps' chunks of more than one token that attended absorbed, in the latent space: chunks of at most ops/latent_attention.absorbed_row_limit tokens, the family's own rule (the latent-attention family).", "export": True},
+    "mla_rows_expanded_tokens": {"kind": "counter", "layer": "engine", "unit": "tokens", "help": "Tokens of mixed steps' chunks that attended expanded, their context's cached latents through W_kvb: chunks of more tokens than the rule's limit (the latent-attention family).", "export": True},
     "state_prefix_hits_declined": {"kind": "counter", "layer": "engine", "unit": "blocks", "help": "Cached blocks the prefix index was not allowed to hand a sequence because nobody kept the state that stood at their end (the stateful families).", "export": True},
     "routed_rows_emitted": {"kind": "counter", "layer": "engine", "unit": "rows", "help": "Rows of chosen expert ids sent to requests annotated routed_experts (the stateful families).", "export": True},
     "step_state_bytes": {"kind": "counter", "layer": "engine", "unit": "bytes", "help": "Of step_min_bytes, the recurrent state's: read and written once for each (row, pass) of the entries dispatched (exported once it is not 0: the stateful families).", "export": True},

@@ -282,6 +282,198 @@ def test_the_absorbed_path_is_the_expanded_one_on_the_same_cache(params, monkeyp
     assert np.abs(np.asarray(both)[1] - two).max() / np.abs(two).max() < 1e-5
 
 
+LIMIT = mla_moe.absorbed_row_limit(CFG)  # T*: 7 tokens at the tiny widths
+
+
+def test_the_rows_limit_is_where_the_two_forms_cost_the_same():
+    """`T*` from the widths alone: expanding costs rank x heads x (nope + v)
+    multiply-adds a cached position, and a query token costs heads x (width
+    + rank) absorbed against heads x (nope + rope + v) expanded. 358 at the
+    published widths in a row of 640 lanes, 7 at the tiny ones in a row of
+    128; widths whose absorbed form is the cheaper one a query token have
+    no crossing; and the family hands the engine the same number."""
+    rule = latent_attention.absorbed_row_limit
+    assert rule(512, 20, 192, 64, 256, 640) == 512 * 448 // 640 == 358
+    assert rule(512, 128, 192, 64, 256, 640) == 358  # heads cancel
+    assert rule(32, 4, 12, 8, 16, 128) == LIMIT == 7
+    assert rule(16, 4, 64, 32, 64, 128) == 2**31 - 1
+    published = mla_moe.MlaMoeConfig(num_layers=8, num_heads=20)
+    assert mla_moe.absorbed_row_limit(published) == 358
+
+
+def short_row_case(params, n, dtype, context=70):
+    """A layer's operands for ONE row of `n` tokens behind `context` cached
+    positions (5 pages: three blocks of 2 pages to both walks), in `dtype`."""
+    seq = sequence(5, context + n)
+    cfg = mla_moe.MlaMoeConfig.tiny_mla_moe(dtype=dtype)
+    if dtype != jnp.float32:
+        params = jax.tree.map(
+            lambda a: a.astype(dtype) if a.dtype == jnp.float32 and a.ndim > 2
+            else a, params)
+    cache, kv_v = alloc_state_cache(cfg, 40, PAGE, 4, 128, 8)
+    fwd = functools.partial(mla_moe.prefill_forward_batched, params, cfg)
+    _, cache, kv_v = hybrid_tests.prefill(
+        params, cache, kv_v, [(0, seq[:context], 0, table_of(0))], 128,
+        fn=lambda _, *a: fwd(*a))
+    layer = jax.tree.map(lambda a: a[1], params["layers"]["attention"])
+    h = jax.random.normal(jax.random.PRNGKey(9), (n, cfg.hidden_size), dtype)
+    pos = context + jnp.arange(n)
+    q = mla_moe._queries(layer, h, pos, cfg)
+    tab = jnp.asarray(table_of(0))[None]
+    pages = kv_quant.kv_write(
+        cache.pages, 1, tab[0, pos // PAGE], pos % PAGE,
+        mla_moe.latent_rows(layer, h, pos, cfg)[:, None, :])
+    return cfg, layer, q, kv_quant.kv_layer(pages, 1), tab
+
+
+def walk_args(cfg, layer, q, latent, tab, starts, lens, ctxs):
+    return (q, latent, layer["wkv_b"], tab, jnp.asarray(starts),
+            jnp.asarray(lens), jnp.asarray(ctxs), cfg.kv_lora_rank,
+            cfg.qk_nope_head_dim, cfg.qk_head_dim ** -0.5)
+
+
+@pytest.fixture
+def small_blocks(monkeypatch):
+    """Blocks of 2 pages and tiles of 4 query tokens: a context of 5 pages
+    takes three steps of every walk's running softmax, and a row of 5 or 7
+    tokens two tiles of the short rows' walk."""
+    monkeypatch.setattr(latent_attention, "ABSORBED_PAGES", 2)
+    monkeypatch.setattr(latent_attention, "EXPANDED_POSITIONS", 2 * PAGE)
+    monkeypatch.setattr(latent_attention, "ABSORBED_ROW_QUERIES", 4)
+
+
+@pytest.mark.parametrize("dtype, tol", [(jnp.float32, 1e-5), (jnp.bfloat16, 2e-2)])
+@pytest.mark.parametrize("n", [2, 5, LIMIT])
+def test_a_short_row_absorbed_is_the_same_row_expanded(params, small_blocks, n, dtype, tol):
+    """A row of 2, 5 and `T*` tokens behind 70 cached positions, once in the
+    latent space (its tokens folded into the head axis, each under its own
+    causal limit, W_kvb on the query's side and on the way out) and once
+    expanded (the context's latents through W_kvb): the same mathematics.
+    In float32 they differ by the order of their sums, to 1e-5 of the
+    largest value as the one-token row does above; in bfloat16, where `q~`,
+    `u`, the expanded keys and values and the probabilities are each
+    rounded to 8 bits of mantissa, to 2e-2 (read: 2.9e-3 to 5.6e-3)."""
+    cfg, layer, q, latent, tab = short_row_case(params, n, dtype)
+    args = walk_args(cfg, layer, q, latent, tab, [0], [n], [70])
+    want = np.asarray(latent_attention.expanded_attention(*args), np.float32)
+    got = np.asarray(latent_attention.absorbed_rows_attention(
+        *args, upto=LIMIT, out=jnp.zeros_like(want, dtype)), np.float32)
+    assert got.shape == want.shape == (n, cfg.num_heads, cfg.v_head_dim)
+    assert np.abs(got - want).max() / np.abs(want).max() < tol
+    # ... and `rows_attention` takes the row down that path
+    both = mla_moe.rows_attention(layer, *args[:2], *args[3:7], cfg)
+    assert (np.asarray(both, np.float32) == got).all()
+
+
+def test_a_row_past_the_limit_still_expands(params, small_blocks):
+    """`T* + 1` tokens: `rows_attention` returns the expanded walk's bits,
+    which are not the absorbed walk's (another order of sums), and the short
+    rows' walk leaves such a row's slots as they came."""
+    n = LIMIT + 1
+    cfg, layer, q, latent, tab = short_row_case(params, n, jnp.float32)
+    args = walk_args(cfg, layer, q, latent, tab, [0], [n], [70])
+    expanded = np.asarray(latent_attention.expanded_attention(*args))
+    got = np.asarray(mla_moe.rows_attention(layer, *args[:2], *args[3:7], cfg))
+    assert (got == expanded).all()
+    absorbed = np.asarray(latent_attention.absorbed_rows_attention(
+        *args, upto=n, out=jnp.zeros_like(expanded)))
+    assert (absorbed != expanded).any()
+    assert np.abs(absorbed - expanded).max() / np.abs(expanded).max() < 1e-5
+    sentinel = jnp.full_like(expanded, 7.0)
+    kept = latent_attention.absorbed_rows_attention(
+        *args, upto=LIMIT, out=sentinel)
+    assert (np.asarray(kept) == 7.0).all()
+
+
+def _loops(jaxpr, found):
+    """Every loop of a jaxpr, nested ones too: (primitive, its jaxpr)."""
+    for eqn in jaxpr.eqns:
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            if eqn.primitive.name in ("while", "scan"):
+                found.append(eqn.primitive.name)
+            _loops(sub, found)
+    return found
+
+
+def test_a_call_with_no_short_row_runs_the_new_loop_zero_times(params, small_blocks):
+    """Rows of one token and a row past the limit, none between: the short
+    rows' walk is three nested `while`s whose trip counts are data (no
+    `scan`, which is what a loop of a fixed length lowers to: nothing is
+    unrolled by row, tile or block), the outer one runs as often as the
+    call holds short rows, here never (a sentinel comes back untouched), and
+    `rows_attention` returns the bits the parent's composition returns: the
+    expanded walk over rows of more than one token, the lanes set into it."""
+    n = LIMIT + 3
+    cfg, layer, q, latent, tab = short_row_case(params, n + 2, jnp.float32)
+    tabs = jnp.concatenate([tab, tab, tab])
+    args = walk_args(cfg, layer, q, latent, tabs, [0, n, n + 1], [n, 1, 1],
+                     [70, 70 + n, 70 + n + 1])
+    sentinel = jnp.full((n + 2, cfg.num_heads, cfg.v_head_dim), 7.0)
+    walk = functools.partial(
+        latent_attention.absorbed_rows_attention, upto=LIMIT)
+    found = _loops(jax.make_jaxpr(  # the rows' lengths are the data
+        lambda lens: walk(*args[:5], lens, *args[6:], out=sentinel)
+    )(args[5]).jaxpr, [])
+    assert found.count("while") == 6 and "scan" not in found  # cond + body each
+    assert (np.asarray(walk(*args, out=sentinel)) == 7.0).all()
+    got = np.asarray(mla_moe.rows_attention(layer, *args[:2], *args[3:7], cfg))
+    parent = latent_attention.expanded_attention(*args)
+    lanes = mla_moe.absorbed(
+        layer, q[n:], latent, tabs[1:], jnp.asarray([70 + n + 1, 70 + n + 2]), cfg)
+    parent = np.asarray(parent.at[jnp.asarray([n, n + 1])].set(lanes))
+    assert (got == parent).all()
+
+
+def test_a_mixed_step_gives_each_kind_of_row_its_own_path(params):
+    """A fresh prompt of 33 tokens (expanded), a tail of 5 tokens behind 56
+    cached positions and one of `T*` behind 24 (absorbed, a row at a time)
+    and two decode rows (absorbed, as lanes) in one flat buffer: every row
+    reads the reference's logits, and a layer's `rows_attention` over the
+    same pack returns each row what its own walk returns alone."""
+    seqs = {lane: sequence(40 + lane, 70) for lane in range(4)}
+    fresh = sequence(44, 33)
+    want = {lane: reference_logits(params, CFG, s)[0] for lane, s in seqs.items()}
+    want_fresh, _ = reference_logits(params, CFG, fresh)
+    cache, kv_v = alloc_state_cache(CFG, 48, PAGE, 5, 256, 8)
+    _, cache, kv_v = prefill(params, cache, kv_v, [
+        (0, seqs[0][:56], 0, table_of(0)), (2, seqs[2][:40], 0, table_of(2)),
+        (3, seqs[3][:40], 0, table_of(3))], 64)
+    _, cache, kv_v = prefill(params, cache, kv_v, [
+        (1, seqs[1][:24], 0, table_of(1))], 32)
+    rows = [  # (lane, tokens, context)
+        (4, fresh, 0), (0, seqs[0][56:61], 56), (1, seqs[1][24:24 + LIMIT], 24),
+        (2, seqs[2][40:41], 40), (3, seqs[3][40:41], 40)]
+    operands = packed(rows, cache, kv_v, 8, 96)
+    logits, after, kv_v = RAGGED(params, *operands)
+    logits = np.asarray(logits)
+    assert off(logits[0], want_fresh[32]) < TOL
+    assert off(logits[1], want[0][60]) < TOL
+    assert off(logits[2], want[1][24 + LIMIT - 1]) < TOL
+    assert off(logits[3], want[2][40]) < TOL and off(logits[4], want[3][40]) < TOL
+    got, _, _ = decode(params, after, kv_v, {
+        0: (seqs[0][61], 61), 1: (seqs[1][24 + LIMIT], 24 + LIMIT)})
+    assert off(got[0], want[0][61]) < TOL
+    assert off(got[1], want[1][24 + LIMIT]) < TOL
+    # one layer over the pages the step left, every row beside its own walk
+    layer = jax.tree.map(lambda a: a[1], params["layers"]["attention"])
+    _, pos, _, _, _, tables, starts, lens, ctxs, _ = operands
+    h = jax.random.normal(jax.random.PRNGKey(3), (96, CFG.hidden_size))
+    q = mla_moe._queries(layer, h, pos, CFG)
+    latent = kv_quant.kv_layer(after.pages, 1)
+    args = walk_args(CFG, layer, q, latent, tables, starts, lens, ctxs)
+    got = np.asarray(mla_moe.rows_attention(layer, *args[:2], *args[3:7], CFG))
+    expanded = np.asarray(latent_attention.expanded_attention(
+        *args, longer_than=LIMIT))
+    short = np.asarray(latent_attention.absorbed_rows_attention(
+        *args, upto=LIMIT, out=jnp.zeros_like(expanded)))
+    lanes = np.asarray(mla_moe.absorbed(
+        layer, q[45:47], latent, tables[3:5], jnp.asarray([41, 41]), CFG))
+    assert (got[:33] == expanded[:33]).all() and expanded[:33].any()
+    assert not expanded[33:].any() and not short[:33].any()
+    assert (got[33:45] == short[33:45]).all() and short[33:45].all()
+    assert (got[45:47] == lanes).all()
+
+
 def test_the_int8_control_fails_the_tolerance(params):
     """The reference itself with every matrix rounded to int8 (the harness's
     own control): twenty times the tolerance and more, at every position
@@ -395,7 +587,7 @@ def test_the_engine_serves_the_references_tokens_and_says_what_it_routed(params)
     assert abs(ratio - ROW / (CFG.num_heads * (CFG.qk_head_dim + CFG.v_head_dim))) < 1e-9
     assert 0 < stats["expert_rows_routed"] <= stats["expert_rows_computed"]
     assert stats["attention_impl"] == {
-        "decode": "xla-latent-absorbed", "prefill": "xla-latent-expanded",
+        "decode": "xla-latent-absorbed", "prefill": "xla-latent-by-row",
         "ragged": "xla-latent-by-row"}
     # the pool is the one store, pages x rows x (rank + rope) a layer, and
     # the leaves the choices are recorded in: no second store of pages
@@ -430,6 +622,38 @@ def test_the_prefix_index_serves_latent_pages(params):
     assert last["kv_prefix_hit_blocks_total"] == 3
     assert after["state_prefix_hits_declined"] == 0
     assert len(third[1]) == len(prompt) + 8 - 1
+
+
+def test_a_prefixed_request_reads_the_same_tokens_cold_and_from_the_cache(params):
+    """A prompt of three pages and 5 tokens served twice: cold and alone,
+    through the split prefill (chunks of 32 and 21 tokens: expanded), then,
+    while another request decodes, from the prefix index through a mixed
+    step, where its tail of 5 tokens behind 48 cached positions attends
+    absorbed (its first token now comes from the latent space): the same
+    greedy tokens, the reference's; and the engine counts the tail's tokens
+    by the family's own rule."""
+    prompt, other = sequence(60, 53), sequence(61, 30)
+
+    async def run():
+        eng = engine(params)
+        cold = await stream(eng, prompt, "cold", 10)
+        before = eng.stats()
+        _, cached = await asyncio.gather(
+            stream(eng, other, "bg", 220),
+            stream(eng, prompt, "cached", 10, delay=0.2))
+        after = eng.stats()
+        await eng.close()
+        return cold[0], cached[0], before, after
+
+    cold, cached, before, after = asyncio.run(run())
+    assert cold == cached == reference_greedy(params, prompt, 10)
+    assert before["mixed_steps"] == 0 and before["split_steps"] == 0
+    assert before["mla_rows_absorbed_tokens"] == 0
+    assert after["kv_prefix_hit_blocks_total"] == 3
+    assert after["mixed_steps"] > 0
+    assert after["mla_rows_absorbed_tokens"] == 5
+    # the other request's 30 tokens rode a mixed step too, or the split pair
+    assert after["mla_rows_expanded_tokens"] in (0, 30)
 
 
 def test_concurrent_requests_of_one_prefix_skip_ahead_over_latent_pages(params):

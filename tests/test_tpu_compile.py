@@ -635,7 +635,8 @@ def test_the_window_familys_steps_compile_at_the_published_widths(
         f"{mem.temp_size_in_bytes / 2**20:.0f} MiB of temporaries")
 
 
-@pytest.mark.parametrize("program", ("block_of_8", "mixed_256", "mixed_2048"))
+@pytest.mark.parametrize(
+    "program", ("block_of_8", "mixed_256", "mixed_2048", "prefill_1024"))
 def test_the_latent_familys_steps_keep_the_pool_where_it_lies(
     program, one_chip, no_persistent_cache, tpu_gate
 ):
@@ -643,14 +644,19 @@ def test_the_latent_familys_steps_keep_the_pool_where_it_lies(
     published widths, 64 experts, the whole vocabulary, 10.3 GB of weights)
     and its auto pool of 5,300 latent pages of 320 a lane, for the described
     v5e: a decode block's scan of 8 decode steps (32 lanes, every lane
-    absorbed) and the smallest and the largest member of the mixed_step
-    family (40 rows). The only kernels are the sparse layers' three grouped
-    matmuls; both attention walks are XLA loops over blocks of gathered
-    pages, so what is pinned is that the pool stays where it lies: the
-    temporaries stay far under the pool (3.5 GB; a row of 576 lanes in
-    place of 640 made the compiler copy the whole pool into another layout
-    and back, a pool of temporaries a step: ops/kv_quant.py), and the whole
-    fits the chip."""
+    absorbed), the smallest and the largest member of the mixed_step
+    family (40 rows) and a split prefill's chunk of 1,024 tokens. The only
+    kernels are the sparse layers' three grouped matmuls; the attention
+    walks are XLA loops over blocks of gathered pages, so what is pinned is
+    that the pool stays where it lies: the temporaries stay far under the
+    pool (3.5 GB; a row of 576 lanes in place of 640 made the compiler copy
+    the whole pool into another layout and back, a pool of temporaries a
+    step: ops/kv_quant.py), and the whole fits the chip. And what a cold
+    start has to compile stays one loop nest a walk and layer (row, tile of
+    its tokens, block of its pages: three `while`s, nothing unrolled by row
+    or by block): the short rows' absorbed walk in every program over a
+    flat axis of rows, the expanded walk only where a row can pass the
+    rule's 358 tokens (the mixed step of 256 slots holds none)."""
     from dynamo_tpu.models import mla_moe
 
     sds = _shapes(one_chip)
@@ -674,8 +680,21 @@ def test_the_latent_familys_steps_keep_the_pool_where_it_lies(
         compiled = jax.jit(block, donate_argnums=(3, 4)).lower(
             params, sds((32,), i32), sds((32,), i32), cache, kv_v,
             sds((32, 321), i32), sds((32,), i32)).compile()
+        nests = {"mla_absorb/": 1}
+    elif program == "prefill_1024":
+        def chunk(params, tokens, positions, kv_k, kv_v, tables, ctx, last):
+            return mla_moe.prefill_forward_batched(
+                params, cfg, tokens, positions, kv_k, kv_v, tables, ctx, last)
+
+        compiled = jax.jit(chunk, donate_argnums=(3, 4)).lower(
+            params, sds((1, 1024), i32), sds((1, 1024), i32), cache, kv_v,
+            sds((1, 321), i32), sds((1,), i32), sds((1,), i32)).compile()
+        nests = {"mla_absorb/": 1, "mla_absorb_rows": 3, "mla_expand": 3}
     else:
         tokens, rows = int(program.split("_")[1]), 40
+        assert mla_moe.absorbed_row_limit(cfg) == 358
+        nests = {"mla_absorb/": 1, "mla_absorb_rows": 3,
+                 "mla_expand": 3 if tokens > 358 else 0}
 
         def step(params, tokens, positions, row_ids, kv_k, kv_v, tables,
                  row_starts, row_lens, ctx_lens, last_flat):
@@ -688,7 +707,12 @@ def test_the_latent_familys_steps_keep_the_pool_where_it_lies(
             sds((tokens,), i32), cache, kv_v, sds((rows, 321), i32),
             sds((rows,), i32), sds((rows,), i32), sds((rows,), i32),
             sds((rows,), i32)).compile()
-    assert compiled.as_text().count("tpu_custom_call") == 7 * 3
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 7 * 3
+    loops = [ln for ln in text.split("\n") if re.search(r"= .* while\(", ln)]
+    for scope in ("mla_absorb/", "mla_absorb_rows", "mla_expand"):
+        found = sum(scope in ln for ln in loops)
+        assert found == 8 * nests.get(scope, 0), (scope, found)
     mem = compiled.memory_analysis()
     need = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert need < HBM_BYTES, f"{program} needs {need / 2**30:.2f} GiB"
