@@ -455,6 +455,8 @@ class _Slot:
     # admission and the first token handed to the stream, time.monotonic()
     admit_s: float = 0.0
     first_token_s: float = 0.0
+    # (blocks, mixed steps) the recorder had seen fetched at the arrival
+    fetched_at_arrival: tuple = (0, 0)
     sched_deadline: float = 0.0
     sched_skips: int = 0
     # dynogate tenant key (docs/overload.md): feeds the StepPlanner's
@@ -2358,6 +2360,17 @@ class JaxEngine:
         return out
 
     def _new_slot(self, req: PreprocessedRequest, context: Context, suffix: str = "") -> _Slot:
+        """The slot of a request as it arrives. The making (the prompt is
+        hashed block by block in it) is the span `engine.ingest`, and ends
+        the stage `ingest` of a request that came with a timeline: it runs
+        as a task of the event loop whenever the engine's own loop yields."""
+        with self._rec.ingest():
+            slot = self._build_slot(req, context, suffix)
+        self._rec.arrived(slot)
+        self.scheduler.assign_deadline(slot)
+        return slot
+
+    def _build_slot(self, req: PreprocessedRequest, context: Context, suffix: str) -> _Slot:
         stop = req.stop_conditions or {}
         sampling = req.sampling_options or {}
         slot = _Slot(
@@ -2431,8 +2444,6 @@ class JaxEngine:
         slot.priority = int(req.priority or 0)
         slot.tenant = req.tenant or ""
         slot.migration = int(getattr(req, "migration", 0) or 0)
-        slot.arrival_s = time.monotonic()
-        self.scheduler.assign_deadline(slot)
         return slot
 
     def _morph_guard(self):

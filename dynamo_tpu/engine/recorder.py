@@ -30,7 +30,18 @@ One table for the engine's loop, exported through `JaxEngine.stats()`:
   that names its shares (models/mla_moe.py: `step_latent_kv_bytes`,
   `step_latent_kv_expanded_bytes`, `step_expert_bytes`).
 * the waits ahead of a first token: `req_admitted`, `req_queue_wait_s`,
-  `req_first_tokens`, `req_admit_to_first_s`.
+  `req_first_tokens`, `req_admit_to_first_s`, and the pipeline entries
+  fetched between a request's arrival and its first token, its own among
+  them: `req_blocks_ahead`, `req_mixed_ahead`.
+* STAGES of a request's path (docs/observability.md, "A request's path"):
+  `req_stage_<stage>_count`, `req_stage_<stage>_s`. A request that came
+  with a timeline on its context (runtime/engine.Context: the request
+  plane's server began it from the caller's header) brings the caller's
+  stages with it, `ingest` is closed here (the server's arrival to the
+  slot's: unpack, checks, the prompt's hashing; a
+  `TraceAnnotation("engine.ingest")` round the slot's making), and
+  `first_frame` by the request plane's server through the context's
+  `on_stamp`. A request that came with none counts nothing.
 * the device calls' host clock by tag (`dispatch_<tag>_count`, `_s`:
   engine._timed).
 
@@ -47,10 +58,16 @@ from typing import Callable, Dict, List, Optional
 
 from jax.profiler import TraceAnnotation
 
+from ..runtime.engine import STAGES as PATH_STAGES
+
 logger = logging.getLogger(__name__)
 
 PHASES = ("admit", "pack", "put", "launch", "fetch", "emit", "wait")
 STEP_KINDS = ("block", "mixed", "prefill")
+#: the stages of a request's path with a row here: the caller's four, the
+#: hop, and the worker's two (`queue` and `first` are the two waits' rows,
+#: `sse` is the frontend's alone)
+STAGES = tuple(s for s in PATH_STAGES if s not in ("queue", "first", "sse"))
 #: a span this long is a stall of the loop: counted, and logged unless
 #: the phase is `wait` (an idle engine is no stall). An entry's interval
 #: this long is one too (a program compiling inside its launch, the
@@ -148,6 +165,12 @@ class Recorder:
         self.req_queue_wait_s = 0.0
         self.req_first_tokens = 0
         self.req_admit_to_first_s = 0.0
+        self.blocks_ahead = self.mixed_ahead = 0
+        # entries fetched so far by kind, the stalled ones among them
+        self.fetched_n: Dict[str, int] = {k: 0 for k in STEP_KINDS}
+        # [requests, seconds] by stage; one writer, the event loop's thread
+        self.stages: Dict[str, list] = {s: [0, 0.0] for s in STAGES}
+        self.hop_unmeasured = 0
         # what the loop is working on, for a slow span's log line
         self.entry_kind = "none"
         self._describe = describe
@@ -216,11 +239,46 @@ class Recorder:
         since = max(self._last_ready, min(e["t_dispatch"] for e in entries))
         share = max(t_ready - since, 0.0) / len(entries)
         for e in entries:
+            self.fetched_n[e["step_kind"]] += 1
             row = self.stalled if share >= SLOW_SPAN_S \
                 else self.steps[e["step_kind"]]
             row[0] += 1
             row[1] += share
         self._last_ready = t_ready
+
+    # -- a request's path ------------------------------------------------ #
+
+    def ingest(self) -> TraceAnnotation:
+        """Round the making of a request's slot: the span `engine.ingest`
+        of a profiler's trace, beside the seven phases."""
+        return TraceAnnotation("engine.ingest")
+
+    def stage(self, name: str, seconds: float):
+        """One request's `seconds` in stage `name`; a stage with no row
+        here (`queue`, `first`: the waits' rows have them) is not kept."""
+        row = self.stages.get(name)
+        if row is not None:
+            row[0] += 1
+            row[1] += seconds
+
+    def arrived(self, slot) -> None:
+        """A request's slot is made: its arrival, and how many entries had
+        been fetched by then. Where the request came with a timeline, the
+        caller's stages are counted, `ingest` is closed, and what is
+        stamped on its context from here on (`first_frame`, by the request
+        plane's server) comes here too. Once a context: a decode entry
+        that falls back to a local prefill makes a second slot."""
+        ctx = slot.context
+        slot.arrival_s = now = time.monotonic()
+        slot.fetched_at_arrival = (self.fetched_n["block"], self.fetched_n["mixed"])
+        if not ctx.stamp_s or ctx.on_stamp is not None:
+            return
+        for name, seconds in ctx.stages.items():
+            self.stage(name, seconds)
+        if "send" in ctx.stages and "hop" not in ctx.stages:
+            self.hop_unmeasured += 1  # the caller's clock is another host's
+        ctx.on_stamp = self.stage
+        ctx.stamp("ingest", now)
 
     # -- the waits ahead of a first token -------------------------------- #
 
@@ -232,6 +290,7 @@ class Recorder:
         slot.admit_s = time.monotonic()
         self.req_admitted += 1
         self.req_queue_wait_s += max(slot.admit_s - slot.arrival_s, 0.0)
+        slot.context.stamp("queue", slot.admit_s)
 
     def first_token(self, slot) -> None:
         """The first token of a request handed to its stream."""
@@ -240,6 +299,11 @@ class Recorder:
         slot.first_token_s = time.monotonic()
         self.req_first_tokens += 1
         self.req_admit_to_first_s += slot.first_token_s - slot.admit_s
+        blocks, mixed = slot.fetched_at_arrival
+        self.blocks_ahead += self.fetched_n["block"] - blocks
+        self.mixed_ahead += self.fetched_n["mixed"] - mixed
+        ctx = slot.context
+        ctx.first_token_s = ctx.stamp("first", slot.first_token_s)
 
     # -- export ---------------------------------------------------------- #
 
@@ -258,7 +322,13 @@ class Recorder:
             "req_queue_wait_s": round(self.req_queue_wait_s, 6),
             "req_first_tokens": self.req_first_tokens,
             "req_admit_to_first_s": round(self.req_admit_to_first_s, 6),
+            "req_blocks_ahead": self.blocks_ahead,
+            "req_mixed_ahead": self.mixed_ahead,
+            "req_hop_unmeasured": self.hop_unmeasured,
         }
+        for name, (cnt, tot) in self.stages.items():
+            out[f"req_stage_{name}_count"] = cnt
+            out[f"req_stage_{name}_s"] = round(tot, 6)
         for name, (cnt, tot, slow) in self.phases.items():
             out[f"phase_{name}_count"] = cnt
             out[f"phase_{name}_s"] = round(tot, 6)

@@ -7,6 +7,7 @@ labeled by model and endpoint type, exported at /metrics.
 
 from __future__ import annotations
 
+import logging
 import time
 
 from prometheus_client import (
@@ -16,6 +17,14 @@ from prometheus_client import (
     Histogram,
     generate_latest,
 )
+
+from ...runtime.engine import STAGES
+
+logger = logging.getLogger(__name__)
+
+#: a first token this late logs one WARNING line that names every stage:
+#: the frontend's counterpart of engine/recorder.py's SLOW_SPAN_S, no knob
+SLOW_FIRST_TOKEN_S = 2.0
 
 
 class HttpMetrics:
@@ -48,6 +57,14 @@ class HttpMetrics:
             registry=self.registry,
             buckets=(0.01, 0.025, 0.05, 0.1, 0.2, 0.4, 0.8, 1.6, 3.2, 6.4, 12.8),
         )
+        stage = Histogram(
+            f"{ns}_stage_seconds",
+            "Seconds of a request in one stage of its path to the first token",
+            ["stage"],
+            registry=self.registry,
+            buckets=(0.0001, 0.0003, 0.001, 0.003, 0.01, 0.03, 0.1, 0.3, 1, 3, 10),
+        )
+        self._stage = {s: stage.labels(s) for s in STAGES}
         self.output_tokens = Counter(
             f"{ns}_output_tokens_total",
             "Total generated tokens",
@@ -116,6 +133,29 @@ class HttpMetrics:
 
     def observe_ttft(self, model: str, seconds: float):
         self.ttft.labels(model).observe(seconds)
+
+    def first_token(self, model: str, ctx, t0: float, now: float):
+        """A request's first token has reached its handler at `now`: the
+        time to first token and, of a request with a timeline, every stage
+        it holds (`sse` is closed here, where the worker's stages came back
+        on the frame that carried the token). A first token later than
+        SLOW_FIRST_TOKEN_S is logged with its whole path."""
+        ttft = now - t0
+        self.observe_ttft(model, ttft)
+        if "first_frame" in ctx.stages:
+            ctx.stamp("sse", now)
+        for name, seconds in ctx.stages.items():
+            child = self._stage.get(name)
+            if child is not None:
+                child.observe(seconds)
+        if ttft >= SLOW_FIRST_TOKEN_S:
+            known = {s: ctx.stages[s] for s in STAGES if s in ctx.stages}
+            logger.warning(
+                "request %s first token after %.3f s: %s, other %.3f",
+                ctx.id, ttft,
+                ", ".join(f"{s} {v:.3f}" for s, v in known.items()),
+                ttft - sum(known.values()),
+            )
 
     def observe_tokens_per_frame(self, model: str, n_tokens: int):
         self.tokens_per_frame.labels(model).observe(n_tokens)

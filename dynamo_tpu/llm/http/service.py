@@ -155,6 +155,16 @@ class HttpService:
     # dynogate admission (docs/overload.md)
     # ------------------------------------------------------------------ #
 
+    @staticmethod
+    def _accepted(t0: float) -> Context:
+        """The context of a request whose body is read and validated: its
+        timeline begins at `t0`, the handler's first line, and `http` ends
+        here (docs/observability.md, "A request's path"). The gate's
+        admission, which follows, is the first part of `route`."""
+        ctx = Context().begin(t0)
+        ctx.stamp("http")
+        return ctx
+
     def _gate_tenant(self, request: web.Request) -> str:
         header = self.gate.config.tenant_header
         return (request.headers.get(header, "") if header else "") or "default"
@@ -364,18 +374,20 @@ class HttpService:
             )
         except Exception as e:  # noqa: BLE001 — malformed request, not a 500
             return self._error(400, f"invalid request: {e}")
+        ctx = self._accepted(t0)
         reject, tenant = await self._gate_admit(
             request, model, body, "responses", t0
         )
         if reject is not None:
             return reject
+        ctx.stamp("route")
         self.metrics.request_start(model, "responses")
-        ctx = Context()
         try:
             pre = await pipeline.preprocessor.preprocess_chat_async(chat_req)
         except ValueError as e:
             self.metrics.request_end(model, "responses", t0, error=True)
             return self._error(400, str(e))
+        ctx.stamp("preprocess")
         if tenant and tenant != "default":
             pre.tenant = tenant
         resp_id = f"resp_{_secrets.token_hex(12)}"
@@ -443,7 +455,7 @@ class HttpService:
                     last_token_at = time.monotonic()
                     if first_token_at is None:
                         first_token_at = last_token_at
-                        self.metrics.observe_ttft(model, first_token_at - t0)
+                        self.metrics.first_token(model, ctx, t0, first_token_at)
                 n_out += len(out.token_ids)
                 if out.text:
                     texts.append(out.text)
@@ -503,16 +515,18 @@ class HttpService:
             return self._error(404, f"model {req.model!r} not found", "model_not_found")
         # admission control BEFORE tokenization: a rejected request must
         # not spend compute-pool time on the chat template (docs/overload.md)
+        ctx = self._accepted(t0)
         reject, tenant = await self._gate_admit(request, req.model, body, "chat", t0)
         if reject is not None:
             return reject
+        ctx.stamp("route")
         self.metrics.request_start(req.model, "chat")
-        ctx = Context()
         try:
             pre = await pipeline.preprocessor.preprocess_chat_async(req)
         except ValueError as e:
             self.metrics.request_end(req.model, "chat", t0, error=True)
             return self._error(400, str(e))
+        ctx.stamp("preprocess")
         if tenant and tenant != "default":
             pre.tenant = tenant  # rides to the worker's fairness tiebreak
         include_usage = bool(
@@ -658,8 +672,7 @@ class HttpService:
                     last_token_at = time.monotonic()
                     if first_token_at is None:
                         first_token_at = last_token_at
-                        self.metrics.observe_ttft(
-                            req.model, first_token_at - t0)
+                        self.metrics.first_token(req.model, ctx, t0, first_token_at)
                     self.metrics.observe_tokens_per_frame(
                         req.model, len(out.token_ids))
                 if out.reasoning_content:
@@ -801,7 +814,7 @@ class HttpService:
                 last_token_at = time.monotonic()
                 if first_token_at is None:
                     first_token_at = last_token_at
-                    self.metrics.observe_ttft(req.model, first_token_at - t0)
+                    self.metrics.first_token(req.model, ctx, t0, first_token_at)
             n_out += len(out.token_ids)
             if out.reasoning_content:
                 reasoning_parts.append(out.reasoning_content)
@@ -861,18 +874,20 @@ class HttpService:
         pipeline = self.manager.get(req.model)
         if pipeline is None:
             return self._error(404, f"model {req.model!r} not found", "model_not_found")
+        ctx = self._accepted(t0)
         reject, tenant = await self._gate_admit(
             request, req.model, body, "completions", t0
         )
         if reject is not None:
             return reject
+        ctx.stamp("route")
         self.metrics.request_start(req.model, "completions")
-        ctx = Context()
         try:
             pre = await pipeline.preprocessor.preprocess_completion_async(req)
         except ValueError as e:
             self.metrics.request_end(req.model, "completions", t0, error=True)
             return self._error(400, str(e))
+        ctx.stamp("preprocess")
         if tenant and tenant != "default":
             pre.tenant = tenant
         gen = CompletionDeltaGenerator(req.model, pre.request_id)
@@ -916,7 +931,7 @@ class HttpService:
                     last_token_at = time.monotonic()
                     if first_token_at is None:
                         first_token_at = last_token_at
-                        self.metrics.observe_ttft(req.model, first_token_at - t0)
+                        self.metrics.first_token(req.model, ctx, t0, first_token_at)
                     self.metrics.observe_tokens_per_frame(
                         req.model, len(out.token_ids))
                 if out.text or out.logprob_entries:
@@ -980,7 +995,7 @@ class HttpService:
                 last_token_at = time.monotonic()
                 if first_token_at is None:
                     first_token_at = last_token_at
-                    self.metrics.observe_ttft(req.model, first_token_at - t0)
+                    self.metrics.first_token(req.model, ctx, t0, first_token_at)
             n_out += len(out.token_ids)
             if out.text:
                 texts.append(out.text)

@@ -16,7 +16,29 @@ from __future__ import annotations
 import asyncio
 import secrets
 import time
-from typing import Any, AsyncIterator, Awaitable, Callable, Optional, Protocol, runtime_checkable
+from typing import Any, AsyncIterator, Awaitable, Callable, Dict, Optional, Protocol, runtime_checkable
+
+
+def _boot_id() -> str:
+    """What two processes share exactly when their `time.monotonic()`
+    clocks compare: the kernel's id of this boot. Where the kernel gives
+    none, a token of this process alone (its clock compares with itself)."""
+    try:
+        with open("/proc/sys/kernel/random/boot_id") as f:
+            return f.read().strip()
+    except OSError:
+        return secrets.token_hex(8)
+
+
+#: a request's path from the HTTP accept to the first SSE write, in order
+#: (docs/observability.md, "A request's path"): the frontend's four, the
+#: hop, the worker's four, and the frontend's last
+STAGES = ("http", "preprocess", "route", "send", "hop", "ingest", "queue",
+          "first", "first_frame", "sse")
+
+#: rides the request plane beside a request's stage times, so that the
+#: receiver knows whether the sender's monotonic stamps mean anything to it
+BOOT_ID = _boot_id()
 
 
 class Context:
@@ -34,6 +56,13 @@ class Context:
     inherit the tightest deadline on the parent chain; the deadline also
     crosses the request plane (`deadline_ms` on the wire) so worker-side
     contexts see the same budget.
+
+    A context also carries the request's timeline (docs/observability.md,
+    "A request's path"): `stages`, seconds by stage name, and `stamp_s`,
+    the `time.monotonic()` of the last stamp. `stamp(stage)` closes a stage
+    where its work ends: the time since the last stamp is the stage's. A
+    context nobody began (`begin`; `stamp_s` is 0) keeps no times, and a
+    child begins none.
     """
 
     def __init__(
@@ -53,8 +82,37 @@ class Context:
         # exclude the dead instance from the retry's re-route
         # (docs/fault_tolerance.md "Request migration")
         self.routed_instance: Optional[int] = None
+        self.stages: Dict[str, float] = {}
+        self.stamp_s = 0.0
+        # who else keeps the stages stamped from here on (the engine's
+        # recorder, once the request has reached it)
+        self.on_stamp: Optional[Callable[[str, float], None]] = None
+        # when the handler handed the first token to its stream, where it
+        # says so: the request plane's server then closes `first_frame`
+        self.first_token_s = 0.0
         if parent is not None:
             parent._children.append(self)
+
+    def begin(self, at: float) -> "Context":
+        """Start the timeline at `at`, a `time.monotonic()` taken where the
+        request was accepted."""
+        self.stamp_s = at
+        return self
+
+    def stamp(self, stage: str, now: Optional[float] = None) -> float:
+        """Close `stage` at `now` (this moment where none is given): what
+        has passed since the last stamp accrues to it. Returns `now`, or 0
+        on a context whose timeline nobody began, which keeps no times."""
+        if not self.stamp_s:
+            return 0.0
+        if now is None:
+            now = time.monotonic()
+        spent = now - self.stamp_s
+        self.stages[stage] = self.stages.get(stage, 0.0) + spent
+        self.stamp_s = now
+        if self.on_stamp is not None:
+            self.on_stamp(stage, spent)
+        return now
 
     @property
     def id(self) -> str:

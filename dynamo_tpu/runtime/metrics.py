@@ -205,6 +205,23 @@ METRICS = {
     "req_queue_wait_s": {"kind": "counter", "layer": "engine", "unit": "seconds", "help": "Summed arrival-to-first-admission wait of those requests.", "export": True},
     "req_first_tokens": {"kind": "counter", "layer": "engine", "unit": "requests", "help": "Requests whose first token was handed to their stream.", "export": True},
     "req_admit_to_first_s": {"kind": "counter", "layer": "engine", "unit": "seconds", "help": "Summed first-admission-to-first-token time of those requests.", "export": True},
+    "req_stage_http_count": {"kind": "counter", "layer": "engine", "unit": "requests", "help": "Requests that came with a timeline and closed the frontend's `http`: the handler's first line to a validated body (HttpService._accepted).", "dynamic": True, "export": True},
+    "req_stage_http_s": {"kind": "counter", "layer": "engine", "unit": "seconds", "help": "Summed seconds of those requests in that stage.", "dynamic": True, "export": True},
+    "req_stage_preprocess_count": {"kind": "counter", "layer": "engine", "unit": "requests", "help": "Requests that came with a timeline and closed the frontend's `preprocess`: the chat template and the tokenizer (OpenAIPreprocessor.preprocess_*, stamped by the handler as it returns).", "dynamic": True, "export": True},
+    "req_stage_preprocess_s": {"kind": "counter", "layer": "engine", "unit": "seconds", "help": "Summed seconds of those requests in that stage.", "dynamic": True, "export": True},
+    "req_stage_route_count": {"kind": "counter", "layer": "engine", "unit": "requests", "help": "Requests that came with a timeline and closed the frontend's `route`: the gate's admission and the router's pick, up to the dial (RequestPlaneClient.call's first line).", "dynamic": True, "export": True},
+    "req_stage_route_s": {"kind": "counter", "layer": "engine", "unit": "seconds", "help": "Summed seconds of those requests in that stage.", "dynamic": True, "export": True},
+    "req_stage_send_count": {"kind": "counter", "layer": "engine", "unit": "requests", "help": "Requests that came with a timeline and closed the frontend's `send`: the dial where the pool holds no connection, and codec.pack of the request, up to write_frame.", "dynamic": True, "export": True},
+    "req_stage_send_s": {"kind": "counter", "layer": "engine", "unit": "seconds", "help": "Summed seconds of those requests in that stage.", "dynamic": True, "export": True},
+    "req_stage_hop_count": {"kind": "counter", "layer": "engine", "unit": "requests", "help": "Requests that came with a timeline and closed `hop`: the sender's stamp before write_frame to the server's arrival stamp before codec.unpack (RequestPlaneServer._run_stream), where both clocks are one host's (the boot ids match).", "dynamic": True, "export": True},
+    "req_stage_hop_s": {"kind": "counter", "layer": "engine", "unit": "seconds", "help": "Summed seconds of those requests in that stage.", "dynamic": True, "export": True},
+    "req_stage_ingest_count": {"kind": "counter", "layer": "engine", "unit": "requests", "help": "Requests that came with a timeline and closed `ingest`: the server's arrival to the slot's (slot.arrival_s): unpack, from_dict, the checks, the prompt's hashing in _new_slot; it runs on the engine's event loop whenever the engine's loop yields (profiler span engine.ingest round the slot's making).", "dynamic": True, "export": True},
+    "req_stage_ingest_s": {"kind": "counter", "layer": "engine", "unit": "seconds", "help": "Summed seconds of those requests in that stage.", "dynamic": True, "export": True},
+    "req_stage_first_frame_count": {"kind": "counter", "layer": "engine", "unit": "requests", "help": "Requests that came with a timeline and closed `first_frame`: the first token handed to the stream (Recorder.first_token) to its frame's write_frame returning in RequestPlaneServer._run_stream.", "dynamic": True, "export": True},
+    "req_stage_first_frame_s": {"kind": "counter", "layer": "engine", "unit": "seconds", "help": "Summed seconds of those requests in that stage.", "dynamic": True, "export": True},
+    "req_hop_unmeasured": {"kind": "counter", "layer": "engine", "unit": "requests", "help": "Requests whose timeline came from another host (another boot id): monotonic clocks do not compare, so `hop` was left out.", "export": True},
+    "req_blocks_ahead": {"kind": "counter", "layer": "engine", "unit": "entries", "help": "Decode blocks fetched between a request's arrival and its first token, summed over the requests counted in req_first_tokens.", "export": True},
+    "req_mixed_ahead": {"kind": "counter", "layer": "engine", "unit": "entries", "help": "Mixed steps fetched between a request's arrival and its first token, its own among them, summed over the requests counted in req_first_tokens.", "export": True},
     # per-kind fused coverage (docs/ragged_attention.md "Row classes"):
     # proves blended guided/spec/lora traffic actually rides the fused
     # path; the blended-trace CI smoke gates mixed_coverage_frac >= 0.9
@@ -324,6 +341,7 @@ METRICS = {
     "dynamo_frontend_input_tokens_total": {"kind": "counter", "layer": "frontend", "unit": "tokens", "help": "Prompt tokens accepted.", "labels": ("model",), "wire": True},
     "dynamo_frontend_inter_token_latency_seconds": {"kind": "histogram", "layer": "frontend", "unit": "seconds", "help": "Mean inter-token latency per request.", "labels": ("model",), "wire": True, "buckets": (0.002, 0.005, 0.01, 0.02, 0.04, 0.08, 0.16, 0.32, 0.64, 1.28)},
     "dynamo_frontend_client_disconnects_total": {"kind": "counter", "layer": "frontend", "help": "Client disconnects mid-stream.", "labels": ("model",)},
+    "dynamo_frontend_stage_seconds": {"kind": "histogram", "layer": "frontend", "unit": "seconds", "help": "Seconds of a request in one stage of its path from the accept to the first token (http, preprocess, route, send, hop, ingest, queue, first, first_frame, sse), observed at its first token.", "labels": ("stage",), "buckets": (0.0001, 0.0003, 0.001, 0.003, 0.01, 0.03, 0.1, 0.3, 1, 3, 10)},
     "dynamo_frontend_tokens_per_frame": {"kind": "histogram", "layer": "frontend", "unit": "tokens", "help": "Generated tokens per streamed delta batch.", "labels": ("model",), "buckets": (1, 2, 4, 8, 16, 32, 64, 128)},
     "dynamo_frontend_migrations_total": {"kind": "counter", "layer": "frontend", "help": "Stream migrations started after worker loss."},
     "dynamo_frontend_migration_replayed_tokens_total": {"kind": "counter", "layer": "frontend", "unit": "tokens", "help": "Tokens replayed into migration retry prompts."},

@@ -12,9 +12,14 @@ broker round-trip from the token hot path — on TPU pods, hosts talk
 directly over DCN anyway.
 
 Wire protocol (two-part frames, codec.py):
-  request :  {t:"req", stream:<id>, subject:<str>, traceparent?:<str>}  + payload
+  request :  {t:"req", stream:<id>, subject:<str>, traceparent?:<str>,
+              timeline?:{s:{<stage>:<seconds>}, at:<monotonic s>, boot:<id>}}
+             + payload
   cancel  :  {t:"cancel", stream:<id>, kill:<bool>}
-  response:  {t:"data", stream:<id>} + payload        (one stream item)
+  response:  {t:"data", stream:<id>} + payload        (one stream item;
+                                                       the frame of a timed
+                                                       request's first token
+                                                       adds stages:{...})
              {t:"data", stream:<id>, n:<k>} + payload (k coalesced items,
                                                        payload = packed list)
              {t:"done", stream:<id>}                  (clean end)
@@ -39,6 +44,7 @@ from __future__ import annotations
 import asyncio
 import itertools
 import logging
+import math
 import socket as _socket
 import time
 from typing import Any, AsyncIterator, Awaitable, Callable, Dict, Optional, Tuple
@@ -58,7 +64,7 @@ from .codec import (
     T_REQ,
 )
 from .config import _env
-from .engine import Context
+from .engine import BOOT_ID, Context
 from .logging import DistributedTraceContext, current_trace, parse_traceparent, set_trace
 
 logger = logging.getLogger(__name__)
@@ -257,17 +263,32 @@ class RequestPlaneServer:
         # then ships pure token-delta batches as packed u32s instead of
         # msgpack dicts, falling back per frame for anything else
         want_binary = bool(control.get("bin"))
+        ctx = Context(id=control.get("ctx_id"))
+        # a request that brings its timeline (docs/observability.md, "A
+        # request's path") is stamped as arrived HERE, before its payload is
+        # unpacked; one that brings none keeps no times on this side either.
+        # `timed`: it brought one, and its first token has not left yet
+        timed = _take_timeline(ctx, control.get("timeline"), time.monotonic())
 
         async def send(ctrl: dict, pl: bytes = b""):
+            nonlocal timed
             ctrl["stream"] = stream_id
+            first = timed and pl and ctx.first_token_s
+            if first:
+                # the first data frame (one with a payload) after the
+                # handler has said that its first token is out takes the
+                # worker's stages back with it, this one frame alone
+                timed = False
+                ctrl["stages"] = _worker_stages(ctx, time.monotonic())
             async with write_lock:
                 await codec.write_frame(writer, ctrl, pl)
+            if first:
+                ctx.stamp("first_frame")
 
         if handler is None:
             await send({"t": T_ERR, "error": f"no such endpoint: {subject}"})
             return
 
-        ctx = Context(id=control.get("ctx_id"))
         deadline_ms = control.get("deadline_ms")
         if deadline_ms is not None:
             # the caller's remaining budget, rebased onto this host's clock
@@ -400,6 +421,55 @@ class RequestPlaneServer:
             self._active.pop((writer, stream_id), None)
 
 
+#: the stages a worker closes, as the frame of the first token carries them
+WORKER_STAGES = ("hop", "ingest", "queue", "first")
+
+
+def _is_seconds(v: Any) -> bool:
+    """A number off the wire that can be added to a counter of seconds."""
+    return isinstance(v, (int, float)) and math.isfinite(v) and v >= 0
+
+
+def _seconds(table: Any) -> dict:
+    """A table of stage seconds as it came off the wire, what is no such
+    thing left out: the stages feed counters that only grow."""
+    if not isinstance(table, dict):
+        return {}
+    return {k: float(v) for k, v in table.items()
+            if isinstance(k, str) and _is_seconds(v)}
+
+
+def _take_timeline(ctx: Context, timeline: Any, arrived: float) -> bool:
+    """Begin `ctx`'s timeline at `arrived` from the table a caller sent on
+    its `req` header: its stages so far, and `hop` where the sender's
+    monotonic clock is this host's (the boot ids match; monotonic clocks
+    don't compare across hosts, and `hop` is then left out). False, and
+    nothing kept, for a caller that sent none."""
+    if not isinstance(timeline, dict):
+        return False
+    ctx.begin(arrived)
+    ctx.stages.update(_seconds(timeline.get("s")))
+    at = timeline.get("at")
+    if timeline.get("boot") == BOOT_ID and _is_seconds(at):
+        ctx.stages["hop"] = max(arrived - at, 0.0)
+    return True
+
+
+def _merge_stages(ctx: Context, control: dict):
+    """The worker's stages off the frame of the first token: the caller's
+    timeline goes on from where that frame was read off the socket."""
+    ctx.stages.update(_seconds(control["stages"]))
+    ctx.stamp_s = control.get("read_s") or ctx.stamp_s
+
+
+def _worker_stages(ctx: Context, now: float) -> dict:
+    """What the frame of the first token takes back: the stages closed on
+    this side, and `first_frame` as far as this header's packing."""
+    out = {k: ctx.stages[k] for k in WORKER_STAGES if k in ctx.stages}
+    out["first_frame"] = max(now - ctx.stamp_s, 0.0)
+    return out
+
+
 class EngineError(RuntimeError):
     """Terminal error surfaced from a remote engine stream."""
 
@@ -439,6 +509,10 @@ class _Connection:
                 if frame is None:
                     break
                 control, payload = frame
+                if "stages" in control:
+                    # local, never on the wire: when the frame that carries
+                    # a worker's stages was read off the socket
+                    control["read_s"] = time.monotonic()
                 q = self.streams.get(control.get("stream"))
                 if q is not None:
                     q.put_nowait((control, payload))
@@ -610,6 +684,7 @@ class RequestPlaneClient:
         """Issue a request; returns the async response stream. Cancelling the
         context sends a cancel frame to the worker."""
         ctx = context or Context()
+        ctx.stamp("route")  # the router's pick ends where the dial begins
         if ctx.deadline_exceeded():
             raise DeadlineExceeded(f"deadline passed before calling {address}")
         try:
@@ -631,9 +706,16 @@ class RequestPlaneClient:
         trace = current_trace()
         if trace is not None:
             control["traceparent"] = trace.traceparent()
+        packed = codec.pack(request)
+        if ctx.stamp_s and "send" not in ctx.stages:
+            # the request's timeline crosses the hop the way the deadline
+            # does, once: a retry or a worker's own onward call sends none,
+            # so a request's stages are counted by one worker
+            at = ctx.stamp("send")
+            control["timeline"] = {"s": dict(ctx.stages), "at": at, "boot": BOOT_ID}
         try:
             async with conn.write_lock:
-                await codec.write_frame(conn.writer, control, codec.pack(request))
+                await codec.write_frame(conn.writer, control, packed)
         except (ConnectionError, OSError) as e:
             conn.streams.pop(stream_id, None)
             raise StreamLost(f"send to {address} failed: {e}") from e
@@ -673,6 +755,8 @@ class RequestPlaneClient:
                 get_task = None
                 t = control.get("t")
                 if t == T_DATA:
+                    if "stages" in control and ctx.stamp_s:
+                        _merge_stages(ctx, control)
                     f = faults.FAULTS
                     if f.enabled:
                         act = await f.on("request_plane.frame")

@@ -1,6 +1,5 @@
-"""Router extras: sharded indexer, snapshots, event recorder/replay, and
-the stream perf recorder (reference indexer.rs:992, kv_cache_routing.md
-snapshots, recorder.rs, perf.rs)."""
+"""Router extras: sharded indexer, snapshots, event recorder/replay
+(reference indexer.rs:992, kv_cache_routing.md snapshots, recorder.rs)."""
 
 import asyncio
 import json
@@ -20,8 +19,6 @@ from dynamo_tpu.llm.kv_router.recorder import (
     replay_to_topic,
 )
 from dynamo_tpu.llm.kv_router.publisher import EVENT_TOPIC_FMT
-from dynamo_tpu.llm.perf import StreamPerf, record_stream
-from dynamo_tpu.llm.protocols.common import Annotated, LLMEngineOutput
 from dynamo_tpu.runtime import (
     DiscoveryServer,
     DistributedRuntime,
@@ -173,45 +170,6 @@ class TestRecorder:
             await idx.close()
             await drt.close()
             await server.stop()
-
-        asyncio.run(main())
-
-
-class TestStreamPerf:
-    def test_ttft_itl_throughput(self):
-        async def main():
-            async def gen():
-                await asyncio.sleep(0.05)
-                yield Annotated(data=LLMEngineOutput(token_ids=[1]))
-                for _ in range(3):
-                    await asyncio.sleep(0.02)
-                    yield Annotated(data=LLMEngineOutput(token_ids=[2]))
-
-            perf = StreamPerf()
-            items = []
-            async for item in record_stream(gen(), perf):
-                items.append(item)
-            assert len(items) == 4
-            s = perf.summary()
-            assert 0.03 < s["ttft_s"] < 0.5
-            assert 0.005 < s["mean_itl_s"] < 0.2
-            assert s["total_tokens"] == 4
-            assert s["tokens_per_second"] > 0
-
-        asyncio.run(main())
-
-    def test_empty_stream(self):
-        async def main():
-            async def gen():
-                return
-                yield  # pragma: no cover
-
-            perf = StreamPerf()
-            async for _ in record_stream(gen(), perf):
-                pass
-            s = perf.summary()
-            assert s["ttft_s"] is None
-            assert s["total_tokens"] == 0
 
         asyncio.run(main())
 
