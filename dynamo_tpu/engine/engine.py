@@ -71,7 +71,7 @@ from .bucketing import (
 )
 from .config import EngineConfig
 from .kv_cache import PageAllocator, alloc_kv_arrays, alloc_state_cache
-from .recorder import Recorder, Work
+from .recorder import ESTIMATE_RUNS, SLOW_SPAN_S, Recorder, Work
 from .sampling import SamplingParams, penalized, sample, sample_lp, unpack_mask
 from .scheduler import SlaConfig, StepPlanner
 
@@ -85,6 +85,14 @@ SCRATCH_PAGE = 0  # physical page 0 is the dump target for masked lanes
 # pads its rows to 128 columns, so 256 KiB here is at most half of it.
 # The benchmark's cell takes 64 x 65 x 4 = 16,640 B. Above it: pow2 rungs.
 MIXED_TABLE_SMEM_BYTES = 256 * 1024
+# The running entry's successor is queued this share of the entry's
+# estimated length before its end (_successor_deadline), or SUCCESSOR_SAFETY
+# times the loop's own longest dispatch of the last few, whichever is more.
+# A closed loop's arrivals land in an entry's first third (PERF.md section
+# 6, PR 57), so a wide margin costs no arrival its place and a late
+# successor costs the device its time.
+SUCCESSOR_MARGIN = 0.25
+SUCCESSOR_SAFETY = 2.0
 
 
 # The one in-checkout home of JAX's persistent compilation cache. The path
@@ -1049,6 +1057,14 @@ class JaxEngine:
         # piped mixed steps {"kind": "mixed", "first": dev[R], "done",
         # "progressed", "decode", "spec"}; at most two entries
         self._inflight: deque = deque()
+        # the running entry's fetch, asked for at the start of the wait for
+        # its successor's moment (_await_successor) and taken up by the
+        # step's own fetch
+        self._early_fetch: Optional[asyncio.Future] = None
+        # the loop's seconds from taking up a step's dispatch to the launch's
+        # return of the entry it queued second, the last few: the margin
+        self._successor_costs: deque = deque(maxlen=ESTIMATE_RUNS)
+        self._sleep = asyncio.sleep  # the wait's timer (a test drives its own)
         # split prefill dispatches and drained mixed steps awaiting their
         # first-token fetch, which the step that dispatched them makes
         self._pending_prefill: List[dict] = []
@@ -1750,6 +1766,8 @@ class JaxEngine:
         # pull left running would keep injecting into reused pages
         for t in list(self._bg_tasks):
             t.cancel()
+        if self._early_fetch is not None:
+            self._early_fetch.cancel()
         if self.kvbm is not None:
             # flush any staged commits, drain in-flight write-through
             # offloads (staged + queued), stop the tier thread, then
@@ -2921,6 +2939,7 @@ class JaxEngine:
             ):
                 self._wake.clear()
                 self._rec.entry_kind = "none"
+                self._rec.idle()
                 with self._rec.span("wait"):
                     await self._wake.wait()
                 continue
@@ -2951,12 +2970,28 @@ class JaxEngine:
         the newer one's compute. A lean mixed step is an entry like a
         block: it queues behind what is in flight and its successor queues
         behind it, so the host never holds more than one entry behind the
-        running one, and an arrival admitted at a wake gets the next entry
-        to itself as a mixed step. A pack that needs host-authoritative
-        lanes (_pack_pipes) drains the pipeline first and is fetched in
-        the step that dispatched it."""
+        running one. A pack that needs host-authoritative lanes
+        (_pack_pipes) drains the pipeline first and is fetched in the step
+        that dispatched it.
+
+        The SECOND entry of the pipeline is queued LATE (_await_successor):
+        with one entry running and its program's length known, the step
+        asks for that entry's fetch first and waits until the entry is
+        about to end, admitting whoever arrives meanwhile, and only then
+        dispatches. An entry queued behind the running one cannot start
+        before it ends, so nothing is lost by waiting, and the arrivals of
+        the wait take that next entry, all of them in ONE mixed step,
+        where each stood behind a block queued before it came (and got a
+        step of its own after that). With no arrival the block is queued
+        at the deadline. Where the pipeline's depth is 1 anyway, or the
+        running program has not been timed yet, the step dispatches at
+        once, and so does the successor of a mixed step that was
+        dispatched with nothing ahead of it (the `chained` block below)."""
         self._admit_waiting()
         progressed = await self._run_injections()
+        if await self._await_successor():
+            progressed |= await self._run_injections()
+        depth, resumed = len(self._inflight), self._rec.clock()
         dispatched = False
         if await self._dispatch_mixed():
             progressed = True
@@ -2991,6 +3026,15 @@ class JaxEngine:
         fetch_block = len(self._inflight) >= 2 or (
             bool(self._inflight) and not dispatched
         )
+        if depth == 1 and len(self._inflight) > 1:
+            # what the margin has to cover: the loop's time from here to
+            # the launch's return of the entry queued second, whether a
+            # wait came before it or not (a margin that outgrew the
+            # estimate must be able to shrink again); a compile inside the
+            # launch is no reading
+            cost = self._inflight[1]["t_launched"] - resumed
+            if cost < SLOW_SPAN_S:
+                self._successor_costs.append(cost)
         progressed |= dispatched
         progressed |= await self._fetch_and_process(fetch_block)
         if self.kvbm is not None:
@@ -2999,6 +3043,76 @@ class JaxEngine:
             # device executor ever sees is that single dispatch
             self.kvbm.flush_step()
         return progressed
+
+    def _successor_deadline(self) -> Optional[float]:
+        """When the running entry's successor has to be on its way, on the
+        recorder's clock: the moment the running entry began + the engine's
+        estimate of its program (the shortest of its last runs) - a margin
+        for the dispatch itself (the larger of SUCCESSOR_MARGIN of the
+        estimate and SUCCESSOR_SAFETY times the longest such dispatch of
+        the last few). None where the successor is queued at once, as it
+        always was: the pipeline does not hold exactly one entry, its depth
+        is 1 anyway (spec mode, a guided lane, a pack waiting for the
+        drain, an invalid carry), the program has not run yet, or the
+        moment has passed."""
+        if len(self._inflight) != 1 or self._pending_prefill \
+                or not self._carry_valid or self._mixed_wait_drain \
+                or self.config.spec_mode or self._guided_decoding():
+            return None
+        running = self._inflight[0]
+        estimate = self._rec.estimate(running)
+        if estimate is None:
+            return None
+        margin = max(
+            SUCCESSOR_MARGIN * estimate,
+            SUCCESSOR_SAFETY * max(self._successor_costs, default=0.0),
+        )
+        deadline = self._rec.began(running) + estimate - margin
+        return deadline if deadline > self._rec.clock() else None
+
+    async def _await_successor(self) -> bool:
+        """Hold the second entry of the pipeline back until the running one
+        is about to end (_successor_deadline). The running entry's fetch is
+        asked for FIRST (it blocks on the fetch thread; _fetch_and_process
+        takes it up after the dispatch), so its return is the entry's true
+        end: an estimate that was too long ends the wait the moment the
+        device falls idle, and the seconds from there to the successor's
+        launch are counted (Recorder.fetched: `step_starved_s`,
+        `successor_late`). A wake inside the wait admits who arrived
+        (pages and the prefix cache, off the deadline's path) and the wait
+        goes on: the arrivals of one wait share the entry that is queued
+        at its end. KV that waits to be injected ends it at once. Returns
+        whether it waited."""
+        deadline = self._successor_deadline()
+        if deadline is None:
+            return False
+        rec = self._rec
+        running = self._inflight[0]
+        self._early_fetch = fetch = asyncio.ensure_future(
+            self._fetch(self._fetch_tree([], running)))
+        rec.successor_waits += 1
+        rec.entry_kind = running["step_kind"]
+        admitted = rec.req_admitted
+        timer = asyncio.ensure_future(self._sleep(deadline - rec.clock()))
+        try:
+            while not (fetch.done() or timer.done()):
+                self._wake.clear()
+                self._admit_waiting()
+                if self._injection_pending():
+                    break
+                wake = asyncio.ensure_future(self._wake.wait())
+                with rec.span("wait"):
+                    await asyncio.wait({fetch, timer, wake},
+                                       return_when=asyncio.FIRST_COMPLETED)
+                wake.cancel()
+        finally:
+            timer.cancel()
+        # who arrived in the turn that ended the wait: a waiter nobody
+        # admitted would hold the dispatch below at depth 1
+        self._admit_waiting()
+        if rec.req_admitted > admitted:
+            rec.successor_woken += 1
+        return True
 
     # -- admission ------------------------------------------------------- #
 
@@ -3197,7 +3311,7 @@ class JaxEngine:
         thread waiting for the device."""
         with self._rec.span("fetch"):
             out = jax.device_get(tree)
-        return out, time.perf_counter()
+        return out, self._rec.clock()
 
     async def _fetch(self, tree):
         """One host read (single RTT) for an arbitrary pytree of device
@@ -3847,6 +3961,13 @@ class JaxEngine:
 
     # -- injections (disagg preload / KVBM onboard) ---------------------- #
 
+    def _injection_pending(self) -> bool:
+        """Whether a slot's KV waits to be injected (_run_injections)."""
+        return any(
+            s is not None and (s.preloaded is not None or s.onboard is not None)
+            for s in self.slots
+        )
+
     async def _run_injections(self) -> bool:
         did = False
         for slot in list(self.slots):
@@ -4463,6 +4584,7 @@ class JaxEngine:
         entry["first"] = await self._run_on_device(
             call, tag="prefill", shape=(bucket, B_pf)
         )
+        self._rec.launched(entry)
         completions = []
         progressed = []
         for s, chunk, lane in meta:
@@ -4522,6 +4644,7 @@ class JaxEngine:
                     top_ks, top_ps, seeds, pens, pen_rows),
             tag="prefill", shape=(T_pad, 1),
         )
+        self._rec.launched(entry)
         self._last_prefill_shape = (T_pad, chunk)
         self._count_expert_rows(T_pad, chunk)
         slot.prefill_pos += chunk
@@ -5457,10 +5580,12 @@ class JaxEngine:
                     ) if s.want_routed
                 ]
                 call = self._routed_behind(call, entry)
-            self._rec.dispatched(entry, "mixed", work.of(self._step_work))
+            self._rec.dispatched(entry, "mixed", work.of(self._step_work),
+                                 program=("mixed", N_pad, *shape))
         entry["first"] = await self._run_on_device(
             call, tag="mixed", shape=(N_pad, row),
         )
+        self._rec.launched(entry)
         if pipes:
             # an entry of the pipeline: fetched in dispatch order, with
             # its successor queued behind it
@@ -5775,13 +5900,8 @@ class JaxEngine:
         # the pipeline depth is 1 and every block must be fetched+processed
         # (FSM advanced) before the next dispatch.
         with self._rec.span("pack", more=True):
-            has_guided = any(
-                s is not None and s.guided_fsm is not None
-                and s.prefill_pos >= len(s.kv_prompt) and s.generated > 0
-                for s in self.slots
-            )
             depth = 1 if (
-                cfg.spec_mode or has_guided
+                cfg.spec_mode or self._guided_decoding()
                 or (not chained and self._prefill_work_pending())
             ) else 2
             if len(self._inflight) >= depth:
@@ -5863,8 +5983,9 @@ class JaxEngine:
             # a spec round is one pass that is sure of one token a lane
             self._rec.dispatched(entry, "block", self._block_work(
                 active, cfg.spec_rounds if kind == "spec" else adv
-            ))
+            ), program=(tag, adv))
         entry["toks"] = await self._run_on_device(call, tag=tag, shape=shape)
+        self._rec.launched(entry)
         with self._rec.span("pack", more=True):
             self._last_decode_shape = (B * adv, len(active) * adv)
             if kind == "spec":
@@ -5890,6 +6011,15 @@ class JaxEngine:
             self._step_counter += 1
         return True
 
+    def _guided_decoding(self) -> bool:
+        """Whether a guided slot is decode-active: the next step's mask
+        then hangs on the token the step before it emitted."""
+        return any(
+            s is not None and s.guided_fsm is not None
+            and s.prefill_pos >= len(s.kv_prompt) and s.generated > 0
+            for s in self.slots
+        )
+
     def _block_work(self, active: List[int], steps: int):
         """(useful operations, least bytes) of a block of `steps` forward
         passes over these lanes: a lane counts the passes that its
@@ -5908,29 +6038,56 @@ class JaxEngine:
         """One RTT: fetch pending prefill first-tokens + the oldest entry of
         the pipeline (a decode block or a piped mixed step) together, then
         run host bookkeeping/emission. Entries leave in the order they
-        were dispatched."""
+        were dispatched. Where the oldest entry's fetch was asked for ahead
+        of the dispatch (_await_successor), that one is taken up first, the
+        entry's alone, and what the step dispatched beside the pipeline
+        rides a fetch of its own behind it."""
+        early, self._early_fetch = self._early_fetch, None
+        if early is not None:
+            await self._process_fetched(
+                [], self._inflight[0], early, waited=True)
+            fetch_block = False
         want = self._inflight[0] if (fetch_block and self._inflight) else None
         prefills = self._pending_prefill
         self._pending_prefill = []
         if want is None and not prefills:
-            return False
+            return early is not None
+        await self._process_fetched(
+            prefills, want, self._fetch(self._fetch_tree(prefills, want)))
+        return True
+
+    @staticmethod
+    def _fetch_tree(prefills: List[dict], want: Optional[dict]):
+        """What one fetch reads of these entries: the prefills' first
+        tokens, the pipeline entry's tokens, and a stateful family's chosen
+        experts, where a request asked."""
+        mixed = want is not None and want["kind"] == "mixed"
+        entries = prefills if want is None else [*prefills, want]
+        return (
+            [p["first"] for p in prefills],
+            None if want is None else want["first" if mixed else "toks"],
+            [e.pop("routed", None) for e in entries],
+        )
+
+    async def _process_fetched(self, prefills: List[dict],
+                               want: Optional[dict], fetch,
+                               waited: bool = False):
+        """Await `fetch` (of _fetch_tree(prefills, want)) and do the host's
+        part for what it brought. `waited`: the fetch was asked for before
+        the successor was queued (Recorder.fetched counts a late one)."""
         mixed = want is not None and want["kind"] == "mixed"
         if mixed and len(self._inflight) >= 2 and not want["after_drain"]:
             # dispatched without a drain before it, and its successor was
             # queued before this fetch: no host round trip on either side
             self.mixed_steps_piped += 1
         entries = prefills if want is None else [*prefills, want]
-        tree = (
-            [p["first"] for p in prefills],
-            None if want is None else want["first" if mixed else "toks"],
-            # a stateful family's chosen experts, where a request asked
-            [e.pop("routed", None) for e in entries],
-        )
         self._rec.entry_kind = (want or prefills[0])["step_kind"]
-        (firsts_np, toks_np, routed_np), t_ready = await self._fetch(tree)
+        (firsts_np, toks_np, routed_np), t_ready = await fetch
         for e, routed in zip(entries, routed_np):
             e["routed"] = routed
-        self._rec.fetched(entries, t_ready)
+        behind = [e["t_launched"] for e in (*self._inflight, *self._pending_prefill)
+                  if e is not want]
+        self._rec.fetched(entries, t_ready, min(behind, default=None), waited)
 
         for p, first in zip(prefills, firsts_np):
             await self._process_prefill_result(p, first)
@@ -5952,7 +6109,6 @@ class JaxEngine:
                     routed=None if want.get("routed") is None
                     else (want["routed"], want["want_routed"]),
                 )
-        return True
 
     async def _process_prefill_result(self, p: dict, first,
                                       piped: bool = False):
@@ -6228,6 +6384,8 @@ class JaxEngine:
         request so callers can migrate/retry rather than hang."""
         self._inflight.clear()
         self._pending_prefill = []
+        self._early_fetch = None
+        self._rec.idle()
         self._carry_valid = False
         self._dirty_lanes.clear()
         self._dirty_tables.clear()
@@ -6259,6 +6417,8 @@ class JaxEngine:
         reach their consumers before flipping discovery."""
         self._inflight.clear()
         self._pending_prefill = []
+        self._early_fetch = None
+        self._rec.idle()
         self._carry_valid = False
         self._dirty_lanes.clear()
         self._dirty_tables.clear()

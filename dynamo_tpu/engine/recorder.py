@@ -28,7 +28,21 @@ One table for the engine's loop, exported through `JaxEngine.stats()`:
   exported once it is not 0: models/hybrid.py, models/nemotron_h.py,
   models/exaone_moe.py). A family may instead end its count with a dict
   that names its shares (models/mla_moe.py: `step_latent_kv_bytes`,
-  `step_latent_kv_expanded_bytes`, `step_expert_bytes`).
+  `step_latent_kv_expanded_bytes`, `step_expert_bytes`). An entry also
+  names its PROGRAM (a block of K steps, a mixed step of a token bucket):
+  the intervals of a program's last ESTIMATE_RUNS runs stay, and the
+  shortest of them is the engine's estimate of its next run
+  (`estimate`), from which the loop times the running entry's successor
+  (engine._await_successor).
+* the successor's wait (docs/observability.md, "The engine's iteration"):
+  `successor_waits` (the loop held the second entry of the pipeline back
+  until the running one was about to end), `successor_woken` (a request
+  was admitted inside the wait), `successor_late` (the running entry's
+  fetch returned before its successor's launch had), and
+  `step_starved_s`: the seconds between an entry's end (its fetch's
+  return: asked for at the start of the wait, so the entry's own) and
+  the launch of the next entry, summed wherever the second came after
+  the first: the device stood idle with work at hand.
 * the waits ahead of a first token: `req_admitted`, `req_queue_wait_s`,
   `req_first_tokens`, `req_admit_to_first_s`, and the pipeline entries
   fetched between a request's arrival and its first token, its own among
@@ -54,6 +68,7 @@ from __future__ import annotations
 
 import logging
 import time
+from collections import deque
 from typing import Callable, Dict, List, Optional
 
 from jax.profiler import TraceAnnotation
@@ -73,6 +88,9 @@ STAGES = tuple(s for s in PATH_STAGES if s not in ("queue", "first", "sse"))
 #: this long is one too (a program compiling inside its launch, the
 #: profiler's stop, a paused guest: no step of a served model takes it)
 SLOW_SPAN_S = 0.5
+#: a program's estimate is the shortest of this many of its last runs: it
+#: errs early, and one run that a pause stretched cannot poison it
+ESTIMATE_RUNS = 8
 
 
 class _Span:
@@ -175,6 +193,16 @@ class Recorder:
         self.entry_kind = "none"
         self._describe = describe
         self._last_ready = 0.0
+        # the host's clock of every entry's stamps (a test drives its own)
+        self.clock = time.perf_counter
+        # ready-to-ready intervals of each program's last runs
+        self.runs: Dict[tuple, deque] = {}
+        self.successor_waits = self.successor_woken = 0
+        self.successor_late = 0
+        self.starved_s = 0.0
+        # when the last entry in flight came back with none behind it:
+        # the device stands idle until the next launch
+        self._unfollowed: Optional[float] = None
 
     # -- phases ---------------------------------------------------------- #
 
@@ -198,9 +226,12 @@ class Recorder:
 
     # -- pipeline entries ------------------------------------------------ #
 
-    def dispatched(self, entry: dict, kind: str, work: tuple):
-        """Stamp `entry` as it goes to the device: its kind, the host's
-        clock, and the (useful operations, least bytes) it was asked for; a
+    def dispatched(self, entry: dict, kind: str, work: tuple,
+                   program: Optional[tuple] = None):
+        """Stamp `entry` as it goes to the device: its kind, its program
+        (what `estimate` keys a run's length by; None: never estimated),
+        the host's clock, and the (useful operations, least bytes) it was
+        asked for; a
         family with a recurrent state says third how many of the bytes are
         the state's (models/hybrid.step_work), may say fourth how many
         are the held experts' (models/nemotron_h.step_work), and fifth and
@@ -208,7 +239,8 @@ class Recorder:
         at the whole context (models/exaone_moe.step_work); or, last, a
         dict that names its shares (models/mla_moe.step_work)."""
         entry["step_kind"] = kind
-        entry["t_dispatch"] = time.perf_counter()
+        entry["program"] = program
+        entry["t_dispatch"] = self.clock()
         if isinstance(work[-1], dict):
             # a family that names its shares of the bytes (models/mla_moe.
             # step_work) in place of counting on their order
@@ -226,14 +258,45 @@ class Recorder:
             self.window_kv_bytes += work[4]
             self.window_kv_whole_bytes += work[5]
 
-    def fetched(self, entries: List[dict], t_ready: float):
+    def launched(self, entry: dict):
+        """The launch of `entry` has returned. If the entry before it had
+        come back already, the device stood idle from then to now."""
+        entry["t_launched"] = now = self.clock()
+        if self._unfollowed is not None:
+            self.starved_s += max(now - self._unfollowed, 0.0)
+            self._unfollowed = None
+
+    def idle(self):
+        """Nothing is in flight and nothing is asked for: the device's
+        idleness from here on is nobody's wait."""
+        self._unfollowed = None
+
+    def began(self, entry: dict) -> float:
+        """When the oldest entry in flight began to run: at the return of
+        the fetch before it, or at its own dispatch if that came later."""
+        return max(self._last_ready, entry["t_dispatch"])
+
+    def estimate(self, entry: dict) -> Optional[float]:
+        """The engine's own reading of how long `entry`'s program runs:
+        the shortest of its last runs, or None before its first."""
+        runs = self.runs.get(entry["program"])
+        return min(runs) if runs else None
+
+    def fetched(self, entries: List[dict], t_ready: float,
+                next_launched: Optional[float] = None,
+                waited: bool = False):
         """The fetch that brought these entries back returned at `t_ready`:
         their interval runs from the later of the fetch before it and
         their dispatch. Entries that one fetch brings back together (a
         split prefill beside a block) share it in equal parts: the host
         cannot tell them apart. A stalled interval is kept out of its
         kind's sum, which a window's mean is taken from: one profiler's
-        stop of 2.5 s would move 600 blocks' mean by 4 ms."""
+        stop of 2.5 s would move 600 blocks' mean by 4 ms, and out of its
+        program's runs. `next_launched`: when the launch of the oldest
+        entry still in flight returned, None with nothing in flight: if
+        that is after `t_ready` the device stood idle between, and where
+        the loop had `waited` with the successor (the fetch was asked for
+        before the successor was queued) the successor came late."""
         if not entries:
             return
         since = max(self._last_ready, min(e["t_dispatch"] for e in entries))
@@ -244,7 +307,18 @@ class Recorder:
                 else self.steps[e["step_kind"]]
             row[0] += 1
             row[1] += share
+        program = entries[0]["program"] if len(entries) == 1 else None
+        if program is not None and share < SLOW_SPAN_S:
+            # an entry that came back alone: the interval is its program's
+            self.runs.setdefault(
+                program, deque(maxlen=ESTIMATE_RUNS)).append(share)
         self._last_ready = t_ready
+        if next_launched is None:
+            self._unfollowed = t_ready
+        else:
+            self.starved_s += max(next_launched - t_ready, 0.0)
+        if waited and (next_launched is None or next_launched > t_ready):
+            self.successor_late += 1
 
     # -- a request's path ------------------------------------------------ #
 
@@ -325,6 +399,10 @@ class Recorder:
             "req_blocks_ahead": self.blocks_ahead,
             "req_mixed_ahead": self.mixed_ahead,
             "req_hop_unmeasured": self.hop_unmeasured,
+            "successor_waits": self.successor_waits,
+            "successor_woken": self.successor_woken,
+            "successor_late": self.successor_late,
+            "step_starved_s": round(self.starved_s, 6),
         }
         for name, (cnt, tot) in self.stages.items():
             out[f"req_stage_{name}_count"] = cnt

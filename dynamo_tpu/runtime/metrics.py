@@ -222,6 +222,10 @@ METRICS = {
     "req_hop_unmeasured": {"kind": "counter", "layer": "engine", "unit": "requests", "help": "Requests whose timeline came from another host (another boot id): monotonic clocks do not compare, so `hop` was left out.", "export": True},
     "req_blocks_ahead": {"kind": "counter", "layer": "engine", "unit": "entries", "help": "Decode blocks fetched between a request's arrival and its first token, summed over the requests counted in req_first_tokens.", "export": True},
     "req_mixed_ahead": {"kind": "counter", "layer": "engine", "unit": "entries", "help": "Mixed steps fetched between a request's arrival and its first token, its own among them, summed over the requests counted in req_first_tokens.", "export": True},
+    "successor_waits": {"kind": "counter", "layer": "engine", "unit": "waits", "help": "Times the step loop held the second entry of the pipeline back until the running entry was about to end (engine._await_successor): one entry in flight, its program's length known, depth 2 allowed.", "export": True},
+    "successor_woken": {"kind": "counter", "layer": "engine", "unit": "waits", "help": "Of successor_waits, those inside which a request was admitted: its prompt rides the entry queued at the wait's end.", "export": True},
+    "successor_late": {"kind": "counter", "layer": "engine", "unit": "waits", "help": "Of successor_waits, those whose running entry came back (its fetch returned) before the launch of its successor had: the estimate was too long or the margin too short, and the device stood idle between.", "export": True},
+    "step_starved_s": {"kind": "counter", "layer": "engine", "unit": "seconds", "help": "Seconds between an entry's end (its fetch's return) and the return of the next entry's launch, summed wherever the launch came second and the engine had not gone idle between: the device waited for the host with work at hand.", "export": True},
     # per-kind fused coverage (docs/ragged_attention.md "Row classes"):
     # proves blended guided/spec/lora traffic actually rides the fused
     # path; the blended-trace CI smoke gates mixed_coverage_frac >= 0.9

@@ -177,9 +177,9 @@ def _engine(family="dense", **over):
     eng.dispatched = []  # (kind, work) of every entry, in order
     stamp = eng._rec.dispatched
 
-    def logged(entry, kind, work):
+    def logged(entry, kind, work, **more):
         eng.dispatched.append((kind, work))
-        return stamp(entry, kind, work)
+        return stamp(entry, kind, work, **more)
 
     eng._rec.dispatched = logged
     return eng
