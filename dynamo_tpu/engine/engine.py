@@ -260,12 +260,17 @@ def _auto_num_pages(params, model_cfg, config: EngineConfig,
         # a family with a recurrent state keeps pages for its attention
         # layers alone, and its state store (one slot a lane and a scratch
         # slot) comes out of what the pool may take
-        kv_layers, state_bytes = model_cfg.num_layers, 0
+        kv_layers, state_bytes, index_bytes = model_cfg.num_layers, 0, 0
         if hasattr(model_family(model_cfg), "STATE_FAMILY"):
-            kv_layers = model_cfg.state_spec().attention_layers
+            spec = model_cfg.state_spec()
+            kv_layers = spec.attention_layers
             state_bytes = (
                 (config.max_num_seqs + 1) * state_bytes_per_lane(model_cfg)
             )
+            # a family that selects keeps an index key a token in its
+            # index layers, in a second store under the same page ids
+            index_bytes = spec.index_layers * kv_page_bytes(
+                config.page_size, 1, spec.index_dim, model_cfg.dtype, "none")
         page_bytes = (
             # K and V; a latent layer keeps one row a token and no V store
             kv_stores(model_cfg)
@@ -275,6 +280,7 @@ def _auto_num_pages(params, model_cfg, config: EngineConfig,
                 model_cfg.head_dim, model_cfg.dtype,
                 resolve_kv_quant(config.kv_quant),
             )
+            + index_bytes
         )
         page_bytes_dev = page_bytes // _kv_shard_div(kv_sharding)
         free = int(limit * util) - int(in_use) - reserve - state_bytes
@@ -641,6 +647,9 @@ class JaxEngine:
         # expanded past them (the family's own rule, read here to count)
         limit_of = getattr(family, "absorbed_row_limit", None)
         self._absorbed_row_limit = limit_of(c) if limit_of else None
+        # ... and where its configuration selects the positions a token
+        # attends, at most so many of a context (0: every position)
+        self._index_topk = getattr(c, "index_topk", 0)
         if self._stateful:
             self._refuse_what_state_cannot_follow(config, mesh, multihost)
 
@@ -4565,7 +4574,7 @@ class JaxEngine:
                     toks, positions, tables, ctx_lens, last_idx, temps,
                     top_ks, top_ps, seeds, pens, pen_rows,
                 )
-            work = Work()
+            work = Work(self._index_topk)
             for s, chunk, lane in meta:
                 work.chunk(s.prefill_pos, chunk,
                            s.prefill_pos + chunk >= len(s.kv_prompt))
@@ -4635,7 +4644,7 @@ class JaxEngine:
                     "pens": pens, "pen_rows": pen_rows,
                 },
             )
-            work = Work()
+            work = Work(self._index_topk)
             work.chunk(slot.prefill_pos, chunk, True)
             entry = {"done": [(slot, 0)]}
             self._rec.dispatched(entry, "prefill", work.of(self._step_work))
@@ -5420,7 +5429,7 @@ class JaxEngine:
 
             off = 0
             row = 0
-            work = Work()  # what the step asks for (llama.step_work)
+            work = Work(self._index_topk)  # what the step asks for (llama.step_work)
             meta = []  # prefill rows: (slot, chunk, row)
             decode_rows = []  # (row, lane_idx, slot)
             spec_rows = []  # (first_row, lane_idx, slot, draft) — 1+d rows each
@@ -6031,8 +6040,18 @@ class JaxEngine:
             min(steps, max(s.max_tokens - s.generated - flying.get(id(s), 0), 0))
             for s in (self.slots[i] for i in active)
         ])
-        context = n * self.seq_lens[active] + n * (n - 1) // 2
-        return self._step_work(int(n.sum()), int(context.sum()), int(n.max()))
+        seq = self.seq_lens[active]
+        context = n * seq + n * (n - 1) // 2
+        more = {}
+        if self._index_topk:
+            # pass j of a lane attends, and reads, min(context + j, the most
+            # a token selects) positions
+            m = np.clip(self._index_topk - seq, 0, n)
+            picked = int((m * seq + m * (m - 1) // 2
+                          + (n - m) * self._index_topk).sum())
+            more = {"attended": picked, "kv_selected": picked}
+        return self._step_work(int(n.sum()), int(context.sum()), int(n.max()),
+                               **more)
 
     async def _fetch_and_process(self, fetch_block: bool) -> bool:
         """One RTT: fetch pending prefill first-tokens + the oldest entry of
@@ -6648,6 +6667,7 @@ def _resolve_model(name: str) -> llama.LlamaConfig:
         "tiny-nemotron-h": nemotron_h.NemotronHConfig.tiny_nemotron_h,
         "tiny-exaone-moe": exaone_moe.ExaoneMoeConfig.tiny_exaone_moe,
         "tiny-mla-moe": mla_moe.MlaMoeConfig.tiny_mla_moe,
+        "tiny-mla-dsa": mla_moe.MlaMoeConfig.tiny_mla_dsa,
         "tiny": llama.LlamaConfig.tiny,
         "llama3-3b": llama.LlamaConfig.llama3_2_3b,
         "llama3-8b": llama.LlamaConfig.llama3_8b,

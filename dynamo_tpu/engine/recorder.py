@@ -28,7 +28,9 @@ One table for the engine's loop, exported through `JaxEngine.stats()`:
   exported once it is not 0: models/hybrid.py, models/nemotron_h.py,
   models/exaone_moe.py). A family may instead end its count with a dict
   that names its shares (models/mla_moe.py: `step_latent_kv_bytes`,
-  `step_latent_kv_expanded_bytes`, `step_expert_bytes`). An entry also
+  `step_latent_kv_expanded_bytes`, `step_expert_bytes`, and for a
+  configuration that selects the rows a token attends
+  `step_index_kv_bytes`, `dsa_context_rows`, `dsa_selected_rows`). An entry also
   names its PROGRAM (a block of K steps, a mixed step of a token bucket):
   the intervals of a program's last ESTIMATE_RUNS runs stay, and the
   shortest of them is the engine's estimate of its next run
@@ -125,11 +127,18 @@ class Work:
     row as models/<family>.step_work takes it: real tokens, positions
     attended, positions whose K and V are read, tokens sampled."""
 
-    __slots__ = ("real", "context", "kv_tokens", "sampled", "rows")
+    __slots__ = ("real", "context", "kv_tokens", "sampled", "rows", "cap",
+                 "attended", "selected")
 
-    def __init__(self):
+    def __init__(self, cap: int = 0):
         self.real = self.context = self.kv_tokens = self.sampled = 0
         self.rows = 0
+        # a family that selects at most `cap` positions of a context a
+        # token (models/mla_moe.py: index_topk; 0: none) is also told the
+        # positions its tokens ATTEND, each at most `cap`, and the rows of
+        # the cache its rows must READ, at most `cap` a row
+        self.cap = cap
+        self.attended = self.selected = 0
 
     def chunk(self, start: int, n: int, completes: bool):
         """A prompt's chunk of `n` tokens behind `start`: token j attends
@@ -140,6 +149,11 @@ class Work:
         self.kv_tokens += start + n
         self.sampled += bool(completes)
         self.rows += 1
+        if self.cap:
+            # the first m tokens see fewer than `cap` positions
+            m = min(max(self.cap - start, 0), n)
+            self.attended += m * start + m * (m + 1) // 2 + (n - m) * self.cap
+            self.selected += min(start + n, self.cap)
 
     def decode(self, seq_len: int):
         """A decode row at a context of `seq_len`, its own token in it."""
@@ -148,11 +162,16 @@ class Work:
         self.kv_tokens += seq_len
         self.sampled += 1
         self.rows += 1
+        if self.cap:
+            self.attended += min(seq_len, self.cap)
+            self.selected += min(seq_len, self.cap)
 
     def of(self, step_work) -> tuple:
+        more = {"attended": self.attended, "kv_selected": self.selected} \
+            if self.cap else {}
         return step_work(self.real, self.context, 1,
                          kv_tokens=self.kv_tokens, sampled=self.sampled,
-                         rows=self.rows)
+                         rows=self.rows, **more)
 
 
 class Recorder:
@@ -173,6 +192,11 @@ class Recorder:
         # would cost as heads of K and V (mla_moe)
         self.latent_kv_bytes = 0
         self.latent_kv_expanded_bytes = 0
+        # ... and where the family selects the rows a token attends: the
+        # index keys' bytes, the positions its rows had behind them and
+        # the rows of those they had to read
+        self.index_kv_bytes = 0
+        self.dsa_context_rows = self.dsa_selected_rows = 0
         self.expert_bytes = 0  # of min_bytes, the held experts' (nemotron_h)
         # K and V bytes the window layers read, and would at the whole
         # context (exaone_moe)
@@ -248,6 +272,9 @@ class Recorder:
             self.expert_bytes += named["expert_bytes"]
             self.latent_kv_bytes += named["latent_kv_bytes"]
             self.latent_kv_expanded_bytes += named["latent_kv_expanded_bytes"]
+            self.index_kv_bytes += named.get("index_kv_bytes", 0)
+            self.dsa_context_rows += named.get("dsa_context_rows", 0)
+            self.dsa_selected_rows += named.get("dsa_selected_rows", 0)
         self.model_flops += work[0]
         self.min_bytes += work[1]
         if len(work) > 2:
@@ -427,6 +454,10 @@ class Recorder:
             out["step_latent_kv_bytes"] = float(self.latent_kv_bytes)
             out["step_latent_kv_expanded_bytes"] = float(
                 self.latent_kv_expanded_bytes)
+        if self.dsa_context_rows:
+            out["step_index_kv_bytes"] = float(self.index_kv_bytes)
+            out["dsa_context_rows"] = self.dsa_context_rows
+            out["dsa_selected_rows"] = self.dsa_selected_rows
         if self.window_kv_whole_bytes:
             out["step_window_kv_bytes"] = float(self.window_kv_bytes)
             out["step_window_kv_whole_bytes"] = float(

@@ -48,7 +48,7 @@ as it came: there is no V store.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any, Dict, Optional
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -59,6 +59,8 @@ from ..ops.latent_attention import (
     absorbed_attention,
     absorbed_rows_attention,
     expanded_attention,
+    select_rows,
+    selected_attention,
 )
 from ..ops.paged_attention import rows_at
 from ..ops.state_cache import StateCache, StateSpec
@@ -99,6 +101,21 @@ WHY_REFUSED = {
 ONE_TABLE_WIDTH = True
 
 
+class LayerTypes(tuple):
+    """A per-layer list of a configuration file as the dataclass keeps it:
+    hashable (the configuration is a static argument and a cache's key), and
+    equal to the LIST it came from, which is what the benchmark's checks
+    compare a field with."""
+
+    def __eq__(self, other):
+        return tuple.__eq__(self, tuple(other) if isinstance(other, list) else other)
+
+    def __ne__(self, other):
+        return not self == other
+
+    __hash__ = tuple.__hash__
+
+
 @dataclass(frozen=True)
 class MlaMoeConfig(LlamaConfig):
     rope_theta: float = 1e6
@@ -118,6 +135,21 @@ class MlaMoeConfig(LlamaConfig):
     routed_scaling_factor: float = 1.8
     n_group: int = 1
     topk_group: int = 1
+    #: the rotation pairs NEIGHBOURS (2i, 2i + 1), not halves (i, i + D/2)
+    rope_interleave: bool = False
+    #: per layer "dense" | "sparse" (None: `first_k_dense_replace` dense
+    #: layers, then sparse ones); more entries than layers: the first count
+    mlp_layer_types: Optional[Tuple[str, ...]] = None
+    #: learned sparse attention (`model_type` `glm_moe_dsa`): a row attends
+    #: the `index_topk` positions its layer's indexer scores highest; 0: none,
+    #: and no leaf, store or operation of what follows exists
+    index_topk: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    indexer_rope_interleave: bool = False
+    #: per layer "full" (an indexer of its own) | "shared" (the picks of the
+    #: nearest full layer below)
+    indexer_types: Optional[Tuple[str, ...]] = None
 
     def __post_init__(self):
         # what the CACHE holds a token and layer, in the names every reader
@@ -140,6 +172,56 @@ class MlaMoeConfig(LlamaConfig):
                 f"experts [{self.first_expert_held}, "
                 f"{self.first_expert_held + self.num_experts}) lie past the "
                 f"router's width {self.router_width}")
+        L = self.num_layers
+        for name, kinds in (("mlp_layer_types", ("dense", "sparse")),
+                            ("indexer_types", ("full", "shared"))):
+            given = getattr(self, name)
+            if given is None:
+                continue
+            given = LayerTypes(tuple(given)[:L])  # the layers that run
+            object.__setattr__(self, name, given)
+            if len(given) != L or set(given) - set(kinds):
+                raise ValueError(
+                    f"{name} {given}: one of {kinds} a layer, {L} layers")
+        if self.mlp_layer_types is not None:
+            leading = next((i for i, k in enumerate(self.mlp_layer_types)
+                            if k != "dense"), L)
+            if leading != self.first_k_dense_replace:
+                raise ValueError(
+                    f"mlp_layer_types begins with {leading} dense layers and "
+                    f"first_k_dense_replace says {self.first_k_dense_replace}")
+            if "sparse" not in self.mlp_layer_types:
+                raise ValueError("mlp_layer_types leaves no sparse layer")
+        if self.index_topk:
+            if self.indexer_types is None or self.indexer_types[0] != "full":
+                raise ValueError(
+                    "index_topk needs indexer_types, and a first layer that "
+                    "is `full`: a `shared` layer borrows the picks of a full "
+                    "layer BELOW it")
+            if (self.index_n_heads < 1
+                    or self.index_head_dim < self.qk_rope_head_dim):
+                raise ValueError(
+                    "an index head holds the rotated qk_rope_head_dim "
+                    "dimensions first, then the others")
+
+    @property
+    def ffn_kinds(self) -> Tuple[str, ...]:
+        """Per layer "dense" or "sparse"."""
+        if self.mlp_layer_types is not None:
+            return self.mlp_layer_types
+        Ld = self.first_k_dense_replace
+        return ("dense",) * Ld + ("sparse",) * (self.num_layers - Ld)
+
+    @property
+    def dense_layers(self) -> int:
+        return self.ffn_kinds.count("dense")
+
+    @property
+    def full_layers(self) -> Tuple[int, ...]:
+        """The layers that hold an indexer (none without `index_topk`)."""
+        if not self.index_topk:
+            return ()
+        return tuple(i for i, k in enumerate(self.indexer_types) if k == "full")
 
     @property
     def qk_head_dim(self) -> int:
@@ -155,9 +237,11 @@ class MlaMoeConfig(LlamaConfig):
         the chosen experts are recorded in (ops/state_cache.py)."""
         return StateSpec(
             state_layers=0, attention_layers=self.num_layers,
-            routed_layers=self.num_layers - self.first_k_dense_replace,
+            routed_layers=self.num_layers - self.dense_layers,
             state_shape=(1,), conv_shape=(1, 1), state_dtype=self.dtype,
-            experts_per_token=self.num_experts_per_tok, value_store=False)
+            experts_per_token=self.num_experts_per_tok, value_store=False,
+            index_layers=len(self.full_layers),
+            index_dim=self.index_head_dim if self.index_topk else 0)
 
     @classmethod
     def tiny_mla_moe(cls, **overrides):
@@ -175,6 +259,21 @@ class MlaMoeConfig(LlamaConfig):
         kw.update(overrides)
         return cls(**kw)
 
+    @classmethod
+    def tiny_mla_dsa(cls, **overrides):
+        """`tiny_mla_moe` with the learned selection at the published
+        ratios: 16 picks under contexts of tens to hundreds, an indexer in
+        layers 0 and 2 whose picks layers 1 and 3 borrow, index heads half
+        rotated, neighbours paired, half of the router's experts held."""
+        kw = dict(
+            rope_interleave=True, index_topk=16, index_n_heads=4,
+            index_head_dim=16, indexer_rope_interleave=True,
+            indexer_types=("full", "shared", "full", "shared"),
+            mlp_layer_types=("dense", "sparse", "sparse", "sparse"),
+        )
+        kw.update(overrides)
+        return cls.tiny_mla_moe(**kw)
+
 
 # ---------------------------------------------------------------------- #
 # weights
@@ -187,7 +286,7 @@ def init_params(config: MlaMoeConfig, key: jax.Array) -> Dict[str, Any]:
     stacking). Matrices are named `w*`, `embed`, `lm_head` (the int8 control
     rounds those); norms, the float32 router and its choice bias are not."""
     c = config
-    L, Ld = c.num_layers, c.first_k_dense_replace
+    L, Ld = c.num_layers, c.dense_layers
     Le = L - Ld
     H, NH = c.hidden_size, c.num_heads
     I, Im, Is = c.intermediate_size, c.moe_intermediate_size, shared_width(c)
@@ -234,12 +333,24 @@ def init_params(config: MlaMoeConfig, key: jax.Array) -> Dict[str, Any]:
         "ws_up": dense((Le, H, Is)),
         "ws_down": dense((Le, Is, H)),
     }
-    return {
+    params = {
         "embed": dense((c.vocab_size, H)),
         "layers": {"attention": attention, "dense": mlp, "experts": routed},
         "final_norm": 1.0 + dense((H,), f32),
         "lm_head": dense((H, c.vocab_size)),
     }
+    if c.index_topk:
+        # the full layers' indexers, drawn LAST: a configuration without the
+        # selection draws what it always drew
+        Lf, J, D = len(c.full_layers), c.index_n_heads, c.index_head_dim
+        params["layers"]["indexer"] = {
+            "wq": dense((Lf, c.q_lora_rank, J * D)),
+            "wk": dense((Lf, H, D)),
+            "k_norm": 1.0 + dense((Lf, D), f32),
+            "k_norm_bias": dense((Lf, D), f32),
+            "w_heads": dense((Lf, H, J)),
+        }
+    return params
 
 
 # ---------------------------------------------------------------------- #
@@ -247,18 +358,64 @@ def init_params(config: MlaMoeConfig, key: jax.Array) -> Dict[str, Any]:
 # ---------------------------------------------------------------------- #
 
 
+def _rotate(x, positions, c: MlaMoeConfig, interleave: bool):
+    """x [..., heads, rope] rotated at `positions` [...]: halves paired (i
+    with i + rope / 2), or NEIGHBOURS (2i with 2i + 1) where `interleave`."""
+    cos, sin = rope_cos_sin(positions, x.shape[-1], c.rope_theta)
+    if not interleave:
+        return apply_rope(x, cos, sin)
+    pairs = x.astype(f32).reshape(*x.shape[:-1], -1, 2)
+    a, b = pairs[..., 0], pairs[..., 1]
+    cos, sin = cos[..., None, :], sin[..., None, :]
+    out = jnp.stack([a * cos - b * sin, b * cos + a * sin], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def _query_latent(layer, h, c: MlaMoeConfig):
+    """c_q = rmsnorm(h W_qa) [..., q_lora_rank]."""
+    with jax.named_scope("mla_q"):
+        return norm(qdot(h, layer["wq_a"]).astype(c.dtype), layer["q_a_norm"],
+                    c.rms_norm_eps)
+
+
 def _queries(layer, h, positions, c: MlaMoeConfig):
     """q [..., heads, nope + rope] of h [..., H] at `positions` [...], its
     rope part rotated."""
+    return _queries_of(layer, _query_latent(layer, h, c), positions, c)
+
+
+def _queries_of(layer, cq, positions, c: MlaMoeConfig):
+    """... of c_q [..., q_lora_rank], which a full layer's indexer reads too."""
     with jax.named_scope("mla_q"):
-        cq = norm(qdot(h, layer["wq_a"]).astype(c.dtype), layer["q_a_norm"],
-                  c.rms_norm_eps)
         q = qdot(cq, layer["wq_b"]).astype(c.dtype)
-        q = q.reshape(*h.shape[:-1], c.num_heads, c.qk_head_dim)
-        cos, sin = rope_cos_sin(positions, c.qk_rope_head_dim, c.rope_theta)
+        q = q.reshape(*cq.shape[:-1], c.num_heads, c.qk_head_dim)
         nope = c.qk_nope_head_dim
         return jnp.concatenate(
-            [q[..., :nope], apply_rope(q[..., nope:], cos, sin)], axis=-1)
+            [q[..., :nope],
+             _rotate(q[..., nope:], positions, c, c.rope_interleave)], axis=-1)
+
+
+def index_inputs(ix, cq, h, positions, c: MlaMoeConfig):
+    """A full layer's indexer on h [..., H] and its c_q: (q^I [..., J, D],
+    k^I [..., D], w [..., J] float32). `q^I = c_q W_qI`; `k^I = layernorm(h
+    W_kI)`, ONE key a token, which the index store keeps; the first
+    `qk_rope_head_dim` dimensions of each are rotated; `w = h W_w` times the
+    positive scale `J^-0.5 D^-0.5`."""
+    J, D, rope = c.index_n_heads, c.index_head_dim, c.qk_rope_head_dim
+    with jax.named_scope("dsa_index_inputs"):
+        q = qdot(cq, ix["wq"]).astype(c.dtype).reshape(*cq.shape[:-1], J, D)
+        k = qdot(h, ix["wk"])
+        k = k - k.mean(-1, keepdims=True)
+        k = k * jax.lax.rsqrt((k * k).mean(-1, keepdims=True) + c.rms_norm_eps)
+        k = (k * ix["k_norm"] + ix["k_norm_bias"]).astype(c.dtype)
+        turn = c.indexer_rope_interleave
+        q = jnp.concatenate(
+            [_rotate(q[..., :rope], positions, c, turn), q[..., rope:]], -1)
+        k = jnp.concatenate(
+            [_rotate(k[..., None, :rope], positions, c, turn)[..., 0, :],
+             k[..., rope:]], -1)
+        w = qdot(h, ix["w_heads"]).astype(f32) * (J * D) ** -0.5
+        return q, k, w
 
 
 def latent_rows(layer, h, positions, c: MlaMoeConfig):
@@ -269,8 +426,8 @@ def latent_rows(layer, h, positions, c: MlaMoeConfig):
         row = qdot(h, layer["wkv_a"]).astype(c.dtype)
         lat = norm(row[..., :c.kv_lora_rank], layer["kv_a_norm"],
                    c.rms_norm_eps)
-        cos, sin = rope_cos_sin(positions, c.qk_rope_head_dim, c.rope_theta)
-        k_r = apply_rope(row[..., None, c.kv_lora_rank:], cos, sin)[..., 0, :]
+        k_r = _rotate(row[..., None, c.kv_lora_rank:], positions, c,
+                      c.rope_interleave)[..., 0, :]
         pad = jnp.zeros((*lat.shape[:-1], c.head_dim - c.latent_dim), c.dtype)
         return jnp.concatenate([lat, k_r, pad], axis=-1)
 
@@ -284,16 +441,43 @@ def _kvb(layer, c: MlaMoeConfig):
 def absorbed(layer, q, latent, page_tables, seq_lens, c: MlaMoeConfig):
     """One-token rows q [B, heads, nope + rope] over their pages in the
     latent space -> [B, heads, v_head_dim]."""
-    nope, rank = c.qk_nope_head_dim, c.kv_lora_rank
-    w = _kvb(layer, c)
     with jax.named_scope("mla_absorb"):
-        q_lat = jnp.einsum("bhn,rhn->bhr", q[..., :nope], w[..., :nope],
-                           preferred_element_type=f32).astype(c.dtype)
-        u = absorbed_attention(
-            jnp.concatenate([q_lat, q[..., nope:]], axis=-1), latent,
-            page_tables, seq_lens, rank, c.qk_head_dim ** -0.5)
-        return jnp.einsum("bhr,rhv->bhv", u, w[..., nope:],
-                          preferred_element_type=f32).astype(c.dtype)
+        return _in_latent_space(
+            layer, q, c, lambda ql: absorbed_attention(
+                ql, latent, page_tables, seq_lens, c.kv_lora_rank,
+                c.qk_head_dim ** -0.5))
+
+
+def _in_latent_space(layer, q, c: MlaMoeConfig, walk):
+    """q [B, heads, nope + rope] with W_kvb's key half folded in, through
+    `walk((q~ | q_r)) -> u [B, heads, rank]`, and out through its value
+    half -> [B, heads, v_head_dim]."""
+    nope = c.qk_nope_head_dim
+    w = _kvb(layer, c)
+    q_lat = jnp.einsum("bhn,rhn->bhr", q[..., :nope], w[..., :nope],
+                       preferred_element_type=f32).astype(c.dtype)
+    u = walk(jnp.concatenate([q_lat, q[..., nope:]], axis=-1))
+    return jnp.einsum("bhr,rhv->bhv", u, w[..., nope:],
+                      preferred_element_type=f32).astype(c.dtype)
+
+
+def selects(c: MlaMoeConfig, page_tables, page_size: int) -> bool:
+    """Whether a program over these tables selects at all: a table of
+    `index_topk` positions or fewer holds no context the selection would
+    thin, and its rows take the walks that stand."""
+    return bool(c.index_topk) and (
+        page_tables.shape[-1] * page_size > c.index_topk)
+
+
+def selected(layer, q, latent, tables, picks, serve, c: MlaMoeConfig):
+    """One-token lanes q [N, heads, nope + rope] over the `index_topk` rows
+    each PICKED (`picks` [N, k], a full layer's own or the layer below's),
+    absorbed -> [N, heads, v_head_dim]; zeros where `serve` [N] is not."""
+    with jax.named_scope("dsa_selected"):
+        return _in_latent_space(
+            layer, q, c, lambda ql: selected_attention(
+                ql, latent, tables, picks, c.kv_lora_rank,
+                c.qk_head_dim ** -0.5, serve))
 
 
 def absorbed_row_limit(c: MlaMoeConfig) -> int:
@@ -306,28 +490,34 @@ def absorbed_row_limit(c: MlaMoeConfig) -> int:
 
 
 def rows_attention(layer, q, latent, page_tables, row_starts, row_lens,
-                   ctx_lens, c: MlaMoeConfig):
+                   ctx_lens, c: MlaMoeConfig, whole=None):
     """q [M, heads, nope + rope] on a flat axis of rows -> [M, heads,
     v_head_dim], each row by what it costs: rows of one token absorbed, as
     lanes (length 0 for every other row: an empty lane reads nothing); rows
     of 2 to `absorbed_row_limit(c)` tokens absorbed, a row at a time; rows
-    of more expanded (a buffer too short to hold one has no such walk)."""
+    of more expanded (a buffer too short to hold one has no such walk).
+    `whole` [R] (a program that selects): the rows of several tokens whose
+    context the selection leaves whole, which alone are served here; every
+    other token is a lane of the selected walk, the one-token rows too."""
     M = q.shape[0]
-    one = row_lens == 1
-    slots = jnp.where(one, row_starts, M).astype(jnp.int32)
-    lanes = absorbed(layer, rows_at(q, slots), latent, page_tables,
-                     jnp.where(one, ctx_lens + 1, 0), c)
+    if whole is None:
+        one = row_lens == 1
+        slots = jnp.where(one, row_starts, M).astype(jnp.int32)
+        lanes = absorbed(layer, rows_at(q, slots), latent, page_tables,
+                         jnp.where(one, ctx_lens + 1, 0), c)
     limit = absorbed_row_limit(c)
     rows = (q, latent, layer["wkv_b"], page_tables, row_starts, row_lens,
             ctx_lens, c.kv_lora_rank, c.qk_nope_head_dim,
             c.qk_head_dim ** -0.5)
     if M > limit:
         with jax.named_scope("mla_expand"):
-            out = expanded_attention(*rows, longer_than=limit)
+            out = expanded_attention(*rows, longer_than=limit, only=whole)
     else:
         out = jnp.zeros((M, c.num_heads, c.v_head_dim), q.dtype)
     with jax.named_scope("mla_absorb_rows"):
-        out = absorbed_rows_attention(*rows, upto=limit, out=out)
+        out = absorbed_rows_attention(*rows, upto=limit, out=out, only=whole)
+    if whole is not None:
+        return out
     return out.at[slots].set(lanes, mode="drop")
 
 
@@ -351,29 +541,88 @@ def _pool(kv_k):
 
 def _layer_stack(params, c: MlaMoeConfig, x, pages, attn_fn, valid=None):
     """x [T, H] through the layers: `attn_fn(layer, h, pages, li) -> (out,
-    pages)` on the normed input of layer `li`. A layer's leaves are taken
-    from the STORED stacks with one static index each. -> (x, pages, the
-    experts chosen [sparse layers, T, K])."""
+    pages)` on the normed input of layer `li` (`pages`: whatever the
+    forward's attention threads from layer to layer: the latent pool, and
+    for a configuration that selects the index store and the picks beside
+    it). A layer's leaves are taken from the STORED stacks with one static
+    index each, a dense or a sparse layer's by its place among its kind.
+    -> (x, pages, the experts chosen [sparse layers, T, K])."""
     layers = params["layers"]
     names = moe.EXPERT_FORMS[EXPERT_FORM]
     stacks = {k: layers["experts"][k] for k in names}
     small = {k: v for k, v in layers["experts"].items() if k not in names}
-    chosen = []
-    for li in range(c.num_layers):
+    chosen, ld = [], 0
+    for li, kind in enumerate(c.ffn_kinds):
         layer = jax.tree.map(lambda a: a[li], layers["attention"])
         with jax.named_scope("attention"):
             h = norm(x, layer["norm"], c.rms_norm_eps)
             out, pages = attn_fn(layer, h, pages, li)
         x = x + out
-        le = li - c.first_k_dense_replace
-        if le < 0:
+        if kind == "dense":
             x = dense_block(
-                jax.tree.map(lambda a: a[li], layers["dense"]), x, c)
+                jax.tree.map(lambda a: a[ld], layers["dense"]), x, c)
+            ld += 1
         else:
+            le = li - ld
             x, idx = routed_block(
                 jax.tree.map(lambda a: a[le], small), stacks, le, x, c, valid)
             chosen.append(idx)
     return x, pages, jnp.stack(chosen)
+
+
+def _index_key_written(params, c: MlaMoeConfig, li, index, cq, h, positions,
+                       phys, offs):
+    """The full layer `li`'s indexer on h and its c_q, its key written into
+    the index store at the slots the latent rows go to -> (the store, the
+    layer's place among the full layers, q^I, w). Keys and queries are
+    padded to the store's row (no lane at the store the engine allocates;
+    a plain pool in `kv_v`'s place is as wide as the latent row)."""
+    fi = c.full_layers.index(li)
+    ix = jax.tree.map(lambda a: a[fi], params["layers"]["indexer"])
+    q_i, k_i, w = index_inputs(ix, cq, h, positions, c)
+    pad = index.shape[3] - k_i.shape[-1]
+    k_i = jnp.pad(k_i, ((0, 0), (0, pad)))
+    index = kv_write(index, fi, phys, offs, k_i[:, None, :])
+    return index, fi, jnp.pad(q_i, ((0, 0), (0, 0), (0, pad))), w
+
+
+class _Selecting(NamedTuple):
+    """What a selecting forward's attention threads through the layers in
+    `pages`' place: the latent pool, the index-key store (`kv_v`'s place in
+    every program: ops/state_cache.py) and the picks of the nearest full
+    layer below."""
+
+    pool: Any
+    index: Any
+    picks: Any = None
+
+
+def _attend_selecting(params, c: MlaMoeConfig, layer, h, sel: _Selecting, li,
+                      positions, phys, offs, tables, seq_lens, serve,
+                      standing=None):
+    """A layer of a program that selects, over one-token lanes (a decode
+    step's, or a flat buffer's tokens each under its row's table): the
+    latent row and, in a full layer, the index key are written; a full layer
+    scores and picks, a shared one takes `sel.picks`; the lanes `serve` marks
+    attend their picks, and `standing(layer, q, latent) -> [N, heads, v]`
+    serves the others. -> (the projected output, `sel` updated)."""
+    cq = _query_latent(layer, h, c)
+    q = _queries_of(layer, cq, positions, c)
+    row = latent_rows(layer, h, positions, c)
+    pool = kv_write(sel.pool, li, phys, offs, row[:, None, :])
+    index, picks = sel.index, sel.picks
+    if li in c.full_layers:
+        index, fi, q_i, w = _index_key_written(
+            params, c, li, index, cq, h, positions, phys, offs)
+        with jax.named_scope("dsa_select"):
+            picks = select_rows(q_i, w, kv_layer(index, fi), tables, seq_lens,
+                                c.index_topk, serve)
+    latent = kv_layer(pool, li)
+    attn = selected(layer, q, latent, tables, picks, serve, c)
+    if standing is not None:
+        attn = jnp.where(serve[:, None, None], attn,
+                         standing(layer, q, latent))
+    return _o_proj(layer, attn, c), _Selecting(pool, index, picks)
 
 
 def _refuse(lora, emb_override=None):
@@ -417,7 +666,14 @@ def decode_forward(
         attn = absorbed(layer, q, kv_layer(pages, li), page_tables, seq_lens, c)
         return _o_proj(layer, attn, c), pages
 
+    if c.index_topk:
+        attn_fn, pages = _selecting(
+            params, c, attn_fn, pages, kv_v, page_tables, positions, phys,
+            offs, lambda: (page_tables, seq_lens, jnp.ones((B,), bool), None))
+
     x, pages, chosen = _layer_stack(params, c, x, pages, attn_fn)
+    if c.index_topk:
+        pages, kv_v = pages.pool, pages.index
     logits = _head(params, c, x)
     if cache is None:
         return logits, pages, kv_v
@@ -427,8 +683,45 @@ def decode_forward(
     return logits, cache.replace(pages=pages, routed_ring=ring), kv_v
 
 
+def _selecting(params, c: MlaMoeConfig, attn_fn, pool, index, page_tables,
+               positions, phys, offs, lanes: Callable,
+               picked: Optional[list] = None):
+    """(the attention of a forward whose configuration selects, what it
+    threads through the layers). Where the program's tables are wide enough
+    to select (`selects`): every layer through `_attend_selecting`, over
+    `lanes() -> (each lane's table, the positions it may pick from, the
+    lanes that select, the walk of the others or None)`; `picked` collects
+    each full layer's picks. Else `attn_fn`, the walks that stand, with a
+    full layer's index key written beside its latent row all the same (a
+    page holds both, whoever wrote it)."""
+    if selects(c, page_tables, kv_page_size(pool)):
+        tables, seq_lens, serve, standing = lanes()
+
+        def selecting(layer, h, sel: _Selecting, li):
+            out, sel = _attend_selecting(
+                params, c, layer, h, sel, li, positions, phys, offs, tables,
+                seq_lens, serve, standing)
+            if picked is not None and li in c.full_layers:
+                picked.append(sel.picks)
+            return out, sel
+
+        return selecting, _Selecting(pool, index)
+
+    def with_keys(layer, h, sel: _Selecting, li):
+        out, pool = attn_fn(layer, h, sel.pool, li)
+        index = sel.index
+        if li in c.full_layers:
+            index, *_ = _index_key_written(
+                params, c, li, index, _query_latent(layer, h, c), h,
+                positions, phys, offs)
+        return out, _Selecting(pool, index)
+
+    return with_keys, _Selecting(pool, index)
+
+
 def _flat_rows(params, c: MlaMoeConfig, kv_k, kv_v, x, positions, phys, offs,
-               valid, page_tables, row_starts, row_lens, ctx_lens, last):
+               valid, page_tables, row_starts, row_lens, ctx_lens, last,
+               row_ids, picked: Optional[list] = None):
     """x [M, H] on a flat axis that rows share (a mixed step's buffer, a
     prefill batch's chunks laid end to end): every row's latents are
     written (a slot that is not `valid` writes to the scratch page), then
@@ -446,7 +739,30 @@ def _flat_rows(params, c: MlaMoeConfig, kv_k, kv_v, x, positions, phys, offs,
             ctx_lens, c)
         return _o_proj(layer, attn, c), pages
 
+    if c.index_topk:
+        def lanes():
+            # a row of several tokens whose context the selection leaves
+            # whole takes the walks that stand, by row; every other real
+            # token (a decode row, a tail behind a long context) is a lane
+            # of the selected walk under its row's table, with a pick of
+            # its own
+            whole = (row_lens > 1) & (ctx_lens + row_lens <= c.index_topk)
+            ids = row_ids
+            if ids is None:  # a prefill batch: rows of equal length
+                T = x.shape[0] // page_tables.shape[0]
+                ids = jnp.arange(x.shape[0], dtype=jnp.int32) // T
+            return (page_tables[ids], positions + 1, valid & ~whole[ids],
+                    lambda layer, q, latent: rows_attention(
+                        layer, q, latent, page_tables, row_starts, row_lens,
+                        ctx_lens, c, whole=whole))
+
+        attn_fn, pages = _selecting(
+            params, c, attn_fn, pages, kv_v, page_tables, positions, phys,
+            offs, lanes, picked)
+
     x, pages, chosen = _layer_stack(params, c, x, pages, attn_fn, valid)
+    if c.index_topk:
+        pages, kv_v = pages.pool, pages.index
     logits = _head(params, c, x[last])
     if cache is None:
         return logits, pages, kv_v
@@ -484,7 +800,7 @@ def ragged_forward(
     valid = jnp.arange(M, dtype=jnp.int32) < row_lens.sum()
     return _flat_rows(
         params, c, kv_k, kv_v, x, positions, phys, offs, valid, page_tables,
-        row_starts, row_lens, ctx_lens, last_flat)
+        row_starts, row_lens, ctx_lens, last_flat, row_ids)
 
 
 def prefill_forward_batched(
@@ -506,11 +822,17 @@ def prefill_forward_batched(
     slots b * T ..., last_idx[b] + 1 real ones). Returns (logits_last [B,
     vocab], kv_k, kv_v)."""
     _refuse(lora, emb_override)
+    return _prefill_batch(params, config, tokens, positions, kv_k, kv_v,
+                          page_tables, context_lens, last_idx, all_logits)
+
+
+def _prefill_batch(params, c: MlaMoeConfig, tokens, positions, kv_k, kv_v,
+                   page_tables, context_lens, last_idx, all_logits=False,
+                   picked: Optional[list] = None):
     if all_logits:
         raise NotImplementedError(
             "the latent-attention family cannot verify drafts: its forwards "
             "return the last position's logits alone")
-    c = config
     B, T = tokens.shape
     with jax.named_scope("embed"):
         x = embed_rows(params["embed"], tokens, c.dtype).reshape(B * T, -1)
@@ -522,7 +844,25 @@ def prefill_forward_batched(
     return _flat_rows(
         params, c, kv_k, kv_v, x, positions.reshape(B * T),
         phys.reshape(B * T), offs.reshape(B * T), valid, page_tables,
-        row_starts, row_lens, context_lens, row_starts + last_idx)
+        row_starts, row_lens, context_lens, row_starts + last_idx, None,
+        picked)
+
+
+def prefill_picks(params, config: MlaMoeConfig, tokens, positions, kv_k, kv_v,
+                  page_tables, context_lens, last_idx):
+    """`prefill_forward_batched` that also says what it PICKED: (logits, kv_k,
+    kv_v, picks [full layers, B * T, index_topk]): the positions each token
+    of the chunks attended in each layer that holds an indexer (-1: none;
+    all -1 for the tokens of a row that the selection leaves whole, which
+    walks by row). For the builders' comparison of the served picks with the
+    reference's (tools/long_lane.py; tests/test_mla_dsa_family.py); no
+    engine program calls it."""
+    if not selects(config, page_tables, kv_page_size(_pool(kv_k)[0])):
+        raise ValueError("tables of index_topk positions or fewer pick nothing")
+    picked: list = []
+    out = _prefill_batch(params, config, tokens, positions, kv_k, kv_v,
+                         page_tables, context_lens, last_idx, picked=picked)
+    return (*out, jnp.stack(picked))
 
 
 def prefill_forward(
@@ -556,10 +896,23 @@ def attention_impl(c: MlaMoeConfig) -> Dict[str, str]:
     ["attention_impl"]): every walk is XLA over gathered blocks of pages,
     and a prefill batch's chunks and a mixed step's rows each take the one
     their length gives them (`rows_attention`)."""
+    if not c.index_topk:
+        return {
+            "decode": "xla-latent-absorbed",
+            "prefill": "xla-latent-by-row",
+            "ragged": "xla-latent-by-row",
+        }
+    # a configuration that selects: every one-token row READS its picked
+    # rows alone (a gather by position, absorbed), and so does every token
+    # of a longer row behind more than `index_topk` positions, a lane each
+    # (gather, not a mask over the row's whole context: 2,048 rows a token
+    # against the context's 16k, and one mechanism for every kind of row);
+    # a row of several tokens that the selection leaves whole walks by row
+    by_row = f"xla-latent-selected-top{c.index_topk}-gather+by-row"
     return {
-        "decode": "xla-latent-absorbed",
-        "prefill": "xla-latent-by-row",
-        "ragged": "xla-latent-by-row",
+        "decode": f"xla-latent-selected-top{c.index_topk}-gather",
+        "prefill": by_row,
+        "ragged": by_row,
     }
 
 
@@ -574,7 +927,9 @@ def step_work(c: MlaMoeConfig, real_tokens: int, context_tokens: int,
               kv_tokens: Optional[int] = None,
               weight_bytes: Optional[float] = None,
               kv_bytes: Optional[float] = None,
-              rows: Optional[int] = None):
+              rows: Optional[int] = None,
+              attended: Optional[int] = None,
+              kv_selected: Optional[int] = None):
     """(useful operations, least HBM bytes, and by name: of those bytes
     the latent cache's `latent_kv_bytes` and the experts' `expert_bytes`,
     and what the same context would cost as `heads` heads of K and V,
@@ -591,8 +946,19 @@ def step_work(c: MlaMoeConfig, real_tokens: int, context_tokens: int,
     with the experts a pass's real rows touch in expectation under an even
     router; the context's latent rows read once and the new ones written,
     each at its width in HBM (640 lanes for 512 + 64: the zeros are read
-    with the row and are counted); the head."""
-    L, Ld = c.num_layers, c.first_k_dense_replace
+    with the row and are counted); the head.
+
+    A configuration that selects (`index_topk`): a token attends `attended`
+    positions, at most `index_topk` each, and a row must read `kv_selected`
+    latent rows, at most `index_topk` a row (a floor: the tokens of one row
+    may pick apart); the least bytes are THOSE, not the context's. In its
+    full layers a token also passes through the indexer's three projections
+    and scores every position of its context at `index_n_heads x
+    index_head_dim` multiply-adds, and a row reads its context's index keys
+    whole and writes its own: `index_kv_bytes`. `dsa_context_rows` /
+    `dsa_selected_rows`: the positions the rows had behind them and the rows
+    of those they had to read."""
+    L, Ld = c.num_layers, c.dense_layers
     Le = L - Ld
     wb = jnp.dtype(c.dtype).itemsize if weight_bytes is None else weight_bytes
     kv_bytes = latent_row_bytes(c) if kv_bytes is None else kv_bytes
@@ -610,26 +976,40 @@ def step_work(c: MlaMoeConfig, real_tokens: int, context_tokens: int,
     router = H * c.router_width  # float32
     head = H * c.vocab_size
     share = c.num_experts / c.router_width
+    Lf, index_flops, index_kv, indexer = len(c.full_layers), 0, 0, 0
+    read = kv_tokens
+    if Lf:
+        attended = context_tokens if attended is None else attended
+        read = kv_tokens if kv_selected is None else kv_selected
+        J, D = c.index_n_heads, c.index_head_dim
+        indexer = c.q_lora_rank * J * D + H * D + H * J
+        index_flops = 2 * Lf * (real_tokens * indexer + J * D * context_tokens)
+        index_kv = (Lf * D * jnp.dtype(c.dtype).itemsize
+                    * (kv_tokens + real_tokens))
+        context_tokens = attended
     flops = (
         2 * real_tokens * (
             L * attention + Ld * mlp
             + Le * (router + shared + K * share * expert))
         + 2 * NH * (c.qk_head_dim + c.v_head_dim) * L * context_tokens
-        + 2 * head * sampled
+        + 2 * head * sampled + index_flops
     )
     one_pass = -(-real_tokens // max(passes, 1))
     touched = moe.experts_touched(c.num_experts, one_pass * K * share)
     experts = passes * Le * touched * expert * wb
-    latent = L * kv_bytes * (kv_tokens + real_tokens)
+    latent = L * kv_bytes * (read + real_tokens)
     expanded = (L * NH * (c.qk_head_dim + c.v_head_dim)
-                * jnp.dtype(c.dtype).itemsize * (kv_tokens + real_tokens))
+                * jnp.dtype(c.dtype).itemsize * (read + real_tokens))
     nbytes = (
         passes * (
-            (L * attention + Ld * mlp) * wb
+            (L * attention + Ld * mlp + Lf * indexer) * wb
             + Le * (router * 4 + shared * wb)
             + head * wb)
-        + experts + latent
+        + experts + latent + index_kv
     )
-    return int(flops), int(nbytes), {
-        "latent_kv_bytes": int(latent), "expert_bytes": int(experts),
-        "latent_kv_expanded_bytes": int(expanded)}
+    named = {"latent_kv_bytes": int(latent), "expert_bytes": int(experts),
+             "latent_kv_expanded_bytes": int(expanded)}
+    if Lf:
+        named.update(index_kv_bytes=int(index_kv), dsa_context_rows=kv_tokens,
+                     dsa_selected_rows=read)
+    return int(flops), int(nbytes), named

@@ -43,6 +43,14 @@ of the context: 13 copies of 21 MB a layer for a tail of 13 behind 16k), and
 a token axis on every lane of the lanes' walk (lanes x tile times the flops
 for one row's sake).
 
+A configuration that SELECTS the positions a token attends (a learned
+indexer's `index_topk` highest: models/mla_moe.py) adds two functions at the
+end of this module and no walk of the three changes for it: `select_rows`
+scores a lane's context from the index-key store and takes the exact top-k,
+`selected_attention` gathers THOSE latent rows by position and attends them
+absorbed; every token that selects is a lane of both (docs/latent_cache.md,
+"A learned selection"). The by-row walks take `only`, the rows to serve.
+
 No Pallas kernel reads the latent row yet (the decode kernel's values are
 as wide as its keys, and the prefill kernels read K and V of equal heads);
 ROADMAP.md M4 has what one needs.
@@ -158,6 +166,12 @@ def _one_function_a_program(*static):
     return functools.partial(jax.jit, static_argnames=static)
 
 
+def _served(serve, only):
+    """`serve` [R] held to the rows `only` marks (None: as it is, and no
+    operation more in the program)."""
+    return serve if only is None else serve & only
+
+
 def _by_row(q, out, serve, Tq, tables, row_starts, row_lens, ctx_lens,
             attend):
     """`out` [M, H, vdim] with the slots of the rows that `serve` [R] marks
@@ -204,13 +218,14 @@ def expanded_attention(
     nope: int,
     scale: float,
     longer_than: int = 1,  # rows of this many tokens and fewer are skipped
+    only=None,  # [R] bool: of those rows, the ones to serve (None: all)
 ) -> jax.Array:
     """Causal attention of every row of MORE than `longer_than` tokens over
     its own pages (its history and itself: the row's latents are written
     already), expanded through `w_kvb` a block at a time -> [M, H, vdim];
     slots of the other rows return zeros."""
     return _expanded_rows(
-        q, *latent, w_kvb, page_tables, row_starts, row_lens, ctx_lens,
+        q, *latent, w_kvb, page_tables, row_starts, row_lens, ctx_lens, only,
         rank=rank, nope=nope, scale=scale, longer_than=longer_than,
         positions=EXPANDED_POSITIONS, queries=EXPANDED_QUERIES)
 
@@ -218,8 +233,8 @@ def expanded_attention(
 @_one_function_a_program(
     "rank", "nope", "scale", "longer_than", "positions", "queries")
 def _expanded_rows(q, pool, li, w_kvb, page_tables, row_starts, row_lens,
-                   ctx_lens, *, rank, nope, scale, longer_than, positions,
-                   queries):
+                   ctx_lens, only, *, rank, nope, scale, longer_than,
+                   positions, queries):
     M, H, D = q.shape
     W, rope = pool.shape[3], D - nope
     vdim = w_kvb.shape[1] // H - nope
@@ -251,8 +266,9 @@ def _expanded_rows(q, pool, li, w_kvb, page_tables, row_starts, row_lens,
         return jnp.moveaxis(o, 0, 1)  # [Tq, H, vdim]
 
     return _by_row(
-        q, jnp.zeros((M, H, vdim), q.dtype), row_lens > longer_than, Tq,
-        tables, row_starts, row_lens, ctx_lens, attend)
+        q, jnp.zeros((M, H, vdim), q.dtype),
+        _served(row_lens > longer_than, only), Tq, tables, row_starts,
+        row_lens, ctx_lens, attend)
 
 
 def absorbed_rows_attention(
@@ -268,6 +284,7 @@ def absorbed_rows_attention(
     scale: float,
     upto: int,  # rows of 2 ... `upto` tokens are served
     out: jax.Array,  # [M, H, vdim]: what the other rows' slots keep
+    only=None,  # [R] bool: of those rows, the ones to serve (None: all)
 ) -> jax.Array:
     """Causal attention of every row of 2 to `upto` tokens over its own
     pages (its history and itself), in the latent space -> `out` with those
@@ -278,14 +295,15 @@ def absorbed_rows_attention(
     to q's dtype, as the lanes' walk rounds them."""
     return _absorbed_rows(
         q, *latent, w_kvb, page_tables, row_starts, row_lens, ctx_lens, out,
-        rank=rank, nope=nope, scale=scale, upto=upto, pages=ABSORBED_PAGES,
+        only, rank=rank, nope=nope, scale=scale, upto=upto, pages=ABSORBED_PAGES,
         queries=ABSORBED_ROW_QUERIES)
 
 
 @_one_function_a_program(
     "rank", "nope", "scale", "upto", "pages", "queries")
 def _absorbed_rows(q, pool, li, w_kvb, page_tables, row_starts, row_lens,
-                   ctx_lens, out, *, rank, nope, scale, upto, pages, queries):
+                   ctx_lens, out, only, *, rank, nope, scale, upto, pages,
+                   queries):
     M, H, D = q.shape
     W, rope = pool.shape[3], D - nope
     w = w_kvb.reshape(rank, H, -1)
@@ -318,5 +336,138 @@ def _absorbed_rows(q, pool, li, w_kvb, page_tables, row_starts, row_lens,
                           preferred_element_type=f32).astype(q.dtype)
 
     return _by_row(
-        q, out, (row_lens > 1) & (row_lens <= upto), Tq, tables, row_starts,
-        row_lens, ctx_lens, attend)
+        q, out, _served((row_lens > 1) & (row_lens <= upto), only), Tq,
+        tables, row_starts, row_lens, ctx_lens, attend)
+
+
+# ---------------------------------------------------------------------- #
+# a learned selection of the context (the lightning indexer's picks)
+# ---------------------------------------------------------------------- #
+
+#: lanes that one step of `select_rows` / `selected_attention` holds: each
+#: lane gathers its OWN `k` latent rows (32 lanes x 2,048 rows x 640 lanes of
+#: bfloat16 are 84 MB) and, while it scores, its own block of index keys
+SELECTED_LANES = 32
+#: pages of every lane that one step of the index scoring gathers
+INDEX_PAGES = 16
+
+
+def _in_tiles(serve, out, fn):
+    """`out` [N, ...] with the slots `serve` [N] marks filled by `fn(ids,
+    live) -> [tile, ...]` for a tile of lanes `ids` (the `live` ones real).
+    The lanes to serve are walked `SELECTED_LANES` at a time, those first:
+    a step's padding and the tokens another walk serves cost no trip. A
+    batch of one tile's lanes or fewer is one call and no loop."""
+    N, tile = serve.shape[0], SELECTED_LANES
+    if N <= tile:
+        ids = jnp.arange(N, dtype=jnp.int32)
+        keep = serve.reshape(N, *[1] * (out.ndim - 1))
+        return jnp.where(keep, fn(ids, serve), out)
+    order = jnp.pad(jnp.argsort(~serve, stable=True).astype(jnp.int32),
+                    (0, tile))
+    count = serve.sum()
+    steps = jnp.arange(tile)
+
+    def one(t, out):
+        ids = jax.lax.dynamic_slice_in_dim(order, t * tile, tile)
+        live = t * tile + steps < count
+        return out.at[jnp.where(live, ids, N)].set(fn(ids, live), mode="drop")
+
+    return jax.lax.fori_loop(0, -(-count // tile), one, out)
+
+
+def select_rows(
+    q_i: jax.Array,  # [N, J, D] index queries, one token a lane
+    w: jax.Array,  # [N, J] float32: a token's weight of each index head
+    index: KVLayer,  # the index-key store [full layers, pages, rows, D] + fi
+    tables: jax.Array,  # [N, max_pages] each lane's own page table
+    seq_lens: jax.Array,  # [N] positions the lane may pick from (s < that)
+    k: int,
+    serve: jax.Array,  # [N] bool: the lanes that pick
+) -> jax.Array:
+    """picks [N, k] i32: the `k` positions of largest index score `I[s] =
+    sum_j w_j relu(q_j . k_s)` among each lane's first `seq_lens` positions,
+    EXACT (`jax.lax.top_k` of float32 scores: the set is the model's), in no
+    order that means anything; -1 in the places a context shorter than `k`
+    leaves empty, and everywhere in a lane that is not served. A block of
+    `INDEX_PAGES` pages of keys a step: the scores of a lane are a vector
+    `[positions]`, never a head axis times the context."""
+    return _select_rows(q_i, w, *index, tables, seq_lens, serve, k=k,
+                        pages=INDEX_PAGES)
+
+
+@_one_function_a_program("k", "pages")
+def _select_rows(q_i, w, store, fi, tables, seq_lens, serve, *, k, pages):
+    N = q_i.shape[0]
+    tables, pb = _blocked_tables(tables, pages)
+    R = store.shape[2]
+    S, span = pb * R, tables.shape[1] * R
+    assert span > k, "a table of k positions or fewer selects nothing"
+
+    def pick(ids, live):
+        qt, wt, tb = q_i[ids], w[ids], tables[ids]
+        lens = jnp.where(live, seq_lens[ids], 0)
+
+        def block(j, scores):
+            t = jax.lax.dynamic_slice_in_dim(tb, j * pb, pb, axis=1)
+            keys = store[fi, t].reshape(ids.shape[0], S, -1)
+            s = jnp.einsum("njd,nsd->njs", qt, keys,
+                           preferred_element_type=f32)
+            s = jnp.einsum("njs,nj->ns", jax.nn.relu(s), wt)
+            return jax.lax.dynamic_update_slice_in_dim(scores, s, j * S, 1)
+
+        scores = jax.lax.fori_loop(
+            0, -(-jnp.max(lens) // S), block,
+            jnp.zeros((ids.shape[0], span), f32))
+        scores = jnp.where(jnp.arange(span)[None, :] < lens[:, None], scores,
+                           NEG_INF)
+        top, at = jax.lax.top_k(scores, k)
+        return jnp.where(top > NEG_INF / 2, at.astype(jnp.int32), -1)
+
+    return _in_tiles(serve, jnp.full((N, k), -1, jnp.int32), pick)
+
+
+def selected_attention(
+    q: jax.Array,  # [N, H, rank + rope]: (q_n W^K | q_r) of one token a lane
+    latent: KVLayer,
+    tables: jax.Array,  # [N, max_pages] each lane's own page table
+    picks: jax.Array,  # [N, k] the positions a lane attends; -1: none
+    rank: int,
+    scale: float,
+    serve: jax.Array,  # [N] bool: the lanes that attend
+) -> jax.Array:
+    """sum_{s in picks} softmax(scale x q . [c_s | k_r_s]) c_s -> [N, H,
+    rank], absorbed: each lane READS its picked rows and no other (a gather
+    by position through the lane's page table, `k` rows of the pool's width
+    a lane and layer whatever the context holds); zeros for a lane that is
+    not served or picks nothing."""
+    return _selected(q, *latent, tables, picks, serve, rank=rank, scale=scale)
+
+
+@_one_function_a_program("rank", "scale")
+def _selected(q, pool, li, tables, picks, serve, *, rank, scale):
+    N, H, _ = q.shape
+    R, W = pool.shape[2], pool.shape[3]
+    flat = pool.reshape(-1, W)
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, W - q.shape[2])))
+
+    def attend(ids, live):
+        at = picks[ids]
+        real = (at >= 0) & live[:, None]
+        at = jnp.maximum(at, 0)
+        page = jnp.take_along_axis(tables[ids], at // R, axis=1)
+        # one index a row into the pool as `[layers x pages x rows, W]` (a
+        # view: the row axis stays whole tiles): on a v5e 1.65 ms for 32 x
+        # 2,048 rows of 640 where `pool[li, page, row]` takes 1.88
+        # (PERF.md section 5, PR 58)
+        rows = jnp.take(flat, (li * pool.shape[1] + page) * R + at % R, axis=0)
+        s = jnp.einsum("nhw,nsw->nhs", q[ids], rows,
+                       preferred_element_type=f32) * scale
+        s = jnp.where(real[:, None, :], s, NEG_INF)
+        p = jnp.where(real[:, None, :],
+                      jnp.exp(s - s.max(-1, keepdims=True)), 0.0)
+        u = jnp.einsum("nhs,nsr->nhr", p.astype(rows.dtype), rows[..., :rank],
+                       preferred_element_type=f32)
+        return (u / jnp.maximum(p.sum(-1), 1e-30)[..., None]).astype(q.dtype)
+
+    return _in_tiles(serve, jnp.zeros((N, H, rank), q.dtype), attend)

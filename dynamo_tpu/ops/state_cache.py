@@ -39,7 +39,12 @@ A family of latent layers (models/mla_moe.py) keeps NO state of a lane
 (`value_store` False: `pages` is the latent store, and the V pool that rides
 beside the cache is one page of one value): pages are all there is to a
 sequence, so the engine serves them from the prefix index as it serves K
-and V pages. It takes a StateCache for the two leaves below.
+and V pages. It takes a StateCache for the two leaves below. Where such a
+family SELECTS the rows a token attends (`index_layers` > 0), the layers with
+an indexer keep one index key a token in a second store of pages, `[index
+layers, pages, rows, index_dim]`, under the SAME page ids: it rides in the V
+pool's place, is written wherever a latent row is written, and a page that
+the prefix index hands to another sequence brings its index keys with it.
 
 The two `routed_*` leaves are what the request plane's `routed_experts`
 annotation is answered from (benchmark/README.md, "The wire contract");
@@ -129,6 +134,12 @@ class StateSpec:
     #: False: the family keeps ONE store a layer and no V store (a latent
     #: row, models/mla_moe.py); `pages` is sized by `head_dim` alone
     value_store: bool = True
+    #: layers that keep an index key of `index_dim` values a token beside
+    #: their latent row (a learned selection of the context): a SECOND store
+    #: `[index_layers, pages, rows, index_dim]` under the latent store's page
+    #: ids, which rides in the V pool's place; 0: none
+    index_layers: int = 0
+    index_dim: int = 0
 
 
 def state_bytes_per_lane(c) -> int:
@@ -158,7 +169,11 @@ def alloc_state_cache(c, num_pages: int, page_size: int, max_seqs: int,
         for _ in range(2 if spec.value_store else 1)
     ]
     if not spec.value_store:
-        pools.append(no_value_store(spec.attention_layers, page_size, c.dtype))
+        pools.append(
+            alloc_kv_store(spec.index_layers, num_pages, page_size, 1,
+                           spec.index_dim, c.dtype, "none")
+            if spec.index_layers else
+            no_value_store(spec.attention_layers, page_size, c.dtype))
     K = spec.experts_per_token
     cache = StateCache(
         pages=pools[0],

